@@ -1,0 +1,664 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives both halves of the main path once, through the entry points a user
+calls, at the full width of gpt3-1.3b (depth and weights as the preset; the
+weights are random, from a seed):
+
+  train leg   fleet.init + parallel.parallelize -> ScanTrainStep, bf16 params,
+              bf16 AdamW moments, per-layer recompute, batch 4 x seq 1024 per
+              chip over a mesh of every local device; two scan chunks.
+  serve leg   LLMEngine behind ServingServer on port 0; four POST /generate
+              requests (12, 200, 700 and 1,500 prompt tokens; the last two
+              concurrent), /metrics scraped, zero compilations after the
+              first request.
+
+Each leg first checks the Pallas kernel it depends on against the repo's
+own reference ON THE DEVICE (flash fwd + dq/dk/dv vs `_attention_reference`;
+`ragged_paged_attention(impl="pallas")` vs `impl="scan"`) and then requires
+that kernel's Mosaic custom calls in the compiled step it just ran. Any
+failed check raises; nothing is caught to let a leg fail while the run
+exits 0.
+
+One process per chip: this parent imports neither jax nor paddle_tpu and
+runs the legs as children, one after the other, so each gets the chip (and
+its HBM) to itself. The default invocation refuses to run without a TPU.
+`--cpu-rehearsal` is the explicit CPU run at gpt2-tiny that the
+on-chip-measurement guide advises before spending chip time; it prints
+platform=cpu and never prints the ok line.
+
+Last line of stdout on success:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(REPO, "chiprun_out")
+LEGS = ("train", "serve")
+# the contract allows 1200 s, compilation included; leave room to report
+BUDGET_S = 1140.0
+
+# The sizes the issue fixes. gpt3-1.3b: hidden 2048, 24 layers, 16 heads x
+# 128, FFN 8192, vocab 50,304 (models/gpt.py GPT_PRESETS).
+FULL = dict(
+    preset="gpt3-1.3b", batch_per_chip=4, seq=1024, scan_steps=4,
+    flash_shape=(2, 16, 1024, 128),
+    # 127 pages x 16 tokens + the pool's 16-token write pad = the model's
+    # 2,048 positions (init_cache refuses a slab longer than the learned
+    # position table, so "8 x 2,048" is 2,032 addressable tokens per slot)
+    slots=8, n_blocks=127, prompts=(12, 200, 700, 1500), max_new=32,
+    paged=dict(heads=16, kv_heads=16, head_dim=128, cache_len=2048),
+)
+TINY = dict(
+    preset="gpt2-tiny", batch_per_chip=2, seq=128, scan_steps=2,
+    flash_shape=(1, 2, 512, 64),
+    slots=4, n_blocks=31, prompts=(12, 40, 100, 200), max_new=8,
+    paged=dict(heads=2, kv_heads=2, head_dim=64, cache_len=128),
+)
+
+
+# --------------------------------------------------------------------------
+# shared by both legs (children only: these import jax / paddle_tpu)
+# --------------------------------------------------------------------------
+
+def _say(msg: str):
+    print(msg, flush=True)
+
+
+def _require(cond: bool, what: str):
+    if not cond:
+        raise AssertionError(f"chip_smoke: check failed: {what}")
+    _say(f"  ok: {what}")
+
+
+def _device_report(rehearsal: bool) -> dict:
+    """First thing in a leg: versions, platform, kind, count. Sets no
+    platform itself; fails unless JAX came up on a TPU whose device_kind is
+    in the peak table."""
+    import importlib.metadata as md
+
+    import jax
+    import jaxlib
+    try:
+        libtpu = md.version("libtpu")
+    except md.PackageNotFoundError:
+        libtpu = "not installed"
+    devs = jax.devices()
+    dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    _say(f"jax {jax.__version__} jaxlib {jaxlib.__version__} libtpu "
+         f"{libtpu} python {sys.version.split()[0]} "
+         f"platform={dev['platform']} device_kind={dev['kind']!r} "
+         f"count={dev['count']}")
+    want = "cpu" if rehearsal else "tpu"
+    if dev["platform"] != want:
+        raise SystemExit(
+            f"chip_smoke: JAX came up on platform={dev['platform']!r}, "
+            f"this run needs {want!r}"
+            + ("" if rehearsal else
+               " — no accelerator found; refusing to run (use "
+               "--cpu-rehearsal for the gpt2-tiny CPU rehearsal)"))
+    from paddle_tpu.obs.flops import peak_flops
+    peak = peak_flops(dev["kind"], dev["platform"])  # raises if unknown
+    _say(f"peak table: {dev['kind']!r} -> {peak / 1e12:g} TFLOP/s bf16")
+    return dev
+
+
+def _start_leg(rehearsal: bool):
+    """Device check first, then the compile cache before any compilation.
+    Returns (device dict, cache entries before)."""
+    dev = _device_report(rehearsal)
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    return dev, _cache_report("before")
+
+
+def _trace_paths() -> dict:
+    """{kernel/path: traces} from ops.pallas_mode, for the log."""
+    from paddle_tpu.ops import pallas_mode
+    return {f"{k}/{p}": n
+            for (k, p), n in sorted(pallas_mode.KERNEL_TRACES.items())}
+
+
+def _cache_report(when: str) -> int:
+    from paddle_tpu.utils import compile_cache
+    n = compile_cache.entry_count()
+    _say(f"compile cache {when}: dir={compile_cache.cache_dir()} "
+         f"entries={n}")
+    return n
+
+
+def _kernel_row(callsite: str) -> dict:
+    """The compile observatory's record of the executable built at
+    `callsite` (exactly one is expected)."""
+    from paddle_tpu.obs.compile_observatory import compile_observatory
+    rows = [r for r in compile_observatory().snapshot()["rows"]
+            if r["callsite"] == callsite]
+    _require(len(rows) == 1,
+             f"one executable registered at {callsite} (got {len(rows)})")
+    return rows[0]
+
+
+def _max_err(a, b) -> float:
+    import jax.numpy as jnp
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
+# --------------------------------------------------------------------------
+# train leg
+# --------------------------------------------------------------------------
+
+def _flash_parity(size: dict, rehearsal: bool):
+    """Flash fwd + dq/dk/dv against `_attention_reference` on this device,
+    bf16 causal. Tolerance: both sides feed bf16 operands to fp32-
+    accumulating dots and round the result to bf16, which keeps 8
+    significant bits — one ulp is 1.56e-2 for a value in [2, 4) and 3.1e-2
+    in [4, 8). Outputs are softmax averages of unit-normal v (the first
+    rows see only a few keys, so |o| reaches 4-5); gradients of
+    sum(o * w) with unit-normal w have the same scale. The two paths round
+    p to bf16 at different points of the accumulation (per 256-wide block
+    after the running rescale vs once per row), so results may differ by
+    one ulp: 4e-2 absolute admits one ulp anywhere below 8 and is ~25x
+    below what a wrong mask, offset or block would produce (O(1)). (First
+    chip run, PR 21: o 1.56e-2, dq 1.86e-2, dk 1.56e-2, dv 1.56e-2.)"""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import attention as A
+    B, H, S, D = size["flash_shape"]
+    rng = np.random.RandomState(0)
+    q, k, v, w = (jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
+                  for _ in range(4))
+    scale = 1.0 / float(np.sqrt(D))
+
+    def flash(**kw):
+        # on the CPU rehearsal force_pallas runs the same kernels
+        # interpreted; on the chip the wrapper picks them by itself
+        return lambda q_, k_, v_: A.flash_attention(
+            q_, k_, v_, causal=True, force_pallas=rehearsal, **kw)
+
+    def ref(q_, k_, v_):
+        return A._attention_reference(q_, k_, v_, True, scale)
+
+    def out_and_grads(fn):
+        """jitted (q, k, v) -> (o, dq, dk, dv) of sum(o * w)."""
+        def loss(q_, k_, v_):
+            o = fn(q_, k_, v_)
+            return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
+        vg = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                        has_aux=True))
+
+        def run():
+            (_, o), grads = vg(q, k, v)
+            return (o,) + tuple(grads)
+        return run
+
+    got, want = out_and_grads(flash())(), out_and_grads(ref)()
+    tol = 4e-2
+    errs = dict(zip(("o", "dq", "dk", "dv"), map(_max_err, got, want)))
+    _say(f"flash vs reference at {[B, H, S, D]} bf16 causal: max abs err "
+         + " ".join(f"{n}={e:.2e}" for n, e in errs.items())
+         + f" (tolerance {tol:g})")
+    for n, e in errs.items():
+        _require(np.isfinite(e) and e <= tol, f"flash {n} within {tol:g}")
+
+    if rehearsal:
+        _say("  dropout variant: not covered (pltpu.prng has no CPU "
+             "lowering)")
+        return
+    # the dropout variant (in-kernel TPU PRNG, SMEM seed) is not on the
+    # smoke's training path (dropout 0): compile and run it once here
+    dropped = out_and_grads(flash(dropout_p=0.1, dropout_seed=7))
+    first, again = dropped(), dropped()
+    _require(all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))
+                 for x in first),
+             "flash dropout_p=0.1 fwd+bwd compiles, finite")
+    _require(_max_err(first[0], got[0]) > 1e-3,
+             "flash dropout_p=0.1 output differs from dropout 0")
+    _require(_max_err(first[0], again[0]) == 0.0,
+             "flash dropout is a function of the seed (same seed, same bits)")
+
+
+def _train_strategy(n_dev: int, scan_steps: int, layout: str = ""):
+    """dp x sharding over every local device, ZeRO stage 2 when the
+    sharding axis is real (the shape of README 'Distributed training').
+    `layout` ("dp=2,mp=2") names another one for a multi-chip host."""
+    from paddle_tpu.distributed import DistributedStrategy
+    if layout:
+        chosen = {k: int(v) for k, v in
+                  (part.split("=") for part in layout.split(","))}
+    elif n_dev % 4 == 0:
+        chosen = {"dp": n_dev // 2, "sharding": 2}
+    else:
+        chosen = {"dp": n_dev}
+    degrees = {"dp": 1, "mp": 1, "sharding": 1, **chosen}
+    strategy = DistributedStrategy()
+    strategy.hybrid_configs = {
+        "dp_degree": degrees["dp"], "mp_degree": degrees["mp"],
+        "pp_degree": 1, "sharding_degree": degrees["sharding"]}
+    if degrees["sharding"] > 1:
+        strategy.sharding = True
+        strategy.sharding_configs = {"stage": 2, "offload": False}
+    strategy.scan_steps = scan_steps
+    return strategy, degrees
+
+
+def leg_train(size: dict, rehearsal: bool, layout: str = "") -> dict:
+    dev, cache_before = _start_leg(rehearsal)
+
+    import jax
+    import numpy as np
+
+    _say("[train] kernel parity on this device")
+    _flash_parity(size, rehearsal)
+
+    import paddle_tpu as paddle
+    from paddle_tpu import optimizer as optim
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.models.gpt import GPTForCausalLM
+    from paddle_tpu.obs.compile_observatory import compile_observatory
+    from paddle_tpu.parallel import ScanTrainStep, parallelize
+
+    n_dev = dev["count"]
+    K = size["scan_steps"]
+    strategy, degrees = _train_strategy(n_dev, K, layout)
+    batch_shards = degrees["dp"] * degrees["sharding"]
+    B, S = size["batch_per_chip"] * batch_shards, size["seq"]
+    _say(f"[train] {size['preset']} bf16, recompute, AdamW bf16 moments, "
+         f"layout {degrees} over {n_dev} device(s), global batch {B} x "
+         f"seq {S}, scan_steps {K}")
+
+    t0 = time.perf_counter()
+    paddle.seed(0)
+    model = GPTForCausalLM.from_preset(size["preset"], use_recompute=True)
+    model.to(dtype="bfloat16")
+    opt = optim.AdamW(learning_rate=1e-4, parameters=model.parameters(),
+                      moment_dtype="bfloat16")
+    fleet.init(is_collective=True, strategy=strategy)
+    mesh = fleet.get_hybrid_communicate_group().build_mesh()
+    _require(mesh.devices.size == n_dev,
+             f"mesh {dict(mesh.shape)} uses every local device")
+    step = parallelize(model, opt, mesh=mesh, strategy=strategy)
+    _require(isinstance(step, ScanTrainStep),
+             "strategy.scan_steps gave a ScanTrainStep")
+    # the observatory AOT-compiles the chunk once (compile seconds, memory
+    # analysis, and which Pallas kernels the compiled text holds)
+    step.observatory = compile_observatory().enable()
+    _say(f"[train] model + sharded state built in "
+         f"{time.perf_counter() - t0:.1f}s")
+
+    rng = np.random.RandomState(0)
+    ids = rng.randint(0, model.config.vocab_size, (B, S)).astype(np.int32)
+    labels = np.roll(ids, -1, axis=1)          # next token, fixed batch
+    ids_chunk = np.broadcast_to(ids, (K,) + ids.shape).copy()
+    labels_chunk = np.broadcast_to(labels, (K,) + labels.shape).copy()
+
+    walls, losses = [], []
+    for chunk in range(2):                      # the second is the warm one
+        t0 = time.perf_counter()
+        out = step(ids_chunk, labels_chunk)
+        vals = np.asarray(jax.block_until_ready(out.data), np.float32)
+        walls.append(time.perf_counter() - t0)
+        losses.extend(float(x) for x in vals)
+        _say(f"[train] chunk {chunk}: {walls[-1]:.2f}s wall, losses "
+             + " ".join(f"{x:.4f}" for x in vals))
+    _require(all(np.isfinite(losses)), "loss finite on every step")
+    _require(losses[-1] < losses[0],
+             f"loss lower at the end ({losses[-1]:.4f}) than at the start "
+             f"({losses[0]:.4f})")
+    _require(step.dispatch_count == 2, "two chunk dispatches")
+
+    row = _kernel_row("train/scan_chunk")
+    kernels = row["pallas_kernels"] or {}
+    _say(f"[train] compiled chunk: compile {row['compile_seconds']:.1f}s "
+         f"(AOT lower+compile), temp {row['temp_bytes']} B, args "
+         f"{row['argument_bytes']} B, Pallas kernels {kernels}")
+    _say(f"[train] kernel trace paths: {_trace_paths()}")
+    if rehearsal:
+        _say("  kernel census not applicable on the CPU (the model takes "
+             "the XLA reference there by design)")
+    else:
+        n_layers = model.config.num_hidden_layers
+        # per layer: one forward, one recomputed forward (use_recompute),
+        # one dq and one dkv kernel — in the compiled text, not the trace
+        for name, want in (("flash_fwd", 2 * n_layers),
+                           ("flash_bwd_dq", n_layers),
+                           ("flash_bwd_dkv", n_layers)):
+            _require(kernels.get(name, 0) == want,
+                     f"compiled step holds {want} {name} custom calls "
+                     f"(got {kernels.get(name, 0)})")
+        _require("flash_attention/reference" not in _trace_paths(),
+                 "no flash_attention call took the XLA reference")
+
+    # state and batch really split across the mesh (four-chip run)
+    params = step._params
+    big = max(params, key=lambda k_: params[k_].size)
+    m_big = next(m for m in step._opt_state[big].values()
+                 if m.shape == params[big].shape)
+    _say(f"[train] largest param {big} {tuple(params[big].shape)}: param "
+         f"shard {tuple(params[big].addressable_shards[0].data.shape)}, "
+         f"moment shard {tuple(m_big.addressable_shards[0].data.shape)}, "
+         f"batch spec {step.data_spec}")
+    shard_deg = degrees["sharding"] * degrees["mp"]
+    if shard_deg > 1:
+        _require(m_big.addressable_shards[0].data.size * shard_deg
+                 <= m_big.size, f"moments split {shard_deg} ways")
+    in_use, peaks = [], []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        in_use.append(stats.get("bytes_in_use"))
+        peaks.append(stats.get("peak_bytes_in_use"))
+    _say(f"[train] INFO (one unrepeated run, not a metric): warm chunk "
+         f"{walls[1]:.2f}s = {walls[1] / K * 1e3:.0f} ms/step wall after "
+         f"block_until_ready; first chunk {walls[0]:.2f}s incl. compile; "
+         f"per device bytes_in_use {in_use} peak_bytes_in_use {peaks}")
+    if n_dev > 1 and all(x is not None for x in in_use):
+        # (the peak is another matter: the model and the whole optimizer
+        # state are built on device 0 before they are sharded)
+        _require(max(in_use) <= 1.1 * min(in_use),
+                 "training state split evenly: per-device bytes_in_use "
+                 "within 10% after the run")
+    # the step took the model's buffers over and donated them; the model
+    # gets the trained weights back by reference
+    step.sync_to_model()
+    w = next(iter(model.parameters())).data
+    _require(not w.is_deleted() and bool(np.isfinite(
+        np.asarray(w[:1], np.float32)).all()),
+        "sync_to_model() rebinds the model to live, finite weights")
+    cache_after = _cache_report("after")
+    return {"device": dev, "layout": degrees, "losses": losses,
+            "chunk_wall_s": walls, "compile_s": row["compile_seconds"],
+            "pallas_kernels": kernels, "bytes_in_use": in_use,
+            "peak_bytes_in_use": peaks,
+            "cache_entries": [cache_before, cache_after]}
+
+
+# --------------------------------------------------------------------------
+# serve leg
+# --------------------------------------------------------------------------
+
+def _paged_parity(size: dict):
+    """`ragged_paged_attention(impl="pallas")` against `impl="scan"` on this
+    device, bf16, ragged seq_lens, block_len 16, query widths 1 and 16.
+    The two run the same per-block op sequence on the same bf16 inputs, so
+    the tolerance is tighter than flash-vs-reference: what remains is the
+    order of the fp32 accumulation inside one MXU dot vs one XLA einsum
+    and one bf16 rounding of the output (|o| <~ 4 -> 8e-3). 2e-2."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+    g = size["paged"]
+    H, Hkv, D, L = g["heads"], g["kv_heads"], g["head_dim"], g["cache_len"]
+    N, bl = 4, 16
+    nb = L // bl
+    rng = np.random.RandomState(1)
+    k = jnp.asarray(rng.randn(N, Hkv, L, D), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(N, Hkv, L, D), jnp.bfloat16)
+    # each row's pages live in another row's slab: a real indirection
+    table = ((np.arange(N)[:, None] + 1) % N * nb
+             + np.arange(nb)[None, :]).astype(np.int32)
+    tol = 2e-2
+    for Tq in (1, 16):
+        q = jnp.asarray(rng.randn(N, H, Tq, D), jnp.bfloat16)
+        q_pos = np.array([0, L // 7, L // 2 + 3, L - Tq], np.int32)
+        lens = q_pos + Tq
+        outs = {impl: ragged_paged_attention(
+            q, k, v, table, lens, q_pos, block_len=bl, pages_per_row=nb,
+            impl=impl) for impl in ("pallas", "scan")}
+        err = _max_err(outs["pallas"], outs["scan"])
+        _say(f"paged pallas vs scan H={H} Hkv={Hkv} D={D} block_len={bl} "
+             f"Tq={Tq} seq_lens={lens.tolist()} bf16: max abs err "
+             f"{err:.2e} (tolerance {tol:g})")
+        _require(np.isfinite(err) and err <= tol,
+                 f"paged Tq={Tq} within {tol:g}")
+
+
+def _post(port: int, path: str, payload: dict, timeout: float):
+    import urllib.request
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}", data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return r.status, json.loads(r.read())
+
+
+def leg_serve(size: dict, rehearsal: bool) -> dict:
+    dev, cache_before = _start_leg(rehearsal)
+
+    import urllib.request
+
+    import jax
+    import numpy as np
+
+    _say("[serve] kernel parity on this device")
+    _paged_parity(size)
+
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models.generation import generate
+    from paddle_tpu.models.gpt import GPTForCausalLM
+    from paddle_tpu.obs.compile_observatory import compile_observatory
+    from paddle_tpu.obs.goodput import RecompileSentinel
+
+    paddle.seed(0)
+    model = GPTForCausalLM.from_preset(size["preset"])
+    model.to(dtype="bfloat16")
+    model.eval()
+    max_new = size["max_new"]
+    cfg = serving.LLMEngineConfig(
+        num_slots=size["slots"], block_len=16, n_blocks=size["n_blocks"],
+        prefill_chunk=16, max_new_tokens=max_new, observatory=True)
+    _say(f"[serve] {size['preset']} bf16 behind LLMEngine + ServingServer: "
+         f"{cfg.num_slots} slots x {cfg.n_blocks} pages x {cfg.block_len} "
+         f"tokens, prefill_chunk {cfg.prefill_chunk}")
+    sentinel = RecompileSentinel().install()
+    engine = serving.LLMEngine(model, cfg)
+    # the first request compiles the 1.3 B step and the 1,500-token prompt
+    # takes ~94 chunked steps: give a request the leg's whole budget, not
+    # the front end's 60 s default
+    server = serving.ServingServer(llm_engine=engine, port=0,
+                                   request_timeout_s=BUDGET_S).start()
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(1, model.config.vocab_size, (n,)).tolist()
+               for n in size["prompts"]]
+    replies = [None] * len(prompts)
+
+    def ask(i):
+        t0 = time.perf_counter()
+        status, body = _post(server.port, "/generate", {
+            "input_ids": prompts[i], "max_new_tokens": max_new,
+            "logprobs": True}, timeout=BUDGET_S)
+        replies[i] = (status, body, time.perf_counter() - t0)
+
+    try:
+        ask(0)
+        # everything the engine needs is compiled now: any later XLA
+        # compilation is a recompile
+        sentinel.mark_warm()
+        compile_observatory().mark_warm()
+        ask(1)
+        pair = [threading.Thread(target=ask, args=(i,)) for i in (2, 3)]
+        for t in pair:
+            t.start()
+        for t in pair:
+            t.join(timeout=BUDGET_S)
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{server.port}/metrics",
+                timeout=60) as r:
+            metrics_status, metrics_text = r.status, r.read().decode()
+        mixed_steps = engine.decode_iterations
+        prefill_only = engine.prefill_dispatches
+    finally:
+        server.stop()
+        sentinel.uninstall()
+
+    for i, rep in enumerate(replies):
+        _require(rep is not None, f"request {i} returned")
+        status, body, wall = rep
+        toks, lps = body.get("tokens", []), body.get("logprobs", [])
+        _say(f"[serve] request {i}: prompt {len(prompts[i])} tokens -> "
+             f"HTTP {status}, {len(toks)} tokens, ttft "
+             f"{body.get('ttft_ms')} ms, {wall:.2f}s wall")
+        _require(status == 200, f"request {i} answered 200")
+        _require(len(toks) == max_new, f"request {i} has {max_new} tokens")
+        _require(len(lps) == max_new and bool(np.all(np.isfinite(lps))),
+                 f"request {i} has {max_new} finite logprobs")
+    _require(metrics_status == 200 and "pdtpu_llm_" in metrics_text,
+             "/metrics scrapes with the pdtpu_llm_ families")
+    _say(f"[serve] steps carrying a decode row {mixed_steps}, prefill-only "
+         f"steps {prefill_only}")
+    _require(sentinel.recompiles == 0,
+             f"zero XLA compilations after the first request "
+             f"(sentinel saw {sentinel.recompiles})")
+    _require(compile_observatory().recompiles == 0,
+             "zero unified-step recompiles (compile observatory)")
+
+    row = _kernel_row("llm/unified_step")
+    kernels = row["pallas_kernels"] or {}
+    _say(f"[serve] compiled unified step: compile "
+         f"{row['compile_seconds']:.1f}s (AOT lower+compile), temp "
+         f"{row['temp_bytes']} B, args {row['argument_bytes']} B, Pallas "
+         f"kernels {kernels}")
+    _say(f"[serve] kernel trace paths: {_trace_paths()}")
+    if rehearsal:
+        _say("  kernel census not applicable on the CPU (impl=None is the "
+             "scan path there by design)")
+    else:
+        n_layers = model.config.num_hidden_layers
+        _require(kernels.get("paged_attention", 0) == n_layers,
+                 f"compiled unified step holds {n_layers} paged_attention "
+                 f"custom calls (got {kernels.get('paged_attention', 0)})")
+
+    # informational: the engine's greedy stream against one-shot generate().
+    # Bit-identity is pinned on the CPU in tests; on the MXU it depends on
+    # the block_len grouping (engine 16, generate() DEFAULT_KV_BLOCK 8).
+    p0 = np.asarray(prompts[0], np.int32)
+    one_shot = np.asarray(generate(model, p0[None, :],
+                                   max_new_tokens=max_new).data)[0, len(p0):]
+    eng_toks = np.asarray(replies[0][1]["tokens"])
+    agree = int(np.argmax(np.append(one_shot != eng_toks, True)))
+    _say(f"[serve] INFO engine greedy stream == one-shot generate(): "
+         f"{bool(agree == max_new)} (first {agree} of {max_new} tokens "
+         f"agree; not required)")
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    _say(f"[serve] INFO (one unrepeated run, not a metric): request wall "
+         f"seconds {[round(r[2], 2) for r in replies]} (the first includes "
+         f"compilation), peak_bytes_in_use per device {peaks}")
+    cache_after = _cache_report("after")
+    return {"device": dev, "request_wall_s": [r[2] for r in replies],
+            "ttft_ms": [r[1].get("ttft_ms") for r in replies],
+            "compile_s": row["compile_seconds"], "pallas_kernels": kernels,
+            "generate_agrees": bool(agree == max_new),
+            "peak_bytes_in_use": peaks,
+            "cache_entries": [cache_before, cache_after]}
+
+
+# --------------------------------------------------------------------------
+# parent: no jax, no paddle_tpu
+# --------------------------------------------------------------------------
+
+def _result_path(leg: str) -> str:
+    return os.path.join(OUT_DIR, f"chip_smoke_{leg}.json")
+
+
+def run_leg(leg: str, rehearsal: bool, layout: str):
+    size = TINY if rehearsal else FULL
+    t0 = time.perf_counter()
+    result = (leg_train(size, rehearsal, layout) if leg == "train"
+              else leg_serve(size, rehearsal))
+    result["leg"] = leg
+    result["wall_s"] = round(time.perf_counter() - t0, 1)
+    os.makedirs(OUT_DIR, exist_ok=True)
+    tmp = _result_path(leg) + ".tmp"
+    with open(tmp, "w") as f:
+        json.dump(result, f, indent=1)
+    os.replace(tmp, _result_path(leg))
+    _say(f"[{leg}] leg passed in {result['wall_s']}s")
+
+
+def _run_child(leg: str, rehearsal: bool, layout: str,
+               deadline: float) -> dict:
+    try:
+        os.remove(_result_path(leg))
+    except FileNotFoundError:
+        pass
+    cmd = [sys.executable, os.path.abspath(__file__), "--leg", leg]
+    if rehearsal:
+        cmd.append("--cpu-rehearsal")
+    if layout:
+        cmd += ["--layout", layout]
+    proc = subprocess.Popen(cmd, cwd=REPO, start_new_session=True)
+
+    def _kill(*_):
+        if proc.poll() is None:
+            os.killpg(proc.pid, signal.SIGKILL)
+            proc.wait()
+
+    prev = signal.signal(signal.SIGTERM,
+                         lambda *_: (_kill(), sys.exit(143)))
+    try:
+        rc = proc.wait(timeout=max(deadline - time.monotonic(), 1.0))
+    except subprocess.TimeoutExpired:
+        raise SystemExit(f"chip_smoke: {leg} leg overran the "
+                         f"{BUDGET_S:.0f}s budget; killed")
+    finally:
+        _kill()
+        signal.signal(signal.SIGTERM, prev)
+    if rc != 0:
+        raise SystemExit(f"chip_smoke: {leg} leg failed (exit code {rc})")
+    with open(_result_path(leg)) as f:
+        return json.load(f)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--leg", choices=LEGS,
+                    help="run one leg in this process (what the parent "
+                         "starts; also handy for debugging one half)")
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="gpt2-tiny on the CPU: control flow only, prints "
+                         "platform=cpu, never prints the ok line")
+    ap.add_argument("--layout", default="",
+                    help='train-leg mesh layout on a multi-chip host, e.g. '
+                         '"dp=2,mp=2" (default: dp x sharding=2, ZeRO-2, '
+                         'over every local device)')
+    args = ap.parse_args(argv)
+    if args.leg:
+        run_leg(args.leg, args.cpu_rehearsal, args.layout)
+        return 0
+
+    deadline = time.monotonic() + BUDGET_S
+    t0 = time.monotonic()
+    results = [_run_child(leg, args.cpu_rehearsal, args.layout, deadline)
+               for leg in LEGS]
+    devices = [r["device"] for r in results]
+    if devices[0] != devices[1]:
+        raise SystemExit(f"chip_smoke: legs saw different devices: "
+                         f"{devices}")
+    for r in results:
+        _say(f"{r['leg']} leg: {r['wall_s']}s, step compile "
+             f"{r['compile_s']:.1f}s, compile cache entries "
+             f"{r['cache_entries'][0]} -> {r['cache_entries'][1]}")
+    _say(f"chip_smoke: both legs passed in {time.monotonic() - t0:.0f}s")
+    if args.cpu_rehearsal:
+        _say(json.dumps({"rehearsal": True, "device": devices[0]}))
+    else:
+        _say(json.dumps({"ok": True, "device": devices[0]}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -35,11 +35,14 @@ import numpy as np
 from .core.tensor import Tensor
 from .utils import fault_injection
 
-try:
+
+def _ocp():
+    """orbax.checkpoint, imported on first use: it pulls in
+    google.cloud.logging (~2.5 s of a 6 s package import), which every
+    process that merely imports paddle_tpu — a launcher, a worker, each leg
+    of chip_smoke.py — would otherwise pay."""
     import orbax.checkpoint as ocp
-    _HAS_ORBAX = True
-except Exception:  # pragma: no cover
-    _HAS_ORBAX = False
+    return ocp
 
 
 def _to_arrays(tree):
@@ -136,12 +139,11 @@ class CheckpointManager:
         self.directory = os.path.abspath(directory)
         os.makedirs(self.directory, exist_ok=True)
         self._max_to_keep = max_to_keep
-        use_orbax = use_orbax and _HAS_ORBAX
         self._async = async_save and use_orbax
         if use_orbax:
-            opts = ocp.CheckpointManagerOptions(
+            opts = _ocp().CheckpointManagerOptions(
                 max_to_keep=max_to_keep, enable_async_checkpointing=self._async)
-            self._mgr = ocp.CheckpointManager(self.directory, options=opts)
+            self._mgr = _ocp().CheckpointManager(self.directory, options=opts)
         else:
             self._mgr = None
 
@@ -161,7 +163,7 @@ class CheckpointManager:
         orbax path in a `step_<s>.cursor.json` sidecar."""
         state = _to_arrays(state)
         if self._mgr is not None:
-            self._mgr.save(step, args=ocp.args.StandardSave(state),
+            self._mgr.save(step, args=_ocp().args.StandardSave(state),
                            force=force)
             if cursor is not None:
                 side = os.path.join(self.directory,
@@ -241,7 +243,8 @@ class CheckpointManager:
                 return None
             if template is not None:
                 return self._mgr.restore(
-                    step, args=ocp.args.StandardRestore(_to_arrays(template)))
+                    step, args=_ocp().args.StandardRestore(
+                        _to_arrays(template)))
             return self._mgr.restore(step)
         from .framework_io import load as _load
         if step is not None:
@@ -833,8 +836,8 @@ def save_sharded(state: Dict[str, Any], path: str, shard_id: int = 0,
     certifies a complete shard set: load_sharded refuses anything less,
     because a shard may be the only copy of its slice of optimizer state
     (the ROADMAP's ZeRO-style sharded update)."""
-    if _HAS_ORBAX and use_orbax:
-        ckptr = ocp.StandardCheckpointer()
+    if use_orbax:
+        ckptr = _ocp().StandardCheckpointer()
         ckptr.save(os.path.abspath(path), _to_arrays(state), force=True)
         ckptr.wait_until_finished()
         return
@@ -871,8 +874,8 @@ def load_sharded(path: str, template: Optional[Dict[str, Any]] = None,
     optimizer state are silent corruption, not resilience. `shard_id`
     picks the shard to load (required when num_shards > 1); `template`
     applies to the orbax path only."""
-    if _HAS_ORBAX and use_orbax:
-        ckptr = ocp.StandardCheckpointer()
+    if use_orbax:
+        ckptr = _ocp().StandardCheckpointer()
         if template is not None:
             return ckptr.restore(os.path.abspath(path), _to_arrays(template))
         return ckptr.restore(os.path.abspath(path))

@@ -6,19 +6,57 @@ watch_local_trainers restart/abort) + elastic.py:90 (etcd membership watch).
 
 TPU-native: the unit is one process per HOST (jax owns all local chips), so on a
 single host the launcher mostly execs the script directly; multi-host mode wires
-PADDLE_TRAINER_ENDPOINTS → jax.distributed coordinator. `--nproc_per_node` is
-still honored for CPU-mesh testing (reference TestDistBase pattern). A watch
-loop restarts failed ranks up to --max_restarts (elastic.py behavior without the
-etcd dependency; state comes back via checkpoint auto-resume).
+PADDLE_TRAINER_ENDPOINTS → jax.distributed coordinator. A chip belongs to one
+process at a time, so:
+
+- this launcher never touches a JAX backend (importing paddle_tpu initialises
+  none; tests/test_chip_bringup.py pins that) — a parent that held the chips
+  would leave its workers to fail or hang;
+- `--nproc_per_node N>1` is for CPU-mesh testing (`JAX_PLATFORMS=cpu`, the
+  reference TestDistBase pattern). On a host that exposes TPU chips it is
+  refused: every worker would claim all local chips. One process drives them
+  all; the mesh inside it is the unit of parallelism;
+- workers inherit the compile-cache placement (utils/compile_cache.py).
+
+A watch loop restarts failed ranks up to --max_restarts (elastic.py behavior
+without the etcd dependency; state comes back via checkpoint auto-resume).
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
 import sys
 import time
+
+from ..utils import compile_cache
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from the device nodes libtpu
+    opens — without JAX, which would claim them."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def require_one_process_per_chip_host(nprocs: int, env=None):
+    """Refuse `nprocs > 1` local workers where each would come up on the
+    TPU and claim every local chip. CPU-pinned workers are fine."""
+    if nprocs <= 1:
+        return
+    env = os.environ if env is None else env
+    if env.get("JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu":
+        return
+    chips = local_tpu_chips()
+    if chips:
+        raise SystemExit(
+            f"[launch] refusing {nprocs} worker processes on a host with "
+            f"{chips} TPU chip(s): a chip belongs to one process and each "
+            "worker would claim all of them. One process drives all local "
+            "chips (build the mesh over jax.devices()); use "
+            "--nproc_per_node 1, or JAX_PLATFORMS=cpu for a CPU-mesh test")
 
 
 class Pod:
@@ -34,6 +72,7 @@ class Pod:
 
     def start(self):
         env = dict(os.environ)
+        env.update(compile_cache.child_env())
         env.update(self.env)
         env["PADDLE_TRAINER_ID"] = str(self.rank)
         env["PADDLE_TRAINERS_NUM"] = str(len(self.endpoints))
@@ -72,8 +111,9 @@ def parse_args(argv=None):
         prog="paddle_tpu.distributed.launch",
         description="launch distributed training (one process per host)")
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="processes on this host (CPU-mesh testing; on TPU "
-                        "keep 1 — jax drives all local chips)")
+                   help="processes on this host (CPU-mesh testing with "
+                        "JAX_PLATFORMS=cpu; refused above 1 on a host with "
+                        "TPU chips — one process drives all local chips)")
     p.add_argument("--hosts", type=str, default=None,
                    help="comma list host:port of all nodes; this host first "
                         "env-detected via PADDLE_TRAINER_ID")
@@ -90,8 +130,10 @@ def parse_args(argv=None):
     p.add_argument("--elastic_timeout", type=float, default=10.0,
                    help="heartbeat expiry (seconds) for membership")
     p.add_argument("--devices", type=str, default=None,
-                   help="accepted for reference-CLI parity; ignored (XLA "
-                        "owns device selection)")
+                   help="reference-CLI flag; refused — one process drives "
+                        "all local chips, so there is nothing to assign "
+                        "(restrict the chips a host process sees with "
+                        "libtpu's TPU_VISIBLE_CHIPS in its environment)")
     p.add_argument("training_script", type=str)
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
     return p.parse_args(argv)
@@ -228,6 +270,13 @@ def _elastic_host_loop(args, endpoints, rank, script_args):
 
 def launch(argv=None):
     args = parse_args(argv)
+    if args.devices is not None:
+        raise SystemExit(
+            "[launch] --devices is not supported: one process drives all "
+            "local chips. To hide chips from a host process set libtpu's "
+            "TPU_VISIBLE_CHIPS in its environment")
+    require_one_process_per_chip_host(
+        1 if args.hosts else args.nproc_per_node)
     endpoints = get_cluster(args)
     script_args = list(args.training_script_args)
     if script_args and script_args[0] == "--":

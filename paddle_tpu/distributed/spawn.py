@@ -1,13 +1,18 @@
 """paddle.distributed.spawn analog (reference: distributed/spawn.py).
 
 On TPU the normal model is one process per host (jax handles all local chips), so
-spawn is mainly used by CPU-mesh tests; it forks `nprocs` processes with the
-reference's PADDLE_* env contract.
+spawn is for CPU-mesh tests; it starts `nprocs` fresh interpreters with the
+reference's PADDLE_* env contract. A chip belongs to one process at a time, so
+spawn refuses what cannot work there: more than one worker on a host that
+exposes TPU chips (each would claim them all), and any worker at all once this
+process has itself brought up an accelerator backend (it holds the chips).
 """
 from __future__ import annotations
 
 import multiprocessing as mp
 import os
+
+from .launch import require_one_process_per_chip_host
 
 
 def _wrapper(func, rank, nprocs, base_port, args):
@@ -19,7 +24,22 @@ def _wrapper(func, rank, nprocs, base_port, args):
     func(*args)
 
 
+def _parent_holds_accelerator() -> bool:
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+    return jax.default_backend() != "cpu"
+
+
 def spawn(func, args=(), nprocs=1, join=True, daemon=False, **options):
+    require_one_process_per_chip_host(nprocs)
+    if _parent_holds_accelerator():
+        raise RuntimeError(
+            "spawn: this process has already initialised an accelerator "
+            "backend and holds its chips; a spawned worker that needs them "
+            "would fail or hang. Spawn before touching JAX, or drive all "
+            "local chips from this one process")
     base_port = int(options.get("started_port", 35000))
     ctx = mp.get_context("spawn")
     procs = []

@@ -260,12 +260,24 @@ def build_mesh_from_dims(dims: Dict[str, int], devices=None) -> Mesh:
     slices the default device order already follows the physical torus; the
     innermost axis (model) gets the fastest-varying devices → TP collectives ride
     the shortest ICI hops.
+
+    With `devices=None` the topology must use every accelerator chip the
+    process holds: a smaller one would quietly run on the first chips and
+    leave the rest idle (the process owns them all either way). To use a
+    subset on purpose, pass it as `devices=`. Virtual CPU devices cost
+    nothing idle, so CPU meshes may still be smaller than the host's count.
     """
     devs = list(devices) if devices is not None else jax.devices()
     total = reduce(lambda a, b: a * b, dims.values(), 1)
     if total > len(devs):
         raise ValueError(
             f"topology {dims} needs {total} devices, have {len(devs)}")
+    if devices is None and total < len(devs) and devs[0].platform != "cpu":
+        raise ValueError(
+            f"topology {dims} uses {total} of this process's {len(devs)} "
+            f"{devs[0].platform} chips and would leave the rest idle; size "
+            "hybrid_configs to the device count (dp_degree=-1 fills it) or "
+            "pass the subset explicitly as devices=")
     arr = np.array(devs[:total]).reshape(tuple(dims.values()))
     return Mesh(arr, tuple(dims.keys()))
 

@@ -2,8 +2,10 @@
 
 Reference analog: framework/data_feed.cc driving trainer threads; here the
 native reader keeps a prefetch ring of length-prefixed records ahead of the
-host loop (which is ahead of jax dispatch). Builds the .so on first use via the
-Makefile (g++ is part of the baked toolchain)."""
+host loop (which is ahead of jax dispatch). The first use in a process runs
+the Makefile (g++ is part of the baked toolchain), which builds the .so when
+it is absent or older than its source — a stale binary left in the tree is
+never loaded as is."""
 from __future__ import annotations
 
 import ctypes
@@ -25,9 +27,8 @@ def _load_lib():
     global _LIB
     if _LIB is not None:
         return _LIB
-    if not os.path.exists(_LIB_PATH):
-        subprocess.run(["make", "-C", _SRC_DIR], check=True,
-                       capture_output=True)
+    subprocess.run(["make", "-C", _SRC_DIR], check=True,
+                   capture_output=True)
     lib = ctypes.CDLL(_LIB_PATH)
     lib.datafeed_create.restype = ctypes.c_void_p
     lib.datafeed_create.argtypes = [

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import hashlib
 import logging
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -148,15 +149,40 @@ def diff_signatures(old: Tuple[tuple, ...],
 
 # ---- AOT analysis ----
 
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_WORD = re.compile(r"[\w.\-]+")
+
+
+def pallas_kernel_census(hlo_text: str) -> Dict[str, int]:
+    """{kernel name: count} over the Mosaic custom calls of compiled HLO
+    text. The name is the `name=` its pallas_call was given: the op_name
+    scope right before `/pallas_call`, inside any `jvp(...)`/
+    `transpose(...)` wrapper. Empty on the CPU, where kernels
+    run interpreted and leave no custom call — which is how a caller
+    tells a step that contains the kernel from one that took an XLA
+    stand-in."""
+    census: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _OP_NAME.search(line)
+        scopes = m.group(1).split("/") if m else []
+        words = (_WORD.findall(scopes[-2]) if len(scopes) >= 2
+                 and scopes[-1] == "pallas_call" else [])
+        name = words[-1] if words else "unnamed"
+        census[name] = census.get(name, 0) + 1
+    return census
+
+
 def _aot_analyses(fn, args) -> Tuple[float, dict]:
     """lower()+compile() `fn` for `args` and pull cost/memory analyses.
     Returns (compile_seconds, analyses-dict); tolerant of callables
     without an AOT path (plain predictors) and of backends whose
     analyses are unavailable — missing numbers stay None, never raise."""
-    out: Dict[str, Optional[float]] = {
+    out: Dict[str, Any] = {
         "flops": None, "bytes_accessed": None, "temp_bytes": None,
         "argument_bytes": None, "output_bytes": None,
-        "generated_code_bytes": None,
+        "generated_code_bytes": None, "pallas_kernels": None,
     }
     lower = getattr(fn, "lower", None)
     if lower is None:
@@ -189,6 +215,10 @@ def _aot_analyses(fn, args) -> Tuple[float, dict]:
                 mem.generated_code_size_in_bytes)
     except Exception:
         _log.debug("memory_analysis unavailable", exc_info=True)
+    try:
+        out["pallas_kernels"] = pallas_kernel_census(compiled.as_text())
+    except Exception:
+        _log.debug("compiled text unavailable", exc_info=True)
     return seconds, out
 
 
@@ -198,8 +228,8 @@ class ExecutableRecord:
 
     __slots__ = ("callsite", "fingerprint", "signature", "compile_seconds",
                  "flops", "bytes_accessed", "temp_bytes", "argument_bytes",
-                 "output_bytes", "generated_code_bytes", "dispatches",
-                 "device_seconds", "built_seq")
+                 "output_bytes", "generated_code_bytes", "pallas_kernels",
+                 "dispatches", "device_seconds", "built_seq")
 
     def __init__(self, callsite: str, fingerprint: str,
                  signature: Tuple[tuple, ...], compile_seconds: float,
@@ -214,6 +244,7 @@ class ExecutableRecord:
         self.argument_bytes = analyses.get("argument_bytes")
         self.output_bytes = analyses.get("output_bytes")
         self.generated_code_bytes = analyses.get("generated_code_bytes")
+        self.pallas_kernels = analyses.get("pallas_kernels")
         self.dispatches = 0
         self.device_seconds = 0.0
         self.built_seq = built_seq
@@ -229,6 +260,7 @@ class ExecutableRecord:
             "argument_bytes": self.argument_bytes,
             "output_bytes": self.output_bytes,
             "generated_code_bytes": self.generated_code_bytes,
+            "pallas_kernels": self.pallas_kernels,
             "dispatches": self.dispatches,
             "device_seconds": round(self.device_seconds, 6),
             "built_seq": self.built_seq,

@@ -12,7 +12,12 @@ counts; nothing here imports jax.
 """
 from __future__ import annotations
 
-# per-chip peak bf16 FLOP/s by device_kind substring (longest match wins)
+# per-chip peak bf16 FLOP/s by device_kind substring (longest match wins).
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+# at 819 GB/s; "TPU v5 lite" is the device_kind libtpu 0.0.34 reports for
+# that chip (chip_smoke.py fails unless the chip it runs on is a key here).
+# The other rows are the same documentation's per-generation peaks; only the
+# v5e row has been run against.
 PEAK_BF16 = {
     "v5 lite": 197e12,
     "v5litepod": 197e12,
@@ -29,19 +34,21 @@ PEAK_BF16 = {
 # the arithmetic defined without pretending to know the host's peak
 CPU_NOMINAL_FLOPS = 1e12
 
-# unknown TPU: assume the smallest current chip rather than refusing
-_UNKNOWN_TPU_FLOPS = 197e12
-
 
 def peak_flops(device_kind: str, backend: str) -> float:
-    """Per-chip peak bf16 FLOP/s for a jax device_kind/backend pair."""
+    """Per-chip peak bf16 FLOP/s for a jax device_kind/backend pair. An
+    accelerator whose device_kind is not in PEAK_BF16 is an error, not a
+    default: a utilization against a guessed peak is not a measurement."""
     if backend == "cpu":
         return CPU_NOMINAL_FLOPS
     kind = (device_kind or "").lower()
     for key in sorted(PEAK_BF16, key=len, reverse=True):
         if key in kind:
             return PEAK_BF16[key]
-    return _UNKNOWN_TPU_FLOPS
+    raise ValueError(
+        f"device_kind {device_kind!r} (backend {backend!r}) is not in "
+        "paddle_tpu.obs.flops.PEAK_BF16; add its published peak with its "
+        "source before reporting a utilization on it")
 
 
 def train_flops_per_step(n_params: int, tokens_per_step: int,

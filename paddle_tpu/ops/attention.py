@@ -32,10 +32,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# block sizes are sweepable via env (bench tuning: FLAGS_flash_block_q/k),
-# resolved per call inside flash_attention; 256x256 is the only block config
-# that has completed a run on the real v5e (BENCH_SWEEP: 512-block configs
-# crashed rc=1 / hung on-chip) — keep the default at what hardware has proven
+from . import pallas_mode
+
+# 256x256 is the block config chip_smoke.py compiles and checks against the
+# reference on a v5e; other sizes (FLAGS_flash_block_q/k, resolved per call
+# inside flash_attention) have not been run on this kernel
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 
@@ -110,6 +111,79 @@ def _sequence_parallel_island(q, k, v, causal, scale, impl="ring"):
     island = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                            out_specs=spec, check_vma=False)
     return island(q, k, v)
+
+
+# trace-time flag: the SPMD step sets this while it traces the model under
+# GSPMD over a mesh of more than one device. A Mosaic kernel has no
+# partitioning rule — JAX refuses to lower a pallas_call whose operands are
+# sharded ("Mosaic kernels cannot be automatically partitioned") — so with it
+# set the flash kernels run inside a shard_map island over the batch and head
+# axes, where each device sees its local [B/dp, H/mp, S, D] block and
+# attention needs no communication.
+_SPMD = _threading.local()
+
+
+class spmd_mesh:
+    """Context manager marking the enclosed trace as GSPMD-partitioned over
+    `mesh`, batch dim sharded over `batch_axes` (a name, a tuple, or None)
+    and heads over `model` where that axis is real."""
+
+    def __init__(self, mesh, batch_axes):
+        self._mesh = mesh
+        self._batch_axes = batch_axes
+
+    def __enter__(self):
+        self._prev = (getattr(_SPMD, "mesh", None),
+                      getattr(_SPMD, "batch_axes", None))
+        _SPMD.mesh, _SPMD.batch_axes = self._mesh, self._batch_axes
+        return self
+
+    def __exit__(self, *exc):
+        _SPMD.mesh, _SPMD.batch_axes = self._prev
+        return False
+
+
+def _flash_spmd_island(q, k, v, mask, seed, causal, scale, block_q, block_k,
+                       dropout_p):
+    """`_flash_attention` on each device's local batch/head block. A dim
+    whose size the axis does not divide stays unsharded in the island (its
+    operand is gathered and the work repeated) rather than failing."""
+    from jax.sharding import PartitionSpec as P
+    mesh = _SPMD.mesh
+    B, H = q.shape[0], q.shape[1]
+
+    def names(axes):
+        return axes if isinstance(axes, tuple) else (axes,) if axes else ()
+
+    def fits(axes, size):
+        n = math.prod(mesh.shape[a] for a in names(axes))
+        return axes if n > 1 and size % n == 0 else None
+
+    b_ax = fits(_SPMD.batch_axes, B)
+    h_ax = fits("model" if "model" in mesh.axis_names else None, H)
+    spec = P(b_ax, h_ax, None, None)
+    operands, in_specs = [q, k, v], [spec, spec, spec]
+    if mask is not None:
+        m4 = mask if mask.ndim == 4 else mask[:, None]
+        operands.append(m4)
+        in_specs.append(P(b_ax if m4.shape[0] == B else None,
+                          h_ax if m4.shape[1] == H else None, None, None))
+    sharded = names(b_ax) + names(h_ax)
+
+    def body(seed_, ql, kl, vl, *ml):
+        if dropout_p > 0.0 and sharded:
+            # the kernels seed per LOCAL (bh, q block, k block): without
+            # this every shard would drop the same positions
+            seed_ = seed_ + jax.lax.axis_index(sharded).astype(jnp.int32) * (
+                ql.shape[0] * ql.shape[1] * 4099)
+        return _flash_attention(ql, kl, vl, ml[0] if ml else None, seed_,
+                                causal, scale, block_q, block_k, dropout_p)
+
+    island = jax.shard_map(body, mesh=mesh, in_specs=(P(), *in_specs),
+                           out_specs=spec, check_vma=False)
+    return island(seed, *operands)
+
+
 _NEG_INF = -1e30
 
 
@@ -147,6 +221,18 @@ def _attention_reference(q, k, v, causal, scale, mask=None, dropout_p=0.0,
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _dot(a, b, a_dim, b_dim):
+    """In-kernel MXU dot contracting a[a_dim] with b[b_dim], fp32 accumulate.
+    Sub-fp32 operands (bf16 on TPU: full MXU rate) name the one-pass
+    precision themselves: the package-wide "highest" default
+    (paddle_tpu/__init__.py) reaches in-kernel dots too, and Mosaic rejects
+    it for bf16 operands ("Bad lhs type")."""
+    prec = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(a, b, (((a_dim,), (b_dim,)), ((), ())),
+                               precision=prec,
+                               preferred_element_type=jnp.float32)
 
 
 def _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, shape, dropout_p):
@@ -207,8 +293,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
         q = q_ref[0]
         kblk = k_ref[0]
         vblk = v_ref[0]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, kblk, 1, 1) * scale
         s = _apply_mask_block(s, mask_ref, causal, block_q, block_k, q_start,
                               k_start, causal_offset)
         m_prev = m_ref[...]
@@ -226,9 +311,8 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
             keep = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, p.shape,
                                dropout_p)
             p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(vblk.dtype),
+                                                   vblk, 1, 0)
         m_ref[...] = m_new
 
     @pl.when(kb == num_kb - 1)
@@ -274,23 +358,19 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
         kblk = k_ref[0]
         vblk = v_ref[0]
         g = g_ref[0]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, kblk, 1, 1) * scale
         s = _apply_mask_block(s, mask_ref, causal, block_q, block_k, q_start,
                               k_start, causal_offset)
         lse_col = lse_ref[0]
         delta_col = delta_ref[0]
         p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - lse_col))
-        dp = jax.lax.dot_general(g, vblk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(g, vblk, 1, 1)
         if dropout_p > 0.0:
             keep = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, p.shape,
                                dropout_p)
             dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
         ds = p * (dp - delta_col) * scale
-        acc_ref[...] += jax.lax.dot_general(
-            ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] += _dot(ds.astype(kblk.dtype), kblk, 1, 0)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -329,16 +409,14 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
         kblk = k_ref[0]
         vblk = v_ref[0]
         g = g_ref[0]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, kblk, 1, 1) * scale
         s = _apply_mask_block(s, mask_ref, causal, block_q, block_k, q_start,
                               k_start, causal_offset)
         lse_col = lse_ref[0]
         delta_col = delta_ref[0]
         p = jnp.where(s <= _NEG_INF / 2, 0.0,
                       jnp.exp(s - lse_col))  # [bq, bk]
-        dp = jax.lax.dot_general(g, vblk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(g, vblk, 1, 1)
         if dropout_p > 0.0:
             keep = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, p.shape,
                                dropout_p)
@@ -349,12 +427,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
             p_drop = p
         ds = p * (dp - delta_col) * scale
         # dv += p_drop^T @ g ; dk += ds^T @ q
-        dv_acc[...] += jax.lax.dot_general(
-            p_drop.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_acc[...] += _dot(p_drop.astype(g.dtype), g, 0, 0)
+        dk_acc[...] += _dot(ds.astype(q.dtype), q, 0, 0)
 
     @pl.when(qi == num_qb - 1)
     def _finalize():
@@ -378,6 +452,12 @@ def _mask_3d(mask, B, H, Sq, Sk):
         return mask.reshape(B, Sq, Sk), H
     flat = jnp.broadcast_to(mask, (B, H, Sq, Sk)).reshape(B * H, Sq, Sk)
     return flat, 1
+
+
+# every flash grid is (batch*heads, outer blocks, inner blocks): the scratch
+# accumulators carry across the innermost axis only
+_GRID_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k, dropout_p,
@@ -429,7 +509,9 @@ def _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k, dropout_p,
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
         ],
-        interpret=(jax.default_backend() == "cpu"),
+        compiler_params=_GRID_SEMANTICS,
+        interpret=pallas_mode.interpret("flash_fwd"),
+        name="flash_fwd",
     )(*operands)
     return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
@@ -453,7 +535,6 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
     lser = lse.reshape(B * H, Sq, 1)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
                     keepdims=True).reshape(B * H, Sq, 1)
-    interp = jax.default_backend() == "cpu"
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
                   causal_offset=Sk - Sq, has_mask=mask is not None,
                   dropout_p=dropout_p, n_qb=n_qb, n_kb=n_kb)
@@ -483,7 +564,9 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interp,
+        compiler_params=_GRID_SEMANTICS,
+        interpret=pallas_mode.interpret("flash_bwd_dq"),
+        name="flash_bwd_dq",
     )(*operands)
 
     # dkv grid: (bh, k_blocks, q_blocks) — q innermost, accumulators per k blk
@@ -521,7 +604,9 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        interpret=interp,
+        compiler_params=_GRID_SEMANTICS,
+        interpret=pallas_mode.interpret("flash_bwd_dkv"),
+        name="flash_bwd_dkv",
     )(*operands_kv)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
             dv.reshape(B, H, Sk, D))
@@ -625,9 +710,11 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     Returns [B, H, Sq, D]. Supports rectangular (cross) attention: causal uses
     bottom-right alignment when Sq != Sk.
 
-    Uses the Pallas kernels (fwd + dq/dkv bwd) on TPU for seqs >= 512; falls
-    back to the fused XLA reference for short sequences and CPU. Dropout on
-    the Pallas path uses the in-kernel TPU PRNG (TPU only).
+    Uses the Pallas kernels (fwd + dq/dkv bwd) on TPU for seqs >= 512 and
+    the fused XLA reference for short sequences, indivisible lengths and the
+    CPU; every reference return is counted, and on a TPU logged once per
+    shape with its reason (`ops.pallas_mode`). Dropout on the Pallas path
+    uses the in-kernel TPU PRNG (TPU only).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -638,6 +725,10 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     if block_k is None:
         block_k = int(os.environ.get("FLAGS_flash_block_k",
                                      str(DEFAULT_BLOCK_K)))
+    on_cpu = pallas_mode.platform() == "cpu"
+    Sq, Sk = q.shape[2], k.shape[2]
+    # why this call takes the XLA reference instead of the kernel, if it does
+    reason = None
     if sequence_sharded_trace() and not force_pallas:
         mesh = getattr(_SEQ_SHARDED, "mesh", None)
         # env var overrides the strategy-configured impl; "gspmd" means the
@@ -648,37 +739,39 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
         # cross-attention (Sq != Sk) keeps the GSPMD-sliced reference too
         if (mesh is not None and "sep" in mesh.axis_names
                 and mesh.shape["sep"] > 1 and mask is None
-                and dropout_p == 0.0 and q.shape[2] == k.shape[2]
-                and impl != "gspmd"):
+                and dropout_p == 0.0 and Sq == Sk and impl != "gspmd"):
             return _sequence_parallel_island(q, k, v, causal, scale, impl)
-        key = jax.random.PRNGKey(jnp.asarray(dropout_seed, jnp.uint32)) \
-            if dropout_p > 0.0 else None
-        return _attention_reference(q, k, v, causal, scale, mask, dropout_p,
-                                    key)
-    if os.environ.get("FLAGS_flash_attention", "1") == "0" \
+        reason = "sequence-sharded trace, no ring island"
+    elif os.environ.get("FLAGS_flash_attention", "1") == "0" \
             and not force_pallas:
+        reason = "FLAGS_flash_attention=0"
+    elif Sq % min(block_q, Sq) or Sk % min(block_k, Sk):
+        reason = f"sequence not divisible by block {block_q}x{block_k}"
+    elif dropout_p > 0.0 and on_cpu:
+        reason = "in-kernel dropout needs the TPU PRNG"
+    elif dropout_p > 0.0 and mask is not None:
+        # the keep-mask lives in the TPU PRNG and cannot be recomputed in
+        # XLA for d(mask), so a differentiable mask would silently get zero
+        # grads — route the combination to the reference path
+        reason = "additive mask + dropout"
+    elif not force_pallas and on_cpu:
+        reason = "cpu"
+    elif not force_pallas and Sq < 512:
+        reason = "query shorter than 512"
+    if reason is not None:
+        pallas_mode.note_reference("flash_attention", reason, q.shape,
+                                   k.shape, str(q.dtype))
         key = jax.random.PRNGKey(jnp.asarray(dropout_seed, jnp.uint32)) \
             if dropout_p > 0.0 else None
         return _attention_reference(q, k, v, causal, scale, mask, dropout_p,
                                     key)
-    on_tpu = jax.default_backend() not in ("cpu",)
-    long_seq = q.shape[2] >= 512
-    Sq, Sk = q.shape[2], k.shape[2]
-    divisible = (Sq % min(block_q, Sq) == 0 and Sk % min(block_k, Sk) == 0)
-    dropout_needs_tpu = dropout_p > 0.0 and jax.default_backend() == "cpu"
-    # mask + dropout: the keep-mask lives in the TPU PRNG and cannot be
-    # recomputed in XLA for d(mask), so a differentiable mask would silently
-    # get zero grads — route the combination to the reference path
-    mask_and_dropout = dropout_p > 0.0 and mask is not None
-    eligible = divisible and not dropout_needs_tpu and not mask_and_dropout
-    if not eligible or (not force_pallas and not (on_tpu and long_seq)):
-        key = jax.random.PRNGKey(jnp.asarray(dropout_seed, jnp.uint32)) \
-            if dropout_p > 0.0 else None
-        return _attention_reference(q, k, v, causal, scale, mask, dropout_p,
-                                    key)
-    return _flash_attention(q, k, v, mask,
-                            jnp.asarray(dropout_seed, jnp.int32), causal,
-                            scale, block_q, block_k, dropout_p)
+    seed = jnp.asarray(dropout_seed, jnp.int32)
+    mesh = getattr(_SPMD, "mesh", None)
+    if mesh is not None and mesh.size > 1:
+        return _flash_spmd_island(q, k, v, mask, seed, causal, scale,
+                                  block_q, block_k, dropout_p)
+    return _flash_attention(q, k, v, mask, seed, causal, scale, block_q,
+                            block_k, dropout_p)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

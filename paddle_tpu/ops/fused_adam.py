@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_mode
+
 _LANES = 128
 
 
@@ -75,7 +77,7 @@ def fused_adam(p, g, m1, m2, lr, b1p, b2p, wd, *, beta1, beta2, epsilon,
     # and would force the sharded param/moments to replicate.
     flag = os.environ.get("FLAGS_use_fused_adam", "0")
     use_pallas = (force_pallas or (flag == "1"
-                                   and jax.default_backend() != "cpu"
+                                   and pallas_mode.platform() != "cpu"
                                    and jax.device_count() == 1)) and \
         eligible(n)
     lr = jnp.asarray(lr, jnp.float32)
@@ -126,7 +128,8 @@ def fused_adam(p, g, m1, m2, lr, b1p, b2p, wd, *, beta1, beta2, epsilon,
             jax.ShapeDtypeStruct((rows_main, _LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows_main, _LANES), jnp.float32),
         ],
-        interpret=(jax.default_backend() == "cpu"),
+        interpret=pallas_mode.interpret("fused_adam"),
+        name="fused_adam",
     )(scal, p2, g2, m12, m22)
 
     newp = newp.reshape(-1)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_mode
+
 _VMEM_BUDGET = 6 * 1024 * 1024  # conservative per-buffer working-set bound
 
 
@@ -71,7 +73,6 @@ def _bwd_kernel(x_ref, w_ref, mu_ref, rstd_ref, dy_ref,
 def _fused_fwd(x2d, w, b, eps):
     R, N = x2d.shape
     br = _block_rows(R, N)
-    interp = jax.default_backend() == "cpu"
     kernel = functools.partial(_fwd_kernel, eps=eps)
     y, mu, rstd = pl.pallas_call(
         kernel,
@@ -91,7 +92,8 @@ def _fused_fwd(x2d, w, b, eps):
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
-        interpret=interp,
+        interpret=pallas_mode.interpret("layernorm_fwd"),
+        name="layernorm_fwd",
     )(x2d, w.reshape(1, N), b.reshape(1, N))
     return y, mu, rstd
 
@@ -99,7 +101,6 @@ def _fused_fwd(x2d, w, b, eps):
 def _fused_bwd(x2d, w, mu, rstd, dy2d):
     R, N = x2d.shape
     br = _block_rows(R, N)
-    interp = jax.default_backend() == "cpu"
     dx, dw, db = pl.pallas_call(
         _bwd_kernel,
         grid=(R // br,),
@@ -120,7 +121,8 @@ def _fused_bwd(x2d, w, mu, rstd, dy2d):
             jax.ShapeDtypeStruct((1, N), jnp.float32),
             jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
-        interpret=interp,
+        interpret=pallas_mode.interpret("layernorm_bwd"),
+        name="layernorm_bwd",
     )(x2d, w.reshape(1, N), mu, rstd, dy2d)
     return dx, dw, db
 
@@ -173,7 +175,7 @@ def fused_layer_norm(x, weight, bias, eps=1e-5, force_pallas=False):
     # partitioning rule replicates its operands.
     import os
     flag = os.environ.get("FLAGS_use_fused_layernorm", "0")
-    on = force_pallas or (flag == "1" and jax.default_backend() != "cpu"
+    on = force_pallas or (flag == "1" and pallas_mode.platform() != "cpu"
                           and jax.device_count() == 1)
     if not on or not eligible(x.shape, 1, True, True):
         h = x.astype(jnp.float32)

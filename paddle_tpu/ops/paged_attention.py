@@ -24,14 +24,21 @@ Layout contract — shared with `SlotPagedKVPool`:
 
 Two implementations with the SAME per-block online-softmax op sequence:
 
-- `_scan_impl` — plain XLA `lax.scan` over logical blocks. The default on
-  CPU: interpret-mode Pallas unrolls every grid cell into the jaxpr, which
-  makes tier-1 compile times explode, while this path compiles once and
-  runs the identical arithmetic.
-- `_pallas_impl` — the TPU kernel: grid (B, H, n_blocks) with the block
-  table / lengths / positions scalar-prefetched so the index_map fetches
-  only the pages a row actually occupies, and `@pl.when` skips compute for
-  blocks past the row's length ("only over occupied KV blocks").
+- `_scan_impl` — plain XLA `lax.scan` over logical blocks. What `impl=None`
+  picks on the CPU: interpret-mode Pallas unrolls every grid cell into the
+  jaxpr, which makes tier-1 compile times explode, while this path compiles
+  once and runs the identical arithmetic. Also the parity reference the
+  kernel is checked against on the chip (chip_smoke.py).
+- `_pallas_impl` — the TPU kernel, what `impl=None` picks on a TPU: grid
+  (B, H, n_blocks) with the block table / lengths / positions
+  scalar-prefetched so the index_map fetches only the pages a row actually
+  occupies, and `@pl.when` skips compute for blocks past the row's length
+  ("only over occupied KV blocks"). Mosaic (jax 0.9.0 / libtpu 0.0.34, v5e)
+  lowers it at `block_len` 8, 16, 32 and 128, in bf16 and fp32, at query
+  widths 1 and 16 — including the 8-row bf16 KV tile (half a packed
+  sublane tile) of `DEFAULT_KV_BLOCK` and the 1-row q tile of the
+  `generate()` decode loop; tests/test_mosaic_aot.py pins 8 and 16 in
+  bf16, the two sizes the repo runs.
 
 Numerics: flash-style online softmax with the repo's exact-zero masking
 convention (ops/attention.py `_fwd_kernel`): masked scores sit at
@@ -52,21 +59,18 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .attention import _NEG_INF
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # Pallas import is deferred-tolerant, like ops/attention.py
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover - environment without pallas
-    pl = pltpu = None
-    _HAS_PALLAS = False
+from . import pallas_mode
+from .attention import _GRID_SEMANTICS, _NEG_INF, _dot
 
 # The kv block size the trivial (non-paged) decode path uses. Engine pools
 # that want streams bit-identical to one-shot generate() must use the SAME
 # block_len (flash accumulation grouping differs across block sizes; see
 # module docstring). 8 divides every cache length the tests use and keeps
-# the CPU scan short.
+# the CPU scan short; on a TPU the kernel lowers at 8 too (module
+# docstring), so the one-shot path keeps it there.
 DEFAULT_KV_BLOCK = 8
 
 
@@ -157,8 +161,7 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0, 0]                       # [Tq, D]
         kblk = k_ref[0, 0]                    # [KB, D] (head picked by map)
         vblk = v_ref[0, 0]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, kblk, 1, 1) * scale
         col = (j * block_len
                + jax.lax.broadcasted_iota(jnp.int32, (Tq, block_len), 1))
         row = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32,
@@ -171,9 +174,8 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(vblk.dtype),
+                                                   vblk, 1, 0)
         m_ref[...] = m_new
 
     @pl.when(j == n_blocks - 1)
@@ -183,7 +185,7 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _pallas_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
-                 block_len: int, scale: float, interpret: bool):
+                 block_len: int, scale: float):
     B, H, Tq, D = q.shape
     Hkv = k_pages.shape[1]
     n_rep = H // Hkv
@@ -219,7 +221,9 @@ def _pallas_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-        interpret=interpret,
+        compiler_params=_GRID_SEMANTICS,  # (B, H, kv blocks): same shape
+        interpret=pallas_mode.interpret("paged_attention"),
+        name="paged_attention",
     )(table, seq_lens.astype(jnp.int32), q_pos.astype(jnp.int32),
       q, k_pages, v_pages)
 
@@ -235,9 +239,9 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
     seq_lens [B], q_pos [B] — see module docstring for the mask contract.
     pages_per_row defaults to L_slab // block_len (pass the pool's
     n_blocks when the slab carries chunk write-padding).
-    impl: None = auto (scan on CPU, pallas elsewhere), or force "scan" /
-    "pallas" / "pallas_interpret" (the parity suite runs the real kernel
-    on CPU this way).
+    impl: None = scan on the CPU, the kernel on a TPU; or name "scan" /
+    "pallas" (on the CPU the kernel runs interpreted — the parity suite
+    does that; `ops.pallas_mode` decides and counts).
     """
     B, H, Tq, D = q.shape
     if scale is None:
@@ -245,20 +249,20 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
     if pages_per_row is None:
         pages_per_row = k_cache.shape[2] // block_len
     if impl is None:
-        impl = "scan" if jax.default_backend() == "cpu" else "pallas"
+        impl = "scan" if pallas_mode.platform() == "cpu" else "pallas"
+    if impl not in ("scan", "pallas"):
+        raise ValueError(f'impl must be "scan" or "pallas", got {impl!r}')
     block_table = jnp.asarray(block_table, jnp.int32)
     seq_lens = jnp.asarray(seq_lens, jnp.int32)
     q_pos = jnp.asarray(q_pos, jnp.int32)
     k_pages = _as_pages(k_cache, block_len, pages_per_row)
     v_pages = _as_pages(v_cache, block_len, pages_per_row)
     if impl == "scan":
+        pallas_mode.count("paged_attention", "scan")
         return _scan_impl(q, k_pages, v_pages, block_table, seq_lens,
                           q_pos, block_len, scale)
-    if not _HAS_PALLAS or pltpu is None:
-        raise RuntimeError("pallas unavailable; use impl='scan'")
     return _pallas_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
-                        block_len, scale,
-                        interpret=(impl == "pallas_interpret"))
+                        block_len, scale)
 
 
 def trivial_block_table(batch: int, cache_len: int,

@@ -534,13 +534,14 @@ class SlotSamplingTable:
 # in-step selection (pure; traced inside the engine's one jitted step)
 # ---------------------------------------------------------------------------
 
-_BASE_KEY = jax.random.PRNGKey(0)
-
-
 def lane_key(seed, index):
     """The seeding contract, exposed for tests/oracles: the key that
-    draws stream token `index` of a request seeded `seed`."""
-    return jax.random.fold_in(jax.random.fold_in(_BASE_KEY, seed), index)
+    draws stream token `index` of a request seeded `seed`. The base key is
+    made here, not at import: a module-level `PRNGKey(0)` initialises the
+    backend, and a process that merely imports the package (a launcher, a
+    DataLoader worker) would then hold the chip its children need."""
+    return jax.random.fold_in(
+        jax.random.fold_in(jax.random.PRNGKey(0), seed), index)
 
 
 def select_tokens(logits, adv, temperature, top_k, top_p, do_sample,

@@ -556,6 +556,8 @@ def main(argv=None):
                     help="reject single requests larger than this many rows")
     ap.add_argument("--final-metrics", default=None)
     args = ap.parse_args(argv)
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()  # before the first compilation
     server = serve(args.model, host=args.host, port=args.port,
                    config=EngineConfig(max_batch_size=args.max_batch_size,
                                        max_wait_ms=args.max_wait_ms,

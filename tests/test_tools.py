@@ -76,11 +76,6 @@ def test_measured_json_dict_shape_parses(tmp_path):
     assert gate.main(["--new", new, "--thresholds", th]) == 0
 
 
-def test_repo_thresholds_pass_against_history():
-    assert gate.main(["--new", os.path.join(gate.REPO,
-                                            "BENCH_MEASURED.json")]) == 0
-
-
 def test_unmapped_key_warns_loudly(tmp_path, capsys):
     """A measured row whose key matches no pinned floor must shout (the
     gate silently going vacuous was ADVICE r5): warning on stderr, and
@@ -92,17 +87,6 @@ def test_unmapped_key_warns_loudly(tmp_path, capsys):
     assert "no pinned floor" in capsys.readouterr().err
     rc = gate.main(["--new", new, "--thresholds", th, "--strict"])
     assert rc == 3
-
-
-def test_sweep_tag_maps_to_preset_floor(tmp_path):
-    """Sweep tags ('125m') resolve to preset names via tpu_sweep's
-    PRESET_SWEEP table, so tag-keyed rows still gate."""
-    row = {"tag": "125m", "metric": "decode-only",
-           "value": 1.0, "extra": {"mfu": 0.10, "backend": "tpu"}}
-    new = _write(tmp_path, "new.json", [row])
-    th = _write(tmp_path, "th.json", {"gpt3-125m": {"mfu": 0.32}})
-    rc = gate.main(["--new", new, "--thresholds", th])
-    assert rc == 2  # 0.10 gates against the gpt3-125m floor and fails
 
 
 def test_chunked_metric_keys_separately():

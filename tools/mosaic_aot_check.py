@@ -1,0 +1,146 @@
+"""Compile the Pallas kernels for a TPU v5e with no chip attached.
+
+The installed libtpu can describe a v5e topology and run XLA's TPU compiler
+(Mosaic included) against it on a CPU-only machine, so a kernel that Mosaic
+rejects is found here, in seconds and for no chip time, instead of on the
+chip. It cannot run anything: numbers and numerics still need
+`chip_smoke.py` on a chip.
+
+    python tools/mosaic_aot_check.py        # every kernel at smoke shapes
+
+Prints one `[OK]`/`[FAIL]` line per case; exit 1 if any case failed, exit 3
+if libtpu could not describe the topology (one process at a time may hold
+libtpu: /tmp/libtpu_lockfile). `tests/test_mosaic_aot.py` runs it in tier-1.
+Other scripts can reuse `v5e_devices()` + `use_tpu_lowering()` to lower
+their own jitted step for the chip (pass ShapeDtypeStructs whose sharding
+names these devices, then `.lower(...).compile()`).
+"""
+from __future__ import annotations
+
+import os
+import sys
+import time
+
+# the process stays on the CPU backend; libtpu is only asked to compile
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+
+
+def v5e_devices(n: int = 1):
+    """`n` (1 or 4) compile-only v5e devices from libtpu's topology."""
+    from jax.experimental import topologies
+    if n == 1:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    else:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    return topo.devices
+
+
+def use_tpu_lowering():
+    """Make ops.pallas_mode answer for the devices being compiled FOR (the
+    default backend here is the CPU, which would pick interpret mode)."""
+    from paddle_tpu.ops import pallas_mode
+    pallas_mode.platform = lambda: "tpu"
+
+
+def compile_case(name, fn, *specs, want=None) -> bool:
+    """Lower + compile `fn` for the specs' devices; `want` is the expected
+    {kernel: count} of Mosaic custom calls in the compiled text."""
+    from paddle_tpu.obs.compile_observatory import pallas_kernel_census
+    t0 = time.perf_counter()
+    try:
+        compiled = jax.jit(fn).lower(*specs).compile()
+    except Exception as e:  # the tool's job is to report every case
+        print(f"[FAIL] {name}: {type(e).__name__}: {str(e)[:1500]}",
+              flush=True)
+        return False
+    census = pallas_kernel_census(compiled.as_text())
+    ok = want is None or census == want
+    print(f"[{'OK' if ok else 'FAIL'}] {name}: {census} in "
+          f"{time.perf_counter() - t0:.1f}s"
+          + ("" if ok else f" (wanted {want})"), flush=True)
+    return ok
+
+
+def main() -> int:
+    try:
+        dev1, dev4 = v5e_devices(1), v5e_devices(4)
+    except Exception as e:
+        print(f"mosaic_aot_check: libtpu gave no v5e topology: {e}",
+              file=sys.stderr)
+        return 3
+    use_tpu_lowering()
+    from paddle_tpu.ops import attention as A
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+
+    one = NamedSharding(Mesh(np.array(dev1), ("x",)), P())
+
+    def spec(shape, dtype, sharding=one):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    results = []
+    qkv = [spec((2, 16, 1024, 128), jnp.bfloat16)] * 3
+    for label, kw in (("", {}), (" dropout", dict(dropout_p=0.1,
+                                                  dropout_seed=7))):
+        def loss(q, k, v, kw=kw):
+            return jnp.sum(A.flash_attention(q, k, v, causal=True, **kw)
+                           .astype(jnp.float32))
+        results.append(compile_case(
+            f"flash fwd+bwd bf16 [2,16,1024,128]{label}",
+            jax.grad(loss, argnums=(0, 1, 2)), *qkv,
+            want={"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}))
+
+    N, H, D, L = 8, 16, 128, 2048
+    for bl in (16, 8):          # engine default / DEFAULT_KV_BLOCK
+        for Tq in (1, 16):      # decode row / prefill chunk
+            def paged(q, k, v, t, sl, qp, bl=bl):
+                return ragged_paged_attention(
+                    q, k, v, t, sl, qp, block_len=bl,
+                    pages_per_row=L // bl, impl="pallas")
+            results.append(compile_case(
+                f"paged bf16 block_len={bl} Tq={Tq}", paged,
+                spec((N, H, Tq, D), jnp.bfloat16),
+                spec((N, H, L + 16, D), jnp.bfloat16),
+                spec((N, H, L + 16, D), jnp.bfloat16),
+                spec((N, L // bl), jnp.int32), spec((N,), jnp.int32),
+                spec((N,), jnp.int32), want={"paged_attention": 1}))
+
+    # four chips: a sharded pallas_call is refused by JAX outright; under
+    # spmd_mesh the kernels run as a shard_map island and compile
+    mesh4 = Mesh(np.array(dev4).reshape(2, 2), ("data", "model"))
+    sharded = NamedSharding(mesh4, P("data", "model"))
+    qkv4 = [spec((4, 16, 1024, 128), jnp.bfloat16, sharded)] * 3
+
+    def island(q, k, v):
+        with A.spmd_mesh(mesh4, "data"):
+            return A.flash_attention(q, k, v, causal=True)
+
+    results.append(compile_case("flash island on a 2x2 mesh", island, *qkv4,
+                                want={"flash_fwd": 1}))
+    try:
+        jax.jit(lambda q, k, v: A.flash_attention(q, k, v, causal=True)
+                ).lower(*qkv4)
+    except NotImplementedError as e:
+        print(f"[OK] bare pallas_call on sharded operands is refused: {e}",
+              flush=True)
+        results.append(True)
+    else:
+        print("[FAIL] bare pallas_call on sharded operands lowered; the "
+              "island may no longer be needed", flush=True)
+        results.append(False)
+    return 0 if all(results) else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
