@@ -31,6 +31,8 @@ from typing import Callable, Iterable, Optional
 
 import numpy as np
 
+from ..profiler import SPAN_TRAIN_BATCH_WAIT, RecordEvent
+
 
 class _Done:
     pass
@@ -185,11 +187,12 @@ class ChunkPrefetcher:
             raise StopIteration
         if self._thread is None:
             iter(self)
-        if self.ledger is not None:
-            with self.ledger.measure("data_wait"):
+        with RecordEvent(SPAN_TRAIN_BATCH_WAIT):
+            if self.ledger is not None:
+                with self.ledger.measure("data_wait"):
+                    item = self._take()
+            else:
                 item = self._take()
-        else:
-            item = self._take()
         if isinstance(item, _Done):
             raise StopIteration
         if isinstance(item, _Err):

@@ -442,9 +442,9 @@ class CompileObservatory:
 
     # ---- dispatch accounting ----
     def note_device_seconds(self, callsite: str, seconds: float):
-        """Attribute measured device-execution seconds (from the goodput
-        / serving-ledger dispatch hooks, which already blocked on the
-        result) to the call site's latest executable."""
+        """Attribute a dispatch's device span (launch to the end of the
+        caller's own wait for the result: the serve engine's fetch, the
+        goodput hook's block) to the call site's latest executable."""
         with self._lock:
             fp = self._latest.get(callsite)
             rec = self._records.get((callsite, fp)) if fp else None

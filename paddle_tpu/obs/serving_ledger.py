@@ -148,9 +148,12 @@ class ServingLedger(PhaseLedger):
                           Iterable[Tuple[str, int]]] = None):
         """Attribute ONE successful device dispatch.
 
-        `device_seconds` is the measured execution span (dispatch →
-        block_until_ready); it is split between `prefill_compute`,
-        `decode_compute` and `draft_compute` by advanced-position weights
+        `device_seconds` is the engine-clock span from the launch of the
+        dispatch to the end of the host's fetch of its result (launch +
+        execution + the device-to-host copies the engine makes anyway; no
+        `block_until_ready` of the ledger's own); it is split between
+        `prefill_compute`, `decode_compute` and `draft_compute` by
+        advanced-position weights
         and — via `book()` — subtracted from the enclosing `host` frame,
         so the pump's tiling holds by construction. `owners` is one
         `(tenant, slo_class, positions)` triple per active row; the

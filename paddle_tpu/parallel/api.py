@@ -28,6 +28,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer
+from ..profiler import SPAN_TRAIN_CHUNK_DISPATCH, RecordEvent
+
+# The scan chunk's inner function name. XLA names the executable
+# `jit_<this>`, and that name is how the benchmark finds the train step in a
+# profiler trace (benchmark/jobs/train.py: "main_module": "jit_chunk_step";
+# PERF_LEDGER's `breakdown` is keyed on it). Pinned by
+# tests/test_trace_spans.py: do not rename.
+CHUNK_STEP_NAME = "chunk_step"
 
 
 def _param_spec(param, mesh: Mesh) -> P:
@@ -869,6 +877,7 @@ class ScanTrainStep(ShardedTrainStep):
                 (lr_vec, steps_vec) + tuple(arrays), length=K)
             return losses, params_, opt_state_, buffers_, extras_
 
+        chunk_step.__name__ = chunk_step.__qualname__ = CHUNK_STEP_NAME
         self._chunk_step_fn = chunk_step  # exposed for jaxpr assertions
         param_sh, opt_sh, buf_sh, extras_specs = self._state_shardings
         scalar_sh = self._scalar_sh
@@ -939,6 +948,10 @@ class ScanTrainStep(ShardedTrainStep):
     def __call__(self, *args):
         """Run K fused steps over stacked [K, ...] inputs; returns the
         per-step loss vector as a length-K Tensor."""
+        with RecordEvent(SPAN_TRAIN_CHUNK_DISPATCH):
+            return self._dispatch_chunk(args)
+
+    def _dispatch_chunk(self, args):
         K = self.scan_steps
         if self.ledger is not None:
             with self.ledger.measure("h2d"):

@@ -10,16 +10,47 @@ threads, and the training loop all append to one shared buffer under a
 lock, so whichever thread calls `export_chrome_tracing` sees every span.
 Only the span *stack* (nesting context) stays per-thread. The disabled
 hot path is a single predicate — no lock is taken unless profiling is on.
+
+One span type, two recorders. Entering a `RecordEvent` also enters a
+`jax.profiler.TraceAnnotation` of the same name, so whenever ANY
+`jax.profiler` session runs (`start_profiler(trace_dir=...)`, an
+operator's `jax.profiler.start_server`, the benchmark's traced window)
+the program's spans are events of the trace's `/host:CPU` plane, on the
+clock the device planes are on, with their keyword arguments as stats.
+The in-memory sink (perf_counter clock, chrome export) is what
+`start_profiler()` without a trace directory gives.
 """
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import threading
 import time
 from typing import Dict, List, Optional
 
 import jax
+
+# ---- the program's span names (the ONE table; docs/observability.md and
+# PERF.md §3 copy it). Every span is a RecordEvent under these names; who
+# reads each is in PERF.md's inventory. The serve children tile `pump`
+# but for a few clock reads.
+SPAN_SERVE_PUMP = "pdtpu/serve/pump"              # _pump_inner; step=
+SPAN_SERVE_ADMIT = "pdtpu/serve/admit"            # expiry drop + _admit
+SPAN_SERVE_EVICT = "pdtpu/serve/evict"            # one evict_for_pressure
+SPAN_SERVE_DRAFT = "pdtpu/serve/draft"            # _draft_phase (if armed)
+SPAN_SERVE_BUILD_ROWS = "pdtpu/serve/build_rows"  # rows, kinds, operands
+SPAN_SERVE_DISPATCH = "pdtpu/serve/dispatch"      # upload + launch;
+#                                                   prefill_rows=, decode_rows=
+SPAN_SERVE_FETCH = "pdtpu/serve/fetch"            # host waits for the device
+SPAN_SERVE_COMMIT = "pdtpu/serve/commit"          # acceptance .. retire
+SPAN_SERVE_PUBLISH = "pdtpu/serve/publish"        # gauges after the step
+SPAN_TRAIN_BATCH_WAIT = "pdtpu/train/batch_wait"  # ChunkPrefetcher get
+SPAN_TRAIN_CHUNK_DISPATCH = "pdtpu/train/chunk_dispatch"  # ScanTrainStep call
+SERVE_SPANS = (SPAN_SERVE_PUMP, SPAN_SERVE_ADMIT, SPAN_SERVE_EVICT,
+               SPAN_SERVE_DRAFT, SPAN_SERVE_BUILD_ROWS, SPAN_SERVE_DISPATCH,
+               SPAN_SERVE_FETCH, SPAN_SERVE_COMMIT, SPAN_SERVE_PUBLISH)
+TRAIN_SPANS = (SPAN_TRAIN_BATCH_WAIT, SPAN_TRAIN_CHUNK_DISPATCH)
 
 
 class _ProfSink:
@@ -39,24 +70,47 @@ class _ProfSink:
 _SINK = _ProfSink()
 
 
+_LANES = itertools.count(1)      # chrome-export lane per thread
+_SPAN_IDS = itertools.count(1)   # sink-side span ids (parent links)
+
+
 class _ThreadState(threading.local):
     def __init__(self):
-        self.stack: List[str] = []
+        self.stack: List[int] = []      # ids of this thread's open spans
+        # a process-wide counter, not get_ident(): a joined thread's ident
+        # is reused, which put two threads on one lane
+        self.lane = next(_LANES)
 
 
 _T = _ThreadState()
 
 
 class RecordEvent:
-    """RAII host span (platform/profiler.h:127 analog)."""
+    """RAII host span (platform/profiler.h:127 analog). Keyword arguments
+    are the ids the span was given (`step=`, `rid=`, row counts): stats of
+    the trace event, `args` of the sink event. The sink event also records
+    `id` and `parent` (the id of the enclosing span on this thread, 0 at
+    top level). With no `jax.profiler` session and the sink off, a span
+    costs the annotation's own inactive check plus one predicate."""
 
-    def __init__(self, name: str, event_type: str = "UserDefined"):
+    __slots__ = ("name", "args", "begin", "_ann", "_open", "_id", "_parent")
+
+    def __init__(self, name: str, event_type: str = "UserDefined", **args):
         self.name = name
+        self.args = args
         self.begin = None
+        self._open = False
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
 
     def __enter__(self):
-        self.begin = time.perf_counter_ns()
-        _T.stack.append(self.name)
+        self._ann.__enter__()
+        self._open = True
+        if _SINK.enabled:
+            stack = _T.stack
+            self._parent = stack[-1] if stack else 0
+            self._id = next(_SPAN_IDS)
+            stack.append(self._id)
+            self.begin = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
@@ -64,17 +118,24 @@ class RecordEvent:
         return False
 
     def end(self):
-        if _T.stack and _T.stack[-1] == self.name:
-            _T.stack.pop()
-        if self.begin is None or not _SINK.enabled:
-            self.begin = None
+        if not self._open:
+            return
+        self._open = False
+        self._ann.__exit__(None, None, None)
+        if self.begin is None:
+            return
+        end = time.perf_counter_ns()
+        stack = _T.stack
+        if stack and stack[-1] == self._id:
+            stack.pop()
+        begin, self.begin = self.begin, None
+        if not _SINK.enabled:
             return
         evt = {
-            "name": self.name, "ts": self.begin / 1e3,
-            "dur": (time.perf_counter_ns() - self.begin) / 1e3,
-            "ph": "X", "pid": 0, "tid": threading.get_ident() % 10000,
+            "name": self.name, "ts": begin / 1e3, "dur": (end - begin) / 1e3,
+            "ph": "X", "pid": 0, "tid": _T.lane,
+            "args": {"id": self._id, "parent": self._parent, **self.args},
         }
-        self.begin = None
         with _SINK.lock:
             _SINK.events.append(evt)
 
@@ -88,7 +149,7 @@ def record_instant(name: str, args: Optional[dict] = None):
     evt = {
         "name": name, "ts": time.perf_counter_ns() / 1e3,
         "ph": "i", "s": "p", "pid": 0,
-        "tid": threading.get_ident() % 10000,
+        "tid": _T.lane,
         "args": args or {},
     }
     with _SINK.lock:

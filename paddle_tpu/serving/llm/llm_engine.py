@@ -126,6 +126,10 @@ import numpy as np
 
 from ...obs.flight_recorder import flight_recorder
 from ...obs.trace import RequestTrace, TimelineStore, new_request_id
+from ...profiler import (SPAN_SERVE_ADMIT, SPAN_SERVE_BUILD_ROWS,
+                         SPAN_SERVE_COMMIT, SPAN_SERVE_DISPATCH,
+                         SPAN_SERVE_DRAFT, SPAN_SERVE_FETCH,
+                         SPAN_SERVE_PUBLISH, SPAN_SERVE_PUMP, RecordEvent)
 from ..clock import Clock, MonotonicClock, SimClock
 from ..engine import DeadlineExceededError, RejectedError
 from ..metrics import LLMMetrics, SLO_CLASSES
@@ -139,6 +143,14 @@ from .sampling import (GREEDY, SamplingParams, SlotSamplingTable,
                        compile_grammar, select_next, select_tokens)
 
 _log = logging.getLogger("paddle_tpu.serving.llm")
+
+# The unified step's inner function name. XLA names the executable
+# `jit_<this>`, and that name is how the benchmark finds the step in a
+# profiler trace (benchmark/jobs/serve_closed_loop.py: "main_module":
+# "jit_step"; `step_gap_ms_p50`, `unified_step_ms_p50` and PERF_LEDGER's
+# `breakdown` are keyed on it). Pinned by tests/test_trace_spans.py: do not
+# rename.
+UNIFIED_STEP_NAME = "step"
 
 
 class WeightSwapError(ValueError):
@@ -740,6 +752,7 @@ class LLMEngine:
                     sel[..., None].astype(jnp.int32), axis=-1)[..., 0]
                 return sel, lp, new_state, new_slabs
 
+            step.__name__ = step.__qualname__ = UNIFIED_STEP_NAME
             self._step_jit = jax.jit(step)
         return self._step_jit
 
@@ -1757,6 +1770,13 @@ class LLMEngine:
                 return self.clock.now()
             return None
 
+    @property
+    def unified_steps(self) -> int:
+        """Lifetime committed unified steps of either kind (mirrored as
+        `metrics.counters["unified_steps"]`; `counters["dispatches"]`
+        counts only those with a decode row)."""
+        return self.decode_iterations + self.prefill_dispatches
+
     def pump(self) -> int:
         """One scheduler pass: drop expired queued requests, admit queued
         requests into free slots (bookkeeping only — no dispatch), then
@@ -1778,13 +1798,25 @@ class LLMEngine:
             return self._pump_inner()
 
     def _pump_inner(self) -> int:
-        now = self.clock.now()
-        # time-weighted slot occupancy (ISSUE 11 satellite): integrate the
-        # level held since the previous pump pass, at pump granularity
-        self.metrics.observe_occupancy(now)
-        self._drop_expired_queued(now)
-        self._admit()
-        n = self._step_once()
+        # the spans of one pass (names: profiler.SERVE_SPANS): the children
+        # tile `pump` but for a few clock reads, so a jax.profiler trace
+        # says what the host did in the gap between two unified steps
+        with RecordEvent(SPAN_SERVE_PUMP, step=self.unified_steps):
+            now = self.clock.now()
+            # time-weighted slot occupancy (ISSUE 11 satellite): integrate
+            # the level held since the previous pump pass, at pump
+            # granularity
+            self.metrics.observe_occupancy(now)
+            with RecordEvent(SPAN_SERVE_ADMIT):
+                self._drop_expired_queued(now)
+                self._admit()
+            n = self._step_once()
+            with RecordEvent(SPAN_SERVE_PUBLISH):
+                self._publish_gauges()
+        return n
+
+    def _publish_gauges(self):
+        """The gauges one pump pass refreshes after its step."""
         with self._cond:
             self.metrics.set_inflight_tokens(self._inflight_tokens_locked())
             per_tenant: Dict[str, int] = {}
@@ -1814,7 +1846,6 @@ class LLMEngine:
                     self.ledger.book("kv_spill", spill - self._spill_booked)
                     self._spill_booked = spill
         self.metrics.set_fragmentation(self.pool.fragmentation_ratio())
-        return n
 
     def _drop_expired_queued(self, now: float):
         with self._cond:
@@ -2095,7 +2126,10 @@ class LLMEngine:
                     [(s, r) for s, r, _, _, _ in catchup], e, "catchup")
                 return {}
             if self.ledger is not None:
-                jax.block_until_ready(out)
+                # the launch span only: nothing waits for the catch-up's
+                # result, so its execution is inside the next device span
+                # the host does wait for (the proposal's fetch below, or
+                # the unified step's)
                 self.ledger.book_dispatch(
                     self.clock.now() - tdc0, prefill_positions=0,
                     decode_positions=0, total_positions=0,
@@ -2174,16 +2208,15 @@ class LLMEngine:
             self._draft_failure([(s, r) for s, r, _, _ in eligible], e,
                                 "propose")
             return {}
+        dpool.slabs = new_slabs
+        drafts = np.asarray(drafts_dev)     # the host waits here, armed or not
         if self.ledger is not None:
-            jax.block_until_ready(drafts_dev)
             self.ledger.book_dispatch(
                 self.clock.now() - tdc0, prefill_positions=0,
                 decode_positions=0, total_positions=0,
                 owners=[(r.tenant, r.slo, K + 1)
                         for _, r, _, _ in eligible],
                 draft_positions=(K + 1) * len(eligible))
-        dpool.slabs = new_slabs
-        drafts = np.asarray(drafts_dev)
         spec: Dict[int, List[int]] = {}
         with self._cond:
             for slot, req, ds, L in eligible:
@@ -2401,9 +2434,12 @@ class LLMEngine:
         to plain greedy decode. Quarantine retries reuse this pump's
         windows: a failed dispatch commits nothing, so the surviving
         rows' positions — and therefore their drafts — are unchanged."""
-        spec_drafts = self._draft_phase()
+        spec_drafts = {}
+        if self.draft_pool is not None and not self._spec_disabled:
+            with RecordEvent(SPAN_SERVE_DRAFT):
+                spec_drafts = self._draft_phase()
         while True:
-            with self._cond:
+            with RecordEvent(SPAN_SERVE_BUILD_ROWS), self._cond:
                 if not self._active:
                     return 0
                 toks, pos, adv, ctr, prefill_slots, decode_slots = \
@@ -2421,228 +2457,250 @@ class LLMEngine:
             self.metrics.on_mask_overhead(mask_dt * 1e3)
             if self.ledger is not None:
                 self.ledger.book("sample_mask", mask_dt)
-            t0 = self.clock.now()
-            fn = self._step()
-            args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
-                    jnp.asarray(adv), self.pool.device_block_table(),
-                    self.pool.slabs) + sargs + aargs
-            if self.observatory is not None:
-                self.observatory.observe_call("llm/unified_step", fn, args)
-            attempts = self.config.dispatch_retries + 1
-            last_err = None
-            nxt = None
-            tc0 = None
-            for attempt in range(attempts):
-                if self.ledger is not None or self.observatory is not None:
-                    # re-armed per attempt: a failed round's wall time
-                    # stays in the host phase; only the successful
-                    # dispatch's span is booked as compute
-                    tc0 = self.clock.now()
-                try:
-                    nxt, lps, new_dstate, new_slabs = self._run_dispatch(
-                        kinds, fn, args)
-                except DispatchFailedError as e:
-                    last_err = e
-                    self.metrics.on_dispatch_failure(e.reason)
-                    flight_recorder().record(
-                        "dispatch_retry", engine="llm", attempt=attempt + 1,
-                        attempts=attempts, reason=e.reason,
-                        prefill_rows=len(prefill_slots),
-                        decode_rows=len(decode_slots))
-                    _log.warning(
-                        "unified step dispatch failed over %d prefill + %d "
-                        "decode row(s) (attempt %d/%d): %s",
-                        len(prefill_slots), len(decode_slots), attempt + 1,
-                        attempts, e)
-                    continue
-                self.pool.slabs = new_slabs
-                if decode_slots:
-                    # the breaker tracks ENGINE-level (decode-protocol)
-                    # failures; prefill-only successes must not launder a
-                    # failure streak between decode attempts
-                    self.supervisor.record_success()
-                break
-            else:
-                if self._blame_and_quarantine(fn, toks, pos, adv, ctr,
-                                              last_err):
-                    continue    # survivors retry on a rebuilt row set
-                self._fail_all_active(attempts, last_err)
-                self.supervisor.record_failure()
-                return 0
-            if self.ledger is not None or self.observatory is not None:
-                # jit dispatch is async: block on the device result so the
-                # measured span is execution, not launch; split it between
-                # the compute phases by advanced positions and meter it to
-                # the rows' tenants / SLO classes (ISSUE 11)
-                jax.block_until_ready(nxt)
-                tc1 = self.clock.now()
-            nxt = np.asarray(nxt)   # [N, C] per-position selected tokens
-            lps = np.asarray(lps)   # [N, C] per-position selected logprobs
-            new_dstate = np.asarray(new_dstate)  # [N] advanced DFA states
-            with self._cond:
-                accept = self._acceptance_locked(decode_slots, spec_drafts,
-                                                 nxt)
-            if self.ledger is not None or self.observatory is not None:
-                if self.ledger is not None:
-                    with self._cond:
-                        owners = [(self._active[s].tenant,
-                                   self._active[s].slo, int(adv[s]))
-                                  for s in prefill_slots
-                                  if s in self._active]
-                        adapter_owners = [
-                            (self._active[s].adapter or "base", int(adv[s]))
-                            for s in prefill_slots if s in self._active]
-                        decode_useful = drafted = accepted = 0
-                        for s in decode_slots:
-                            req = self._active.get(s)
-                            if req is None or s not in accept:
-                                continue
-                            emit_toks, acc, k = accept[s]
-                            owners.append((req.tenant, req.slo,
-                                           len(emit_toks)))
-                            adapter_owners.append((req.adapter or "base",
-                                                   len(emit_toks)))
-                            decode_useful += len(emit_toks)
-                            drafted += k
-                            accepted += acc
-                    # a verify row's rejected columns stay inside
-                    # total_positions but out of the useful decode count:
-                    # wasted draft positions surface as pad-waste in
-                    # token_efficiency, exactly like prefill padding.
-                    # adapter_owners (ISSUE 20) re-buckets the SAME
-                    # per-row shares by adapter id, so per-adapter
-                    # device-seconds sum exactly to the tenant total.
-                    self.ledger.book_dispatch(
-                        tc1 - tc0,
-                        prefill_positions=int(sum(adv[s]
-                                                  for s in prefill_slots)),
-                        decode_positions=decode_useful,
-                        total_positions=int(toks.size),
-                        owners=owners,
-                        drafted=drafted, draft_accepted=accepted,
-                        adapter_owners=(adapter_owners
-                                        if self.adapter_bank is not None
-                                        else None))
+            with RecordEvent(SPAN_SERVE_DISPATCH,
+                             prefill_rows=len(prefill_slots),
+                             decode_rows=len(decode_slots)):
+                t0 = self.clock.now()
+                fn = self._step()
+                args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
+                        jnp.asarray(adv), self.pool.device_block_table(),
+                        self.pool.slabs) + sargs + aargs
                 if self.observatory is not None:
-                    # the span above already blocked on the result, so it
-                    # is pure device execution — attribute it to this
-                    # call site's latest executable (ISSUE 12)
-                    self.observatory.note_device_seconds(
-                        "llm/unified_step", tc1 - tc0)
-            now = self.clock.now()
-            with self._cond:
-                n_decode = len(decode_slots)
-                if n_decode:
-                    self.decode_iterations += 1
-                elif prefill_slots:
-                    self.prefill_dispatches += 1
-                for slot in prefill_slots:
-                    # evacuate() (deploy drain) may have freed the slot
-                    # between row build and commit in threaded mode
-                    req = self._active.get(slot)
-                    if req is None:
+                    self.observatory.observe_call("llm/unified_step", fn,
+                                                  args)
+                attempts = self.config.dispatch_retries + 1
+                last_err = None
+                nxt = None
+                tc0 = None
+                for attempt in range(attempts):
+                    if self.ledger is not None or self.observatory is not None:
+                        # the start of the dispatch's device span, after the
+                        # operand uploads and `observe_call`: launch to the
+                        # end of `fetch` below, where np.asarray has already
+                        # waited for the device. Nothing synchronises for
+                        # the ledger's or the observatory's sake, so an
+                        # armed engine runs the default engine's host
+                        # sequence. Re-armed per attempt: a failed round's
+                        # wall time stays in the host phase.
+                        tc0 = self.clock.now()
+                    try:
+                        nxt, lps, new_dstate, new_slabs = \
+                            self._run_dispatch(kinds, fn, args)
+                    except DispatchFailedError as e:
+                        last_err = e
+                        self.metrics.on_dispatch_failure(e.reason)
+                        flight_recorder().record(
+                            "dispatch_retry", engine="llm",
+                            attempt=attempt + 1, attempts=attempts,
+                            reason=e.reason,
+                            prefill_rows=len(prefill_slots),
+                            decode_rows=len(decode_slots))
+                        _log.warning(
+                            "unified step dispatch failed over %d prefill "
+                            "+ %d decode row(s) (attempt %d/%d): %s",
+                            len(prefill_slots), len(decode_slots),
+                            attempt + 1, attempts, e)
                         continue
-                    n = int(adv[slot])
-                    off = req.chunk_off
-                    self.pool.set_length(slot, off + n)
-                    req.chunk_off = off + n
-                    self.prefill_tokens += n
+                    self.pool.slabs = new_slabs
+                    if decode_slots:
+                        # the breaker tracks ENGINE-level (decode-protocol)
+                        # failures; prefill-only successes must not launder
+                        # a failure streak between decode attempts
+                        self.supervisor.record_success()
+                    break
+                else:
+                    if self._blame_and_quarantine(fn, toks, pos, adv, ctr,
+                                                  last_err):
+                        continue    # survivors retry on a rebuilt row set
+                    self._fail_all_active(attempts, last_err)
+                    self.supervisor.record_failure()
+                    return 0
+            with RecordEvent(SPAN_SERVE_FETCH):
+                # jit dispatch is async: these conversions are where the
+                # host waits for the device
+                nxt = np.asarray(nxt)   # [N, C] per-position tokens
+                lps = np.asarray(lps)   # [N, C] per-position logprobs
+                new_dstate = np.asarray(new_dstate)  # [N] DFA states
+            now = self.clock.now()
+            with RecordEvent(SPAN_SERVE_COMMIT):
+                return self._commit_step(
+                    nxt, lps, new_dstate, toks, pos, adv, prefill_slots,
+                    decode_slots, spec_drafts, t0, tc0, now)
+
+    def _commit_step(self, nxt, lps, new_dstate, toks, pos, adv,
+                     prefill_slots, decode_slots, spec_drafts, t0: float,
+                     tc0: Optional[float], now: float) -> int:
+        """Commit one fetched unified step: draft acceptance, the
+        ledger's/observatory's booking of the device span `now - tc0`
+        (launch to fetch end; `tc0` is None on an engine that arms
+        neither), emission, retire, finish. `t0` is the start of the
+        `dispatch` span (the decode-step histogram's base), `now` the
+        engine clock at the end of the fetch."""
+        with self._cond:
+            accept = self._acceptance_locked(decode_slots, spec_drafts,
+                                             nxt)
+        if self.ledger is not None or self.observatory is not None:
+            if self.ledger is not None:
+                with self._cond:
+                    owners = [(self._active[s].tenant,
+                               self._active[s].slo, int(adv[s]))
+                              for s in prefill_slots
+                              if s in self._active]
+                    adapter_owners = [
+                        (self._active[s].adapter or "base", int(adv[s]))
+                        for s in prefill_slots if s in self._active]
+                    decode_useful = drafted = accepted = 0
+                    for s in decode_slots:
+                        req = self._active.get(s)
+                        if req is None or s not in accept:
+                            continue
+                        emit_toks, acc, k = accept[s]
+                        owners.append((req.tenant, req.slo,
+                                       len(emit_toks)))
+                        adapter_owners.append((req.adapter or "base",
+                                               len(emit_toks)))
+                        decode_useful += len(emit_toks)
+                        drafted += k
+                        accepted += acc
+                # a verify row's rejected columns stay inside
+                # total_positions but out of the useful decode count:
+                # wasted draft positions surface as pad-waste in
+                # token_efficiency, exactly like prefill padding.
+                # adapter_owners (ISSUE 20) re-buckets the SAME
+                # per-row shares by adapter id, so per-adapter
+                # device-seconds sum exactly to the tenant total.
+                self.ledger.book_dispatch(
+                    now - tc0,
+                    prefill_positions=int(sum(adv[s]
+                                              for s in prefill_slots)),
+                    decode_positions=decode_useful,
+                    total_positions=int(toks.size),
+                    owners=owners,
+                    drafted=drafted, draft_accepted=accepted,
+                    adapter_owners=(adapter_owners
+                                    if self.adapter_bank is not None
+                                    else None))
+            if self.observatory is not None:
+                # the fetch already waited for the result, so the span is
+                # launch + execution — attribute it to this call site's
+                # latest executable (ISSUE 12)
+                self.observatory.note_device_seconds(
+                    "llm/unified_step", now - tc0)
+        with self._cond:
+            n_decode = len(decode_slots)
+            if n_decode:
+                self.decode_iterations += 1
+            elif prefill_slots:
+                self.prefill_dispatches += 1
+            for slot in prefill_slots:
+                # evacuate() (deploy drain) may have freed the slot
+                # between row build and commit in threaded mode
+                req = self._active.get(slot)
+                if req is None:
+                    continue
+                n = int(adv[slot])
+                off = req.chunk_off
+                self.pool.set_length(slot, off + n)
+                req.chunk_off = off + n
+                self.prefill_tokens += n
+                if req.trace is not None:
+                    req.trace.event("prefill_chunk", now, off=off, n=n)
+                if req.chunk_off >= len(req.prompt):
+                    # final chunk landed: first token emitted, TTFT
+                    # ends here
+                    req.handle.ttft_ms = (now - req.arrival) * 1e3
                     if req.trace is not None:
-                        req.trace.event("prefill_chunk", now, off=off, n=n)
-                    if req.chunk_off >= len(req.prompt):
-                        # final chunk landed: first token emitted, TTFT
-                        # ends here
-                        req.handle.ttft_ms = (now - req.arrival) * 1e3
-                        if req.trace is not None:
-                            # same instant as ttft_ms, so the trace's TTFT
-                            # boundary reconciles with the handle exactly
-                            req.trace.mark("first_token", now)
-                        self.metrics.on_prefill(req.handle.ttft_ms,
-                                                slo=req.slo)
-                        if self.burn is not None:
-                            target = (self.config.slo_ttft_target_ms
-                                      or {}).get(req.slo)
-                            self.burn.observe(
-                                req.slo,
-                                target is None
-                                or req.handle.ttft_ms <= target,
-                                outcome="ttft")
-                        if self.prefix_cache is not None:
-                            # index the completed prefill while the slot
-                            # is still active: siblings queued behind it
-                            # attach without waiting for it to finish
-                            self.prefix_cache.insert(
-                                self._kv_ns(req.tenant, req.adapter),
-                                req.prompt, slot, req.attached_pages)
-                        self._emit(req, int(nxt[slot, int(adv[slot]) - 1]),
-                                   float(lps[slot, int(adv[slot]) - 1]))
-                        if req.gid:
-                            # first constrained emission: commit the DFA
-                            # state advanced in-step past that token
-                            self.sampling_table.set_dfa_state(
-                                slot, int(new_dstate[slot]))
-                        if self._finish_if_done(req, now):
-                            del self._active[slot]
-                        elif req.deadline is not None and now >= req.deadline:
-                            self._evict_expired_locked(req, slot, now)
-                    elif req.deadline is not None and now >= req.deadline:
-                        # mid-prefill eviction: no tokens yet, but the slot
-                        # must not keep absorbing chunk work
-                        self._evict_expired_locked(req, slot, now)
-                total_emitted = 0
-                for slot in decode_slots:
-                    req = self._active.get(slot)
-                    if req is None or slot not in accept:
-                        continue  # evacuated mid-step (deploy drain)
-                    emit_toks, acc, k = accept[slot]
-                    L = int(pos[slot])
-                    # the verify wrote KV for every consumed column, but
-                    # only the accepted prefix + corrective token is
-                    # committed: lengths/block tables never cover the
-                    # rejected tail, so the pool's garbage-past-length
-                    # invariant IS the rollback
-                    self.pool.set_length(slot, L + len(emit_toks))
-                    if self.draft_pool is not None \
-                            and req.draft_slot is not None \
-                            and self.draft_pool.active[req.draft_slot]:
-                        # the draft ran ahead on its own proposals; rewind
-                        # its tables to the verified stream so the next
-                        # window extends truth, not rejected speculation
-                        dlen = int(self.draft_pool.lengths[req.draft_slot])
-                        self.draft_pool.rewind_length(
-                            req.draft_slot,
-                            min(dlen, L + len(emit_toks)))
-                    if req.trace is not None:
-                        ev = dict(tok=int(emit_toks[-1]),
-                                  n_active=len(decode_slots))
-                        if k:
-                            ev.update(drafted=k, accepted=acc)
-                        req.trace.event("decode_step", now, **ev)
-                    for j, tok in enumerate(emit_toks):
-                        self._emit(req, tok, float(lps[slot, j]))
+                        # same instant as ttft_ms, so the trace's TTFT
+                        # boundary reconciles with the handle exactly
+                        req.trace.mark("first_token", now)
+                    self.metrics.on_prefill(req.handle.ttft_ms,
+                                            slo=req.slo)
+                    if self.burn is not None:
+                        target = (self.config.slo_ttft_target_ms
+                                  or {}).get(req.slo)
+                        self.burn.observe(
+                            req.slo,
+                            target is None
+                            or req.handle.ttft_ms <= target,
+                            outcome="ttft")
+                    if self.prefix_cache is not None:
+                        # index the completed prefill while the slot
+                        # is still active: siblings queued behind it
+                        # attach without waiting for it to finish
+                        self.prefix_cache.insert(
+                            self._kv_ns(req.tenant, req.adapter),
+                            req.prompt, slot, req.attached_pages)
+                    self._emit(req, int(nxt[slot, int(adv[slot]) - 1]),
+                               float(lps[slot, int(adv[slot]) - 1]))
                     if req.gid:
-                        # constrained rows never speculate (one emission
-                        # per step), so the in-step advanced state is
-                        # exactly the post-emission state
+                        # first constrained emission: commit the DFA
+                        # state advanced in-step past that token
                         self.sampling_table.set_dfa_state(
                             slot, int(new_dstate[slot]))
-                    total_emitted += len(emit_toks)
-                    if k:
-                        self.spec_windows += 1
-                        self.spec_drafted += k
-                        self.spec_accepted += acc
-                        self.metrics.on_spec_window(k, acc)
                     if self._finish_if_done(req, now):
                         del self._active[slot]
                     elif req.deadline is not None and now >= req.deadline:
                         self._evict_expired_locked(req, slot, now)
-                self.metrics.set_slots(self.pool.active_slots(),
-                                       self.pool.num_slots)
-            if n_decode:
-                self.metrics.on_decode_step(n_decode, (now - t0) * 1e3,
-                                            tokens=total_emitted)
-                return 1
-            return 0
+                elif req.deadline is not None and now >= req.deadline:
+                    # mid-prefill eviction: no tokens yet, but the slot
+                    # must not keep absorbing chunk work
+                    self._evict_expired_locked(req, slot, now)
+            total_emitted = 0
+            for slot in decode_slots:
+                req = self._active.get(slot)
+                if req is None or slot not in accept:
+                    continue  # evacuated mid-step (deploy drain)
+                emit_toks, acc, k = accept[slot]
+                L = int(pos[slot])
+                # the verify wrote KV for every consumed column, but
+                # only the accepted prefix + corrective token is
+                # committed: lengths/block tables never cover the
+                # rejected tail, so the pool's garbage-past-length
+                # invariant IS the rollback
+                self.pool.set_length(slot, L + len(emit_toks))
+                if self.draft_pool is not None \
+                        and req.draft_slot is not None \
+                        and self.draft_pool.active[req.draft_slot]:
+                    # the draft ran ahead on its own proposals; rewind
+                    # its tables to the verified stream so the next
+                    # window extends truth, not rejected speculation
+                    dlen = int(self.draft_pool.lengths[req.draft_slot])
+                    self.draft_pool.rewind_length(
+                        req.draft_slot,
+                        min(dlen, L + len(emit_toks)))
+                if req.trace is not None:
+                    ev = dict(tok=int(emit_toks[-1]),
+                              n_active=len(decode_slots))
+                    if k:
+                        ev.update(drafted=k, accepted=acc)
+                    req.trace.event("decode_step", now, **ev)
+                for j, tok in enumerate(emit_toks):
+                    self._emit(req, tok, float(lps[slot, j]))
+                if req.gid:
+                    # constrained rows never speculate (one emission
+                    # per step), so the in-step advanced state is
+                    # exactly the post-emission state
+                    self.sampling_table.set_dfa_state(
+                        slot, int(new_dstate[slot]))
+                total_emitted += len(emit_toks)
+                if k:
+                    self.spec_windows += 1
+                    self.spec_drafted += k
+                    self.spec_accepted += acc
+                    self.metrics.on_spec_window(k, acc)
+                if self._finish_if_done(req, now):
+                    del self._active[slot]
+                elif req.deadline is not None and now >= req.deadline:
+                    self._evict_expired_locked(req, slot, now)
+            self.metrics.set_slots(self.pool.active_slots(),
+                                   self.pool.num_slots)
+        if n_decode:
+            self.metrics.on_decode_step(n_decode, (now - t0) * 1e3,
+                                        tokens=total_emitted)
+            return 1
+        if prefill_slots:
+            self.metrics.on_prefill_step()
+        return 0
 
     def _evict_expired_locked(self, req: _GenRequest, slot: int,
                               now: float):

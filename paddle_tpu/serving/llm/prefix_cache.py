@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ...profiler import SPAN_SERVE_EVICT, RecordEvent
 from .kv_pool import SlotPagedKVPool
 
 
@@ -329,37 +330,38 @@ class PrefixCache:
         Returns pages released. Pages with live readers never qualify,
         so eviction under slot pressure cannot reclaim a block a stream
         is still reading — the fault matrix proves this."""
-        released = 0
-        while not self.pool.has_allocatable_row():
-            victim = self._lru_victim()
-            if victim is None:
-                break
-            _, kind, tenant, holder, key, path = victim
-            ts = self._ts(tenant)
-            if kind == "tail":
-                self.pool.release_cached(holder.tail_page)
-                holder.tail_tokens = None
-                holder.tail_page = None
-                holder.tail_tick = 0
-            else:
-                child = holder.children.pop(key)
-                if self.host_pool is not None:
-                    # spill the full block to the host tier before the
-                    # page is released (refcount is provably 0 here, so
-                    # the device copy is quiescent — the export is the
-                    # exact KV the trie indexed)
-                    t0 = self.clock() if self.clock is not None else None
-                    self.host_pool.put(
-                        tenant, path, self.pool.export_page(child.page))
-                    self.spilled_pages += 1
-                    if t0 is not None:
-                        self.spill_seconds += self.clock() - t0
-                self.pool.release_cached(child.page)
-            ts["evictions"] += 1
-            self.stats["evictions"] += 1
-            ts["cached_blocks"] -= 1
-            self.stats["cached_blocks"] -= 1
-            released += 1
+        with RecordEvent(SPAN_SERVE_EVICT):
+            released = 0
+            while not self.pool.has_allocatable_row():
+                victim = self._lru_victim()
+                if victim is None:
+                    break
+                _, kind, tenant, holder, key, path = victim
+                ts = self._ts(tenant)
+                if kind == "tail":
+                    self.pool.release_cached(holder.tail_page)
+                    holder.tail_tokens = None
+                    holder.tail_page = None
+                    holder.tail_tick = 0
+                else:
+                    child = holder.children.pop(key)
+                    if self.host_pool is not None:
+                        # spill the full block to the host tier before the
+                        # page is released (refcount is provably 0 here, so
+                        # the device copy is quiescent — the export is the
+                        # exact KV the trie indexed)
+                        t0 = self.clock() if self.clock is not None else None
+                        self.host_pool.put(
+                            tenant, path, self.pool.export_page(child.page))
+                        self.spilled_pages += 1
+                        if t0 is not None:
+                            self.spill_seconds += self.clock() - t0
+                    self.pool.release_cached(child.page)
+                ts["evictions"] += 1
+                self.stats["evictions"] += 1
+                ts["cached_blocks"] -= 1
+                self.stats["cached_blocks"] -= 1
+                released += 1
         return released
 
     def clear(self, only=None) -> int:

@@ -270,6 +270,7 @@ class LLMMetrics(ServingMetrics):
         # (active_rows, step_ms) pairs: tokens/sec over the recent window
         self._decode_window: deque = deque(maxlen=self.window)
         self.counters.update({"prefills": 0, "decode_steps": 0,
+                              "unified_steps": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
                               "brownout_entries": 0,
                               "prefix_hits": 0, "prefix_misses": 0,
@@ -462,13 +463,16 @@ class LLMMetrics(ServingMetrics):
                 self.batch_hist.get(active_rows, 0) + 1
             self.dispatched_rows += int(active_rows)
             self.counters["dispatches"] += 1
+            self.counters["unified_steps"] += 1
             self._intertoken_ms.append(float(step_ms))
             self._decode_window.append((tokens, float(step_ms)))
-        from ..profiler import record_instant
-        record_instant("serving/llm_decode", {
-            "active_rows": active_rows, "step_ms": step_ms,
-            "tokens": tokens,
-        })
+
+    def on_prefill_step(self):
+        """One committed unified step that carried only prefill rows:
+        with `on_decode_step` it makes `unified_steps` every committed
+        step (`dispatches` keeps meaning steps with a decode row)."""
+        with self._lock:
+            self.counters["unified_steps"] += 1
 
     def on_spec_window(self, drafted: int, accepted: int):
         """One verified speculative window (ISSUE 17): `drafted` tokens
@@ -658,6 +662,8 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_tokens_total", s["tokens_out"])
         b.family(f"{px}_decode_steps_total", "counter")
         b.sample(f"{px}_decode_steps_total", s["decode_steps"])
+        b.family(f"{px}_unified_steps_total", "counter")
+        b.sample(f"{px}_unified_steps_total", s["unified_steps"])
         b.family(f"{px}_prefills_total", "counter")
         b.sample(f"{px}_prefills_total", s["prefills"])
         # ---- speculative decoding families (ISSUE 17) ----
