@@ -29,37 +29,53 @@ from ..utils.jit_cache import JitLRUCache
 _GENERATE_JIT_CACHE_CAP = 8
 
 
-def _top_p_filter(lg, top_p):
-    """Nucleus filter on [B, V] logits; `top_p` is a scalar or [B] f32.
-
-    Keeps the smallest set of tokens whose probability mass reaches
-    top_p (the standard "cumulative mass before this sorted slot is
-    still < p" rule, so at least the most-likely token always
-    survives), then maps the sorted cut back to logit space as a
-    per-row threshold — ties at the threshold survive, matching the
-    top-k tie semantics above."""
-    B, V = lg.shape
-    p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
-    srt = jnp.sort(lg, axis=-1)[:, ::-1]
+def _nucleus_threshold(srt, p):
+    """[B, 1] logit threshold of the nucleus of rows sorted descending
+    (`srt` [B, V], `p` [B]): the smallest set of tokens whose
+    probability mass reaches p, by the standard "cumulative mass before
+    this sorted slot is still < p" rule, so at least the most-likely
+    token always survives."""
     probs = jax.nn.softmax(srt, axis=-1)
     cum_before = jnp.cumsum(probs, axis=-1) - probs
     n_keep = jnp.maximum(jnp.sum(cum_before < p[:, None], axis=-1), 1)
-    thr = jnp.take_along_axis(srt, (n_keep - 1)[:, None], axis=-1)
+    return jnp.take_along_axis(srt, (n_keep - 1)[:, None], axis=-1)
+
+
+def _top_p_filter(lg, top_p):
+    """Nucleus filter on [B, V] logits; `top_p` is a scalar (the static
+    path of `_select_token`; the batched path takes the same threshold
+    from `_top_k_top_p_filter`'s one sort).
+
+    Maps the sorted cut back to logit space as a per-row threshold —
+    ties at the threshold survive, matching the top-k tie semantics."""
+    B, V = lg.shape
+    p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
+    thr = _nucleus_threshold(jnp.sort(lg, axis=-1)[:, ::-1], p)
     keep = (lg >= thr) | (p[:, None] >= 1.0)
     return jnp.where(keep, lg, -1e30)
 
 
-def _top_k_filter(lg, top_k):
-    """Per-row top-k filter on [B, V] logits; `top_k` is an i32 [B]
-    vector (the serving engine's batched path) — k <= 0 means no
-    filter for that row. Sort-based so k can differ per row; tie
-    semantics match the static lax.top_k branch (>= kth survives)."""
-    B, V = lg.shape
-    k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
+def _top_k_top_p_filter(lg, top_k, top_p):
+    """Per-row top-k then top-p filter on [B, V] logits with ONE sort;
+    `top_k` an i32 [B] and `top_p` an f32 [B] vector (the serving
+    engine's batched path). k <= 0 / p >= 1 mean no such filter for
+    that row; ties at either threshold survive (every logit >= the
+    threshold), as in the static lax.top_k / `_top_p_filter` branches.
+
+    The top-p filter's input is the top-k-filtered row. That filter is
+    monotone (`lg >= kth` survives), so the filtered row sorted again
+    equals `where(srt >= kth, srt, -1e30)` of the row sorted once,
+    element for element, and the nucleus threshold comes out as a
+    second sort would give it."""
+    V = lg.shape[-1]
     srt = jnp.sort(lg, axis=-1)[:, ::-1]
     kth = jnp.take_along_axis(
-        srt, (jnp.clip(k, 1, V) - 1)[:, None], axis=-1)
-    keep = (lg >= kth) | (k[:, None] <= 0)
+        srt, (jnp.clip(top_k, 1, V) - 1)[:, None], axis=-1)
+    no_k = top_k[:, None] <= 0
+    lg = jnp.where((lg >= kth) | no_k, lg, -1e30)
+    thr = _nucleus_threshold(
+        jnp.where((srt >= kth) | no_k, srt, -1e30), top_p)
+    keep = (lg >= thr) | (top_p[:, None] >= 1.0)
     return jnp.where(keep, lg, -1e30)
 
 
@@ -91,15 +107,35 @@ def _select_token(logits, do_sample, temperature, top_k, key, top_p=1.0):
         if top_p is not None and float(top_p) < 1.0:
             lg = _top_p_filter(lg, float(top_p))
         return jax.random.categorical(key, lg, axis=-1).astype(jnp.int32)
-    # batched per-row path: params and keys are traced arrays
+    # batched per-row path: params and keys are traced arrays. Only the
+    # argmax is unconditional. The draw, and the filters' sort before
+    # it, sit in branches of one conditional on the operands themselves:
+    # a step whose rows are all greedy sorts nothing and draws nothing,
+    # and temperature-only sampling draws without a sort. The device
+    # runs one branch; the executable is the same whatever the mix.
+    # (One three-way switch and not a conditional inside another: its
+    # branches share their temporaries, the nested form held one more
+    # [B, V] float32 buffer.)
     B = logits.shape[0]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
-    lg = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)[:, None]
-    lg = _top_k_filter(lg, top_k)
-    lg = _top_p_filter(lg, top_p)
-    sampled = jax.vmap(jax.random.categorical)(key, lg).astype(jnp.int32)
-    return jnp.where(jnp.asarray(do_sample, bool), sampled, greedy)
+    samp = jnp.broadcast_to(jnp.asarray(do_sample, bool), (B,))
+    k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
+    p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
+
+    def _draw(filtered):
+        temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
+        lg = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)[:, None]
+        if filtered:
+            lg = _top_k_top_p_filter(lg, k, p)
+        sampled = jax.vmap(jax.random.categorical)(key, lg)
+        return jnp.where(samp, sampled.astype(jnp.int32), greedy)
+
+    # 0: no row samples; 1: some do, none with a filter; 2: some filter
+    filters = samp & ((k > 0) | (p < 1.0))
+    mode = (jnp.any(samp).astype(jnp.int32)
+            + jnp.any(filters).astype(jnp.int32))
+    return jax.lax.switch(mode, (lambda: greedy, lambda: _draw(False),
+                                 lambda: _draw(True)))
 
 
 def make_decoder_fns(model):

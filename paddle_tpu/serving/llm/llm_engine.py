@@ -2445,6 +2445,12 @@ class LLMEngine:
                 toks, pos, adv, ctr, prefill_slots, decode_slots = \
                     self._build_rows_locked(spec_drafts)
                 kinds = self._kinds_of(prefill_slots, decode_slots)
+                # rows of this step that draw: what the step's sampler
+                # branches on (a freed slot is cleared to greedy, so the
+                # active rows are all that can sample)
+                sampled_rows = int(np.count_nonzero(
+                    self.sampling_table.do_sample[
+                        prefill_slots + decode_slots]))
                 # sampling-operand assembly (ISSUE 18) — per-slot params,
                 # RNG-lane counters, DFA states and the grammar bank —
                 # is the host-side cost of constrained/sampled decoding;
@@ -2459,7 +2465,8 @@ class LLMEngine:
                 self.ledger.book("sample_mask", mask_dt)
             with RecordEvent(SPAN_SERVE_DISPATCH,
                              prefill_rows=len(prefill_slots),
-                             decode_rows=len(decode_slots)):
+                             decode_rows=len(decode_slots),
+                             sampled_rows=sampled_rows):
                 t0 = self.clock.now()
                 fn = self._step()
                 args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
@@ -2523,6 +2530,8 @@ class LLMEngine:
                 new_dstate = np.asarray(new_dstate)  # [N] DFA states
             now = self.clock.now()
             with RecordEvent(SPAN_SERVE_COMMIT):
+                if sampled_rows:
+                    self.metrics.on_sampler_filter_step()
                 return self._commit_step(
                     nxt, lps, new_dstate, toks, pos, adv, prefill_slots,
                     decode_slots, spec_drafts, t0, tc0, now)

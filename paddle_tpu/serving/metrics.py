@@ -271,6 +271,7 @@ class LLMMetrics(ServingMetrics):
         self._decode_window: deque = deque(maxlen=self.window)
         self.counters.update({"prefills": 0, "decode_steps": 0,
                               "unified_steps": 0,
+                              "sampler_filter_steps": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
                               "brownout_entries": 0,
                               "prefix_hits": 0, "prefix_misses": 0,
@@ -474,6 +475,14 @@ class LLMMetrics(ServingMetrics):
         with self._lock:
             self.counters["unified_steps"] += 1
 
+    def on_sampler_filter_step(self):
+        """One committed unified step in which at least one active row
+        sampled, so the step's sampler ran its draw for every row (and
+        one vocabulary sort if a sampling row set top-k or top-p).
+        Over `unified_steps`: the share of steps that paid for it."""
+        with self._lock:
+            self.counters["sampler_filter_steps"] += 1
+
     def on_spec_window(self, drafted: int, accepted: int):
         """One verified speculative window (ISSUE 17): `drafted` tokens
         proposed, `accepted` of them kept (the corrective token is not
@@ -664,6 +673,9 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_decode_steps_total", s["decode_steps"])
         b.family(f"{px}_unified_steps_total", "counter")
         b.sample(f"{px}_unified_steps_total", s["unified_steps"])
+        b.family(f"{px}_sampler_filter_steps_total", "counter")
+        b.sample(f"{px}_sampler_filter_steps_total",
+                 s["sampler_filter_steps"])
         b.family(f"{px}_prefills_total", "counter")
         b.sample(f"{px}_prefills_total", s["prefills"])
         # ---- speculative decoding families (ISSUE 17) ----

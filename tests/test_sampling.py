@@ -429,3 +429,258 @@ def test_sampling_metrics_and_lane_export(gpt_tiny):
         assert fam in text, fam
     led = eng.ledger.snapshot()
     assert "sample_mask" in led["phase_seconds"]
+
+
+# ---- the conditional, one-sort sampler (ISSUE 24) ----
+
+def _two_sort_oracle(logits, do_sample, temperature, top_k, key, top_p):
+    """The batched branch of `_select_token` as it stood before ISSUE
+    24, copied: unconditional top-k filter (one full sort), top-p
+    filter (a second full sort of the filtered rows), a draw for every
+    row, and the greedy/sampled choice last."""
+    import jax
+    import jax.numpy as jnp
+    B, V = logits.shape
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
+    lg = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)[:, None]
+    k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
+    srt = jnp.sort(lg, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(
+        srt, (jnp.clip(k, 1, V) - 1)[:, None], axis=-1)
+    lg = jnp.where((lg >= kth) | (k[:, None] <= 0), lg, -1e30)
+    p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
+    srt = jnp.sort(lg, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(srt, axis=-1)
+    cum_before = jnp.cumsum(probs, axis=-1) - probs
+    n_keep = jnp.maximum(jnp.sum(cum_before < p[:, None], axis=-1), 1)
+    thr = jnp.take_along_axis(srt, (n_keep - 1)[:, None], axis=-1)
+    lg = jnp.where((lg >= thr) | (p[:, None] >= 1.0), lg, -1e30)
+    sampled = jax.vmap(jax.random.categorical)(key, lg).astype(jnp.int32)
+    return jnp.where(jnp.asarray(do_sample, bool), sampled, greedy)
+
+
+_B, _V = 12, 97
+
+
+def _tied_logits(seed):
+    """Seeded [B, V] float32 logits on a coarse grid, so every row holds
+    runs of equal values: the k-th largest and the nucleus threshold
+    both fall inside a tie."""
+    rng = np.random.default_rng(seed)
+    return (rng.integers(-6, 7, size=(_B, _V)) * 0.5).astype(np.float32)
+
+
+def _mix(name):
+    """Per-row (do_sample, temperature, top_k, top_p) for a named mix."""
+    samp = np.ones(_B, bool)
+    temp = np.linspace(0.6, 1.4, _B).astype(np.float32)
+    topk = np.zeros(_B, np.int32)
+    topp = np.ones(_B, np.float32)
+    ks = np.array([1, 2, 5, 9, 20, 40, _V, _V + 50, 3, 7, 11, 13], np.int32)
+    ps = np.linspace(0.05, 0.99, _B).astype(np.float32)
+    if name == "all_greedy":
+        samp[:] = False
+        topk, topp = ks, ps         # filters set on rows that never draw
+    elif name == "all_sampled":
+        topk, topp = ks, ps
+    elif name == "temperature_only":
+        pass
+    elif name == "top_k_only":
+        topk = ks
+    elif name == "top_p_only":
+        topp = ps
+    elif name == "both":
+        topk, topp = ks[::-1].copy(), ps
+    elif name == "one_sampled_among_greedy":
+        samp[:] = False
+        samp[5] = True
+        topk, topp = ks, ps
+    elif name == "no_filter_rows":
+        # k <= 0 and p >= 1 rows beside filtered ones, greedy rows between
+        topk = np.array([0, -1, 5, 0, 9, -3, 0, 2, 0, 40, 0, 1], np.int32)
+        topp = np.array([1.0, 1.5, 1.0, 0.5, 1.0, 0.9, 2.0, 1.0, 0.3, 1.0,
+                         1.0, 0.7], np.float32)
+        samp[[3, 8]] = False
+    else:
+        raise AssertionError(name)
+    return samp, temp, topk, topp
+
+
+_MIXES = ("all_greedy", "all_sampled", "temperature_only", "top_k_only",
+          "top_p_only", "both", "one_sampled_among_greedy",
+          "no_filter_rows")
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("mix", _MIXES)
+def test_one_sort_sampler_matches_two_sort_oracle(mix, jitted):
+    """Bit-for-bit tokens against the parent's two-sort formulation, on
+    logits with ties at both thresholds, for every mix of rows."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.generation import _select_token
+    from paddle_tpu.serving.llm.sampling import lane_key
+    samp, temp, topk, topp = _mix(mix)
+    new, old = _select_token, _two_sort_oracle
+    if jitted:
+        new, old = jax.jit(new), jax.jit(old)
+    for seed in (0, 1, 2**31 - 5):
+        logits = jnp.asarray(_tied_logits(seed % 1000))
+        keys = jax.vmap(lambda i: lane_key(seed % 2**31, i))(
+            jnp.arange(_B, dtype=jnp.int32))
+        got = new(logits, jnp.asarray(samp), jnp.asarray(temp),
+                  jnp.asarray(topk), keys, jnp.asarray(topp))
+        want = old(logits, jnp.asarray(samp), jnp.asarray(temp),
+                   jnp.asarray(topk), keys, jnp.asarray(topp))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert got.dtype == jnp.int32
+        if mix == "all_greedy":
+            np.testing.assert_array_equal(
+                np.asarray(got), np.argmax(np.asarray(logits), axis=-1))
+
+
+def test_one_sort_filter_matches_under_a_grammar_mask():
+    """Masked logits (-1e30 before the temperature, so below -1e30 after
+    a temperature under 1) keep the equivalence, also when k exceeds the
+    number of legal tokens."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.generation import _select_token
+    from paddle_tpu.serving.llm.sampling import lane_key
+    samp, temp, topk, topp = _mix("all_sampled")
+    logits = _tied_logits(11)
+    legal = np.zeros((_B, _V), bool)
+    legal[:, 3:11] = True
+    legal[4, :] = False
+    legal[4, 50] = True             # one legal token
+    masked = jnp.asarray(np.where(legal, logits, -1e30).astype(np.float32))
+    keys = jax.vmap(lambda i: lane_key(9, i))(
+        jnp.arange(_B, dtype=jnp.int32))
+    args = (masked, jnp.asarray(samp), jnp.asarray(temp),
+            jnp.asarray(topk), keys, jnp.asarray(topp))
+    got = np.asarray(_select_token(*args))
+    np.testing.assert_array_equal(got, np.asarray(_two_sort_oracle(*args)))
+    assert legal[np.arange(_B), got].all()
+
+
+def _count_eqns(jaxpr, name, inside_cond=False):
+    """(total, outside any cond) count of `name` equations, through
+    every nested jaxpr."""
+    import jax
+    total = top = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            total += 1
+            top += not inside_cond
+        nested_in_cond = inside_cond or eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            t, o = _count_eqns(sub, name, nested_in_cond)
+            total += t
+            top += o
+    return total, top
+
+
+@pytest.mark.parametrize("fn", ["select_tokens", "select_next"])
+def test_sampler_holds_one_sort_and_only_inside_a_cond(fn):
+    """The CPU-checkable form of "an all-greedy step runs no sort": the
+    traced selection holds exactly one `sort`, inside a `cond` branch,
+    where the draw's random bits and the nucleus cumsum sit too."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.llm import sampling
+    N, C, V = 3, 4, 64
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)
+    f32 = lambda *s: jnp.ones(s, jnp.float32)
+    samp = jnp.zeros((N,), bool)
+    if fn == "select_tokens":
+        bank = jnp.zeros((2, 5, V), jnp.int32)
+        jaxpr = jax.make_jaxpr(sampling.select_tokens)(
+            f32(N, C, V), i32(N), f32(N), i32(N), f32(N), samp, i32(N),
+            i32(N), i32(N), i32(N), bank)
+    else:
+        jaxpr = jax.make_jaxpr(sampling.select_next)(
+            f32(N, V), f32(N), i32(N), f32(N), samp, i32(N), i32(N))
+    assert _count_eqns(jaxpr.jaxpr, "sort") == (1, 0)
+    # one three-way conditional: greedy / draw / filter and draw
+    assert _count_eqns(jaxpr.jaxpr, "cond") == (1, 1)
+    for prim in ("cumsum", "random_bits", "div"):
+        total, outside = _count_eqns(jaxpr.jaxpr, prim)
+        assert total > 0 and outside == 0, prim
+    # the argmax every row needs stays outside
+    assert _count_eqns(jaxpr.jaxpr, "argmax")[1] == 1
+
+
+def test_engine_counts_sampling_steps_and_never_recompiles(gpt_tiny):
+    """Greedy, then sampled, then greedy requests run one `jit_step`
+    executable; `sampler_filter_steps` counts the committed steps that
+    held a sampling row (0 before the first) and `sampled_rows` rides
+    the dispatch span."""
+    from paddle_tpu import profiler, serving
+    from paddle_tpu.obs.goodput import RecompileSentinel
+    from paddle_tpu.models.generation import generate
+    from paddle_tpu.profiler import SPAN_SERVE_DISPATCH
+
+    clock = serving.SimClock()
+    # no prefix cache: its copy-on-write executable compiles on the
+    # first repeated prompt, and this test repeats one on purpose
+    eng = _engine(gpt_tiny, clock, enable_prefix_cache=False)
+
+    def dispatches():
+        return [e for e in profiler.get_events()
+                if e["name"] == SPAN_SERVE_DISPATCH]
+
+    profiler.start_profiler()       # the in-memory sink only
+    try:
+        g1 = eng.submit(_PROMPT, max_new_tokens=6)
+        _drain(eng, clock)                      # compiles jit_step
+        warm_steps = eng.unified_steps
+        assert warm_steps > 0
+        assert eng.metrics.snapshot()["sampler_filter_steps"] == 0
+        assert all(e["args"]["sampled_rows"] == 0 for e in dispatches())
+
+        sentinel = RecompileSentinel().install()
+        try:
+            sp = _params(temperature=0.9, top_k=16, top_p=0.95, seed=3)
+            hs = eng.submit(_PROMPT, max_new_tokens=5, sampling=sp)
+            hg = eng.submit(_PROMPT + 1, max_new_tokens=9)   # a batch-mate
+            _drain(eng, clock)
+            mixed_steps = eng.unified_steps - warm_steps
+            ht = eng.submit(_PROMPT, max_new_tokens=4,
+                            sampling=_params(temperature=1.2, seed=8))
+            _drain(eng, clock)
+            temp_steps = eng.unified_steps - warm_steps - mixed_steps
+            g2 = eng.submit(_PROMPT, max_new_tokens=6)
+            _drain(eng, clock)
+        finally:
+            sentinel.uninstall()
+    finally:
+        profiler._SINK.enabled = False
+    eng.stop()
+    assert sentinel.compiles == 0
+    assert eng._step()._cache_size() == 1
+
+    spans = dispatches()
+    assert len(spans) == eng.unified_steps
+    with_rows = [e for e in spans if e["args"]["sampled_rows"] > 0]
+    assert {e["args"]["sampled_rows"] for e in with_rows} == {1}
+    # the sampled request held a row for its prefill step and one step
+    # per further token; the greedy batch-mate outlived it
+    assert len(with_rows) == 5 + temp_steps
+    assert mixed_steps > 5 and temp_steps == 4
+    snap = eng.metrics.snapshot()
+    assert snap["sampler_filter_steps"] == len(with_rows)
+    assert snap["unified_steps"] == eng.unified_steps
+    assert (f"pdtpu_llm_sampler_filter_steps_total {len(with_rows)}"
+            in eng.metrics.render())
+
+    # greedy rows are what one-shot generate() gives, before, beside and
+    # after the sampling rows; the sampled stream differs from greedy
+    want = np.asarray(generate(gpt_tiny, _PROMPT[None, :],
+                               max_new_tokens=6))[0, len(_PROMPT):]
+    np.testing.assert_array_equal(g1.result(0), want)
+    np.testing.assert_array_equal(g2.result(0), want)
+    mate = np.asarray(generate(gpt_tiny, (_PROMPT + 1)[None, :],
+                               max_new_tokens=9))[0, len(_PROMPT):]
+    np.testing.assert_array_equal(hg.result(0), mate)
+    assert hs.result(0).size == 5 and ht.result(0).size == 4
