@@ -182,7 +182,11 @@ class GPTDecoderLayer(Layer):
         if cache is not None:
             if self.use_moe:
                 raise NotImplementedError(
-                    "KV-cache decode is not wired through MoE layers yet")
+                    "KV-cache decode is not wired through this capacity-"
+                    "based MoE layer (it drops tokens by batch "
+                    "composition); the served sparse path is models/"
+                    "llama.py with LlamaConfig(num_experts=...), over "
+                    "nn/layer/moe.py::DroplessMoE")
             h, new_cache = self.self_attn(self.norm1(x), cache=cache,
                                           pos=pos, paged=paged,
                                           adapters=adapters)
@@ -351,7 +355,8 @@ class GPTForCausalLM(Layer):
                  temperature=1.0, top_k=0, eos_token_id=None, seed=0):
         from .generation import generate
         return generate(self, input_ids, max_new_tokens, do_sample,
-                        temperature, top_k, eos_token_id, seed)
+                        temperature, top_k, eos_token_id=eos_token_id,
+                        seed=seed)
 
     # ---- pipeline-parallel segmentation protocol (pp_layers.py:44-76) ----
     def pipe_layer_prefixes(self):

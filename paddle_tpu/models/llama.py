@@ -25,7 +25,8 @@ from ..distributed.meta_parallel.mp_layers import (ColumnParallelLinear,
                                                    RowParallelLinear,
                                                    VocabParallelEmbedding)
 from ..nn import functional as F
-from ..nn.layer.layers import Layer, LayerList
+from ..nn.layer.layers import Layer, LayerList, parameter_dtype
+from ..nn.layer.moe import DroplessMoE
 from ..ops.attention import decode_attention, flash_attention, \
     update_kv_cache
 from ..ops.lora import add_lora_delta
@@ -44,7 +45,18 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     tie_word_embeddings: bool = False
     use_recompute: bool = False
+    # the type the constructor creates every parameter in (no float32 copy
+    # is made first: a model that fills the chip in bf16 must never exist
+    # in float32)
     dtype: str = "float32"
+    # sparse experts (OLMoE): 0 = the dense SwiGLU MLP; otherwise every
+    # layer's FFN is `num_experts` experts of width `intermediate_size`,
+    # `num_experts_per_tok` of them per position (nn/layer/moe.py)
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+    # RMSNorm over the whole q and the whole k projection, before RoPE
+    qk_norm: bool = False
 
     @property
     def head_dim(self):
@@ -123,6 +135,11 @@ class LlamaAttention(Layer):
                                            has_bias=False, gather_output=False)
         self.o_proj = RowParallelLinear(self.num_heads * self.head_dim, h,
                                         has_bias=False, input_is_parallel=True)
+        if config.qk_norm:
+            self.q_norm = RMSNorm(self.num_heads * self.head_dim,
+                                  config.rms_norm_eps)
+            self.k_norm = RMSNorm(self.num_kv_heads * self.head_dim,
+                                  config.rms_norm_eps)
 
     def forward(self, hidden, attn_mask=None, cache=None, pos=None,
                 paged=None, adapters=None):
@@ -147,6 +164,9 @@ class LlamaAttention(Layer):
                                    aidx, ascale)
                 v = add_lora_delta(v, hidden, amap.get("v_proj"),
                                    aidx, ascale)
+        if self.config.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
+        if cache is not None:
             return self._forward_cached(q, k, v, cache, pos, n_rep, hd,
                                         theta, paged=paged,
                                         adapters=adapters)
@@ -251,7 +271,11 @@ class LlamaDecoderLayer(Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.self_attn = LlamaAttention(config)
-        self.mlp = LlamaMLP(config)
+        self.sparse = config.num_experts > 0
+        self.mlp = DroplessMoE(
+            config.hidden_size, config.intermediate_size,
+            config.num_experts, config.num_experts_per_tok,
+            config.norm_topk_prob) if self.sparse else LlamaMLP(config)
         self.input_layernorm = RMSNorm(config.hidden_size,
                                        config.rms_norm_eps)
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
@@ -269,15 +293,18 @@ class LlamaDecoderLayer(Layer):
         return residual + h
 
     def forward(self, hidden, cache=None, pos=None, paged=None,
-                adapters=None):
+                adapters=None, live=None):
         if cache is not None:
             residual = hidden
             h, new_cache = self.self_attn(self.input_layernorm(hidden),
                                           cache=cache, pos=pos,
                                           paged=paged, adapters=adapters)
             hidden = residual + h
-            hidden = hidden + self.mlp(
-                self.post_attention_layernorm(hidden), adapters=adapters)
+            h = self.post_attention_layernorm(hidden)
+            # a dense MLP computes padding too and nobody reads it; a
+            # router would send it to experts, so it is told what is live
+            hidden = hidden + (self.mlp(h, live=live) if self.sparse
+                               else self.mlp(h, adapters=adapters))
             return hidden, new_cache
         if self._use_recompute and self.training:
             from ..distributed.fleet.utils.recompute import recompute
@@ -299,12 +326,22 @@ class LlamaModel(Layer):
                 adapters=None):
         hidden = self.embed_tokens(input_ids)
         if caches is not None:
+            live = None
+            if paged is not None and self.config.num_experts:
+                # column t of row b is a real token while pos[b] + t is
+                # short of the row's length after this step (`paged[1]`):
+                # the rest of a decode row, and all of a free slot, is
+                # padding
+                t = jnp.arange(input_ids.shape[1], dtype=jnp.int32)
+                live = jnp.reshape(getattr(pos, "data", pos), (-1, 1)) + t \
+                    < jnp.reshape(paged[1], (-1, 1))
             new_caches = []
             for i, (layer, cache) in enumerate(zip(self.layers, caches)):
                 layer_ad = None if adapters is None else (
                     adapters[0][i], adapters[1], adapters[2])
                 hidden, nc = layer(hidden, cache=cache, pos=pos,
-                                   paged=paged, adapters=layer_ad)
+                                   paged=paged, adapters=layer_ad,
+                                   live=live)
                 new_caches.append(nc)
             return self.norm(hidden), new_caches
         for layer in self.layers:
@@ -316,17 +353,26 @@ class LlamaForCausalLM(Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
-        self.llama = LlamaModel(config)
-        # gather_output=False: under explicit TP the vocab-sharded logits
-        # feed ParallelCrossEntropy's sharded softmax-CE directly (Megatron
-        # pairing; mp_layers.py:249). The GSPMD path ignores the flag.
-        self.lm_head = ColumnParallelLinear(config.hidden_size,
-                                            config.vocab_size,
-                                            has_bias=False,
-                                            gather_output=False)
+        with parameter_dtype(config.dtype):
+            self.llama = LlamaModel(config)
+            # gather_output=False: under explicit TP the vocab-sharded
+            # logits feed ParallelCrossEntropy's sharded softmax-CE directly
+            # (Megatron pairing; mp_layers.py:249). The GSPMD path ignores
+            # the flag.
+            self.lm_head = ColumnParallelLinear(config.hidden_size,
+                                                config.vocab_size,
+                                                has_bias=False,
+                                                gather_output=False)
         self.loss_fn = ParallelCrossEntropy()
 
     def forward(self, input_ids, labels=None):
+        if labels is not None and self.config.num_experts:
+            raise NotImplementedError(
+                "training a sparse-expert LlamaConfig is not wired yet: the "
+                "loss would lack the router's load-balancing and z losses "
+                "(ROADMAP B1, the train half: those losses, gradients "
+                "against benchmark/reference/olmoe.py, the dropless layer "
+                "under `ep`)")
         hidden = self.llama(input_ids)
         logits = self.lm_head(hidden)
         if labels is not None:
@@ -354,7 +400,8 @@ class LlamaForCausalLM(Layer):
                  temperature=1.0, top_k=0, eos_token_id=None, seed=0):
         from .generation import generate
         return generate(self, input_ids, max_new_tokens, do_sample,
-                        temperature, top_k, eos_token_id, seed)
+                        temperature, top_k, eos_token_id=eos_token_id,
+                        seed=seed)
 
     # ---- pipeline-parallel segmentation protocol ----
     # (the LayerDesc/SharedLayerDesc contract of reference pp_layers.py:44-76,

@@ -47,14 +47,32 @@ class ParamAttr:
         raise TypeError(f"cannot convert {attr!r} to ParamAttr")
 
 
+# the type a Layer creates its parameters in when its constructor names
+# none; `parameter_dtype` sets it for the layers built inside its block
+_PARAMETER_DTYPE = ["float32"]
+
+
+@contextlib.contextmanager
+def parameter_dtype(dtype):
+    """Layers constructed inside the block create their parameters in
+    `dtype` from the start (a model that only fits the device in bf16 is
+    never materialised in float32 and cast)."""
+    outer = _PARAMETER_DTYPE[0]
+    _PARAMETER_DTYPE[0] = dtypes.dtype_name(dtypes.convert_dtype(dtype))
+    try:
+        yield
+    finally:
+        _PARAMETER_DTYPE[0] = outer
+
+
 class Layer:
-    def __init__(self, name_scope=None, dtype="float32"):
+    def __init__(self, name_scope=None, dtype=None):
         object.__setattr__(self, "_parameters", OrderedDict())
         object.__setattr__(self, "_buffers", OrderedDict())
         object.__setattr__(self, "_sub_layers", OrderedDict())
         self._non_persistable_buffer_names = set()
         self.training = True
-        self._dtype = dtype
+        self._dtype = dtype or _PARAMETER_DTYPE[0]
         self._name_scope = name_scope or self.__class__.__name__.lower()
         self._forward_pre_hooks: "OrderedDict[int, Callable]" = OrderedDict()
         self._forward_post_hooks: "OrderedDict[int, Callable]" = OrderedDict()

@@ -10,18 +10,56 @@ this is parity-plus, designed GSPMD-first (Switch/GLaM pattern):
   einsum dispatch) so XLA sees fixed shapes and inserts the all_to_all when the
   token→expert einsum crosses the ep sharding;
 - the load-balancing auxiliary loss (Switch eq. 4) is returned alongside.
+
+That capacity path (`moe_forward`, `MoELayer`) is what GPT training uses.
+Serving uses the dropless path below (`moe_dropless_forward`,
+`DroplessMoE`): capacity drops tokens, and which token is dropped depends
+on its batchmates, so a request's stream would depend on who shares its
+step; and the `[T, E, C]` one-hot tensors do not fit at a serving shape.
+The dropless path sorts the assignments of the live positions by expert
+and runs the experts as grouped matmuls (`ops/grouped_matmul.py`) over the
+same `[E, ...]` stacked weights and `ep` partition spec.
 """
 from __future__ import annotations
+
+import collections
+import contextlib
+import threading
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...core.tensor import apply
+from ...ops.grouped_matmul import grouped_matmul
 from .. import initializer as I
 from .layers import Layer
 
 EXPERT_AXIS = "ep"
+
+# (layer, expert) -> live assignments routed there, over every dropless
+# engine of this process (`LLMEngine.moe_expert_tokens()` adds what is new
+# since it last did; so does `stop()`). What `ops.pallas_mode.KERNEL_TRACES`
+# is to kernel paths: always on, and what a benchmark reads after the job,
+# when the engine is gone.
+EXPERT_TOKENS: collections.Counter = collections.Counter()
+_collecting = threading.local()
+
+
+@contextlib.contextmanager
+def collect_expert_counts():
+    """Inside the block, every `DroplessMoE` traced on this thread appends
+    its `[E]` int32 counts of live assignments to the list this yields, in
+    call order: how a jitted step gets the counts out of a model whose
+    forward returns logits and caches only. Outside such a block the
+    counts are dropped."""
+    sink = []
+    outer = getattr(_collecting, "sink", None)
+    _collecting.sink = sink
+    try:
+        yield sink
+    finally:
+        _collecting.sink = outer
 
 
 def _top_k_dispatch(gates, capacity, top_k):
@@ -182,3 +220,96 @@ class MoELayer(Layer):
                          self.b2)
         self.aux_loss = aux
         return out
+
+
+def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
+                         norm_topk_prob=False, live=None):
+    """Dropless top-k SwiGLU experts over arrays. x `[..., H]`; router_w
+    `[H, E]`; w_gate, w_up `[E, H, F]`; w_down `[E, F, H]`; `live` a bool
+    mask over x's leading axes (None: every position is live). Returns
+    (out like x, counts `[E]` int32 of live assignments per expert).
+
+        p = softmax_float32(x router_w);  S = the top_k largest p
+        out = sum_{e in S} p_e (silu(x Wg_e) * (x Wu_e)) Wd_e
+
+    with p renormalised over S if `norm_topk_prob`. No capacity: every
+    live position reaches all its `top_k` experts. A position that is not
+    live (the padding of a decode row, a free slot) is routed to no expert,
+    takes no expert row, counts nowhere, and gets zeros.
+
+    How: the `T * top_k` assignments are sorted by expert (those of
+    positions that are not live sort last, past every group), the rows
+    gathered in that order, three grouped matmuls run over them, and each
+    position's `top_k` results are gathered back and summed in float32 in
+    its own top-k order. Nothing a position receives depends on which
+    other positions are in the batch: a row of a grouped matmul depends on
+    that row and its expert's weights alone, and the sum is over a
+    position's own results in an order fixed by its own routing (a
+    scatter-add would sum in the order of the sorted batch).
+    """
+    E = router_w.shape[1]
+    lead, H = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, H)
+    T = xt.shape[0]
+    logits = xt.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if live is not None:
+        idx = jnp.where(live.reshape(T, 1), idx, E)    # E: no expert
+    expert = idx.reshape(-1)                             # [T * top_k]
+    counts = jnp.sum(expert[:, None] == jnp.arange(E, dtype=expert.dtype),
+                     axis=0, dtype=jnp.int32)
+    order = jnp.argsort(expert, stable=True)
+    xs = xt[order // top_k]
+    act = jax.nn.silu(grouped_matmul(xs, w_gate, counts)) \
+        * grouped_matmul(xs, w_up, counts)
+    ys = grouped_matmul(act, w_down, counts)
+    # back to [T, top_k, H]: assignment a sits at sorted row place[a]
+    place = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = ys[place].reshape(T, top_k, H).astype(jnp.float32)
+    keep = (idx < E)[..., None]        # rows of no group are unspecified
+    out = jnp.sum(jnp.where(keep, y * w[..., None], 0.0), axis=1)
+    return out.astype(x.dtype).reshape(*lead, H), counts
+
+
+class DroplessMoE(Layer):
+    """A sparse SwiGLU FFN: a router and `num_experts` experts of width
+    `d_hidden`, `top_k` per position, no shared expert, no bias
+    (`moe_dropless_forward`). `forward(x, live=None)`; under
+    `collect_expert_counts()` each call also hands over its per-expert
+    counts of live assignments."""
+
+    def __init__(self, d_model, d_hidden, num_experts, top_k,
+                 norm_topk_prob=False):
+        super().__init__()
+        if not 0 < top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.norm_topk_prob = norm_topk_prob
+        init = I.Normal(0.0, 0.02)
+        self.router_weight = self.create_parameter(
+            [d_model, num_experts], default_initializer=init)
+        self.w_gate = self.create_parameter(
+            [num_experts, d_model, d_hidden], default_initializer=init)
+        self.w_up = self.create_parameter(
+            [num_experts, d_model, d_hidden], default_initializer=init)
+        self.w_down = self.create_parameter(
+            [num_experts, d_hidden, d_model], default_initializer=init)
+        for p in (self.w_gate, self.w_up, self.w_down):
+            p.partition_spec = P(EXPERT_AXIS)
+
+    def forward(self, x, live=None):
+        top_k, norm = self.top_k, self.norm_topk_prob
+
+        def f(xa, rw, wg, wu, wd):
+            out, counts = moe_dropless_forward(xa, rw, wg, wu, wd, top_k,
+                                               norm, live)
+            sink = getattr(_collecting, "sink", None)
+            if sink is not None:
+                sink.append(counts)
+            return out
+
+        return apply(f, x, self.router_weight, self.w_gate, self.w_up,
+                     self.w_down)

@@ -41,7 +41,8 @@ SPAN_SERVE_EVICT = "pdtpu/serve/evict"            # one evict_for_pressure
 SPAN_SERVE_DRAFT = "pdtpu/serve/draft"            # _draft_phase (if armed)
 SPAN_SERVE_BUILD_ROWS = "pdtpu/serve/build_rows"  # rows, kinds, operands
 SPAN_SERVE_DISPATCH = "pdtpu/serve/dispatch"      # upload + launch;
-#                                                   prefill_rows=, decode_rows=
+#                                                   prefill_rows=, decode_rows=,
+#                                                   sampled_rows=, live_tokens=
 SPAN_SERVE_FETCH = "pdtpu/serve/fetch"            # host waits for the device
 SPAN_SERVE_COMMIT = "pdtpu/serve/commit"          # acceptance .. retire
 SPAN_SERVE_PUBLISH = "pdtpu/serve/publish"        # gauges after the step

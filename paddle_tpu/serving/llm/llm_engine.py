@@ -124,6 +124,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...nn.layer import moe
 from ...obs.flight_recorder import flight_recorder
 from ...obs.trace import RequestTrace, TimelineStore, new_request_id
 from ...profiler import (SPAN_SERVE_ADMIT, SPAN_SERVE_BUILD_ROWS,
@@ -624,6 +625,25 @@ class LLMEngine:
         self._brownout = False
         self._thread: Optional[threading.Thread] = None
         self._step_jit = None        # the ONE unified step executable
+        # sparse experts: the model's dropless expert layers, found by
+        # their type (nothing here knows which model holds them). Their
+        # per-layer per-expert totals of live assignments `[L, E]` live on
+        # the device and ride the step as one more operand and result; a
+        # dense model has neither and its step is the step it always was
+        experts = [layer for layer in model.sublayers()
+                   if isinstance(layer, moe.DroplessMoE)]
+        self._moe_totals = None
+        if experts:
+            if len({m.num_experts for m in experts}) != 1:
+                raise ValueError("expert layers of different sizes cannot "
+                                 "share one [layers, experts] table")
+            self._moe_totals = jnp.zeros(
+                (len(experts), experts[0].num_experts), jnp.int32)
+            # assignments one live token makes on its way through the model
+            self._moe_per_token = sum(m.top_k for m in experts)
+            self._moe_published = np.zeros(self._moe_totals.shape, np.int64)
+            self._moe_publish_lock = threading.Lock()
+            self.metrics.moe_source = self.moe_expert_tokens
         self.decode_iterations = 0   # lifetime steps carrying >=1 decode row
         self.prefill_dispatches = 0  # lifetime steps carrying ONLY prefill
         #                              rows — near-zero under mixed load,
@@ -725,7 +745,7 @@ class LLMEngine:
 
             def step(params, toks, pos, adv, table, slabs, temp, topk,
                      topp, samp, seed, ctr, dstate, gid, bank,
-                     adapters=None):
+                     adapters=None, moe_totals=None):
                 # `adapters` (ISSUE 20) is the AdapterBank's stacked LoRA
                 # operand — (per-layer A/B banks, per-slot adapter_idx,
                 # per-row scale). An unarmed engine never passes it, so
@@ -734,8 +754,10 @@ class LLMEngine:
                 # as adapters load/swap — zero recompiles either way.
                 seq_lens = (pos + adv).astype(jnp.int32)
                 paged = (table, seq_lens, block_len, pages_per_row)
-                logits, new_slabs = prefill(params, toks, slabs, pos,
-                                            paged=paged, adapters=adapters)
+                with moe.collect_expert_counts() as expert_counts:
+                    logits, new_slabs = prefill(params, toks, slabs, pos,
+                                                paged=paged,
+                                                adapters=adapters)
                 sel, new_state = select_tokens(
                     logits, adv, temp, topk, topp, samp, seed, ctr,
                     dstate, gid, bank)
@@ -750,7 +772,12 @@ class LLMEngine:
                 lp = jnp.take_along_axis(
                     jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
                     sel[..., None].astype(jnp.int32), axis=-1)[..., 0]
-                return sel, lp, new_state, new_slabs
+                if moe_totals is None:
+                    return sel, lp, new_state, new_slabs
+                # a sparse model (the only kind that is handed totals):
+                # this step's live assignments per layer and expert
+                return (sel, lp, new_state, new_slabs,
+                        moe_totals + jnp.stack(expert_counts))
 
             step.__name__ = step.__qualname__ = UNIFIED_STEP_NAME
             self._step_jit = jax.jit(step)
@@ -776,6 +803,16 @@ class LLMEngine:
         if self.adapter_bank is None:
             return ()
         return (self.adapter_bank.device_args(),)
+
+    def _tail_args_locked(self):
+        """The step's operands after the sampling ones: the adapter
+        operand of an armed engine, then, for a sparse model, the running
+        `[L, E]` totals (behind an `adapters` of None where no bank is
+        armed: None is no operand). () for a dense, unarmed engine."""
+        tail = self._adapter_args_locked()
+        if self._moe_totals is not None:
+            tail = (tail or (None,)) + (self._moe_totals,)
+        return tail
 
     def _draft_step(self):
         """Draft-pool analogue of `_step` (ISSUE 17): the chunk-wide
@@ -992,6 +1029,7 @@ class LLMEngine:
                 self.metrics.set_slots(0, self.pool.num_slots)
             self._stopped = True
             self._cond.notify_all()
+        self.moe_expert_tokens()    # leave the totals where they outlive us
         flight_recorder().record("drain_end", engine="llm",
                                  stranded=stranded)
 
@@ -1777,6 +1815,25 @@ class LLMEngine:
         counts only those with a decode row)."""
         return self.decode_iterations + self.prefill_dispatches
 
+    def moe_expert_tokens(self) -> Optional[np.ndarray]:
+        """`[layers, experts]` lifetime totals of live (position, expert)
+        assignments, fetched from the device now (it waits for the step in
+        flight, so: on /metrics, at `stop()` and on demand, never inside a
+        step); None for a dense model. Every row sums to live tokens x
+        experts per token, and the whole to `counters["moe_assignments"]`.
+        What is new since the last call is also added to the process-wide
+        `nn.layer.moe.EXPERT_TOKENS`."""
+        if self._moe_totals is None:
+            return None
+        with self._moe_publish_lock:
+            totals = np.asarray(self._moe_totals, np.int64)
+            fresh = totals - self._moe_published
+            self._moe_published = totals
+            for layer, expert in zip(*np.nonzero(fresh)):
+                moe.EXPERT_TOKENS[(int(layer), int(expert))] += \
+                    int(fresh[layer, expert])
+        return totals
+
     def pump(self) -> int:
         """One scheduler pass: drop expired queued requests, admit queued
         requests into free slots (bookkeeping only — no dispatch), then
@@ -2459,14 +2516,19 @@ class LLMEngine:
                 ts0 = self.clock.now()
                 sargs = self._sampling_args_locked(ctr)
                 mask_dt = self.clock.now() - ts0
-                aargs = self._adapter_args_locked()
+                aargs = self._tail_args_locked()
             self.metrics.on_mask_overhead(mask_dt * 1e3)
             if self.ledger is not None:
                 self.ledger.book("sample_mask", mask_dt)
+            # positions of this step that hold a real token: all a dense
+            # model wastes on the rest is arithmetic, a sparse one must
+            # keep them out of its experts
+            live_tokens = int(adv.sum())
             with RecordEvent(SPAN_SERVE_DISPATCH,
                              prefill_rows=len(prefill_slots),
                              decode_rows=len(decode_slots),
-                             sampled_rows=sampled_rows):
+                             sampled_rows=sampled_rows,
+                             live_tokens=live_tokens):
                 t0 = self.clock.now()
                 fn = self._step()
                 args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
@@ -2491,7 +2553,7 @@ class LLMEngine:
                         # wall time stays in the host phase.
                         tc0 = self.clock.now()
                     try:
-                        nxt, lps, new_dstate, new_slabs = \
+                        nxt, lps, new_dstate, new_slabs, *moe_out = \
                             self._run_dispatch(kinds, fn, args)
                     except DispatchFailedError as e:
                         last_err = e
@@ -2509,6 +2571,12 @@ class LLMEngine:
                             attempt + 1, attempts, e)
                         continue
                     self.pool.slabs = new_slabs
+                    if moe_out:
+                        # committed with the step, like the slabs: a failed
+                        # attempt or a blame probe counts nowhere
+                        self._moe_totals, = moe_out
+                        self.metrics.on_moe_assignments(
+                            live_tokens * self._moe_per_token)
                     if decode_slots:
                         # the breaker tracks ENGINE-level (decode-protocol)
                         # failures; prefill-only successes must not launder
@@ -2768,7 +2836,7 @@ class LLMEngine:
                 # the REAL adapter operands, so an adapter-scoped fault
                 # reproduces in isolation too
                 sargs = self._sampling_args_locked(solo_ctr)
-                aargs = self._adapter_args_locked()
+                aargs = self._tail_args_locked()
             args = (self.params, jnp.asarray(solo_toks),
                     jnp.asarray(solo_pos), jnp.asarray(solo_adv),
                     self.pool.device_block_table(),

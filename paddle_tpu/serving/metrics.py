@@ -272,6 +272,7 @@ class LLMMetrics(ServingMetrics):
         self.counters.update({"prefills": 0, "decode_steps": 0,
                               "unified_steps": 0,
                               "sampler_filter_steps": 0,
+                              "moe_assignments": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
                               "brownout_entries": 0,
                               "prefix_hits": 0, "prefix_misses": 0,
@@ -327,6 +328,11 @@ class LLMMetrics(ServingMetrics):
         # HostKVPool's snapshot() each pump; None until a tiered engine
         # reports, so a device-only engine renders no host families
         self.host_kv: Optional[Dict[str, int]] = None
+        # sparse experts: a sparse engine sets this to its
+        # `moe_expert_tokens` (the `[L, E]` totals live on the device and
+        # are fetched when /metrics is rendered, never in a step); None,
+        # and no expert family, for a dense model
+        self.moe_source = None
         # multi-LoRA serving (ISSUE 18/20): emitted tokens per adapter id
         # ("base" for row-0 streams) — on an armed engine every emission
         # lands in exactly one bucket, so these sum to tokens_out
@@ -482,6 +488,14 @@ class LLMMetrics(ServingMetrics):
         Over `unified_steps`: the share of steps that paid for it."""
         with self._lock:
             self.counters["sampler_filter_steps"] += 1
+
+    def on_moe_assignments(self, n: int):
+        """One committed unified step of a sparse model routed `n`
+        (position, expert) pairs: its live tokens x experts per token x
+        expert layers. Nothing is dropped, so the device's per-expert
+        totals (`LLMEngine.moe_expert_tokens()`) sum to this."""
+        with self._lock:
+            self.counters["moe_assignments"] += int(n)
 
     def on_spec_window(self, drafted: int, accepted: int):
         """One verified speculative window (ISSUE 17): `drafted` tokens
@@ -678,6 +692,14 @@ class LLMMetrics(ServingMetrics):
                  s["sampler_filter_steps"])
         b.family(f"{px}_prefills_total", "counter")
         b.sample(f"{px}_prefills_total", s["prefills"])
+        if self.moe_source is not None:
+            b.family(f"{px}_moe_assignments_total", "counter")
+            b.sample(f"{px}_moe_assignments_total", s["moe_assignments"])
+            b.family(f"{px}_moe_expert_tokens_total", "counter")
+            for layer, row in enumerate(self.moe_source()):
+                for expert, n in enumerate(row):
+                    b.sample(f"{px}_moe_expert_tokens_total", int(n),
+                             {"layer": layer, "expert": expert})
         # ---- speculative decoding families (ISSUE 17) ----
         b.family(f"{px}_spec_windows_total", "counter")
         b.sample(f"{px}_spec_windows_total", s["spec_windows"])

@@ -112,6 +112,14 @@ def _site_owner(layer, name, arch):
                  if name in ("q_proj", "k_proj", "v_proj", "o_proj")
                  else layer.mlp)
     if not hasattr(owner, name):
+        from ..nn.layer.moe import DroplessMoE
+        if isinstance(owner, DroplessMoE):
+            raise ValueError(
+                f"LoRA target {name!r}: this model's FFN is a sparse-expert "
+                "layer, and adapters on expert projections are not "
+                "supported (stacked [E, in, out] weights, a row per "
+                "assignment); name attention targets only, e.g. "
+                'targets=("q_proj", "k_proj", "v_proj", "o_proj")')
         raise ValueError(f"unknown LoRA target {name!r} for arch {arch!r}")
     return owner
 

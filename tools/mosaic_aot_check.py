@@ -116,6 +116,16 @@ def main() -> int:
                 spec((N, L // bl), jnp.int32), spec((N,), jnp.int32),
                 spec((N,), jnp.int32), want={"paged_attention": 1}))
 
+    # the grouped matmul of the dropless expert layer at OLMoE's widths and
+    # the decode cell's rows (2,048 positions x 8 experts each)
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul
+    for k, n in ((2048, 1024), (1024, 2048)):   # gate / up, down
+        results.append(compile_case(
+            f"moe_gmm bf16 [16384,{k}] x [64,{k},{n}]",
+            lambda lhs, rhs, gs: grouped_matmul(lhs, rhs, gs, impl="pallas"),
+            spec((16384, k), jnp.bfloat16), spec((64, k, n), jnp.bfloat16),
+            spec((64,), jnp.int32), want={"moe_gmm": 1}))
+
     # four chips: a sharded pallas_call is refused by JAX outright; under
     # spmd_mesh the kernels run as a shard_map island and compile
     mesh4 = Mesh(np.array(dev4).reshape(2, 2), ("data", "model"))
