@@ -56,13 +56,17 @@ FULL = dict(
     # 2,048 positions (init_cache refuses a slab longer than the learned
     # position table, so "8 x 2,048" is 2,032 addressable tokens per slot)
     slots=8, n_blocks=127, prompts=(12, 200, 700, 1500), max_new=32,
-    paged=dict(heads=16, kv_heads=16, head_dim=128, cache_len=2048),
+    # the serve cells' head layouts and slot geometry: Mistral (GQA 32/8)
+    # and OLMoE (MHA 16/16), 14 pages of 16 a slot + a chunk of write-padding
+    paged=(dict(heads=32, kv_heads=8, head_dim=128, pages=14),
+           dict(heads=16, kv_heads=16, head_dim=128, pages=14)),
 )
 TINY = dict(
     preset="gpt2-tiny", batch_per_chip=2, seq=128, scan_steps=2,
     flash_shape=(1, 2, 512, 64),
     slots=4, n_blocks=31, prompts=(12, 40, 100, 200), max_new=8,
-    paged=dict(heads=2, kv_heads=2, head_dim=64, cache_len=128),
+    paged=(dict(heads=4, kv_heads=2, head_dim=64, pages=4),
+           dict(heads=2, kv_heads=2, head_dim=64, pages=4)),
 )
 
 
@@ -390,39 +394,47 @@ def leg_train(size: dict, rehearsal: bool, layout: str = "") -> dict:
 
 def _paged_parity(size: dict):
     """`ragged_paged_attention(impl="pallas")` against `impl="scan"` on this
-    device, bf16, ragged seq_lens, block_len 16, query widths 1 and 16.
-    The two run the same per-block op sequence on the same bf16 inputs, so
-    the tolerance is tighter than flash-vs-reference: what remains is the
-    order of the fp32 accumulation inside one MXU dot vs one XLA einsum
-    and one bf16 rounding of the output (|o| <~ 4 -> 8e-3). 2e-2."""
+    device at each head layout of `size["paged"]`, bf16, ragged seq_lens,
+    block_len 16, query widths 1 and 16, slabs with write-padding past the
+    page region. The two run the same per-block op sequence on the same
+    bf16 inputs, so the tolerance is tighter than flash-vs-reference: what
+    remains is the order of the fp32 accumulation inside one MXU dot vs
+    one XLA einsum and one bf16 rounding of the output (|o| <~ 4 -> 8e-3).
+    2e-2. Prints the grid and tile the kernel chose for each shape."""
     import jax.numpy as jnp
     import numpy as np
 
+    from paddle_tpu.ops import pallas_mode
     from paddle_tpu.ops.paged_attention import ragged_paged_attention
-    g = size["paged"]
-    H, Hkv, D, L = g["heads"], g["kv_heads"], g["head_dim"], g["cache_len"]
-    N, bl = 4, 16
-    nb = L // bl
+    N, bl, tol = 8, 16, 2e-2
     rng = np.random.RandomState(1)
-    k = jnp.asarray(rng.randn(N, Hkv, L, D), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(N, Hkv, L, D), jnp.bfloat16)
-    # each row's pages live in another row's slab: a real indirection
-    table = ((np.arange(N)[:, None] + 1) % N * nb
-             + np.arange(nb)[None, :]).astype(np.int32)
-    tol = 2e-2
-    for Tq in (1, 16):
-        q = jnp.asarray(rng.randn(N, H, Tq, D), jnp.bfloat16)
-        q_pos = np.array([0, L // 7, L // 2 + 3, L - Tq], np.int32)
-        lens = q_pos + Tq
-        outs = {impl: ragged_paged_attention(
-            q, k, v, table, lens, q_pos, block_len=bl, pages_per_row=nb,
-            impl=impl) for impl in ("pallas", "scan")}
-        err = _max_err(outs["pallas"], outs["scan"])
-        _say(f"paged pallas vs scan H={H} Hkv={Hkv} D={D} block_len={bl} "
-             f"Tq={Tq} seq_lens={lens.tolist()} bf16: max abs err "
-             f"{err:.2e} (tolerance {tol:g})")
-        _require(np.isfinite(err) and err <= tol,
-                 f"paged Tq={Tq} within {tol:g}")
+    for g in size["paged"]:
+        H, Hkv, D, nb = g["heads"], g["kv_heads"], g["head_dim"], g["pages"]
+        L = nb * bl
+        k = jnp.asarray(rng.randn(N, Hkv, L + bl, D), jnp.bfloat16)
+        v = jnp.asarray(rng.randn(N, Hkv, L + bl, D), jnp.bfloat16)
+        # each row's pages live in another row's slab: a real indirection
+        table = ((np.arange(N)[:, None] + 1) % N * nb
+                 + np.arange(nb)[None, :]).astype(np.int32)
+        for Tq in (1, 16):
+            q = jnp.asarray(rng.randn(N, H, Tq, D), jnp.bfloat16)
+            q_pos = np.array([0, L // 7, L // 2 + 3, L - Tq, 1, bl - 1, bl,
+                              L // 3], np.int32)
+            lens = q_pos + Tq
+            pallas_mode.KERNEL_TILINGS.clear()
+            outs = {impl: ragged_paged_attention(
+                q, k, v, table, lens, q_pos, block_len=bl, pages_per_row=nb,
+                impl=impl) for impl in ("pallas", "scan")}
+            (_, tiling), = pallas_mode.KERNEL_TILINGS
+            tiling = dict(tiling)
+            err = _max_err(outs["pallas"], outs["scan"])
+            _say(f"paged pallas vs scan H={H} Hkv={Hkv} D={D} block_len={bl} "
+                 f"Tq={Tq} seq_lens={lens.tolist()} bf16: grid "
+                 f"{tiling['grid']} (G={tiling['grid'][1]}), tile "
+                 f"{tiling['heads']} KV heads x {tiling['rows']} rows; max "
+                 f"abs err {err:.2e} (tolerance {tol:g})")
+            _require(np.isfinite(err) and err <= tol,
+                     f"paged H={H}/{Hkv} Tq={Tq} within {tol:g}")
 
 
 def _post(port: int, path: str, payload: dict, timeout: float):

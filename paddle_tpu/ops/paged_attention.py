@@ -22,7 +22,10 @@ Layout contract — shared with `SlotPagedKVPool`:
     q_pos            [B] int32: absolute position of q's first token in
                      row b; causal mask is col <= q_pos[b] + t
 
-Two implementations with the SAME per-block online-softmax op sequence:
+Two implementations with the SAME per-block online-softmax op sequence;
+both read the slabs as stored (a page is `block_len` columns of one slab
+row, for every KV head at once), so nothing slices, transposes or copies
+the pool on the way in:
 
 - `_scan_impl` — plain XLA `lax.scan` over logical blocks. What `impl=None`
   picks on the CPU: interpret-mode Pallas unrolls every grid cell into the
@@ -30,15 +33,23 @@ Two implementations with the SAME per-block online-softmax op sequence:
   once and runs the identical arithmetic. Also the parity reference the
   kernel is checked against on the chip (chip_smoke.py).
 - `_pallas_impl` — the TPU kernel, what `impl=None` picks on a TPU: grid
-  (B, H, n_blocks) with the block table / lengths / positions
-  scalar-prefetched so the index_map fetches only the pages a row actually
-  occupies, and `@pl.when` skips compute for blocks past the row's length
-  ("only over occupied KV blocks"). Mosaic (jax 0.9.0 / libtpu 0.0.34, v5e)
-  lowers it at `block_len` 8, 16, 32 and 128, in bf16 and fp32, at query
-  widths 1 and 16 — including the 8-row bf16 KV tile (half a packed
-  sublane tile) of `DEFAULT_KV_BLOCK` and the 1-row q tile of the
-  `generate()` decode loop; tests/test_mosaic_aot.py pins 8 and 16 in
-  bf16, the two sizes the repo runs.
+  (B, G, n_blocks), one step per (slot, head group, page). A step's K and V
+  tiles are `[heads, block_len, D]`, cut from the slab by the index_map; a
+  KV head's `n_rep` query heads are folded into the rows of its q tile
+  (`q` viewed as `[B, Hkv, n_rep*Tq, D]`: row r is token `r mod Tq`), so a
+  GQA group reads its page once. The block table / lengths / positions are
+  scalar-prefetched: the index_map fetches only the pages a row occupies
+  (a page past its length names the last live one again, which fetches
+  nothing) and `@pl.when` skips their compute ("only over occupied KV
+  blocks"). `_choose_tile` takes the tile from the shapes and one VMEM
+  budget: every head in one tile (G = 1) at the engine's shapes, fewer KV
+  heads or a part of one GQA group for a whole-prompt prefill of hundreds
+  of rows. `pallas_mode.KERNEL_TILINGS` records the choice of each trace.
+  Mosaic (jax 0.9.0 / libtpu 0.0.34, v5e) lowers it in bf16 at `block_len`
+  8 and 16 (the two sizes the repo runs), query widths 1 to 2,048, MHA and
+  GQA — including the 8-row bf16 KV tile (half a packed sublane tile) of
+  `DEFAULT_KV_BLOCK` and the 1-row q tile of the MHA `generate()` decode
+  loop; tests/test_mosaic_aot.py pins those and the serve cells' shapes.
 
 Numerics: flash-style online softmax with the repo's exact-zero masking
 convention (ops/attention.py `_fwd_kernel`): masked scores sit at
@@ -74,30 +85,52 @@ from .attention import _GRID_SEMANTICS, _NEG_INF, _dot
 DEFAULT_KV_BLOCK = 8
 
 
-def _as_pages(cache, block_len: int, pages_per_row: int):
-    """[N, Hkv, L_slab, D] slab -> [N*pages_per_row, Hkv, block_len, D]
-    pages. Columns past pages_per_row*block_len (slab write-padding for
-    chunked prefill's fixed-width stripes) are never addressable by a
-    block table and are sliced off here."""
-    N, Hkv, L, D = cache.shape
-    need = pages_per_row * block_len
-    if L < need:
-        raise ValueError(
-            f"cache length {L} cannot back {pages_per_row} pages of "
-            f"{block_len} tokens")
-    pages = cache[:, :, :need, :].reshape(N, Hkv, pages_per_row, block_len,
-                                          D)
-    return jnp.transpose(pages, (0, 2, 1, 3, 4)).reshape(
-        N * pages_per_row, Hkv, block_len, D)
+# What one grid step of the kernel may hold in VMEM: its q, K, V and output
+# tiles (double buffered by the pipeline), the fp32 online-softmax scratch,
+# and the step's fp32 scores and probabilities. Mosaic's scoped limit on a
+# v5e is 16 MB; half of it leaves the compiler its own temporaries. The
+# engine's shapes need ~1.5 MB, so every head rides one tile there; a
+# whole-prompt prefill of hundreds of rows splits the heads into groups.
+_VMEM_BUDGET = 8 << 20
 
 
-def _scan_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
-               block_len: int, scale: float):
+def _tile_bytes(heads: int, rows: int, block_len: int, D: int,
+                itemsize: int) -> int:
+    """VMEM of one grid step whose tile holds `heads` KV heads (or pieces
+    of one) with `rows` folded query rows each. fp32 rows whose last dim
+    is under a lane tile (m, l, scores over a 16-wide page) pad to 128."""
+    io = 2 * (2 * rows * D + 2 * block_len * D) * itemsize     # q, o, k, v
+    state = (rows * D + 2 * rows * 128) * 4                    # acc, m, l
+    scores = 2 * rows * max(block_len, 128) * 4                # s, p
+    return heads * (io + state + scores)
+
+
+def _choose_tile(H: int, Hkv: int, Tq: int, block_len: int, D: int,
+                 itemsize: int):
+    """(heads, fold): the tile of one grid step, from the shapes alone.
+
+    A tile holds `heads` KV heads, each with `fold` of its `n_rep` query
+    heads folded into `fold*Tq` rows. The largest tile inside the budget
+    wins: every KV head with its whole GQA group where that fits (G = 1),
+    else fewer KV heads, else one KV head with a part of its group (the
+    page is then fetched once per part). G = H // (heads*fold)."""
+    n_rep = H // Hkv
+    tiles = [(h, n_rep) for h in range(Hkv, 0, -1) if Hkv % h == 0]
+    tiles += [(1, f) for f in range(n_rep - 1, 0, -1) if n_rep % f == 0]
+    for heads, fold in tiles:
+        if (_tile_bytes(heads, fold * Tq, block_len, D, itemsize)
+                <= _VMEM_BUDGET):
+            return heads, fold
+    return tiles[-1]
+
+
+def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+               block_len: int, pages_per_row: int, scale: float):
     """lax.scan over logical blocks, carrying (m, l, acc) — the same
     masked-score -> exact-zero-p -> alpha-rescale sequence as the kernel,
     one compiled program regardless of grid size."""
     B, H, Tq, D = q.shape
-    Hkv = k_pages.shape[1]
+    Hkv = k_cache.shape[1]
     n_rep = H // Hkv
     row = q_pos[:, None] + jnp.arange(Tq, dtype=jnp.int32)   # [B, Tq]
 
@@ -105,12 +138,16 @@ def _scan_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
     l0 = jnp.zeros((B, H, Tq, 1), jnp.float32)
     acc0 = jnp.zeros((B, H, Tq, D), jnp.float32)
 
-    def body(carry, jt):
+    def page(cache, r, c):   # [Hkv, KB, D]: columns c.. of slab row r
+        return jax.lax.dynamic_slice(cache, (r, 0, c, 0),
+                                     (1, Hkv, block_len, D))[0]
+
+    def body(carry, j):
         m_prev, l_prev, acc = carry
-        j, tcol = jt                         # scalar block idx, [B] page ids
-        idx = jnp.maximum(tcol, 0)           # -1 padding clamps to page 0
-        k_j = k_pages[idx]                   # [B, Hkv, KB, D]
-        v_j = v_pages[idx]
+        g = jnp.maximum(block_table[:, j], 0)  # -1 padding clamps to page 0
+        r, c = g // pages_per_row, g % pages_per_row * block_len
+        k_j = jax.vmap(page, (None, 0, 0))(k_cache, r, c)   # [B,Hkv,KB,D]
+        v_j = jax.vmap(page, (None, 0, 0))(v_cache, r, c)
         if n_rep > 1:
             k_j = jnp.repeat(k_j, n_rep, axis=1)
             v_j = jnp.repeat(v_j, n_rep, axis=1)
@@ -130,23 +167,30 @@ def _scan_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc), None
 
-    n_blocks = block_table.shape[1]
-    js = jnp.arange(n_blocks, dtype=jnp.int32)
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0),
-                                  (js, block_table.T))
+    js = jnp.arange(block_table.shape[1], dtype=jnp.int32)
+    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), js)
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
+def _head_dot(a, b, a_dim, b_dim):
+    """`ops.attention._dot` for each head of [heads, ., .] operands: one
+    MXU matmul per head (a batched dot_general over dim 0), contracting
+    a[h][a_dim] with b[h][b_dim]."""
+    return jax.vmap(lambda x, y: _dot(x, y, a_dim, b_dim))(a, b)
+
+
 def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, block_len, scale):
-    """Grid (B, H, n_blocks), kv innermost; online-softmax state in VMEM
-    scratch across one (b, h) row's blocks. table/lens/pos arrive via
-    scalar prefetch so the index_map already routed k_ref/v_ref to THIS
-    block's page."""
+                  acc_ref, m_ref, l_ref, *, block_len, scale, Tq):
+    """Grid (B, G, n_blocks), pages innermost; one step is one page of one
+    slot for every head of the tile. q/o tiles [heads, fold*Tq, D] (a KV
+    head's query heads folded into rows), K/V tiles [heads, block_len, D]
+    cut from the slab by the index_map, online-softmax state in VMEM
+    scratch across a (b, g) row's pages. table/lens/pos arrive via scalar
+    prefetch."""
     b = pl.program_id(0)
     j = pl.program_id(2)
     n_blocks = pl.num_programs(2)
-    Tq = q_ref.shape[2]
+    rows = q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -158,15 +202,15 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     # contribute (every column masked -> exact no-op), so skip its compute
     @pl.when(j * block_len < lens_ref[b])
     def _compute():
-        q = q_ref[0, 0]                       # [Tq, D]
-        kblk = k_ref[0, 0]                    # [KB, D] (head picked by map)
-        vblk = v_ref[0, 0]
-        s = _dot(q, kblk, 1, 1) * scale
         col = (j * block_len
-               + jax.lax.broadcasted_iota(jnp.int32, (Tq, block_len), 1))
-        row = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32,
-                                                    (Tq, block_len), 0)
-        s = jnp.where((col <= row) & (col < lens_ref[b]), s, _NEG_INF)
+               + jax.lax.broadcasted_iota(jnp.int32, (rows, block_len), 1))
+        t = jax.lax.broadcasted_iota(jnp.int32, (rows, block_len), 0)
+        if rows != Tq:                        # folded row r is token r mod Tq
+            t = jax.lax.rem(t, Tq)
+        keep = (col <= pos_ref[b] + t) & (col < lens_ref[b])
+        vblk = v_ref[0]                       # [heads, KB, D]
+        s = _head_dot(q_ref[0], k_ref[0], 1, 1) * scale  # [heads,rows,KB]
+        s = jnp.where(keep[None], s, _NEG_INF)
         m_prev = m_ref[...]
         l_prev = l_ref[...]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -174,58 +218,65 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(vblk.dtype),
-                                                   vblk, 1, 0)
+        acc_ref[...] = acc_ref[...] * alpha + _head_dot(
+            p.astype(vblk.dtype), vblk, 1, 0)
         m_ref[...] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _pallas_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
-                 block_len: int, scale: float):
+def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+                 block_len: int, pages_per_row: int, scale: float):
     B, H, Tq, D = q.shape
-    Hkv = k_pages.shape[1]
+    Hkv = k_cache.shape[1]
     n_rep = H // Hkv
     n_blocks = block_table.shape[1]
-    table = jnp.maximum(block_table, 0).astype(jnp.int32)
+    heads, fold = _choose_tile(H, Hkv, Tq, block_len, D, q.dtype.itemsize)
+    rows = fold * Tq
+    G = H // (heads * fold)
+    parts = n_rep // fold          # tiles that share one KV head (1: none)
+    grid = (B, G, n_blocks)
+    pallas_mode.note_tiling("paged_attention", grid=grid, heads=heads,
+                            rows=rows)
+    table = jnp.maximum(block_table, 0)
 
-    def q_map(b, h, j, table_ref, lens_ref, pos_ref):
-        return (b, h, 0, 0)
+    def q_map(b, g, j, table_ref, lens_ref, pos_ref):
+        return (b, g, 0, 0)
 
-    def kv_map(b, h, j, table_ref, lens_ref, pos_ref):
-        return (table_ref[b, j], h // n_rep, 0, 0)
+    def kv_map(b, g, j, table_ref, lens_ref, pos_ref):
+        # a page past the row's length names the row's last live page
+        # again: the block index does not change, so nothing is fetched
+        last = jnp.maximum(lens_ref[b] - 1, 0) // block_len
+        page = table_ref[b, jnp.minimum(j, last)]
+        return (page // pages_per_row, g // parts, page % pages_per_row, 0)
 
-    def o_map(b, h, j, table_ref, lens_ref, pos_ref):
-        return (b, h, 0, 0)
-
+    tile = pl.BlockSpec((1, heads, rows, D), q_map)
+    kv_tile = pl.BlockSpec((1, heads, block_len, D), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, H, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, Tq, D), q_map),
-            pl.BlockSpec((1, 1, block_len, D), kv_map),
-            pl.BlockSpec((1, 1, block_len, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Tq, D), o_map),
+        grid=grid,
+        in_specs=[tile, kv_tile, kv_tile],
+        out_specs=tile,
         scratch_shapes=[
-            pltpu.VMEM((Tq, D), jnp.float32),
-            pltpu.VMEM((Tq, 1), jnp.float32),
-            pltpu.VMEM((Tq, 1), jnp.float32),
+            pltpu.VMEM((heads, rows, D), jnp.float32),
+            pltpu.VMEM((heads, rows, 1), jnp.float32),
+            pltpu.VMEM((heads, rows, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, block_len=block_len,
-                               scale=scale)
-    return pl.pallas_call(
+                               scale=scale, Tq=Tq)
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-        compiler_params=_GRID_SEMANTICS,  # (B, H, kv blocks): same shape
+        out_shape=jax.ShapeDtypeStruct((B, H // fold, rows, D), q.dtype),
+        compiler_params=_GRID_SEMANTICS,  # (B, G, pages): same shape
         interpret=pallas_mode.interpret("paged_attention"),
         name="paged_attention",
-    )(table, seq_lens.astype(jnp.int32), q_pos.astype(jnp.int32),
-      q, k_pages, v_pages)
+    )(table, seq_lens, q_pos, q.reshape(B, H // fold, rows, D),
+      k_cache, v_cache)
+    return out.reshape(B, H, Tq, D)
 
 
 def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
@@ -255,14 +306,17 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
     block_table = jnp.asarray(block_table, jnp.int32)
     seq_lens = jnp.asarray(seq_lens, jnp.int32)
     q_pos = jnp.asarray(q_pos, jnp.int32)
-    k_pages = _as_pages(k_cache, block_len, pages_per_row)
-    v_pages = _as_pages(v_cache, block_len, pages_per_row)
+    if k_cache.shape[2] < pages_per_row * block_len:
+        raise ValueError(
+            f"cache length {k_cache.shape[2]} cannot back {pages_per_row} "
+            f"pages of {block_len} tokens")
     if impl == "scan":
         pallas_mode.count("paged_attention", "scan")
-        return _scan_impl(q, k_pages, v_pages, block_table, seq_lens,
-                          q_pos, block_len, scale)
-    return _pallas_impl(q, k_pages, v_pages, block_table, seq_lens, q_pos,
-                        block_len, scale)
+        impl_fn = _scan_impl
+    else:
+        impl_fn = _pallas_impl
+    return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+                   block_len, pages_per_row, scale)
 
 
 def trivial_block_table(batch: int, cache_len: int,

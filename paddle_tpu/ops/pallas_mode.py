@@ -20,6 +20,9 @@ _log = logging.getLogger("paddle_tpu.ops")
 # (kernel, path) -> times traced that way. path: "mosaic" | "interpret" for a
 # pallas_call, "reference" / "scan" for the XLA code that stood in for one
 KERNEL_TRACES: collections.Counter = collections.Counter()
+# (kernel, ((name, value), ...)) -> times traced with that tiling: what a
+# kernel whose grid and tile follow its input chose for each traced call
+KERNEL_TILINGS: collections.Counter = collections.Counter()
 _lock = threading.Lock()
 _reference_seen = set()
 
@@ -32,6 +35,13 @@ def platform() -> str:
 def count(kernel: str, path: str):
     with _lock:
         KERNEL_TRACES[(kernel, path)] += 1
+
+
+def note_tiling(kernel: str, **tiling):
+    """Record, while tracing, the grid and tile `kernel` chose from its
+    shapes (ints and tuples of ints; nothing happens at run time)."""
+    with _lock:
+        KERNEL_TILINGS[(kernel, tuple(sorted(tiling.items())))] += 1
 
 
 def interpret(kernel: str) -> bool:

@@ -177,30 +177,140 @@ def test_bf16_parity_documented_tolerance():
     assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref))) <= 2e-2
 
 
-def test_pallas_interpret_matches_scan_and_reference():
-    """The REAL kernel body (grid, scalar-prefetched index maps, VMEM
-    online-softmax scratch) runs interpreted on the CPU and must agree
-    with the scan path and the oracle — tier-1 proof that the TPU kernel
-    computes the same function."""
-    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+# (H, Hkv, Tq, block_len, table, tile): the cells' head layouts at small
+# sizes (GQA n_rep 4, MHA), a decode row and a chunk, both block sizes the
+# repo runs; a `tile` (KV heads, folded query heads) shrinks the module's
+# VMEM budget to what that tile needs, so that the heads split into groups
+# (G > 1), down to a part of one GQA group
+_KERNEL_CASES = [
+    pytest.param(8, 2, 1, 8, "fragmented", None, id="gqa4-tq1-bl8-frag"),
+    pytest.param(8, 2, 4, 16, "shared", None, id="gqa4-chunk-bl16-shared"),
+    pytest.param(4, 4, 1, 16, "fragmented", None, id="mha-tq1-bl16-frag"),
+    pytest.param(4, 4, 4, 8, "shared", None, id="mha-chunk-bl8-shared"),
+    pytest.param(8, 2, 4, 8, "identity", None, id="gqa4-chunk-bl8-identity"),
+    pytest.param(8, 4, 4, 8, "fragmented", (2, 2), id="gqa2-chunk-bl8-G2"),
+    pytest.param(8, 2, 4, 16, "shared", (1, 2),
+                 id="gqa4-chunk-bl16-G4-split-group"),
+]
+
+
+@pytest.mark.parametrize("H,Hkv,Tq,bl,layout,tile", _KERNEL_CASES)
+def test_pallas_interpret_matches_scan_and_reference(monkeypatch, H, Hkv, Tq,
+                                                     bl, layout, tile):
+    """The REAL kernel body (grid over (slot, head group, page), index maps
+    into the slabs as stored, VMEM online-softmax scratch over the folded
+    rows) runs interpreted on the CPU and must agree with the scan path
+    and the oracle — tier-1 proof that the TPU kernel computes the same
+    function. Every case: a slab with write-padding past the page region
+    (filled with NaN: never addressed), `-1` table padding, a row of
+    length 0, and an indirection through another slot's slab."""
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
     rng = np.random.RandomState(4)
-    B, H, Hkv, D, bl, nb = 2, 2, 1, 8, 4, 3
-    k = _rand(rng, (B, Hkv, nb * bl, D))
-    v = _rand(rng, (B, Hkv, nb * bl, D))
-    q = _rand(rng, (B, H, 4, D))
-    lens = np.array([9, 5], np.int32)
-    q_pos = np.array([5, 4], np.int32)
-    table = _identity_table(B, nb)
-    scan = ragged_paged_attention(q, k, v, table, lens, q_pos,
-                                  block_len=bl, impl="scan")
-    pal = ragged_paged_attention(q, k, v, table, lens, q_pos,
-                                 block_len=bl, impl="pallas")
-    assert float(jnp.max(jnp.abs(pal - scan))) <= 1e-6
-    ref = _ref_paged(q, k, v, table, lens, q_pos, bl, nb)
+    B, D, nb, pad = 4, 8, 3, 8
+
+    def slab():
+        x = _rand(rng, (B, Hkv, nb * bl + pad, D))
+        return x.at[:, :, nb * bl:].set(jnp.nan)
+    k, v = slab(), slab()
+    q = _rand(rng, (B, H, Tq, D))
+    # a full row, a row inside its second page, an empty row, one token
+    lens = np.array([nb * bl, bl + 3, 0, 1], np.int32)
+    q_pos = np.maximum(lens - Tq, 0).astype(np.int32)
+    table = {
+        "identity": _identity_table(B, nb),
+        # every row's pages scattered over other slots' slabs
+        "fragmented": np.array([[7, 2, 9], [4, 11, -1], [-1, -1, -1],
+                                [0, -1, -1]], np.int32),
+        # rows 0 and 1 read the same first page (a shared prefix)
+        "shared": np.array([[5, 6, 1], [5, 3, -1], [-1, -1, -1],
+                            [10, -1, -1]], np.int32),
+    }[layout]
+    heads, fold = tile or (Hkv, H // Hkv)
+    if tile:
+        monkeypatch.setattr(PA, "_VMEM_BUDGET",
+                            PA._tile_bytes(heads, fold * Tq, bl, D, 4))
+    pallas_mode.KERNEL_TILINGS.clear()
+    run = {impl: PA.ragged_paged_attention(
+        q, k, v, table, lens, q_pos, block_len=bl, pages_per_row=nb,
+        impl=impl) for impl in ("scan", "pallas")}
+    G = H // (heads * fold)
+    assert (G > 1) == bool(tile)
+    assert dict(pallas_mode.KERNEL_TILINGS) == {
+        ("paged_attention", (("grid", (B, G, nb)), ("heads", heads),
+                             ("rows", fold * Tq))): 1}
+    assert bool(jnp.all(jnp.isfinite(run["pallas"])))
+    assert float(jnp.max(jnp.abs(run["pallas"] - run["scan"]))) <= 1e-6
+    assert not np.asarray(run["pallas"][2]).any()      # the empty row
+    ref = _ref_paged(q, jnp.nan_to_num(k), jnp.nan_to_num(v), table, lens,
+                     q_pos, bl, nb)
     for b in range(B):
         n = int(lens[b] - q_pos[b])        # valid query rows
-        assert float(jnp.max(jnp.abs(pal[b, :, :n] - ref[b, :, :n]))) \
-            <= 1e-5
+        assert float(jnp.max(jnp.abs(run["pallas"][b, :, :n]
+                                     - ref[b, :, :n]), initial=0.0)) <= 1e-5
+
+
+@pytest.mark.parametrize("name,q_shape,hkv,bl,want", [
+    # the serve cells: everything in one tile, G = 1
+    ("mistral decode", (128, 32, 16, 128), 8, 16, (8, 4)),
+    ("mistral prefill", (32, 32, 16, 128), 8, 16, (8, 4)),
+    ("olmoe decode", (128, 16, 16, 128), 16, 16, (16, 1)),
+    # one-shot generate(): the decode loop, then whole-prompt prefills
+    ("gqa decode loop", (8, 32, 1, 128), 8, 8, (8, 4)),
+    ("gqa prompt 512", (2, 32, 512, 128), 8, 8, (1, 4)),
+    ("gqa prompt 1024", (1, 32, 1024, 128), 8, 8, (1, 2)),
+    ("mha prompt 2048", (1, 16, 2048, 128), 16, 8, (1, 1)),
+])
+def test_tile_choice_follows_shapes_and_budget(name, q_shape, hkv, bl, want):
+    """`_choose_tile` at the shapes the cells and generate() run, bf16: the
+    whole head set in one tile wherever it fits the module's one budget,
+    fewer KV heads, then a part of one GQA group, where it does not."""
+    from paddle_tpu.ops import paged_attention as PA
+    _, H, Tq, D = q_shape
+    heads, fold = PA._choose_tile(H, hkv, Tq, bl, D, 2)
+    assert (heads, fold) == want, name
+    assert hkv % heads == 0 and (H // hkv) % fold == 0
+    if heads * fold > 1:
+        assert PA._tile_bytes(heads, fold * Tq, bl, D, 2) <= PA._VMEM_BUDGET
+    # the next larger tile would not have fit
+    if (heads, fold) != (hkv, H // hkv):
+        bigger = (heads * 2, fold) if fold == H // hkv else (1, fold * 2)
+        assert PA._tile_bytes(bigger[0], bigger[1] * Tq, bl, D, 2) \
+            > PA._VMEM_BUDGET
+
+
+def test_tpu_path_hands_the_slabs_to_the_kernel_as_stored(monkeypatch):
+    """On the TPU path nothing slices, transposes, reshapes or copies a
+    slab before the kernel: the jaxpr's one `pallas_call` takes the
+    function's own k_cache / v_cache variables, and no other equation
+    reads them."""
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
+    monkeypatch.setattr(pallas_mode, "platform", lambda: "tpu")
+    B, H, Hkv, Tq, D, bl, nb = 4, 8, 2, 4, 128, 16, 3
+    slab = jax.ShapeDtypeStruct((B, Hkv, nb * bl + 16, D), jnp.bfloat16)
+
+    def f(q, k, v, table, lens, q_pos):
+        return PA.ragged_paged_attention(q, k, v, table, lens, q_pos,
+                                         block_len=bl, pages_per_row=nb)
+    jaxpr = jax.make_jaxpr(f)(
+        jax.ShapeDtypeStruct((B, H, Tq, D), jnp.bfloat16), slab, slab,
+        jax.ShapeDtypeStruct((B, nb), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32)).jaxpr
+    k_var, v_var = jaxpr.invars[1], jaxpr.invars[2]
+    readers = [e for e in jaxpr.eqns
+               if any(x is k_var or x is v_var for x in e.invars)]
+    assert [e.primitive.name for e in readers] == ["pallas_call"]
+    call, = readers
+    assert sum(x is k_var for x in call.invars) == 1
+    assert sum(x is v_var for x in call.invars) == 1
+    assert call.params["name"] == "paged_attention"
+    assert sum(e.primitive.name == "pallas_call" for e in jaxpr.eqns) == 1
+    for e in jaxpr.eqns:                     # and nothing slab-sized is made
+        for o in e.outvars:
+            if e.primitive.name != "pallas_call":
+                assert o.aval.size < slab.size, e
 
 
 def test_chunked_prefill_bitwise_equals_whole_prompt():

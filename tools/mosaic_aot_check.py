@@ -101,20 +101,37 @@ def main() -> int:
             jax.grad(loss, argnums=(0, 1, 2)), *qkv,
             want={"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}))
 
-    N, H, D, L = 8, 16, 128, 2048
-    for bl in (16, 8):          # engine default / DEFAULT_KV_BLOCK
-        for Tq in (1, 16):      # decode row / prefill chunk
-            def paged(q, k, v, t, sl, qp, bl=bl):
-                return ragged_paged_attention(
-                    q, k, v, t, sl, qp, block_len=bl,
-                    pages_per_row=L // bl, impl="pallas")
-            results.append(compile_case(
-                f"paged bf16 block_len={bl} Tq={Tq}", paged,
-                spec((N, H, Tq, D), jnp.bfloat16),
-                spec((N, H, L + 16, D), jnp.bfloat16),
-                spec((N, H, L + 16, D), jnp.bfloat16),
-                spec((N, L // bl), jnp.int32), spec((N,), jnp.int32),
-                spec((N,), jnp.int32), want={"paged_attention": 1}))
+    # (label, q [B, H, Tq, D], slab [N, Hkv, L_slab, D], block_len, pages a
+    # row): MHA at both block sizes the repo runs and both query widths,
+    # then the serve cells' own shapes, the one-shot decode loop's GQA row
+    # and a whole-prompt prefill that must split the heads (G > 1)
+    D, L = 128, 2048
+    paged_cases = [
+        (f"block_len={bl} Tq={Tq}", (8, 16, Tq, D), (8, 16, L + 16, D), bl,
+         L // bl) for bl in (16, 8) for Tq in (1, 16)]
+    paged_cases += [
+        ("mistral decode cell", (128, 32, 16, D), (128, 8, 240, D), 16, 14),
+        ("olmoe decode cell", (128, 16, 16, D), (128, 16, 240, D), 16, 14),
+        ("mistral prefill cells", (32, 32, 16, D), (32, 8, 1056, D), 16, 65),
+        ("GQA block_len=8 Tq=1", (8, 32, 1, D), (8, 8, L + 16, D), 8, L // 8),
+        ("GQA block_len=8 Tq=512, G>1", (2, 32, 512, D), (2, 8, 512, D), 8,
+         64),
+    ]
+    for label, q_shape, slab, bl, ppr in paged_cases:
+        def paged(q, k, v, t, sl, qp, bl=bl, ppr=ppr):
+            return ragged_paged_attention(
+                q, k, v, t, sl, qp, block_len=bl, pages_per_row=ppr,
+                impl="pallas")
+        B = q_shape[0]
+        results.append(compile_case(
+            f"paged bf16 {label} q={list(q_shape)} slab={list(slab)}", paged,
+            spec(q_shape, jnp.bfloat16), spec(slab, jnp.bfloat16),
+            spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
+            spec((B,), jnp.int32), spec((B,), jnp.int32),
+            want={"paged_attention": 1}))
+    from paddle_tpu.ops import pallas_mode
+    for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
+        print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
 
     # the grouped matmul of the dropless expert layer at OLMoE's widths and
     # the decode cell's rows (2,048 positions x 8 experts each)
