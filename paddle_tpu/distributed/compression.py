@@ -209,7 +209,7 @@ def dequant_mean_rows(payload_rows, scales_rows, out_dtype):
                     axis=0).astype(out_dtype)
 
 
-# ---- wire-byte accounting (bench.py --comm / regression gate) ----
+# ---- wire-byte accounting (analytic; read by tests/test_compression.py) ----
 
 def comm_bytes_per_step(n: int, world: int,
                         cfg: Optional[QuantAllreduceConfig] = None,

@@ -317,9 +317,6 @@ class GPTForCausalLM(Layer):
 
     @staticmethod
     def _can_fuse_lm_ce():
-        import os
-        if os.environ.get("FLAGS_fused_lm_ce", "1") != "1":
-            return False
         from ..distributed.meta_parallel.mp_layers import (_explicit_tp,
                                                            _mp_degree)
         from ..ops.attention import sequence_sharded_trace

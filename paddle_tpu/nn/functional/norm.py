@@ -20,11 +20,6 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     n_axes = len(normalized_shape)
 
     def f(a, *wb):
-        from ...ops import layernorm as _ln
-        if _ln.eligible(a.shape, n_axes, weight is not None,
-                        bias is not None) and a.ndim - n_axes >= 1:
-            # one-pass Pallas kernel on TPU (fp32 stats, fused affine + vjp)
-            return _ln.fused_layer_norm(a, wb[0], wb[1], epsilon)
         axes = tuple(range(a.ndim - n_axes, a.ndim))
         orig = a.dtype
         h = a.astype(jnp.float32)

@@ -18,8 +18,8 @@
   analyses, dispatch + device-seconds accounting) and the recompile
   explainer that names the culprit leaf behind every post-warmup
   recompile;
-- `flops` — the analytic FLOPs / peak-FLOPs helpers bench.py and the
-  live MFU gauges share;
+- `flops` — the per-chip peak table (read by `chip_smoke.py`) and the
+  analytic FLOPs helpers behind the live MFU gauges;
 - `numerics` (ISSUE 13) — the training numerics observatory: in-step
   grad/param/update-ratio telemetry, the culprit-named non-finite blame
   report, and the loss-spike sentinel, plus the shared non-finite
@@ -33,8 +33,7 @@ from .compile_observatory import (CompileObservatory, compile_observatory,
                                   signature_of)
 from .deploy_metrics import DeployMetrics
 from .flight_recorder import DUMP_DIR_ENV, FlightRecorder, flight_recorder
-from .flops import (conv_train_flops_per_step, decode_flops_per_token,
-                    decode_mfu, peak_flops, train_flops_per_step)
+from .flops import decode_mfu, peak_flops, train_flops_per_step
 from .goodput import (PHASES, GoodputLedger, HBMTelemetry, PhaseLedger,
                       RecompileSentinel, oom_forensics)
 from .numerics import (NumericsObservatory, all_finite, bracket_path,
@@ -51,8 +50,7 @@ __all__ = [
     "fingerprint_of", "signature_of",
     "DeployMetrics",
     "DUMP_DIR_ENV", "FlightRecorder", "flight_recorder",
-    "conv_train_flops_per_step", "decode_flops_per_token", "decode_mfu",
-    "peak_flops", "train_flops_per_step",
+    "decode_mfu", "peak_flops", "train_flops_per_step",
     "PHASES", "GoodputLedger", "HBMTelemetry", "PhaseLedger",
     "RecompileSentinel", "oom_forensics",
     "NumericsObservatory", "all_finite", "bracket_path", "current_numerics",

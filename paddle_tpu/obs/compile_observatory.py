@@ -30,7 +30,7 @@ reserved. This module closes that gap:
   ``pdtpu_compile_*`` Prometheus families, chrome ``compile/<callsite>``
   lanes, and a predicted-vs-measured HBM row reconciling
   ``memory_analysis()`` totals against the PR 10 HBMTelemetry watermark
-  (the same cross-check discipline live MFU uses against bench MFU).
+  (a ratio far from 1 means XLA's plan and the allocator disagree).
 
 Analyses come from JAX's AOT path (``jit(f).lower(*args).compile()``
 then ``cost_analysis()`` / ``memory_analysis()``). The AOT compile is
@@ -497,8 +497,7 @@ class CompileObservatory:
         """Predicted-vs-measured HBM: sum memory_analysis() totals over
         each call site's LATEST executable (the resident set a steady
         process keeps live) against the PR 10 watermark gauge. A ratio
-        far from 1 means XLA's plan and the allocator disagree — the
-        same cross-check discipline live MFU applies to bench MFU."""
+        far from 1 means XLA's plan and the allocator disagree."""
         with self._lock:
             if latest is None:
                 latest = dict(self._latest)

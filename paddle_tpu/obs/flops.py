@@ -1,11 +1,12 @@
 """Analytic FLOPs / peak-FLOPs accounting (ISSUE 10 satellite).
 
-ONE source of truth for the model-FLOPs arithmetic that used to live
-inline in bench.py: the per-chip peak table, the 6ND train-step formula
-(with MoE active-param correction), the conv MAC→FLOP convention, and
-the 2ND decode formula. bench.py's offline MFU and the goodput ledger's
-live MFU (obs.goodput) both call these helpers, so the two numbers can
-never diverge by formula — only by what they measured.
+The per-chip peak table (`peak_flops`: read by `chip_smoke.py`, which
+refuses a chip that is not in it), the 6ND train-step formula with the MoE
+active-param correction (what callers register with the goodput ledger's
+live MFU, `obs.goodput`), and the decode-MFU formula behind the serving
+ledger's gauge (`obs.serving_ledger`). The benchmark's utilization metrics
+have their own cost model, which counts attention too:
+`benchmark/kernel_costs.py`.
 
 Stdlib-only: callers pass device_kind/backend strings and parameter
 counts; nothing here imports jax.
@@ -69,33 +70,17 @@ def train_flops_per_step(n_params: int, tokens_per_step: int,
     return 6.0 * n_active * tokens_per_step
 
 
-def conv_train_flops_per_step(fwd_mac_flops: float, batch: int) -> float:
-    """Conv-net train-step FLOPs from measured forward MACs.
-
-    paddle.flops counts MACs (one multiply-add = 1); true FLOPs are 2x
-    that, and fwd+bwd ~ 3x the forward.
-    """
-    return 3.0 * (2.0 * float(fwd_mac_flops)) * batch
-
-
-def decode_flops_per_token(n_params: int) -> float:
-    """2N forward-only FLOPs per generated token (KV-cache decode)."""
-    return 2.0 * n_params
-
-
 def lora_decode_flops_per_token(rank: int, target_dims) -> float:
     """Extra forward FLOPs per token for one LoRA-adapted row (ISSUE 20).
 
     Each adapted site adds two skinny matmuls to the base projection:
     ``x[in] @ A.T[in, r]`` then ``z[r] @ B.T[r, out]`` — `2*r*(in+out)`
-    FLOPs under the same 2·MAC convention as `decode_flops_per_token`.
+    FLOPs (2 per multiply-add, as the 2N decode formula counts).
     `target_dims` is an iterable of per-site `(in_features,
     out_features)` pairs covering EVERY adapted site of EVERY layer
     (i.e. `num_layers * len(targets)` entries — the caller flattens,
     mirroring how the MoE correction counts active params, not per-layer
-    shorthand). The adapter-overhead analytics in bench.py's lora phase
-    and docs sizing math both call this, so the bound can never diverge
-    from the measured `llm_lora_overhead_pct` by formula."""
+    shorthand). The sizing math of docs/serving.md's multi-LoRA section."""
     r = int(rank)
     return float(sum(2.0 * r * (int(i) + int(o)) for i, o in target_dims))
 
@@ -104,10 +89,10 @@ def decode_mfu(flops_per_token: float, tokens: int, seconds: float,
                peak_flops_total: float):
     """Effective decode MFU: achieved decode FLOP/s over peak.
 
-    ONE formula for bench.py's offline row and the serving ledger's live
-    gauge (ISSUE 11), mirroring how train MFU shares
-    `train_flops_per_step`. Returns None when any input is degenerate
-    (no tokens, no measured seconds, no registered peak)."""
+    The serving ledger's live gauge (ISSUE 11); `flops_per_token` is 2N
+    for a dense decoder (forward only, KV-cache decode). Returns None when
+    any input is degenerate (no tokens, no measured seconds, no registered
+    peak)."""
     if not (flops_per_token and tokens and seconds and peak_flops_total):
         return None
     if seconds <= 0 or peak_flops_total <= 0:

@@ -27,8 +27,8 @@ zero. Tests reconcile the sum against wall clock within 1%
 On top of the ledger:
 
 - **live MFU** — `flops_per_step x productive_steps / wall / peak`,
-  with the FLOPs arithmetic imported from obs.flops — the SAME helpers
-  bench.py uses, so live and offline MFU can only differ by measurement;
+  with the FLOPs arithmetic taken from obs.flops (the benchmark's
+  `train_mfu_pct` counts attention too: benchmark/kernel_costs.py);
 - **RecompileSentinel** — counts XLA compilations (jax.monitoring's
   ``/jax/core/compile/backend_compile_duration`` where available,
   JitLRUCache miss hooks otherwise), books compile time as

@@ -31,9 +31,9 @@ On top of the phase tiling:
 - **token economics** — every dispatch of the fixed-width unified step
   advances `useful` positions out of `num_slots * prefill_chunk` total;
   `token_efficiency = useful / total` is the pad-waste observable, and
-  `decode_mfu = decode_flops_per_token * decode_tokens /
+  `decode_mfu = flops_per_token * decode_tokens /
   decode_compute_seconds / peak` is the effective decode utilization
-  (same `obs.flops` helpers bench.py uses offline);
+  (`obs.flops.decode_mfu`; the caller registers `flops_per_token`);
 - **cost metering** — the dispatch's device seconds are apportioned to
   the rows' tenants and SLO classes by position weights, accumulating
   `pdtpu_llm_tenant_device_seconds_total` /

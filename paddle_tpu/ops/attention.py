@@ -35,8 +35,8 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_mode
 
 # 256x256 is the block config chip_smoke.py compiles and checks against the
-# reference on a v5e; other sizes (FLAGS_flash_block_q/k, resolved per call
-# inside flash_attention) have not been run on this kernel
+# reference on a v5e; other sizes (the block_q / block_k arguments of
+# flash_attention) have not been run on this kernel
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 
@@ -718,23 +718,19 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    import os
-    if block_q is None:  # env-sweepable (FLAGS_flash_block_q/k), per call
-        block_q = int(os.environ.get("FLAGS_flash_block_q",
-                                     str(DEFAULT_BLOCK_Q)))
+    if block_q is None:
+        block_q = DEFAULT_BLOCK_Q
     if block_k is None:
-        block_k = int(os.environ.get("FLAGS_flash_block_k",
-                                     str(DEFAULT_BLOCK_K)))
+        block_k = DEFAULT_BLOCK_K
     on_cpu = pallas_mode.platform() == "cpu"
     Sq, Sk = q.shape[2], k.shape[2]
     # why this call takes the XLA reference instead of the kernel, if it does
     reason = None
     if sequence_sharded_trace() and not force_pallas:
         mesh = getattr(_SEQ_SHARDED, "mesh", None)
-        # env var overrides the strategy-configured impl; "gspmd" means the
-        # partitioner-sliced reference path (no island)
-        impl = (os.environ.get("FLAGS_sp_impl", "")
-                or getattr(_SEQ_SHARDED, "impl", "ring") or "ring")
+        # strategy-configured; "gspmd" is the partitioner-sliced reference
+        # path (no island)
+        impl = getattr(_SEQ_SHARDED, "impl", "ring") or "ring"
         # ring/Ulysses need the sep axis and take no additive mask/dropout;
         # cross-attention (Sq != Sk) keeps the GSPMD-sliced reference too
         if (mesh is not None and "sep" in mesh.axis_names
@@ -742,9 +738,6 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                 and dropout_p == 0.0 and Sq == Sk and impl != "gspmd"):
             return _sequence_parallel_island(q, k, v, causal, scale, impl)
         reason = "sequence-sharded trace, no ring island"
-    elif os.environ.get("FLAGS_flash_attention", "1") == "0" \
-            and not force_pallas:
-        reason = "FLAGS_flash_attention=0"
     elif Sq % min(block_q, Sq) or Sk % min(block_k, Sk):
         reason = f"sequence not divisible by block {block_q}x{block_k}"
     elif dropout_p > 0.0 and on_cpu:

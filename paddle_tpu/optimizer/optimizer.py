@@ -360,6 +360,21 @@ class Momentum(Optimizer):
         return (p32 - lr * update).astype(p.dtype), out
 
 
+def _adam_math(p32, g, m1, m2, lr, b1p, b2p, wd, *, b1, b2, eps, decoupled):
+    """One Adam / AdamW update in float32 (adam_op.h AdamFunctor): returns
+    (new_p32, new_m1, new_m2). `b1p` / `b2p` are beta^t after this step;
+    `decoupled` applies the decay to the update (AdamW), not the gradient."""
+    g = g.astype(jnp.float32)
+    if not decoupled:
+        g = g + wd * p32
+    m1n = b1 * m1 + (1.0 - b1) * g
+    m2n = b2 * m2 + (1.0 - b2) * g * g
+    update = (m1n / (1.0 - b1p)) / (jnp.sqrt(m2n / (1.0 - b2p)) + eps)
+    if decoupled:
+        update = update + wd * p32
+    return p32 - lr * update, m1n, m2n
+
+
 class Adam(Optimizer):
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-08, parameters=None, weight_decay=None,
@@ -421,18 +436,17 @@ class Adam(Optimizer):
         b1, b2 = self._beta1, self._beta2
         b1p = slots["beta1_pow"] * b1
         b2p = slots["beta2_pow"] * b2
-        # one source of truth for the update math: ops.fused_adam dispatches
-        # between the Pallas single-pass kernel (opt-in, adam_op.cu parity)
-        # and the XLA formula internally
-        from ..ops.fused_adam import fused_adam
-        new_p, m1, m2 = fused_adam(
-            p, g, slots["moment1"].astype(jnp.float32),
-            slots["moment2"].astype(jnp.float32), lr, b1p, b2p,
-            wd or 0.0, beta1=b1, beta2=b2, epsilon=self._epsilon,
-            decoupled=self._decoupled())
+        new_p, m1, m2 = _adam_math(
+            p.astype(jnp.float32), g,
+            slots["moment1"].astype(jnp.float32),
+            slots["moment2"].astype(jnp.float32),
+            jnp.asarray(lr, jnp.float32), jnp.asarray(b1p, jnp.float32),
+            jnp.asarray(b2p, jnp.float32), jnp.asarray(wd or 0.0, jnp.float32),
+            b1=b1, b2=b2, eps=self._epsilon, decoupled=self._decoupled())
         md = self._moment_dtype
-        return new_p, {"moment1": m1.astype(md), "moment2": m2.astype(md),
-                       "beta1_pow": b1p, "beta2_pow": b2p}
+        return new_p.astype(p.dtype), {
+            "moment1": m1.astype(md), "moment2": m2.astype(md),
+            "beta1_pow": b1p, "beta2_pow": b2p}
 
 
 class AdamW(Adam):

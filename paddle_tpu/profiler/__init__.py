@@ -265,9 +265,9 @@ class ThroughputTracker:
 
     The chunk run loop (trainer.DeviceWorker over a parallel.ScanTrainStep)
     calls `update(steps=K, seconds=dt, tokens=K*B*S)` once per fused
-    dispatch, so utilization is reported from the production path without a
-    separate bench run. Rates are computed over a sliding window of recent
-    chunks (warmup/compile chunks age out) alongside lifetime totals; each
+    dispatch, so utilization is reported from the production path. Rates
+    are computed over a sliding window of recent chunks (warmup/compile
+    chunks age out) alongside lifetime totals; each
     update also drops a `throughput` instant on the profiler timeline when
     profiling is enabled.
     """

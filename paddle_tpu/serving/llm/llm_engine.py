@@ -589,7 +589,7 @@ class LLMEngine:
         self.spec_drafted = 0           # lifetime draft tokens verified
         self.spec_accepted = 0          # lifetime draft tokens accepted
         # tiered KV + disaggregation (ISSUE 19): lifetime counters the
-        # bench's tiered phase and the tests read directly
+        # tests read directly
         self.host_onboard_tokens = 0    # prompt tokens onboarded from the
         #                                 host spill tier (skipped prefill)
         self.kv_import_tokens = 0       # prompt tokens imported via a
@@ -2510,9 +2510,8 @@ class LLMEngine:
                         prefill_slots + decode_slots]))
                 # sampling-operand assembly (ISSUE 18) — per-slot params,
                 # RNG-lane counters, DFA states and the grammar bank —
-                # is the host-side cost of constrained/sampled decoding;
-                # meter it so the mask-overhead ceiling row in bench has
-                # a real signal behind it
+                # is the host-side cost of constrained/sampled decoding,
+                # metered as pdtpu_llm_sample_mask_overhead_ms
                 ts0 = self.clock.now()
                 sargs = self._sampling_args_locked(ctr)
                 mask_dt = self.clock.now() - ts0

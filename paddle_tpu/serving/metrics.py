@@ -385,7 +385,7 @@ class LLMMetrics(ServingMetrics):
         """One admission-time prefix-cache lookup: `hit_tokens` prompt
         tokens were served from cached KV (attach + COW) out of
         `prompt_tokens` looked up. The token-weighted ratio of these two
-        counters is the cache hit rate the bench gates pin."""
+        counters is the cache hit rate."""
         with self._lock:
             hit = hit_tokens > 0
             self.counters["prefix_hits" if hit else "prefix_misses"] += 1
@@ -524,8 +524,7 @@ class LLMMetrics(ServingMetrics):
 
     def on_mask_overhead(self, ms: float):
         """Host-side sampling-operand assembly time for one unified step
-        (params + RNG-lane counters + DFA states + grammar bank): the
-        per-step overhead the bench's mask-overhead ceiling row bounds."""
+        (params + RNG-lane counters + DFA states + grammar bank)."""
         with self._lock:
             self._mask_overhead_ms.append(float(ms))
 
@@ -879,8 +878,7 @@ class RouterMetrics:
     def on_handoff(self, src: str, dst: str, ms: float):
         """One completed prefill→decode stream handoff (ISSUE 19): KV
         exported from `src`, stream re-admitted on `dst` after `ms`
-        milliseconds of export-to-accepted-submit wall time — the
-        latency the bench's `llm_handoff_ms` ceiling bounds."""
+        milliseconds of export-to-accepted-submit wall time."""
         with self._lock:
             self.handoffs += 1
             self._handoff_ms.append(float(ms))

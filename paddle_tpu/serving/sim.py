@@ -12,9 +12,6 @@ provable in a unit test.
     engine = BatchingEngine(fn, EngineConfig(max_batch_size=8), clock=clock)
     report = replay(engine, poisson_trace(64, rate_hz=2000, make_inputs=mk))
     assert report.dispatches <= 9
-
-`bench.py --serve` replays the same kind of trace against a real clock for
-measured latency/throughput rows.
 """
 from __future__ import annotations
 

@@ -43,16 +43,20 @@ def mesh8():
     _GLOBAL_MESH[0] = None
 
 
-# ---- test tiering (VERDICT r3 item 9) ----
-# Heavy modules (multi-device shard_map compiles, cross-process fixtures,
-# model zoos) are auto-marked `slow`. Smoke tier: `pytest -m "not slow"`
-# (<5 min); the FULL suite stays the round gate.
+# ---- test tiering ----
+# Modules auto-marked `slow` stay out of tier-1 (`-m "not slow"`), which the
+# driver runs with `-n 6 --dist loadfile` under a 1,470 s limit (342 s at PR
+# 26). The files that guard the benchmark's layers are NOT here:
+# test_attention (flash kernels), test_parallel (SPMD step), test_generation
+# (the engine tests' bit-identity reference). What is left is heavy
+# (multi-device shard_map compiles, cross-process fixtures, model zoos) and
+# is reviewed against that limit in ROADMAP.md C8.
 _SLOW_MODULES = {
-    "test_pipeline", "test_pipeline_compose", "test_parallel",
+    "test_pipeline", "test_pipeline_compose",
     "test_strategy_compiler", "test_sequence_parallel",
-    "test_ring_attention", "test_moe", "test_generation",
+    "test_ring_attention", "test_moe",
     "test_multiprocess_dist", "test_metrics_elastic", "test_vision_models",
-    "test_amp", "test_attention", "test_fused_ops", "test_softmax_ce",
+    "test_amp", "test_softmax_ce",
     "test_cpp_predictor", "test_op_numerics_batch3",
     "test_op_numerics_batch4", "test_op_numerics_batch5",
     "test_highlevel", "test_beam_search",
@@ -63,8 +67,8 @@ _SLOW_MODULES = {
 
 def pytest_configure(config):
     config.addinivalue_line(
-        "markers", "slow: heavy multi-device/model tests (excluded from the "
-        "smoke tier via -m 'not slow'; full suite remains the gate)")
+        "markers", "slow: heavy multi-device/model tests (excluded from "
+        "tier-1 via -m 'not slow')")
     config.addinivalue_line(
         "markers", "fault_matrix: end-to-end fault-injection recovery "
         "scenarios (subprocess-based); run standalone via "

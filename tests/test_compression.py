@@ -286,10 +286,29 @@ def test_e2e_parity_quant_on_vs_off():
     q_losses, q = _run(2, _quant_strategy())
     # quantization noise must not derail the trajectory
     np.testing.assert_allclose(q_losses, base_losses, rtol=0.05, atol=0.02)
+    # What parity of the parameters can mean under AdamW. The bias-corrected
+    # update |m1^| / sqrt(m2^) is at most 1.03 over the first 8 steps
+    # (Cauchy-Schwarz over the two moving averages), so a step moves an
+    # element by about lr whatever the size of its gradient. The quantiser's
+    # error is unbiased and below one step (absmax / 127 of the element's
+    # block of 64), so it turns an update around only where a gradient lies
+    # within a step of zero -- or where, on a batch of 4 rows, the small
+    # shift of the weights flips a ReLU unit on one row and with it that
+    # unit's whole column of gradients (0.weight[26, 45] in the third step
+    # here: +1.1e-2 against -6.6e-3 at weights 2e-5 apart). Such an element
+    # then runs the other way at lr a step, 2 * lr * steps = 0.16 at the
+    # most. An element-wise atol of 0.02, two steps' worth, failed on 1 of
+    # 2,048 elements for three model seeds of six. So an element is held to
+    # what a turned direction reaches in half the run, lr * steps = 0.08
+    # (largest seen over six seeds 0.041), and a tensor, since turns are
+    # rare, to a relative L2 error of 10% (seen: weights 0.9-1.6%, the
+    # zero-initialised biases 0.9-5.9%; a trajectory that ignored the
+    # gradients would sit near lr * steps / rms(p), 80%).
+    lr, steps = 1e-2, len(q_losses)
     for k in base._params:
-        np.testing.assert_allclose(
-            np.asarray(q._params[k]), np.asarray(base._params[k]),
-            rtol=0.1, atol=0.02, err_msg=k)
+        got, want = np.asarray(q._params[k]), np.asarray(base._params[k])
+        assert np.linalg.norm(got - want) <= 0.1 * np.linalg.norm(want), k
+        assert np.abs(got - want).max() <= lr * steps, k
     assert q_losses[-1] < q_losses[0]  # it actually trains
 
 

@@ -1,5 +1,5 @@
 """Training goodput ledger (ISSUE 10): phase attribution that tiles the
-trainer's wall clock, live MFU sharing bench.py's analytic-FLOPs
+trainer's wall clock, live MFU from obs.flops' analytic-FLOPs
 helpers, the recompile sentinel (jax.monitoring + jit-cache fallback),
 HBM telemetry + OOM forensics, and the rollback-storm fault-matrix
 scenario proving a faulted run books rollback_waste, drops goodput, and
@@ -396,9 +396,8 @@ def test_faulted_run_reconciles_phases_against_wall_clock(tmp_path):
 
 
 def test_live_mfu_matches_offline_formula_on_clean_run(tmp_path):
-    """Acceptance: live MFU (ledger) and the offline number computed the
-    way bench.py computes it — same obs.flops helpers, wall measured
-    around the run — agree within 5%."""
+    """Acceptance: live MFU (ledger) and the same obs.flops arithmetic
+    over a wall clock measured around the run agree within 5%."""
     from paddle_tpu.obs.flops import peak_flops, train_flops_per_step
 
     flops_per_step = train_flops_per_step(1e6, tokens_per_step=64)

@@ -86,8 +86,7 @@ def test_fused_ce_bf16_compute():
     assert gh.dtype == jnp.bfloat16 and gw.dtype == jnp.bfloat16
 
 
-def test_gpt_model_loss_matches_dense_path():
-    import os
+def test_gpt_model_loss_matches_dense_path(monkeypatch):
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import GPTForCausalLM
 
@@ -99,9 +98,7 @@ def test_gpt_model_loss_matches_dense_path():
     labels = paddle.to_tensor(rng.randint(
         0, model.config.vocab_size, (2, 32)).astype(np.int32))
     loss_fused = float(model(ids, labels).item())
-    os.environ["FLAGS_fused_lm_ce"] = "0"
-    try:
-        loss_dense = float(model(ids, labels).item())
-    finally:
-        os.environ.pop("FLAGS_fused_lm_ce")
+    monkeypatch.setattr(GPTForCausalLM, "_can_fuse_lm_ce",
+                        staticmethod(lambda: False))
+    loss_dense = float(model(ids, labels).item())
     np.testing.assert_allclose(loss_fused, loss_dense, rtol=2e-4)
