@@ -171,15 +171,22 @@ def make_decoder_fns(model):
     row inside the step, so K adapters share one executable and bank row
     0 (all-zeros) keeps adapter-less rows bit-identical to base. Left as
     None, the adapted projections are not even traced.
+
+    `prefill` also accepts `pack` (`ops.attention.TokenPack`): `prompt` is
+    then `[T, 1]`, the live tokens of a serving step as rows of width one,
+    `pos [T]` each token's own position and `adapter_idx [T]` its slot's;
+    the logits come back `[T, 1, V]` (the engine's `_step`). Left as None
+    it is not traced either.
     """
     params, buffers = model.functional_state()
 
-    def prefill(p, prompt, caches_, pos, paged=None, adapters=None):
+    def prefill(p, prompt, caches_, pos, paged=None, adapters=None,
+                pack=None):
         with model._bound_state(p, buffers), no_grad():
             logits, new_caches = model.forward_with_cache(
                 Tensor(prompt),
                 [(Tensor(k), Tensor(v)) for k, v in caches_], pos,
-                paged=paged, adapters=adapters)
+                paged=paged, adapters=adapters, pack=pack)
         return logits.data, [(k.data, v.data) for k, v in new_caches]
 
     def decode_step(p, tok, pos, caches_, paged=None, adapters=None):

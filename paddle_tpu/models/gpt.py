@@ -85,7 +85,7 @@ class GPTAttention(Layer):
         self.dropout_p = config.attention_dropout_prob
 
     def forward(self, hidden, cache=None, pos=None, paged=None,
-                adapters=None):
+                adapters=None, pack=None):
         qkv = self.qkv_proj(hidden)
         hd = self.head_dim
         if cache is not None:
@@ -101,7 +101,11 @@ class GPTAttention(Layer):
                 # pos_ scalar: whole batch at one offset (generate());
                 # pos_ [B]: per-row offsets (slot-paged decode, ISSUE 5).
                 # `paged` (closed over — constants, not Tensors) routes
-                # attention through the slot-pool block tables (ISSUE 7)
+                # attention through the slot-pool block tables (ISSUE 7).
+                # `pack` (closed over too): `a` holds packed tokens;
+                # attention runs in the slots' layout at their offsets
+                if pack is not None:
+                    a, pos_ = pack.unpack(a), pack.slot_pos
                 B, T = a.shape[0], a.shape[1]
                 n_local = a.shape[-1] // (3 * hd)
                 a4 = a.reshape(B, T, n_local, 3 * hd)
@@ -113,8 +117,10 @@ class GPTAttention(Layer):
                 out = decode_attention(qh, kc, vc, pos_,
                                        scale=1.0 / (hd ** 0.5),
                                        paged=paged)
-                return (jnp.swapaxes(out, 1, 2).reshape(B, T, -1),
-                        kc, vc)
+                out = jnp.swapaxes(out, 1, 2).reshape(B, T, -1)
+                if pack is not None:
+                    out = pack.pack(out)
+                return out, kc, vc
 
             ctx, new_k, new_v = apply(attn_dec, qkv, k_cache, v_cache, pos)
             out = self.out_proj(ctx)
@@ -178,7 +184,8 @@ class GPTDecoderLayer(Layer):
             aux = None
         return x + self.dropout(h), aux
 
-    def forward(self, x, cache=None, pos=None, paged=None, adapters=None):
+    def forward(self, x, cache=None, pos=None, paged=None, adapters=None,
+                pack=None):
         if cache is not None:
             if self.use_moe:
                 raise NotImplementedError(
@@ -189,7 +196,7 @@ class GPTDecoderLayer(Layer):
                     "nn/layer/moe.py::DroplessMoE")
             h, new_cache = self.self_attn(self.norm1(x), cache=cache,
                                           pos=pos, paged=paged,
-                                          adapters=adapters)
+                                          adapters=adapters, pack=pack)
             # same dropout as the training forward (identity in eval), so
             # forward_with_cache on a training-mode model matches forward()
             x = x + self.dropout(h)
@@ -235,11 +242,13 @@ class GPTModel(Layer):
                                     epsilon=config.layer_norm_eps)
 
     def forward(self, input_ids, caches=None, pos=None, paged=None,
-                adapters=None):
+                adapters=None, pack=None):
         """Returns (hidden, total_aux_loss) — aux is None for dense models.
         With caches: (hidden, new_caches), positions offset by `pos`.
         `adapters` is the per-slot LoRA indirection operand
-        (per_layer_banks, adapter_idx, scale) — see ops/lora.py."""
+        (per_layer_banks, adapter_idx, scale) — see ops/lora.py. `pack`
+        (`ops.attention.TokenPack`): the rows are a serving step's packed
+        tokens, one each, and only attention needs to know."""
         S = input_ids.shape[1]
         from ..core.tensor import Tensor, apply as _apply
         from ..tensor.creation import arange
@@ -259,7 +268,8 @@ class GPTModel(Layer):
                 layer_ad = None if adapters is None else (
                     adapters[0][i], adapters[1], adapters[2])
                 hidden, nc = layer(hidden, cache=cache, pos=pos,
-                                   paged=paged, adapters=layer_ad)
+                                   paged=paged, adapters=layer_ad,
+                                   pack=pack)
                 new_caches.append(nc)
             return self.final_norm(hidden), new_caches
         pos_ids = arange(S, dtype="int64")
@@ -343,9 +353,11 @@ class GPTForCausalLM(Layer):
                 for _ in range(cfg.num_hidden_layers)]
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
-                           adapters=None):
+                           adapters=None, pack=None):
+        """`pack`: see `LlamaForCausalLM.forward_with_cache`."""
         hidden, new_caches = self.gpt(input_ids, caches=caches, pos=pos,
-                                      paged=paged, adapters=adapters)
+                                      paged=paged, adapters=adapters,
+                                      pack=pack)
         return self.lm_head(hidden), new_caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,

@@ -142,7 +142,7 @@ class LlamaAttention(Layer):
                                   config.rms_norm_eps)
 
     def forward(self, hidden, attn_mask=None, cache=None, pos=None,
-                paged=None, adapters=None):
+                paged=None, adapters=None, pack=None):
         if attn_mask is not None:
             raise NotImplementedError(
                 "padding masks are not wired into the fused attention yet; "
@@ -169,7 +169,7 @@ class LlamaAttention(Layer):
         if cache is not None:
             return self._forward_cached(q, k, v, cache, pos, n_rep, hd,
                                         theta, paged=paged,
-                                        adapters=adapters)
+                                        adapters=adapters, pack=pack)
 
         def attn(qa, ka, va):
             qh = qa.reshape(qa.shape[0], qa.shape[1], -1, hd)
@@ -194,17 +194,23 @@ class LlamaAttention(Layer):
         return self.o_proj(ctx)
 
     def _forward_cached(self, q, k, v, cache, pos, n_rep, hd, theta,
-                        paged=None, adapters=None):
+                        paged=None, adapters=None, pack=None):
         """Static-shape KV-cache decode/prefill step (jit/scan friendly):
         new k/v are written into the [B, Hkv, Lmax, D] cache at `pos`,
         attention runs over the FULL cache with an absolute-position causal
         mask (cols <= pos + t). No reference analog (Paddle 2.1 core has no
-        generation loop) — TPU-first inference parity-plus."""
+        generation loop) — TPU-first inference parity-plus. With `pack`
+        (`ops.attention.TokenPack`) q/k/v arrive as packed tokens and
+        attention runs in the slots' own layout, the context packed again
+        behind it."""
         k_cache, v_cache = cache
 
         def attn_dec(qa, ka, va, kc, vc, pos_):
             import jax.numpy as jnp
             from jax import lax
+            if pack is not None:
+                qa, ka, va = pack.unpack(qa), pack.unpack(ka), pack.unpack(va)
+                pos_ = pack.slot_pos
             B, T = qa.shape[0], qa.shape[1]
             Lmax = kc.shape[2]
             qh = jnp.swapaxes(qa.reshape(B, T, -1, hd), 1, 2)
@@ -230,6 +236,8 @@ class LlamaAttention(Layer):
             out = decode_attention(qh, kc, vc, pos_,
                                    scale=1.0 / (hd ** 0.5), paged=paged)
             out = jnp.swapaxes(out, 1, 2).reshape(B, T, -1)
+            if pack is not None:
+                out = pack.pack(out)
             return out, kc, vc
 
         ctx, new_k, new_v = apply(attn_dec, q, k, v, k_cache, v_cache, pos)
@@ -293,12 +301,13 @@ class LlamaDecoderLayer(Layer):
         return residual + h
 
     def forward(self, hidden, cache=None, pos=None, paged=None,
-                adapters=None, live=None):
+                adapters=None, live=None, pack=None):
         if cache is not None:
             residual = hidden
             h, new_cache = self.self_attn(self.input_layernorm(hidden),
                                           cache=cache, pos=pos,
-                                          paged=paged, adapters=adapters)
+                                          paged=paged, adapters=adapters,
+                                          pack=pack)
             hidden = residual + h
             h = self.post_attention_layernorm(hidden)
             # a dense MLP computes padding too and nobody reads it; a
@@ -323,11 +332,13 @@ class LlamaModel(Layer):
         self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, input_ids, caches=None, pos=None, paged=None,
-                adapters=None):
+                adapters=None, pack=None):
         hidden = self.embed_tokens(input_ids)
         if caches is not None:
             live = None
-            if paged is not None and self.config.num_experts:
+            if pack is not None:
+                live = pack.live[:, None]
+            elif paged is not None and self.config.num_experts:
                 # column t of row b is a real token while pos[b] + t is
                 # short of the row's length after this step (`paged[1]`):
                 # the rest of a decode row, and all of a free slot, is
@@ -341,7 +352,7 @@ class LlamaModel(Layer):
                     adapters[0][i], adapters[1], adapters[2])
                 hidden, nc = layer(hidden, cache=cache, pos=pos,
                                    paged=paged, adapters=layer_ad,
-                                   live=live)
+                                   live=live, pack=pack)
                 new_caches.append(nc)
             return self.norm(hidden), new_caches
         for layer in self.layers:
@@ -391,9 +402,14 @@ class LlamaForCausalLM(Layer):
                 for _ in range(cfg.num_hidden_layers)]
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
-                           adapters=None):
+                           adapters=None, pack=None):
+        """`pack` (`ops.attention.TokenPack`, the serving step's): the
+        rows of `input_ids [T, 1]` are a step's live tokens, each at its
+        own `pos [T]`, and `pack` tells attention which slot and column
+        each belongs to. The logits come back packed, `[T, 1, V]`."""
         hidden, new_caches = self.llama(input_ids, caches=caches, pos=pos,
-                                        paged=paged, adapters=adapters)
+                                        paged=paged, adapters=adapters,
+                                        pack=pack)
         return self.lm_head(hidden), new_caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,

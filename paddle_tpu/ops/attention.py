@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -804,6 +804,65 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 # exp(-1e30 - row_max) underflows to exact fp32 0.0, so padded cache tail
 # and foreign batch rows contribute nothing to any softmax numerator or
 # denominator.
+
+class TokenPack(NamedTuple):
+    """Where the live tokens of a `[slots N, chunk C]` step sit in one
+    `[T, 1]` block, and back (`token_pack` builds it inside the step).
+
+    A serving step gives every slot a C-wide row and `adv[n]` says how many
+    of its columns hold a token: one for a decode row, none for a free
+    slot. Whatever is a function of one token (embedding, norms,
+    projections, MLP or experts, the head, the sampler) runs on the packed
+    block, T rows of width one, token t at its own position `pos[t]`: to
+    that code a packed step is a batch of T one-token sequences. Attention
+    alone needs a token's slot (its cache row, its page table, its chunk
+    mates), so the two decoder stacks `unpack` q/k/v into `[N, C, ·]` just
+    before RoPE, the KV write and `decode_attention`, run those at
+    `slot_pos` exactly as an unpacked step does, and `pack` the context
+    again. Packed positions past the live ones repeat slot 0's column 0
+    and columns past `adv` read packed position T - 1: both hold finite
+    values nobody reads, as the padding of an unpacked step does.
+    """
+    src: jax.Array       # [T] n * C + c of the column token t holds
+    dst: jax.Array       # [N, C] packed position of column c of slot n
+    slot: jax.Array      # [T] the slot n of token t
+    col: jax.Array       # [T] its column c in that slot's row
+    pos: jax.Array       # [T] its absolute position, slot_pos[n] + c
+    live: jax.Array      # [T] bool: t < sum(adv)
+    last: jax.Array      # [N] packed position of slot n's last live column
+    slot_pos: jax.Array  # [N] each slot's write offset (the step's `pos`)
+
+    def pack(self, x):
+        """`[N, C, ...]` -> `[T, 1, ...]`."""
+        flat = x.reshape((-1,) + x.shape[2:])
+        return jnp.take(flat, self.src, axis=0, mode="clip")[:, None]
+
+    def unpack(self, x):
+        """`[T, 1, ...]` -> `[N, C, ...]`."""
+        return jnp.take(x[:, 0], self.dst, axis=0, mode="clip")
+
+
+def token_pack(adv, pos, chunk: int, step_tokens: int) -> TokenPack:
+    """The pack index of one step from its `adv [N]` and `pos [N]` alone:
+    slot n's live columns follow slot n - 1's (a cumulative sum; every
+    shape is static). The caller keeps `sum(adv) <= step_tokens`."""
+    adv = adv.astype(jnp.int32)
+    pos = pos.astype(jnp.int32)
+    end = jnp.cumsum(adv)
+    start = end - adv
+    t = jnp.arange(step_tokens, dtype=jnp.int32)
+    live = t < end[-1]
+    # slots that end at or before t: t's own slot (free slots are passed)
+    slot = jnp.where(live, jnp.sum(t[:, None] >= end[None, :], axis=1,
+                                   dtype=jnp.int32), 0)
+    col = jnp.where(live, t - start[slot], 0)
+    c = jnp.arange(chunk, dtype=jnp.int32)
+    dst = jnp.where(c[None, :] < adv[:, None], start[:, None] + c[None, :],
+                    step_tokens - 1)
+    return TokenPack(src=slot * chunk + col, dst=dst, slot=slot, col=col,
+                     pos=pos[slot] + col, live=live,
+                     last=jnp.maximum(end - 1, 0), slot_pos=pos)
+
 
 def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
     """Write k/v [B, Hkv, T, D] into static [B, Hkv, L, D] caches at `pos`.

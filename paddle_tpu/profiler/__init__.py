@@ -42,7 +42,8 @@ SPAN_SERVE_DRAFT = "pdtpu/serve/draft"            # _draft_phase (if armed)
 SPAN_SERVE_BUILD_ROWS = "pdtpu/serve/build_rows"  # rows, kinds, operands
 SPAN_SERVE_DISPATCH = "pdtpu/serve/dispatch"      # upload + launch;
 #                                                   prefill_rows=, decode_rows=,
-#                                                   sampled_rows=, live_tokens=
+#                                                   sampled_rows=, live_tokens=,
+#                                                   step_tokens=, deferred_rows=
 SPAN_SERVE_FETCH = "pdtpu/serve/fetch"            # host waits for the device
 SPAN_SERVE_COMMIT = "pdtpu/serve/commit"          # acceptance .. retire
 SPAN_SERVE_PUBLISH = "pdtpu/serve/publish"        # gauges after the step

@@ -29,6 +29,19 @@ continuously-batched service:
   per-row target lengths, and emits each row's next greedy token. No
   per-pow2-bucket prefill executable zoo, no bucket padding FLOPs: the
   engine compiles exactly one step program for its lifetime;
+- **token packing**: the `[slots, prefill_chunk]` rows are the step's
+  operands and results, not what it computes. An engine wider than
+  `MIN_STEP_TOKENS` positions packs the columns that hold a token into one
+  `[step_tokens, 1]` block inside the executable (`ops.attention.
+  token_pack`, from `adv` and `pos` alone) and runs every token-wise
+  operation — embedding, norms, projections, MLP or experts, LoRA deltas,
+  the head, the sampler, the log-softmax — on that; attention alone
+  unpacks to the slots' layout. `step_tokens = min(slots * chunk,
+  max(slots * (1 + draft window) + chunk, MIN_STEP_TOKENS))` follows from
+  the engine's shapes, so a decode row costs one position and not
+  `prefill_chunk`. The scheduler keeps a step's live tokens within it:
+  decode rows always fit, prefill rows ride oldest first with their whole
+  chunk or wait a step (`prefill_rows_deferred`);
 - **chunked prefill**: prompts longer than `prefill_chunk` are admitted
   as fixed-size chunks interleaved with the decode loop, so a short
   prompt's TTFT is bounded by a couple of chunk-width steps instead of a
@@ -127,6 +140,7 @@ import numpy as np
 from ...nn.layer import moe
 from ...obs.flight_recorder import flight_recorder
 from ...obs.trace import RequestTrace, TimelineStore, new_request_id
+from ...ops.attention import token_pack
 from ...profiler import (SPAN_SERVE_ADMIT, SPAN_SERVE_BUILD_ROWS,
                          SPAN_SERVE_COMMIT, SPAN_SERVE_DISPATCH,
                          SPAN_SERVE_DRAFT, SPAN_SERVE_FETCH,
@@ -152,6 +166,15 @@ _log = logging.getLogger("paddle_tpu.serving.llm")
 # `breakdown` are keyed on it). Pinned by tests/test_trace_spans.py: do not
 # rename.
 UNIFIED_STEP_NAME = "step"
+
+# The fewest packed positions a step computes (`LLMEngine.step_tokens`). A
+# bf16 matmul on a TPU v5e is bound by reading its weights while it has
+# fewer than 197e12 FLOP/s / 819e9 B/s = 240 rows, and costs the same
+# whatever it has below that; 512 rows cost about twice that floor, and
+# beside 128 decode rows they leave the prefill 24 whole 16-token chunks a
+# step. An engine whose `num_slots * prefill_chunk` is no more than this
+# computes every column, as it always did.
+MIN_STEP_TOKENS = 512
 
 
 class WeightSwapError(ValueError):
@@ -625,6 +648,15 @@ class LLMEngine:
         self._brownout = False
         self._thread: Optional[threading.Thread] = None
         self._step_jit = None        # the ONE unified step executable
+        # packed positions the step computes: every decode row with its
+        # draft window always fits beside one whole prefill chunk, and the
+        # step is never narrower than MIN_STEP_TOKENS nor wider than the
+        # `[slots, chunk]` block, at which width nothing is packed
+        window = 1 + (self.config.spec_k if draft_model is not None else 0)
+        self.step_tokens = min(
+            self.config.num_slots * self.config.prefill_chunk,
+            max(self.config.num_slots * window + self.config.prefill_chunk,
+                MIN_STEP_TOKENS))
         # sparse experts: the model's dropless expert layers, found by
         # their type (nothing here knows which model holds them). Their
         # per-layer per-expert totals of live assignments `[L, E]` live on
@@ -737,11 +769,25 @@ class LLMEngine:
         window in this one dispatch (free rows emit harmless selections
         of fully-masked rows). All sampling inputs are traced [N]
         arrays + the fixed-shape DFA bank, so the mix of request params
-        never changes the executable."""
+        never changes the executable.
+
+        Operands and results keep that `[N, C]` layout whatever happens
+        inside. Where `step_tokens < N * C` the executable packs the live
+        columns (`sum(adv) <= step_tokens`, the scheduler's budget) into
+        `step_tokens` rows of width one (`ops.attention.token_pack`, from
+        `adv` and `pos` alone), runs the model, the sampler and the
+        log-softmax on those, attention alone in the slots' layout, and
+        unpacks `sel` and `lp` on the device: a decode row costs one
+        position, not C."""
         if self._step_jit is None:
             block_len = self.pool.block_len
             pages_per_row = self.pool.n_blocks
             prefill = self._prefill_fn
+            chunk = self.config.prefill_chunk
+            step_tokens = self.step_tokens
+            # a Python branch on static shapes: at the block's own width
+            # the pack is not traced and the step is the program it was
+            packed = step_tokens < self.pool.num_slots * chunk
 
             def step(params, toks, pos, adv, table, slabs, temp, topk,
                      topp, samp, seed, ctr, dstate, gid, bank,
@@ -754,10 +800,28 @@ class LLMEngine:
                 # as adapters load/swap — zero recompiles either way.
                 seq_lens = (pos + adv).astype(jnp.int32)
                 paged = (table, seq_lens, block_len, pages_per_row)
+                pack = None
+                rows_adv, rows_dstate = adv, dstate
+                if packed:
+                    # the live tokens as `step_tokens` rows of width one,
+                    # each with its slot's operands: what follows reads
+                    # them as it reads an unpacked step's rows
+                    pack = token_pack(adv, pos, chunk, step_tokens)
+                    toks, pos = pack.pack(toks), pack.pos
+                    adv = pack.live.astype(jnp.int32)
+                    temp, topk, topp, seed, dstate, gid = (
+                        a[pack.slot]
+                        for a in (temp, topk, topp, seed, dstate, gid))
+                    samp = samp[pack.slot] & pack.live
+                    ctr = ctr[pack.slot] + pack.col
+                    if adapters is not None:
+                        banks, adapter_idx, scale = adapters
+                        adapters = (banks, adapter_idx[pack.slot], scale)
                 with moe.collect_expert_counts() as expert_counts:
                     logits, new_slabs = prefill(params, toks, slabs, pos,
                                                 paged=paged,
-                                                adapters=adapters)
+                                                adapters=adapters,
+                                                pack=pack)
                 sel, new_state = select_tokens(
                     logits, adv, temp, topk, topp, samp, seed, ctr,
                     dstate, gid, bank)
@@ -772,6 +836,12 @@ class LLMEngine:
                 lp = jnp.take_along_axis(
                     jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
                     sel[..., None].astype(jnp.int32), axis=-1)[..., 0]
+                if packed:
+                    # back to the slots' layout the host reads; a slot's
+                    # DFA state is the one behind its last live token
+                    sel, lp = pack.unpack(sel), pack.unpack(lp)
+                    new_state = jnp.where(rows_adv > 0, new_state[pack.last],
+                                          rows_dstate)
                 if moe_totals is None:
                     return sel, lp, new_state, new_slabs
                 # a sparse model (the only kind that is handed totals):
@@ -2408,10 +2478,18 @@ class LLMEngine:
     def _build_rows_locked(self, spec_drafts=None):
         """Assemble the unified step's host-side row set from the active
         table: (toks [N, C], pos [N], adv [N], ctr [N], prefill_slots,
-        decode_slots). Free slots stay all-zero (adv=0 → fully masked).
-        A decode row with a draft window (ISSUE 17) carries
+        decode_slots, deferred). Free slots stay all-zero (adv=0 → fully
+        masked). A decode row with a draft window (ISSUE 17) carries
         [last_tok, d1..dk] at adv=1+k — the verify chunk; plain decode
         rows stay [last_tok] at adv=1.
+
+        The step computes `step_tokens` positions, so `sum(adv)` stays
+        within them: a prefill row whose chunk no longer fits waits this
+        step as a free slot does (adv=0, stripe in the pad region; not
+        in `prefill_slots`, counted in `deferred`) and rides a later one
+        with the same chunk, so a request's chunk boundaries, and with
+        them its arithmetic, do not depend on its neighbours. The oldest
+        prefill row always fits: nobody starves.
 
         `ctr` (ISSUE 18) is each row's RNG-lane stream index for column
         0: decode rows sit at `sample_offset + emitted` (column t draws
@@ -2433,28 +2511,39 @@ class LLMEngine:
         adv = np.zeros((N,), np.int32)
         prefill_slots: List[int] = []
         decode_slots: List[int] = []
+        waiting: List[int] = []
         for slot, req in self._active.items():
-            plen = len(req.prompt)
-            base = req.sample_offset + len(req.emitted)
-            if req.chunk_off < plen:
-                off = req.chunk_off
-                n = min(C, plen - off)
-                toks[slot, :n] = req.prompt[off:off + n]
-                pos[slot] = off
-                adv[slot] = n
-                ctr[slot] = base - (n - 1)
-                prefill_slots.append(slot)
-            else:
-                drafts = (spec_drafts.get(slot, ())
-                          if spec_drafts else ())
-                toks[slot, 0] = req.last_tok
-                for j, d in enumerate(drafts):
-                    toks[slot, 1 + j] = d
-                pos[slot] = self.pool.lengths[slot]
-                adv[slot] = 1 + len(drafts)
-                ctr[slot] = base
-                decode_slots.append(slot)
-        return toks, pos, adv, ctr, prefill_slots, decode_slots
+            if req.chunk_off < len(req.prompt):
+                waiting.append(slot)
+                continue
+            drafts = (spec_drafts.get(slot, ())
+                      if spec_drafts else ())
+            toks[slot, 0] = req.last_tok
+            for j, d in enumerate(drafts):
+                toks[slot, 1 + j] = d
+            pos[slot] = self.pool.lengths[slot]
+            adv[slot] = 1 + len(drafts)
+            ctr[slot] = req.sample_offset + len(req.emitted)
+            decode_slots.append(slot)
+        # the decode rows always fit (`step_tokens`' first term); the
+        # prefill rows take what is left, oldest admitted first (`_active`
+        # keeps admission order), each its whole chunk or none of it
+        budget = self.step_tokens - int(adv.sum())
+        deferred = 0
+        for slot in waiting:
+            req = self._active[slot]
+            off = req.chunk_off
+            n = min(C, len(req.prompt) - off)
+            if n > budget:
+                deferred += 1
+                continue
+            budget -= n
+            toks[slot, :n] = req.prompt[off:off + n]
+            pos[slot] = off
+            adv[slot] = n
+            ctr[slot] = req.sample_offset + len(req.emitted) - (n - 1)
+            prefill_slots.append(slot)
+        return toks, pos, adv, ctr, prefill_slots, decode_slots, deferred
 
     def _kinds_of(self, prefill_slots, decode_slots) -> Tuple:
         """(kind, request_ids) announcement order for fault injection:
@@ -2499,8 +2588,8 @@ class LLMEngine:
             with RecordEvent(SPAN_SERVE_BUILD_ROWS), self._cond:
                 if not self._active:
                     return 0
-                toks, pos, adv, ctr, prefill_slots, decode_slots = \
-                    self._build_rows_locked(spec_drafts)
+                toks, pos, adv, ctr, prefill_slots, decode_slots, \
+                    deferred = self._build_rows_locked(spec_drafts)
                 kinds = self._kinds_of(prefill_slots, decode_slots)
                 # rows of this step that draw: what the step's sampler
                 # branches on (a freed slot is cleared to greedy, so the
@@ -2519,15 +2608,18 @@ class LLMEngine:
             self.metrics.on_mask_overhead(mask_dt * 1e3)
             if self.ledger is not None:
                 self.ledger.book("sample_mask", mask_dt)
-            # positions of this step that hold a real token: all a dense
-            # model wastes on the rest is arithmetic, a sparse one must
-            # keep them out of its experts
+            # positions of this step that hold a real token, of the
+            # `step_tokens` it computes: all a dense model wastes on the
+            # rest is arithmetic, a sparse one must keep them out of its
+            # experts
             live_tokens = int(adv.sum())
             with RecordEvent(SPAN_SERVE_DISPATCH,
                              prefill_rows=len(prefill_slots),
                              decode_rows=len(decode_slots),
                              sampled_rows=sampled_rows,
-                             live_tokens=live_tokens):
+                             live_tokens=live_tokens,
+                             step_tokens=self.step_tokens,
+                             deferred_rows=deferred):
                 t0 = self.clock.now()
                 fn = self._step()
                 args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
@@ -2576,6 +2668,8 @@ class LLMEngine:
                         self._moe_totals, = moe_out
                         self.metrics.on_moe_assignments(
                             live_tokens * self._moe_per_token)
+                    self.metrics.on_step_tokens(live_tokens,
+                                                self.step_tokens, deferred)
                     if decode_slots:
                         # the breaker tracks ENGINE-level (decode-protocol)
                         # failures; prefill-only successes must not launder
@@ -2650,7 +2744,7 @@ class LLMEngine:
                     prefill_positions=int(sum(adv[s]
                                               for s in prefill_slots)),
                     decode_positions=decode_useful,
-                    total_positions=int(toks.size),
+                    total_positions=self.step_tokens,
                     owners=owners,
                     drafted=drafted, draft_accepted=accepted,
                     adapter_owners=(adapter_owners

@@ -272,6 +272,9 @@ class LLMMetrics(ServingMetrics):
         self.counters.update({"prefills": 0, "decode_steps": 0,
                               "unified_steps": 0,
                               "sampler_filter_steps": 0,
+                              "step_tokens_live": 0,
+                              "step_tokens_computed": 0,
+                              "prefill_rows_deferred": 0,
                               "moe_assignments": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
                               "brownout_entries": 0,
@@ -489,6 +492,18 @@ class LLMMetrics(ServingMetrics):
         with self._lock:
             self.counters["sampler_filter_steps"] += 1
 
+    def on_step_tokens(self, live: int, computed: int, deferred: int):
+        """One committed unified step: `live` of the `computed` positions
+        it ran held a token (`computed` is the engine's `step_tokens`:
+        the packed width, or slots x chunk where nothing is packed), and
+        `deferred` prefill rows waited for a later step because their
+        chunk did not fit. `step_tokens_live / step_tokens_computed` is
+        the share of the step's arithmetic that somebody reads."""
+        with self._lock:
+            self.counters["step_tokens_live"] += int(live)
+            self.counters["step_tokens_computed"] += int(computed)
+            self.counters["prefill_rows_deferred"] += int(deferred)
+
     def on_moe_assignments(self, n: int):
         """One committed unified step of a sparse model routed `n`
         (position, expert) pairs: its live tokens x experts per token x
@@ -689,6 +704,10 @@ class LLMMetrics(ServingMetrics):
         b.family(f"{px}_sampler_filter_steps_total", "counter")
         b.sample(f"{px}_sampler_filter_steps_total",
                  s["sampler_filter_steps"])
+        for name in ("step_tokens_live", "step_tokens_computed",
+                     "prefill_rows_deferred"):
+            b.family(f"{px}_{name}_total", "counter")
+            b.sample(f"{px}_{name}_total", s[name])
         b.family(f"{px}_prefills_total", "counter")
         b.sample(f"{px}_prefills_total", s["prefills"])
         if self.moe_source is not None:

@@ -368,7 +368,7 @@ def _lowered_step(model):
     eng.submit(_prompts()[0], max_new_tokens=2)
     with eng._cond:
         eng._admit()
-        toks, pos, adv, ctr, _, _ = eng._build_rows_locked({})
+        toks, pos, adv, ctr, *_ = eng._build_rows_locked({})
         args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(adv), eng.pool.device_block_table(),
                 eng.pool.slabs) + eng._sampling_args_locked(ctr) \
