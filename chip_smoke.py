@@ -15,7 +15,8 @@ weights are random, from a seed):
 
 Each leg first checks the Pallas kernel it depends on against the repo's
 own reference ON THE DEVICE (flash fwd + dq/dk/dv vs `_attention_reference`;
-`ragged_paged_attention(impl="pallas")` vs `impl="scan"`) and then requires
+`ragged_paged_attention(impl="pallas")` and `ssm_update(impl="pallas")` vs
+`impl="scan"`) and then requires
 that kernel's Mosaic custom calls in the compiled step it just ran. Any
 failed check raises; nothing is caught to let a leg fail while the run
 exits 0.
@@ -60,6 +61,8 @@ FULL = dict(
     # and OLMoE (MHA 16/16), 14 pages of 16 a slot + a chunk of write-padding
     paged=(dict(heads=32, kv_heads=8, head_dim=128, pages=14),
            dict(heads=16, kv_heads=16, head_dim=128, pages=14)),
+    # granite-4.0-h-small's Mamba-2 state: 128 heads x 64, 128 channels
+    ssm=dict(heads=128, head_dim=64, state=128),
 )
 TINY = dict(
     preset="gpt2-tiny", batch_per_chip=2, seq=128, scan_steps=2,
@@ -67,6 +70,7 @@ TINY = dict(
     slots=4, n_blocks=31, prompts=(12, 40, 100, 200), max_new=8,
     paged=(dict(heads=4, kv_heads=2, head_dim=64, pages=4),
            dict(heads=2, kv_heads=2, head_dim=64, pages=4)),
+    ssm=dict(heads=4, head_dim=64, state=16),
 )
 
 
@@ -437,6 +441,54 @@ def _paged_parity(size: dict):
                      f"paged H={H}/{Hkv} Tq={Tq} within {tol:g}")
 
 
+def _ssm_parity(size: dict):
+    """`ssm_update(impl="pallas")` against `impl="scan"` on this device at
+    `size["ssm"]`, bf16 state and inputs, 8 rows of 16, of 1 and of 80
+    columns (80: past `MAX_COLUMNS`, the chunked walk `generate()`'s whole
+    prompt takes) with ragged `adv` (a dead row, a row that starts from
+    zero, a row that ends inside the second chunk). Both run
+    the same float32 arithmetic column after column and differ in the
+    order of one sum over the state's channels and one bf16 rounding of
+    `y` and of the state: 2e-2 of the largest value. Prints the kernel's
+    grid and state tile."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import pallas_mode
+    from paddle_tpu.ops.ssm import ssm_update
+    g = size["ssm"]
+    H, P, N, rows, tol = g["heads"], g["head_dim"], g["state"], 8, 2e-2
+    rng = np.random.RandomState(2)
+    state = jnp.asarray(rng.randn(rows, N, H * P), jnp.bfloat16)
+    a = -jnp.exp(jnp.asarray(rng.randn(H), jnp.float32))
+    for T in (16, 1, 80):
+        x = jnp.asarray(rng.randn(rows, T, H * P), jnp.bfloat16)
+        dt = jax.nn.softplus(jnp.asarray(rng.randn(rows, T, H), jnp.float32))
+        b = jnp.asarray(rng.randn(rows, T, N), jnp.bfloat16)
+        c = jnp.asarray(rng.randn(rows, T, N), jnp.bfloat16)
+        adv = jnp.asarray(np.minimum([T, 1, 0, T, 3, 70, T, 1], T), jnp.int32)
+        fresh = jnp.asarray([0, 0, 0, 1, 0, 1, 0, 0], jnp.int32)
+        pallas_mode.KERNEL_TILINGS.clear()
+        outs = {impl: ssm_update(x, dt, a, b, c, state, adv, fresh,
+                                 impl=impl) for impl in ("pallas", "scan")}
+        (_, tiling), = pallas_mode.KERNEL_TILINGS
+        tiling = dict(tiling)
+        live = (np.arange(T)[None, :] < np.asarray(adv)[:, None])[..., None]
+        y = {k: np.where(live, np.asarray(v[0], np.float32), 0.0)
+             for k, v in outs.items()}
+        scale = max(float(np.abs(y["scan"]).max()), 1.0)
+        err_y = _max_err(y["pallas"], y["scan"]) / scale
+        err_s = _max_err(outs["pallas"][1], outs["scan"][1]) / max(
+            float(jnp.abs(outs["scan"][1].astype(jnp.float32)).max()), 1.0)
+        _say(f"ssm_update pallas vs scan H={H} P={P} N={N} T={T} adv="
+             f"{np.asarray(adv).tolist()} bf16: grid {tiling['grid']}, "
+             f"state tile {tiling['state_tile']}; max err / largest value "
+             f"y {err_y:.2e}, state {err_s:.2e} (tolerance {tol:g})")
+        _require(np.isfinite(err_y + err_s) and max(err_y, err_s) <= tol,
+                 f"ssm_update T={T} within {tol:g}")
+
+
 def _post(port: int, path: str, payload: dict, timeout: float):
     import urllib.request
     req = urllib.request.Request(
@@ -456,6 +508,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
 
     _say("[serve] kernel parity on this device")
     _paged_parity(size)
+    _ssm_parity(size)
 
     import paddle_tpu as paddle
     from paddle_tpu import serving

@@ -16,6 +16,8 @@ per row to batch-locked greedy generate().
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -136,6 +138,19 @@ def _select_token(logits, do_sample, temperature, top_k, key, top_p=1.0):
             + jnp.any(filters).astype(jnp.int32))
     return jax.lax.switch(mode, (lambda: greedy, lambda: _draw(False),
                                  lambda: _draw(True)))
+
+
+class RecurrentState(NamedTuple):
+    """What `init_cache` returns for a layer whose state does not grow
+    with the sequence (a state-space mixer), where an attention layer
+    returns its `(k, v)` slabs: `conv [batch, K - 1, channels]`, the last
+    inputs of the layer's causal convolution, and `ssm [batch, N, H * P]`,
+    the recurrence's state (`ops/ssm.py`). A pair like `(k, v)`, so it
+    rides wherever a cache rides; by its type a cache manager knows that
+    this layer's state is one fixed block per row, valid only at the row's
+    committed length: it has no pages to share, trim or re-read."""
+    conv: jax.Array
+    ssm: jax.Array
 
 
 def make_decoder_fns(model):

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +58,13 @@ class LlamaConfig:
     norm_topk_prob: bool = False
     # RMSNorm over the whole q and the whole k projection, before RoPE
     qk_norm: bool = False
+    # False: no rotary embedding (a model that takes its order from
+    # elsewhere, e.g. state-space layers between its attention layers)
+    rope: bool = True
+    # scores are scaled by this and not by 1/sqrt(head_dim): q is scaled by
+    # `attention_multiplier * sqrt(head_dim)` after its projection, so the
+    # attention kernels keep their own 1/sqrt(head_dim)
+    attention_multiplier: Optional[float] = None
 
     @property
     def head_dim(self):
@@ -166,6 +174,10 @@ class LlamaAttention(Layer):
                                    aidx, ascale)
         if self.config.qk_norm:
             q, k = self.q_norm(q), self.k_norm(k)
+        if self.config.attention_multiplier is not None:
+            q_scale = self.config.attention_multiplier * math.sqrt(hd)
+            q = apply(lambda a: a * q_scale, q)
+        rope = self.config.rope
         if cache is not None:
             return self._forward_cached(q, k, v, cache, pos, n_rep, hd,
                                         theta, paged=paged,
@@ -178,11 +190,12 @@ class LlamaAttention(Layer):
             qh = jnp.swapaxes(qh, 1, 2)   # [B, H, S, D]
             kh = jnp.swapaxes(kh, 1, 2)
             vh = jnp.swapaxes(vh, 1, 2)
-            cos, sin = _rope_cos_sin(qa.shape[1], hd, theta)
-            cos = cos.astype(qh.dtype)[None].squeeze(0)
-            sin = sin.astype(qh.dtype)[None].squeeze(0)
-            qh = _apply_rope(qh, cos, sin)
-            kh = _apply_rope(kh, cos, sin)
+            if rope:
+                cos, sin = _rope_cos_sin(qa.shape[1], hd, theta)
+                cos = cos.astype(qh.dtype)[None].squeeze(0)
+                sin = sin.astype(qh.dtype)[None].squeeze(0)
+                qh = _apply_rope(qh, cos, sin)
+                kh = _apply_rope(kh, cos, sin)
             if n_rep > 1:  # GQA: repeat kv heads
                 kh = jnp.repeat(kh, n_rep, axis=1)
                 vh = jnp.repeat(vh, n_rep, axis=1)
@@ -216,20 +229,24 @@ class LlamaAttention(Layer):
             qh = jnp.swapaxes(qa.reshape(B, T, -1, hd), 1, 2)
             kh = jnp.swapaxes(ka.reshape(B, T, -1, hd), 1, 2)
             vh = jnp.swapaxes(va.reshape(B, T, -1, hd), 1, 2)
-            cos, sin = _rope_cos_sin(Lmax, hd, theta)
-            if jnp.ndim(pos_) == 0:
-                cos_t = lax.dynamic_slice_in_dim(cos, pos_, T, 0)
-                sin_t = lax.dynamic_slice_in_dim(sin, pos_, T, 0)
-            else:
-                # per-row rotation angles for slot-paged decode: each row
-                # sits at its own absolute position → cos/sin [B, T, D]
-                row = jax.vmap(
-                    lambda tab, p: lax.dynamic_slice_in_dim(tab, p, T, 0),
-                    in_axes=(None, 0))
-                cos_t, sin_t = row(cos, pos_), row(sin, pos_)
-            cos_t, sin_t = cos_t.astype(qh.dtype), sin_t.astype(qh.dtype)
-            qh = _apply_rope(qh, cos_t, sin_t)
-            kh = _apply_rope(kh, cos_t, sin_t)
+            if self.config.rope:
+                cos, sin = _rope_cos_sin(Lmax, hd, theta)
+                if jnp.ndim(pos_) == 0:
+                    cos_t = lax.dynamic_slice_in_dim(cos, pos_, T, 0)
+                    sin_t = lax.dynamic_slice_in_dim(sin, pos_, T, 0)
+                else:
+                    # per-row rotation angles for slot-paged decode: each
+                    # row sits at its own absolute position → cos/sin
+                    # [B, T, D]
+                    row = jax.vmap(
+                        lambda tab, p: lax.dynamic_slice_in_dim(tab, p, T,
+                                                                0),
+                        in_axes=(None, 0))
+                    cos_t, sin_t = row(cos, pos_), row(sin, pos_)
+                cos_t = cos_t.astype(qh.dtype)
+                sin_t = sin_t.astype(qh.dtype)
+                qh = _apply_rope(qh, cos_t, sin_t)
+                kh = _apply_rope(kh, cos_t, sin_t)
             kc, vc = update_kv_cache(kc, vc, kh, vh, pos_)
             # `paged` closed over (constants): slot-pool block-table
             # routing for the ragged kernel (ISSUE 7)

@@ -43,6 +43,10 @@ EXPERT_AXIS = "ep"
 # is to kernel paths: always on, and what a benchmark reads after the job,
 # when the engine is gone.
 EXPERT_TOKENS: collections.Counter = collections.Counter()
+# layer -> live positions that layer routed, published with EXPERT_TOKENS:
+# x experts per token, what the layer's row sums to where every expert is
+# held, and what a share's row is a part of.
+ROUTED_TOKENS: collections.Counter = collections.Counter()
 _collecting = threading.local()
 
 
@@ -223,11 +227,19 @@ class MoELayer(Layer):
 
 
 def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
-                         norm_topk_prob=False, live=None):
+                         norm_topk_prob=False, live=None, held=None):
     """Dropless top-k SwiGLU experts over arrays. x `[..., H]`; router_w
     `[H, E]`; w_gate, w_up `[E, H, F]`; w_down `[E, F, H]`; `live` a bool
     mask over x's leading axes (None: every position is live). Returns
     (out like x, counts `[E]` int32 of live assignments per expert).
+
+    `held=(first, count)`: this layer holds experts `first .. first +
+    count - 1` of the router's E (one chip's share under expert
+    parallelism): the weights are `[count, ...]`, routing, top-k and gates
+    are over all E as published, and an assignment to an expert held
+    elsewhere is treated as one of a position that is not live: no row, no
+    count, zeros. The result is this share's part of the layer's sum; the
+    counts are `[count]`. None: every expert is held.
 
         p = softmax_float32(x router_w);  S = the top_k largest p
         out = sum_{e in S} p_e (silu(x Wg_e) * (x Wu_e)) Wd_e
@@ -257,6 +269,9 @@ def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     if live is not None:
         idx = jnp.where(live.reshape(T, 1), idx, E)    # E: no expert
+    if held is not None:
+        first, E = held          # from here on E counts the experts held
+        idx = jnp.where((idx >= first) & (idx < first + E), idx - first, E)
     expert = idx.reshape(-1)                             # [T * top_k]
     counts = jnp.sum(expert[:, None] == jnp.arange(E, dtype=expert.dtype),
                      axis=0, dtype=jnp.int32)
@@ -277,35 +292,43 @@ def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
 class DroplessMoE(Layer):
     """A sparse SwiGLU FFN: a router and `num_experts` experts of width
     `d_hidden`, `top_k` per position, no shared expert, no bias
-    (`moe_dropless_forward`). `forward(x, live=None)`; under
-    `collect_expert_counts()` each call also hands over its per-expert
-    counts of live assignments."""
+    (`moe_dropless_forward`). `held=(first, count)`: the layer holds that
+    share of the experts (its weights are `[count, ...]`, `num_held` =
+    count) and returns the share's part of the sum; None: all of them.
+    `forward(x, live=None)`; under `collect_expert_counts()` each call
+    also hands over its per-expert counts of live assignments (`[num_held]`)."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k,
-                 norm_topk_prob=False):
+                 norm_topk_prob=False, held=None):
         super().__init__()
         if not 0 < top_k <= num_experts:
             raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        if held is not None and not (
+                0 <= held[0] and 0 < held[1]
+                and held[0] + held[1] <= num_experts):
+            raise ValueError(f"held {held} of {num_experts} experts")
         self.num_experts, self.top_k = num_experts, top_k
         self.norm_topk_prob = norm_topk_prob
+        self.held = None if held is None else (int(held[0]), int(held[1]))
+        self.num_held = num_experts if held is None else self.held[1]
         init = I.Normal(0.0, 0.02)
         self.router_weight = self.create_parameter(
             [d_model, num_experts], default_initializer=init)
         self.w_gate = self.create_parameter(
-            [num_experts, d_model, d_hidden], default_initializer=init)
+            [self.num_held, d_model, d_hidden], default_initializer=init)
         self.w_up = self.create_parameter(
-            [num_experts, d_model, d_hidden], default_initializer=init)
+            [self.num_held, d_model, d_hidden], default_initializer=init)
         self.w_down = self.create_parameter(
-            [num_experts, d_hidden, d_model], default_initializer=init)
+            [self.num_held, d_hidden, d_model], default_initializer=init)
         for p in (self.w_gate, self.w_up, self.w_down):
             p.partition_spec = P(EXPERT_AXIS)
 
     def forward(self, x, live=None):
-        top_k, norm = self.top_k, self.norm_topk_prob
+        top_k, norm, held = self.top_k, self.norm_topk_prob, self.held
 
         def f(xa, rw, wg, wu, wd):
             out, counts = moe_dropless_forward(xa, rw, wg, wu, wd, top_k,
-                                               norm, live)
+                                               norm, live, held)
             sink = getattr(_collecting, "sink", None)
             if sink is not None:
                 sink.append(counts)

@@ -53,6 +53,19 @@ lives. When the slot frees, each own page either transfers to the cache
 (it was registered: now *cached*) or is *freed*. Evicting a cache-owned
 page frees it. `blocks_allocated == blocks_freed + blocks_active +
 blocks_cached` at every quiescent point.
+
+A second kind of per-slot state (PR 29). A layer whose `init_cache` entry
+is a `models.generation.RecurrentState` (a state-space mixer) keeps one
+fixed block per slot, `(conv [slots, K - 1, channels], ssm [slots, N,
+H * P])`: not paged, never shared, valid only at the row's committed
+length, zeroed inside the step when a row starts at position 0. It sits
+in `slabs` at its layer's index and rides the step as operand and result
+exactly as the K/V slabs do; `layer_kinds` says which is which. Nothing
+that re-reads, copies or trims pages can serve such a layer: on a pool
+that holds one (`recurrent`), `rewind_length`, `cow_copy`, `export_rows`
+/ `import_rows` and `export_page` / `import_page` raise
+`RecurrentStateError`, and `defrag` leaves the state alone (a freed
+slot's state is dead: the next row in it starts from zero).
 """
 from __future__ import annotations
 
@@ -61,6 +74,17 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ...models.generation import RecurrentState
+
+PAGED, RECURRENT = "paged", "recurrent"
+
+
+class RecurrentStateError(NotImplementedError):
+    """Asked of a pool that holds recurrent per-slot state: an operation
+    that rebuilds a row from its pages (a rewind, a page copy, an export
+    or import). A recurrence's state exists only at the row's committed
+    length; snapshots of it are later work (ROADMAP)."""
 
 
 class SlotsExhaustedError(RuntimeError):
@@ -75,7 +99,8 @@ class SlotPagedKVPool:
     shared, refcounted block pool for prefix sharing.
 
     init_cache_fn(batch, max_len) must return the model's cache pytree — a
-    list of (k, v) arrays shaped [batch, Hkv, max_len, D] — and is called
+    list with, per layer, (k, v) arrays shaped [batch, Hkv, max_len, D] or
+    a `RecurrentState` (fixed size, `layer_kinds`) — and is called
     once with batch=num_slots, max_len=block_len*n_blocks (+pad). Models
     enforce their own limits here (GPT refuses capacity beyond its
     learned position table).
@@ -101,9 +126,15 @@ class SlotPagedKVPool:
         self.pad_tokens = int(pad_tokens)
         self.slab_len = self.capacity + self.pad_tokens
         kwargs = {} if dtype is None else {"dtype": dtype}
+        entries = list(init_cache_fn(self.num_slots, self.slab_len,
+                                     **kwargs))
+        # what each layer keeps per slot, told by its entry's type
+        self.layer_kinds: List[str] = [
+            RECURRENT if isinstance(e, RecurrentState) else PAGED
+            for e in entries]
+        self.recurrent = RECURRENT in self.layer_kinds
         self.slabs: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
-            (k, v) for k, v in init_cache_fn(self.num_slots, self.slab_len,
-                                             **kwargs)]
+            (a, b) for a, b in entries]
         self.lengths = np.zeros((self.num_slots,), np.int32)
         self.active = np.zeros((self.num_slots,), bool)
         # freed-but-not-scrubbed rows: their non-cached pages still hold
@@ -141,6 +172,21 @@ class SlotPagedKVPool:
         self._lens_version = 1
         self._lens_uploaded = 0
         self._dev_lens: Optional[jnp.ndarray] = None
+
+    @property
+    def recurrent_state_bytes(self) -> int:
+        """Bytes of the recurrent layers' per-slot state, all slots."""
+        return sum(int(a.nbytes) + int(b.nbytes)
+                   for (a, b), kind in zip(self.slabs, self.layer_kinds)
+                   if kind == RECURRENT)
+
+    def _refuse_recurrent(self, what: str):
+        if self.recurrent:
+            raise RecurrentStateError(
+                f"{what}: this pool holds recurrent state "
+                f"({self.layer_kinds.count(RECURRENT)} of "
+                f"{len(self.layer_kinds)} layers), which exists only at a "
+                "row's committed length and cannot be rebuilt from pages")
 
     def _identity_table(self) -> np.ndarray:
         return (np.arange(self.num_slots, dtype=np.int32)[:, None]
@@ -270,6 +316,8 @@ class SlotPagedKVPool:
             raise ValueError(f"slot {slot} is not active")
         length = int(length)
         cur = int(self.lengths[slot])
+        if length < cur:
+            self._refuse_recurrent("rewind_length")
         if length > cur:
             raise ValueError(
                 f"rewind_length can only shrink: {length} > committed "
@@ -342,6 +390,7 @@ class SlotPagedKVPool:
         append divergent tokens into it. One jitted two-op copy
         (dynamic_slice + dynamic_update_slice) per slab; traced row/col
         offsets keep it a single executable per slab shape."""
+        self._refuse_recurrent("cow_copy")
         if not self.active[dst_slot]:
             raise ValueError(f"slot {dst_slot} is not active")
         block_idx = src_page % self.n_blocks
@@ -533,6 +582,7 @@ class SlotPagedKVPool:
         is not enough to resume a SAMPLED stream bit-identically: pair
         this payload with `LLMEngine.export_sampling_lanes` (ISSUE 18),
         which carries each slot's RNG-lane index and grammar DFA state."""
+        self._refuse_recurrent("export_rows")
         rows: Dict[int, dict] = {}
         for slot in slots:
             slot = int(slot)
@@ -575,6 +625,7 @@ class SlotPagedKVPool:
         so the transfer is exactly `width` tokens. This is the spill unit
         the host tier (HostKVPool, ISSUE 19) stores; `width` defaults to
         the full block."""
+        self._refuse_recurrent("export_page")
         if not (0 <= page < self.num_slots * self.n_blocks):
             raise ValueError(f"page {page} out of range")
         w = self.block_len if width is None else int(width)
@@ -595,6 +646,7 @@ class SlotPagedKVPool:
         block table already covers it). Inverse of `export_page`, bitwise.
         Ledger accounting rides the normal path: the engine's next
         `set_length` past this block claims the own page."""
+        self._refuse_recurrent("import_page")
         if not self.active[slot]:
             raise ValueError(f"slot {slot} is not active")
         if not (0 <= block_idx < self.n_blocks):
@@ -624,6 +676,7 @@ class SlotPagedKVPool:
         identity pages — attachment structure is not preserved, the KV
         bytes are), and lands the K/V columns bitwise via
         dynamic_update_slice. Returns {source_slot: destination_slot}."""
+        self._refuse_recurrent("import_rows")
         if int(exported["block_len"]) != self.block_len:
             raise ValueError(
                 f"block_len mismatch: exported {exported['block_len']} "
@@ -682,7 +735,8 @@ class SlotPagedKVPool:
         keep_j = jnp.asarray(keep)
         self.slabs = [(self._scrub(k, keep_j.astype(k.dtype)),
                        self._scrub(v, keep_j.astype(v.dtype)))
-                      for k, v in self.slabs]
+                      if kind == PAGED else (k, v)
+                      for (k, v), kind in zip(self.slabs, self.layer_kinds)]
         self.dirty[:] = False
         self.stats["defrags"] += 1
         return reclaimed

@@ -568,6 +568,33 @@ class LLMEngine:
             model.init_cache, self.config.num_slots, self.config.block_len,
             self.config.n_blocks, dtype=self.config.cache_dtype,
             pad_tokens=self.config.prefill_chunk)
+        # a model with recurrent layers (state-space mixers; told by what
+        # its `init_cache` returns, PR 29): their per-slot state exists
+        # only at a row's committed length, so nothing that restarts a row
+        # from pages it did not compute in this slot can serve it. Prefix
+        # sharing is switched off (`enable_prefix_cache` is the effective
+        # setting); the host tier, a draft model, `kv_row` imports and
+        # stream exports are refused by name
+        self.enable_prefix_cache = self.config.enable_prefix_cache
+        if self.pool.recurrent:
+            if self.config.host_kv_bytes > 0:
+                raise ValueError(
+                    "host_kv_bytes > 0 with a model that has recurrent "
+                    "layers: a page brought back from the host tier has "
+                    "no recurrent state to go with it")
+            if draft_model is not None:
+                raise ValueError(
+                    "draft_model with a target that has recurrent layers: "
+                    "a rejected draft position cannot be rolled back out "
+                    "of a recurrence's state")
+            if self.enable_prefix_cache:
+                _log.warning(
+                    "enable_prefix_cache is switched off: the model has "
+                    "recurrent layers, and a shared prefix's pages carry "
+                    "no recurrent state")
+                self.enable_prefix_cache = False
+            self.metrics.set_recurrent_state(
+                self.pool.recurrent_state_bytes)
         # host-RAM spill tier (ISSUE 19): a byte-budgeted LRU the prefix
         # cache spills refcount-0 pages into on pressure eviction; the
         # admission path re-onboards covered blocks instead of
@@ -583,7 +610,7 @@ class LLMEngine:
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.pool, host_pool=self.host_kv,
                         clock=self.clock.now)
-            if self.config.enable_prefix_cache
+            if self.enable_prefix_cache
             else None)
         # ---- speculative decoding (ISSUE 17) ----
         # a draft model arms spec mode: per decode pump a SINGLE draft
@@ -636,7 +663,12 @@ class LLMEngine:
                 self.config.block_len, self.config.n_blocks,
                 dtype=self.config.cache_dtype,
                 pad_tokens=self.config.prefill_chunk)
-            if self.config.enable_prefix_cache:
+            if self.draft_pool.recurrent:
+                raise ValueError(
+                    "draft_model with recurrent layers: the draft pool "
+                    "rewinds to the verified stream after every window, "
+                    "and a recurrence's state cannot be rewound")
+            if self.enable_prefix_cache:
                 self.draft_prefix_cache = PrefixCache(self.draft_pool,
                                                       name="draft")
         self.metrics.set_slots(0, self.pool.num_slots)
@@ -666,13 +698,15 @@ class LLMEngine:
                    if isinstance(layer, moe.DroplessMoE)]
         self._moe_totals = None
         if experts:
-            if len({m.num_experts for m in experts}) != 1:
+            if len({m.num_held for m in experts}) != 1:
                 raise ValueError("expert layers of different sizes cannot "
                                  "share one [layers, experts] table")
             self._moe_totals = jnp.zeros(
-                (len(experts), experts[0].num_experts), jnp.int32)
-            # assignments one live token makes on its way through the model
-            self._moe_per_token = sum(m.top_k for m in experts)
+                (len(experts), experts[0].num_held), jnp.int32)
+            # live positions the committed steps routed (each through
+            # every expert layer), and how many of them the last fetch
+            # had published
+            self._moe_routed = self._moe_routed_published = 0
             self._moe_published = np.zeros(self._moe_totals.shape, np.int64)
             self._moe_publish_lock = threading.Lock()
             self.metrics.moe_source = self.moe_expert_tokens
@@ -770,6 +804,12 @@ class LLMEngine:
         of fully-masked rows). All sampling inputs are traced [N]
         arrays + the fixed-shape DFA bank, so the mix of request params
         never changes the executable.
+
+        `slabs` holds, per layer, what that layer's kind keeps per slot
+        (`pool.layer_kinds`): the paged K/V slabs, or a state-space
+        layer's `(conv, ssm)` state, which the model advances by each
+        row's `adv` live columns and starts from zero for a row at `pos`
+        0; both ride the step as operand and result.
 
         Operands and results keep that `[N, C]` layout whatever happens
         inside. Where `step_tokens < N * C` the executable packs the live
@@ -1719,6 +1759,10 @@ class LLMEngine:
                         q = nq
                     dstate0 = q
         if kv_row is not None:
+            if self.pool.recurrent:
+                raise ValueError(
+                    "kv_row with a model that has recurrent layers: "
+                    "imported pages carry no recurrent state")
             if int(kv_row.get("block_len", -1)) != self.pool.block_len:
                 raise ValueError(
                     f"kv_row block_len {kv_row.get('block_len')!r} does "
@@ -1890,18 +1934,27 @@ class LLMEngine:
         assignments, fetched from the device now (it waits for the step in
         flight, so: on /metrics, at `stop()` and on demand, never inside a
         step); None for a dense model. Every row sums to live tokens x
-        experts per token, and the whole to `counters["moe_assignments"]`.
-        What is new since the last call is also added to the process-wide
-        `nn.layer.moe.EXPERT_TOKENS`."""
+        experts per token (to the assignments that fell on the held
+        experts, where a layer holds a share: `[layers, held]`). The
+        fetch brings `counters["moe_assignments"]` up to the table's sum,
+        and adds what is new since the last one to the process-wide
+        `nn.layer.moe.EXPERT_TOKENS` and, per layer, the live positions
+        routed since then to `nn.layer.moe.ROUTED_TOKENS`."""
         if self._moe_totals is None:
             return None
         with self._moe_publish_lock:
+            routed = self._moe_routed
             totals = np.asarray(self._moe_totals, np.int64)
             fresh = totals - self._moe_published
             self._moe_published = totals
+            self.metrics.on_moe_assignments(int(fresh.sum()))
             for layer, expert in zip(*np.nonzero(fresh)):
                 moe.EXPERT_TOKENS[(int(layer), int(expert))] += \
                     int(fresh[layer, expert])
+            for layer in range(totals.shape[0]):
+                moe.ROUTED_TOKENS[layer] += \
+                    routed - self._moe_routed_published
+            self._moe_routed_published = routed
         return totals
 
     def pump(self) -> int:
@@ -2613,13 +2666,19 @@ class LLMEngine:
             # rest is arithmetic, a sparse one must keep them out of its
             # experts
             live_tokens = int(adv.sum())
-            with RecordEvent(SPAN_SERVE_DISPATCH,
-                             prefill_rows=len(prefill_slots),
+            span_args = dict(prefill_rows=len(prefill_slots),
                              decode_rows=len(decode_slots),
                              sampled_rows=sampled_rows,
                              live_tokens=live_tokens,
                              step_tokens=self.step_tokens,
-                             deferred_rows=deferred):
+                             deferred_rows=deferred)
+            started = 0
+            if self.pool.recurrent:
+                # rows whose recurrent state this step advances, and those
+                # of them it starts from zero (position 0)
+                span_args["recurrent_rows"] = int(np.count_nonzero(adv))
+                started = int(np.count_nonzero((adv > 0) & (pos == 0)))
+            with RecordEvent(SPAN_SERVE_DISPATCH, **span_args):
                 t0 = self.clock.now()
                 fn = self._step()
                 args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
@@ -2666,10 +2725,11 @@ class LLMEngine:
                         # committed with the step, like the slabs: a failed
                         # attempt or a blame probe counts nowhere
                         self._moe_totals, = moe_out
-                        self.metrics.on_moe_assignments(
-                            live_tokens * self._moe_per_token)
+                        self._moe_routed += live_tokens
                     self.metrics.on_step_tokens(live_tokens,
                                                 self.step_tokens, deferred)
+                    if started:
+                        self.metrics.on_recurrent_rows_started(started)
                     if decode_slots:
                         # the breaker tracks ENGINE-level (decode-protocol)
                         # failures; prefill-only successes must not launder

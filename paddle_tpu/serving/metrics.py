@@ -251,6 +251,13 @@ def _quantile(sorted_vals, q: float) -> Optional[float]:
     return sorted_vals[idx]
 
 
+# The per-slot recurrent state the newest LLM engine's pool holds, in bytes
+# (`LLMMetrics.set_recurrent_state`; 0: no engine with recurrent layers
+# yet): for a reader that comes after the engine is gone, as
+# `nn.layer.moe.EXPERT_TOKENS` is for the expert counts.
+RECURRENT_STATE_BYTES = 0
+
+
 class LLMMetrics(ServingMetrics):
     """ServingMetrics extended for the continuous-batching LLM engine
     (ISSUE 5): TTFT and inter-token latency summaries, decode-throughput
@@ -276,6 +283,7 @@ class LLMMetrics(ServingMetrics):
                               "step_tokens_computed": 0,
                               "prefill_rows_deferred": 0,
                               "moe_assignments": 0,
+                              "recurrent_rows_started": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
                               "brownout_entries": 0,
                               "prefix_hits": 0, "prefix_misses": 0,
@@ -336,6 +344,10 @@ class LLMMetrics(ServingMetrics):
         # are fetched when /metrics is rendered, never in a step); None,
         # and no expert family, for a dense model
         self.moe_source = None
+        # bytes of per-slot recurrent state (state-space layers) the pool
+        # holds beside the paged K/V; None, and no family, for a model
+        # without recurrent layers
+        self.recurrent_state_bytes: Optional[int] = None
         # multi-LoRA serving (ISSUE 18/20): emitted tokens per adapter id
         # ("base" for row-0 streams) — on an armed engine every emission
         # lands in exactly one bucket, so these sum to tokens_out
@@ -504,11 +516,26 @@ class LLMMetrics(ServingMetrics):
             self.counters["step_tokens_computed"] += int(computed)
             self.counters["prefill_rows_deferred"] += int(deferred)
 
+    def set_recurrent_state(self, nbytes: int):
+        with self._lock:
+            self.recurrent_state_bytes = int(nbytes)
+        global RECURRENT_STATE_BYTES
+        RECURRENT_STATE_BYTES = int(nbytes)
+
+    def on_recurrent_rows_started(self, n: int):
+        """`n` rows of a committed step began at position 0: the step
+        started them from a zero recurrent state."""
+        with self._lock:
+            self.counters["recurrent_rows_started"] += int(n)
+
     def on_moe_assignments(self, n: int):
-        """One committed unified step of a sparse model routed `n`
-        (position, expert) pairs: its live tokens x experts per token x
-        expert layers. Nothing is dropped, so the device's per-expert
-        totals (`LLMEngine.moe_expert_tokens()`) sum to this."""
+        """A fetch of the device's per-expert totals
+        (`LLMEngine.moe_expert_tokens()`: on /metrics, at `stop()`, on
+        demand) found `n` (position, expert) pairs routed to experts this
+        engine holds since the last one. The counter is the table's sum as
+        of the newest fetch: live tokens x experts per token x expert
+        layers where every expert is held (nothing is dropped), the held
+        experts' part of that where the layers hold a share."""
         with self._lock:
             self.counters["moe_assignments"] += int(n)
 
@@ -664,6 +691,7 @@ class LLMMetrics(ServingMetrics):
             s["host_kv"] = (dict(self.host_kv)
                             if self.host_kv is not None else None)
             s["adapter_tokens"] = dict(self.adapter_tokens)
+            s["recurrent_state_bytes"] = self.recurrent_state_bytes
         s["mask_overhead_p99_ms"] = self.mask_overhead_quantile_ms(0.99)
         s["shed_rate"] = (s["shed"] / s["submitted"] if s["submitted"]
                           else 0.0)
@@ -676,6 +704,9 @@ class LLMMetrics(ServingMetrics):
 
     def _render_into(self, b: PromBuilder):
         super()._render_into(b)
+        # fetched before the snapshot: the fetch is what brings
+        # `moe_assignments` up to the device's totals
+        moe_table = None if self.moe_source is None else self.moe_source()
         s = self.snapshot()
         px = self._PREFIX
         for fam, prefix in ((f"{px}_ttft_ms", "ttft"),
@@ -710,11 +741,18 @@ class LLMMetrics(ServingMetrics):
             b.sample(f"{px}_{name}_total", s[name])
         b.family(f"{px}_prefills_total", "counter")
         b.sample(f"{px}_prefills_total", s["prefills"])
+        if s["recurrent_state_bytes"] is not None:
+            b.family(f"{px}_recurrent_state_bytes", "gauge")
+            b.sample(f"{px}_recurrent_state_bytes",
+                     s["recurrent_state_bytes"])
+            b.family(f"{px}_recurrent_rows_started_total", "counter")
+            b.sample(f"{px}_recurrent_rows_started_total",
+                     s["recurrent_rows_started"])
         if self.moe_source is not None:
             b.family(f"{px}_moe_assignments_total", "counter")
             b.sample(f"{px}_moe_assignments_total", s["moe_assignments"])
             b.family(f"{px}_moe_expert_tokens_total", "counter")
-            for layer, row in enumerate(self.moe_source()):
+            for layer, row in enumerate(moe_table):
                 for expert, n in enumerate(row):
                     b.sample(f"{px}_moe_expert_tokens_total", int(n),
                              {"layer": layer, "expert": expert})

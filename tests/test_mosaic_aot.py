@@ -1,5 +1,5 @@
 """Tier-1 guard for the Mosaic lowering: tools/mosaic_aot_check.py compiles
-the flash and paged Pallas kernels for a TPU v5e through the installed
+the flash, paged, grouped-matmul and state-recurrence Pallas kernels for a TPU v5e through the installed
 libtpu, with no chip attached (ISSUE 21: CPU interpret-mode tests say
 nothing of whether Mosaic accepts a kernel). Runs in a subprocess so the
 libtpu lock and the TPU_* environment stay out of the test process."""
@@ -21,4 +21,4 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip():
     cases = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
     assert r.returncode == 0, "\n".join(cases) + r.stderr[-1500:]
-    assert len(cases) == 15 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 20 and all(c.startswith("[OK]") for c in cases)

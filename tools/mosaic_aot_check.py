@@ -129,19 +129,35 @@ def main() -> int:
             spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
             spec((B,), jnp.int32), spec((B,), jnp.int32),
             want={"paged_attention": 1}))
-    from paddle_tpu.ops import pallas_mode
-    for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
-        print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
-
     # the grouped matmul of the dropless expert layer at OLMoE's widths and
     # the decode cell's rows (2,048 positions x 8 experts each)
     from paddle_tpu.ops.grouped_matmul import grouped_matmul
-    for k, n in ((2048, 1024), (1024, 2048)):   # gate / up, down
+    # and at granite-4.0-h-small's (512 packed positions x 10, 18 held)
+    for m, e, k, n in ((16384, 64, 2048, 1024), (16384, 64, 1024, 2048),
+                       (5120, 18, 4096, 768), (5120, 18, 768, 4096)):
         results.append(compile_case(
-            f"moe_gmm bf16 [16384,{k}] x [64,{k},{n}]",
+            f"moe_gmm bf16 [{m},{k}] x [{e},{k},{n}]",
             lambda lhs, rhs, gs: grouped_matmul(lhs, rhs, gs, impl="pallas"),
-            spec((16384, k), jnp.bfloat16), spec((64, k, n), jnp.bfloat16),
-            spec((64,), jnp.int32), want={"moe_gmm": 1}))
+            spec((m, k), jnp.bfloat16), spec((e, k, n), jnp.bfloat16),
+            spec((e,), jnp.int32), want={"moe_gmm": 1}))
+
+    # the Mamba-2 recurrence at granite-4.0-h-small's widths: the decode
+    # cell's step (128 slots x 16 columns), one-shot generate()'s decode
+    # step and a whole prompt walked in chunks
+    from paddle_tpu.ops.ssm import ssm_update
+    for rows, T in ((128, 16), (2, 1), (2, 128)):
+        results.append(compile_case(
+            f"ssm_update bf16 rows={rows} T={T} state=[128,8192]",
+            ssm_update,
+            spec((rows, T, 8192), jnp.bfloat16),
+            spec((rows, T, 128), jnp.float32), spec((128,), jnp.float32),
+            spec((rows, T, 128), jnp.bfloat16),
+            spec((rows, T, 128), jnp.bfloat16),
+            spec((rows, 128, 8192), jnp.bfloat16), spec((rows,), jnp.int32),
+            spec((rows,), jnp.int32), want={"ssm_update": 1}))
+    from paddle_tpu.ops import pallas_mode
+    for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
+        print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
 
     # four chips: a sharded pallas_call is refused by JAX outright; under
     # spmd_mesh the kernels run as a shard_map island and compile
