@@ -135,6 +135,10 @@ class SlotPagedKVPool:
         self.recurrent = RECURRENT in self.layer_kinds
         self.slabs: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
             (a, b) for a, b in entries]
+        # buffers shaped like `slabs` that hold nothing anybody reads: the
+        # pool a step read becomes the scratch its successor's result is
+        # written into (`scratch_slabs`, `advance`)
+        self.spare: Optional[List[Tuple[jnp.ndarray, jnp.ndarray]]] = None
         self.lengths = np.zeros((self.num_slots,), np.int32)
         self.active = np.zeros((self.num_slots,), bool)
         # freed-but-not-scrubbed rows: their non-cached pages still hold
@@ -179,6 +183,26 @@ class SlotPagedKVPool:
         return sum(int(a.nbytes) + int(b.nbytes)
                    for (a, b), kind in zip(self.slabs, self.layer_kinds)
                    if kind == RECURRENT)
+
+    def scratch_slabs(self) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
+        """Buffers shaped like `slabs` for the next step to write its
+        result into, handed to the step as a donated operand it never
+        reads: the pool before last where `advance` kept one, else fresh
+        zeros (the first step; after an operation below that rebuilt the
+        slabs; after a dispatch that consumed them and failed). So the
+        pool exists twice, as it does while any undonated step runs, and
+        never a third time when a step is launched before its predecessor
+        has finished with the pool it read."""
+        if self.spare is None or any(
+                a.is_deleted() for a in jax.tree_util.tree_leaves(self.spare)):
+            self.spare = [(jnp.zeros_like(a), jnp.zeros_like(b))
+                          for a, b in self.slabs]
+        return self.spare
+
+    def advance(self, new_slabs):
+        """A step's result becomes the pool; the pool it read, which its
+        successor no longer needs, becomes the scratch."""
+        self.spare, self.slabs = self.slabs, new_slabs
 
     def _refuse_recurrent(self, what: str):
         if self.recurrent:
@@ -397,6 +421,7 @@ class SlotPagedKVPool:
         src_row = src_page // self.n_blocks
         if src_row == dst_slot:
             return
+        self.spare = None   # the copy below is the pool's second buffer
         if self._cow is None:
             blk_len = self.block_len
 
@@ -657,6 +682,7 @@ class SlotPagedKVPool:
                 f"payload has {len(layers)} layers, pool has "
                 f"{len(self.slabs)}")
         c0 = block_idx * self.block_len
+        self.spare = None   # the copy below is the pool's second buffer
         new_slabs = []
         for (k, v), (ke, ve) in zip(self.slabs, layers):
             if ke.shape[1] > self.block_len:
@@ -692,6 +718,7 @@ class SlotPagedKVPool:
             dst = self.allocate(length)
             self.set_length(dst, length)
             if length > 0:
+                self.spare = None
                 new_slabs = []
                 for (k, v), (ke, ve) in zip(self.slabs, row["layers"]):
                     ku = jnp.asarray(ke, dtype=k.dtype)[None]
@@ -733,6 +760,7 @@ class SlotPagedKVPool:
             self._scrub = jax.jit(
                 lambda slab, keep: slab * keep[:, None, :, None])
         keep_j = jnp.asarray(keep)
+        self.spare = None
         self.slabs = [(self._scrub(k, keep_j.astype(k.dtype)),
                        self._scrub(v, keep_j.astype(v.dtype)))
                       if kind == PAGED else (k, v)

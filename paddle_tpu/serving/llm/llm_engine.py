@@ -21,7 +21,7 @@ hostage. This engine schedules the same numeric path (the
 paged-attention kernel, so outputs are bit-identical per row) as a
 continuously-batched service:
 
-- ONE unified mixed-row dispatch per pump iteration (`_step_once`): every
+- ONE unified mixed-row dispatch per pump iteration (`_launch`): every
   slot contributes a fixed-width `[prefill_chunk]` row — a prompt chunk
   for prefilling requests, `[last_tok, 0, ...]` for decoding requests,
   zeros for free slots — and the single jitted executable writes all KV
@@ -42,6 +42,22 @@ continuously-batched service:
   `prefill_chunk`. The scheduler keeps a step's live tokens within it:
   decode rows always fit, prefill rows ride oldest first with their whole
   chunk or wait a step (`prefill_rows_deferred`);
+- **one step in flight**: a pump pass launches step k+1 (`_launch`) before
+  it fetches and commits step k (`_retire`), so the chip runs while the
+  host admits, builds rows, uploads and commits. Step k+1's rows are
+  built from what step k WILL commit — known at launch for a plain row:
+  positions advance by `adv`, a row at its `max_new_tokens` is not
+  scheduled again — and the one unknown, the token step k selects, is fed
+  back on the device (`feed`, `prev_sel`). A request that ends unseen by
+  the launch (EOS, a deadline) rides one step more and that row is
+  discarded at `_retire`, which matches rows to requests by identity, not
+  by slot. Draft windows, grammar rows and engines that book device time
+  per step keep launch, retire (`_can_launch_ahead`); a dispatch that
+  fails ahead of its predecessor is retried after that one is retired.
+  The step is not donated the pool it reads; it writes its result into
+  the buffers of the pool before that one (`kv_pool.scratch_slabs`), so
+  the pool exists twice and, with a step queued behind a running one,
+  not three times;
 - **chunked prefill**: prompts longer than `prefill_chunk` are admitted
   as fixed-size chunks interleaved with the decode loop, so a short
   prompt's TTFT is bounded by a couple of chunk-width steps instead of a
@@ -506,6 +522,28 @@ class _GenRequest:
         #                                       model (bank row 0)
 
 
+@dataclass
+class _StepInFlight:
+    """One unified step between `_launch` and `_retire`: the device results
+    nobody has fetched yet (`nxt`, `lps`, `new_dstate`), the host rows it
+    was built from, and each row's request OBJECT (`reqs`: slot ->
+    request). A slot alone does not name a row's owner: while the step is
+    in flight `_retire` of its predecessor may free the slot and `_admit`
+    bind another request to it."""
+    nxt: jax.Array                  # [N, C] selected tokens, on the device
+    lps: jax.Array                  # [N, C] their log-probabilities
+    new_dstate: jax.Array           # [N] advanced grammar-DFA states
+    pos: np.ndarray                 # [N] the rows' write offsets
+    adv: np.ndarray                 # [N] live columns a row
+    prefill_slots: List[int]
+    decode_slots: List[int]
+    reqs: Dict[int, _GenRequest]
+    spec_drafts: Dict[int, List[int]]
+    sampled_rows: int
+    t0: float                       # clock at the start of `dispatch`
+    tc0: Optional[float]            # start of the device span, if booked
+
+
 class LLMEngine:
     """submit() a prompt, get a GenerationHandle streaming greedy tokens.
 
@@ -680,6 +718,12 @@ class LLMEngine:
         self._brownout = False
         self._thread: Optional[threading.Thread] = None
         self._step_jit = None        # the ONE unified step executable
+        # the step that is launched and not yet retired (`_StepInFlight`),
+        # or None: owned by whoever runs `pump()`. `_no_sel` stands in for
+        # a predecessor's selections on a step that feeds nothing back
+        self._inflight: Optional[_StepInFlight] = None
+        self._no_sel = None
+        self._retired_at = float("-inf")   # clock at the last `_retire`
         # packed positions the step computes: every decode row with its
         # draft window always fits beside one whole prefill chunk, and the
         # step is never narrower than MIN_STEP_TOKENS nor wider than the
@@ -830,14 +874,26 @@ class LLMEngine:
             packed = step_tokens < self.pool.num_slots * chunk
 
             def step(params, toks, pos, adv, table, slabs, temp, topk,
-                     topp, samp, seed, ctr, dstate, gid, bank,
-                     adapters=None, moe_totals=None):
+                     topp, samp, seed, ctr, dstate, gid, bank, feed,
+                     prev_sel, spare, adapters=None, moe_totals=None):
+                # `spare` is never read: a donated operand shaped like
+                # `slabs` whose buffers XLA aliases to `new_slabs`
+                # (`pool.scratch_slabs`). `slabs` itself is not donated: a
+                # failed dispatch and a blame probe leave the pool intact.
                 # `adapters` (ISSUE 20) is the AdapterBank's stacked LoRA
                 # operand — (per-layer A/B banks, per-slot adapter_idx,
                 # per-row scale). An unarmed engine never passes it, so
                 # its traced signature is unchanged; an armed engine
                 # passes a fixed-structure pytree whose leaf VALUES churn
                 # as adapters load/swap — zero recompiles either way.
+                # token feedback: a row launched before its predecessor
+                # step was fetched takes its input token from that step's
+                # selections, still on the device (`feed` names the
+                # column; -1 keeps the host's `toks[:, 0]`)
+                fed = jnp.take_along_axis(
+                    prev_sel, jnp.maximum(feed, 0)[:, None], axis=1)[:, 0]
+                toks = toks.at[:, 0].set(
+                    jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
                 seq_lens = (pos + adv).astype(jnp.int32)
                 paged = (table, seq_lens, block_len, pages_per_row)
                 pack = None
@@ -890,7 +946,8 @@ class LLMEngine:
                         moe_totals + jnp.stack(expert_counts))
 
             step.__name__ = step.__qualname__ = UNIFIED_STEP_NAME
-            self._step_jit = jax.jit(step)
+            self._step_jit = jax.jit(step, donate_argnames=("spare",),
+                                     keep_unused=True)
         return self._step_jit
 
     def _sampling_args_locked(self, ctr):
@@ -903,6 +960,21 @@ class LLMEngine:
         temp, topk, topp, samp, seed, dstate, gid = tab.device_args()
         return (temp, topk, topp, samp, seed, jnp.asarray(ctr),
                 dstate, gid, tab.device_bank())
+
+    def _feedback_args(self, feed=None, ahead_of=None):
+        """The unified step's token-feedback operands `(feed [N],
+        prev_sel [N, C])`: for a step launched ahead of `ahead_of`, that
+        step's selections as they lie on the device and, per row, the
+        column that is its next input; for every other step -1 in every
+        row and a block of zeros nobody reads, so that both are one
+        executable."""
+        if ahead_of is not None:
+            return jnp.asarray(feed), ahead_of.nxt
+        if self._no_sel is None:
+            shape = (self.pool.num_slots, self.config.prefill_chunk)
+            self._no_sel = (jnp.full(shape[:1], -1, jnp.int32),
+                            jnp.zeros(shape, jnp.int32))
+        return self._no_sel
 
     def _adapter_args_locked(self):
         """The unified step's adapter operand as a (possibly empty) args
@@ -1104,16 +1176,18 @@ class LLMEngine:
             # longer advance anything (e.g. breaker open mid-drain) falls
             # through to the stranded-future cleanup instead of spinning
             prev = None
-            while True:
-                with self._cond:
-                    if not (self._queue_len_locked() or self._active):
-                        break
+            while self.has_work():
                 self.pump()
                 state = (self._queue_len_locked(), len(self._active),
-                         self._dispatch_idx)
+                         self._dispatch_idx, self._inflight is None)
                 if state == prev:
                     break
                 prev = state
+        if thread is None or not thread.is_alive():
+            # a step still in flight (the scheduler left on `_stopped` or
+            # an open breaker, or the loop above gave up): its rows'
+            # requests are over or about to be failed below
+            self._retire_in_flight()
         with self._cond:
             stranded = 0
             for q in self._queues.values():
@@ -1216,6 +1290,11 @@ class LLMEngine:
             self.metrics.set_slots(self.pool.active_slots(),
                                    self.pool.num_slots)
             self._cond.notify_all()
+        if self._thread is None:
+            # the step in flight now carries only orphans: discard it here
+            # (a scheduler thread does so on its next pass, for which
+            # `has_work()` stays true)
+            self._retire_in_flight()
         if n:
             flight_recorder().record("deploy_evacuate", engine="llm",
                                      reason=reason, n=n)
@@ -1271,7 +1350,13 @@ class LLMEngine:
         (the receiving replica's handle carries the stream forward — the
         same convention as failover-abandoned handles) and the row is
         freed for new work. Raises ValueError when the rid is not active
-        or still mid-prefill."""
+        or still mid-prefill.
+
+        A unified step in flight is left there: the export reads the
+        row's columns below its committed length, which that step does
+        not write (the host read waits for it by data dependence), and
+        the row's token in it is discarded when the step retires, the
+        request no longer holding the slot."""
         with self._cond:
             found = None
             for slot, req in self._active.items():
@@ -1330,7 +1415,7 @@ class LLMEngine:
     def replace_params(self, new_params, version: str):
         """Hot in-place weight swap between pump iterations — NO
         recompile. The unified step executable keys on its arguments'
-        abstract signature (shape/dtype tree), and `_step_once` reads
+        abstract signature (shape/dtype tree), and `_launch` reads
         `self.params` fresh on every dispatch, so rebinding the attribute
         with a signature-identical tree reuses the warm `_step_jit` —
         verified end to end by the compile observatory (no
@@ -1361,12 +1446,15 @@ class LLMEngine:
                     f"{tuple(new.shape)}/{new.dtype}, serving params have "
                     f"{tuple(old.shape)}/{old.dtype} — abstract signature "
                     "must match exactly (swap without recompile)")
+        if self._thread is None:
+            self._retire_in_flight()
         with self._cond:
-            if self._queue_len_locked() or self._active:
+            if self._has_work_locked():
                 raise WeightSwapError(
                     f"cannot swap to {version!r} with work in flight "
                     f"(queued={self._queue_len_locked()}, "
-                    f"active={len(self._active)}): drain first")
+                    f"active={len(self._active)}, unretired step="
+                    f"{self._inflight is not None}): drain first")
             flushed = 0
             if self.prefix_cache is not None:
                 flushed = self.prefix_cache.clear()
@@ -1908,9 +1996,14 @@ class LLMEngine:
             return self._inflight_tokens_locked()
 
     # ---- scheduling ----
+    def _has_work_locked(self) -> bool:
+        """Anything queued, decoding, or launched and not yet retired."""
+        return bool(self._queue_len_locked() or self._active
+                    or self._inflight is not None)
+
     def has_work(self) -> bool:
         with self._cond:
-            return bool(self._queue_len_locked() or self._active)
+            return self._has_work_locked()
 
     def next_event_time(self) -> Optional[float]:
         """Clock instant of the next scheduler action — `now` whenever any
@@ -1918,7 +2011,7 @@ class LLMEngine:
         immediately due), None when idle. The sim harness advances its
         clock here between scripted arrivals."""
         with self._cond:
-            if self._queue_len_locked() or self._active:
+            if self._has_work_locked():
                 return self.clock.now()
             return None
 
@@ -1960,16 +2053,19 @@ class LLMEngine:
     def pump(self) -> int:
         """One scheduler pass: drop expired queued requests, admit queued
         requests into free slots (bookkeeping only — no dispatch), then
-        run ONE unified mixed prefill+decode step and retire
-        finished/evicted rows. Returns the number of decode iterations
-        executed (0 or 1; a step carrying only prefill chunks returns 0) —
+        retire ONE unified mixed prefill+decode step — its successor
+        launched first where its rows can be projected (`_step_pass`), so
+        a pass may return with a step in flight; the next pass, `stop()`
+        or `evacuate()` retires it, and `has_work()` stays true until
+        then. Returns the number of decode iterations
+        retired (0 or 1; a step carrying only prefill chunks returns 0) —
         the quantity the continuous-batching tests count. This is THE
         scheduler: the background thread and the sim harness both call
         it.
 
         With economics armed (ISSUE 11) the whole pass runs inside the
         serving ledger's ``measure("host")`` frame; the successful
-        dispatch's device span is booked out of it by `_step_once`, so
+        dispatch's device span is booked out of it by `_commit_step`, so
         host/compute/idle tile the pump's wall clock by construction."""
         led = self.ledger
         if led is None:
@@ -1990,9 +2086,36 @@ class LLMEngine:
             with RecordEvent(SPAN_SERVE_ADMIT):
                 self._drop_expired_queued(now)
                 self._admit()
-            n = self._step_once()
+            n = self._step_pass()
             with RecordEvent(SPAN_SERVE_PUBLISH):
                 self._publish_gauges()
+        return n
+
+    def _step_pass(self) -> int:
+        """Retire one unified step, with its successor launched first
+        where that can be: `_launch(k+1)` then `_retire(k)`, so the chip
+        runs step k+1 while the host fetches and commits step k, admits
+        and builds. One step in flight at most. Where the successor's rows
+        do not follow from the step's own (`_can_launch_ahead`), or
+        nothing is in flight, this is launch, retire: the same two
+        functions in today's order. A dispatch that fails ahead of its
+        predecessor commits nothing; the predecessor is retired, exactly
+        once, and the failed step is taken up synchronously."""
+        rec, self._inflight = self._inflight, None
+        if rec is None:
+            rec = self._launch()
+            if rec is None:
+                return 0
+        ahead = failed = None
+        if self._can_launch_ahead(rec):
+            try:
+                ahead = self._launch(ahead_of=rec)
+            except DispatchFailedError as e:
+                failed = e
+        n = self._retire(rec)
+        if failed is not None:
+            ahead = self._launch(failed=failed)
+        self._inflight = ahead
         return n
 
     def _publish_gauges(self):
@@ -2495,7 +2618,7 @@ class LLMEngine:
                 "unattributable draft dispatch failures; the engine "
                 "continues on plain decode", self._draft_failstreak)
 
-    def _acceptance_locked(self, decode_slots, spec_drafts,
+    def _acceptance_locked(self, decode_rows, spec_drafts,
                            nxt) -> Dict[int, Tuple[List[int], int, int]]:
         """Greedy verification over the step's per-position tokens:
         for each decode row, walk the longest prefix of its draft window
@@ -2504,10 +2627,11 @@ class LLMEngine:
         caps exactly where sequential decode would stop. Returns
         {slot: (emit_tokens, accepted_draft_count, drafted_count)}; a
         plain decode row (no drafts) degenerates to ([next_token], 0, 0),
-        which is precisely the pre-spec commit."""
+        which is precisely the pre-spec commit. `decode_rows` maps each
+        decode slot to the request its row belongs to, None where that
+        request no longer holds the slot."""
         accept: Dict[int, Tuple[List[int], int, int]] = {}
-        for slot in decode_slots:
-            req = self._active.get(slot)
+        for slot, req in decode_rows.items():
             if req is None:
                 continue
             drafts = spec_drafts.get(slot, ())
@@ -2528,13 +2652,13 @@ class LLMEngine:
             accept[slot] = (emit_toks, min(len(emit_toks), a), k)
         return accept
 
-    def _build_rows_locked(self, spec_drafts=None):
+    def _build_rows_locked(self, spec_drafts=None, ahead_of=None):
         """Assemble the unified step's host-side row set from the active
         table: (toks [N, C], pos [N], adv [N], ctr [N], prefill_slots,
-        decode_slots, deferred). Free slots stay all-zero (adv=0 → fully
-        masked). A decode row with a draft window (ISSUE 17) carries
-        [last_tok, d1..dk] at adv=1+k — the verify chunk; plain decode
-        rows stay [last_tok] at adv=1.
+        decode_slots, deferred, feed [N]). Free slots stay all-zero
+        (adv=0 → fully masked). A decode row with a draft window (ISSUE
+        17) carries [last_tok, d1..dk] at adv=1+k — the verify chunk;
+        plain decode rows stay [last_tok] at adv=1.
 
         The step computes `step_tokens` positions, so `sum(adv)` stays
         within them: a prefill row whose chunk no longer fits waits this
@@ -2550,11 +2674,24 @@ class LLMEngine:
         adv-1 so the emission column adv-1 lands exactly on the first
         emitted token's index — the earlier columns' draws are discarded
         with their logits, negative intermediate indices fold_in as
-        harmless uint32 bit-casts."""
+        harmless uint32 bit-casts.
+
+        `ahead_of` is the step in flight when this one is built before
+        that one is retired. A request that rides it is read as that step
+        WILL commit it, all of which is known for a plain row: its
+        position and prefilled offset advance by the row's `adv`, and a
+        decode row, or a prefill row whose last chunk rode, has emitted
+        one token more. Which token is the one thing the host does not
+        know: `feed[slot]` names the column of that step's selections
+        the device takes it from (-1 everywhere else: the host's
+        `toks[:, 0]` stands). A request whose cap that token reaches is
+        not scheduled again. The committed fields stay what
+        `_commit_step` wrote."""
         N = self.pool.num_slots
         C = self.config.prefill_chunk
         toks = np.zeros((N, C), np.int32)
         ctr = np.zeros((N,), np.int32)
+        feed = np.full((N,), -1, np.int32)
         # free rows still get a (discarded) C-wide KV stripe written at
         # their pos by the unified step; park it in the slab's pad region
         # (block tables never address cols >= n_blocks*block_len) so it
@@ -2564,28 +2701,40 @@ class LLMEngine:
         adv = np.zeros((N,), np.int32)
         prefill_slots: List[int] = []
         decode_slots: List[int] = []
-        waiting: List[int] = []
+        waiting: List[Tuple[int, int, int]] = []
         for slot, req in self._active.items():
-            if req.chunk_off < len(req.prompt):
-                waiting.append(slot)
+            off, emitted = req.chunk_off, len(req.emitted)
+            length = int(self.pool.lengths[slot])
+            col = -1
+            if ahead_of is not None and ahead_of.reqs.get(slot) is req:
+                was_prefill = off < len(req.prompt)
+                off = length = int(ahead_of.pos[slot] + ahead_of.adv[slot])
+                if not was_prefill or off >= len(req.prompt):
+                    emitted += 1
+                    col = int(ahead_of.adv[slot]) - 1
+                    if emitted >= req.max_new_tokens:
+                        continue    # its last token is in flight
+            if off < len(req.prompt):
+                waiting.append((slot, off, emitted))
                 continue
             drafts = (spec_drafts.get(slot, ())
                       if spec_drafts else ())
-            toks[slot, 0] = req.last_tok
+            if col < 0:
+                toks[slot, 0] = req.last_tok
+            feed[slot] = col
             for j, d in enumerate(drafts):
                 toks[slot, 1 + j] = d
-            pos[slot] = self.pool.lengths[slot]
+            pos[slot] = length
             adv[slot] = 1 + len(drafts)
-            ctr[slot] = req.sample_offset + len(req.emitted)
+            ctr[slot] = req.sample_offset + emitted
             decode_slots.append(slot)
         # the decode rows always fit (`step_tokens`' first term); the
         # prefill rows take what is left, oldest admitted first (`_active`
         # keeps admission order), each its whole chunk or none of it
         budget = self.step_tokens - int(adv.sum())
         deferred = 0
-        for slot in waiting:
+        for slot, off, emitted in waiting:
             req = self._active[slot]
-            off = req.chunk_off
             n = min(C, len(req.prompt) - off)
             if n > budget:
                 deferred += 1
@@ -2594,9 +2743,10 @@ class LLMEngine:
             toks[slot, :n] = req.prompt[off:off + n]
             pos[slot] = off
             adv[slot] = n
-            ctr[slot] = req.sample_offset + len(req.emitted) - (n - 1)
+            ctr[slot] = req.sample_offset + emitted - (n - 1)
             prefill_slots.append(slot)
-        return toks, pos, adv, ctr, prefill_slots, decode_slots, deferred
+        return (toks, pos, adv, ctr, prefill_slots, decode_slots, deferred,
+                feed)
 
     def _kinds_of(self, prefill_slots, decode_slots) -> Tuple:
         """(kind, request_ids) announcement order for fault injection:
@@ -2619,14 +2769,41 @@ class LLMEngine:
                 self._active[s].submit_idx for s in adapter_rows))))
         return tuple(kinds)
 
-    def _step_once(self) -> int:
-        """Run ONE unified mixed prefill+decode dispatch over every slot
-        and commit its results. Returns 1 when the committed step carried
-        at least one decode row (the decode-iteration count the
-        continuous-batching invariants pin), else 0.
+    def _can_launch_ahead(self, rec: _StepInFlight) -> bool:
+        """Whether the step after `rec` can be built before `rec` is
+        fetched: every row of `rec` must commit what `_build_rows_locked`
+        projects for it. Not so where a draft model proposes (the draft
+        phase replays committed streams, and a window's accepted length
+        is the step's result), nor for a grammar row (its DFA state is
+        committed through the sampling table, whose device operands the
+        commit invalidates). An engine that books device time per step
+        (`ledger`, `observatory`: launch to the end of the fetch) keeps
+        one step at a time, so that what it books stays one step's."""
+        if self.ledger is not None or self.observatory is not None:
+            return False
+        if self.draft_pool is not None and not self._spec_disabled:
+            return False
+        return not any(req.gid for req in rec.reqs.values())
 
-        With a draft model attached (ISSUE 17) the pump first runs the
-        draft phase: decode rows carry verify windows [last_tok, d1..dK]
+    def _launch(self, ahead_of: Optional[_StepInFlight] = None,
+                failed: Optional[DispatchFailedError] = None
+                ) -> Optional[_StepInFlight]:
+        """Build and dispatch ONE unified mixed prefill+decode step over
+        every slot, and return it in flight (None when no row rides).
+        Nothing here waits for the device.
+
+        `ahead_of` is the unretired predecessor when this step is
+        launched before that one is fetched: rows come from its
+        projection (`_build_rows_locked`), each row's input token from
+        its selections on the device, and a failed dispatch raises
+        `DispatchFailedError` at once — the caller retires the
+        predecessor and calls again with `failed` set, which takes this
+        step's retry and blame protocol up synchronously at its second
+        attempt. Without it this is the synchronous launch: rows from the
+        committed state, retries, then blame and quarantine.
+
+        With a draft model attached (ISSUE 17) the draft phase runs
+        first: decode rows carry verify windows [last_tok, d1..dK]
         instead of a lone token, and the commit takes the longest
         target-matching draft prefix plus the corrective token — up to
         K+1 tokens per row from the SAME single dispatch, bit-identical
@@ -2637,12 +2814,18 @@ class LLMEngine:
         if self.draft_pool is not None and not self._spec_disabled:
             with RecordEvent(SPAN_SERVE_DRAFT):
                 spec_drafts = self._draft_phase()
+        first_attempt = 0 if failed is None else 1
         while True:
             with RecordEvent(SPAN_SERVE_BUILD_ROWS), self._cond:
                 if not self._active:
-                    return 0
+                    return None
                 toks, pos, adv, ctr, prefill_slots, decode_slots, \
-                    deferred = self._build_rows_locked(spec_drafts)
+                    deferred, feed = self._build_rows_locked(spec_drafts,
+                                                             ahead_of)
+                if not (prefill_slots or decode_slots):
+                    return None     # every row ends with the step in flight
+                reqs = {s: self._active[s]
+                        for s in prefill_slots + decode_slots}
                 kinds = self._kinds_of(prefill_slots, decode_slots)
                 # rows of this step that draw: what the step's sampler
                 # branches on (a freed slot is cleared to greedy, so the
@@ -2671,7 +2854,8 @@ class LLMEngine:
                              sampled_rows=sampled_rows,
                              live_tokens=live_tokens,
                              step_tokens=self.step_tokens,
-                             deferred_rows=deferred)
+                             deferred_rows=deferred,
+                             in_flight=int(ahead_of is not None))
             started = 0
             if self.pool.recurrent:
                 # rows whose recurrent state this step advances, and those
@@ -2683,20 +2867,23 @@ class LLMEngine:
                 fn = self._step()
                 args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
                         jnp.asarray(adv), self.pool.device_block_table(),
-                        self.pool.slabs) + sargs + aargs
+                        self.pool.slabs) + sargs \
+                    + self._feedback_args(feed, ahead_of) \
+                    + (self.pool.scratch_slabs(),) + aargs
                 if self.observatory is not None:
                     self.observatory.observe_call("llm/unified_step", fn,
                                                   args)
                 attempts = self.config.dispatch_retries + 1
-                last_err = None
+                last_err = failed
                 nxt = None
                 tc0 = None
-                for attempt in range(attempts):
+                for attempt in range(first_attempt, attempts):
                     if self.ledger is not None or self.observatory is not None:
                         # the start of the dispatch's device span, after the
                         # operand uploads and `observe_call`: launch to the
-                        # end of `fetch` below, where np.asarray has already
-                        # waited for the device. Nothing synchronises for
+                        # end of `fetch` in `_retire`, where np.asarray has
+                        # already waited for the device (such an engine never
+                        # launches ahead). Nothing synchronises for
                         # the ledger's or the observatory's sake, so an
                         # armed engine runs the default engine's host
                         # sequence. Re-armed per attempt: a failed round's
@@ -2719,8 +2906,10 @@ class LLMEngine:
                             "+ %d decode row(s) (attempt %d/%d): %s",
                             len(prefill_slots), len(decode_slots),
                             attempt + 1, attempts, e)
+                        if ahead_of is not None:
+                            raise   # retire the predecessor first
                         continue
-                    self.pool.slabs = new_slabs
+                    self.pool.advance(new_slabs)
                     if moe_out:
                         # committed with the step, like the slabs: a failed
                         # attempt or a blame probe counts nowhere
@@ -2730,6 +2919,8 @@ class LLMEngine:
                                                 self.step_tokens, deferred)
                     if started:
                         self.metrics.on_recurrent_rows_started(started)
+                    if ahead_of is not None:
+                        self.metrics.on_step_overlapped()
                     if decode_slots:
                         # the breaker tracks ENGINE-level (decode-protocol)
                         # failures; prefill-only successes must not launder
@@ -2737,51 +2928,82 @@ class LLMEngine:
                         self.supervisor.record_success()
                     break
                 else:
+                    first_attempt = 0
                     if self._blame_and_quarantine(fn, toks, pos, adv, ctr,
                                                   last_err):
                         continue    # survivors retry on a rebuilt row set
                     self._fail_all_active(attempts, last_err)
                     self.supervisor.record_failure()
-                    return 0
-            with RecordEvent(SPAN_SERVE_FETCH):
-                # jit dispatch is async: these conversions are where the
-                # host waits for the device
-                nxt = np.asarray(nxt)   # [N, C] per-position tokens
-                lps = np.asarray(lps)   # [N, C] per-position logprobs
-                new_dstate = np.asarray(new_dstate)  # [N] DFA states
-            now = self.clock.now()
-            with RecordEvent(SPAN_SERVE_COMMIT):
-                if sampled_rows:
-                    self.metrics.on_sampler_filter_step()
-                return self._commit_step(
-                    nxt, lps, new_dstate, toks, pos, adv, prefill_slots,
-                    decode_slots, spec_drafts, t0, tc0, now)
+                    return None
+            return _StepInFlight(
+                nxt=nxt, lps=lps, new_dstate=new_dstate, pos=pos, adv=adv,
+                prefill_slots=prefill_slots,
+                decode_slots=decode_slots, reqs=reqs,
+                spec_drafts=spec_drafts, sampled_rows=sampled_rows, t0=t0,
+                tc0=tc0)
 
-    def _commit_step(self, nxt, lps, new_dstate, toks, pos, adv,
-                     prefill_slots, decode_slots, spec_drafts, t0: float,
-                     tc0: Optional[float], now: float) -> int:
+    def _retire(self, rec: _StepInFlight) -> int:
+        """Fetch one launched step's results (the only place the host
+        waits for the device) and commit them. Returns 1 when the step
+        carried at least one decode row (the decode-iteration count the
+        continuous-batching invariants pin), else 0."""
+        with RecordEvent(SPAN_SERVE_FETCH):
+            # jit dispatch is async: these conversions are where the
+            # host waits for the device
+            nxt = np.asarray(rec.nxt)   # [N, C] per-position tokens
+            lps = np.asarray(rec.lps)   # [N, C] per-position logprobs
+            new_dstate = np.asarray(rec.new_dstate)  # [N] DFA states
+        now = self.clock.now()
+        with RecordEvent(SPAN_SERVE_COMMIT):
+            if rec.sampled_rows:
+                self.metrics.on_sampler_filter_step()
+            return self._commit_step(rec, nxt, lps, new_dstate, now)
+
+    def _retire_in_flight(self):
+        """Retire the unretired step, if any, from the caller's thread:
+        for callers that end or empty the engine from outside a pump pass,
+        where no scheduler thread can be inside one."""
+        rec, self._inflight = self._inflight, None
+        if rec is not None:
+            self._retire(rec)
+
+    def _commit_step(self, rec: _StepInFlight, nxt, lps, new_dstate,
+                     now: float) -> int:
         """Commit one fetched unified step: draft acceptance, the
         ledger's/observatory's booking of the device span `now - tc0`
         (launch to fetch end; `tc0` is None on an engine that arms
-        neither), emission, retire, finish. `t0` is the start of the
-        `dispatch` span (the decode-step histogram's base), `now` the
-        engine clock at the end of the fetch."""
+        neither), emission, retire, finish. `now` is the engine clock at
+        the end of the fetch.
+
+        A row is committed to the request that rode it, and only while
+        that request still holds the slot (`bound`): a request that ended
+        after the step was launched (EOS, a deadline, a grammar's end,
+        `evacuate()`) has its row discarded, whoever holds the slot by
+        now (`rows_discarded`). What the row wrote lies past every
+        committed length of its slot."""
+        pos, adv = rec.pos, rec.adv
+        prefill_slots, decode_slots = rec.prefill_slots, rec.decode_slots
+        tc0 = rec.tc0
+
+        def bound(slot):
+            req = rec.reqs[slot]
+            return req if self._active.get(slot) is req else None
+
         with self._cond:
-            accept = self._acceptance_locked(decode_slots, spec_drafts,
-                                             nxt)
+            accept = self._acceptance_locked(
+                {s: bound(s) for s in decode_slots}, rec.spec_drafts, nxt)
         if self.ledger is not None or self.observatory is not None:
             if self.ledger is not None:
                 with self._cond:
-                    owners = [(self._active[s].tenant,
-                               self._active[s].slo, int(adv[s]))
-                              for s in prefill_slots
-                              if s in self._active]
-                    adapter_owners = [
-                        (self._active[s].adapter or "base", int(adv[s]))
-                        for s in prefill_slots if s in self._active]
+                    riding = [(bound(s), int(adv[s])) for s in prefill_slots]
+                    owners = [(req.tenant, req.slo, n)
+                              for req, n in riding if req is not None]
+                    adapter_owners = [(req.adapter or "base", n)
+                                      for req, n in riding
+                                      if req is not None]
                     decode_useful = drafted = accepted = 0
                     for s in decode_slots:
-                        req = self._active.get(s)
+                        req = bound(s)
                         if req is None or s not in accept:
                             continue
                         emit_toks, acc, k = accept[s]
@@ -2822,11 +3044,13 @@ class LLMEngine:
                 self.decode_iterations += 1
             elif prefill_slots:
                 self.prefill_dispatches += 1
+            discarded = 0
             for slot in prefill_slots:
-                # evacuate() (deploy drain) may have freed the slot
-                # between row build and commit in threaded mode
-                req = self._active.get(slot)
+                # the request may have ended, and the slot gone to another,
+                # since the row was built
+                req = bound(slot)
                 if req is None:
+                    discarded += 1
                     continue
                 n = int(adv[slot])
                 off = req.chunk_off
@@ -2877,9 +3101,10 @@ class LLMEngine:
                     self._evict_expired_locked(req, slot, now)
             total_emitted = 0
             for slot in decode_slots:
-                req = self._active.get(slot)
+                req = bound(slot)
                 if req is None or slot not in accept:
-                    continue  # evacuated mid-step (deploy drain)
+                    discarded += 1  # ended after launch, or evacuated
+                    continue
                 emit_toks, acc, k = accept[slot]
                 L = int(pos[slot])
                 # the verify wrote KV for every consumed column, but
@@ -2924,8 +3149,15 @@ class LLMEngine:
                     self._evict_expired_locked(req, slot, now)
             self.metrics.set_slots(self.pool.active_slots(),
                                    self.pool.num_slots)
+        if discarded:
+            self.metrics.on_rows_discarded(discarded)
+        # the time this step added: from its launch, or from the previous
+        # step's retire where it was launched before that (then the wait
+        # since launch covers its predecessor's run too)
+        step_ms = (now - max(rec.t0, self._retired_at)) * 1e3
+        self._retired_at = now
         if n_decode:
-            self.metrics.on_decode_step(n_decode, (now - t0) * 1e3,
+            self.metrics.on_decode_step(n_decode, step_ms,
                                         tokens=total_emitted)
             return 1
         if prefill_slots:
@@ -2993,7 +3225,8 @@ class LLMEngine:
             args = (self.params, jnp.asarray(solo_toks),
                     jnp.asarray(solo_pos), jnp.asarray(solo_adv),
                     self.pool.device_block_table(),
-                    self.pool.slabs) + sargs + aargs
+                    self.pool.slabs) + sargs + self._feedback_args() \
+                + (self.pool.scratch_slabs(),) + aargs
             probe_kinds = [(kind, (req.submit_idx,))]
             if req.adapter:
                 # the solo probe must announce the same adapter kind the
@@ -3001,7 +3234,10 @@ class LLMEngine:
                 # reproduce and the fault would look unattributable
                 probe_kinds.append(("adapter", (req.submit_idx,)))
             try:
-                self._run_dispatch(tuple(probe_kinds), fn, args)
+                # a probe's result is never committed: its slabs, written
+                # into the scratch it consumed, are the next scratch
+                self.pool.spare = self._run_dispatch(
+                    tuple(probe_kinds), fn, args)[3]
             except DispatchFailedError as e:
                 blamed.append((slot, req, e))
                 flight_recorder().record(
@@ -3110,11 +3346,10 @@ class LLMEngine:
                 while True:
                     if self._stopped or self.supervisor.open:
                         return
-                    if (self._draining and not self._queue_len_locked()
-                            and not self._active):
-                        return          # drained: stop() joins us
-                    if self._queue_len_locked() or self._active:
+                    if self._has_work_locked():
                         break
+                    if self._draining:
+                        return          # drained: stop() joins us
                     self.clock.wait(self._cond, None)
             try:
                 self.pump()

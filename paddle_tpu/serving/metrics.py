@@ -282,6 +282,8 @@ class LLMMetrics(ServingMetrics):
                               "step_tokens_live": 0,
                               "step_tokens_computed": 0,
                               "prefill_rows_deferred": 0,
+                              "steps_overlapped": 0,
+                              "rows_discarded": 0,
                               "moe_assignments": 0,
                               "recurrent_rows_started": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
@@ -516,6 +518,21 @@ class LLMMetrics(ServingMetrics):
             self.counters["step_tokens_computed"] += int(computed)
             self.counters["prefill_rows_deferred"] += int(deferred)
 
+    def on_step_overlapped(self):
+        """One unified step launched while its predecessor was still
+        unretired: the chip had it queued before the host fetched and
+        committed the step before it. Over `unified_steps`: the share of
+        steps that paid no host gap."""
+        with self._lock:
+            self.counters["steps_overlapped"] += 1
+
+    def on_rows_discarded(self, n: int):
+        """`n` rows of a retired step belonged to requests that had ended
+        after the step was launched (EOS, a deadline, a grammar's end, an
+        evacuation): their tokens were dropped, not emitted."""
+        with self._lock:
+            self.counters["rows_discarded"] += int(n)
+
     def set_recurrent_state(self, nbytes: int):
         with self._lock:
             self.recurrent_state_bytes = int(nbytes)
@@ -736,7 +753,8 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_sampler_filter_steps_total",
                  s["sampler_filter_steps"])
         for name in ("step_tokens_live", "step_tokens_computed",
-                     "prefill_rows_deferred"):
+                     "prefill_rows_deferred", "steps_overlapped",
+                     "rows_discarded"):
             b.family(f"{px}_{name}_total", "counter")
             b.sample(f"{px}_{name}_total", s[name])
         b.family(f"{px}_prefills_total", "counter")

@@ -182,7 +182,11 @@ def test_eos_retires_row_early_and_frees_its_slot(gpt_tiny):
     got = h.result(timeout=0)
     assert got.shape == (j + 1,) and got[-1] == eos
     assert np.array_equal(got, ref[:j + 1])
-    assert eng.decode_iterations == j      # one iteration per post-prefill tok
+    # one iteration per post-prefill token, and the step that was launched
+    # before the host saw the eos: its row is discarded
+    assert eng.decode_iterations == j + 1
+    snap = eng.metrics.snapshot()
+    assert snap["rows_discarded"] == 1 and snap["tokens_out"] == j
     assert eng.pool.free_slots() == 1      # retired row released its slot
     ref_eos = generate(gpt_tiny, prompt[None, :], max_new_tokens=12,
                        eos_token_id=eos)
@@ -266,6 +270,10 @@ def test_mid_decode_eviction_keeps_partial_tokens(gpt_tiny):
         h.result(timeout=0)
     partial = h.tokens_so_far()
     assert 1 <= len(partial) < 16          # stream stays readable
+    eng.pump()                             # the step launched before the
+    #                                        eviction retires: row discarded
+    assert h.tokens_so_far() == partial
+    assert eng.metrics.snapshot()["rows_discarded"] == 1
     assert not eng.has_work()
     h2 = eng.submit([5, 6], max_new_tokens=2)   # slot came back
     while eng.has_work():
@@ -669,9 +677,9 @@ def test_llm_drain_timeout_fails_stragglers_typed(gpt_tiny):
     calls = []
 
     def wedged_step(*args):
-        if not calls:                       # let h1's prefill chunk land
-            calls.append(1)
-            return real_step(*args)
+        if len(calls) < 2:                  # let h1's prefill chunk land:
+            calls.append(1)                 # its step, and the one launched
+            return real_step(*args)         # ahead before that is retired
         release.wait(60)
         raise RuntimeError("released")
     eng._step_jit = wedged_step             # _step() now returns the wedge

@@ -296,16 +296,19 @@ def test_prefill_rows_that_do_not_fit_wait_in_admission_order(gpt_tiny):
     profiler.start_profiler()           # the in-memory sink only
     try:
         handles = [eng.submit(p, max_new_tokens=5) for p in prompts]
-        eng.pump()                      # admits all 40, runs one step
+        # admits all 40, launches the first step and, ahead of it, the
+        # second (built from what the first will commit), retires the first
+        eng.pump()
         by_age = sorted(eng._active.values(), key=lambda r: r.submit_idx)
         assert [r.chunk_off for r in by_age] == [16] * 32 + [0] * 8
-        first = _dispatch_spans()[-1]
+        first, second = _dispatch_spans()[-2:]
         assert first["live_tokens"] == 512 and first["deferred_rows"] == 8
-        assert first["prefill_rows"] == 32
-        eng.pump()
+        assert first["prefill_rows"] == 32 and first["in_flight"] == 0
         # second step: 8 whole first chunks beside 32 eight-token tails
+        assert second["live_tokens"] == 32 * 8 + 8 * 16
+        assert second["in_flight"] == 1
+        eng.pump()                      # retires the second
         assert [r.chunk_off for r in by_age] == [24] * 32 + [16] * 8
-        assert _dispatch_spans()[-1]["live_tokens"] == 32 * 8 + 8 * 16
         _drain(eng)
         spans = _dispatch_spans()
     finally:
@@ -351,6 +354,7 @@ def test_decode_rows_are_placed_first_and_a_smaller_chunk_may_fill(gpt_tiny):
     assert span["decode_rows"] == 7 and span["prefill_rows"] == 32
     assert span["live_tokens"] == 7 + 31 * 16 + 3
     assert span["deferred_rows"] == 1
+    eng.pump()                          # that step, launched ahead, retires
     waiting = [r for r in eng._active.values() if r.chunk_off == 0]
     assert [len(r.prompt) for r in waiting] == [16]
     _drain(eng)
@@ -369,6 +373,7 @@ def _lowered(eng, prompt):
         args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(adv), eng.pool.device_block_table(),
                 eng.pool.slabs) + eng._sampling_args_locked(ctr) \
+            + eng._feedback_args() + (eng.pool.scratch_slabs(),) \
             + eng._tail_args_locked()
     return args, eng._step().lower(*args).as_text()
 
@@ -396,7 +401,14 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
     prefill = eng._prefill_fn
 
     def step(params, toks, pos, adv, table, slabs, temp, topk, topp, samp,
-             seed, ctr, dstate, gid, bank, moe_totals=None):
+             seed, ctr, dstate, gid, bank, feed, prev_sel, spare,
+             moe_totals=None):
+        # `spare`: never read; donated, its buffers take the new slabs
+        # the input token of a row launched ahead of its predecessor
+        fed = jnp.take_along_axis(
+            prev_sel, jnp.maximum(feed, 0)[:, None], axis=1)[:, 0]
+        toks = toks.at[:, 0].set(
+            jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
         paged = (table, (pos + adv).astype(jnp.int32), block_len, pages)
         with llm_engine.moe.collect_expert_counts() as counts:
             logits, new_slabs = prefill(params, toks, slabs, pos,
@@ -410,9 +422,10 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
             return sel, lp, state, new_slabs
         return sel, lp, state, new_slabs, moe_totals + jnp.stack(counts)
 
-    if len(args) == 17:                 # a sparse model: (None, totals)
-        args = args[:15] + (args[16],)
-    assert text == jax.jit(step).lower(*args).as_text()
+    if len(args) == 20:                 # a sparse model: (None, totals)
+        args = args[:18] + (args[19],)
+    assert text == jax.jit(step, donate_argnames=("spare",),
+                           keep_unused=True).lower(*args).as_text()
 
     packed_eng = _engine(model)
     _, packed_text = _lowered(packed_eng, prompt)

@@ -502,6 +502,7 @@ def test_chunked_short_prompt_ttft_beats_bucket_baseline(gpt_tiny):
         clock=clock)
     long = eng.submit(np.arange(1, 61, dtype=np.int32), max_new_tokens=4)
     eng.pump()                             # long's chunk 0 (prefill-only)
+    #                                        retired, chunk 1 in flight
     clock.advance(C / 1e3)
     short = eng.submit(np.arange(70, 76, dtype=np.int32),
                        max_new_tokens=4)
@@ -511,8 +512,10 @@ def test_chunked_short_prompt_ttft_beats_bucket_baseline(gpt_tiny):
         eng.pump()                         # mixed: long chunk + short row
         clock.advance(C / 1e3)
         pumps += 1
-    assert pumps == 1                      # tok0 on its FIRST ride-along
-    assert eng._dispatch_idx - idx0 == 1   # one dispatch per mixed pump
+    # tok0 on its FIRST ride-along: the step launched in the pass that
+    # admits it, behind the one in flight, retired by the pass after
+    assert pumps == 2
+    assert eng._dispatch_idx - idx0 == 2   # one dispatch per mixed pump
     # bucket baseline: pow2(60)=64-wide long prefill, then pow2(6)=8-wide
     # short prefill, sequential dispatches -> 72ms before short's tok0
     baseline_ms = 64 + 8
@@ -587,21 +590,24 @@ def test_chunk1_failure_blames_mid_prefill_row_only(gpt_tiny):
     good_p = np.arange(1, 4, dtype=np.int32)
     ref = np.asarray(generate(gpt_tiny, good_p[None, :],
                               max_new_tokens=6).numpy())[0, 3:]
-    # idx 0: good's solo prefill. idx 1: bad chunk0 + good decode (ok).
-    # idx 2: bad chunk1 + good decode RAISES (retries=0); probes — good
-    # solo decode idx 3 (clean), bad solo prefill idx 4 (raises) -> the
-    # mid-prefill row is blamed; survivors re-step at idx 5.
-    plan = FaultPlan.from_spec("dispatch_raise@2;dispatch_raise@4")
+    # idx 0: good's solo prefill; idx 1, launched ahead in the same pass:
+    # good's first decode. idx 2: bad chunk0 + good decode (ok).
+    # idx 3: bad chunk1 + good decode, launched ahead of idx 2, RAISES
+    # (retries=0): idx 2 is retired first, then probes — good solo decode
+    # idx 4 (clean), bad solo prefill idx 5 (raises) -> the mid-prefill
+    # row is blamed; survivors re-step at idx 6.
+    plan = FaultPlan.from_spec("dispatch_raise@3;dispatch_raise@5")
     eng = serving.LLMEngine(
         gpt_tiny, _cfg(num_slots=2, prefill_chunk=4, dispatch_retries=0),
         clock=serving.SimClock(), fault_plan=plan)
     good = eng.submit(good_p, max_new_tokens=6)          # submit idx 0
-    eng.pump()                                           # idx 0
+    eng.pump()                                           # idx 0, 1
     bad = eng.submit(np.arange(10, 20, dtype=np.int32),  # submit idx 1
                      max_new_tokens=4)
-    eng.pump()                                           # idx 1: chunk 0
-    assert eng._active[bad_slot(eng, bad)].chunk_off == 4
-    eng.pump()                             # idx 2 fails -> blame -> idx 5
+    eng.pump()                                           # idx 2: chunk 0
+    assert eng._inflight.adv[bad_slot(eng, bad)] == 4    # ... in flight
+    assert eng._active[bad_slot(eng, bad)].chunk_off == 0
+    eng.pump()          # idx 3 fails -> idx 2 commits -> blame -> idx 6
     with pytest.raises(serving.DispatchFailedError, match="isolation") \
             as exc:
         bad.result(timeout=0)
@@ -610,7 +616,7 @@ def test_chunk1_failure_blames_mid_prefill_row_only(gpt_tiny):
     while eng.has_work():
         eng.pump()
     assert np.array_equal(good.result(timeout=0), ref)
-    assert sorted(plan.log) == ["dispatch_raise@2", "dispatch_raise@4"]
+    assert sorted(plan.log) == ["dispatch_raise@3", "dispatch_raise@5"]
     snap = eng.metrics.snapshot()
     assert snap["quarantined"] == 1 and snap["completed"] == 1
     assert not eng.broken
