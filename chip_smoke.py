@@ -15,8 +15,8 @@ weights are random, from a seed):
 
 Each leg first checks the Pallas kernel it depends on against the repo's
 own reference ON THE DEVICE (flash fwd + dq/dk/dv vs `_attention_reference`;
-`ragged_paged_attention(impl="pallas")` and `ssm_update(impl="pallas")` vs
-`impl="scan"`) and then requires
+`ragged_paged_attention(impl="pallas")`, full walk and windowed walk through
+a ring, and `ssm_update(impl="pallas")` vs `impl="scan"`) and then requires
 that kernel's Mosaic custom calls in the compiled step it just ran. Any
 failed check raises; nothing is caught to let a leg fail while the run
 exits 0.
@@ -61,6 +61,10 @@ FULL = dict(
     # and OLMoE (MHA 16/16), 14 pages of 16 a slot + a chunk of write-padding
     paged=(dict(heads=32, kv_heads=8, head_dim=128, pages=14),
            dict(heads=16, kv_heads=16, head_dim=128, pages=14)),
+    # the window/full cell's window layers: GQA 32/4, a window of 1,024 in
+    # a ring of 65 pages (window + one chunk)
+    paged_window=dict(heads=32, kv_heads=4, head_dim=128, window=1024,
+                      ring_pages=65),
     # granite-4.0-h-small's Mamba-2 state: 128 heads x 64, 128 channels
     ssm=dict(heads=128, head_dim=64, state=128),
 )
@@ -70,6 +74,8 @@ TINY = dict(
     slots=4, n_blocks=31, prompts=(12, 40, 100, 200), max_new=8,
     paged=(dict(heads=4, kv_heads=2, head_dim=64, pages=4),
            dict(heads=2, kv_heads=2, head_dim=64, pages=4)),
+    paged_window=dict(heads=4, kv_heads=2, head_dim=64, window=32,
+                      ring_pages=3),
     ssm=dict(heads=4, head_dim=64, state=16),
 )
 
@@ -441,6 +447,49 @@ def _paged_parity(size: dict):
                      f"paged H={H}/{Hkv} Tq={Tq} within {tol:g}")
 
 
+def _paged_window_parity(size: dict):
+    """The windowed walk (`paged_window`) `impl="pallas"` against
+    `impl="scan"` on this device at `size["paged_window"]`: bf16, block_len
+    16, each row its own ring (position p at column p mod ring, so rows
+    past the ring have wrapped), query widths 1 and 16, rows shorter than
+    the window, across it, twice and five times round the ring. Tolerance
+    as `_paged_parity`'s."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import pallas_mode
+    from paddle_tpu.ops.paged_attention import (WINDOW_KERNEL,
+                                                ragged_paged_attention)
+    g = size["paged_window"]
+    H, Hkv, D = g["heads"], g["kv_heads"], g["head_dim"]
+    W, pages = g["window"], g["ring_pages"]
+    N, bl, tol = 8, 16, 2e-2
+    ring = pages * bl
+    rng = np.random.RandomState(2)
+    k = jnp.asarray(rng.randn(N, Hkv, ring + bl, D), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(N, Hkv, ring + bl, D), jnp.bfloat16)
+    for Tq in (1, 16):
+        q = jnp.asarray(rng.randn(N, H, Tq, D), jnp.bfloat16)
+        lens = np.maximum(Tq, np.array(
+            [1, W // 3, W - 1, W + bl + 1, ring, 2 * ring + 5,
+             5 * ring + bl - 1, 8 * ring - 3], np.int32))
+        q_pos = (lens - Tq).astype(np.int32)
+        pallas_mode.KERNEL_TILINGS.clear()
+        outs = {impl: ragged_paged_attention(
+            q, k, v, None, lens, q_pos, block_len=bl, pages_per_row=pages,
+            impl=impl, window=W) for impl in ("pallas", "scan")}
+        ((kernel, tiling),) = pallas_mode.KERNEL_TILINGS
+        tiling = dict(tiling)
+        err = _max_err(outs["pallas"], outs["scan"])
+        _say(f"{kernel} pallas vs scan H={H} Hkv={Hkv} D={D} window={W} "
+             f"ring={pages} pages Tq={Tq} seq_lens={lens.tolist()} bf16: "
+             f"grid {tiling['grid']}, tile {tiling['heads']} KV heads x "
+             f"{tiling['rows']} rows; max abs err {err:.2e} (tolerance "
+             f"{tol:g})")
+        _require(kernel == WINDOW_KERNEL and np.isfinite(err) and err <= tol,
+                 f"{WINDOW_KERNEL} H={H}/{Hkv} Tq={Tq} within {tol:g}")
+
+
 def _ssm_parity(size: dict):
     """`ssm_update(impl="pallas")` against `impl="scan"` on this device at
     `size["ssm"]`, bf16 state and inputs, 8 rows of 16, of 1 and of 80
@@ -508,6 +557,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
 
     _say("[serve] kernel parity on this device")
     _paged_parity(size)
+    _paged_window_parity(size)
     _ssm_parity(size)
 
     import paddle_tpu as paddle

@@ -153,6 +153,17 @@ class RecurrentState(NamedTuple):
     ssm: jax.Array
 
 
+class WindowKV(NamedTuple):
+    """What `init_cache` returns for an attention layer with a sliding
+    window when the caller asked for a ring (`window_slab=`): `(k, v)`
+    slabs `[batch, Hkv, ring + pad, D]`, shorter than the other layers'.
+    Position p lives at column `p mod ring` and keys older than the window
+    are overwritten, so the layer's pages cannot be shared, exported or
+    re-read once aged out; by this type a cache manager knows."""
+    k: jax.Array
+    v: jax.Array
+
+
 def make_decoder_fns(model):
     """Expose the prefill/decode-step builders for a cached-decode model.
 

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,9 @@ from ..nn.layer.moe import DroplessMoE
 from ..ops.attention import decode_attention, flash_attention, \
     update_kv_cache
 from ..ops.lora import add_lora_delta
+
+
+FULL, SLIDING = "full_attention", "sliding_attention"
 
 
 @dataclass
@@ -65,10 +68,52 @@ class LlamaConfig:
     # `attention_multiplier * sqrt(head_dim)` after its projection, so the
     # attention kernels keep their own 1/sqrt(head_dim)
     attention_multiplier: Optional[float] = None
+    # width of one head; None: hidden_size // num_attention_heads. A model
+    # may state another (q is then heads * head_dim wide, not hidden_size)
+    head_dim: Optional[int] = None
+    # one entry a layer, FULL or SLIDING; None: every layer attends to the
+    # whole cache. A SLIDING layer's query sees the `sliding_window` keys
+    # up to itself, and its cache in the serving pool is a ring
+    layer_types: Optional[Sequence[str]] = None
+    sliding_window: Optional[int] = None
+    # rotary parameters by layer type, as Hugging Face's `rope_parameters`:
+    # {layer type: {"rope_type": "default" | "yarn", "rope_theta", and for
+    # yarn "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "attention_factor"}}. None, or a type it lacks:
+    # `rope_theta`, unscaled
+    rope_parameters: Optional[Dict[str, dict]] = None
+    # (first, count): every expert layer holds that share of `num_experts`
+    # (one chip's under expert parallelism; `DroplessMoE(held=)`)
+    experts_held: Optional[Tuple[int, int]] = None
 
-    @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_attention_heads
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            bad = set(self.layer_types) - {FULL, SLIDING}
+            if bad or len(self.layer_types) != self.num_hidden_layers:
+                raise ValueError(
+                    f"layer_types: {self.num_hidden_layers} entries of "
+                    f"{FULL!r} / {SLIDING!r}, got {self.layer_types}")
+            if SLIDING in self.layer_types and not (
+                    self.sliding_window and self.sliding_window > 0):
+                raise ValueError("a sliding_attention layer needs "
+                                 "sliding_window >= 1")
+
+    def window_of(self, layer: Optional[int]) -> Optional[int]:
+        """The window of layer `layer`, None where it attends to all."""
+        if layer is None or self.layer_types is None \
+                or self.layer_types[layer] != SLIDING:
+            return None
+        return int(self.sliding_window)
+
+    def rope_of(self, layer: Optional[int]) -> dict:
+        """The rotary parameters of layer `layer`."""
+        kind = FULL if layer is None or self.layer_types is None \
+            else self.layer_types[layer]
+        return dict((self.rope_parameters or {}).get(
+            kind, {"rope_type": "default", "rope_theta": self.rope_theta}))
 
 
 LLAMA_PRESETS = {
@@ -107,12 +152,52 @@ class RMSNorm(Layer):
         return apply(f, x, self.weight)
 
 
-def _rope_cos_sin(seq_len, head_dim, theta, dtype=jnp.float32):
+def rope_inv_freq(head_dim, rope: dict):
+    """(inv_freq [D/2] float32, the factor cos and sin are multiplied by)
+    of one layer type's rotary parameters (`LlamaConfig.rope_parameters`).
+
+    "default": `theta^(-2i/D)`, factor 1. "yarn" (Peng et al. 2023, as
+    Hugging Face computes it): dimensions that turn more than `beta_fast`
+    times over the original context keep their frequency, those that turn
+    less than `beta_slow` times take it divided by `factor`, a linear ramp
+    between; cos and sin are scaled by `attention_factor` (0.1 ln(factor)
+    + 1 where the parameters give none)."""
+    theta = float(rope["rope_theta"])
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                            dtype=jnp.float32) / head_dim))
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return inv_freq, 1.0
+    if kind != "yarn":
+        raise NotImplementedError(f"rope_type {kind!r}")
+    factor = float(rope["factor"])
+    original = rope["original_max_position_embeddings"]
+
+    def correction_dim(turns):
+        return head_dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(rope.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(rope.get("beta_slow", 1))),
+               head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    attention_factor = rope.get("attention_factor")
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return (inv_freq / factor * ramp + inv_freq * (1.0 - ramp),
+            float(attention_factor))
+
+
+def _rope_cos_sin(seq_len, head_dim, rope: dict):
+    """cos, sin [S, D] of positions 0..S-1 under one layer type's rotary
+    parameters (`LlamaConfig.rope_of`)."""
+    inv_freq, factor = rope_inv_freq(head_dim, rope)
     t = jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)           # [S, D/2]
     emb = jnp.concatenate([freqs, freqs], -1)  # [S, D]
+    if factor != 1.0:
+        return jnp.cos(emb) * factor, jnp.sin(emb) * factor
     return jnp.cos(emb), jnp.sin(emb)
 
 
@@ -128,9 +213,13 @@ def _apply_rope(x, cos, sin):
 
 
 class LlamaAttention(Layer):
-    def __init__(self, config: LlamaConfig):
+    def __init__(self, config: LlamaConfig, layer: Optional[int] = None):
         super().__init__()
         self.config = config
+        # by the layer's type: its window (None: the whole cache) and its
+        # rotary parameters
+        self.window = config.window_of(layer)
+        self.rope = config.rope_of(layer)
         self.num_heads = config.num_attention_heads
         self.num_kv_heads = config.num_key_value_heads
         self.head_dim = config.head_dim
@@ -160,7 +249,7 @@ class LlamaAttention(Layer):
         v = self.v_proj(hidden)
         n_rep = self.num_heads // self.num_kv_heads
         hd = self.head_dim
-        theta = self.config.rope_theta
+        rope_params, window = self.rope, self.window
         if cache is not None:
             if adapters is not None:
                 # gathered per-row LoRA deltas (ISSUE 20); bank row 0 is
@@ -180,8 +269,8 @@ class LlamaAttention(Layer):
         rope = self.config.rope
         if cache is not None:
             return self._forward_cached(q, k, v, cache, pos, n_rep, hd,
-                                        theta, paged=paged,
-                                        adapters=adapters, pack=pack)
+                                        paged=paged, adapters=adapters,
+                                        pack=pack)
 
         def attn(qa, ka, va):
             qh = qa.reshape(qa.shape[0], qa.shape[1], -1, hd)
@@ -191,7 +280,7 @@ class LlamaAttention(Layer):
             kh = jnp.swapaxes(kh, 1, 2)
             vh = jnp.swapaxes(vh, 1, 2)
             if rope:
-                cos, sin = _rope_cos_sin(qa.shape[1], hd, theta)
+                cos, sin = _rope_cos_sin(qa.shape[1], hd, rope_params)
                 cos = cos.astype(qh.dtype)[None].squeeze(0)
                 sin = sin.astype(qh.dtype)[None].squeeze(0)
                 qh = _apply_rope(qh, cos, sin)
@@ -199,15 +288,15 @@ class LlamaAttention(Layer):
             if n_rep > 1:  # GQA: repeat kv heads
                 kh = jnp.repeat(kh, n_rep, axis=1)
                 vh = jnp.repeat(vh, n_rep, axis=1)
-            out = flash_attention(qh, kh, vh, causal=True)
+            out = flash_attention(qh, kh, vh, causal=True, window=window)
             out = jnp.swapaxes(out, 1, 2)
             return out.reshape(out.shape[0], out.shape[1], -1)
 
         ctx = apply(attn, q, k, v)
         return self.o_proj(ctx)
 
-    def _forward_cached(self, q, k, v, cache, pos, n_rep, hd, theta,
-                        paged=None, adapters=None, pack=None):
+    def _forward_cached(self, q, k, v, cache, pos, n_rep, hd, paged=None,
+                        adapters=None, pack=None):
         """Static-shape KV-cache decode/prefill step (jit/scan friendly):
         new k/v are written into the [B, Hkv, Lmax, D] cache at `pos`,
         attention runs over the FULL cache with an absolute-position causal
@@ -215,8 +304,24 @@ class LlamaAttention(Layer):
         generation loop) — TPU-first inference parity-plus. With `pack`
         (`ops.attention.TokenPack`) q/k/v arrive as packed tokens and
         attention runs in the slots' own layout, the context packed again
-        behind it."""
+        behind it.
+
+        A window layer (`self.window`): with `paged` (the serving pool's
+        step) its cache is a ring of `paged[4]` pages, written at `pos mod
+        ring` and read from the window's first block; without, a
+        full-length cache read the same way (`generate()`)."""
         k_cache, v_cache = cache
+        window, rope_params = self.window, self.rope
+        ring = None
+        if window is not None and paged is not None:
+            _, seq_lens, block_len, pages_per_row, ring_pages = paged
+            ring = int(ring_pages) * int(block_len)
+            # positions the rotary table must reach: the slot's capacity
+            # and the chunk-wide stripe a free row writes past it
+            positions = int(pages_per_row) * int(block_len)
+            paged = (None, seq_lens, block_len, ring_pages)
+        elif paged is not None:
+            paged = paged[:4]
 
         def attn_dec(qa, ka, va, kc, vc, pos_):
             import jax.numpy as jnp
@@ -230,7 +335,9 @@ class LlamaAttention(Layer):
             kh = jnp.swapaxes(ka.reshape(B, T, -1, hd), 1, 2)
             vh = jnp.swapaxes(va.reshape(B, T, -1, hd), 1, 2)
             if self.config.rope:
-                cos, sin = _rope_cos_sin(Lmax, hd, theta)
+                cos, sin = _rope_cos_sin(
+                    Lmax if ring is None else positions + T, hd,
+                    rope_params)
                 if jnp.ndim(pos_) == 0:
                     cos_t = lax.dynamic_slice_in_dim(cos, pos_, T, 0)
                     sin_t = lax.dynamic_slice_in_dim(sin, pos_, T, 0)
@@ -247,11 +354,12 @@ class LlamaAttention(Layer):
                 sin_t = sin_t.astype(qh.dtype)
                 qh = _apply_rope(qh, cos_t, sin_t)
                 kh = _apply_rope(kh, cos_t, sin_t)
-            kc, vc = update_kv_cache(kc, vc, kh, vh, pos_)
+            kc, vc = update_kv_cache(kc, vc, kh, vh, pos_, ring=ring)
             # `paged` closed over (constants): slot-pool block-table
             # routing for the ragged kernel (ISSUE 7)
             out = decode_attention(qh, kc, vc, pos_,
-                                   scale=1.0 / (hd ** 0.5), paged=paged)
+                                   scale=1.0 / (hd ** 0.5), paged=paged,
+                                   window=window)
             out = jnp.swapaxes(out, 1, 2).reshape(B, T, -1)
             if pack is not None:
                 out = pack.pack(out)
@@ -293,14 +401,15 @@ class LlamaMLP(Layer):
 
 
 class LlamaDecoderLayer(Layer):
-    def __init__(self, config: LlamaConfig):
+    def __init__(self, config: LlamaConfig, layer: Optional[int] = None):
         super().__init__()
-        self.self_attn = LlamaAttention(config)
+        self.self_attn = LlamaAttention(config, layer)
         self.sparse = config.num_experts > 0
         self.mlp = DroplessMoE(
             config.hidden_size, config.intermediate_size,
             config.num_experts, config.num_experts_per_tok,
-            config.norm_topk_prob) if self.sparse else LlamaMLP(config)
+            config.norm_topk_prob, held=config.experts_held) \
+            if self.sparse else LlamaMLP(config)
         self.input_layernorm = RMSNorm(config.hidden_size,
                                        config.rms_norm_eps)
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
@@ -344,8 +453,8 @@ class LlamaModel(Layer):
         self.config = config
         self.embed_tokens = VocabParallelEmbedding(config.vocab_size,
                                                    config.hidden_size)
-        self.layers = LayerList([LlamaDecoderLayer(config)
-                                 for _ in range(config.num_hidden_layers)])
+        self.layers = LayerList([LlamaDecoderLayer(config, i)
+                                 for i in range(config.num_hidden_layers)])
         self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, input_ids, caches=None, pos=None, paged=None,
@@ -410,13 +519,30 @@ class LlamaForCausalLM(Layer):
         return logits
 
     # ---- KV-cache generation (parity-plus; models/generation.py) ----
-    def init_cache(self, batch_size: int, max_len: int, dtype=None):
+    def init_cache(self, batch_size: int, max_len: int, dtype=None,
+                   window_slab=None):
+        """Per layer `(k, v)` slabs `[batch, Hkv, max_len, D]`. A cache
+        manager that keeps a window layer's keys in a ring passes
+        `window_slab(window) -> columns`: such a layer's entry is then a
+        `generation.WindowKV` of that many columns, by whose type and
+        shape the manager knows the layer's kind and its ring. Left out
+        (`generate()`), every layer's slab is `max_len` long."""
+        from .generation import WindowKV
         cfg = self.config
-        import jax.numpy as jnp
         dt = dtype or self.llama.embed_tokens.weight.dtype
-        shape = (batch_size, cfg.num_key_value_heads, max_len, cfg.head_dim)
-        return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-                for _ in range(cfg.num_hidden_layers)]
+
+        def slab(cols):
+            shape = (batch_size, cfg.num_key_value_heads, cols, cfg.head_dim)
+            return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+
+        entries = []
+        for i in range(cfg.num_hidden_layers):
+            window = cfg.window_of(i)
+            if window is None or window_slab is None:
+                entries.append(slab(max_len))
+            else:
+                entries.append(WindowKV(*slab(int(window_slab(window)))))
+        return entries
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
                            adapters=None, pack=None):
@@ -459,5 +585,7 @@ class LlamaForCausalLM(Layer):
     @classmethod
     def from_preset(cls, name: str, **overrides):
         import dataclasses
+        # a head width the preset derived follows the overridden sizes
+        overrides.setdefault("head_dim", None)
         cfg = dataclasses.replace(LLAMA_PRESETS[name], **overrides)
         return cls(cfg)

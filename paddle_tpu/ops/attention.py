@@ -705,10 +705,14 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     force_pallas: bool = False, mask=None,
-                    dropout_p: float = 0.0, dropout_seed: int = 0):
+                    dropout_p: float = 0.0, dropout_seed: int = 0,
+                    window: Optional[int] = None):
     """q,k,v: [B, H, S, D] jax arrays; optional additive mask [B, 1|H, Sq, Sk].
     Returns [B, H, Sq, D]. Supports rectangular (cross) attention: causal uses
-    bottom-right alignment when Sq != Sk.
+    bottom-right alignment when Sq != Sk. `window=W`: a query also sees no
+    key more than W - 1 positions behind it (itself included in the W); it
+    rides as an additive mask, so a kernel that skips the blocks below the
+    window is later work.
 
     Uses the Pallas kernels (fwd + dq/dkv bwd) on TPU for seqs >= 512 and
     the fused XLA reference for short sequences, indivisible lengths and the
@@ -724,6 +728,11 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
         block_k = DEFAULT_BLOCK_K
     on_cpu = pallas_mode.platform() == "cpu"
     Sq, Sk = q.shape[2], k.shape[2]
+    if window is not None:
+        # a key W or more positions behind its query is out of the window
+        out = causal_mask(Sq, Sk, q_offset=Sk - Sq, k_offset=int(window))
+        low = jnp.where(out, _NEG_INF, 0.0)[None, None]
+        mask = low if mask is None else mask + low
     # why this call takes the XLA reference instead of the kernel, if it does
     reason = None
     if sequence_sharded_trace() and not force_pallas:
@@ -864,7 +873,23 @@ def token_pack(adv, pos, chunk: int, step_tokens: int) -> TokenPack:
                      last=jnp.maximum(end - 1, 0), slot_pos=pos)
 
 
-def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
+def _ring_write(cache, new, pos, ring: int):
+    """One row: `new [Hkv, T, D]` at column `pos mod ring` of a ring of
+    `ring` columns that lies in `cache [Hkv, ring + T, D]`. The stripe is
+    written where it starts (its end may run into the T columns behind the
+    ring), then what ran over is brought round to the ring's first
+    columns: a write that straddles the ring's end is split in two."""
+    from jax import lax
+    T = new.shape[1]
+    c0 = pos % ring
+    cache = lax.dynamic_update_slice(cache, new, (0, c0, 0))
+    over = c0 + T - ring                                 # columns past it
+    wrapped = jnp.arange(T, dtype=jnp.int32)[None, :, None] < over
+    head = jnp.where(wrapped, cache[:, ring:ring + T], cache[:, :T])
+    return cache.at[:, :T].set(head)
+
+
+def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, ring=None):
     """Write k/v [B, Hkv, T, D] into static [B, Hkv, L, D] caches at `pos`.
 
     `pos` is the absolute position of the first new token: a scalar writes
@@ -873,11 +898,24 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
     slot sits at a different sequence length). All shapes stay static —
     vector writes are a vmapped dynamic_update_slice, not a gather/scatter
     with dynamic extents.
+
+    `ring` (a window layer's slab in the serving pool): the first `ring`
+    columns of the cache are a ring, position p lives at column `p mod
+    ring`, and L >= ring + T (`_ring_write`).
     """
     from jax import lax
     pos = jnp.asarray(pos)
     k_new = k_new.astype(k_cache.dtype)
     v_new = v_new.astype(v_cache.dtype)
+    if ring is not None:
+        if k_cache.shape[2] < ring + k_new.shape[2]:
+            raise ValueError(
+                f"a ring of {ring} columns written {k_new.shape[2]} at a "
+                f"time needs {ring + k_new.shape[2]} columns, the cache "
+                f"has {k_cache.shape[2]}")
+        pos = jnp.broadcast_to(pos, k_cache.shape[:1]).astype(jnp.int32)
+        write = jax.vmap(lambda c, u, p: _ring_write(c, u, p, int(ring)))
+        return write(k_cache, k_new, pos), write(v_cache, v_new, pos)
     if pos.ndim == 0:
         return (lax.dynamic_update_slice(k_cache, k_new, (0, 0, pos, 0)),
                 lax.dynamic_update_slice(v_cache, v_new, (0, 0, pos, 0)))
@@ -886,7 +924,8 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
     return row_write(k_cache, k_new, pos), row_write(v_cache, v_new, pos)
 
 
-def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None):
+def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
+                     window=None):
     """Length-masked attention of q [B, H, T, D] over padded static caches
     [B, Hkv, L, D] (GQA: Hkv divides H; kv heads are repeated).
 
@@ -903,6 +942,13 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None):
     continuous-batched streams stay bit-identical to one-shot generate()
     whenever both sides use the same kv block size — the flash-accumulation
     grouping, and therefore the bits, depend on block_len alone.
+
+    `window=W`: a query sees the W keys up to itself. With `paged` the
+    caches are then rings (`update_kv_cache(ring=)`): `pages_per_row` is
+    the ring's pages and a `block_table` of None each row's own ring; left
+    as None the contiguous cache is walked from the window's first block.
+    Either way the blocks walked are the logical ones, so both give the
+    same bits at one block size.
     """
     from .paged_attention import (DEFAULT_KV_BLOCK, ragged_paged_attention,
                                   trivial_block_table)
@@ -916,7 +962,7 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None):
         return ragged_paged_attention(
             q, k_cache, v_cache, block_table, seq_lens, jnp.asarray(pos),
             block_len=int(block_len), pages_per_row=int(pages_per_row),
-            scale=scale)
+            scale=scale, window=window)
     L = k_cache.shape[2]
     table, nb = trivial_block_table(B, L, DEFAULT_KV_BLOCK)
     pad = nb * DEFAULT_KV_BLOCK - L
@@ -928,4 +974,5 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None):
     seq_lens = q_pos + T
     return ragged_paged_attention(q, k_cache, v_cache, table, seq_lens,
                                   q_pos, block_len=DEFAULT_KV_BLOCK,
-                                  pages_per_row=nb, scale=scale)
+                                  pages_per_row=nb, scale=scale,
+                                  window=window)

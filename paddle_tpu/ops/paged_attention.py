@@ -51,6 +51,18 @@ the pool on the way in:
   `DEFAULT_KV_BLOCK` and the 1-row q tile of the MHA `generate()` decode
   loop; tests/test_mosaic_aot.py pins those and the serve cells' shapes.
 
+A window (PR 31). With `window=W` a query at position p sees the W keys
+`p - W < col <= p` (itself included) and the cache may be a *ring*: logical
+block j lives in page `table[b, j mod R]`, R = `block_table.shape[1]`
+pages a row (position p at ring column `p mod (R * block_len)`; a
+contiguous cache is the ring that never wraps). The walk then starts at the
+first block that cuts the row's window and takes at most
+`ceil((W + Tq) / block_len) + 1` steps whatever the row's length: grid
+(B, G, steps), its own `pallas_call` name `paged_window`, so that a trace
+tells its time from the full walk's `paged_attention`. The mask is taken
+on logical columns, so a ring page visited under two logical blocks (the
+walk's first and last may share one) shows each its own columns.
+
 Numerics: flash-style online softmax with the repo's exact-zero masking
 convention (ops/attention.py `_fwd_kernel`): masked scores sit at
 `_NEG_INF`, `p = where(s <= _NEG_INF/2, 0, exp(s - m_new))` contributes an
@@ -61,7 +73,9 @@ the result for a query at absolute position P depends only on
 (q, K[0..P], V[0..P]) and the block iteration order — never on the query
 width, the chunk boundary, or how many trailing padded blocks the grid
 carries. Different `block_len`s group the accumulation differently and are
-documented-tolerance-identical only.
+documented-tolerance-identical only. The window keeps it: blocks wholly
+outside a row's window are exact no-ops, so the windowed walk through a
+ring gives the bits of a walk over every block of a full-length cache.
 """
 from __future__ import annotations
 
@@ -83,6 +97,11 @@ from .attention import _GRID_SEMANTICS, _NEG_INF, _dot
 # the CPU scan short; on a TPU the kernel lowers at 8 too (module
 # docstring), so the one-shot path keeps it there.
 DEFAULT_KV_BLOCK = 8
+
+# The windowed walk's name: of its `pallas_call` (so of its instruction in
+# a device trace) and in `pallas_mode`'s counters. It does not hold the
+# full walk's name, because trace readers find a kernel by substring.
+WINDOW_KERNEL = "paged_window"
 
 
 # What one grid step of the kernel may hold in VMEM: its q, K, V and output
@@ -124,11 +143,26 @@ def _choose_tile(H: int, Hkv: int, Tq: int, block_len: int, D: int,
     return tiles[-1]
 
 
+def _first_block(pos, window: int, block_len: int):
+    """The first logical block that cuts the window of a row whose first
+    query sits at `pos`."""
+    return jnp.maximum(pos - window + 1, 0) // block_len
+
+
+def _window_steps(window: int, Tq: int, block_len: int, ring_pages: int):
+    """Steps of a windowed walk: the blocks that W + Tq - 1 consecutive
+    columns can cut, and never more than a ring that holds them has (its
+    first and last block may share a page: + 1)."""
+    return min(-(-(window + Tq) // block_len) + 1, ring_pages + 1)
+
+
 def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
-               block_len: int, pages_per_row: int, scale: float):
+               block_len: int, pages_per_row: int, scale: float,
+               window: int = None):
     """lax.scan over logical blocks, carrying (m, l, acc) — the same
     masked-score -> exact-zero-p -> alpha-rescale sequence as the kernel,
-    one compiled program regardless of grid size."""
+    one compiled program regardless of grid size. With `window` step i is
+    row b's logical block `first[b] + i`, read from the ring."""
     B, H, Tq, D = q.shape
     Hkv = k_cache.shape[1]
     n_rep = H // Hkv
@@ -142,9 +176,19 @@ def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
         return jax.lax.dynamic_slice(cache, (r, 0, c, 0),
                                      (1, Hkv, block_len, D))[0]
 
+    ring_pages = block_table.shape[1]
+    if window is not None:
+        first = _first_block(q_pos, window, block_len)              # [B]
+
     def body(carry, j):
         m_prev, l_prev, acc = carry
-        g = jnp.maximum(block_table[:, j], 0)  # -1 padding clamps to page 0
+        if window is None:
+            g = block_table[:, j]
+        else:
+            j = first + j                      # [B]: each row's own block
+            g = jnp.take_along_axis(
+                block_table, (j % ring_pages)[:, None], axis=1)[:, 0]
+        g = jnp.maximum(g, 0)                  # -1 padding clamps to page 0
         r, c = g // pages_per_row, g % pages_per_row * block_len
         k_j = jax.vmap(page, (None, 0, 0))(k_cache, r, c)   # [B,Hkv,KB,D]
         v_j = jax.vmap(page, (None, 0, 0))(v_cache, r, c)
@@ -153,9 +197,15 @@ def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
             v_j = jnp.repeat(v_j, n_rep, axis=1)
         s = jnp.einsum("bhtd,bhkd->bhtk", q, k_j,
                        preferred_element_type=jnp.float32) * scale
-        col = j * block_len + jnp.arange(block_len, dtype=jnp.int32)  # [KB]
-        keep = ((col[None, None, :] <= row[:, :, None])
-                & (col[None, None, :] < seq_lens[:, None, None]))
+        if window is None:
+            col = j * block_len + jnp.arange(block_len, dtype=jnp.int32)
+            col = col[None, None, :]                         # [1, 1, KB]
+        else:
+            col = (j[:, None] * block_len
+                   + jnp.arange(block_len, dtype=jnp.int32))[:, None, :]
+        keep = (col <= row[:, :, None]) & (col < seq_lens[:, None, None])
+        if window is not None:
+            keep &= col > row[:, :, None] - window
         s = jnp.where(keep[:, None], s, _NEG_INF)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -167,7 +217,9 @@ def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc), None
 
-    js = jnp.arange(block_table.shape[1], dtype=jnp.int32)
+    steps = ring_pages if window is None \
+        else _window_steps(window, Tq, block_len, ring_pages)
+    js = jnp.arange(steps, dtype=jnp.int32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), js)
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
@@ -180,19 +232,23 @@ def _head_dot(a, b, a_dim, b_dim):
 
 
 def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, block_len, scale, Tq):
+                  acc_ref, m_ref, l_ref, *, block_len, scale, Tq,
+                  window=None):
     """Grid (B, G, n_blocks), pages innermost; one step is one page of one
     slot for every head of the tile. q/o tiles [heads, fold*Tq, D] (a KV
     head's query heads folded into rows), K/V tiles [heads, block_len, D]
     cut from the slab by the index_map, online-softmax state in VMEM
     scratch across a (b, g) row's pages. table/lens/pos arrive via scalar
-    prefetch."""
+    prefetch. With `window`, step i is logical block `first + i` of the
+    row's own walk and the mask has a lower edge too."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    i = pl.program_id(2)
     n_blocks = pl.num_programs(2)
     rows = q_ref.shape[2]
+    j = i if window is None \
+        else _first_block(pos_ref[b], window, block_len) + i
 
-    @pl.when(j == 0)
+    @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -208,6 +264,8 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         if rows != Tq:                        # folded row r is token r mod Tq
             t = jax.lax.rem(t, Tq)
         keep = (col <= pos_ref[b] + t) & (col < lens_ref[b])
+        if window is not None:
+            keep &= col > pos_ref[b] + t - window
         vblk = v_ref[0]                       # [heads, KB, D]
         s = _head_dot(q_ref[0], k_ref[0], 1, 1) * scale  # [heads,rows,KB]
         s = jnp.where(keep[None], s, _NEG_INF)
@@ -222,25 +280,28 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             p.astype(vblk.dtype), vblk, 1, 0)
         m_ref[...] = m_new
 
-    @pl.when(j == n_blocks - 1)
+    @pl.when(i == n_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
-                 block_len: int, pages_per_row: int, scale: float):
+                 block_len: int, pages_per_row: int, scale: float,
+                 window: int = None):
     B, H, Tq, D = q.shape
     Hkv = k_cache.shape[1]
     n_rep = H // Hkv
-    n_blocks = block_table.shape[1]
+    ring_pages = block_table.shape[1]
+    n_blocks = ring_pages if window is None \
+        else _window_steps(window, Tq, block_len, ring_pages)
     heads, fold = _choose_tile(H, Hkv, Tq, block_len, D, q.dtype.itemsize)
     rows = fold * Tq
     G = H // (heads * fold)
     parts = n_rep // fold          # tiles that share one KV head (1: none)
     grid = (B, G, n_blocks)
-    pallas_mode.note_tiling("paged_attention", grid=grid, heads=heads,
-                            rows=rows)
+    name = "paged_attention" if window is None else WINDOW_KERNEL
+    pallas_mode.note_tiling(name, grid=grid, heads=heads, rows=rows)
     table = jnp.maximum(block_table, 0)
 
     def q_map(b, g, j, table_ref, lens_ref, pos_ref):
@@ -250,7 +311,11 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
         # a page past the row's length names the row's last live page
         # again: the block index does not change, so nothing is fetched
         last = jnp.maximum(lens_ref[b] - 1, 0) // block_len
-        page = table_ref[b, jnp.minimum(j, last)]
+        if window is not None:     # the row's own walk, through the ring
+            j = (_first_block(pos_ref[b], window, block_len) + j)
+            page = table_ref[b, jnp.minimum(j, last) % ring_pages]
+        else:
+            page = table_ref[b, jnp.minimum(j, last)]
         return (page // pages_per_row, g // parts, page % pages_per_row, 0)
 
     tile = pl.BlockSpec((1, heads, rows, D), q_map)
@@ -267,13 +332,13 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
         ],
     )
     kernel = functools.partial(_paged_kernel, block_len=block_len,
-                               scale=scale, Tq=Tq)
+                               scale=scale, Tq=Tq, window=window)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H // fold, rows, D), q.dtype),
         compiler_params=_GRID_SEMANTICS,  # (B, G, pages): same shape
-        interpret=pallas_mode.interpret("paged_attention"),
-        name="paged_attention",
+        interpret=pallas_mode.interpret(name),
+        name=name,
     )(table, seq_lens, q_pos, q.reshape(B, H // fold, rows, D),
       k_cache, v_cache)
     return out.reshape(B, H, Tq, D)
@@ -282,7 +347,7 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
 def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
                            q_pos, *, block_len: int,
                            pages_per_row: int = None, scale: float = None,
-                           impl: str = None):
+                           impl: str = None, window: int = None):
     """Attention of q [B, H, Tq, D] over block-table-addressed KV pages.
 
     k_cache/v_cache: [N, Hkv, L_slab, D] slabs (N need not equal B — block
@@ -293,6 +358,10 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
     impl: None = scan on the CPU, the kernel on a TPU; or name "scan" /
     "pallas" (on the CPU the kernel runs interpreted — the parity suite
     does that; `ops.pallas_mode` decides and counts).
+    window: None, or W: a query sees the W keys up to itself, and the
+    table's `max_blocks` columns are a ring (module docstring); a
+    `block_table` of None is then each row's own ring, `pages_per_row`
+    pages of slab row b (N == B).
     """
     B, H, Tq, D = q.shape
     if scale is None:
@@ -303,6 +372,16 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
         impl = "scan" if pallas_mode.platform() == "cpu" else "pallas"
     if impl not in ("scan", "pallas"):
         raise ValueError(f'impl must be "scan" or "pallas", got {impl!r}')
+    if block_table is None:
+        if window is None or k_cache.shape[0] != B:
+            raise ValueError("block_table=None names each row's own ring: "
+                             "it needs a window and one slab row a query "
+                             "row")
+        block_table = (jnp.arange(B, dtype=jnp.int32)[:, None]
+                       * pages_per_row
+                       + jnp.arange(pages_per_row, dtype=jnp.int32)[None])
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
     block_table = jnp.asarray(block_table, jnp.int32)
     seq_lens = jnp.asarray(seq_lens, jnp.int32)
     q_pos = jnp.asarray(q_pos, jnp.int32)
@@ -311,12 +390,16 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
             f"cache length {k_cache.shape[2]} cannot back {pages_per_row} "
             f"pages of {block_len} tokens")
     if impl == "scan":
-        pallas_mode.count("paged_attention", "scan")
+        pallas_mode.count(
+            "paged_attention" if window is None else WINDOW_KERNEL, "scan")
         impl_fn = _scan_impl
     else:
         impl_fn = _pallas_impl
+    if window is None:
+        return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+                       block_len, pages_per_row, scale)
     return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
-                   block_len, pages_per_row, scale)
+                   block_len, pages_per_row, scale, int(window))
 
 
 def trivial_block_table(batch: int, cache_len: int,

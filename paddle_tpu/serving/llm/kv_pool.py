@@ -66,18 +66,33 @@ that holds one (`recurrent`), `rewind_length`, `cow_copy`, `export_rows`
 / `import_rows` and `export_page` / `import_page` raise
 `RecurrentStateError`, and `defrag` leaves the state alone (a freed
 slot's state is dead: the next row in it starts from zero).
+
+A third kind: a ring (PR 31). A layer that attends a sliding window
+(`init_cache(window_slab=)` answers with a `models.generation.WindowKV`)
+keeps `ring_len` = window + `pad_tokens` columns a slot, rounded up to
+whole pages and to whole chunks, not `capacity`: position p lives at
+column `p mod ring_len` of the slot's own row, and the step's chunk-wide
+stripe overwrites only keys that have left every live query's window
+(that is what the `pad_tokens` of slack are for). `lengths`, the block
+table and the ledger stay logical: they count the row's positions, as the
+full layers hold them. What the ring has overwritten cannot be re-read,
+so on a pool that holds one (`windowed`) prefix sharing (`attach_blocks`,
+`register_cached`), `cow_copy`, `export_rows` / `import_rows`,
+`export_page` / `import_page` and a `rewind_length` of more than the slack
+raise `WindowRingError`; `defrag` scrubs a freed row's whole ring.
 """
 from __future__ import annotations
 
+import inspect
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.generation import RecurrentState
+from ...models.generation import RecurrentState, WindowKV
 
-PAGED, RECURRENT = "paged", "recurrent"
+PAGED, RECURRENT, WINDOW = "paged", "recurrent", "window"
 
 
 class RecurrentStateError(NotImplementedError):
@@ -85,6 +100,14 @@ class RecurrentStateError(NotImplementedError):
     that rebuilds a row from its pages (a rewind, a page copy, an export
     or import). A recurrence's state exists only at the row's committed
     length; snapshots of it are later work (ROADMAP)."""
+
+
+class WindowRingError(NotImplementedError):
+    """Asked of a pool that keeps a window layer's keys in a ring: an
+    operation that reads, copies or shares pages by their logical block
+    (prefix sharing, a page copy, an export or import), or a rewind past
+    the ring's slack. Keys older than the window have been overwritten;
+    prefix reuse over window layers is later work (ROADMAP)."""
 
 
 class SlotsExhaustedError(RuntimeError):
@@ -126,13 +149,29 @@ class SlotPagedKVPool:
         self.pad_tokens = int(pad_tokens)
         self.slab_len = self.capacity + self.pad_tokens
         kwargs = {} if dtype is None else {"dtype": dtype}
+        self.window: Optional[int] = None   # the window layers', if any
+        # a model with window layers is told how long their slabs are
+        if "window_slab" in inspect.signature(init_cache_fn).parameters:
+            kwargs["window_slab"] = self._window_slab
         entries = list(init_cache_fn(self.num_slots, self.slab_len,
                                      **kwargs))
         # what each layer keeps per slot, told by its entry's type
         self.layer_kinds: List[str] = [
-            RECURRENT if isinstance(e, RecurrentState) else PAGED
+            RECURRENT if isinstance(e, RecurrentState)
+            else WINDOW if isinstance(e, WindowKV) else PAGED
             for e in entries]
         self.recurrent = RECURRENT in self.layer_kinds
+        self.windowed = WINDOW in self.layer_kinds
+        # the window layers' ring, in columns and pages (None: no such
+        # layer); every window layer of a model has the one window
+        rings = {int(e.k.shape[2]) - self.pad_tokens
+                 for e in entries if isinstance(e, WindowKV)}
+        if len(rings) > 1:
+            raise ValueError(f"window layers of different rings: {rings}")
+        self.ring_len: Optional[int] = rings.pop() if rings else None
+        self.ring_pages: Optional[int] = (
+            None if self.ring_len is None
+            else self.ring_len // self.block_len)
         self.slabs: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
             (a, b) for a, b in entries]
         # buffers shaped like `slabs` that hold nothing anybody reads: the
@@ -177,6 +216,25 @@ class SlotPagedKVPool:
         self._lens_uploaded = 0
         self._dev_lens: Optional[jnp.ndarray] = None
 
+    def _window_slab(self, window: int) -> int:
+        """Columns of a window layer's slab: a ring of window + the
+        chunk's slack, whole pages and whole chunks (an aligned chunk then
+        never straddles the ring's end), and the write pad behind it."""
+        self.window = int(window)
+        unit = int(np.lcm(self.block_len, max(self.pad_tokens, 1)))
+        return -(-(int(window) + self.pad_tokens) // unit) * unit \
+            + self.pad_tokens
+
+    def kv_bytes(self) -> Dict[str, int]:
+        """Bytes of the K/V slabs by what they are: "full" (a slot's whole
+        context) and "window" (a ring), all slots and layers."""
+        out = {"full": 0, "window": 0}
+        for (a, b), kind in zip(self.slabs, self.layer_kinds):
+            if kind != RECURRENT:
+                out["window" if kind == WINDOW else "full"] += \
+                    int(a.nbytes) + int(b.nbytes)
+        return out
+
     @property
     def recurrent_state_bytes(self) -> int:
         """Bytes of the recurrent layers' per-slot state, all slots."""
@@ -204,13 +262,22 @@ class SlotPagedKVPool:
         successor no longer needs, becomes the scratch."""
         self.spare, self.slabs = self.slabs, new_slabs
 
-    def _refuse_recurrent(self, what: str):
+    def _refuse_reread(self, what: str):
+        """Refuse `what`, which re-reads a row's pages, on a pool some of
+        whose layers do not keep them."""
         if self.recurrent:
             raise RecurrentStateError(
                 f"{what}: this pool holds recurrent state "
                 f"({self.layer_kinds.count(RECURRENT)} of "
                 f"{len(self.layer_kinds)} layers), which exists only at a "
                 "row's committed length and cannot be rebuilt from pages")
+        if self.windowed:
+            raise WindowRingError(
+                f"{what}: this pool keeps "
+                f"{self.layer_kinds.count(WINDOW)} of "
+                f"{len(self.layer_kinds)} layers' keys in a ring of "
+                f"{self.ring_len} columns a slot; a page older than the "
+                "window has been overwritten")
 
     def _identity_table(self) -> np.ndarray:
         return (np.arange(self.num_slots, dtype=np.int32)[:, None]
@@ -340,8 +407,12 @@ class SlotPagedKVPool:
             raise ValueError(f"slot {slot} is not active")
         length = int(length)
         cur = int(self.lengths[slot])
-        if length < cur:
-            self._refuse_recurrent("rewind_length")
+        # a ring gives back what its slack holds: the stripe of a rejected
+        # draft window overwrote nothing a query still sees (on a pool of
+        # paged layers alone nothing is refused)
+        if length < cur and (self.recurrent
+                             or cur - length > self.pad_tokens):
+            self._refuse_reread(f"rewind_length by {cur - length}")
         if length > cur:
             raise ValueError(
                 f"rewind_length can only shrink: {length} > committed "
@@ -378,6 +449,8 @@ class SlotPagedKVPool:
         at its logical block offset (`page % n_blocks == j` — the write
         path guarantees a slot's block j is physically at column j of its
         own row, so cached pages always satisfy this)."""
+        if self.windowed and pages:
+            self._refuse_reread("attach_blocks")
         if not self.active[slot]:
             raise ValueError(f"slot {slot} is not active")
         if len(pages) > self.n_blocks:
@@ -414,7 +487,7 @@ class SlotPagedKVPool:
         append divergent tokens into it. One jitted two-op copy
         (dynamic_slice + dynamic_update_slice) per slab; traced row/col
         offsets keep it a single executable per slab shape."""
-        self._refuse_recurrent("cow_copy")
+        self._refuse_reread("cow_copy")
         if not self.active[dst_slot]:
             raise ValueError(f"slot {dst_slot} is not active")
         block_idx = src_page % self.n_blocks
@@ -443,6 +516,8 @@ class SlotPagedKVPool:
     def register_cached(self, page: int):
         """Pin a page on behalf of the prefix cache: its row leaves the
         allocatable set and defrag will never scrub its columns."""
+        if self.windowed:
+            self._refuse_reread("register_cached")
         if not (0 <= page < self.num_slots * self.n_blocks):
             raise ValueError(f"page {page} out of range")
         if page in self.cached:
@@ -607,7 +682,7 @@ class SlotPagedKVPool:
         is not enough to resume a SAMPLED stream bit-identically: pair
         this payload with `LLMEngine.export_sampling_lanes` (ISSUE 18),
         which carries each slot's RNG-lane index and grammar DFA state."""
-        self._refuse_recurrent("export_rows")
+        self._refuse_reread("export_rows")
         rows: Dict[int, dict] = {}
         for slot in slots:
             slot = int(slot)
@@ -650,7 +725,7 @@ class SlotPagedKVPool:
         so the transfer is exactly `width` tokens. This is the spill unit
         the host tier (HostKVPool, ISSUE 19) stores; `width` defaults to
         the full block."""
-        self._refuse_recurrent("export_page")
+        self._refuse_reread("export_page")
         if not (0 <= page < self.num_slots * self.n_blocks):
             raise ValueError(f"page {page} out of range")
         w = self.block_len if width is None else int(width)
@@ -671,7 +746,7 @@ class SlotPagedKVPool:
         block table already covers it). Inverse of `export_page`, bitwise.
         Ledger accounting rides the normal path: the engine's next
         `set_length` past this block claims the own page."""
-        self._refuse_recurrent("import_page")
+        self._refuse_reread("import_page")
         if not self.active[slot]:
             raise ValueError(f"slot {slot} is not active")
         if not (0 <= block_idx < self.n_blocks):
@@ -702,7 +777,7 @@ class SlotPagedKVPool:
         identity pages — attachment structure is not preserved, the KV
         bytes are), and lands the K/V columns bitwise via
         dynamic_update_slice. Returns {source_slot: destination_slot}."""
-        self._refuse_recurrent("import_rows")
+        self._refuse_reread("import_rows")
         if int(exported["block_len"]) != self.block_len:
             raise ValueError(
                 f"block_len mismatch: exported {exported['block_len']} "
@@ -759,11 +834,16 @@ class SlotPagedKVPool:
         if self._scrub is None:
             self._scrub = jax.jit(
                 lambda slab, keep: slab * keep[:, None, :, None])
-        keep_j = jnp.asarray(keep)
+        # a ring holds no cached page (`register_cached` refuses): a freed
+        # row's ring goes whole
+        masks = {PAGED: jnp.asarray(keep)}
+        if self.windowed:
+            masks[WINDOW] = jnp.asarray(
+                np.repeat(keep[:, :1], self.ring_len + self.pad_tokens, 1))
         self.spare = None
-        self.slabs = [(self._scrub(k, keep_j.astype(k.dtype)),
-                       self._scrub(v, keep_j.astype(v.dtype)))
-                      if kind == PAGED else (k, v)
+        self.slabs = [(self._scrub(k, masks[kind].astype(k.dtype)),
+                       self._scrub(v, masks[kind].astype(v.dtype)))
+                      if kind != RECURRENT else (k, v)
                       for (k, v), kind in zip(self.slabs, self.layer_kinds)]
         self.dirty[:] = False
         self.stats["defrags"] += 1

@@ -633,6 +633,23 @@ class LLMEngine:
                 self.enable_prefix_cache = False
             self.metrics.set_recurrent_state(
                 self.pool.recurrent_state_bytes)
+        # a model with window layers (PR 31): the pool keeps their keys in
+        # a ring, and what the ring has overwritten cannot be re-read:
+        # prefix sharing is switched off, the host tier refused by name
+        # (`kv_row` imports and stream exports where they are asked for)
+        if self.pool.windowed:
+            if self.config.host_kv_bytes > 0:
+                raise ValueError(
+                    "host_kv_bytes > 0 with a model that has window "
+                    "layers: a page brought back from the host tier has "
+                    "aged out of the ring it would be written to")
+            if self.enable_prefix_cache:
+                _log.warning(
+                    "enable_prefix_cache is switched off: the model has "
+                    "window layers, whose keys the pool keeps in a ring; "
+                    "a shared prefix's pages have aged out of it")
+                self.enable_prefix_cache = False
+            self.metrics.set_kv_pool_bytes(self.pool.kv_bytes())
         # host-RAM spill tier (ISSUE 19): a byte-budgeted LRU the prefix
         # cache spills refcount-0 pages into on pressure eviction; the
         # admission path re-onboards covered blocks instead of
@@ -866,6 +883,7 @@ class LLMEngine:
         if self._step_jit is None:
             block_len = self.pool.block_len
             pages_per_row = self.pool.n_blocks
+            ring = self._ring_operand(self.pool)
             prefill = self._prefill_fn
             chunk = self.config.prefill_chunk
             step_tokens = self.step_tokens
@@ -895,7 +913,7 @@ class LLMEngine:
                 toks = toks.at[:, 0].set(
                     jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
                 seq_lens = (pos + adv).astype(jnp.int32)
-                paged = (table, seq_lens, block_len, pages_per_row)
+                paged = (table, seq_lens, block_len, pages_per_row) + ring
                 pack = None
                 rows_adv, rows_dstate = adv, dstate
                 if packed:
@@ -949,6 +967,14 @@ class LLMEngine:
             self._step_jit = jax.jit(step, donate_argnames=("spare",),
                                      keep_unused=True)
         return self._step_jit
+
+    @staticmethod
+    def _ring_operand(pool) -> tuple:
+        """What a step's `paged` tuple carries past its four fields: the
+        pages of the window layers' ring, on a pool that holds one. ()
+        otherwise: the operand, and the executable, of a model without
+        window layers are what they were."""
+        return (pool.ring_pages,) if pool.windowed else ()
 
     def _sampling_args_locked(self, ctr):
         """The unified step's per-slot sampling operands: the live table
@@ -1005,11 +1031,12 @@ class LLMEngine:
         if self._draft_step_jit is None:
             block_len = self.draft_pool.block_len
             pages_per_row = self.draft_pool.n_blocks
+            ring = self._ring_operand(self.draft_pool)
             vfy = self._draft_verify_fn
 
             def step(params, toks, pos, adv, table, slabs):
                 seq_lens = (pos + adv).astype(jnp.int32)
-                paged = (table, seq_lens, block_len, pages_per_row)
+                paged = (table, seq_lens, block_len, pages_per_row) + ring
                 return vfy(params, toks, slabs, pos, paged=paged)
 
             self._draft_step_jit = jax.jit(step)
@@ -1039,6 +1066,7 @@ class LLMEngine:
         if self._draft_propose_jit is None:
             block_len = self.draft_pool.block_len
             pages_per_row = self.draft_pool.n_blocks
+            ring = self._ring_operand(self.draft_pool)
             K = self.config.spec_k
             dprefill = self._draft_prefill_fn
 
@@ -1047,7 +1075,8 @@ class LLMEngine:
                 def body(carry, j):
                     tok, off, slabs_c = carry
                     seq_lens = (pos + off + act).astype(jnp.int32)
-                    paged = (table, seq_lens, block_len, pages_per_row)
+                    paged = (table, seq_lens, block_len, pages_per_row) \
+                        + ring
                     lg, slabs_c = dprefill(params, tok[:, None], slabs_c,
                                            pos + off, paged=paged)
                     nxt = select_next(lg[:, 0], temp, topk, topp, samp,
@@ -1851,6 +1880,10 @@ class LLMEngine:
                 raise ValueError(
                     "kv_row with a model that has recurrent layers: "
                     "imported pages carry no recurrent state")
+            if self.pool.windowed:
+                raise ValueError(
+                    "kv_row with a model that has window layers: their "
+                    "keys live in a ring that imported pages cannot fill")
             if int(kv_row.get("block_len", -1)) != self.pool.block_len:
                 raise ValueError(
                     f"kv_row block_len {kv_row.get('block_len')!r} does "
@@ -2862,6 +2895,17 @@ class LLMEngine:
                 # of them it starts from zero (position 0)
                 span_args["recurrent_rows"] = int(np.count_nonzero(adv))
                 started = int(np.count_nonzero((adv > 0) & (pos == 0)))
+            kv_tokens = None
+            if self.pool.windowed:
+                # what this step's attention calls must read: a row's keys
+                # after the step, and the part of them inside the window;
+                # rows whose ring has begun to overwrite its oldest keys
+                after = (pos + adv)[adv > 0]
+                kv_tokens = (int(np.minimum(after, self.pool.window).sum()),
+                             int(after.sum()))
+                span_args["window_rows"] = int(after.size)
+                span_args["wrapped_rows"] = int(np.count_nonzero(
+                    after > self.pool.ring_len))
             with RecordEvent(SPAN_SERVE_DISPATCH, **span_args):
                 t0 = self.clock.now()
                 fn = self._step()
@@ -2919,6 +2963,8 @@ class LLMEngine:
                                                 self.step_tokens, deferred)
                     if started:
                         self.metrics.on_recurrent_rows_started(started)
+                    if kv_tokens is not None:
+                        self.metrics.on_kv_tokens(*kv_tokens)
                     if ahead_of is not None:
                         self.metrics.on_step_overlapped()
                     if decode_slots:

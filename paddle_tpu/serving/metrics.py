@@ -256,6 +256,10 @@ def _quantile(sorted_vals, q: float) -> Optional[float]:
 # yet): for a reader that comes after the engine is gone, as
 # `nn.layer.moe.EXPERT_TOKENS` is for the expert counts.
 RECURRENT_STATE_BYTES = 0
+# Likewise the K/V slabs of the newest LLM engine whose pool keeps window
+# layers in a ring, by kind: {"full": bytes, "window": bytes}
+# (`LLMMetrics.set_kv_pool_bytes`; empty: no such engine yet).
+KV_POOL_BYTES: dict = {}
 
 
 class LLMMetrics(ServingMetrics):
@@ -286,6 +290,8 @@ class LLMMetrics(ServingMetrics):
                               "rows_discarded": 0,
                               "moe_assignments": 0,
                               "recurrent_rows_started": 0,
+                              "window_kv_tokens": 0,
+                              "full_kv_tokens": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
                               "brownout_entries": 0,
                               "prefix_hits": 0, "prefix_misses": 0,
@@ -350,6 +356,9 @@ class LLMMetrics(ServingMetrics):
         # holds beside the paged K/V; None, and no family, for a model
         # without recurrent layers
         self.recurrent_state_bytes: Optional[int] = None
+        # bytes of the K/V slabs by kind ("full", "window") of a pool that
+        # keeps window layers in a ring; None, and no family, otherwise
+        self.kv_pool_bytes: Optional[dict] = None
         # multi-LoRA serving (ISSUE 18/20): emitted tokens per adapter id
         # ("base" for row-0 streams) — on an armed engine every emission
         # lands in exactly one bucket, so these sum to tokens_out
@@ -539,6 +548,21 @@ class LLMMetrics(ServingMetrics):
         global RECURRENT_STATE_BYTES
         RECURRENT_STATE_BYTES = int(nbytes)
 
+    def set_kv_pool_bytes(self, by_kind: dict):
+        with self._lock:
+            self.kv_pool_bytes = dict(by_kind)
+        global KV_POOL_BYTES
+        KV_POOL_BYTES = dict(by_kind)
+
+    def on_kv_tokens(self, window: int, full: int):
+        """One committed unified step of an engine with window layers:
+        the keys one window layer's call had to read (sum over the active
+        rows of min(length, window)) and one full layer's (sum of the
+        lengths)."""
+        with self._lock:
+            self.counters["window_kv_tokens"] += int(window)
+            self.counters["full_kv_tokens"] += int(full)
+
     def on_recurrent_rows_started(self, n: int):
         """`n` rows of a committed step began at position 0: the step
         started them from a zero recurrent state."""
@@ -709,6 +733,8 @@ class LLMMetrics(ServingMetrics):
                             if self.host_kv is not None else None)
             s["adapter_tokens"] = dict(self.adapter_tokens)
             s["recurrent_state_bytes"] = self.recurrent_state_bytes
+            s["kv_pool_bytes"] = (None if self.kv_pool_bytes is None
+                                  else dict(self.kv_pool_bytes))
         s["mask_overhead_p99_ms"] = self.mask_overhead_quantile_ms(0.99)
         s["shed_rate"] = (s["shed"] / s["submitted"] if s["submitted"]
                           else 0.0)
@@ -766,6 +792,13 @@ class LLMMetrics(ServingMetrics):
             b.family(f"{px}_recurrent_rows_started_total", "counter")
             b.sample(f"{px}_recurrent_rows_started_total",
                      s["recurrent_rows_started"])
+        if s["kv_pool_bytes"] is not None:
+            b.family(f"{px}_kv_pool_bytes", "gauge")
+            for kind, nbytes in sorted(s["kv_pool_bytes"].items()):
+                b.sample(f"{px}_kv_pool_bytes", nbytes, {"kind": kind})
+            for name in ("window_kv_tokens", "full_kv_tokens"):
+                b.family(f"{px}_{name}_total", "counter")
+                b.sample(f"{px}_{name}_total", s[name])
         if self.moe_source is not None:
             b.family(f"{px}_moe_assignments_total", "counter")
             b.sample(f"{px}_moe_assignments_total", s["moe_assignments"])

@@ -1,6 +1,7 @@
 """Tier-1 guard for the Mosaic lowering: tools/mosaic_aot_check.py compiles
-the flash, paged, grouped-matmul and state-recurrence Pallas kernels for a TPU v5e through the installed
-libtpu, with no chip attached (ISSUE 21: CPU interpret-mode tests say
+the flash, paged (the full walk and the windowed walk through a ring, at
+the window/full cell's shapes), grouped-matmul and state-recurrence Pallas
+kernels for a TPU v5e through the installed libtpu, with no chip attached (ISSUE 21: CPU interpret-mode tests say
 nothing of whether Mosaic accepts a kernel). Runs in a subprocess so the
 libtpu lock and the TPU_* environment stay out of the test process."""
 import os
@@ -21,4 +22,7 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip():
     cases = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
     assert r.returncode == 0, "\n".join(cases) + r.stderr[-1500:]
-    assert len(cases) == 20 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 25 and all(c.startswith("[OK]") for c in cases)
+    window = [c for c in cases if "'paged_window': 1" in c]
+    assert len(window) == 2 and all("slab=[32, 4, 1056, 128]" in c
+                                    for c in window)

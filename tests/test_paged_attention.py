@@ -250,6 +250,161 @@ def test_pallas_interpret_matches_scan_and_reference(monkeypatch, H, Hkv, Tq,
                                      - ref[b, :, :n]), initial=0.0)) <= 1e-5
 
 
+def _ring_of(logical, length, ring, pad, written=None):
+    """A ring slab `[Hkv, ring + pad, D]` as the pool holds it for a row
+    of `length` committed positions (or `written`, counting a stripe's
+    garbage past them): column c holds the newest position p with p = c
+    (mod ring), a large finite value where nothing was written (a masked
+    column is multiplied by an exact zero, so it must be finite, as a
+    pool's stale keys are); the pad, never addressed, is NaN."""
+    Hkv, _, D = logical.shape
+    slab = np.full((Hkv, ring + pad, D), np.nan, np.float32)
+    slab[:, :ring] = 1e3
+    for p in range(written or length):
+        slab[:, p % ring] = logical[:, p]
+    return slab
+
+
+def _ref_window(q, k_log, v_log, lens, q_pos, window, scale=None):
+    """Oracle: `_attention_reference` over each row's contiguous logical
+    keys, the mask `q_pos + t - window < col <= q_pos + t`, `col < len`."""
+    from paddle_tpu.ops.attention import _NEG_INF, _attention_reference
+    B, H, Tq, D = q.shape
+    scale = scale or 1.0 / D ** 0.5
+    outs = []
+    for b in range(B):
+        kb, vb = k_log[b][None], v_log[b][None]
+        rep = H // kb.shape[1]
+        kb, vb = jnp.repeat(kb, rep, axis=1), jnp.repeat(vb, rep, axis=1)
+        col = np.arange(kb.shape[2])[None, :]
+        row = int(q_pos[b]) + np.arange(Tq)[:, None]
+        keep = (col <= row) & (col > row - window) & (col < int(lens[b]))
+        mask = jnp.asarray(np.where(keep, 0.0, _NEG_INF), jnp.float32)[None]
+        outs.append(_attention_reference(q[b:b + 1], kb, vb, causal=False,
+                                         scale=scale, mask=mask))
+    return jnp.concatenate(outs, 0)
+
+
+# (H, Hkv, Tq, block_len, window, ring pages): a decode row and a chunk of
+# the unified step, both block sizes, a window that is and is not a
+# multiple of the block, GQA and MHA; the ring holds window + Tq, rounded
+# up to whole pages
+_WINDOW_CASES = [
+    pytest.param(8, 2, 1, 8, 32, 6, id="gqa4-tq1-bl8-w32"),
+    pytest.param(8, 2, 16, 16, 32, 3, id="gqa4-chunk16-bl16-w32"),
+    pytest.param(8, 2, 16, 8, 20, 5, id="gqa4-chunk16-bl8-w20-ragged"),
+    pytest.param(4, 4, 1, 16, 27, 3, id="mha-tq1-bl16-w27-ragged"),
+    pytest.param(4, 4, 4, 8, 13, 3, id="mha-chunk4-bl8-w13-ragged"),
+]
+
+
+@pytest.mark.parametrize("H,Hkv,Tq,bl,window,ring_pages", _WINDOW_CASES)
+def test_window_kernel_matches_scan_and_reference(H, Hkv, Tq, bl, window,
+                                                  ring_pages):
+    """The windowed walk (`paged_window`: grid over the pages that cut a
+    row's window, logical block -> ring page, both mask edges) interpreted
+    on the CPU = the scan path = the oracle over each row's contiguous
+    keys, at ragged lengths: shorter than the window, crossing it, twice
+    and five times round the ring, an empty row and a one-token row.
+    Columns the ring never wrote hold garbage, its write pad NaN."""
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
+    rng = np.random.RandomState(11)
+    D, ring = 8, ring_pages * bl
+    assert ring >= window + Tq
+    lens = np.array([window - 3, window + bl + 1, 2 * ring + 5,
+                     5 * ring + bl - 1, 0, 1], np.int32)
+    lens = np.maximum(lens, 0)
+    B, S = len(lens), int(lens.max()) + Tq
+    q_pos = np.maximum(lens - Tq, 0).astype(np.int32)
+    k_log = rng.standard_normal((B, Hkv, S, D)).astype(np.float32)
+    v_log = rng.standard_normal((B, Hkv, S, D)).astype(np.float32)
+    k = jnp.asarray(np.stack([_ring_of(k_log[b], lens[b], ring, Tq)
+                              for b in range(B)]))
+    v = jnp.asarray(np.stack([_ring_of(v_log[b], lens[b], ring, Tq)
+                              for b in range(B)]))
+    q = _rand(rng, (B, H, Tq, D))
+    pallas_mode.KERNEL_TILINGS.clear()
+    run = {impl: PA.ragged_paged_attention(
+        q, k, v, None, lens, q_pos, block_len=bl, pages_per_row=ring_pages,
+        impl=impl, window=window) for impl in ("scan", "pallas")}
+    steps = -(-(window + Tq) // bl) + 1
+    assert steps <= ring_pages + 1
+    assert dict(pallas_mode.KERNEL_TILINGS) == {
+        (PA.WINDOW_KERNEL, (("grid", (B, 1, steps)), ("heads", Hkv),
+                            ("rows", H // Hkv * Tq))): 1}
+    ref = _ref_window(q, jnp.asarray(k_log), jnp.asarray(v_log), lens,
+                      q_pos, window)
+    for b in range(B):
+        n = int(lens[b] - q_pos[b])        # valid query rows
+        for impl in ("scan", "pallas"):
+            got = run[impl][b, :, :n]
+            assert bool(jnp.all(jnp.isfinite(got))), (impl, b)
+            assert float(jnp.max(jnp.abs(got - ref[b, :, :n]),
+                                 initial=0.0)) <= 1e-5, (impl, b)
+    assert float(jnp.max(jnp.abs(jnp.nan_to_num(run["pallas"])
+                                 - jnp.nan_to_num(run["scan"])))) <= 1e-6
+    assert not np.asarray(run["pallas"][4]).any()      # the empty row
+
+
+def test_window_walk_through_a_ring_is_bitwise_the_walk_of_a_full_cache():
+    """The same keys in a ring and in a full-length contiguous cache, the
+    windowed walk over either, and the full walk with the window as its
+    mask... the first two give the same bits: the blocks walked are the
+    logical ones, and a block outside the window is an exact no-op."""
+    from paddle_tpu.ops import paged_attention as PA
+    rng = np.random.RandomState(12)
+    B, H, Hkv, D, bl, window, Tq = 3, 4, 2, 8, 8, 20, 4
+    ring_pages = 4                                   # 32 >= 20 + 4
+    lens = np.array([70, 19, 33], np.int32)
+    S = 72
+    nb = S // bl
+    q_pos = (lens - Tq).astype(np.int32)
+    k_log = rng.standard_normal((B, Hkv, S, D)).astype(np.float32)
+    v_log = rng.standard_normal((B, Hkv, S, D)).astype(np.float32)
+    ring = ring_pages * bl
+    kr = jnp.asarray(np.stack([_ring_of(k_log[b], lens[b], ring, Tq)
+                               for b in range(B)]))
+    vr = jnp.asarray(np.stack([_ring_of(v_log[b], lens[b], ring, Tq)
+                               for b in range(B)]))
+    q = _rand(rng, (B, H, Tq, D))
+    through_ring = PA.ragged_paged_attention(
+        q, kr, vr, None, lens, q_pos, block_len=bl,
+        pages_per_row=ring_pages, impl="scan", window=window)
+    contiguous = PA.ragged_paged_attention(
+        q, jnp.asarray(k_log), jnp.asarray(v_log), _identity_table(B, nb),
+        lens, q_pos, block_len=bl, pages_per_row=nb, impl="scan",
+        window=window)
+    assert np.array_equal(np.asarray(through_ring), np.asarray(contiguous))
+
+
+def test_ring_write_splits_a_stripe_that_straddles_the_rings_end():
+    """`update_kv_cache(ring=)`: position p lands at column p mod ring; a
+    stripe that runs past the ring's end continues at its start (two
+    parts, not clamped back onto live keys), row by row."""
+    from paddle_tpu.ops.attention import update_kv_cache
+    ring, T, Hkv, D = 24, 8, 2, 4
+    pos = np.array([0, 16, 20, 23 + 2 * ring, 8], np.int32)
+    B = len(pos)
+    kc = jnp.zeros((B, Hkv, ring + T, D))
+    new = jnp.asarray(np.arange(1, B * Hkv * T * D + 1, dtype=np.float32)
+                      .reshape(B, Hkv, T, D))
+    k2, v2 = update_kv_cache(kc, kc, new, -new, pos, ring=ring)
+    for b in range(B):
+        for t in range(T):
+            col = (int(pos[b]) + t) % ring
+            assert np.array_equal(np.asarray(k2[b, :, col]),
+                                  np.asarray(new[b, :, t])), (b, t)
+            assert np.array_equal(np.asarray(v2[b, :, col]),
+                                  -np.asarray(new[b, :, t])), (b, t)
+        untouched = sorted(set(range(ring))
+                           - {(int(pos[b]) + t) % ring for t in range(T)})
+        assert not np.asarray(k2[b][:, untouched]).any()
+    with pytest.raises(ValueError, match="needs 32 columns"):
+        update_kv_cache(kc[:, :, :ring], kc[:, :, :ring], new, new, pos,
+                        ring=ring)
+
+
 @pytest.mark.parametrize("name,q_shape,hkv,bl,want", [
     # the serve cells: everything in one tile, G = 1
     ("mistral decode", (128, 32, 16, 128), 8, 16, (8, 4)),

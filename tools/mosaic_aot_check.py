@@ -129,12 +129,36 @@ def main() -> int:
             spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
             spec((B,), jnp.int32), spec((B,), jnp.int32),
             want={"paged_attention": 1}))
+    # the windowed walk through a ring (`paged_window`) and the full walk at
+    # the window/full GQA cell's shapes: 32 slots, 32/4 heads x 128, a ring
+    # of 65 pages (window 1,024 + a chunk) beside 518 full-length pages;
+    # the step's 16-wide rows, and a one-token row
+    for label, q_shape, slab, ppr, window in (
+            ("window ring, chunk rows", (32, 32, 16, D), (32, 4, 1056, D),
+             65, 1024),
+            ("window ring, Tq=1", (32, 32, 1, D), (32, 4, 1056, D), 65,
+             1024),
+            ("window/full cell, full layers", (32, 32, 16, D),
+             (32, 4, 8304, D), 518, None)):
+        def windowed(q, k, v, t, sl, qp, ppr=ppr, window=window):
+            return ragged_paged_attention(
+                q, k, v, t, sl, qp, block_len=16, pages_per_row=ppr,
+                impl="pallas", window=window)
+        B = q_shape[0]
+        results.append(compile_case(
+            f"paged bf16 {label} q={list(q_shape)} slab={list(slab)}",
+            windowed, spec(q_shape, jnp.bfloat16), spec(slab, jnp.bfloat16),
+            spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
+            spec((B,), jnp.int32), spec((B,), jnp.int32),
+            want={"paged_window" if window else "paged_attention": 1}))
     # the grouped matmul of the dropless expert layer at OLMoE's widths and
     # the decode cell's rows (2,048 positions x 8 experts each)
     from paddle_tpu.ops.grouped_matmul import grouped_matmul
     # and at granite-4.0-h-small's (512 packed positions x 10, 18 held)
+    # and at the window/full cell's (512 x 8, 16 of 64 held, width 896)
     for m, e, k, n in ((16384, 64, 2048, 1024), (16384, 64, 1024, 2048),
-                       (5120, 18, 4096, 768), (5120, 18, 768, 4096)):
+                       (5120, 18, 4096, 768), (5120, 18, 768, 4096),
+                       (4096, 16, 2304, 896), (4096, 16, 896, 2304)):
         results.append(compile_case(
             f"moe_gmm bf16 [{m},{k}] x [{e},{k},{n}]",
             lambda lhs, rhs, gs: grouped_matmul(lhs, rhs, gs, impl="pallas"),
