@@ -143,6 +143,14 @@ def _trace_paths() -> dict:
             for (k, p), n in sorted(pallas_mode.KERNEL_TRACES.items())}
 
 
+def _tilings() -> list:
+    """["kernel xN {grid, groups, pages, heads, rows}", ...]: the tile each
+    kernel call site traced so far chose from its shapes."""
+    from paddle_tpu.ops import pallas_mode
+    return [f"{k} x{n} {dict(t)}"
+            for (k, t), n in sorted(pallas_mode.KERNEL_TILINGS.items())]
+
+
 def _cache_report(when: str) -> int:
     from paddle_tpu.utils import compile_cache
     n = compile_cache.entry_count()
@@ -160,6 +168,15 @@ def _kernel_row(callsite: str) -> dict:
     _require(len(rows) == 1,
              f"one executable registered at {callsite} (got {len(rows)})")
     return rows[0]
+
+
+def _phases(row: dict) -> str:
+    """The seconds an executable's AOT build took, by phase: tracing and
+    lowering are paid by every process, compiling only where the compile
+    cache misses (one figure hid a kernel whose lowering had tripled)."""
+    p = row["phase_seconds"]
+    return (f"trace {p['trace']:.1f}s + lower {p['lower']:.1f}s + compile "
+            f"{p['compile']:.1f}s = {row['compile_seconds']:.1f}s (AOT)")
 
 
 def _max_err(a, b) -> float:
@@ -335,9 +352,9 @@ def leg_train(size: dict, rehearsal: bool, layout: str = "") -> dict:
 
     row = _kernel_row("train/scan_chunk")
     kernels = row["pallas_kernels"] or {}
-    _say(f"[train] compiled chunk: compile {row['compile_seconds']:.1f}s "
-         f"(AOT lower+compile), temp {row['temp_bytes']} B, args "
-         f"{row['argument_bytes']} B, Pallas kernels {kernels}")
+    _say(f"[train] compiled chunk: {_phases(row)}, temp "
+         f"{row['temp_bytes']} B, args {row['argument_bytes']} B, Pallas "
+         f"kernels {kernels}")
     _say(f"[train] kernel trace paths: {_trace_paths()}")
     if rehearsal:
         _say("  kernel census not applicable on the CPU (the model takes "
@@ -406,11 +423,13 @@ def _paged_parity(size: dict):
     """`ragged_paged_attention(impl="pallas")` against `impl="scan"` on this
     device at each head layout of `size["paged"]`, bf16, ragged seq_lens,
     block_len 16, query widths 1 and 16, slabs with write-padding past the
-    page region. The two run the same per-block op sequence on the same
-    bf16 inputs, so the tolerance is tighter than flash-vs-reference: what
-    remains is the order of the fp32 accumulation inside one MXU dot vs
-    one XLA einsum and one bf16 rounding of the output (|o| <~ 4 -> 8e-3).
-    2e-2. Prints the grid and tile the kernel chose for each shape."""
+    page region. The two run the same arithmetic in the same precision on
+    the same bf16 inputs, the scan a page a step and the kernel 128 keys a
+    step, so the tolerance is tighter than flash-vs-reference: what
+    remains is the grouping and order of the fp32 accumulation (one MXU
+    dot over a group vs one XLA einsum a page) and one bf16 rounding of
+    the output (|o| <~ 4 -> 8e-3). 2e-2. Prints the grid, the pages a
+    group and the tile the kernel chose for each shape."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -440,9 +459,10 @@ def _paged_parity(size: dict):
             err = _max_err(outs["pallas"], outs["scan"])
             _say(f"paged pallas vs scan H={H} Hkv={Hkv} D={D} block_len={bl} "
                  f"Tq={Tq} seq_lens={lens.tolist()} bf16: grid "
-                 f"{tiling['grid']} (G={tiling['grid'][1]}), tile "
-                 f"{tiling['heads']} KV heads x {tiling['rows']} rows; max "
-                 f"abs err {err:.2e} (tolerance {tol:g})")
+                 f"{tiling['grid']} (G={tiling['grid'][1]}), up to "
+                 f"{tiling['groups']} groups of pages={tiling['pages']} a "
+                 f"row, tile {tiling['heads']} KV heads x {tiling['rows']} "
+                 f"rows; max abs err {err:.2e} (tolerance {tol:g})")
             _require(np.isfinite(err) and err <= tol,
                      f"paged H={H}/{Hkv} Tq={Tq} within {tol:g}")
 
@@ -483,9 +503,10 @@ def _paged_window_parity(size: dict):
         err = _max_err(outs["pallas"], outs["scan"])
         _say(f"{kernel} pallas vs scan H={H} Hkv={Hkv} D={D} window={W} "
              f"ring={pages} pages Tq={Tq} seq_lens={lens.tolist()} bf16: "
-             f"grid {tiling['grid']}, tile {tiling['heads']} KV heads x "
-             f"{tiling['rows']} rows; max abs err {err:.2e} (tolerance "
-             f"{tol:g})")
+             f"grid {tiling['grid']}, up to {tiling['groups']} groups of "
+             f"pages={tiling['pages']} a row, tile {tiling['heads']} KV "
+             f"heads x {tiling['rows']} rows; max abs err {err:.2e} "
+             f"(tolerance {tol:g})")
         _require(kernel == WINDOW_KERNEL and np.isfinite(err) and err <= tol,
                  f"{WINDOW_KERNEL} H={H}/{Hkv} Tq={Tq} within {tol:g}")
 
@@ -562,6 +583,8 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
 
     import paddle_tpu as paddle
     from paddle_tpu import serving
+    from paddle_tpu.ops import pallas_mode
+    pallas_mode.KERNEL_TILINGS.clear()      # the engine's from here on
     from paddle_tpu.models.generation import generate
     from paddle_tpu.models.gpt import GPTForCausalLM
     from paddle_tpu.obs.compile_observatory import compile_observatory
@@ -642,11 +665,11 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
 
     row = _kernel_row("llm/unified_step")
     kernels = row["pallas_kernels"] or {}
-    _say(f"[serve] compiled unified step: compile "
-         f"{row['compile_seconds']:.1f}s (AOT lower+compile), temp "
+    _say(f"[serve] compiled unified step: {_phases(row)}, temp "
          f"{row['temp_bytes']} B, args {row['argument_bytes']} B, Pallas "
          f"kernels {kernels}")
     _say(f"[serve] kernel trace paths: {_trace_paths()}")
+    _say(f"[serve] kernel tilings: {_tilings()}")
     if rehearsal:
         _say("  kernel census not applicable on the CPU (impl=None is the "
              "scan path there by design)")
@@ -657,8 +680,11 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
                  f"custom calls (got {kernels.get('paged_attention', 0)})")
 
     # informational: the engine's greedy stream against one-shot generate().
-    # Bit-identity is pinned on the CPU in tests; on the MXU it depends on
-    # the block_len grouping (engine 16, generate() DEFAULT_KV_BLOCK 8).
+    # Bit-identity is pinned on the CPU in tests (the scan on both sides at
+    # one block_len). On the chip the kernel groups 128 keys a step at
+    # either block_len, but the engine's rows are chunks of its prompt
+    # beside other slots' and generate()'s a whole prompt, then one token:
+    # different matmul shapes around the kernel, so the bits may differ.
     p0 = np.asarray(prompts[0], np.int32)
     one_shot = np.asarray(generate(model, p0[None, :],
                                    max_new_tokens=max_new).data)[0, len(p0):]

@@ -183,17 +183,27 @@ def _aot_analyses(fn, args) -> Tuple[float, dict]:
         "flops": None, "bytes_accessed": None, "temp_bytes": None,
         "argument_bytes": None, "output_bytes": None,
         "generated_code_bytes": None, "pallas_kernels": None,
+        "phase_seconds": None,
     }
     lower = getattr(fn, "lower", None)
     if lower is None:
         return 0.0, out
     t0 = time.monotonic()
     try:
-        compiled = lower(*args).compile()
+        # apart: tracing and lowering run in every process before the
+        # compile cache can be asked, compiling only where it misses
+        trace = getattr(fn, "trace", None)
+        traced = trace(*args) if trace is not None else None
+        t1 = time.monotonic()
+        lowered = traced.lower() if traced is not None else lower(*args)
+        t2 = time.monotonic()
+        compiled = lowered.compile()
     except Exception:
         _log.debug("AOT lower/compile failed", exc_info=True)
         return time.monotonic() - t0, out
     seconds = time.monotonic() - t0
+    out["phase_seconds"] = {"trace": t1 - t0, "lower": t2 - t1,
+                            "compile": t0 + seconds - t2}
     try:
         cost = compiled.cost_analysis()
         if isinstance(cost, (list, tuple)):   # per-device on older jax
@@ -229,7 +239,8 @@ class ExecutableRecord:
     __slots__ = ("callsite", "fingerprint", "signature", "compile_seconds",
                  "flops", "bytes_accessed", "temp_bytes", "argument_bytes",
                  "output_bytes", "generated_code_bytes", "pallas_kernels",
-                 "dispatches", "device_seconds", "built_seq")
+                 "phase_seconds", "dispatches", "device_seconds",
+                 "built_seq")
 
     def __init__(self, callsite: str, fingerprint: str,
                  signature: Tuple[tuple, ...], compile_seconds: float,
@@ -245,6 +256,7 @@ class ExecutableRecord:
         self.output_bytes = analyses.get("output_bytes")
         self.generated_code_bytes = analyses.get("generated_code_bytes")
         self.pallas_kernels = analyses.get("pallas_kernels")
+        self.phase_seconds = analyses.get("phase_seconds")
         self.dispatches = 0
         self.device_seconds = 0.0
         self.built_seq = built_seq
@@ -261,6 +273,7 @@ class ExecutableRecord:
             "output_bytes": self.output_bytes,
             "generated_code_bytes": self.generated_code_bytes,
             "pallas_kernels": self.pallas_kernels,
+            "phase_seconds": self.phase_seconds,
             "dispatches": self.dispatches,
             "device_seconds": round(self.device_seconds, 6),
             "built_seq": self.built_seq,

@@ -22,32 +22,61 @@ Layout contract — shared with `SlotPagedKVPool`:
     q_pos            [B] int32: absolute position of q's first token in
                      row b; causal mask is col <= q_pos[b] + t
 
-Two implementations with the SAME per-block online-softmax op sequence;
-both read the slabs as stored (a page is `block_len` columns of one slab
-row, for every KV head at once), so nothing slices, transposes or copies
-the pool on the way in:
+Two implementations of one function; both read the slabs as stored (a
+page is `block_len` columns of one slab row, for every KV head at once),
+so nothing slices, transposes or copies the pool on the way in. They run
+the same masked-score -> exact-zero-p -> alpha-rescale arithmetic in the
+same precision (operands as stored, float32 scores, softmax state and
+accumulator) over every key a query may see and no other, but group the
+accumulation differently — a page a step in the scan, 128 keys a step in
+the kernel — so they agree to a documented tolerance (1e-6 in float32 at
+the tests' shapes, chip_smoke.py's 2e-2 in bf16 on the chip), not by op
+sequence:
 
-- `_scan_impl` — plain XLA `lax.scan` over logical blocks. What `impl=None`
-  picks on the CPU: interpret-mode Pallas unrolls every grid cell into the
-  jaxpr, which makes tier-1 compile times explode, while this path compiles
-  once and runs the identical arithmetic. Also the parity reference the
-  kernel is checked against on the chip (chip_smoke.py).
+- `_scan_impl` — plain XLA `lax.scan` over logical blocks, one page a
+  step. What `impl=None` picks on the CPU: interpret-mode Pallas unrolls
+  every grid cell into the jaxpr, which makes tier-1 compile times explode,
+  while this path compiles once. Also the parity reference the kernel is
+  checked against on the chip (chip_smoke.py).
 - `_pallas_impl` — the TPU kernel, what `impl=None` picks on a TPU: grid
-  (B, G, n_blocks), one step per (slot, head group, page). A step's K and V
-  tiles are `[heads, block_len, D]`, cut from the slab by the index_map; a
-  KV head's `n_rep` query heads are folded into the rows of its q tile
-  (`q` viewed as `[B, Hkv, n_rep*Tq, D]`: row r is token `r mod Tq`), so a
-  GQA group reads its page once. The block table / lengths / positions are
-  scalar-prefetched: the index_map fetches only the pages a row occupies
-  (a page past its length names the last live one again, which fetches
-  nothing) and `@pl.when` skips their compute ("only over occupied KV
-  blocks"). `_choose_tile` takes the tile from the shapes and one VMEM
-  budget: every head in one tile (G = 1) at the engine's shapes, fewer KV
-  heads or a part of one GQA group for a whole-prompt prefill of hundreds
-  of rows. `pallas_mode.KERNEL_TILINGS` records the choice of each trace.
+  (B, G), one step per (slot, head group), and inside it a loop over the
+  row's live *groups*. A group is the `_group_pages(block_len)` consecutive
+  logical pages that hold `_GROUP_KEYS` = 128 keys, one lane tile (8 pages
+  of 16, 16 of 8), aligned on absolute logical blocks (group g = blocks
+  [g*P, (g+1)*P)): one masked score matrix `[heads, rows, 128]`, one
+  online-softmax update and one p.V product a group, where a page a step
+  left seven eighths of the lanes and of the MXU's width idle and paid a
+  grid step's fixed cost sixteen keys at a time. The slabs stay in HBM
+  (`memory_space=ANY`); each page of a group is still found through its
+  own block-table entry and brought by its own async copy into a
+  double-buffered `[heads, 128, D]` scratch, the next group (or the next
+  grid step's first) in flight while one is computed. The copies are
+  issued at one place in the body, by a loop over the group's pages (eight
+  written out a pass), and awaited by one wait a buffer (its semaphore counts bytes), so the body a
+  process traces and lowers is a few hundred equations at either
+  `block_len`; and the `pallas_call` sits under one module-level `jax.jit`
+  whose integers are static (`_paged_call`), so the call sites of a traced
+  program that agree on shapes and window (a step's layers) share one
+  jaxpr and the lowered module holds one kernel body for them. Both
+  matter because tracing and lowering run in every process before the
+  compile cache can be asked (PR 32's unrolled copies, a body a layer: +15
+  s of an engine's start). A KV head's `n_rep`
+  query heads are folded into the rows of its q tile (`q` viewed as
+  `[B, Hkv, n_rep*Tq, D]`: row r is token `r mod Tq`), so a GQA group
+  reads its pages once. The block table / lengths / positions are
+  scalar-prefetched; the loop ends with the group that holds the row's
+  length ("only over occupied KV blocks"), and in that group a page past
+  the length is the last live page again, masked by column. `_choose_tile`
+  takes the tile from the shapes and one VMEM budget: every head in one
+  tile (G = 1) at the engine's shapes, fewer KV heads or a part of one GQA
+  group for a whole-prompt prefill of hundreds of rows — heads, never
+  keys: the group is a constant of the kernel, so the accumulation
+  grouping follows from nothing a caller chooses, `block_len` included.
+  `pallas_mode.KERNEL_TILINGS` records the choice of each trace (`grid`,
+  `groups` = the most a row's loop takes, `pages`, `heads`, `rows`).
   Mosaic (jax 0.9.0 / libtpu 0.0.34, v5e) lowers it in bf16 at `block_len`
   8 and 16 (the two sizes the repo runs), query widths 1 to 2,048, MHA and
-  GQA — including the 8-row bf16 KV tile (half a packed sublane tile) of
+  GQA — including the 8-row bf16 page (half a packed sublane tile) of
   `DEFAULT_KV_BLOCK` and the 1-row q tile of the MHA `generate()` decode
   loop; tests/test_mosaic_aot.py pins those and the serve cells' shapes.
 
@@ -56,11 +85,13 @@ A window (PR 31). With `window=W` a query at position p sees the W keys
 block j lives in page `table[b, j mod R]`, R = `block_table.shape[1]`
 pages a row (position p at ring column `p mod (R * block_len)`; a
 contiguous cache is the ring that never wraps). The walk then starts at the
-first block that cuts the row's window and takes at most
-`ceil((W + Tq) / block_len) + 1` steps whatever the row's length: grid
-(B, G, steps), its own `pallas_call` name `paged_window`, so that a trace
-tells its time from the full walk's `paged_attention`. The mask is taken
-on logical columns, so a ring page visited under two logical blocks (the
+first block that cuts the row's window (the kernel: at the group that holds
+it) and takes at most `ceil((W + Tq) / block_len) + 1` scan steps
+(`ceil((W + Tq - 1) / 128) + 1` groups) whatever the row's length, under
+its own `pallas_call` name `paged_window`, so that a trace tells its time
+from the full walk's `paged_attention`. The mask is taken on logical
+columns and a ring page is found per page (`(g*P + p) mod R`: R need be no
+multiple of P), so a ring page visited under two logical blocks (the
 walk's first and last may share one) shows each its own columns.
 
 Numerics: flash-style online softmax with the repo's exact-zero masking
@@ -68,18 +99,22 @@ convention (ops/attention.py `_fwd_kernel`): masked scores sit at
 `_NEG_INF`, `p = where(s <= _NEG_INF/2, 0, exp(s - m_new))` contributes an
 exact fp32 0.0, and a fully-masked block leaves (m, l, acc) bit-unchanged
 (`alpha = exp(m - m) = 1.0`). That no-op property is what makes chunked
-prefill *bit-identical* to whole-prompt prefill at a fixed `block_len`:
-the result for a query at absolute position P depends only on
-(q, K[0..P], V[0..P]) and the block iteration order — never on the query
-width, the chunk boundary, or how many trailing padded blocks the grid
-carries. Different `block_len`s group the accumulation differently and are
-documented-tolerance-identical only. The window keeps it: blocks wholly
-outside a row's window are exact no-ops, so the windowed walk through a
-ring gives the bits of a walk over every block of a full-length cache.
+prefill *bit-identical* to whole-prompt prefill within one implementation:
+its steps are aligned on absolute logical blocks (pages in the scan, groups
+in the kernel), so the result for a query at absolute position P depends
+only on (q, K[0..P], V[0..P]) and the step order — never on the query
+width, the chunk boundary, or how many trailing dead steps the walk
+carries. The scan at different `block_len`s groups the accumulation
+differently and is documented-tolerance-identical only; the kernel's
+grouping is 128 keys at every `block_len` that divides it. The window
+keeps it: steps wholly outside a row's window are exact no-ops, so the
+windowed walk through a ring gives the bits of a walk over every block of
+a full-length cache, in the scan and in the kernel.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -88,14 +123,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import pallas_mode
-from .attention import _GRID_SEMANTICS, _NEG_INF, _dot
+from .attention import _NEG_INF, _dot
 
 # The kv block size the trivial (non-paged) decode path uses. Engine pools
-# that want streams bit-identical to one-shot generate() must use the SAME
-# block_len (flash accumulation grouping differs across block sizes; see
-# module docstring). 8 divides every cache length the tests use and keeps
-# the CPU scan short; on a TPU the kernel lowers at 8 too (module
-# docstring), so the one-shot path keeps it there.
+# that want streams bit-identical to one-shot generate() on the CPU must
+# use the SAME block_len (the scan's accumulation grouping differs across
+# block sizes; see module docstring). 8 divides every cache length the
+# tests use and keeps the CPU scan short; on a TPU the kernel lowers at 8
+# too (module docstring), so the one-shot path keeps it there.
 DEFAULT_KV_BLOCK = 8
 
 # The windowed walk's name: of its `pallas_call` (so of its instruction in
@@ -104,23 +139,42 @@ DEFAULT_KV_BLOCK = 8
 WINDOW_KERNEL = "paged_window"
 
 
-# What one grid step of the kernel may hold in VMEM: its q, K, V and output
-# tiles (double buffered by the pipeline), the fp32 online-softmax scratch,
-# and the step's fp32 scores and probabilities. Mosaic's scoped limit on a
-# v5e is 16 MB; half of it leaves the compiler its own temporaries. The
-# engine's shapes need ~1.5 MB, so every head rides one tile there; a
-# whole-prompt prefill of hundreds of rows splits the heads into groups.
+# Keys one grid step of the kernel covers: one lane tile. A step takes the
+# `_group_pages(block_len)` consecutive logical pages that hold them (8 of
+# 16, 16 of 8), so its scores are `[heads, rows, 128]` on full lanes and
+# its two products use the MXU's whole width. A constant of the kernel:
+# when VMEM is short `_choose_tile` gives up heads, never keys, so the
+# accumulation grouping follows from nothing a caller chooses.
+_GROUP_KEYS = 128
+
+
+def _group_pages(block_len: int) -> int:
+    """Pages a grid step covers (P): the group is the pages that fill one
+    lane tile of keys; a page wider than that is a group of its own."""
+    return max(_GROUP_KEYS // block_len, 1)
+
+
+# What one grid step of the kernel may hold in VMEM: its q and output
+# tiles (double buffered by the pipeline), the two [heads, keys, D] buffers
+# each of K and V (one group computed, one in flight), the fp32
+# online-softmax scratch, and a group's fp32 scores and probabilities.
+# Mosaic's scoped limit on a v5e is 16 MB; half of it leaves the compiler
+# its own temporaries. The engine's shapes need ~3 MB, so every head rides
+# one tile there; a whole-prompt prefill of hundreds of rows splits the
+# heads into groups.
 _VMEM_BUDGET = 8 << 20
 
 
 def _tile_bytes(heads: int, rows: int, block_len: int, D: int,
                 itemsize: int) -> int:
     """VMEM of one grid step whose tile holds `heads` KV heads (or pieces
-    of one) with `rows` folded query rows each. fp32 rows whose last dim
-    is under a lane tile (m, l, scores over a 16-wide page) pad to 128."""
-    io = 2 * (2 * rows * D + 2 * block_len * D) * itemsize     # q, o, k, v
+    of one) with `rows` folded query rows each, over groups of
+    `_group_pages(block_len)` pages. fp32 rows whose last dim is under a
+    lane tile (m, l) pad to 128."""
+    keys = _group_pages(block_len) * block_len
+    io = 2 * (2 * rows * D + 2 * keys * D) * itemsize          # q, o, k, v
     state = (rows * D + 2 * rows * 128) * 4                    # acc, m, l
-    scores = 2 * rows * max(block_len, 128) * 4                # s, p
+    scores = 2 * rows * max(keys, 128) * 4                     # s, p
     return heads * (io + state + scores)
 
 
@@ -132,7 +186,8 @@ def _choose_tile(H: int, Hkv: int, Tq: int, block_len: int, D: int,
     heads folded into `fold*Tq` rows. The largest tile inside the budget
     wins: every KV head with its whole GQA group where that fits (G = 1),
     else fewer KV heads, else one KV head with a part of its group (the
-    page is then fetched once per part). G = H // (heads*fold)."""
+    group's pages are then fetched once per part). G = H // (heads*fold).
+    The keys of a step are not its to choose (`_GROUP_KEYS`)."""
     n_rep = H // Hkv
     tiles = [(h, n_rep) for h in range(Hkv, 0, -1) if Hkv % h == 0]
     tiles += [(1, f) for f in range(n_rep - 1, 0, -1) if n_rep % f == 0]
@@ -154,6 +209,16 @@ def _window_steps(window: int, Tq: int, block_len: int, ring_pages: int):
     columns can cut, and never more than a ring that holds them has (its
     first and last block may share a page: + 1)."""
     return min(-(-(window + Tq) // block_len) + 1, ring_pages + 1)
+
+
+def _window_groups(window: int, Tq: int, block_len: int, ring_pages: int):
+    """Grid steps of the kernel's windowed walk: the aligned groups that
+    the W + Tq - 1 columns a row's queries see can cut, and never more
+    than a ring that holds them has (its first and last group may share
+    pages: + 1)."""
+    P = _group_pages(block_len)
+    return min(-(-(window + Tq - 1) // (P * block_len)), -(-ring_pages // P)
+               ) + 1
 
 
 def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
@@ -231,117 +296,219 @@ def _head_dot(a, b, a_dim, b_dim):
     return jax.vmap(lambda x, y: _dot(x, y, a_dim, b_dim))(a, b)
 
 
-def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, block_len, scale, Tq,
-                  window=None):
-    """Grid (B, G, n_blocks), pages innermost; one step is one page of one
-    slot for every head of the tile. q/o tiles [heads, fold*Tq, D] (a KV
-    head's query heads folded into rows), K/V tiles [heads, block_len, D]
-    cut from the slab by the index_map, online-softmax state in VMEM
-    scratch across a (b, g) row's pages. table/lens/pos arrive via scalar
-    prefetch. With `window`, step i is logical block `first + i` of the
-    row's own walk and the mask has a lower edge too."""
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    n_blocks = pl.num_programs(2)
-    rows = q_ref.shape[2]
-    j = i if window is None \
-        else _first_block(pos_ref[b], window, block_len) + i
+def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref, *,
+                  block_len, pages, pages_per_row, n_groups, parts, scale,
+                  Tq, window=None):
+    """Grid (B, G); one step is one slot's whole walk for every head of
+    the tile: a loop over the row's live groups of `pages` consecutive
+    logical pages. q/o tiles [heads, fold*Tq, D] (a KV head's query heads
+    folded into rows) come through the pipeline; the slabs stay in HBM and
+    a group's K and V reach VMEM by one copy a page, each found through
+    its own block-table entry, side by side along the key axis of a
+    double-buffered [heads, keys, D] scratch. The loop is a pipeline
+    without a prologue: pass i sets going what comes after group i (group
+    i+1, or the first group of the next grid step) and then computes group
+    i, so the copies are issued at one place, by a loop over the group's
+    pages, and awaited at one place, by one wait a buffer; the grid's very
+    first step, with nothing in flight yet, takes one pass more (i = -1),
+    and a row with no live group one pass that only fetches.
+    table/lens/pos arrive via scalar prefetch. With `window` the walk
+    starts at the group that holds the window's first block and the mask
+    has a lower edge too."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    B, G = pl.num_programs(0), pl.num_programs(1)
+    heads, rows = q_ref.shape[1], q_ref.shape[2]
+    ring_pages = table_ref.shape[1]
+    keys = pages * block_len
 
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def walk(row):
+        """(first group, live groups) of `row`: a group wholly past the
+        row's length cannot contribute (every column masked -> exact
+        no-op), so the walk ends with the group that holds the length."""
+        end = (lens_ref[row] + keys - 1) // keys
+        if window is None:
+            return 0, jnp.minimum(end, n_groups)
+        first = _first_block(pos_ref[row], window, block_len) // pages
+        return first, jnp.clip(end - first, 0, n_groups)
 
-    # occupied-blocks-only: a block wholly past this row's length cannot
-    # contribute (every column masked -> exact no-op), so skip its compute
-    @pl.when(j * block_len < lens_ref[b])
-    def _compute():
-        col = (j * block_len
-               + jax.lax.broadcasted_iota(jnp.int32, (rows, block_len), 1))
-        t = jax.lax.broadcasted_iota(jnp.int32, (rows, block_len), 0)
-        if rows != Tq:                        # folded row r is token r mod Tq
-            t = jax.lax.rem(t, Tq)
-        keep = (col <= pos_ref[b] + t) & (col < lens_ref[b])
-        if window is not None:
-            keep &= col > pos_ref[b] + t - window
-        vblk = v_ref[0]                       # [heads, KB, D]
-        s = _head_dot(q_ref[0], k_ref[0], 1, 1) * scale  # [heads,rows,KB]
-        s = jnp.where(keep[None], s, _NEG_INF)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + _head_dot(
-            p.astype(vblk.dtype), vblk, 1, 0)
-        m_ref[...] = m_new
+    def fetch(row, tile, j, slot):
+        """Set group j of `row` going into buffer `slot`: K and V of each
+        page, [heads, block_len, D] cut from the slab as stored. A page
+        past the row's length (or the table's width) names the row's last
+        live page again: finite, and masked by column."""
+        last = jnp.maximum(lens_ref[row] - 1, 0) // block_len
+        h0 = tile // parts * heads
 
-    @pl.when(i == n_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        def page(p):
+            blk = jnp.minimum(j * pages + p, last)
+            blk = blk % ring_pages if window is not None \
+                else jnp.minimum(blk, ring_pages - 1)
+            at = table_ref[row, blk]
+            r = at // pages_per_row
+            c = pl.multiple_of(at % pages_per_row * block_len, block_len)
+            to = pl.multiple_of(p * block_len, block_len)
+            for hbm, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                pltpu.make_async_copy(
+                    hbm.at[r, pl.ds(h0, heads), pl.ds(c, block_len), :],
+                    buf.at[slot, :, pl.ds(to, block_len), :],
+                    sem.at[slot]).start()
 
+        # eight pages' copies a pass of the loop, written out, so that the
+        # scheduler can lay their scalar work beside the group's vector
+        # work (one page a pass: +14% a live group on a v5e); the body is
+        # the same size at either block_len
+        at_once = math.gcd(pages, 8)
 
-def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
-                 block_len: int, pages_per_row: int, scale: float,
-                 window: int = None):
-    B, H, Tq, D = q.shape
-    Hkv = k_cache.shape[1]
-    n_rep = H // Hkv
-    ring_pages = block_table.shape[1]
-    n_blocks = ring_pages if window is None \
-        else _window_steps(window, Tq, block_len, ring_pages)
-    heads, fold = _choose_tile(H, Hkv, Tq, block_len, D, q.dtype.itemsize)
-    rows = fold * Tq
-    G = H // (heads * fold)
-    parts = n_rep // fold          # tiles that share one KV head (1: none)
-    grid = (B, G, n_blocks)
-    name = "paged_attention" if window is None else WINDOW_KERNEL
-    pallas_mode.note_tiling(name, grid=grid, heads=heads, rows=rows)
-    table = jnp.maximum(block_table, 0)
+        def issue(k, carry):
+            for u in range(at_once):
+                page(k * at_once + u)
+            return carry
 
-    def q_map(b, g, j, table_ref, lens_ref, pos_ref):
-        return (b, g, 0, 0)
-
-    def kv_map(b, g, j, table_ref, lens_ref, pos_ref):
-        # a page past the row's length names the row's last live page
-        # again: the block index does not change, so nothing is fetched
-        last = jnp.maximum(lens_ref[b] - 1, 0) // block_len
-        if window is not None:     # the row's own walk, through the ring
-            j = (_first_block(pos_ref[b], window, block_len) + j)
-            page = table_ref[b, jnp.minimum(j, last) % ring_pages]
+        if pages == at_once:
+            issue(0, None)
         else:
-            page = table_ref[b, jnp.minimum(j, last)]
-        return (page // pages_per_row, g // parts, page % pages_per_row, 0)
+            jax.lax.fori_loop(0, pages // at_once, issue, None)
 
-    tile = pl.BlockSpec((1, heads, rows, D), q_map)
-    kv_tile = pl.BlockSpec((1, heads, block_len, D), kv_map)
+    first, n = walk(b)
+    # the grid step after this one, whose first group this one sets going
+    wrap = g + 1 == G
+    nb, ng = b + wrap.astype(jnp.int32), jnp.where(wrap, 0, g + 1)
+    nrow = jnp.minimum(nb, B - 1)
+    nfirst, nn = walk(nrow)
+    has_next = (nb < B) & (nn > 0)
+    opening = (b == 0) & (g == 0)             # nothing is in flight yet
+    lo = jnp.where(opening, -1, 0)
+    hi = jnp.maximum(n, lo + 1)
+    slot0 = jnp.where(opening, 0, slot_ref[0])   # holds this row's group 0
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    t = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
+    if rows != Tq:                            # folded row r is token r mod Tq
+        t = jax.lax.rem(t, Tq)
+    row_pos = pos_ref[b] + t
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+
+    def group(i, carry):
+        ahead = (slot0 + i + 1) % 2           # the buffer group i is not in
+        more = i + 1 < n      # else: the next grid step's first group
+
+        @pl.when(more | has_next)
+        def _():
+            fetch(jnp.where(more, b, nrow), jnp.where(more, g, ng),
+                  jnp.where(more, first + i + 1, nfirst), ahead)
+
+        @pl.when((i >= 0) & (i < n))
+        def _():
+            slot = 1 - ahead
+            for buf in (kbuf, vbuf):
+                # the group's copies share the buffer's semaphore, which
+                # counts bytes: one wait for the buffer's size takes all
+                pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                      sem.at[slot]).wait()
+            col = (first + i) * keys + lane
+            keep = (col <= row_pos) & (col < lens_ref[b])
+            if window is not None:
+                keep &= col > row_pos - window
+            vgrp = vbuf[slot]                             # [heads, keys, D]
+            s = _head_dot(q_ref[0], kbuf[slot], 1, 1) * scale
+            s = jnp.where(keep[None], s, _NEG_INF)        # [heads, rows, keys]
+            m_prev = m_ref[...]
+            l_prev = l_ref[...]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + _head_dot(
+                p.astype(vgrp.dtype), vgrp, 1, 0)
+            m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(lo, hi, group, None)
+    slot_ref[0] = (slot0 + hi) % 2
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_len", "pages_per_row", "scale", "window", "heads", "fold",
+    "n_groups", "interpret"))
+def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos, *,
+                block_len, pages_per_row, scale, window, heads, fold,
+                n_groups, interpret):
+    """The kernel's `pallas_call` at one tile. Jitted at module level with
+    every integer static, so the call sites of one traced program that
+    agree on shapes and window (a step's layers, unrolled) share one
+    jaxpr, and the program lowers one kernel body for them, not one
+    each."""
+    B, H, Tq, D = q.shape
+    n_rep = H // k_cache.shape[1]
+    P = _group_pages(block_len)
+    rows = fold * Tq
+    name = "paged_attention" if window is None else WINDOW_KERNEL
+    tile = pl.BlockSpec((1, heads, rows, D),
+                        lambda b, g, table_ref, lens_ref, pos_ref:
+                        (b, g, 0, 0))
+    slab = pl.BlockSpec(memory_space=pl.ANY)
+    group = (2, heads, P * block_len, D)       # two buffers: one in flight
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[tile, kv_tile, kv_tile],
+        grid=(B, H // (heads * fold)),
+        in_specs=[tile, slab, slab],
         out_specs=tile,
         scratch_shapes=[
+            pltpu.VMEM(group, k_cache.dtype),
+            pltpu.VMEM(group, v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),       # the buffer in flight
             pltpu.VMEM((heads, rows, D), jnp.float32),
             pltpu.VMEM((heads, rows, 1), jnp.float32),
             pltpu.VMEM((heads, rows, 1), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_kernel, block_len=block_len,
-                               scale=scale, Tq=Tq, window=window)
+    kernel = functools.partial(
+        _paged_kernel, block_len=block_len, pages=P,
+        pages_per_row=pages_per_row, n_groups=n_groups,
+        parts=n_rep // fold,       # tiles that share one KV head (1: none)
+        scale=scale, Tq=Tq, window=window)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H // fold, rows, D), q.dtype),
-        compiler_params=_GRID_SEMANTICS,  # (B, G, pages): same shape
-        interpret=pallas_mode.interpret(name),
+        # in order: a step sets the next one's first group going
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
         name=name,
-    )(table, seq_lens, q_pos, q.reshape(B, H // fold, rows, D),
-      k_cache, v_cache)
+    )(jnp.maximum(block_table, 0), seq_lens, q_pos,
+      q.reshape(B, H // fold, rows, D), k_cache, v_cache)
     return out.reshape(B, H, Tq, D)
+
+
+def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+                 block_len: int, pages_per_row: int, scale: float,
+                 window: int = None):
+    """Choose the tile from the shapes, record it, and call the kernel
+    through its one jitted entry."""
+    B, H, Tq, D = q.shape
+    ring_pages = block_table.shape[1]
+    P = _group_pages(block_len)
+    n_groups = -(-ring_pages // P) if window is None \
+        else _window_groups(window, Tq, block_len, ring_pages)
+    heads, fold = _choose_tile(H, k_cache.shape[1], Tq, block_len, D,
+                               q.dtype.itemsize)
+    name = "paged_attention" if window is None else WINDOW_KERNEL
+    pallas_mode.note_tiling(name, grid=(B, H // (heads * fold)),
+                            groups=n_groups, pages=P, heads=heads,
+                            rows=fold * Tq)
+    return _paged_call(
+        q, k_cache, v_cache, block_table, seq_lens, q_pos,
+        block_len=block_len, pages_per_row=pages_per_row,
+        scale=float(scale), window=window, heads=heads, fold=fold,
+        n_groups=n_groups, interpret=pallas_mode.interpret(name))
 
 
 def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,

@@ -244,6 +244,11 @@ def test_cost_analysis_flops_agree_with_6nd(gpt_tiny, global_observatory):
     assert row["temp_bytes"] > 0
     assert row["argument_bytes"] > 0 and row["output_bytes"] > 0
     assert row["compile_seconds"] > 0
+    # trace, lower and compile apart: they add up to the one figure
+    phases = row["phase_seconds"]
+    assert set(phases) == {"trace", "lower", "compile"}
+    assert all(v > 0 for v in phases.values())
+    assert sum(phases.values()) == pytest.approx(row["compile_seconds"])
     assert row["dispatches"] == 1
 
 

@@ -5,6 +5,7 @@ kernels for a TPU v5e through the installed libtpu, with no chip attached (ISSUE
 nothing of whether Mosaic accepts a kernel). Runs in a subprocess so the
 libtpu lock and the TPU_* environment stay out of the test process."""
 import os
+import re
 import subprocess
 import sys
 
@@ -13,16 +14,69 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_pallas_kernels_compile_for_v5e_without_a_chip():
+@pytest.fixture(scope="module")
+def tool():
+    """One run of the tool for every test here (one process at a time may
+    hold libtpu, and a run takes half a minute): its stdout."""
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "mosaic_aot_check.py")],
-        capture_output=True, text=True, timeout=300)
+        capture_output=True, text=True, timeout=400)
     if r.returncode == 3:
         pytest.skip(f"libtpu gave no v5e topology: {r.stderr[-300:]}")
     cases = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
     assert r.returncode == 0, "\n".join(cases) + r.stderr[-1500:]
-    assert len(cases) == 25 and all(c.startswith("[OK]") for c in cases)
+    return r.stdout
+
+
+def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
+    cases = [ln for ln in tool.splitlines()
+             if ln.startswith(("[OK]", "[FAIL]"))]
+    assert len(cases) == 28 and all(c.startswith("[OK]") for c in cases)
+    paged = [c for c in cases if c.startswith("[OK] paged bf16")]
+    assert len(paged) == 13         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
     assert len(window) == 2 and all("slab=[32, 4, 1056, 128]" in c
                                     for c in window)
+    # a grid step's loop takes 128 keys: 8 pages of 16 at every cell's
+    # shape, 16 pages of 8 on the one-shot path
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling paged_")]
+    assert tilings and all("'pages': 8" in t or "'pages': 16" in t
+                           for t in tilings)
+    for grid, groups in (("(128, 1)", 2), ("(32, 1)", 9), ("(32, 1)", 65),
+                         ("(32, 1)", 10)):
+        assert any(f"'grid': {grid}, 'groups': {groups}," in t
+                   and "'pages': 8" in t for t in tilings), (grid, groups)
+
+
+def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
+    """Tracing and lowering run in every process before the compile cache
+    can be asked, so a kernel body a layer is paid on every start (PR 32:
+    +15 s of set-up over 8 layers). The kernel's entry is one jitted
+    function: the lowered unified step of a 3-layer engine holds one
+    Mosaic body, that of an engine with window and full layers two, and
+    the compiled step still a custom call a layer."""
+    steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
+    assert len(steps) == 2
+    full, mixed = steps
+    assert "3 full layers: 1 Mosaic body " in full
+    assert "{'paged_attention': 3} in the compiled one" in full
+    assert "3 window layers + 1 full: 2 Mosaic bodies " in mixed
+    assert "'paged_window': 3" in mixed and "'paged_attention': 1" in mixed
+    for ln in steps:        # the three phases are timed apart
+        assert re.search(r"trace \d+\.\ds \+ lower \d+\.\ds \+ compile "
+                         r"\d+\.\ds$", ln), ln
+
+
+def test_kernel_body_does_not_grow_with_the_pages_of_a_group(tool):
+    """The copies of a group's pages are issued by a loop and awaited by
+    one wait a buffer: the body at `block_len` 8 (16 pages a group) is
+    within 10% of the body at 16 (8 pages), and a few hundred equations
+    (PR 32's unrolled copies: 1,334 and 2,454)."""
+    body = {m[1]: int(m[2]) for m in re.finditer(
+        r"^body paged_attention block_len=(\d+) Tq=16: (\d+) equations$",
+        tool, re.M)}
+    assert set(body) == {"8", "16"}
+    assert abs(body["8"] - body["16"]) <= 0.1 * body["16"]
+    assert body["8"] < 700

@@ -177,55 +177,77 @@ def test_bf16_parity_documented_tolerance():
     assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref))) <= 2e-2
 
 
-# (H, Hkv, Tq, block_len, table, tile): the cells' head layouts at small
-# sizes (GQA n_rep 4, MHA), a decode row and a chunk, both block sizes the
-# repo runs; a `tile` (KV heads, folded query heads) shrinks the module's
-# VMEM budget to what that tile needs, so that the heads split into groups
-# (G > 1), down to a part of one GQA group
+# (H, Hkv, Tq, block_len, table width, layout, tile): the cells' head
+# layouts at small sizes (GQA n_rep 4, MHA), a decode row and a chunk, both
+# block sizes the repo runs; a `tile` (KV heads, folded query heads) shrinks
+# the module's VMEM budget to what that tile needs, so that the heads split
+# into groups (G > 1), down to a part of one GQA group. Then what a group of
+# P = 128 // block_len pages adds: table widths over P and no multiple of
+# it, so that a row ends inside its second group (live and dead pages in
+# one step), on a group's edge or inside its first; a prefix shared by two
+# rows and a row scattered over other slots' slabs inside one group; the
+# heads split over several tiles with more than one group a row
 _KERNEL_CASES = [
-    pytest.param(8, 2, 1, 8, "fragmented", None, id="gqa4-tq1-bl8-frag"),
-    pytest.param(8, 2, 4, 16, "shared", None, id="gqa4-chunk-bl16-shared"),
-    pytest.param(4, 4, 1, 16, "fragmented", None, id="mha-tq1-bl16-frag"),
-    pytest.param(4, 4, 4, 8, "shared", None, id="mha-chunk-bl8-shared"),
-    pytest.param(8, 2, 4, 8, "identity", None, id="gqa4-chunk-bl8-identity"),
-    pytest.param(8, 4, 4, 8, "fragmented", (2, 2), id="gqa2-chunk-bl8-G2"),
-    pytest.param(8, 2, 4, 16, "shared", (1, 2),
+    pytest.param(8, 2, 1, 8, 3, "fragmented", None, id="gqa4-tq1-bl8-frag"),
+    pytest.param(8, 2, 4, 16, 3, "shared", None, id="gqa4-chunk-bl16-shared"),
+    pytest.param(4, 4, 1, 16, 3, "fragmented", None, id="mha-tq1-bl16-frag"),
+    pytest.param(4, 4, 4, 8, 3, "shared", None, id="mha-chunk-bl8-shared"),
+    pytest.param(8, 2, 4, 8, 3, "identity", None,
+                 id="gqa4-chunk-bl8-identity"),
+    pytest.param(8, 4, 4, 8, 3, "fragmented", (2, 2), id="gqa2-chunk-bl8-G2"),
+    pytest.param(8, 2, 4, 16, 3, "shared", (1, 2),
                  id="gqa4-chunk-bl16-G4-split-group"),
+    pytest.param(4, 2, 4, 16, 11, "fragmented", None,
+                 id="bl16-width11-fragmented"),
+    pytest.param(4, 2, 1, 16, 11, "shared", None, id="bl16-width11-shared"),
+    pytest.param(4, 4, 4, 8, 19, "fragmented", None,
+                 id="bl8-width19-fragmented"),
+    pytest.param(8, 2, 16, 8, 35, "shared", None, id="bl8-width35-shared"),
+    pytest.param(4, 2, 4, 16, 16, "identity", None, id="bl16-width16-whole"),
+    pytest.param(8, 4, 4, 16, 11, "fragmented", (2, 2),
+                 id="bl16-width11-G2"),
 ]
 
 
-@pytest.mark.parametrize("H,Hkv,Tq,bl,layout,tile", _KERNEL_CASES)
+@pytest.mark.parametrize("H,Hkv,Tq,bl,nb,layout,tile", _KERNEL_CASES)
 def test_pallas_interpret_matches_scan_and_reference(monkeypatch, H, Hkv, Tq,
-                                                     bl, layout, tile):
-    """The REAL kernel body (grid over (slot, head group, page), index maps
-    into the slabs as stored, VMEM online-softmax scratch over the folded
-    rows) runs interpreted on the CPU and must agree with the scan path
-    and the oracle — tier-1 proof that the TPU kernel computes the same
-    function. Every case: a slab with write-padding past the page region
-    (filled with NaN: never addressed), `-1` table padding, a row of
-    length 0, and an indirection through another slot's slab."""
+                                                     bl, nb, layout, tile):
+    """The REAL kernel body (grid over (slot, head group), a loop over the
+    row's live groups of 128 keys, a copy a page out of the slabs as
+    stored, each page through its own table entry, VMEM online-softmax
+    scratch over the folded rows) runs interpreted on the CPU and must
+    agree with the scan path (a page a step) to 1e-6 and the oracle to
+    1e-5 — tier-1 proof that the TPU kernel computes the same function.
+    Every case: a slab with write-padding past the page region (filled
+    with NaN: never addressed), `-1` table padding past each row's length,
+    a full row, rows that end inside a page, an empty row, a one-token
+    row, and an indirection through other slots' slabs."""
     from paddle_tpu.ops import paged_attention as PA
     from paddle_tpu.ops import pallas_mode
     rng = np.random.RandomState(4)
-    B, D, nb, pad = 4, 8, 3, 8
+    P = 128 // bl
+    keys, cap, D, pad = P * bl, nb * bl, 8, 8
+    # full, inside the second page, empty, one token, inside the third
+    # page; and where the table is that wide: inside the second group, on
+    # the first group's edge
+    lens = np.array([n for n in (cap, bl + 3, 0, 1, 2 * bl + 5,
+                                 keys + bl + 3, keys) if n <= cap], np.int32)
+    B = len(lens)
+    q_pos = np.maximum(lens - Tq, 0).astype(np.int32)
 
     def slab():
-        x = _rand(rng, (B, Hkv, nb * bl + pad, D))
-        return x.at[:, :, nb * bl:].set(jnp.nan)
+        x = _rand(rng, (B, Hkv, cap + pad, D))
+        return x.at[:, :, cap:].set(jnp.nan)
     k, v = slab(), slab()
     q = _rand(rng, (B, H, Tq, D))
-    # a full row, a row inside its second page, an empty row, one token
-    lens = np.array([nb * bl, bl + 3, 0, 1], np.int32)
-    q_pos = np.maximum(lens - Tq, 0).astype(np.int32)
-    table = {
-        "identity": _identity_table(B, nb),
-        # every row's pages scattered over other slots' slabs
-        "fragmented": np.array([[7, 2, 9], [4, 11, -1], [-1, -1, -1],
-                                [0, -1, -1]], np.int32),
-        # rows 0 and 1 read the same first page (a shared prefix)
-        "shared": np.array([[5, 6, 1], [5, 3, -1], [-1, -1, -1],
-                            [10, -1, -1]], np.int32),
-    }[layout]
+    if layout == "identity":
+        table = _identity_table(B, nb)
+    else:           # every row's pages scattered over other slots' slabs
+        table = rng.permutation(B * nb).astype(np.int32).reshape(B, nb)
+        if layout == "shared":      # rows 0 and 1 read the same first page
+            table[1, 0] = table[0, 0]
+    table = np.where(np.arange(nb)[None, :] * bl < lens[:, None], table,
+                     -1).astype(np.int32)
     heads, fold = tile or (Hkv, H // Hkv)
     if tile:
         monkeypatch.setattr(PA, "_VMEM_BUDGET",
@@ -237,7 +259,8 @@ def test_pallas_interpret_matches_scan_and_reference(monkeypatch, H, Hkv, Tq,
     G = H // (heads * fold)
     assert (G > 1) == bool(tile)
     assert dict(pallas_mode.KERNEL_TILINGS) == {
-        ("paged_attention", (("grid", (B, G, nb)), ("heads", heads),
+        ("paged_attention", (("grid", (B, G)), ("groups", -(-nb // P)),
+                             ("heads", heads), ("pages", P),
                              ("rows", fold * Tq))): 1}
     assert bool(jnp.all(jnp.isfinite(run["pallas"])))
     assert float(jnp.max(jnp.abs(run["pallas"] - run["scan"]))) <= 1e-6
@@ -248,6 +271,51 @@ def test_pallas_interpret_matches_scan_and_reference(monkeypatch, H, Hkv, Tq,
         n = int(lens[b] - q_pos[b])        # valid query rows
         assert float(jnp.max(jnp.abs(run["pallas"][b, :, :n]
                                      - ref[b, :, :n]), initial=0.0)) <= 1e-5
+
+
+# Where the kernel's loop has nothing in flight to wait for, or nobody to
+# fetch for: (block_len, lengths in grid order, tile). 300 is three groups
+# at either block size (its last inside a page), 128 one group to its edge
+_PIPELINE_CASES = [
+    pytest.param(16, (0, 300, 0, 0, 128, 0), None, id="bl16-grid-opens-empty"),
+    pytest.param(8, (0, 0, 5, 300), None, id="bl8-opens-on-two-empty-rows"),
+    pytest.param(16, (300, 0, 0), None, id="bl16-ends-on-empty-rows"),
+    pytest.param(8, (0, 0, 0), None, id="bl8-every-row-empty"),
+    pytest.param(16, (0, 300, 0, 129), (1, 2), id="bl16-G2-between-empty"),
+    pytest.param(8, (128, 0, 257), (1, 1), id="bl8-G4-between-empty"),
+]
+
+
+@pytest.mark.parametrize("bl,lens,tile", _PIPELINE_CASES)
+def test_kernel_pipeline_with_nothing_in_flight(monkeypatch, bl, lens, tile):
+    """A grid step's first group is set going by the step before it, the
+    grid's first step fetches its own (one pass more), and a row with no
+    live group only fetches for the next: empty rows at the grid's start,
+    at its end and in runs, alone and with the heads over several tiles
+    (the next step is then the same row's next tile), a whole prompt as
+    the query block. Against the scan, and zeros for an empty row."""
+    from paddle_tpu.ops import paged_attention as PA
+    rng = np.random.RandomState(21)
+    H, Hkv, D, Tq, nb = 4, 2, 8, 24, 320 // bl
+    lens = np.array(lens, np.int32)
+    B = len(lens)
+    q_pos = np.maximum(lens - Tq, 0).astype(np.int32)
+    k = _rand(rng, (B, Hkv, nb * bl, D))
+    v = _rand(rng, (B, Hkv, nb * bl, D))
+    q = _rand(rng, (B, H, Tq, D))
+    table = rng.permutation(B * nb).astype(np.int32).reshape(B, nb)
+    table = np.where(np.arange(nb)[None, :] * bl < lens[:, None], table,
+                     -1).astype(np.int32)
+    if tile:
+        monkeypatch.setattr(PA, "_VMEM_BUDGET",
+                            PA._tile_bytes(tile[0], tile[1] * Tq, bl, D, 4))
+    run = {impl: np.asarray(PA.ragged_paged_attention(
+        q, k, v, table, lens, q_pos, block_len=bl, pages_per_row=nb,
+        impl=impl)) for impl in ("scan", "pallas")}
+    assert np.isfinite(run["pallas"]).all()
+    assert np.abs(run["pallas"] - run["scan"]).max() <= 1e-6
+    assert not run["pallas"][lens == 0].any()
+    assert lens.max() == 0 or run["pallas"][lens > 0].any()
 
 
 def _ring_of(logical, length, ring, pad, written=None):
@@ -295,14 +363,16 @@ _WINDOW_CASES = [
     pytest.param(8, 2, 16, 8, 20, 5, id="gqa4-chunk16-bl8-w20-ragged"),
     pytest.param(4, 4, 1, 16, 27, 3, id="mha-tq1-bl16-w27-ragged"),
     pytest.param(4, 4, 4, 8, 13, 3, id="mha-chunk4-bl8-w13-ragged"),
+    # the window/full cell's ring: 65 pages are no multiple of a group's 8
+    pytest.param(4, 2, 16, 16, 1024, 65, id="gqa2-chunk16-bl16-w1024-ring65"),
 ]
 
 
 @pytest.mark.parametrize("H,Hkv,Tq,bl,window,ring_pages", _WINDOW_CASES)
 def test_window_kernel_matches_scan_and_reference(H, Hkv, Tq, bl, window,
                                                   ring_pages):
-    """The windowed walk (`paged_window`: grid over the pages that cut a
-    row's window, logical block -> ring page, both mask edges) interpreted
+    """The windowed walk (`paged_window`: a loop over the groups that cut
+    a row's window, logical block -> ring page, both mask edges) interpreted
     on the CPU = the scan path = the oracle over each row's contiguous
     keys, at ragged lengths: shorter than the window, crossing it, twice
     and five times round the ring, an empty row and a one-token row.
@@ -328,10 +398,11 @@ def test_window_kernel_matches_scan_and_reference(H, Hkv, Tq, bl, window,
     run = {impl: PA.ragged_paged_attention(
         q, k, v, None, lens, q_pos, block_len=bl, pages_per_row=ring_pages,
         impl=impl, window=window) for impl in ("scan", "pallas")}
-    steps = -(-(window + Tq) // bl) + 1
-    assert steps <= ring_pages + 1
+    P = 128 // bl
+    groups = min(-(-(window + Tq - 1) // 128), -(-ring_pages // P)) + 1
     assert dict(pallas_mode.KERNEL_TILINGS) == {
-        (PA.WINDOW_KERNEL, (("grid", (B, 1, steps)), ("heads", Hkv),
+        (PA.WINDOW_KERNEL, (("grid", (B, 1)), ("groups", groups),
+                            ("heads", Hkv), ("pages", P),
                             ("rows", H // Hkv * Tq))): 1}
     ref = _ref_window(q, jnp.asarray(k_log), jnp.asarray(v_log), lens,
                       q_pos, window)
@@ -347,22 +418,29 @@ def test_window_kernel_matches_scan_and_reference(H, Hkv, Tq, bl, window,
     assert not np.asarray(run["pallas"][4]).any()      # the empty row
 
 
-def test_window_walk_through_a_ring_is_bitwise_the_walk_of_a_full_cache():
+@pytest.mark.parametrize("impl,bl,window,Tq,ring_pages,lens,S", [
+    ("scan", 8, 20, 4, 4, (70, 19, 33), 72),
+    ("pallas", 8, 20, 4, 4, (70, 19, 33), 72),
+    # more than one group a walk; neither the ring's 14 pages nor the
+    # cache's 45 are a multiple of a group's 8
+    ("pallas", 16, 200, 16, 14, (700, 199, 330, 225), 720),
+])
+def test_window_walk_through_a_ring_is_bitwise_the_walk_of_a_full_cache(
+        impl, bl, window, Tq, ring_pages, lens, S):
     """The same keys in a ring and in a full-length contiguous cache, the
-    windowed walk over either, and the full walk with the window as its
-    mask... the first two give the same bits: the blocks walked are the
-    logical ones, and a block outside the window is an exact no-op."""
+    windowed walk over either: the same bits, in the scan and in the
+    kernel. The steps walked are the logical ones (aligned pages, aligned
+    groups), and a step outside the window is an exact no-op."""
     from paddle_tpu.ops import paged_attention as PA
     rng = np.random.RandomState(12)
-    B, H, Hkv, D, bl, window, Tq = 3, 4, 2, 8, 8, 20, 4
-    ring_pages = 4                                   # 32 >= 20 + 4
-    lens = np.array([70, 19, 33], np.int32)
-    S = 72
-    nb = S // bl
+    H, Hkv, D = 4, 2, 8
+    lens = np.array(lens, np.int32)
+    B, nb = len(lens), S // bl
+    ring = ring_pages * bl
+    assert ring >= window + Tq and nb * bl == S
     q_pos = (lens - Tq).astype(np.int32)
     k_log = rng.standard_normal((B, Hkv, S, D)).astype(np.float32)
     v_log = rng.standard_normal((B, Hkv, S, D)).astype(np.float32)
-    ring = ring_pages * bl
     kr = jnp.asarray(np.stack([_ring_of(k_log[b], lens[b], ring, Tq)
                                for b in range(B)]))
     vr = jnp.asarray(np.stack([_ring_of(v_log[b], lens[b], ring, Tq)
@@ -370,10 +448,10 @@ def test_window_walk_through_a_ring_is_bitwise_the_walk_of_a_full_cache():
     q = _rand(rng, (B, H, Tq, D))
     through_ring = PA.ragged_paged_attention(
         q, kr, vr, None, lens, q_pos, block_len=bl,
-        pages_per_row=ring_pages, impl="scan", window=window)
+        pages_per_row=ring_pages, impl=impl, window=window)
     contiguous = PA.ragged_paged_attention(
         q, jnp.asarray(k_log), jnp.asarray(v_log), _identity_table(B, nb),
-        lens, q_pos, block_len=bl, pages_per_row=nb, impl="scan",
+        lens, q_pos, block_len=bl, pages_per_row=nb, impl=impl,
         window=window)
     assert np.array_equal(np.asarray(through_ring), np.asarray(contiguous))
 
@@ -410,6 +488,7 @@ def test_ring_write_splits_a_stripe_that_straddles_the_rings_end():
     ("mistral decode", (128, 32, 16, 128), 8, 16, (8, 4)),
     ("mistral prefill", (32, 32, 16, 128), 8, 16, (8, 4)),
     ("olmoe decode", (128, 16, 16, 128), 16, 16, (16, 1)),
+    ("mellum step", (32, 32, 16, 128), 4, 16, (4, 8)),
     # one-shot generate(): the decode loop, then whole-prompt prefills
     ("gqa decode loop", (8, 32, 1, 128), 8, 8, (8, 4)),
     ("gqa prompt 512", (2, 32, 512, 128), 8, 8, (1, 4)),
@@ -419,9 +498,14 @@ def test_ring_write_splits_a_stripe_that_straddles_the_rings_end():
 def test_tile_choice_follows_shapes_and_budget(name, q_shape, hkv, bl, want):
     """`_choose_tile` at the shapes the cells and generate() run, bf16: the
     whole head set in one tile wherever it fits the module's one budget,
-    fewer KV heads, then a part of one GQA group, where it does not."""
+    fewer KV heads, then a part of one GQA group, where it does not. The
+    budget counts a group's 128 keys at either block size: heads are given
+    up, never keys."""
     from paddle_tpu.ops import paged_attention as PA
     _, H, Tq, D = q_shape
+    assert PA._group_pages(bl) * bl == PA._GROUP_KEYS == 128
+    assert PA._tile_bytes(1, Tq, 8, D, 2) == PA._tile_bytes(1, Tq, 16, D, 2)
+    assert PA._tile_bytes(1, Tq, bl, D, 2) >= 2 * 2 * 128 * D * 2
     heads, fold = PA._choose_tile(H, hkv, Tq, bl, D, 2)
     assert (heads, fold) == want, name
     assert hkv % heads == 0 and (H // hkv) % fold == 0
@@ -436,9 +520,11 @@ def test_tile_choice_follows_shapes_and_budget(name, q_shape, hkv, bl, want):
 
 def test_tpu_path_hands_the_slabs_to_the_kernel_as_stored(monkeypatch):
     """On the TPU path nothing slices, transposes, reshapes or copies a
-    slab before the kernel: the jaxpr's one `pallas_call` takes the
-    function's own k_cache / v_cache variables, and no other equation
-    reads them."""
+    slab before the kernel: the function's own k_cache / v_cache variables
+    go to the kernel's one jitted entry (`_paged_call`) and inside it to
+    its one `pallas_call`, once each (the kernel copies a group's pages out
+    of the slab in HBM itself, so a slab is one operand whatever the pages
+    a group), and no other equation reads them."""
     from paddle_tpu.ops import paged_attention as PA
     from paddle_tpu.ops import pallas_mode
     monkeypatch.setattr(pallas_mode, "platform", lambda: "tpu")
@@ -453,44 +539,53 @@ def test_tpu_path_hands_the_slabs_to_the_kernel_as_stored(monkeypatch):
         jax.ShapeDtypeStruct((B, nb), jnp.int32),
         jax.ShapeDtypeStruct((B,), jnp.int32),
         jax.ShapeDtypeStruct((B,), jnp.int32)).jaxpr
-    k_var, v_var = jaxpr.invars[1], jaxpr.invars[2]
-    readers = [e for e in jaxpr.eqns
-               if any(x is k_var or x is v_var for x in e.invars)]
-    assert [e.primitive.name for e in readers] == ["pallas_call"]
-    call, = readers
-    assert sum(x is k_var for x in call.invars) == 1
-    assert sum(x is v_var for x in call.invars) == 1
-    assert call.params["name"] == "paged_attention"
-    assert sum(e.primitive.name == "pallas_call" for e in jaxpr.eqns) == 1
-    for e in jaxpr.eqns:                     # and nothing slab-sized is made
-        for o in e.outvars:
-            if e.primitive.name != "pallas_call":
-                assert o.aval.size < slab.size, e
+    for name, want in (("jit", "_paged_call"), ("pallas_call",
+                                                "paged_attention")):
+        k_var, v_var = jaxpr.invars[1], jaxpr.invars[2]
+        readers = [e for e in jaxpr.eqns
+                   if any(x is k_var or x is v_var for x in e.invars)]
+        assert [e.primitive.name for e in readers] == [name]
+        call, = readers
+        assert sum(x is k_var for x in call.invars) == 1
+        assert sum(x is v_var for x in call.invars) == 1
+        assert call.params["name"] == want
+        assert sum(e.primitive.name == name for e in jaxpr.eqns) == 1
+        for e in jaxpr.eqns:                 # and nothing slab-sized is made
+            for o in e.outvars:
+                if e is not call:
+                    assert o.aval.size < slab.size, e
+        if name == "jit":                    # the same inside the entry
+            jaxpr = call.params["jaxpr"].jaxpr
 
 
-def test_chunked_prefill_bitwise_equals_whole_prompt():
+@pytest.mark.parametrize("impl,bl,nb,L,C", [
+    ("scan", 8, 3, 20, 8),
+    ("pallas", 8, 3, 20, 8),
+    # three groups of 128 keys; chunk edges inside a group and on one
+    ("pallas", 16, 19, 300, 32),
+])
+def test_chunked_prefill_bitwise_equals_whole_prompt(impl, bl, nb, L, C):
     """Chunk invariance, the property the engine's bit-identity rests on:
-    at a fixed block_len, a query row's output depends only on its
+    within one implementation a query row's output depends only on its
     absolute position and the committed KV — never on the chunk boundary
-    — so chunked outputs match the whole-prompt dispatch BITWISE."""
+    — so chunked outputs match the whole-prompt dispatch BITWISE, in the
+    scan (a page a step) and in the kernel (128 keys a step)."""
     from paddle_tpu.ops.paged_attention import ragged_paged_attention
     rng = np.random.RandomState(5)
-    H, Hkv, D, bl, nb, L = 2, 2, 8, 8, 3, 20
+    H, Hkv, D = 2, 2, 8
     k = _rand(rng, (1, Hkv, nb * bl, D))
     v = _rand(rng, (1, Hkv, nb * bl, D))
     q = _rand(rng, (1, H, L, D))
     table = _identity_table(1, nb)
-    whole = ragged_paged_attention(
-        q, k, v, table, np.array([L], np.int32), np.array([0], np.int32),
-        block_len=bl, impl="scan")
-    C = 8
+    attend = jax.jit(lambda q, lens, q_pos: ragged_paged_attention(
+        q, k, v, table, lens, q_pos, block_len=bl, impl=impl))
+    whole = attend(q, np.array([L], np.int32), np.array([0], np.int32))
     for off in range(0, L, C):
         n = min(C, L - off)
         qc = jnp.zeros((1, H, C, D), q.dtype).at[:, :, :n].set(
             q[:, :, off:off + n])
-        out = ragged_paged_attention(
-            qc, k, v, table, np.array([off + n], np.int32),
-            np.array([off], np.int32), block_len=bl, impl="scan")
+        out = attend(qc, np.array([off + n], np.int32),
+                     np.array([off], np.int32))
         assert np.array_equal(np.asarray(out[:, :, :n]),
                               np.asarray(whole[:, :, off:off + n])), \
             f"chunk at offset {off} diverged from whole-prompt prefill"

@@ -73,6 +73,86 @@ def compile_case(name, fn, *specs, want=None) -> bool:
     return ok
 
 
+def _subjaxprs(eqn):
+    """The jaxprs an equation holds (a loop's body, a branch, a call)."""
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (tuple, list)) else (value,):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr, those of the jaxprs they hold included."""
+    return sum(1 + sum(map(_equations, _subjaxprs(eqn)))
+               for eqn in jaxpr.eqns)
+
+
+def kernel_equations(fn, *specs) -> dict:
+    """{kernel: equations of its body} for every `pallas_call` that
+    tracing `fn` reaches: what a process traces and lowers for a kernel
+    before the compile cache can be asked."""
+    found = {}
+
+    def visit(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = _equations(eqn.params["jaxpr"])
+            else:
+                for sub in _subjaxprs(eqn):
+                    visit(sub)
+    visit(jax.make_jaxpr(fn)(*specs).jaxpr)
+    return found
+
+
+def serve_step_case(name, model, device, want) -> bool:
+    """Trace, lower and compile (timed apart) the unified step of an
+    `LLMEngine` over `model` for `device`. `want` is the number of Mosaic
+    kernel bodies in the lowered module: one a distinct kernel shape,
+    whatever the layers that call it (the compiled text still holds a
+    custom call a layer)."""
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import serving
+    from paddle_tpu.obs.compile_observatory import pallas_kernel_census
+    eng = serving.LLMEngine(model, serving.LLMEngineConfig(
+        num_slots=8, block_len=16, n_blocks=20, max_queue_depth=8,
+        enable_prefix_cache=False), clock=serving.SimClock())
+    eng.submit(np.arange(1, 40, dtype=np.int32), max_new_tokens=4)
+    with eng._cond:                # the operands of a step, as `_launch`'s
+        eng._admit()
+        toks, pos, adv, ctr, *_ = eng._build_rows_locked({})
+        args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(adv), eng.pool.device_block_table(),
+                eng.pool.slabs) + eng._sampling_args_locked(ctr) \
+            + eng._feedback_args() + (eng.pool.scratch_slabs(),) \
+            + eng._tail_args_locked()
+    sharding = SingleDeviceSharding(device)
+    specs = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+    try:
+        t0 = time.perf_counter()
+        traced = eng._step().trace(*specs)
+        t1 = time.perf_counter()
+        lowered = traced.lower()
+        t2 = time.perf_counter()
+        compiled = lowered.compile()
+        t3 = time.perf_counter()
+    except Exception as e:  # the tool's job is to report every case
+        print(f"[FAIL] {name}: {type(e).__name__}: {str(e)[:1500]}",
+              flush=True)
+        return False
+    bodies = lowered.as_text().count("stablehlo.custom_call @tpu_custom_call")
+    ok = bodies == want
+    print(f"[{'OK' if ok else 'FAIL'}] {name}: {bodies} Mosaic "
+          f"{'body' if bodies == 1 else 'bodies'} in the lowered step for "
+          f"{pallas_kernel_census(compiled.as_text())} in the compiled one; "
+          f"trace {t1 - t0:.1f}s + lower {t2 - t1:.1f}s + compile "
+          f"{t3 - t2:.1f}s" + ("" if ok else f" (wanted {want})"),
+          flush=True)
+    return ok
+
+
 def main() -> int:
     try:
         dev1, dev4 = v5e_devices(1), v5e_devices(4)
@@ -104,7 +184,9 @@ def main() -> int:
     # (label, q [B, H, Tq, D], slab [N, Hkv, L_slab, D], block_len, pages a
     # row): MHA at both block sizes the repo runs and both query widths,
     # then the serve cells' own shapes, the one-shot decode loop's GQA row
-    # and a whole-prompt prefill that must split the heads (G > 1)
+    # and whole-prompt prefills that must split the heads (G > 1). A step
+    # takes a group of 128 keys, 8 pages of 16 or 16 pages of 8 (an 8-row
+    # bf16 page is half a packed sublane tile): `pages` in the tilings
     D, L = 128, 2048
     paged_cases = [
         (f"block_len={bl} Tq={Tq}", (8, 16, Tq, D), (8, 16, L + 16, D), bl,
@@ -116,6 +198,8 @@ def main() -> int:
         ("GQA block_len=8 Tq=1", (8, 32, 1, D), (8, 8, L + 16, D), 8, L // 8),
         ("GQA block_len=8 Tq=512, G>1", (2, 32, 512, D), (2, 8, 512, D), 8,
          64),
+        ("GQA block_len=16 Tq=512, G>1", (2, 32, 512, D), (2, 8, 528, D), 16,
+         33),
     ]
     for label, q_shape, slab, bl, ppr in paged_cases:
         def paged(q, k, v, t, sl, qp, bl=bl, ppr=ppr):
@@ -129,10 +213,20 @@ def main() -> int:
             spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
             spec((B,), jnp.int32), spec((B,), jnp.int32),
             want={"paged_attention": 1}))
+        if label.startswith("block_len="):
+            # the body a process traces and lowers does not grow with the
+            # pages of a group: the copies are issued by a loop
+            body = kernel_equations(
+                paged, spec(q_shape, jnp.bfloat16), spec(slab, jnp.bfloat16),
+                spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
+                spec((B,), jnp.int32), spec((B,), jnp.int32))
+            print(f"body paged_attention {label}: "
+                  f"{body['paged_attention']} equations", flush=True)
     # the windowed walk through a ring (`paged_window`) and the full walk at
     # the window/full GQA cell's shapes: 32 slots, 32/4 heads x 128, a ring
-    # of 65 pages (window 1,024 + a chunk) beside 518 full-length pages;
-    # the step's 16-wide rows, and a one-token row
+    # of 65 pages (window 1,024 + a chunk) beside 518 full-length pages
+    # (neither a multiple of a group's 8); the step's 16-wide rows, and a
+    # one-token row
     for label, q_shape, slab, ppr, window in (
             ("window ring, chunk rows", (32, 32, 16, D), (32, 4, 1056, D),
              65, 1024),
@@ -179,6 +273,26 @@ def main() -> int:
             spec((rows, T, 128), jnp.bfloat16),
             spec((rows, 128, 8192), jnp.bfloat16), spec((rows,), jnp.int32),
             spec((rows,), jnp.int32), want={"ssm_update": 1}))
+    # the unified step of an engine, its layers unrolled: the kernel's
+    # jitted entry gives the lowered module one Mosaic body a distinct
+    # (shapes, window) pair, not one a layer (what every process traces
+    # and lowers before it can ask the compile cache)
+    import paddle_tpu as paddle
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(0)
+    for label, want, kw in (
+            ("3 full layers", 1, dict(num_hidden_layers=3)),
+            ("3 window layers + 1 full", 2, dict(
+                num_hidden_layers=4, sliding_window=128,
+                layer_types=["sliding_attention"] * 2 + ["full_attention"]
+                + ["sliding_attention"]))):
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=512, hidden_size=256, intermediate_size=512,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+            max_position_embeddings=1024, dtype="bfloat16", **kw))
+        model.eval()
+        results.append(serve_step_case(f"serve step, {label}", model,
+                                       dev1[0], want))
     from paddle_tpu.ops import pallas_mode
     for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
         print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
