@@ -9,7 +9,18 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-import jax as _jax
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()
+
+import jax as _jax  # noqa: E402
+
+from .profiler import SPAN_SETUP_IMPORT as _SPAN_IMPORT  # noqa: E402
+from .profiler import SetupSpan as _SetupSpan  # noqa: E402
+
+# this import, as the set-up ledger's first phase (on a profiler's trace
+# only the part from here on: a span needs jax)
+_import_span = _SetupSpan(_SPAN_IMPORT, t0=_IMPORT_T0).__enter__()
 
 # fp32 tensors must get true-fp32 matmul/conv accumulation (reference CUDA fp32
 # kernel semantics). jax's DEFAULT precision lowers fp32 matmuls to bf16 passes
@@ -152,3 +163,12 @@ def summary(net, input_size=None, dtypes=None):
     report = "\n".join(lines)
     print(report)
     return {"total_params": total, "trainable_params": trainable}
+
+
+# the set-up ledger (obs.goodput.CompileLedger): its jax.monitoring
+# listeners are registered here, once, so that no program the process
+# traces or compiles escapes it
+from .obs.goodput import register_listeners as _register  # noqa: E402
+
+_register()
+_import_span.end()

@@ -20,25 +20,29 @@ reserved. This module closes that gap:
   culprit leaf (``batch['x'].shape[0]: 32→48``). Recompiles are counted
   per culprit; a per-culprit storm (>= storm_threshold) logs a grouped
   warning, records a ``compile_storm`` event, and dumps the black box.
-- **Hooks** — signature capture rides ``utils/jit_cache.JitLRUCache``
-  builds (the cache key IS the abstract signature there) plus explicit
-  ``observe_call()`` wrappers in ``DeviceWorker``, ``ScanTrainStep``,
-  ``ShardedTrainStep``, the LLM engine's unified step, and
-  ``BatchingEngine`` predict — each costing exactly one
+- **Hooks** — explicit ``observe_call()`` wrappers in ``DeviceWorker``,
+  ``ScanTrainStep``, ``ShardedTrainStep``, the LLM engine's unified
+  step, and ``BatchingEngine`` predict — each costing exactly one
   ``is not None`` predicate when disabled (the PR 9 cost contract).
 - **Exposition** — ``GET /debug/compiles`` on both HTTP servers,
   ``pdtpu_compile_*`` Prometheus families, chrome ``compile/<callsite>``
   lanes, and a predicted-vs-measured HBM row reconciling
   ``memory_analysis()`` totals against the PR 10 HBMTelemetry watermark
-  (a ratio far from 1 means XLA's plan and the allocator disagree).
+  (a ratio far from 1 means XLA's plan and the allocator disagree). The
+  same payload carries the always-on set-up ledger
+  (``obs.goodput.CompileLedger``): ``programs``, every program the
+  process traced, lowered, compiled or loaded, by name, and ``setup``,
+  its totals and the start-up phases as of the first ``mark_warm()``.
 
 Analyses come from JAX's AOT path (``jit(f).lower(*args).compile()``
 then ``cost_analysis()`` / ``memory_analysis()``). The AOT compile is
 issued once per NEW fingerprint only, and only while the observatory is
 enabled; backends that share the XLA compilation cache pay nothing
 extra, others pay one bounded duplicate compile per distinct signature
-— the price of knowing what the program costs. Module import stays
-stdlib-only; jax is only touched inside the AOT helper.
+— the price of knowing what the program costs. What it took is read
+from the ledger's row for the function, not from a clock of its own.
+Module import stays stdlib-only; jax is only touched inside the AOT
+helper.
 """
 from __future__ import annotations
 
@@ -50,7 +54,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .flight_recorder import flight_recorder
-from .goodput import _emit_chrome_span
+from .goodput import CompileLedger, _emit_chrome_span, compile_ledger
 
 _log = logging.getLogger("paddle_tpu.compile_observatory")
 
@@ -188,10 +192,14 @@ def _aot_analyses(fn, args) -> Tuple[float, dict]:
     lower = getattr(fn, "lower", None)
     if lower is None:
         return 0.0, out
+    # what the build takes is what the set-up ledger books to the
+    # function's row while it runs: trace and lower (paid in every
+    # process before the compile cache can be asked) and the backend's
+    # compile or load, apart; the wall clock where jax names no such row
+    name = getattr(fn, "__name__", None)
+    before = compile_ledger().row(name) if name else None
     t0 = time.monotonic()
     try:
-        # apart: tracing and lowering run in every process before the
-        # compile cache can be asked, compiling only where it misses
         trace = getattr(fn, "trace", None)
         traced = trace(*args) if trace is not None else None
         t1 = time.monotonic()
@@ -201,9 +209,17 @@ def _aot_analyses(fn, args) -> Tuple[float, dict]:
     except Exception:
         _log.debug("AOT lower/compile failed", exc_info=True)
         return time.monotonic() - t0, out
-    seconds = time.monotonic() - t0
-    out["phase_seconds"] = {"trace": t1 - t0, "lower": t2 - t1,
-                            "compile": t0 + seconds - t2}
+    paid = {"trace": t1 - t0, "lower": t2 - t1,
+            "compile": time.monotonic() - t2}
+    after = compile_ledger().row(name) if name else None
+    if after is not None:
+        booked = {ph: after[key] - (before[key] if before else 0.0)
+                  for ph, key in (("trace", "trace_s"), ("lower", "lower_s"),
+                                  ("compile", "backend_s"))}
+        if booked["compile"] > 0:
+            paid = booked
+    out["phase_seconds"] = paid
+    seconds = sum(paid.values())
     try:
         cost = compiled.cost_analysis()
         if isinstance(cost, (list, tuple)):   # per-device on older jax
@@ -306,7 +322,6 @@ class CompileObservatory:
         self.recompiles = 0
         self.recompiles_by_culprit: Dict[str, int] = {}
         self._storm_warned: set = set()
-        self._jit_cache_hooked = False
 
     # ---- lifecycle ----
     @property
@@ -314,26 +329,14 @@ class CompileObservatory:
         return self._enabled
 
     def enable(self) -> "CompileObservatory":
-        """Arm signature capture; also rides every JitLRUCache build via
-        the miss-listener hook (the cache key is the signature there).
-        Idempotent."""
+        """Arm signature capture. Idempotent."""
         with self._lock:
-            if self._enabled:
-                return self
             self._enabled = True
-        from ..utils import jit_cache
-        if not self._jit_cache_hooked:
-            jit_cache.add_miss_listener(self._on_jit_cache_miss)
-            self._jit_cache_hooked = True
         return self
 
     def disable(self):
         with self._lock:
             self._enabled = False
-        if self._jit_cache_hooked:
-            from ..utils import jit_cache
-            jit_cache.remove_miss_listener(self._on_jit_cache_miss)
-            self._jit_cache_hooked = False
 
     def mark_warm(self):
         """Baseline: builds so far were warmup; any later build for an
@@ -381,9 +384,8 @@ class CompileObservatory:
                      seconds: float = 0.0,
                      static_hash: Optional[str] = None,
                      analyses: Optional[dict] = None) -> str:
-        """Register a build observed externally (e.g. a JitLRUCache
-        miss, where the build was already timed). Returns the
-        fingerprint; re-registering a known fingerprint is a no-op."""
+        """Register a build observed externally, already timed. Returns
+        the fingerprint; re-registering a known fingerprint is a no-op."""
         fp = fingerprint_of(signature, static_hash)
         with self._lock:
             if (callsite, fp) in self._records:
@@ -439,20 +441,6 @@ class CompileObservatory:
             flight_recorder().try_dump(reason="recompile_storm")
         return rec
 
-    # ---- jit-cache ride-along ----
-    def _on_jit_cache_miss(self, name: str, key, seconds: float):
-        """JitLRUCache miss listener: the cache key IS the abstract
-        signature for those executables (callers key builds by static
-        shapes/knobs), so it fingerprints and diffs like any other."""
-        if not self._enabled:
-            return
-        try:
-            self.record_build(f"jit_cache/{name}",
-                              signature_of(key, prefix="key"),
-                              seconds=seconds)
-        except Exception:
-            _log.debug("jit-cache ride-along failed", exc_info=True)
-
     # ---- dispatch accounting ----
     def note_device_seconds(self, callsite: str, seconds: float):
         """Attribute a dispatch's device span (launch to the end of the
@@ -477,7 +465,9 @@ class CompileObservatory:
                  hbm=None) -> dict:
         """The /debug/compiles payload: per-executable rows (sorted by
         compile seconds, then dispatches), totals, recompiles grouped by
-        culprit, and — when an HBMTelemetry is supplied — the
+        culprit, the set-up ledger (`programs`: its rows, slowest first;
+        `setup`: totals, phases and rows as of the first `mark_warm()`,
+        None before it), and — when an HBMTelemetry is supplied — the
         predicted-vs-measured HBM reconciliation row."""
         with self._lock:
             records = list(self._records.values())
@@ -502,6 +492,13 @@ class CompileObservatory:
             "recompiles_by_culprit": by_culprit,
             "rows": rows,
         }
+        ledger = compile_ledger()
+        now, at_warm = ledger.snapshot(), ledger.at_warm
+        out["programs"] = CompileLedger.slowest(now["rows"], top)
+        out["program_totals"] = {**now["totals"], "phases": now["phases"]}
+        out["setup"] = None if at_warm is None else {
+            "totals": at_warm["totals"], "phases": at_warm["phases"],
+            "programs": CompileLedger.slowest(at_warm["rows"], top)}
         if hbm is not None:
             out["hbm"] = self.reconcile_hbm(hbm, latest=latest)
         return out
@@ -537,9 +534,9 @@ class CompileObservatory:
         return row
 
     def render_prom(self) -> str:
-        """`pdtpu_compile_*` families; empty when nothing is registered
-        (so scrapes of processes that never armed the observatory are
-        byte-identical to before)."""
+        """`pdtpu_compile_*` families of the registry; empty when nothing
+        is registered (the ledger's families are `render_prom()`'s, with
+        or without an observatory)."""
         snap = self.snapshot()
         if not snap["rows"] and not snap["recompiles_by_culprit"]:
             return ""
@@ -614,13 +611,23 @@ def compile_observatory() -> CompileObservatory:
 
 
 def render_prom() -> str:
-    """Scrape-time helper for the HTTP servers: the global observatory's
-    `pdtpu_compile_*` exposition, or "" when it was never created or has
-    nothing registered — scrapes stay byte-identical for processes that
-    never armed it."""
+    """Scrape-time helper for the HTTP servers: the set-up ledger's
+    totals (no per-program label: cardinality), then the global
+    observatory's `pdtpu_compile_*` exposition where it was armed."""
+    from .prom import PromBuilder
+    totals = compile_ledger().snapshot(rows=False)["totals"]
+    b = PromBuilder()
+    for family, key in (
+            ("pdtpu_compile_trace_seconds_total", "trace_s"),
+            ("pdtpu_compile_lower_seconds_total", "lower_s"),
+            ("pdtpu_compile_backend_seconds_total", "backend_s"),
+            ("pdtpu_compile_cache_hits_total", "cache_hits"),
+            ("pdtpu_compile_cache_misses_total", "cache_misses")):
+        b.family(family, "counter")
+        b.sample(family, totals[key])
     with _GLOBAL_LOCK:
         inst = _GLOBAL
-    return inst.render_prom() if inst is not None else ""
+    return b.render() + (inst.render_prom() if inst is not None else "")
 
 
 def culprit_summary(limit: int = 3) -> str:

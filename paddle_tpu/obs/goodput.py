@@ -29,12 +29,17 @@ On top of the ledger:
 - **live MFU** — `flops_per_step x productive_steps / wall / peak`,
   with the FLOPs arithmetic taken from obs.flops (the benchmark's
   `train_mfu_pct` counts attention too: benchmark/kernel_costs.py);
-- **RecompileSentinel** — counts XLA compilations (jax.monitoring's
-  ``/jax/core/compile/backend_compile_duration`` where available,
-  JitLRUCache miss hooks otherwise), books compile time as
-  non-productive, and treats any compilation after ``mark_warm()`` as a
-  recompile: each drops a ``train_recompile`` flight-recorder event and
-  a storm (>= storm_threshold recompiles) logs a warning;
+- **CompileLedger** — process-wide and always on: every program's
+  trace, lower and compile-or-load seconds by name (jax.monitoring's
+  three ``/jax/core/compile/*_duration`` events), cache hits and
+  misses, the program's own start-up phases, and all of it as it stood
+  when the process turned warm (``at_warm``);
+- **RecompileSentinel** — counts backend builds (compiles, and loads
+  from the persistent cache beside them), books their time as
+  non-productive, and treats any build after ``mark_warm()`` as a
+  recompile: each drops a ``train_recompile`` flight-recorder event
+  naming the program, and a storm (>= storm_threshold recompiles) logs
+  a warning;
 - **HBMTelemetry** — ``device.memory_stats()`` watermark gauges with
   params/opt-state/KV-slab attribution, and ``oom_forensics`` which
   turns a RESOURCE_EXHAUSTED failure into a ``train_oom`` flight event
@@ -45,7 +50,7 @@ pays exactly one predicate per hook (`if ledger is not None:`) — no
 clock read, no allocation, no lock.
 
 Module import stays stdlib-only; jax and paddle_tpu.utils are imported
-lazily inside ``RecompileSentinel.install`` / the default HBM stats fn.
+lazily inside ``register_listeners`` / the default HBM stats fn.
 """
 from __future__ import annotations
 
@@ -53,6 +58,7 @@ import contextlib
 import logging
 import threading
 import time
+from collections import deque
 from typing import Callable, Dict, List, Optional
 
 from .flight_recorder import flight_recorder
@@ -63,9 +69,6 @@ _log = logging.getLogger("paddle_tpu.goodput")
 PHASES = ("compute", "rollback_waste", "data_wait", "h2d", "compile",
           "checkpoint", "idle")
 
-# the jax.monitoring event that fires once per XLA backend compile
-# (cache hits do not fire it)
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class PhaseLedger:
@@ -265,54 +268,320 @@ def _emit_chrome_span(lane: str, t_in: float, t_out: float):
     }])
 
 
-# ---- recompile sentinel ----
+# ---- the set-up ledger and the recompile sentinel ----
 #
-# jax.monitoring listeners cannot be unregistered through public API, so
-# ONE module-level dispatcher is registered (at most once per process)
-# and fans out to whichever sentinels are currently installed. The
-# jit-cache fallback mirrors the same shape: one module-level miss
-# listener fanning out, never a per-sentinel registration. Each
-# dispatcher only feeds sentinels installed on ITS source, and "auto"
-# resolution is pinned process-wide on first use — a JitLRUCache build
-# that also fires jax's backend_compile event can therefore never reach
-# the same sentinel through both paths (ISSUE 12 satellite: the
-# double-counting fix).
+# JAX (pinned at a version that has them) emits for every program three
+# duration events that carry `fun_name` — the trace to a jaxpr, the
+# lowering to an MLIR module, and the backend's compile *or load*: the
+# third wraps `compiler.compile_or_get_cached`, so a hit in the persistent
+# cache fires it too, with `cache_hits` and `cache_retrieval_time_sec`
+# fired inside it on the same thread. ONE dispatcher per listener kind is
+# registered, once per process (`register_listeners`, called by the last
+# line of `paddle_tpu/__init__.py`, so that no program escapes); it feeds
+# the process-wide `CompileLedger` and fans a backend event out, with its
+# program's name, to whichever sentinels are installed. The listeners run
+# only when something is traced, lowered or compiled: never in a warm step.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# fires once per backend compile AND once per load of a cached executable
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_PHASE_OF = {TRACE_EVENT: "trace", LOWER_EVENT: "lower",
+             COMPILE_EVENT: "backend"}
+
+
+def program_key(fun_name) -> str:
+    """The ledger's key for a program: the traced function's name. The
+    trace event carries it bare (`step`), the lower and backend events as
+    the module's name (`jit(step)`), the device trace as `jit_step`."""
+    name = str(fun_name) if fun_name else "<unnamed>"
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    if name.startswith("jit_"):
+        return name[4:]
+    return name
+
+
+class _Program:
+    """One row of the ledger. `*_s` are inclusive seconds, `*_self_s` the
+    same less what events nested inside them (a `jax.jit` traced inside
+    another, an eager op compiled while tracing) reported themselves."""
+
+    __slots__ = ("traces", "trace_s", "lower_s", "backend_s",
+                 "trace_self_s", "lower_self_s", "backend_self_s",
+                 "cache_hits", "cache_misses", "retrieval_s",
+                 "first_seen", "last_seen", "first_call_s",
+                 "pending_trace_s", "pending_lower_s")
+
+    def __init__(self, now: float):
+        self.traces = self.cache_hits = self.cache_misses = 0
+        self.trace_s = self.lower_s = self.backend_s = 0.0
+        self.trace_self_s = self.lower_self_s = self.backend_self_s = 0.0
+        self.retrieval_s = 0.0
+        self.first_seen = self.last_seen = now
+        self.first_call_s: Optional[float] = None
+        # trace and lower seconds since the row's last backend event:
+        # what the next build of this program paid before its compile
+        self.pending_trace_s = self.pending_lower_s = 0.0
+
+    def to_dict(self) -> dict:
+        out = {k: getattr(self, k) for k in self.__slots__
+               if not k.startswith("pending_")}
+        for k, v in out.items():
+            if isinstance(v, float):
+                out[k] = round(v, 6)
+        return out
+
+
+class CompileLedger:
+    """Where set-up went, by program: every executable's trace, lower and
+    compile-or-load seconds by name, the program's own start-up phases,
+    and what both were when the process turned warm.
+
+    Rows are keyed by `program_key(fun_name)`, at most `MAX_ROWS` of them
+    (a set-up has hundreds of small eager-op programs, and every `jax.jit`
+    traced inside another reports its own trace): later names fold into
+    `<other>` with their count. Totals are sums of SELF time, so nothing
+    nested is counted twice: the time-span listener sees a thread's spans
+    in the order they end, a child before its parent, and a span's self
+    time is its length less the spans that ended inside it.
+
+    `freeze()` (first `RecompileSentinel.mark_warm()` of the process)
+    copies totals, phases and rows to `at_warm`; whatever is traced or
+    compiled afterwards is a recompile with a name."""
+
+    MAX_ROWS = 512
+    OTHER = "<other>"
+    # a thread's finished spans that a span still open may contain
+    _MAX_PENDING_SPANS = 4096
+    _MAX_FOLDED = 8192    # names counted exactly beyond the rows' cap
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        self.reset()
+
+    def reset(self):
+        """Forget everything, `at_warm` too (tests; a process has one
+        set-up)."""
+        with self._lock:
+            self._rows: Dict[str, _Program] = {}
+            self._folded: set = set()   # names that share the OTHER row
+            self.totals = {"trace_s": 0.0, "lower_s": 0.0,
+                           "backend_s": 0.0, "cache_hits": 0,
+                           "cache_misses": 0}
+            self.phases: Dict[str, float] = {}
+            self.at_warm: Optional[dict] = None
+
+    # ---- fed by the dispatchers ----
+    def _row_locked(self, key: str, now: float) -> _Program:
+        row = self._rows.get(key)
+        if row is None:
+            if len(self._rows) < self.MAX_ROWS - 1:
+                row = self._rows[key] = _Program(now)
+            else:
+                if len(self._folded) < self._MAX_FOLDED:
+                    self._folded.add(key)
+                row = self._rows.get(self.OTHER)
+                if row is None:
+                    row = self._rows[self.OTHER] = _Program(now)
+        return row
+
+    def on_duration(self, event: str, seconds: float, fun_name=None,
+                    **_kw) -> Optional[dict]:
+        """One duration event. Returns, for a backend event, what that
+        build paid (`fun_name`, `loaded`, the trace and lower seconds
+        before it): the sentinels' feed."""
+        phase = _PHASE_OF.get(event)
+        if phase is None:
+            if event == CACHE_RETRIEVAL_EVENT:
+                self._tls.retrieval_s = seconds
+            return None
+        seconds = max(float(seconds), 0.0)
+        key = program_key(fun_name)
+        now = time.time()
+        with self._lock:
+            row = self._row_locked(key, now)
+            row.last_seen = now
+            if phase == "trace":
+                row.traces += 1
+                row.trace_s += seconds
+                row.pending_trace_s += seconds
+                return None
+            if phase == "lower":
+                row.lower_s += seconds
+                row.pending_lower_s += seconds
+                return None
+            tls = self._tls
+            loaded = bool(getattr(tls, "hit", False))
+            tls.hit = False
+            row.backend_s += seconds
+            if loaded:
+                row.cache_hits += 1
+                row.retrieval_s += getattr(tls, "retrieval_s", 0.0)
+                self.totals["cache_hits"] += 1
+            else:
+                row.cache_misses += 1
+                self.totals["cache_misses"] += 1
+            tls.retrieval_s = 0.0
+            build = {"fun_name": key, "loaded": loaded,
+                     "trace_s": row.pending_trace_s,
+                     "lower_s": row.pending_lower_s}
+            row.pending_trace_s = row.pending_lower_s = 0.0
+        return build
+
+    def on_span(self, event: str, start: float, end: float, fun_name=None,
+                **_kw):
+        """One time span: the event's self time, into its row and the
+        totals."""
+        phase = _PHASE_OF.get(event)
+        if phase is None:
+            return
+        done = getattr(self._tls, "done", None)
+        if done is None:
+            done = self._tls.done = deque(maxlen=self._MAX_PENDING_SPANS)
+        inner = 0.0
+        while done and done[-1][0] >= start:
+            s, e = done.pop()
+            inner += e - s
+        done.append((start, end))
+        self_s = max(end - start - inner, 0.0)
+        key = program_key(fun_name)
+        with self._lock:
+            row = self._row_locked(key, start)
+            if phase == "trace":
+                row.trace_self_s += self_s
+            elif phase == "lower":
+                row.lower_self_s += self_s
+            else:
+                row.backend_self_s += self_s
+            self.totals[phase + "_s"] += self_s
+
+    def on_event(self, event: str, **_kw):
+        """A plain event: a hit in the persistent cache, fired inside the
+        backend event it belongs to, on its thread."""
+        if event == CACHE_HIT_EVENT:
+            self._tls.hit = True
+
+    def add_phase(self, name: str, seconds: float,
+                  program: Optional[str] = None):
+        """A start-up phase of the program's own (`profiler.SetupSpan`):
+        `phases[name]` grows by `seconds`; with `program`, that row's
+        `first_call_s` is set if it was not."""
+        seconds = max(float(seconds), 0.0)
+        with self._lock:
+            self.phases[name] = self.phases.get(name, 0.0) + seconds
+            if program is not None:
+                row = self._row_locked(program_key(program), time.time())
+                if row.first_call_s is None:
+                    row.first_call_s = seconds
+
+    # ---- read ----
+    def freeze(self) -> dict:
+        """The set-up as of the first call; later calls leave it."""
+        with self._lock:
+            if self.at_warm is None:
+                self.at_warm = self._snapshot_locked()
+            return self.at_warm
+
+    def _snapshot_locked(self, rows: bool = True) -> dict:
+        totals = {k: round(v, 6) if isinstance(v, float) else v
+                  for k, v in self.totals.items()}
+        totals["programs"] = len(self._rows) - (self.OTHER in self._rows) \
+            + len(self._folded)
+        out = {"totals": totals, "time": time.time(),
+               "phases": {k: round(v, 6) for k, v in self.phases.items()}}
+        if rows:
+            out["rows"] = {k: r.to_dict() for k, r in self._rows.items()}
+            if self.OTHER in out["rows"]:
+                out["rows"][self.OTHER]["programs"] = len(self._folded)
+        return out
+
+    def snapshot(self, rows: bool = True) -> dict:
+        """Totals, phases and (unless `rows` is False: a scrape) rows as
+        they stand."""
+        with self._lock:
+            return self._snapshot_locked(rows)
+
+    def row(self, fun_name) -> Optional[dict]:
+        """The row of one program as it stands, or None."""
+        with self._lock:
+            row = self._rows.get(program_key(fun_name))
+            return row.to_dict() if row is not None else None
+
+    @staticmethod
+    def slowest(rows: Dict[str, dict], top: Optional[int] = None) -> list:
+        """`rows` as a list, each with its `program`, slowest first by
+        self time."""
+        out = [{"program": k, **r} for k, r in rows.items()]
+        out.sort(key=lambda r: -(r["trace_self_s"] + r["lower_self_s"]
+                                 + r["backend_self_s"]))
+        return out if top is None else out[:top]
+
+
+_LEDGER = CompileLedger()
 _DISPATCH_LOCK = threading.Lock()
 _ACTIVE_SENTINELS: set = set()
-_MONITORING_REGISTERED = False
-_JIT_CACHE_REGISTERED = False
-_PROCESS_SOURCE: Optional[str] = None   # pinned by the first "auto" install
+_LISTENERS_REGISTERED = False
 
 
-def _monitoring_dispatch(event: str, duration: float, **_kw):
-    if event != COMPILE_EVENT:
+def compile_ledger() -> CompileLedger:
+    """The process-wide set-up ledger, always on."""
+    return _LEDGER
+
+
+# the dispatchers look `_LEDGER` up when called: a test may stand a fresh
+# ledger in its place for its own length
+def _span_dispatch(event: str, start: float, end: float, **kw):
+    _LEDGER.on_span(event, start, end, **kw)
+
+
+def _event_dispatch(event: str, **kw):
+    _LEDGER.on_event(event, **kw)
+
+
+def _duration_dispatch(event: str, duration: float, **kw):
+    build = _LEDGER.on_duration(event, duration, **kw)
+    if build is None:
         return
     with _DISPATCH_LOCK:
-        active = [s for s in _ACTIVE_SENTINELS
-                  if s.installed == "monitoring"]
+        active = list(_ACTIVE_SENTINELS)
     for s in active:
-        s.on_compile(duration)
+        s.on_compile(duration, **build)
 
 
-def _jit_cache_dispatch(name, key, seconds):
+def register_listeners():
+    """Register the three dispatchers with `jax.monitoring`, once, for
+    the life of the process."""
+    global _LISTENERS_REGISTERED
     with _DISPATCH_LOCK:
-        active = [s for s in _ACTIVE_SENTINELS
-                  if s.installed == "jit_cache"]
-    for s in active:
-        s.on_compile(seconds)
+        if _LISTENERS_REGISTERED:
+            return
+        _LISTENERS_REGISTERED = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_duration_dispatch)
+    jax.monitoring.register_event_time_span_listener(_span_dispatch)
+    jax.monitoring.register_event_listener(_event_dispatch)
 
 
 class RecompileSentinel:
-    """Counts XLA compilations and alarms on post-warmup recompiles.
+    """Counts backend builds and alarms on post-warmup recompiles.
 
-    Compilations during warmup (before `mark_warm()`) are expected; any
-    compile after it means the step function's static shapes churned —
-    each one drops a `train_recompile` flight-recorder event, and
-    reaching `storm_threshold` recompiles logs a warning naming the
-    count (shape churn is fixed at the call site, not hidden). Compile
-    seconds are booked to the ledger's `compile` phase so they are
-    subtracted from productive compute.
+    Builds during warmup (before `mark_warm()`) are expected; any build
+    after it, compiled or loaded from the persistent cache, means the
+    step function's static shapes churned — each one counts in
+    `recompiles`, drops a `train_recompile` flight-recorder event naming
+    the program (`fun_name`), which of trace / lower / backend it paid
+    and whether it was `loaded` or `compiled`, and is kept by name in
+    `recompiled` (newest last); reaching `storm_threshold` recompiles
+    logs a warning naming them (shape churn is fixed at the call site,
+    not hidden). `compiles` counts real compiles, `loads` the builds the
+    persistent cache answered; both book their seconds to the ledger's
+    `compile` phase so they are subtracted from productive compute.
     """
+
+    KEEP_RECOMPILED = 32
 
     def __init__(self, ledger: Optional[GoodputLedger] = None,
                  storm_threshold: int = 3):
@@ -322,37 +591,61 @@ class RecompileSentinel:
         self.ledger = ledger
         self.storm_threshold = int(storm_threshold)
         self.compiles = 0
+        self.loads = 0
         self.compile_seconds = 0.0
         self.recompiles = 0
-        self.installed: Optional[str] = None  # "monitoring" | "jit_cache"
+        self.recompiled: List[dict] = []
+        self.installed = False
         self._warm = False
         self._storm_warned = False
         self._lock = threading.Lock()
 
     def mark_warm(self):
-        """Baseline: compilations so far were warmup, later ones are not."""
+        """Baseline: builds so far were warmup, later ones are not. The
+        first call of a process also freezes the set-up ledger
+        (`compile_ledger().at_warm`)."""
         with self._lock:
             self._warm = True
+        _LEDGER.freeze()
 
-    def on_compile(self, seconds: float = 0.0):
+    def on_compile(self, seconds: float = 0.0, fun_name: str = "<unnamed>",
+                   loaded: bool = False, trace_s: float = 0.0,
+                   lower_s: float = 0.0):
+        """One backend event: `seconds` of compile, or of load where
+        `loaded`; `trace_s` / `lower_s` are what the program paid before
+        it."""
         seconds = max(float(seconds), 0.0)
+        how = "loaded" if loaded else "compiled"
+        paid = "+".join(p for p, s in (("trace", trace_s), ("lower", lower_s),
+                                       ("backend", seconds)) if s > 0)
         with self._lock:
-            self.compiles += 1
+            if loaded:
+                self.loads += 1
+            else:
+                self.compiles += 1
             self.compile_seconds += seconds
             is_recompile = self._warm
             if is_recompile:
                 self.recompiles += 1
+                self.recompiled.append(
+                    {"fun_name": fun_name, "how": how, "paid": paid,
+                     "seconds": round(seconds + trace_s + lower_s, 6)})
+                del self.recompiled[:-self.KEEP_RECOMPILED]
             count = self.recompiles
             storm = (is_recompile and count >= self.storm_threshold
                      and not self._storm_warned)
             if storm:
                 self._storm_warned = True
+                names = ", ".join(f"{r['fun_name']} ({r['how']})"
+                                  for r in self.recompiled)
         if self.ledger is not None:
             self.ledger.book("compile", seconds)
         if is_recompile:
             flight_recorder().record(
-                "train_recompile", recompiles=count,
-                seconds=round(seconds, 6), storm=storm)
+                "train_recompile", recompiles=count, fun_name=fun_name,
+                how=how, paid=paid, seconds=round(seconds, 6),
+                trace_seconds=round(trace_s, 6),
+                lower_seconds=round(lower_s, 6), storm=storm)
             if storm:
                 # the compile observatory (when armed) knows WHICH leaf
                 # churned; grouping by culprit turns "3 recompiles" into
@@ -360,88 +653,32 @@ class RecompileSentinel:
                 from .compile_observatory import culprit_summary
                 grouped = culprit_summary()
                 _log.warning(
-                    "recompile storm: %d XLA compilations after warmup "
-                    "(threshold %d) — the step fn's static shapes are "
-                    "churning; bucket the shapes at the call site%s",
-                    count, self.storm_threshold,
+                    "recompile storm: %d builds after warmup (threshold "
+                    "%d): %s — the step fn's static shapes are churning; "
+                    "bucket the shapes at the call site%s",
+                    count, self.storm_threshold, names,
                     f" (recompiles by culprit: {grouped})" if grouped
                     else "")
 
-    # jit-cache fallback: JitLRUCache miss listeners carry (name, key,
-    # build_seconds). Kept for back-compat with callers that registered
-    # the bound method directly; the install() path now routes through
-    # the module-level _jit_cache_dispatch instead.
-    def _on_cache_miss(self, name, key, seconds):
-        self.on_compile(seconds)
-
-    def install(self, source: str = "auto") -> "RecompileSentinel":
-        """Start observing compilations. `source`: "monitoring" (jax's
-        per-compile event), "jit_cache" (JitLRUCache miss hooks), or
-        "auto" (monitoring where available, cache hooks otherwise —
-        resolved ONCE per process so both sources can never observe the
-        same build)."""
-        global _MONITORING_REGISTERED, _JIT_CACHE_REGISTERED
-        global _PROCESS_SOURCE
-        if self.installed is not None:
-            return self
-        if source == "auto":
-            with _DISPATCH_LOCK:
-                if _PROCESS_SOURCE is not None:
-                    source = _PROCESS_SOURCE
-        if source in ("auto", "monitoring"):
-            try:
-                import jax.monitoring
-                with _DISPATCH_LOCK:
-                    if not _MONITORING_REGISTERED:
-                        jax.monitoring \
-                            .register_event_duration_secs_listener(
-                                _monitoring_dispatch)
-                        _MONITORING_REGISTERED = True
-                    # installed is tagged before the sentinel joins the
-                    # set: the dispatchers filter on it, and an untagged
-                    # member would be invisible to both
-                    self.installed = "monitoring"
-                    _ACTIVE_SENTINELS.add(self)
-                    if _PROCESS_SOURCE is None:
-                        _PROCESS_SOURCE = "monitoring"
-                return self
-            except Exception:
-                if source == "monitoring":
-                    raise
-        from ..utils import jit_cache
+    def install(self) -> "RecompileSentinel":
+        """Start observing backend events (idempotent)."""
         with _DISPATCH_LOCK:
-            if not _JIT_CACHE_REGISTERED:
-                jit_cache.add_miss_listener(_jit_cache_dispatch)
-                _JIT_CACHE_REGISTERED = True
-            self.installed = "jit_cache"
+            self.installed = True
             _ACTIVE_SENTINELS.add(self)
-            if _PROCESS_SOURCE is None and source == "auto":
-                _PROCESS_SOURCE = "jit_cache"
         return self
 
     def uninstall(self):
-        global _JIT_CACHE_REGISTERED
         with _DISPATCH_LOCK:
-            was = self.installed
-            self.installed = None
+            self.installed = False
             _ACTIVE_SENTINELS.discard(self)
-            # the monitoring listener cannot be unregistered (jax has no
-            # API for it); the jit-cache one can, so drop it when the
-            # last jit_cache sentinel leaves
-            drop = (was == "jit_cache" and _JIT_CACHE_REGISTERED
-                    and not any(s.installed == "jit_cache"
-                                for s in _ACTIVE_SENTINELS))
-            if drop:
-                _JIT_CACHE_REGISTERED = False
-        if drop:
-            from ..utils import jit_cache
-            jit_cache.remove_miss_listener(_jit_cache_dispatch)
 
     def snapshot(self) -> dict:
         with self._lock:
             return {"compiles": self.compiles,
+                    "loads": self.loads,
                     "recompiles": self.recompiles,
-                    "compile_seconds": self.compile_seconds}
+                    "compile_seconds": self.compile_seconds,
+                    "recompiled": list(self.recompiled)}
 
 
 # ---- HBM telemetry ----

@@ -245,6 +245,8 @@ class TrainingMetrics:
             r = s["recompile"]
             b.family(f"{px}_compiles_total", "counter")
             b.sample(f"{px}_compiles_total", r["compiles"])
+            b.family(f"{px}_compile_loads_total", "counter")
+            b.sample(f"{px}_compile_loads_total", r["loads"])
             b.family(f"{px}_recompiles_total", "counter")
             b.sample(f"{px}_recompiles_total", r["recompiles"])
             b.family(f"{px}_compile_seconds_total", "counter")
@@ -315,8 +317,9 @@ class MetricsServer:
             def do_GET(self):
                 if self.path == "/metrics":
                     text = "".join(fn() for fn in render_fns)
-                    # pdtpu_compile_* families ride the same scrape; ""
-                    # unless the process armed the observatory (ISSUE 12)
+                    # pdtpu_compile_* families ride the same scrape: the
+                    # set-up ledger's totals always, the observatory's
+                    # registry where the process armed it (ISSUE 12)
                     from .compile_observatory import \
                         render_prom as _compile_render_prom
                     text += _compile_render_prom()

@@ -28,7 +28,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer
-from ..profiler import SPAN_TRAIN_CHUNK_DISPATCH, RecordEvent
+from ..profiler import (SPAN_SETUP_FIRST_STEP, SPAN_SETUP_PARALLELIZE,
+                        SPAN_TRAIN_CHUNK_DISPATCH, RecordEvent, SetupSpan)
 
 # The scan chunk's inner function name. XLA names the executable
 # `jit_<this>`, and that name is how the benchmark finds the train step in a
@@ -498,6 +499,7 @@ class ShardedTrainStep:
         # or an update, so the census runs on the exact params that blew up
         self._compute_loss_fn = compute_loss
         self._blame_jitted = None
+        self._ran = False      # the step has had its first call
         self._param_sizes = {k: int(np.prod(v.shape)) or 1
                              for k, v in params.items()}
 
@@ -684,12 +686,26 @@ class ShardedTrainStep:
                 (self._params, opt_in, self._buffers, self._extras, lr,
                  step, rng, tuple(arrays)))
         (loss, self._params, opt_out, self._buffers,
-         self._extras) = self._jitted(
+         self._extras) = self._run(self._jitted)(
             self._params, opt_in, self._buffers, self._extras, lr,
             step, rng, tuple(arrays))
         self._opt_state = (jax.device_put(opt_out, self._opt_host_sh)
                            if self._offload else opt_out)
         return Tensor(loss)
+
+    def _run(self, jitted):
+        """`jitted` itself, but for the step's first call: that one is
+        awaited inside the set-up ledger's `first_step` phase (trace,
+        lower, compile or load, and the first run)."""
+        if self._ran:
+            return jitted
+        self._ran = True
+
+        def first(*args):
+            with SetupSpan(SPAN_SETUP_FIRST_STEP,
+                           program=getattr(jitted, "__name__", None)):
+                return jax.block_until_ready(jitted(*args))
+        return first
 
     def _spec_for(self, arr):
         """Per-array data sharding: the sep (token) axis only applies to
@@ -969,7 +985,7 @@ class ScanTrainStep(ShardedTrainStep):
                 (self._params, opt_in, self._buffers, self._extras, lr_vec,
                  steps_vec, self._base_rng, tuple(arrays)))
         (losses, self._params, opt_out, self._buffers,
-         self._extras) = self._chunk_jitted(
+         self._extras) = self._run(self._chunk_jitted)(
             self._params, opt_in, self._buffers, self._extras, lr_vec,
             steps_vec, self._base_rng, tuple(arrays))
         self.dispatch_count += 1
@@ -986,6 +1002,11 @@ def parallelize(model: Layer, optimizer=None, mesh: Optional[Mesh] = None,
     DistributedStrategy flags are resolved by StrategyCompiler (the
     meta-optimizer composition analog) and executed by the returned step.
     """
+    with SetupSpan(SPAN_SETUP_PARALLELIZE):
+        return _parallelize(model, optimizer, mesh, strategy, loss_fn)
+
+
+def _parallelize(model, optimizer, mesh, strategy, loss_fn):
     from ..distributed.topology import get_mesh
     from ..distributed.fleet.strategy_compiler import StrategyCompiler
     if mesh is None:

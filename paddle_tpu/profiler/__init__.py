@@ -49,10 +49,20 @@ SPAN_SERVE_COMMIT = "pdtpu/serve/commit"          # acceptance .. retire
 SPAN_SERVE_PUBLISH = "pdtpu/serve/publish"        # gauges after the step
 SPAN_TRAIN_BATCH_WAIT = "pdtpu/train/batch_wait"  # ChunkPrefetcher get
 SPAN_TRAIN_CHUNK_DISPATCH = "pdtpu/train/chunk_dispatch"  # ScanTrainStep call
+# start-up phases (`SetupSpan`): each also adds its seconds to
+# `obs.goodput.compile_ledger().phases[<last word>]`, because a profiler
+# session rarely covers a start-up
+SPAN_SETUP_IMPORT = "pdtpu/setup/import"          # `import paddle_tpu`
+SPAN_SETUP_ENGINE_INIT = "pdtpu/setup/engine_init"  # LLMEngine.__init__
+SPAN_SETUP_PARALLELIZE = "pdtpu/setup/parallelize"  # parallelize() -> step
+SPAN_SETUP_FIRST_STEP = "pdtpu/setup/first_step"  # the step's first call,
+#                                                   launch to result; program=
 SERVE_SPANS = (SPAN_SERVE_PUMP, SPAN_SERVE_ADMIT, SPAN_SERVE_EVICT,
                SPAN_SERVE_DRAFT, SPAN_SERVE_BUILD_ROWS, SPAN_SERVE_DISPATCH,
                SPAN_SERVE_FETCH, SPAN_SERVE_COMMIT, SPAN_SERVE_PUBLISH)
 TRAIN_SPANS = (SPAN_TRAIN_BATCH_WAIT, SPAN_TRAIN_CHUNK_DISPATCH)
+SETUP_SPANS = (SPAN_SETUP_IMPORT, SPAN_SETUP_ENGINE_INIT,
+               SPAN_SETUP_PARALLELIZE, SPAN_SETUP_FIRST_STEP)
 
 
 class _ProfSink:
@@ -104,6 +114,10 @@ class RecordEvent:
         self._open = False
         self._ann = jax.profiler.TraceAnnotation(name, **args)
 
+    # False: the span takes a parent but is nobody's, so it may end after
+    # spans that began inside it (SetupSpan)
+    _nests = True
+
     def __enter__(self):
         self._ann.__enter__()
         self._open = True
@@ -111,7 +125,8 @@ class RecordEvent:
             stack = _T.stack
             self._parent = stack[-1] if stack else 0
             self._id = next(_SPAN_IDS)
-            stack.append(self._id)
+            if self._nests:
+                stack.append(self._id)
             self.begin = time.perf_counter_ns()
         return self
 
@@ -140,6 +155,40 @@ class RecordEvent:
         }
         with _SINK.lock:
             _SINK.events.append(evt)
+
+
+class SetupSpan(RecordEvent):
+    """A span of `SETUP_SPANS`: a `RecordEvent` that also adds its seconds
+    to the set-up ledger's phase of the same last word, and, given
+    `program` (the name of the jitted function whose first call it times),
+    to that program's row. `t0` is the `perf_counter` reading the phase
+    began at, where that was before a span could be made (`import`). It
+    is no span's parent, so it need not end in the order it began: the
+    engine's `first_step` begins in a `dispatch` and ends after the
+    `fetch` that follows. A set-up path: never entered in a warm step."""
+
+    __slots__ = ("_t0", "_program")
+    _nests = False
+
+    def __init__(self, name: str, program: Optional[str] = None,
+                 t0: Optional[float] = None):
+        super().__init__(name, **({"program": program} if program else {}))
+        self._program = program
+        self._t0 = t0
+
+    def __enter__(self):
+        if self._t0 is None:
+            self._t0 = time.perf_counter()
+        return super().__enter__()
+
+    def end(self):
+        if not self._open:
+            return
+        super().end()
+        from ..obs.goodput import compile_ledger
+        compile_ledger().add_phase(self.name.rsplit("/", 1)[-1],
+                                   time.perf_counter() - self._t0,
+                                   self._program)
 
 
 def record_instant(name: str, args: Optional[dict] = None):

@@ -160,7 +160,9 @@ from ...ops.attention import token_pack
 from ...profiler import (SPAN_SERVE_ADMIT, SPAN_SERVE_BUILD_ROWS,
                          SPAN_SERVE_COMMIT, SPAN_SERVE_DISPATCH,
                          SPAN_SERVE_DRAFT, SPAN_SERVE_FETCH,
-                         SPAN_SERVE_PUBLISH, SPAN_SERVE_PUMP, RecordEvent)
+                         SPAN_SERVE_PUBLISH, SPAN_SERVE_PUMP,
+                         SPAN_SETUP_ENGINE_INIT, SPAN_SETUP_FIRST_STEP,
+                         RecordEvent, SetupSpan)
 from ..clock import Clock, MonotonicClock, SimClock
 from ..engine import DeadlineExceededError, RejectedError
 from ..metrics import LLMMetrics, SLO_CLASSES
@@ -564,6 +566,12 @@ class LLMEngine:
                  fault_plan=None,
                  on_break: Optional[Callable[[], None]] = None,
                  draft_model=None):
+        with SetupSpan(SPAN_SETUP_ENGINE_INIT):
+            self._init(model, config, clock, metrics, fault_plan, on_break,
+                       draft_model)
+
+    def _init(self, model, config, clock, metrics, fault_plan, on_break,
+              draft_model):
         from ...models.generation import make_decoder_fns, make_verify_fn
         self.model = model
         model.eval()
@@ -735,6 +743,8 @@ class LLMEngine:
         self._brownout = False
         self._thread: Optional[threading.Thread] = None
         self._step_jit = None        # the ONE unified step executable
+        self._dispatch_step = self._first_dispatch
+        self._first_step_span: Optional[SetupSpan] = None
         # the step that is launched and not yet retired (`_StepInFlight`),
         # or None: owned by whoever runs `pump()`. `_no_sel` stands in for
         # a predecessor's selections on a step that feeds nothing back
@@ -1120,6 +1130,23 @@ class LLMEngine:
             return fn(*args)
 
         return self.supervisor.run(guarded, label=label, exempt=exempt)
+
+    def _first_dispatch(self, kinds, fn, args):
+        """The unified step's first call. Its trace, lower, compile or
+        load and first run are the set-up ledger's `first_step` phase:
+        the span begins here and `_retire` ends it once that step's
+        result is on the host. Every later step goes to `_run_dispatch`
+        directly."""
+        span = SetupSpan(SPAN_SETUP_FIRST_STEP,
+                         program=getattr(fn, "__name__", None)).__enter__()
+        try:
+            out = self._run_dispatch(kinds, fn, args)
+        except BaseException:
+            span.end()
+            raise
+        self._first_step_span = span
+        self._dispatch_step = self._run_dispatch
+        return out
 
     def _free_row_locked(self, req: "_GenRequest", slot: int):
         """Free a request's target-pool row AND its draft-pool row (ISSUE
@@ -2935,7 +2962,7 @@ class LLMEngine:
                         tc0 = self.clock.now()
                     try:
                         nxt, lps, new_dstate, new_slabs, *moe_out = \
-                            self._run_dispatch(kinds, fn, args)
+                            self._dispatch_step(kinds, fn, args)
                     except DispatchFailedError as e:
                         last_err = e
                         self.metrics.on_dispatch_failure(e.reason)
@@ -2999,6 +3026,9 @@ class LLMEngine:
             nxt = np.asarray(rec.nxt)   # [N, C] per-position tokens
             lps = np.asarray(rec.lps)   # [N, C] per-position logprobs
             new_dstate = np.asarray(rec.new_dstate)  # [N] DFA states
+        if self._first_step_span is not None:
+            self._first_step_span.end()
+            self._first_step_span = None
         now = self.clock.now()
         with RecordEvent(SPAN_SERVE_COMMIT):
             if rec.sampled_rows:

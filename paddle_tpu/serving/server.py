@@ -228,8 +228,9 @@ class ServingServer:
                     text = "".join(e.metrics.render() for e in
                                    (outer.engine, outer.llm_engine)
                                    if e is not None)
-                    # pdtpu_compile_* families ride the same scrape; ""
-                    # unless some engine armed the observatory (ISSUE 12)
+                    # pdtpu_compile_* families ride the same scrape: the
+                    # set-up ledger's totals always, the observatory's
+                    # registry where some engine armed it (ISSUE 12)
                     from ..obs.compile_observatory import \
                         render_prom as _compile_render_prom
                     text += _compile_render_prom()
@@ -261,7 +262,9 @@ class ServingServer:
                     # executable (fingerprint, compile seconds, AOT
                     # cost/memory analyses, dispatches, device-seconds)
                     # plus recompiles grouped by culprit — the registry is
-                    # process-global, so one table covers both engines
+                    # process-global, so one table covers both engines —
+                    # and the always-on set-up ledger: `programs` by name,
+                    # `setup` as of the first mark_warm()
                     from ..obs.compile_observatory import compile_observatory
                     self._reply_json(
                         200, compile_observatory().snapshot(top=50))

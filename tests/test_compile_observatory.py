@@ -3,9 +3,9 @@ argument pytrees, culprit-named recompile diffs (`batch['x'].shape[0]:
 32→48`), the process-global executable registry with AOT
 cost/memory analyses, the 6ND-vs-XLA-cost-model cross-check, the
 /debug/compiles + pdtpu_compile_* exposition on both HTTP servers, the
-one-predicate-when-disabled contract, the recompile sentinel's
-single-source install (no double-counting across jax.monitoring and the
-jit-cache fallback), the hardened jit-cache miss listeners, and the
+one-predicate-when-disabled contract, the one jax.monitoring dispatcher
+behind sentinels and set-up ledger alike (no build counted twice), the
+hardened jit-cache miss listeners, and the
 shape-churn fault-matrix scenario proving every post-warmup recompile
 event names the churned leaf — readable by
 `tools/flight_recorder.py --kind 'compile_*'`."""
@@ -297,11 +297,26 @@ def test_llm_engine_registers_every_executable_with_flops(
         assert {row["fingerprint"] for row in doc["rows"]} == \
             {row["fingerprint"] for row in snap["rows"]}
         assert all(row["flops"] > 0 for row in doc["rows"])
+        # the always-on set-up ledger rides the same payload: programs by
+        # name, slowest first, and the set-up as of the first mark_warm()
+        # (null before it: an operator's "why was this start slow")
+        step = next(p for p in doc["programs"] if p["program"] == "step")
+        assert step["traces"] >= 1 and step["backend_s"] > 0
+        cost = [p["trace_self_s"] + p["lower_self_s"] + p["backend_self_s"]
+                for p in doc["programs"]]
+        assert cost == sorted(cost, reverse=True)
+        assert doc["program_totals"]["phases"]["engine_init"] > 0
+        assert "setup" in doc
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{server.port}/metrics",
                 timeout=30) as r:
             text = r.read().decode()
         assert "pdtpu_compile_executables" in text
+        flat = obs.parse_exposition(text)
+        assert flat["pdtpu_compile_trace_seconds_total"] > 0
+        assert flat["pdtpu_compile_lower_seconds_total"] > 0
+        assert flat["pdtpu_compile_cache_hits_total"] \
+            + flat["pdtpu_compile_cache_misses_total"] >= 1
     finally:
         server.stop()
 
@@ -342,6 +357,11 @@ def test_batching_engine_debug_compiles_endpoint(global_observatory):
             text = r.read().decode()
         assert 'pdtpu_compile_dispatches_total{callsite="serve/predict"}' \
             in text
+        # ... and the set-up ledger's, on this server too
+        assert isinstance(doc["programs"], list) and "setup" in doc
+        for family in ("trace_seconds", "lower_seconds", "cache_hits",
+                       "cache_misses"):
+            assert f"pdtpu_compile_{family}_total " in text
     finally:
         server.stop()
 
@@ -380,61 +400,62 @@ def test_disabled_hooks_never_touch_the_observatory(monkeypatch):
     assert worker.run_step(np.ones((3,), np.float32)) == 3.0
 
 
-# ---- satellite: sentinel single-source install (no double-count) ----
+# ---- one dispatcher, one ledger (ISSUE 34; the jit_cache source went) ----
 
-def test_sentinel_counts_each_build_once_per_source():
-    """One JitLRUCache build whose build() triggers a REAL backend
-    compile reaches a monitoring-installed sentinel exactly once (via
-    the jax event) and a jit_cache-installed sentinel exactly once (via
-    the miss listener) — never twice, whichever sources are live in the
-    process (the ISSUE 12 double-counting regression)."""
+def test_one_build_reaches_every_sentinel_and_the_ledger_once():
+    """One real backend compile reaches each installed sentinel exactly
+    once and the ledger's row exactly once, whether or not the build
+    happened inside a JitLRUCache (whose miss hooks no longer feed
+    either: the ISSUE 12 double-counting cannot come back)."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.obs.goodput import RecompileSentinel
+    from paddle_tpu.obs.goodput import RecompileSentinel, compile_ledger
     from paddle_tpu.utils.jit_cache import JitLRUCache
 
     x = jnp.ones((7,))                 # materialized BEFORE installing:
     _ = float(x.sum())                 # its fill/reduce compiles are done
-    mon = RecompileSentinel().install(source="monitoring")
-    jc = RecompileSentinel().install(source="jit_cache")
-    assert mon.installed == "monitoring" and jc.installed == "jit_cache"
+    a = RecompileSentinel().install()
+    b = RecompileSentinel().install()
+    assert a.installed and b.installed
     try:
-        cache = JitLRUCache(4, name="iss12-single-source")
+        cache = JitLRUCache(4, name="iss34-one-source")
+
+        def iss34_once(v):
+            return v * 3.0 + 1.0
 
         def build():
-            f = jax.jit(lambda v: v * 3.0 + 1.0)
+            f = jax.jit(iss34_once)
             f(x).block_until_ready()   # the one backend compile
             return f
 
         cache.get_or_build(("k7",), build)
-        assert jc.compiles == 1, \
-            f"jit_cache sentinel counted {jc.compiles}, expected 1"
-        assert mon.compiles == 1, \
-            f"monitoring sentinel counted {mon.compiles}, expected 1"
-        # a cache HIT reaches neither source
+        assert a.compiles + a.loads == 1, a.snapshot()
+        assert b.compiles + b.loads == 1, b.snapshot()
+        row = compile_ledger().row("iss34_once")
+        assert row["traces"] == 1
+        assert row["cache_hits"] + row["cache_misses"] == 1
+        # a cache HIT reaches nobody
         cache.get_or_build(("k7",), build)
-        assert jc.compiles == 1 and mon.compiles == 1
+        assert a.compiles + a.loads == 1 and b.compiles + b.loads == 1
+        assert compile_ledger().row("iss34_once") == row
     finally:
-        mon.uninstall()
-        jc.uninstall()
-    assert mon.installed is None and jc.installed is None
+        a.uninstall()
+        b.uninstall()
+    assert not a.installed and not b.installed
 
 
-def test_auto_install_pins_one_source_per_process():
+def test_listeners_are_registered_once_per_process():
+    from jax._src import monitoring as mon   # the getters are not public
     from paddle_tpu.obs import goodput
-    from paddle_tpu.obs.goodput import RecompileSentinel
 
-    s1 = RecompileSentinel().install()          # auto -> monitoring here
-    try:
-        assert s1.installed == "monitoring"
-        assert goodput._PROCESS_SOURCE == "monitoring"
-        s2 = RecompileSentinel().install()      # auto reuses the pin
-        try:
-            assert s2.installed == "monitoring"
-        finally:
-            s2.uninstall()
-    finally:
-        s1.uninstall()
+    goodput.register_listeners()       # `import paddle_tpu` did already
+    goodput.register_listeners()
+    assert mon.get_event_duration_listeners().count(
+        goodput._duration_dispatch) == 1
+    assert mon.get_event_time_span_listeners().count(
+        goodput._span_dispatch) == 1
+    assert mon.get_event_listeners().count(goodput._event_dispatch) == 1
+    assert not hasattr(goodput, "_PROCESS_SOURCE")
 
 
 # ---- satellite: hardened jit-cache miss listeners ----

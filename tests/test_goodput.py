@@ -157,24 +157,37 @@ def test_sentinel_warmup_then_recompiles_and_storm_warning(caplog):
     assert sen.compiles == 1 and sen.recompiles == 0
     sen.mark_warm()
     with caplog.at_level(logging.WARNING, logger="paddle_tpu.goodput"):
-        sen.on_compile(0.5)
+        sen.on_compile(0.5, fun_name="step", trace_s=0.1, lower_s=0.2)
         assert not any("recompile storm" in r.message
                        for r in caplog.records)
-        sen.on_compile(0.25)            # hits threshold -> warn once
-        sen.on_compile(0.25)
+        # a load from the persistent cache after warm is a recompile too
+        sen.on_compile(0.25, fun_name="step", loaded=True)  # -> warn once
+        sen.on_compile(0.25, fun_name="other")
     storms = [r for r in caplog.records if "recompile storm" in r.message]
     assert len(storms) == 1
+    assert "step (compiled), step (loaded)" in storms[0].getMessage()
     snap = sen.snapshot()
-    assert snap == {"compiles": 4, "recompiles": 3,
+    assert snap["recompiled"] == sen.recompiled
+    del snap["recompiled"]
+    assert snap == {"compiles": 3, "loads": 1, "recompiles": 3,
                     "compile_seconds": pytest.approx(2.5)}
+    # the names, newest last, for whoever prints the count
+    assert [(r["fun_name"], r["how"], r["paid"])
+            for r in sen.recompiled] == [
+        ("step", "compiled", "trace+lower+backend"),
+        ("step", "loaded", "backend"), ("other", "compiled", "backend")]
     # compile seconds booked to the ledger
     assert led.snapshot()["phase_seconds"]["compile"] == pytest.approx(2.5)
-    # every post-warm compile dropped a flight event; the storm one is
-    # flagged
+    # every post-warm build dropped a flight event that names the program,
+    # what it paid and how it was had; the storm one is flagged
     ev = [e for e in obs.flight_recorder().snapshot()["events"]
           if e["kind"] == "train_recompile"]
     assert [e["recompiles"] for e in ev] == [1, 2, 3]
     assert [e["storm"] for e in ev] == [False, True, False]
+    assert [e["fun_name"] for e in ev] == ["step", "step", "other"]
+    assert [e["how"] for e in ev] == ["compiled", "loaded", "compiled"]
+    assert ev[0]["paid"] == "trace+lower+backend"
+    assert ev[0]["trace_seconds"] == pytest.approx(0.1)
 
 
 def test_sentinel_rejects_bad_threshold():
@@ -182,28 +195,50 @@ def test_sentinel_rejects_bad_threshold():
         RecompileSentinel(storm_threshold=0)
 
 
-def test_sentinel_jit_cache_fallback_counts_build_misses():
-    from paddle_tpu.utils.jit_cache import JitLRUCache
-    sen = RecompileSentinel().install(source="jit_cache")
-    assert sen.installed == "jit_cache"
+def test_sentinel_counts_monitoring_events_compiles_and_loads():
+    """The one source (the `jit_cache` fallback went with ISSUE 34): the
+    backend event under its own name, as jax fires it. A hit in the
+    persistent cache fires it too, after `cache_hits` on the same thread:
+    a load, not a compile."""
+    import jax.monitoring as mon
+    from paddle_tpu.obs.goodput import (CACHE_HIT_EVENT, COMPILE_EVENT,
+                                        LOWER_EVENT, TRACE_EVENT)
+    sen = RecompileSentinel().install()
+    assert sen.installed
     try:
-        cache = JitLRUCache(4, name="goodput-test")
-        cache.get_or_build(("a",), lambda: object())   # miss -> compile
-        cache.get_or_build(("a",), lambda: object())   # hit -> nothing
-        cache.get_or_build(("b",), lambda: object())   # miss
-        assert sen.compiles == 2
+        mon.record_event_duration_secs(COMPILE_EVENT, 0.5,
+                                       fun_name="jit(goodput_a)")
+        mon.record_event_duration_secs(TRACE_EVENT, 0.1,
+                                       fun_name="goodput_a")   # no build
+        mon.record_event_duration_secs(COMPILE_EVENT, 0.5,
+                                       fun_name="jit(goodput_b)")
+        assert (sen.compiles, sen.loads) == (2, 0)
+        mon.record_event(CACHE_HIT_EVENT)
+        mon.record_event_duration_secs(COMPILE_EVENT, 0.01,
+                                       fun_name="jit(goodput_a)")
+        assert (sen.compiles, sen.loads) == (2, 1)
         sen.mark_warm()
-        cache.get_or_build(("c",), lambda: object())
+        mon.record_event_duration_secs(TRACE_EVENT, 0.2,
+                                       fun_name="goodput_c")
+        mon.record_event_duration_secs(LOWER_EVENT, 0.3,
+                                       fun_name="jit(goodput_c)")
+        mon.record_event_duration_secs(COMPILE_EVENT, 0.4,
+                                       fun_name="jit(goodput_c)")
         assert sen.recompiles == 1
+        assert sen.recompiled[-1] == {
+            "fun_name": "goodput_c", "how": "compiled",
+            "paid": "trace+lower+backend",
+            "seconds": pytest.approx(0.9)}
     finally:
         sen.uninstall()
-    cache.get_or_build(("d",), lambda: object())       # detached: ignored
-    assert sen.compiles == 3 and sen.installed is None
+    mon.record_event_duration_secs(COMPILE_EVENT, 0.5,
+                                   fun_name="jit(goodput_d)")  # detached
+    assert sen.compiles == 3 and not sen.installed
 
 
 def test_sentinel_install_is_idempotent_and_uninstall_detaches():
-    s1 = RecompileSentinel().install(source="jit_cache")
-    assert s1.install(source="jit_cache") is s1        # second no-op
+    s1 = RecompileSentinel().install()
+    assert s1.install() is s1                          # second no-op
     s1.uninstall()
     s1.uninstall()                                     # idempotent
 

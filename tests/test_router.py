@@ -484,13 +484,18 @@ def test_router_server_replica_kill_reconciles_metrics(tmp_path):
         for t in threads:
             t.start()
         # keep traffic flowing until the fault timer's kill is VISIBLE in
-        # fleet health, so the replica loss provably lands mid-traffic
+        # fleet health, so the replica loss provably lands mid-traffic.
+        # Seen means quarantined: between the crash and the supervision
+        # pass that quarantines it the fleet already reads "degraded"
+        # with the replica's own word for its state, and a reading taken
+        # then is not the one asserted on below
         health = None
         deadline = time.time() + 240
         while time.time() < deadline:
             with urllib.request.urlopen(base + "/healthz", timeout=30) as r:
                 health = json.loads(r.read())
-            if health["status"] == "degraded":
+            if health["status"] == "degraded" \
+                    and health["replicas"]["replica0"] == "quarantined":
                 break
             time.sleep(0.2)
         time.sleep(1.0)       # one more round of post-kill traffic
