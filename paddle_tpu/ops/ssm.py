@@ -155,6 +155,10 @@ def _mosaic(x, dt_live, decay, b, c, state, adv, fresh):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=64 << 20),
+        # the new state takes the state's buffer: a grid step reads and
+        # writes its own tile alone, so where the caller donates the state
+        # (the serving step its pool) nothing is copied round the kernel
+        input_output_aliases={7: 1},
         interpret=pallas_mode.interpret(KERNEL),
         name=KERNEL,
     )(adv, fresh, scalars(decay), scalars(dt_live), x, b, c, state)

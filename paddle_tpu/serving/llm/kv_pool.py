@@ -84,6 +84,7 @@ raise `WindowRingError`; `defrag` scrubs a freed row's whole ring.
 from __future__ import annotations
 
 import inspect
+import threading
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import jax
@@ -172,12 +173,13 @@ class SlotPagedKVPool:
         self.ring_pages: Optional[int] = (
             None if self.ring_len is None
             else self.ring_len // self.block_len)
+        # the engine's step is donated `slabs` and its result, in the same
+        # buffers, is assigned back: a reference kept across a step is a
+        # deleted array. Read the attribute when you work, on the thread
+        # that launches steps, or under `slabs_lock` on another
         self.slabs: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
             (a, b) for a, b in entries]
-        # buffers shaped like `slabs` that hold nothing anybody reads: the
-        # pool a step read becomes the scratch its successor's result is
-        # written into (`scratch_slabs`, `advance`)
-        self.spare: Optional[List[Tuple[jnp.ndarray, jnp.ndarray]]] = None
+        self.slabs_lock = threading.Lock()
         self.lengths = np.zeros((self.num_slots,), np.int32)
         self.active = np.zeros((self.num_slots,), bool)
         # freed-but-not-scrubbed rows: their non-cached pages still hold
@@ -242,25 +244,18 @@ class SlotPagedKVPool:
                    for (a, b), kind in zip(self.slabs, self.layer_kinds)
                    if kind == RECURRENT)
 
-    def scratch_slabs(self) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
-        """Buffers shaped like `slabs` for the next step to write its
-        result into, handed to the step as a donated operand it never
-        reads: the pool before last where `advance` kept one, else fresh
-        zeros (the first step; after an operation below that rebuilt the
-        slabs; after a dispatch that consumed them and failed). So the
-        pool exists twice, as it does while any undonated step runs, and
-        never a third time when a step is launched before its predecessor
-        has finished with the pool it read."""
-        if self.spare is None or any(
-                a.is_deleted() for a in jax.tree_util.tree_leaves(self.spare)):
-            self.spare = [(jnp.zeros_like(a), jnp.zeros_like(b))
-                          for a, b in self.slabs]
-        return self.spare
+    def consumed(self) -> bool:
+        """Whether a dispatch that was donated the slabs took them: asked
+        after one failed, never on the way of a step that did not."""
+        return any(a.is_deleted()
+                   for a in jax.tree_util.tree_leaves(self.slabs))
 
-    def advance(self, new_slabs):
-        """A step's result becomes the pool; the pool it read, which its
-        successor no longer needs, becomes the scratch."""
-        self.spare, self.slabs = self.slabs, new_slabs
+    def reset_slabs(self):
+        """A zeroed pool of the same shapes, after the slabs were lost to
+        a dispatch that consumed them and failed. The caller has freed
+        every row and dropped what a prefix cache pinned."""
+        self.slabs = [tuple(jnp.zeros(a.shape, a.dtype) for a in entry)
+                      for entry in self.slabs]
 
     def _refuse_reread(self, what: str):
         """Refuse `what`, which re-reads a row's pages, on a pool some of
@@ -494,7 +489,6 @@ class SlotPagedKVPool:
         src_row = src_page // self.n_blocks
         if src_row == dst_slot:
             return
-        self.spare = None   # the copy below is the pool's second buffer
         if self._cow is None:
             blk_len = self.block_len
 
@@ -684,36 +678,40 @@ class SlotPagedKVPool:
         which carries each slot's RNG-lane index and grammar DFA state."""
         self._refuse_reread("export_rows")
         rows: Dict[int, dict] = {}
-        for slot in slots:
-            slot = int(slot)
-            if not self.active[slot]:
-                raise ValueError(f"slot {slot} is not active")
-            length = int(self.lengths[slot])
-            pages = list(self.block_table.get(slot, []))
-            layers = []
-            for k, v in self.slabs:
-                # ISSUE 19: length-trimmed fetch — slice each occupied
-                # page's columns on DEVICE and fetch only those, instead
-                # of materializing the whole [num_slots, Hkv, slab_len, D]
-                # slab on the host per layer. Spill/handoff copies scale
-                # with the row's committed length, not the pool size; the
-                # payload is bit-identical to the untrimmed path (pinned
-                # in tests/test_router.py).
-                kparts, vparts = [], []
-                for j, p in enumerate(pages):
-                    prow = p // self.n_blocks
-                    c0 = (p % self.n_blocks) * self.block_len
-                    w = min(self.block_len, length - j * self.block_len)
-                    kparts.append(np.asarray(k[prow, :, c0:c0 + w, :]))
-                    vparts.append(np.asarray(v[prow, :, c0:c0 + w, :]))
-                if kparts:
-                    layers.append((np.concatenate(kparts, axis=1),
-                                   np.concatenate(vparts, axis=1)))
-                else:
-                    hkv, d = k.shape[1], k.shape[3]
-                    empty = np.zeros((hkv, 0, d), dtype=k.dtype)
-                    layers.append((empty, empty.copy()))
-            rows[slot] = {"length": length, "layers": layers}
+        # the one reader that may run beside the thread that launches
+        # steps (a router handing a stream off): no step is dispatched,
+        # and no slab consumed, while it reads
+        with self.slabs_lock:
+            for slot in slots:
+                slot = int(slot)
+                if not self.active[slot]:
+                    raise ValueError(f"slot {slot} is not active")
+                length = int(self.lengths[slot])
+                pages = list(self.block_table.get(slot, []))
+                layers = []
+                for k, v in self.slabs:
+                    # ISSUE 19: length-trimmed fetch — slice each occupied
+                    # page's columns on DEVICE and fetch only those, instead
+                    # of materializing the whole [num_slots, Hkv, slab_len, D]
+                    # slab on the host per layer. Spill/handoff copies scale
+                    # with the row's committed length, not the pool size; the
+                    # payload is bit-identical to the untrimmed path (pinned
+                    # in tests/test_router.py).
+                    kparts, vparts = [], []
+                    for j, p in enumerate(pages):
+                        prow = p // self.n_blocks
+                        c0 = (p % self.n_blocks) * self.block_len
+                        w = min(self.block_len, length - j * self.block_len)
+                        kparts.append(np.asarray(k[prow, :, c0:c0 + w, :]))
+                        vparts.append(np.asarray(v[prow, :, c0:c0 + w, :]))
+                    if kparts:
+                        layers.append((np.concatenate(kparts, axis=1),
+                                       np.concatenate(vparts, axis=1)))
+                    else:
+                        hkv, d = k.shape[1], k.shape[3]
+                        empty = np.zeros((hkv, 0, d), dtype=k.dtype)
+                        layers.append((empty, empty.copy()))
+                rows[slot] = {"length": length, "layers": layers}
         return {"block_len": self.block_len, "capacity": self.capacity,
                 "rows": rows}
 
@@ -757,7 +755,6 @@ class SlotPagedKVPool:
                 f"payload has {len(layers)} layers, pool has "
                 f"{len(self.slabs)}")
         c0 = block_idx * self.block_len
-        self.spare = None   # the copy below is the pool's second buffer
         new_slabs = []
         for (k, v), (ke, ve) in zip(self.slabs, layers):
             if ke.shape[1] > self.block_len:
@@ -793,7 +790,6 @@ class SlotPagedKVPool:
             dst = self.allocate(length)
             self.set_length(dst, length)
             if length > 0:
-                self.spare = None
                 new_slabs = []
                 for (k, v), (ke, ve) in zip(self.slabs, row["layers"]):
                     ku = jnp.asarray(ke, dtype=k.dtype)[None]
@@ -840,7 +836,6 @@ class SlotPagedKVPool:
         if self.windowed:
             masks[WINDOW] = jnp.asarray(
                 np.repeat(keep[:, :1], self.ring_len + self.pad_tokens, 1))
-        self.spare = None
         self.slabs = [(self._scrub(k, masks[kind].astype(k.dtype)),
                        self._scrub(v, masks[kind].astype(v.dtype)))
                       if kind != RECURRENT else (k, v)

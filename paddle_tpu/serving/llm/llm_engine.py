@@ -54,10 +54,14 @@ continuously-batched service:
   by slot. Draft windows, grammar rows and engines that book device time
   per step keep launch, retire (`_can_launch_ahead`); a dispatch that
   fails ahead of its predecessor is retried after that one is retired.
-  The step is not donated the pool it reads; it writes its result into
-  the buffers of the pool before that one (`kv_pool.scratch_slabs`), so
-  the pool exists twice and, with a step queued behind a running one,
-  not three times;
+  The step is donated the pool it reads: the K/V writes and a
+  state-space layer's state land in the buffers they were read from, so
+  the pool exists once, a step queued behind a running one included, and
+  `pool.slabs` as held before a launch is a deleted array after it. A
+  blame probe is donated a copy (`pool_copies`, the failure path alone);
+  a dispatch that took the pool and failed leaves the active rows
+  without their K/V: they fail and the pool starts again from zeros
+  (`pool_lost`);
 - **chunked prefill**: prompts longer than `prefill_chunk` are admitted
   as fixed-size chunks interleaved with the decode loop, so a short
   prompt's TTFT is bounded by a couple of chunk-width steps instead of a
@@ -903,11 +907,11 @@ class LLMEngine:
 
             def step(params, toks, pos, adv, table, slabs, temp, topk,
                      topp, samp, seed, ctr, dstate, gid, bank, feed,
-                     prev_sel, spare, adapters=None, moe_totals=None):
-                # `spare` is never read: a donated operand shaped like
-                # `slabs` whose buffers XLA aliases to `new_slabs`
-                # (`pool.scratch_slabs`). `slabs` itself is not donated: a
-                # failed dispatch and a blame probe leave the pool intact.
+                     prev_sel, adapters=None, moe_totals=None):
+                # `slabs` is donated: XLA aliases it to `new_slabs`, so the
+                # K/V writes and the recurrent state land in place and no
+                # step copies the pool. The caller's reference is a deleted
+                # array once the executable is enqueued (`_launch`).
                 # `adapters` (ISSUE 20) is the AdapterBank's stacked LoRA
                 # operand — (per-layer A/B banks, per-slot adapter_idx,
                 # per-row scale). An unarmed engine never passes it, so
@@ -974,8 +978,7 @@ class LLMEngine:
                         moe_totals + jnp.stack(expert_counts))
 
             step.__name__ = step.__qualname__ = UNIFIED_STEP_NAME
-            self._step_jit = jax.jit(step, donate_argnames=("spare",),
-                                     keep_unused=True)
+            self._step_jit = jax.jit(step, donate_argnames=("slabs",))
         return self._step_jit
 
     @staticmethod
@@ -2862,6 +2865,10 @@ class LLMEngine:
         attempt. Without it this is the synchronous launch: rows from the
         committed state, retries, then blame and quarantine.
 
+        The step is donated `pool.slabs`: a failed dispatch that left
+        them whole is retried on the same operands, one that took them is
+        never retried (`_pool_lost`).
+
         With a draft model attached (ISSUE 17) the draft phase runs
         first: decode rows carry verify windows [last_tok, d1..dK]
         instead of a lone token, and the commit takes the longest
@@ -2870,6 +2877,8 @@ class LLMEngine:
         to plain greedy decode. Quarantine retries reuse this pump's
         windows: a failed dispatch commits nothing, so the surviving
         rows' positions — and therefore their drafts — are unchanged."""
+        if failed is not None and self._pool_gone(failed):
+            return self._pool_lost(1, failed)
         spec_drafts = {}
         if self.draft_pool is not None and not self._spec_disabled:
             with RecordEvent(SPAN_SERVE_DRAFT):
@@ -2939,8 +2948,7 @@ class LLMEngine:
                 args = (self.params, jnp.asarray(toks), jnp.asarray(pos),
                         jnp.asarray(adv), self.pool.device_block_table(),
                         self.pool.slabs) + sargs \
-                    + self._feedback_args(feed, ahead_of) \
-                    + (self.pool.scratch_slabs(),) + aargs
+                    + self._feedback_args(feed, ahead_of) + aargs
                 if self.observatory is not None:
                     self.observatory.observe_call("llm/unified_step", fn,
                                                   args)
@@ -2961,8 +2969,12 @@ class LLMEngine:
                         # wall time stays in the host phase.
                         tc0 = self.clock.now()
                     try:
-                        nxt, lps, new_dstate, new_slabs, *moe_out = \
-                            self._dispatch_step(kinds, fn, args)
+                        # the pool's buffers go in and come back as the
+                        # result: no reader sees them half way
+                        with self.pool.slabs_lock:
+                            nxt, lps, new_dstate, self.pool.slabs, \
+                                *moe_out = self._dispatch_step(kinds, fn,
+                                                               args)
                     except DispatchFailedError as e:
                         last_err = e
                         self.metrics.on_dispatch_failure(e.reason)
@@ -2979,8 +2991,9 @@ class LLMEngine:
                             attempt + 1, attempts, e)
                         if ahead_of is not None:
                             raise   # retire the predecessor first
-                        continue
-                    self.pool.advance(new_slabs)
+                        if self._pool_gone(e):
+                            return self._pool_lost(attempt + 1, e)
+                        continue    # on the same `args`: the pool is whole
                     if moe_out:
                         # committed with the step, like the slabs: a failed
                         # attempt or a blame probe counts nowhere
@@ -3264,12 +3277,13 @@ class LLMEngine:
         (toks=0, pos=0, adv=0), announced as that single request's kind
         ("prefill" for a row still in chunked prefill, "decode"
         otherwise) — and quarantine the rows whose solo presence
-        reproduces the failure. Probe results are never committed (slabs
-        are immutable jax arrays; only a successful full step assigns
-        pool.slabs), so survivors' streams stay bit-identical to a
-        fault-free run — including decode rows co-scheduled with a
-        request poisoned in prefill chunk k>0, which lose nothing but the
-        failed step's wall time.
+        reproduces the failure. Probe results are never committed (a
+        probe is donated a COPY of the pool, `pool_copies`, and its result
+        is dropped; only a successful full step assigns pool.slabs), so
+        survivors' streams stay bit-identical to a fault-free run —
+        including decode rows co-scheduled with a request poisoned in
+        prefill chunk k>0, which lose nothing but the failed step's wall
+        time.
 
         When EVERY probe of a multi-row batch fails, the failure is not
         attributable to any one request — that is an engine-level fault
@@ -3298,11 +3312,12 @@ class LLMEngine:
                 # reproduces in isolation too
                 sargs = self._sampling_args_locked(solo_ctr)
                 aargs = self._tail_args_locked()
+            self.metrics.on_pool_copy()
             args = (self.params, jnp.asarray(solo_toks),
                     jnp.asarray(solo_pos), jnp.asarray(solo_adv),
                     self.pool.device_block_table(),
-                    self.pool.slabs) + sargs + self._feedback_args() \
-                + (self.pool.scratch_slabs(),) + aargs
+                    jax.tree.map(jnp.copy, self.pool.slabs)) + sargs \
+                + self._feedback_args() + aargs
             probe_kinds = [(kind, (req.submit_idx,))]
             if req.adapter:
                 # the solo probe must announce the same adapter kind the
@@ -3310,10 +3325,7 @@ class LLMEngine:
                 # reproduce and the fault would look unattributable
                 probe_kinds.append(("adapter", (req.submit_idx,)))
             try:
-                # a probe's result is never committed: its slabs, written
-                # into the scratch it consumed, are the next scratch
-                self.pool.spare = self._run_dispatch(
-                    tuple(probe_kinds), fn, args)[3]
+                self._run_dispatch(tuple(probe_kinds), fn, args)
             except DispatchFailedError as e:
                 blamed.append((slot, req, e))
                 flight_recorder().record(
@@ -3350,6 +3362,27 @@ class LLMEngine:
                      "unified step with %d survivor(s)", len(blamed),
                      len(suspects) - len(blamed))
         return True
+
+    def _pool_gone(self, err: DispatchFailedError) -> bool:
+        """Whether the dispatch that failed with `err` took the pool, or
+        (a call the watchdog abandoned) may take it yet."""
+        return err.abandoned or self.pool.consumed()
+
+    def _pool_lost(self, attempts: int, err) -> None:
+        """A dispatch consumed the pool and failed: the active rows' K/V
+        and state are gone with it. They fail, what the prefix cache
+        pinned is dropped, the pool starts again from zeros of the same
+        shapes and the breaker is charged; queued requests are served
+        from the fresh pool."""
+        self.metrics.on_pool_lost()
+        _log.error("a unified step took the K/V pool and failed (%s): "
+                   "failing the active requests, zeroing the pool", err)
+        self._fail_all_active(attempts, err)
+        with self._cond:
+            if self.prefix_cache is not None:
+                self.prefix_cache.clear()
+        self.pool.reset_slabs()
+        self.supervisor.record_failure()
 
     def _fail_all_active(self, attempts: int, last_err):
         """Non-attributable step failure: fail every active request with

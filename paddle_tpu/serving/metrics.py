@@ -288,6 +288,7 @@ class LLMMetrics(ServingMetrics):
                               "prefill_rows_deferred": 0,
                               "steps_overlapped": 0,
                               "rows_discarded": 0,
+                              "pool_copies": 0, "pool_lost": 0,
                               "moe_assignments": 0,
                               "recurrent_rows_started": 0,
                               "window_kv_tokens": 0,
@@ -542,6 +543,18 @@ class LLMMetrics(ServingMetrics):
         with self._lock:
             self.counters["rows_discarded"] += int(n)
 
+    def on_pool_copy(self):
+        """One copy of the whole pool, for a blame probe to be donated
+        in the pool's place: the failure path alone."""
+        with self._lock:
+            self.counters["pool_copies"] += 1
+
+    def on_pool_lost(self):
+        """One dispatch after which the pool was found consumed: its
+        active rows failed and the pool was zeroed."""
+        with self._lock:
+            self.counters["pool_lost"] += 1
+
     def set_recurrent_state(self, nbytes: int):
         with self._lock:
             self.recurrent_state_bytes = int(nbytes)
@@ -780,7 +793,7 @@ class LLMMetrics(ServingMetrics):
                  s["sampler_filter_steps"])
         for name in ("step_tokens_live", "step_tokens_computed",
                      "prefill_rows_deferred", "steps_overlapped",
-                     "rows_discarded"):
+                     "rows_discarded", "pool_copies", "pool_lost"):
             b.family(f"{px}_{name}_total", "counter")
             b.sample(f"{px}_{name}_total", s[name])
         b.family(f"{px}_prefills_total", "counter")

@@ -48,16 +48,23 @@ class DispatchFailedError(RuntimeError):
     fired), "poisoned" (failure attributed to one request after retries),
     "engine" (engine-level protocol exhaustion failed this request)."""
 
+    # the call is still running on a worker thread the watchdog gave up
+    # on, and may yet consume what it was donated (`DispatchHungError`)
+    abandoned = False
+
     def __init__(self, msg: str, reason: str = "raise"):
         super().__init__(msg)
         self.reason = reason
 
 
 class DispatchHungError(DispatchFailedError):
-    """The dispatch exceeded the watchdog budget and was abandoned."""
+    """The dispatch exceeded the watchdog budget and was abandoned
+    (`abandoned`; never so for an injected hang, which is raised before
+    the call)."""
 
-    def __init__(self, msg: str):
+    def __init__(self, msg: str, abandoned: bool = False):
         super().__init__(msg, reason="hang")
+        self.abandoned = abandoned
 
 
 class EngineSupervisor:
@@ -161,7 +168,7 @@ class EngineSupervisor:
             raise DispatchHungError(
                 f"{self.name} {label} dispatch exceeded the "
                 f"{self.dispatch_timeout_s:.1f}s watchdog budget; "
-                "abandoning the worker thread")
+                "abandoning the worker thread", abandoned=True)
         if "error" in box:
             raise box["error"]
         return box["value"]

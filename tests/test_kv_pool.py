@@ -141,10 +141,31 @@ def test_ledger_balances_through_grow_rewind_free_and_defrag():
         assert np.asarray(k[b]).all() and np.asarray(k[2]).all(), kind
     pool.free(b)
     assert pool.check_balance()
-    scratch = pool.scratch_slabs()
-    assert [k.shape for k, _ in scratch] == [k.shape for k, _ in pool.slabs]
-    pool.advance(scratch)
     assert pool.layer_kinds == [WINDOW, WINDOW, PAGED]
+
+
+def test_a_consumed_pool_is_told_by_its_leaves_and_starts_again_zeroed():
+    """What the engine asks after a dispatch that was donated the slabs
+    failed, and what it does when they are gone."""
+    def init_cache(batch, max_len, dtype=None, window_slab=None):
+        ring = jnp.zeros((batch, HKV, window_slab(32), D))
+        full = jnp.zeros((batch, HKV, max_len, D), jnp.bfloat16)
+        return [WindowKV(ring, ring), (full, full),
+                RecurrentState(jnp.zeros((batch, 3, 8)),
+                               jnp.zeros((batch, 4, 8)))]
+    pool = SlotPagedKVPool(init_cache, 2, 8, 8, pad_tokens=16)
+    pool.slabs = [tuple(a + 1 for a in entry) for entry in pool.slabs]
+    shapes = [tuple((a.shape, a.dtype) for a in entry)
+              for entry in pool.slabs]
+    assert not pool.consumed()
+    pool.slabs[1][0].delete()                    # one leaf is enough
+    assert pool.consumed()
+    pool.reset_slabs()
+    assert not pool.consumed()
+    assert [tuple((a.shape, a.dtype) for a in entry)
+            for entry in pool.slabs] == shapes
+    assert not any(np.asarray(a).any() for e in pool.slabs for a in e)
+    assert pool.layer_kinds == [WINDOW, PAGED, "recurrent"]
 
 
 def test_a_recurrent_layer_beside_a_ring_refuses_as_recurrent():

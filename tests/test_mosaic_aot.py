@@ -32,7 +32,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 28 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 29 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 13         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -58,8 +58,9 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     Mosaic body, that of an engine with window and full layers two, and
     the compiled step still a custom call a layer."""
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
-    assert len(steps) == 2
-    full, mixed = steps
+    assert len(steps) == 3
+    full, mixed, hybrid = steps
+    assert "'ssm_update': 2" in hybrid and "'paged_attention': 1" in hybrid
     assert "3 full layers: 1 Mosaic body " in full
     assert "{'paged_attention': 3} in the compiled one" in full
     assert "3 window layers + 1 full: 2 Mosaic bodies " in mixed
@@ -67,6 +68,16 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     for ln in steps:        # the three phases are timed apart
         assert re.search(r"trace \d+\.\ds \+ lower \d+\.\ds \+ compile "
                          r"\d+\.\ds$", ln), ln
+
+
+def test_the_compiled_step_aliases_the_whole_pool_for_the_v5e(tool):
+    """The step is donated its pool (PR 35): compiled for the chip, every
+    byte of a full-length, a ring and a recurrent engine's slabs is
+    aliased to the result, or the step would copy the pool again."""
+    steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
+    for ln in steps:
+        m = re.search(r"; (\d+) bytes aliased of a pool of (\d+); ", ln)
+        assert m and int(m[1]) >= int(m[2]) > 0, ln
 
 
 def test_kernel_body_does_not_grow_with_the_pages_of_a_group(tool):

@@ -372,8 +372,7 @@ def _lowered_step(model):
         args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(adv), eng.pool.device_block_table(),
                 eng.pool.slabs) + eng._sampling_args_locked(ctr) \
-            + eng._feedback_args() + (eng.pool.scratch_slabs(),) \
-            + eng._tail_args_locked()
+            + eng._feedback_args() + eng._tail_args_locked()
     lowered = eng._step().lower(*args)
     return eng, args, lowered
 
@@ -392,8 +391,8 @@ def test_a_dense_configuration_lowers_to_the_step_it_always_had():
     n_params = len(jax.tree_util.tree_leaves(eng.params))
     n_slabs = len(jax.tree_util.tree_leaves(eng.pool.slabs))
     assert len(jax.tree_util.tree_leaves(args)) \
-        == n_params + 4 + 2 * n_slabs + 9 + 2  # rows, table; sampling;
-    #                                 token feedback; the result's buffers
+        == n_params + 4 + n_slabs + 9 + 2   # rows, table; the pool, once;
+    #                                         sampling; token feedback
     out = jax.tree_util.tree_leaves(lowered.out_info)
     assert len(out) == 3 + n_slabs                 # sel, lp, state, slabs
     text = lowered.as_text()
@@ -403,7 +402,7 @@ def test_a_dense_configuration_lowers_to_the_step_it_always_had():
     sparse_eng, sparse_args, sparse = _lowered_step(_model(2))
     assert len(jax.tree_util.tree_leaves(sparse_args)) \
         == len(jax.tree_util.tree_leaves(sparse_eng.params)) \
-        + 4 + 2 * n_slabs + 9 + 2 + 1
+        + 4 + n_slabs + 9 + 2 + 1
     outs = jax.tree_util.tree_leaves(sparse.out_info)
     assert len(outs) == 3 + n_slabs + 1
     assert outs[-1].shape == (LAYERS, EXPERTS)

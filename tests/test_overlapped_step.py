@@ -427,44 +427,3 @@ def test_cached_prompt_pages_survive_the_freed_rows_stray_write(gpt_tiny):
     assert _counters(eng)["prefix_hit_tokens"] == 11   # all but one token
     eng.pool.check_balance()
     eng.stop()
-
-
-# ---- the pool exists twice, never three times -------------------------------
-
-def _buffers(slabs):
-    import jax
-    return sorted(a.unsafe_buffer_pointer()
-                  for a in jax.tree_util.tree_leaves(slabs))
-
-
-def test_a_steps_result_takes_the_buffers_of_the_pool_before_last(gpt_tiny):
-    """The step does not donate the pool it reads (a failed dispatch and a
-    blame probe leave it intact); it is handed the pool BEFORE that one as
-    a donated operand it never reads, and writes its result there. With a
-    step launched while its predecessor still reads that pool, no third
-    set of buffers appears: two sets, in turns, for the engine's life."""
-    eng = _engine(gpt_tiny)
-    handles = [eng.submit(p, max_new_tokens=6) for p in _prompts([6, 19, 30])]
-    seen = set()
-    scratch = _buffers(eng.pool.scratch_slabs())
-    first = _buffers(eng.pool.slabs)
-    eng._admit()
-    rec = eng._launch()
-    assert _buffers(eng.pool.slabs) == scratch      # written into the scratch
-    assert _buffers(eng.pool.spare) == first        # the pool it read: next
-    ahead = eng._launch(ahead_of=rec)
-    assert _buffers(eng.pool.slabs) == first and ahead is not None
-    eng._retire(rec)
-    eng._inflight = ahead
-    while eng.has_work():
-        eng.pump()
-        seen.update(_buffers(eng.pool.slabs))
-    assert seen == set(scratch) | set(first)
-    for p, h in zip(_prompts([6, 19, 30]), handles):
-        np.testing.assert_array_equal(h.result(0),
-                                      _reference(gpt_tiny, p, 6))
-    # an operation that rebuilds the slabs drops the scratch: its own copy
-    # is the pool's second set of buffers
-    eng.pool.defrag()
-    assert eng.pool.spare is None
-    eng.stop()

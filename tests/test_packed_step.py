@@ -373,8 +373,7 @@ def _lowered(eng, prompt):
         args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(adv), eng.pool.device_block_table(),
                 eng.pool.slabs) + eng._sampling_args_locked(ctr) \
-            + eng._feedback_args() + (eng.pool.scratch_slabs(),) \
-            + eng._tail_args_locked()
+            + eng._feedback_args() + eng._tail_args_locked()
     return args, eng._step().lower(*args).as_text()
 
 
@@ -401,9 +400,8 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
     prefill = eng._prefill_fn
 
     def step(params, toks, pos, adv, table, slabs, temp, topk, topp, samp,
-             seed, ctr, dstate, gid, bank, feed, prev_sel, spare,
-             moe_totals=None):
-        # `spare`: never read; donated, its buffers take the new slabs
+             seed, ctr, dstate, gid, bank, feed, prev_sel, moe_totals=None):
+        # `slabs` is donated: the new slabs take its buffers
         # the input token of a row launched ahead of its predecessor
         fed = jnp.take_along_axis(
             prev_sel, jnp.maximum(feed, 0)[:, None], axis=1)[:, 0]
@@ -422,10 +420,10 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
             return sel, lp, state, new_slabs
         return sel, lp, state, new_slabs, moe_totals + jnp.stack(counts)
 
-    if len(args) == 20:                 # a sparse model: (None, totals)
-        args = args[:18] + (args[19],)
-    assert text == jax.jit(step, donate_argnames=("spare",),
-                           keep_unused=True).lower(*args).as_text()
+    if len(args) == 19:                 # a sparse model: (None, totals)
+        args = args[:17] + (args[18],)
+    assert text == jax.jit(
+        step, donate_argnames=("slabs",)).lower(*args).as_text()
 
     packed_eng = _engine(model)
     _, packed_text = _lowered(packed_eng, prompt)

@@ -124,8 +124,7 @@ def serve_step_case(name, model, device, want) -> bool:
         args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(adv), eng.pool.device_block_table(),
                 eng.pool.slabs) + eng._sampling_args_locked(ctr) \
-            + eng._feedback_args() + (eng.pool.scratch_slabs(),) \
-            + eng._tail_args_locked()
+            + eng._feedback_args() + eng._tail_args_locked()
     sharding = SingleDeviceSharding(device)
     specs = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
@@ -143,10 +142,15 @@ def serve_step_case(name, model, device, want) -> bool:
               flush=True)
         return False
     bodies = lowered.as_text().count("stablehlo.custom_call @tpu_custom_call")
-    ok = bodies == want
+    # the step is donated the pool: every slab's bytes must be aliased to
+    # the result, or the executable copies the pool once a step
+    pool = sum(a.nbytes for a in jax.tree_util.tree_leaves(eng.pool.slabs))
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    ok = bodies == want and aliased >= pool
     print(f"[{'OK' if ok else 'FAIL'}] {name}: {bodies} Mosaic "
           f"{'body' if bodies == 1 else 'bodies'} in the lowered step for "
           f"{pallas_kernel_census(compiled.as_text())} in the compiled one; "
+          f"{aliased} bytes aliased of a pool of {pool}; "
           f"trace {t1 - t0:.1f}s + lower {t2 - t1:.1f}s + compile "
           f"{t3 - t2:.1f}s" + ("" if ok else f" (wanted {want})"),
           flush=True)
@@ -293,6 +297,20 @@ def main() -> int:
         model.eval()
         results.append(serve_step_case(f"serve step, {label}", model,
                                        dev1[0], want))
+    # recurrent state in the same donated pool (`ssm_update` aliases the
+    # state it carries: the step would copy it whole behind every call)
+    from paddle_tpu.models.granitemoehybrid import (
+        GraniteMoeHybridConfig, GraniteMoeHybridForCausalLM)
+    model = GraniteMoeHybridForCausalLM(GraniteMoeHybridConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=128,
+        shared_intermediate_size=128, num_hidden_layers=3,
+        layer_types=["mamba", "attention", "mamba"], num_attention_heads=2,
+        num_key_value_heads=1, num_local_experts=8, num_experts_per_tok=2,
+        mamba_n_heads=8, mamba_d_head=64, mamba_d_state=128,
+        max_position_embeddings=1024, dtype="bfloat16"))
+    model.eval()
+    results.append(serve_step_case("serve step, 2 recurrent layers + 1 full",
+                                   model, dev1[0], 12))
     from paddle_tpu.ops import pallas_mode
     for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
         print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
