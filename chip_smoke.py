@@ -65,6 +65,10 @@ FULL = dict(
     # a ring of 65 pages (window + one chunk)
     paged_window=dict(heads=32, kv_heads=4, head_dim=128, window=1024,
                       ring_pages=65),
+    # the latent cell's MLA layers: 64 heads over one 512-wide latent and
+    # one 64-wide rotary key (stored in 128 lanes), 518 pages a slot
+    paged_latent=dict(heads=64, latent=512, rope=64, rope_cols=128,
+                      pages=518),
     # granite-4.0-h-small's Mamba-2 state: 128 heads x 64, 128 channels
     ssm=dict(heads=128, head_dim=64, state=128),
 )
@@ -76,6 +80,7 @@ TINY = dict(
            dict(heads=2, kv_heads=2, head_dim=64, pages=4)),
     paged_window=dict(heads=4, kv_heads=2, head_dim=64, window=32,
                       ring_pages=3),
+    paged_latent=dict(heads=4, latent=32, rope=8, rope_cols=8, pages=20),
     ssm=dict(heads=4, head_dim=64, state=16),
 )
 
@@ -511,6 +516,53 @@ def _paged_window_parity(size: dict):
                  f"{WINDOW_KERNEL} H={H}/{Hkv} Tq={Tq} within {tol:g}")
 
 
+def _paged_latent_parity(size: dict):
+    """The latent walk (`paged_latent`) `impl="pallas"` against
+    `impl="scan"` on this device at `size["paged_latent"]`: bf16, block_len
+    16, the latent `c` and the rotary key `r` (its first `rope` columns;
+    zeros behind, as `models/deepseek.py` stores it), every query head over
+    the one latent, query widths 1 and 16, ragged lengths from one key to
+    the whole slot. Tolerance as `_paged_parity`'s."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import pallas_mode
+    from paddle_tpu.ops.paged_attention import (LATENT_KERNEL,
+                                                ragged_paged_attention)
+    g = size["paged_latent"]
+    H, R, Dr, cols, pages = (g["heads"], g["latent"], g["rope"],
+                             g["rope_cols"], g["pages"])
+    N, bl, tol = 8, 16, 2e-2
+    L = pages * bl
+    rng = np.random.RandomState(3)
+    pad = ((0, 0), (0, 0), (0, 0), (0, cols - Dr))
+    c = jnp.asarray(rng.randn(N, 1, L + bl, R), jnp.bfloat16)
+    r = jnp.pad(jnp.asarray(rng.randn(N, 1, L + bl, Dr), jnp.bfloat16), pad)
+    table = np.arange(N * pages, dtype=np.int32).reshape(N, pages)
+    scale = (R + Dr) ** -0.5
+    for Tq in (1, 16):
+        q = jnp.asarray(rng.randn(N, H, Tq, R), jnp.bfloat16)
+        qr = jnp.pad(jnp.asarray(rng.randn(N, H, Tq, Dr), jnp.bfloat16), pad)
+        lens = np.maximum(Tq, np.array(
+            [1, bl - 1, 127, 129, L // 7, L // 3, L - bl - 1, L],
+            np.int32))
+        q_pos = (lens - Tq).astype(np.int32)
+        pallas_mode.KERNEL_TILINGS.clear()
+        outs = {impl: ragged_paged_attention(
+            q, c, r, table, lens, q_pos, block_len=bl, pages_per_row=pages,
+            scale=scale, impl=impl, q_rope=qr) for impl in ("pallas", "scan")}
+        ((kernel, tiling),) = pallas_mode.KERNEL_TILINGS
+        tiling = dict(tiling)
+        err = _max_err(outs["pallas"], outs["scan"])
+        _say(f"{kernel} pallas vs scan H={H} latent={R} rope={Dr} (in "
+             f"{cols} columns) Tq={Tq} seq_lens={lens.tolist()} bf16: grid "
+             f"{tiling['grid']}, up to {tiling['groups']} groups of "
+             f"pages={tiling['pages']} a row, tile {tiling['rows']} rows; "
+             f"max abs err {err:.2e} (tolerance {tol:g})")
+        _require(kernel == LATENT_KERNEL and np.isfinite(err) and err <= tol,
+                 f"{LATENT_KERNEL} H={H} Tq={Tq} within {tol:g}")
+
+
 def _ssm_parity(size: dict):
     """`ssm_update(impl="pallas")` against `impl="scan"` on this device at
     `size["ssm"]`, bf16 state and inputs, 8 rows of 16, of 1 and of 80
@@ -579,6 +631,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
     _say("[serve] kernel parity on this device")
     _paged_parity(size)
     _paged_window_parity(size)
+    _paged_latent_parity(size)
     _ssm_parity(size)
 
     import paddle_tpu as paddle

@@ -129,7 +129,7 @@ from . import vision  # noqa: F401,E402
 from .flags import get_flags, set_flags  # noqa: F401,E402
 from .distributed.data_parallel import DataParallel  # noqa: F401,E402
 from .hapi import Model  # noqa: F401,E402
-from .nn.layer.layers import ParamAttr  # noqa: F401,E402
+from .nn.layer.layers import LazyGuard, ParamAttr  # noqa: F401,E402
 
 # paddle.disable_static / enable_static parity: eager is the default and the
 # "static" mode is jax.jit tracing — both are always available, so these are

@@ -148,8 +148,52 @@ def _is_tracer(x) -> bool:
     return isinstance(x, jax.core.Tracer)
 
 
+class UnassignedParameterError(RuntimeError):
+    """A parameter created under `LazyGuard` was used before `p.data` was
+    assigned."""
+
+
+class Unassigned:
+    """What a parameter created under `nn.layer.layers.LazyGuard` holds in
+    place of an array: its shape and type, nothing on any device. Assigning
+    `p.data = array` makes the parameter real; anything that asks this
+    object for values raises `UnassignedParameterError` with the
+    parameter's name (`label`: the owning layer's class and attribute,
+    filled in when the layer takes the parameter)."""
+
+    __slots__ = ("shape", "dtype", "label")
+
+    def __init__(self, shape, dtype, label="a parameter"):
+        self.shape = tuple(int(d) for d in shape)
+        self.dtype = np.dtype(dtypes.convert_dtype(dtype))
+        self.label = label
+
+    @property
+    def ndim(self):
+        return len(self.shape)
+
+    @property
+    def size(self):
+        return int(np.prod(self.shape)) if self.shape else 1
+
+    def _refuse(self, *_args, **_kwargs):
+        raise UnassignedParameterError(
+            f"{self.label} {list(self.shape)} was created under LazyGuard "
+            "and has not been assigned: give it `p.data = array` first")
+
+    def __getattr__(self, name):
+        self._refuse()
+
+    __array__ = __getitem__ = __len__ = _refuse
+
+    def __repr__(self):
+        return f"Unassigned({self.label}, {list(self.shape)}, {self.dtype})"
+
+
 def to_array(value, dtype=None) -> jax.Array:
     """Convert arbitrary input to a jax.Array (host numpy path for lists/scalars)."""
+    if isinstance(value, Unassigned):
+        return value
     if isinstance(value, Tensor):
         arr = value.data
     elif isinstance(value, (jax.Array,)) or _is_tracer(value):
@@ -455,25 +499,32 @@ def apply(fn: Callable, *args, **kwargs):
                     and dtypes.is_floating_point(a.dtype)):
                 diff_idx.append(i)
 
-    if not diff_idx:
-        outs = fn(*raw, **kwargs)
-        tensors, single = _wrap_outputs(outs, node_needed=False)
-        return tensors[0] if single else tuple(tensors)
+    try:
+        if not diff_idx:
+            outs = fn(*raw, **kwargs)
+        else:
+            def closed(*diff_vals):
+                vals = list(raw)
+                for i, v in zip(diff_idx, diff_vals):
+                    vals[i] = v
+                return fn(*vals, **kwargs)
 
-    def closed(*diff_vals):
-        vals = list(raw)
-        for i, v in zip(diff_idx, diff_vals):
-            vals[i] = v
-        return fn(*vals, **kwargs)
-
-    outs, vjp_fn = jax.vjp(closed, *[raw[i] for i in diff_idx])
-    tensors, single = _wrap_outputs(outs, node_needed=True)
-    _STATE.seq += 1
-    node = _Node(vjp_fn, [args[i] for i in diff_idx], tensors, single,
-                 _STATE.seq, fn_info=(fn, raw, diff_idx, kwargs))
-    for k, t in enumerate(tensors):
-        t._node = node
-        t._out_index = k
+            outs, vjp_fn = jax.vjp(closed, *[raw[i] for i in diff_idx])
+    except (TypeError, ValueError):
+        # JAX's own complaint about an operand it cannot read says nothing
+        # of which parameter it was
+        for r in raw:
+            if isinstance(r, Unassigned):
+                r._refuse()
+        raise
+    tensors, single = _wrap_outputs(outs, node_needed=bool(diff_idx))
+    if diff_idx:
+        _STATE.seq += 1
+        node = _Node(vjp_fn, [args[i] for i in diff_idx], tensors, single,
+                     _STATE.seq, fn_info=(fn, raw, diff_idx, kwargs))
+        for k, t in enumerate(tensors):
+            t._node = node
+            t._out_index = k
     return tensors[0] if single else tuple(tensors)
 
 

@@ -1,0 +1,395 @@
+"""The DeepSeek-V3 family of decoders (DeepSeek-V3, and models built on its
+block such as SK Telecom's A.X-K1, `model_type` "axk1"): multi-head latent
+attention, a router with sigmoid scores and a group-limited choice beside a
+shared expert, dense SwiGLU layers before the sparse ones.
+
+A file of its own and not `models/llama.py` grown: that file's attention is
+"q, k, v of one head width and a `(k, v)` cache", which every other decoder
+here shares; this family's attention has none of the three (two low-rank
+down-projections with their norms, keys and values up-projected from one
+latent, a rotary key shared by all heads, q/k of 192 against v of 128, a
+latent cache), so a branch a line would have left `LlamaAttention` two
+classes in one. What the families do share is imported: `RMSNorm`,
+`LlamaMLP`, the rotary tables (`rope_inv_freq`: YaRN with this family's
+`mscale` / `mscale_all_dim`), `DroplessMoE`, the cache ops.
+
+Per layer, pre-norm residual (`x += Attn(RMSNorm(x)); x += FFN(RMSNorm(x))`),
+no biases. Attention, heads h = 1..H:
+
+    c_q = RMSNorm(x W_qa);  [q_nope_h | q_r_h] = c_q W_qb
+    [c_kv | k_r] = x W_kva; c_kv = RMSNorm(c_kv); k_r = RoPE(k_r)   one key
+    [k_nope_h | v_h] = c_kv W_kvb;  q_r_h = RoPE(q_r_h)
+    s_h = scale (q_nope_h . k_nope_h + q_r_h . k_r), causal softmax (f32)
+    out = concat_h(sum p v_h) W_o
+    scale = (nope + rope)^-0.5 * yarn_mscale(factor, mscale_all_dim)^2
+
+Two forms of the same sum. **Expanded** (the uncached forward): keys and
+values are made for every position and `flash_attention` runs over q/k of
+`nope + rope` columns, v padded with zeros to that width (one kernel for
+every model; the pad costs a third more p.v work on a path that is not
+measured). **Absorbed** (the cached forward, every query width: the engine's
+chunks and decode rows and `generate()`'s prompt alike, so that the two
+stay bit-identical): the cache holds per token `c_kv` (after its norm) and
+`k_r` (after RoPE), `kv_lora_rank + qk_rope_head_dim` values; the key
+up-projection is folded into the query, the value up-projection applied to
+the attention's result:
+
+    qt_h = q_nope_h W_kvb[k part, h]^T          [kv_lora_rank]
+    s_h  = scale (qt_h . c_kv + q_r_h . k_r)
+    o_h  = (sum p c_kv) W_kvb[v part, h]
+
+which `ops.paged_attention` walks as `paged_latent`: one "KV head" for all H
+query heads, the latent page read once for scores and values. The rotary
+key's slab is padded to whole lane tiles (`_lanes`: 64 -> 128 columns; a
+TPU array's minor dimension is stored in tiles of 128, so a 64-wide slab
+takes as much HBM, and the kernel's page copies want whole tiles).
+
+FFN: layers `< first_k_dense_replace` a dense SwiGLU of `intermediate_size`;
+the rest `DroplessMoE` (`nn.layer.moe.route`: `scoring_func`, `n_group` /
+`topk_group`, `norm_topk_prob`, `routed_scaling_factor`, an optional
+selection bias) plus `n_shared_experts` shared experts as one SwiGLU of
+`n_shared_experts * moe_intermediate_size`, weight 1. `experts_held` as
+`LlamaConfig`'s. Serving only: no load-balancing loss is wired.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from types import SimpleNamespace
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..core.tensor import apply
+from ..distributed.meta_parallel.mp_layers import (ColumnParallelLinear,
+                                                   RowParallelLinear,
+                                                   VocabParallelEmbedding)
+from ..nn.layer.common import Linear
+from ..nn.layer.layers import Layer, LayerList, parameter_dtype
+from ..nn.layer.moe import DroplessMoE
+from ..ops.attention import decode_attention, flash_attention, \
+    update_kv_cache
+from .llama import LlamaMLP, RMSNorm, _apply_rope, _rope_cos_sin, \
+    yarn_mscale
+
+LANES = 128
+
+
+def _lanes(width: int) -> int:
+    """`width` rounded up to whole lane tiles."""
+    return -(-width // LANES) * LANES
+
+
+@dataclass
+class DeepseekConfig:
+    vocab_size: int = 129280
+    hidden_size: int = 7168
+    intermediate_size: int = 18432         # a dense layer's SwiGLU
+    moe_intermediate_size: int = 2048      # one expert's
+    num_hidden_layers: int = 61
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 256
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    first_k_dense_replace: int = 3
+    n_group: int = 8
+    topk_group: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    scoring_func: str = "sigmoid"
+    # a per-expert bias on the router's choice ("noaux_tc" checkpoints
+    # carry one); False: the router has no such parameter
+    select_bias: bool = False
+    max_position_embeddings: int = 163840
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    # Hugging Face's `rope_scaling`: None, or {"type": "yarn", "factor",
+    # "original_max_position_embeddings", "beta_fast", "beta_slow",
+    # "mscale", "mscale_all_dim"}
+    rope_scaling: Optional[dict] = None
+    tie_word_embeddings: bool = False
+    dtype: str = "float32"
+    # (first, count): every expert layer holds that share of
+    # `n_routed_experts` (`DroplessMoE(held=)`)
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.tie_word_embeddings:
+            raise NotImplementedError("a tied head is not wired")
+        if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError(
+                f"first_k_dense_replace {self.first_k_dense_replace} of "
+                f"{self.num_hidden_layers} layers")
+
+    @property
+    def rope(self) -> dict:
+        """The rotary parameters as `llama.rope_inv_freq` reads them."""
+        scaling = dict(self.rope_scaling or {})
+        kind = scaling.pop("type", scaling.pop("rope_type", "default"))
+        return {"rope_type": kind, "rope_theta": self.rope_theta, **scaling}
+
+    @property
+    def softmax_scale(self) -> float:
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        rope = self.rope
+        if rope["rope_type"] == "yarn" and rope.get("mscale_all_dim"):
+            scale *= yarn_mscale(rope["factor"],
+                                 rope["mscale_all_dim"]) ** 2
+        return scale
+
+
+class MLAttention(Layer):
+    """Multi-head latent attention (module docstring)."""
+
+    def __init__(self, config: DeepseekConfig):
+        super().__init__()
+        self.config = config
+        h, H = config.hidden_size, config.num_attention_heads
+        self.qk_dim = config.qk_nope_head_dim + config.qk_rope_head_dim
+        self.q_a_proj = Linear(h, config.q_lora_rank, bias_attr=False)
+        self.q_a_layernorm = RMSNorm(config.q_lora_rank, config.rms_norm_eps)
+        self.q_b_proj = ColumnParallelLinear(
+            config.q_lora_rank, H * self.qk_dim, has_bias=False,
+            gather_output=False)
+        self.kv_a_proj_with_mqa = Linear(
+            h, config.kv_lora_rank + config.qk_rope_head_dim,
+            bias_attr=False)
+        self.kv_a_layernorm = RMSNorm(config.kv_lora_rank,
+                                      config.rms_norm_eps)
+        self.kv_b_proj = ColumnParallelLinear(
+            config.kv_lora_rank,
+            H * (config.qk_nope_head_dim + config.v_head_dim),
+            has_bias=False, gather_output=False)
+        self.o_proj = RowParallelLinear(H * config.v_head_dim, h,
+                                        has_bias=False,
+                                        input_is_parallel=True)
+
+    def _down(self, hidden):
+        """(q [.., H * (nope + rope)], c_kv [.., rank] after its norm,
+        k_r [.., rope] before RoPE)."""
+        rank = self.config.kv_lora_rank
+        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(hidden)))
+        ckv = self.kv_a_proj_with_mqa(hidden)
+        c = self.kv_a_layernorm(apply(lambda a: a[..., :rank], ckv))
+        return q, c, apply(lambda a: a[..., rank:], ckv)
+
+    def forward(self, hidden, cache=None, pos=None, paged=None, pack=None):
+        q, c, k_r = self._down(hidden)
+        if cache is not None:
+            return self._forward_cached(q, c, k_r, cache, pos, paged, pack)
+        cfg = self.config
+        H, nope, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                           cfg.qk_rope_head_dim, cfg.v_head_dim)
+        rope, qk = cfg.rope, self.qk_dim
+        # the kernels keep their own 1/sqrt(width): q carries the rest
+        q_scale = cfg.softmax_scale * math.sqrt(qk)
+
+        def attn(qa, kv, kr):
+            B, S = qa.shape[:2]
+            qh = jnp.swapaxes(qa.reshape(B, S, H, qk), 1, 2)   # [B,H,S,qk]
+            kvh = jnp.swapaxes(kv.reshape(B, S, H, nope + dv), 1, 2)
+            cos, sin = (t.astype(qa.dtype)
+                        for t in _rope_cos_sin(S, dr, rope))
+            q_rot = _apply_rope(qh[..., nope:], cos, sin)
+            k_rot = _apply_rope(kr[:, None], cos, sin)         # [B,1,S,dr]
+            qh = jnp.concatenate([qh[..., :nope], q_rot], -1) * q_scale
+            kh = jnp.concatenate(
+                [kvh[..., :nope], jnp.broadcast_to(k_rot, (B, H, S, dr))],
+                -1)
+            vh = jnp.pad(kvh[..., nope:],
+                         ((0, 0), (0, 0), (0, 0), (0, qk - dv)))
+            out = flash_attention(qh.astype(qa.dtype), kh, vh,
+                                  causal=True)[..., :dv]
+            return jnp.swapaxes(out, 1, 2).reshape(B, S, H * dv)
+
+        return self.o_proj(apply(attn, q, self.kv_b_proj(c), k_r))
+
+    def _forward_cached(self, q, c, k_r, cache, pos, paged, pack):
+        """The absorbed form through the latent cache `(c [B, 1, L, rank],
+        r [B, 1, L, lanes(rope)])`: the step's latents and rotary keys are
+        written at `pos`, then every query attends to the cache
+        (`decode_attention(q_rope=)`). `pack` as `LlamaAttention`'s."""
+        cfg = self.config
+        H, nope, dr, dv, rank = (
+            cfg.num_attention_heads, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank)
+        rope, qk, scale = cfg.rope, self.qk_dim, cfg.softmax_scale
+        if paged is not None:
+            paged = paged[:4]
+
+        def attn(qa, ca, kr, w_kvb, c_cache, r_cache, pos_):
+            if pack is not None:
+                qa, ca, kr = pack.unpack(qa), pack.unpack(ca), \
+                    pack.unpack(kr)
+                pos_ = pack.slot_pos
+            B, T = qa.shape[:2]
+            qh = jnp.swapaxes(qa.reshape(B, T, H, qk), 1, 2)   # [B,H,T,qk]
+            cos, sin = _rope_cos_sin(c_cache.shape[2], dr, rope)
+            if jnp.ndim(pos_) == 0:
+                cos_t = lax.dynamic_slice_in_dim(cos, pos_, T, 0)
+                sin_t = lax.dynamic_slice_in_dim(sin, pos_, T, 0)
+            else:       # each row at its own position: [B, T, dr]
+                row = jax.vmap(
+                    lambda tab, p: lax.dynamic_slice_in_dim(tab, p, T, 0),
+                    in_axes=(None, 0))
+                cos_t, sin_t = row(cos, pos_), row(sin, pos_)
+            cos_t, sin_t = cos_t.astype(qa.dtype), sin_t.astype(qa.dtype)
+            pad = ((0, 0), (0, 0), (0, 0), (0, r_cache.shape[3] - dr))
+            q_rot = jnp.pad(_apply_rope(qh[..., nope:], cos_t, sin_t), pad)
+            k_rot = jnp.pad(_apply_rope(kr[:, None], cos_t, sin_t), pad)
+            c_cache, r_cache = update_kv_cache(c_cache, r_cache,
+                                               ca[:, None], k_rot, pos_)
+            w = w_kvb.reshape(rank, H, nope + dv)
+            q_lat = jnp.einsum("bhtd,rhd->bhtr", qh[..., :nope],
+                               w[..., :nope]).astype(qa.dtype)
+            out = decode_attention(q_lat, c_cache, r_cache, pos_,
+                                   scale=scale, paged=paged, q_rope=q_rot)
+            out = jnp.einsum("bhtr,rhd->bthd", out, w[..., nope:])
+            out = out.reshape(B, T, H * dv).astype(qa.dtype)
+            if pack is not None:
+                out = pack.pack(out)
+            return out, c_cache, r_cache
+
+        ctx, new_c, new_r = apply(attn, q, c, k_r, self.kv_b_proj.weight,
+                                  *cache, pos)
+        return self.o_proj(ctx), (new_c, new_r)
+
+
+class DeepseekMoE(Layer):
+    """The routed experts' part (held here) plus the shared expert's."""
+
+    def __init__(self, config: DeepseekConfig):
+        super().__init__()
+        self.experts = DroplessMoE(
+            config.hidden_size, config.moe_intermediate_size,
+            config.n_routed_experts, config.num_experts_per_tok,
+            config.norm_topk_prob, held=config.experts_held,
+            scoring=config.scoring_func, n_group=config.n_group,
+            topk_group=config.topk_group, select_bias=config.select_bias,
+            routed_scale=config.routed_scaling_factor)
+        self.shared_experts = LlamaMLP(SimpleNamespace(
+            hidden_size=config.hidden_size,
+            intermediate_size=config.n_shared_experts
+            * config.moe_intermediate_size)) \
+            if config.n_shared_experts else None
+
+    def forward(self, x, live=None):
+        out = self.experts(x, live=live)
+        return out if self.shared_experts is None \
+            else out + self.shared_experts(x)
+
+
+class DeepseekDecoderLayer(Layer):
+    def __init__(self, config: DeepseekConfig, layer: int):
+        super().__init__()
+        self.self_attn = MLAttention(config)
+        self.sparse = layer >= config.first_k_dense_replace
+        self.mlp = DeepseekMoE(config) if self.sparse else LlamaMLP(config)
+        self.input_layernorm = RMSNorm(config.hidden_size,
+                                       config.rms_norm_eps)
+        self.post_attention_layernorm = RMSNorm(config.hidden_size,
+                                                config.rms_norm_eps)
+
+    def forward(self, hidden, cache=None, pos=None, paged=None, live=None,
+                pack=None):
+        h = self.self_attn(self.input_layernorm(hidden), cache=cache,
+                           pos=pos, paged=paged, pack=pack)
+        new_cache = None
+        if cache is not None:
+            h, new_cache = h
+        hidden = hidden + h
+        h = self.post_attention_layernorm(hidden)
+        # a router must not send padding to experts: it is told what is live
+        hidden = hidden + (self.mlp(h, live=live) if self.sparse
+                           else self.mlp(h))
+        return hidden if cache is None else (hidden, new_cache)
+
+
+class DeepseekModel(Layer):
+    def __init__(self, config: DeepseekConfig):
+        super().__init__()
+        self.config = config
+        self.embed_tokens = VocabParallelEmbedding(config.vocab_size,
+                                                   config.hidden_size)
+        self.layers = LayerList([DeepseekDecoderLayer(config, i)
+                                 for i in range(config.num_hidden_layers)])
+        self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
+
+    def forward(self, input_ids, caches=None, pos=None, paged=None,
+                pack=None):
+        hidden = self.embed_tokens(input_ids)
+        if caches is None:
+            for layer in self.layers:
+                hidden = layer(hidden)
+            return self.norm(hidden)
+        live = None
+        if pack is not None:
+            live = pack.live[:, None]
+        elif paged is not None:
+            # column t of row b is a real token while pos[b] + t is short
+            # of the row's length after this step (`paged[1]`)
+            t = jnp.arange(input_ids.shape[1], dtype=jnp.int32)
+            live = jnp.reshape(getattr(pos, "data", pos), (-1, 1)) + t \
+                < jnp.reshape(paged[1], (-1, 1))
+        new_caches = []
+        for layer, cache in zip(self.layers, caches):
+            hidden, new_cache = layer(hidden, cache=cache, pos=pos,
+                                      paged=paged, live=live, pack=pack)
+            new_caches.append(new_cache)
+        return self.norm(hidden), new_caches
+
+
+class DeepseekForCausalLM(Layer):
+    def __init__(self, config: DeepseekConfig):
+        super().__init__()
+        self.config = config
+        with parameter_dtype(config.dtype):
+            self.model = DeepseekModel(config)
+            self.lm_head = ColumnParallelLinear(
+                config.hidden_size, config.vocab_size, has_bias=False,
+                gather_output=False)
+
+    def forward(self, input_ids, labels=None):
+        if labels is not None:
+            raise NotImplementedError(
+                "training DeepseekForCausalLM is not wired: the loss would "
+                "lack the router's balancing terms")
+        return self.lm_head(self.model(input_ids))
+
+    # ---- the cached-decode contract (models/generation.py) ----
+    def init_cache(self, batch_size: int, max_len: int, dtype=None):
+        """Per layer a `generation.LatentKV`: `c [batch, 1, max_len,
+        kv_lora_rank]` and `r [batch, 1, max_len, lanes(qk_rope_head_dim)]`
+        (the rotary key in the first `qk_rope_head_dim` columns, zeros
+        behind)."""
+        from .generation import LatentKV
+        cfg = self.config
+        dt = dtype or self.model.embed_tokens.weight.dtype
+        return [LatentKV(
+            jnp.zeros((batch_size, 1, max_len, cfg.kv_lora_rank), dt),
+            jnp.zeros((batch_size, 1, max_len,
+                       _lanes(cfg.qk_rope_head_dim)), dt))
+            for _ in range(cfg.num_hidden_layers)]
+
+    def forward_with_cache(self, input_ids, caches, pos, paged=None,
+                           adapters=None, pack=None):
+        if adapters is not None:
+            raise NotImplementedError(
+                "LoRA adapters are not wired into DeepseekForCausalLM")
+        hidden, new_caches = self.model(input_ids, caches=caches, pos=pos,
+                                        paged=paged, pack=pack)
+        return self.lm_head(hidden), new_caches
+
+    def generate(self, input_ids, max_new_tokens=32, do_sample=False,
+                 temperature=1.0, top_k=0, eos_token_id=None, seed=0):
+        from .generation import generate
+        return generate(self, input_ids, max_new_tokens, do_sample,
+                        temperature, top_k, eos_token_id=eos_token_id,
+                        seed=seed)

@@ -164,6 +164,19 @@ class WindowKV(NamedTuple):
     v: jax.Array
 
 
+class LatentKV(NamedTuple):
+    """What `init_cache` returns for an attention layer that caches a
+    latent (multi-head latent attention, `models/deepseek.py`): `c [batch,
+    1, max_len, kv_lora_rank]`, the compressed keys-and-values after their
+    norm, and `r [batch, 1, max_len, qk_rope_head_dim]`, the one rotary
+    key every head shares, after RoPE. A pair like `(k, v)` of unequal
+    widths and one "head", addressed by position like `(k, v)`: its pages
+    can be shared, copied, exported and re-read, so a cache manager treats
+    it as it treats K/V pages and by this type only knows what to call it."""
+    c: jax.Array
+    r: jax.Array
+
+
 def make_decoder_fns(model):
     """Expose the prefill/decode-step builders for a cached-decode model.
 

@@ -152,6 +152,12 @@ class RMSNorm(Layer):
         return apply(f, x, self.weight)
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction for a context stretched `factor` times:
+    `0.1 mscale ln(factor) + 1` (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rope_inv_freq(head_dim, rope: dict):
     """(inv_freq [D/2] float32, the factor cos and sin are multiplied by)
     of one layer type's rotary parameters (`LlamaConfig.rope_parameters`).
@@ -160,8 +166,13 @@ def rope_inv_freq(head_dim, rope: dict):
     Hugging Face computes it): dimensions that turn more than `beta_fast`
     times over the original context keep their frequency, those that turn
     less than `beta_slow` times take it divided by `factor`, a linear ramp
-    between; cos and sin are scaled by `attention_factor` (0.1 ln(factor)
-    + 1 where the parameters give none)."""
+    between; cos and sin are scaled by `attention_factor`. Where the
+    parameters give none it is `yarn_mscale(factor)`, or, with the DeepSeek
+    family's `mscale` and `mscale_all_dim`, `yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)`: that family puts the correction
+    into the softmax scale (times `yarn_mscale(factor, mscale_all_dim)`
+    squared, the attention layer's to apply) and leaves cos and sin the
+    ratio, 1 where the two are equal."""
     theta = float(rope["rope_theta"])
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                            dtype=jnp.float32) / head_dim))
@@ -183,8 +194,12 @@ def rope_inv_freq(head_dim, rope: dict):
     ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
                     / max(high - low, 1e-3), 0.0, 1.0)
     attention_factor = rope.get("attention_factor")
-    if attention_factor is None:
-        attention_factor = 0.1 * math.log(factor) + 1.0
+    if attention_factor is None and rope.get("mscale") \
+            and rope.get("mscale_all_dim"):
+        attention_factor = yarn_mscale(factor, rope["mscale"]) \
+            / yarn_mscale(factor, rope["mscale_all_dim"])
+    elif attention_factor is None:
+        attention_factor = yarn_mscale(factor)
     return (inv_freq / factor * ramp + inv_freq * (1.0 - ramp),
             float(attention_factor))
 

@@ -16,7 +16,8 @@ import jax
 import numpy as np
 
 from ...core import dtypes
-from ...core.tensor import Parameter, Tensor, no_grad
+from ...core.tensor import (Parameter, Tensor, Unassigned,
+                            UnassignedParameterError, no_grad)
 from .. import initializer as I
 
 
@@ -65,6 +66,28 @@ def parameter_dtype(dtype):
         _PARAMETER_DTYPE[0] = outer
 
 
+_LAZY = [False]
+
+
+class LazyGuard(contextlib.ContextDecorator):
+    """Layers constructed inside the block allocate nothing: every
+    `create_parameter` records shape, type and (as its layer sets them)
+    partition spec and the other attributes, and the parameter holds an
+    `Unassigned` in place of an array. `p.data = array` makes it real.
+    For a caller that is about to give the model its weights (a checkpoint,
+    seeded weights) and cannot hold the constructor's copy beside them.
+    A parameter used before it is assigned raises
+    `UnassignedParameterError` by name. (Paddle's own later API name.)"""
+
+    def __enter__(self):
+        self._outer, _LAZY[0] = _LAZY[0], True
+        return self
+
+    def __exit__(self, *exc):
+        _LAZY[0] = self._outer
+        return False
+
+
 class Layer:
     def __init__(self, name_scope=None, dtype=None):
         object.__setattr__(self, "_parameters", OrderedDict())
@@ -87,6 +110,8 @@ class Layer:
             if params is None:
                 raise RuntimeError("call Layer.__init__ before assigning params")
             params[name] = value
+            if isinstance(value.data, Unassigned):
+                value.data.label = f"{type(self).__name__}.{name}"
             buffers.pop(name, None) if buffers else None
             layers.pop(name, None) if layers else None
             object.__setattr__(self, name, value)
@@ -110,7 +135,7 @@ class Layer:
         dtype = dtype or self._dtype
         init = attr.initializer or default_initializer or (
             I.Constant(0.0) if is_bias else I._GLOBAL_DEFAULT[0])
-        data = init(shape, dtype)
+        data = Unassigned(shape, dtype) if _LAZY[0] else init(shape, dtype)
         p = Parameter(data, name=attr.name, trainable=attr.trainable)
         p.optimize_attr = {"learning_rate": attr.learning_rate}
         p.regularizer = attr.regularizer
@@ -282,6 +307,12 @@ class Layer:
     # ---- functional bridge (the TPU fast path) ----
     def functional_state(self):
         """Return (param_arrays, buffer_arrays) pytrees keyed by structured name."""
+        lazy = [k for k, p in self.named_parameters()
+                if isinstance(p.data, Unassigned)]
+        if lazy:
+            raise UnassignedParameterError(
+                f"{len(lazy)} parameter(s) created under LazyGuard have not "
+                f"been assigned: {lazy[:4]}{' ...' if len(lazy) > 4 else ''}")
         params = {k: p.data for k, p in self.named_parameters() if p.trainable}
         frozen = {k: p.data for k, p in self.named_parameters() if not p.trainable}
         bufs = {k: b.data for k, b in self.named_buffers()}

@@ -226,8 +226,60 @@ class MoELayer(Layer):
         return out
 
 
+def route(logits, top_k, norm_topk_prob=False, scoring="softmax",
+          n_group=1, topk_group=1, select_bias=None, routed_scale=1.0):
+    """Router logits `[T, E]` float32 -> (gates `[T, top_k]` float32, the
+    chosen experts `[T, top_k]` int32).
+
+        s = softmax(logits) | sigmoid(logits)             (`scoring`)
+        c = s + select_bias             for choosing only, never in a gate
+        groups: E experts in `n_group` equal groups; a group's score is
+                the sum of its 2 largest c; the `topk_group` best groups
+                are eligible                     (n_group 1: every expert)
+        S = the top_k largest c among the eligible experts
+        g_e = s_e, over S renormalised to sum 1 if `norm_topk_prob`,
+              times `routed_scale`
+
+    The defaults are the plain softmax top-k, on the operations it always
+    took (OLMoE's, granite's and Mellum's arithmetic stays bit for bit);
+    the rest is the DeepSeek-V3 family's router (Hugging Face's
+    `DeepseekV3TopkRouter`; an ineligible expert is out of the choice
+    whatever the sign of c, where that code fills in 0)."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+    s = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if n_group == 1 and select_bias is None:
+        w, idx = jax.lax.top_k(s, top_k)
+    else:
+        T, E = s.shape
+        if E % n_group or not 0 < topk_group <= n_group:
+            raise ValueError(f"{topk_group} of {n_group} groups over {E} "
+                             "experts")
+        c = s if select_bias is None \
+            else s + select_bias.astype(jnp.float32)
+        if n_group > 1:
+            grouped = c.reshape(T, n_group, E // n_group)
+            score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            _, best = jax.lax.top_k(score, topk_group)      # [T, topk_group]
+            eligible = jnp.any(
+                best[..., None] == jnp.arange(n_group, dtype=best.dtype),
+                axis=1)                                      # [T, n_group]
+            c = jnp.where(eligible[..., None], grouped,
+                          -jnp.inf).reshape(T, E)
+        _, idx = jax.lax.top_k(c, top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if routed_scale != 1.0:
+        w = w * routed_scale
+    return w, idx
+
+
 def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
-                         norm_topk_prob=False, live=None, held=None):
+                         norm_topk_prob=False, live=None, held=None,
+                         scoring="softmax", n_group=1, topk_group=1,
+                         select_bias=None, routed_scale=1.0):
     """Dropless top-k SwiGLU experts over arrays. x `[..., H]`; router_w
     `[H, E]`; w_gate, w_up `[E, H, F]`; w_down `[E, F, H]`; `live` a bool
     mask over x's leading axes (None: every position is live). Returns
@@ -244,7 +296,10 @@ def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
         p = softmax_float32(x router_w);  S = the top_k largest p
         out = sum_{e in S} p_e (silu(x Wg_e) * (x Wu_e)) Wd_e
 
-    with p renormalised over S if `norm_topk_prob`. No capacity: every
+    with p renormalised over S if `norm_topk_prob`; `scoring`, `n_group`,
+    `topk_group`, `select_bias` `[E]` and `routed_scale` make it another
+    router (`route`: sigmoid scores, group-limited choice, a bias on the
+    choice alone, scaled gates), over all E as published. No capacity: every
     live position reaches all its `top_k` experts. A position that is not
     live (the padding of a decode row, a free slot) is routed to no expert,
     takes no expert row, counts nowhere, and gets zeros.
@@ -264,9 +319,8 @@ def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
     xt = x.reshape(-1, H)
     T = xt.shape[0]
     logits = xt.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    if norm_topk_prob:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    w, idx = route(logits, top_k, norm_topk_prob, scoring, n_group,
+                   topk_group, select_bias, routed_scale)
     if live is not None:
         idx = jnp.where(live.reshape(T, 1), idx, E)    # E: no expert
     if held is not None:
@@ -292,14 +346,18 @@ def moe_dropless_forward(x, router_w, w_gate, w_up, w_down, top_k,
 class DroplessMoE(Layer):
     """A sparse SwiGLU FFN: a router and `num_experts` experts of width
     `d_hidden`, `top_k` per position, no shared expert, no bias
-    (`moe_dropless_forward`). `held=(first, count)`: the layer holds that
+    (`moe_dropless_forward`; `scoring`, `n_group`, `topk_group`,
+    `routed_scale` and `select_bias=True`, a `[num_experts]` parameter
+    `select_bias`, are `route`'s). `held=(first, count)`: the layer holds that
     share of the experts (its weights are `[count, ...]`, `num_held` =
     count) and returns the share's part of the sum; None: all of them.
     `forward(x, live=None)`; under `collect_expert_counts()` each call
     also hands over its per-expert counts of live assignments (`[num_held]`)."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k,
-                 norm_topk_prob=False, held=None):
+                 norm_topk_prob=False, held=None, scoring="softmax",
+                 n_group=1, topk_group=1, select_bias=False,
+                 routed_scale=1.0):
         super().__init__()
         if not 0 < top_k <= num_experts:
             raise ValueError(f"top_k {top_k} of {num_experts} experts")
@@ -311,6 +369,16 @@ class DroplessMoE(Layer):
         self.norm_topk_prob = norm_topk_prob
         self.held = None if held is None else (int(held[0]), int(held[1]))
         self.num_held = num_experts if held is None else self.held[1]
+        self.router = dict(scoring=scoring, n_group=int(n_group),
+                           topk_group=int(topk_group),
+                           routed_scale=float(routed_scale))
+        # a per-expert bias on the router's choice (never on a gate): a
+        # buffer of the model's, zero until a checkpoint gives it
+        self.select_bias = self.create_parameter(
+            [num_experts], is_bias=True) if select_bias else None
+        if self.select_bias is not None:      # set by balancing, not SGD
+            self.select_bias.trainable = False
+            self.select_bias.stop_gradient = True
         init = I.Normal(0.0, 0.02)
         self.router_weight = self.create_parameter(
             [d_model, num_experts], default_initializer=init)
@@ -325,14 +393,17 @@ class DroplessMoE(Layer):
 
     def forward(self, x, live=None):
         top_k, norm, held = self.top_k, self.norm_topk_prob, self.held
+        router = self.router
 
-        def f(xa, rw, wg, wu, wd):
-            out, counts = moe_dropless_forward(xa, rw, wg, wu, wd, top_k,
-                                               norm, live, held)
+        def f(xa, rw, wg, wu, wd, *bias):
+            out, counts = moe_dropless_forward(
+                xa, rw, wg, wu, wd, top_k, norm, live, held,
+                select_bias=bias[0] if bias else None, **router)
             sink = getattr(_collecting, "sink", None)
             if sink is not None:
                 sink.append(counts)
             return out
 
+        bias = () if self.select_bias is None else (self.select_bias,)
         return apply(f, x, self.router_weight, self.w_gate, self.w_up,
-                     self.w_down)
+                     self.w_down, *bias)

@@ -925,7 +925,7 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, ring=None):
 
 
 def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
-                     window=None):
+                     window=None, q_rope=None):
     """Length-masked attention of q [B, H, T, D] over padded static caches
     [B, Hkv, L, D] (GQA: Hkv divides H; kv heads are repeated).
 
@@ -949,11 +949,15 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
     as None the contiguous cache is walked from the window's first block.
     Either way the blocks walked are the logical ones, so both give the
     same bits at one block size.
+
+    `q_rope`: the caches are a latent and its rotary key and q has the key
+    projection absorbed (`ragged_paged_attention(q_rope=)`); `scale` is
+    then required.
     """
     from .paged_attention import (DEFAULT_KV_BLOCK, ragged_paged_attention,
                                   trivial_block_table)
     B, H, T, D = q.shape
-    if scale is None:
+    if scale is None and q_rope is None:
         scale = 1.0 / (D ** 0.5)
     if paged is not None:
         # pool slabs may carry chunk write-padding past the page region,
@@ -962,7 +966,7 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
         return ragged_paged_attention(
             q, k_cache, v_cache, block_table, seq_lens, jnp.asarray(pos),
             block_len=int(block_len), pages_per_row=int(pages_per_row),
-            scale=scale, window=window)
+            scale=scale, window=window, q_rope=q_rope)
     L = k_cache.shape[2]
     table, nb = trivial_block_table(B, L, DEFAULT_KV_BLOCK)
     pad = nb * DEFAULT_KV_BLOCK - L
@@ -975,4 +979,4 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
     return ragged_paged_attention(q, k_cache, v_cache, table, seq_lens,
                                   q_pos, block_len=DEFAULT_KV_BLOCK,
                                   pages_per_row=nb, scale=scale,
-                                  window=window)
+                                  window=window, q_rope=q_rope)

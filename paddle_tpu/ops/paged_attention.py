@@ -94,6 +94,19 @@ columns and a ring page is found per page (`(g*P + p) mod R`: R need be no
 multiple of P), so a ring page visited under two logical blocks (the
 walk's first and last may share one) shows each its own columns.
 
+A latent cache (PR 36). With `q_rope=` the two slabs are multi-head latent
+attention's: `c [N, 1, L_slab, R]`, the compressed latent every head's keys
+and values are projections of, and `r [N, 1, L_slab, Dr]`, the one rotary
+key the heads share. The caller has absorbed the key projection into its
+queries (`q [B, H, Tq, R]`) and applies the value projection to the result,
+so a step's scores are `scale * (q . c + q_rope . r)` (one masked matrix
+from two products), its values the latent page itself, read once: the H
+query heads are one "GQA group" over one KV head whose K is 576 wide and
+whose V is its first 512 columns, already in VMEM. The same walk, pipeline
+and masking under the `pallas_call` name `paged_latent`; `_choose_tile`
+folds as many heads into a tile's rows as the budget holds (32 x 16 rows at
+the serve cell's shape, so G = 2 and the small page is fetched twice).
+
 Numerics: flash-style online softmax with the repo's exact-zero masking
 convention (ops/attention.py `_fwd_kernel`): masked scores sit at
 `_NEG_INF`, `p = where(s <= _NEG_INF/2, 0, exp(s - m_new))` contributes an
@@ -137,6 +150,14 @@ DEFAULT_KV_BLOCK = 8
 # a device trace) and in `pallas_mode`'s counters. It does not hold the
 # full walk's name, because trace readers find a kernel by substring.
 WINDOW_KERNEL = "paged_window"
+# The latent walk's name (multi-head latent attention's cache): likewise
+# neither of the other two's.
+LATENT_KERNEL = "paged_latent"
+
+
+def _kernel_name(window, latent: bool) -> str:
+    return LATENT_KERNEL if latent else \
+        "paged_attention" if window is None else WINDOW_KERNEL
 
 
 # Keys one grid step of the kernel covers: one lane tile. A step takes the
@@ -223,11 +244,14 @@ def _window_groups(window: int, Tq: int, block_len: int, ring_pages: int):
 
 def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                block_len: int, pages_per_row: int, scale: float,
-               window: int = None):
+               window: int = None, q_rope=None):
     """lax.scan over logical blocks, carrying (m, l, acc) — the same
     masked-score -> exact-zero-p -> alpha-rescale sequence as the kernel,
     one compiled program regardless of grid size. With `window` step i is
-    row b's logical block `first[b] + i`, read from the ring."""
+    row b's logical block `first[b] + i`, read from the ring. With
+    `q_rope` the caches are a latent and its rotary key (module
+    docstring): the scores are the sum of two products and the latent page
+    is the values' page too."""
     B, H, Tq, D = q.shape
     Hkv = k_cache.shape[1]
     n_rep = H // Hkv
@@ -239,7 +263,7 @@ def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
 
     def page(cache, r, c):   # [Hkv, KB, D]: columns c.. of slab row r
         return jax.lax.dynamic_slice(cache, (r, 0, c, 0),
-                                     (1, Hkv, block_len, D))[0]
+                                     (1, Hkv, block_len, cache.shape[3]))[0]
 
     ring_pages = block_table.shape[1]
     if window is not None:
@@ -261,7 +285,12 @@ def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
             k_j = jnp.repeat(k_j, n_rep, axis=1)
             v_j = jnp.repeat(v_j, n_rep, axis=1)
         s = jnp.einsum("bhtd,bhkd->bhtk", q, k_j,
-                       preferred_element_type=jnp.float32) * scale
+                       preferred_element_type=jnp.float32)
+        if q_rope is not None:
+            s, v_j = s + jnp.einsum(
+                "bhtd,bhkd->bhtk", q_rope, v_j,
+                preferred_element_type=jnp.float32), k_j
+        s = s * scale
         if window is None:
             col = j * block_len + jnp.arange(block_len, dtype=jnp.int32)
             col = col[None, None, :]                         # [1, 1, KB]
@@ -296,10 +325,9 @@ def _head_dot(a, b, a_dim, b_dim):
     return jax.vmap(lambda x, y: _dot(x, y, a_dim, b_dim))(a, b)
 
 
-def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref, *,
+def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
                   block_len, pages, pages_per_row, n_groups, parts, scale,
-                  Tq, window=None):
+                  Tq, window=None, latent=False):
     """Grid (B, G); one step is one slot's whole walk for every head of
     the tile: a loop over the row's live groups of `pages` consecutive
     logical pages. q/o tiles [heads, fold*Tq, D] (a KV head's query heads
@@ -315,7 +343,13 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     and a row with no live group one pass that only fetches.
     table/lens/pos arrive via scalar prefetch. With `window` the walk
     starts at the group that holds the window's first block and the mask
-    has a lower edge too."""
+    has a lower edge too. With `latent` a second q tile follows the first
+    (`[1, rows, rope width]`), "K" is the latent slab and "V" the rotary
+    key's: the scores add the second product, and the values are the
+    latent page that is already in VMEM."""
+    qr_ref = refs[0] if latent else None
+    (k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, acc_ref, m_ref,
+     l_ref) = refs[1:] if latent else refs
     b, g = pl.program_id(0), pl.program_id(1)
     B, G = pl.num_programs(0), pl.num_programs(1)
     heads, rows = q_ref.shape[1], q_ref.shape[2]
@@ -414,7 +448,11 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
             if window is not None:
                 keep &= col > row_pos - window
             vgrp = vbuf[slot]                             # [heads, keys, D]
-            s = _head_dot(q_ref[0], kbuf[slot], 1, 1) * scale
+            s = _head_dot(q_ref[0], kbuf[slot], 1, 1)
+            if latent:
+                s = s + _head_dot(qr_ref[0], vgrp, 1, 1)
+                vgrp = kbuf[slot]
+            s = s * scale
             s = jnp.where(keep[None], s, _NEG_INF)        # [heads, rows, keys]
             m_prev = m_ref[...]
             l_prev = l_ref[...]
@@ -437,9 +475,9 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 @functools.partial(jax.jit, static_argnames=(
     "block_len", "pages_per_row", "scale", "window", "heads", "fold",
     "n_groups", "interpret"))
-def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos, *,
-                block_len, pages_per_row, scale, window, heads, fold,
-                n_groups, interpret):
+def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+                q_rope=None, *, block_len, pages_per_row, scale, window,
+                heads, fold, n_groups, interpret):
     """The kernel's `pallas_call` at one tile. Jitted at module level with
     every integer static, so the call sites of one traced program that
     agree on shapes and window (a step's layers, unrolled) share one
@@ -449,20 +487,31 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos, *,
     n_rep = H // k_cache.shape[1]
     P = _group_pages(block_len)
     rows = fold * Tq
-    name = "paged_attention" if window is None else WINDOW_KERNEL
-    tile = pl.BlockSpec((1, heads, rows, D),
-                        lambda b, g, table_ref, lens_ref, pos_ref:
-                        (b, g, 0, 0))
+    latent = q_rope is not None
+    name = _kernel_name(window, latent)
+
+    def tile(width):
+        return pl.BlockSpec((1, heads, rows, width),
+                            lambda b, g, table_ref, lens_ref, pos_ref:
+                            (b, g, 0, 0))
+
     slab = pl.BlockSpec(memory_space=pl.ANY)
-    group = (2, heads, P * block_len, D)       # two buffers: one in flight
+
+    def group(cache):                          # two buffers: one in flight
+        return pltpu.VMEM((2, heads, P * block_len, cache.shape[3]),
+                          cache.dtype)
+
+    queries = [q.reshape(B, H // fold, rows, D)]
+    if latent:
+        queries.append(q_rope.reshape(B, H // fold, rows, -1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, H // (heads * fold)),
-        in_specs=[tile, slab, slab],
-        out_specs=tile,
+        in_specs=[tile(x.shape[3]) for x in queries] + [slab, slab],
+        out_specs=tile(D),
         scratch_shapes=[
-            pltpu.VMEM(group, k_cache.dtype),
-            pltpu.VMEM(group, v_cache.dtype),
+            group(k_cache),
+            group(v_cache),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),       # the buffer in flight
             pltpu.VMEM((heads, rows, D), jnp.float32),
@@ -474,7 +523,7 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos, *,
         _paged_kernel, block_len=block_len, pages=P,
         pages_per_row=pages_per_row, n_groups=n_groups,
         parts=n_rep // fold,       # tiles that share one KV head (1: none)
-        scale=scale, Tq=Tq, window=window)
+        scale=scale, Tq=Tq, window=window, latent=latent)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H // fold, rows, D), q.dtype),
@@ -483,14 +532,14 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos, *,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(jnp.maximum(block_table, 0), seq_lens, q_pos,
-      q.reshape(B, H // fold, rows, D), k_cache, v_cache)
+    )(jnp.maximum(block_table, 0), seq_lens, q_pos, *queries, k_cache,
+      v_cache)
     return out.reshape(B, H, Tq, D)
 
 
 def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                  block_len: int, pages_per_row: int, scale: float,
-                 window: int = None):
+                 window: int = None, q_rope=None):
     """Choose the tile from the shapes, record it, and call the kernel
     through its one jitted entry."""
     B, H, Tq, D = q.shape
@@ -498,14 +547,17 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
     P = _group_pages(block_len)
     n_groups = -(-ring_pages // P) if window is None \
         else _window_groups(window, Tq, block_len, ring_pages)
-    heads, fold = _choose_tile(H, k_cache.shape[1], Tq, block_len, D,
+    # a latent tile holds both q tiles and both pages
+    width = D if q_rope is None else D + q_rope.shape[3]
+    heads, fold = _choose_tile(H, k_cache.shape[1], Tq, block_len, width,
                                q.dtype.itemsize)
-    name = "paged_attention" if window is None else WINDOW_KERNEL
+    name = _kernel_name(window, q_rope is not None)
     pallas_mode.note_tiling(name, grid=(B, H // (heads * fold)),
                             groups=n_groups, pages=P, heads=heads,
                             rows=fold * Tq)
+    more = () if q_rope is None else (q_rope,)
     return _paged_call(
-        q, k_cache, v_cache, block_table, seq_lens, q_pos,
+        q, k_cache, v_cache, block_table, seq_lens, q_pos, *more,
         block_len=block_len, pages_per_row=pages_per_row,
         scale=float(scale), window=window, heads=heads, fold=fold,
         n_groups=n_groups, interpret=pallas_mode.interpret(name))
@@ -514,7 +566,8 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
 def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
                            q_pos, *, block_len: int,
                            pages_per_row: int = None, scale: float = None,
-                           impl: str = None, window: int = None):
+                           impl: str = None, window: int = None,
+                           q_rope=None):
     """Attention of q [B, H, Tq, D] over block-table-addressed KV pages.
 
     k_cache/v_cache: [N, Hkv, L_slab, D] slabs (N need not equal B — block
@@ -529,8 +582,20 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
     table's `max_blocks` columns are a ring (module docstring); a
     `block_table` of None is then each row's own ring, `pages_per_row`
     pages of slab row b (N == B).
+    q_rope [B, H, Tq, Dr]: the caches are a latent `c [N, 1, L_slab, D]`
+    and its rotary key `r [N, 1, L_slab, Dr]` (module docstring, "A latent
+    cache"): scores `scale * (q . c + q_rope . r)`, values c; the result
+    is [B, H, Tq, D] in the latent's space. `scale` is then the caller's
+    to give.
     """
     B, H, Tq, D = q.shape
+    if q_rope is not None:
+        if window is not None or scale is None or k_cache.shape[1] != 1 \
+                or v_cache.shape[3] != q_rope.shape[3]:
+            raise ValueError(
+                "a latent cache: one head, no window, the caller's scale, "
+                f"and a rotary key as wide as q_rope (c {k_cache.shape}, "
+                f"r {v_cache.shape}, q_rope {q_rope.shape})")
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if pages_per_row is None:
@@ -557,11 +622,13 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
             f"cache length {k_cache.shape[2]} cannot back {pages_per_row} "
             f"pages of {block_len} tokens")
     if impl == "scan":
-        pallas_mode.count(
-            "paged_attention" if window is None else WINDOW_KERNEL, "scan")
+        pallas_mode.count(_kernel_name(window, q_rope is not None), "scan")
         impl_fn = _scan_impl
     else:
         impl_fn = _pallas_impl
+    if q_rope is not None:
+        return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+                       block_len, pages_per_row, scale, None, q_rope)
     if window is None:
         return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                        block_len, pages_per_row, scale)

@@ -80,6 +80,17 @@ so on a pool that holds one (`windowed`) prefix sharing (`attach_blocks`,
 `register_cached`), `cow_copy`, `export_rows` / `import_rows`,
 `export_page` / `import_page` and a `rewind_length` of more than the slack
 raise `WindowRingError`; `defrag` scrubs a freed row's whole ring.
+
+A fourth kind: latent pages (PR 36). A layer of multi-head latent
+attention (`init_cache` answers with a `models.generation.LatentKV`) keeps
+per token one compressed latent and one rotary key shared by every head,
+`(c [slots, 1, slab_len, kv_lora_rank], r [slots, 1, slab_len,
+qk_rope_head_dim])`: a pair of unequal widths with one "head", addressed
+by position exactly as `(k, v)` is. So it is paged like `paged`:
+`attach_blocks`, `register_cached`, `cow_copy`, `export_*` / `import_*`,
+`rewind_length` and `defrag` work on it as they are, the prefix cache
+stays on, nothing is refused; `layer_kinds` says `latent` and `kv_bytes()`
+counts it under its own name.
 """
 from __future__ import annotations
 
@@ -91,9 +102,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.generation import RecurrentState, WindowKV
+from ...models.generation import LatentKV, RecurrentState, WindowKV
 
-PAGED, RECURRENT, WINDOW = "paged", "recurrent", "window"
+PAGED, RECURRENT, WINDOW, LATENT = "paged", "recurrent", "window", "latent"
 
 
 class RecurrentStateError(NotImplementedError):
@@ -159,10 +170,12 @@ class SlotPagedKVPool:
         # what each layer keeps per slot, told by its entry's type
         self.layer_kinds: List[str] = [
             RECURRENT if isinstance(e, RecurrentState)
-            else WINDOW if isinstance(e, WindowKV) else PAGED
+            else WINDOW if isinstance(e, WindowKV)
+            else LATENT if isinstance(e, LatentKV) else PAGED
             for e in entries]
         self.recurrent = RECURRENT in self.layer_kinds
         self.windowed = WINDOW in self.layer_kinds
+        self.latent = LATENT in self.layer_kinds
         # the window layers' ring, in columns and pages (None: no such
         # layer); every window layer of a model has the one window
         rings = {int(e.k.shape[2]) - self.pad_tokens
@@ -229,12 +242,16 @@ class SlotPagedKVPool:
 
     def kv_bytes(self) -> Dict[str, int]:
         """Bytes of the K/V slabs by what they are: "full" (a slot's whole
-        context) and "window" (a ring), all slots and layers."""
+        context), "window" (a ring) and, on a pool that holds one,
+        "latent" (a slot's whole context as a latent and a rotary key),
+        all slots and layers."""
+        names = {PAGED: "full", WINDOW: "window", LATENT: "latent"}
         out = {"full": 0, "window": 0}
+        if self.latent:
+            out["latent"] = 0
         for (a, b), kind in zip(self.slabs, self.layer_kinds):
             if kind != RECURRENT:
-                out["window" if kind == WINDOW else "full"] += \
-                    int(a.nbytes) + int(b.nbytes)
+                out[names[kind]] += int(a.nbytes) + int(b.nbytes)
         return out
 
     @property
@@ -708,9 +725,9 @@ class SlotPagedKVPool:
                         layers.append((np.concatenate(kparts, axis=1),
                                        np.concatenate(vparts, axis=1)))
                     else:
-                        hkv, d = k.shape[1], k.shape[3]
-                        empty = np.zeros((hkv, 0, d), dtype=k.dtype)
-                        layers.append((empty, empty.copy()))
+                        layers.append(tuple(
+                            np.zeros((a.shape[1], 0, a.shape[3]), a.dtype)
+                            for a in (k, v)))
                 rows[slot] = {"length": length, "layers": layers}
         return {"block_len": self.block_len, "capacity": self.capacity,
                 "rows": rows}
@@ -833,6 +850,7 @@ class SlotPagedKVPool:
         # a ring holds no cached page (`register_cached` refuses): a freed
         # row's ring goes whole
         masks = {PAGED: jnp.asarray(keep)}
+        masks[LATENT] = masks[PAGED]
         if self.windowed:
             masks[WINDOW] = jnp.asarray(
                 np.repeat(keep[:, :1], self.ring_len + self.pad_tokens, 1))

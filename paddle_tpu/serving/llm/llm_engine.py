@@ -661,6 +661,11 @@ class LLMEngine:
                     "window layers, whose keys the pool keeps in a ring; "
                     "a shared prefix's pages have aged out of it")
                 self.enable_prefix_cache = False
+        # the slabs' bytes by kind, where the pool holds a second kind of
+        # page beside (or in place of) full-length K/V: a ring, or latent
+        # pages (PR 36: position-addressed like K/V, so nothing above is
+        # switched off or refused for them)
+        if self.pool.windowed or self.pool.latent:
             self.metrics.set_kv_pool_bytes(self.pool.kv_bytes())
         # host-RAM spill tier (ISSUE 19): a byte-budgeted LRU the prefix
         # cache spills refcount-0 pages into on pressure eviction; the
@@ -2931,17 +2936,20 @@ class LLMEngine:
                 # of them it starts from zero (position 0)
                 span_args["recurrent_rows"] = int(np.count_nonzero(adv))
                 started = int(np.count_nonzero((adv > 0) & (pos == 0)))
-            kv_tokens = None
+            # what this step's attention calls must read: a row's keys
+            # after the step (one full or latent layer's call) and, on a
+            # pool with a ring, the part of them inside the window
+            after = (pos + adv)[adv > 0]
+            in_window = 0
             if self.pool.windowed:
-                # what this step's attention calls must read: a row's keys
-                # after the step, and the part of them inside the window;
                 # rows whose ring has begun to overwrite its oldest keys
-                after = (pos + adv)[adv > 0]
-                kv_tokens = (int(np.minimum(after, self.pool.window).sum()),
-                             int(after.sum()))
+                in_window = int(np.minimum(after, self.pool.window).sum())
                 span_args["window_rows"] = int(after.size)
                 span_args["wrapped_rows"] = int(np.count_nonzero(
                     after > self.pool.ring_len))
+            if self.pool.latent:
+                span_args["latent_rows"] = int(after.size)
+            kv_tokens = (in_window, int(after.sum()))
             with RecordEvent(SPAN_SERVE_DISPATCH, **span_args):
                 t0 = self.clock.now()
                 fn = self._step()
@@ -3003,8 +3011,7 @@ class LLMEngine:
                                                 self.step_tokens, deferred)
                     if started:
                         self.metrics.on_recurrent_rows_started(started)
-                    if kv_tokens is not None:
-                        self.metrics.on_kv_tokens(*kv_tokens)
+                    self.metrics.on_kv_tokens(*kv_tokens)
                     if ahead_of is not None:
                         self.metrics.on_step_overlapped()
                     if decode_slots:

@@ -257,8 +257,9 @@ def _quantile(sorted_vals, q: float) -> Optional[float]:
 # `nn.layer.moe.EXPERT_TOKENS` is for the expert counts.
 RECURRENT_STATE_BYTES = 0
 # Likewise the K/V slabs of the newest LLM engine whose pool keeps window
-# layers in a ring, by kind: {"full": bytes, "window": bytes}
-# (`LLMMetrics.set_kv_pool_bytes`; empty: no such engine yet).
+# layers in a ring or latent pages, by kind: {"full": bytes, "window":
+# bytes[, "latent": bytes]} (`LLMMetrics.set_kv_pool_bytes`; empty: no
+# such engine yet).
 KV_POOL_BYTES: dict = {}
 
 
@@ -568,10 +569,10 @@ class LLMMetrics(ServingMetrics):
         KV_POOL_BYTES = dict(by_kind)
 
     def on_kv_tokens(self, window: int, full: int):
-        """One committed unified step of an engine with window layers:
-        the keys one window layer's call had to read (sum over the active
-        rows of min(length, window)) and one full layer's (sum of the
-        lengths)."""
+        """One committed unified step: the keys one window layer's call
+        had to read (sum over the active rows of min(length, window); 0
+        on an engine without window layers) and one full or latent
+        layer's (sum of the lengths; every engine)."""
         with self._lock:
             self.counters["window_kv_tokens"] += int(window)
             self.counters["full_kv_tokens"] += int(full)
@@ -809,9 +810,10 @@ class LLMMetrics(ServingMetrics):
             b.family(f"{px}_kv_pool_bytes", "gauge")
             for kind, nbytes in sorted(s["kv_pool_bytes"].items()):
                 b.sample(f"{px}_kv_pool_bytes", nbytes, {"kind": kind})
-            for name in ("window_kv_tokens", "full_kv_tokens"):
-                b.family(f"{px}_{name}_total", "counter")
-                b.sample(f"{px}_{name}_total", s[name])
+            b.family(f"{px}_window_kv_tokens_total", "counter")
+            b.sample(f"{px}_window_kv_tokens_total", s["window_kv_tokens"])
+        b.family(f"{px}_full_kv_tokens_total", "counter")
+        b.sample(f"{px}_full_kv_tokens_total", s["full_kv_tokens"])
         if self.moe_source is not None:
             b.family(f"{px}_moe_assignments_total", "counter")
             b.sample(f"{px}_moe_assignments_total", s["moe_assignments"])

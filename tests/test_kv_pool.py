@@ -8,11 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.models.generation import RecurrentState, WindowKV
-from paddle_tpu.serving.llm.kv_pool import (PAGED, WINDOW,
+from paddle_tpu.models.generation import LatentKV, RecurrentState, WindowKV
+from paddle_tpu.serving.llm.kv_pool import (LATENT, PAGED, WINDOW,
                                             RecurrentStateError,
                                             SlotPagedKVPool,
                                             WindowRingError)
+from paddle_tpu.serving.llm.prefix_cache import PrefixCache
 
 HKV, D = 2, 4
 
@@ -189,3 +190,141 @@ def test_rings_of_different_windows_are_refused():
                 for w in (32, 64)]
     with pytest.raises(ValueError, match="different rings"):
         SlotPagedKVPool(init_cache, 2, 8, 32, pad_tokens=16)
+
+
+# ---- a fourth kind: latent pages (PR 36) ----
+
+RANK, ROPE = 6, 2
+
+
+def _latent_pool(layers=2, block_len=8, n_blocks=8, pad=16, slots=3):
+    """An `init_cache` like `DeepseekForCausalLM.init_cache`: per layer a
+    latent and a rotary key, one "head", unequal widths."""
+    def init_cache(batch, max_len, dtype=None):
+        dt = dtype or jnp.float32
+        return [LatentKV(jnp.zeros((batch, 1, max_len, RANK), dt),
+                         jnp.zeros((batch, 1, max_len, ROPE), dt))
+                for _ in range(layers)]
+    return SlotPagedKVPool(init_cache, slots, block_len, n_blocks,
+                           pad_tokens=pad)
+
+
+def _fill(pool, seed=0):
+    """Distinct values in every column of every slab."""
+    rng = np.random.default_rng(seed)
+    pool.slabs = [tuple(jnp.asarray(rng.normal(size=a.shape), a.dtype)
+                        for a in entry) for entry in pool.slabs]
+
+
+def test_latent_kind_and_bytes_by_kind():
+    pool = _latent_pool()
+    assert pool.layer_kinds == [LATENT, LATENT]
+    assert not pool.windowed and not pool.recurrent
+    assert pool.ring_len is None and pool.window is None
+    assert [(c.shape, r.shape) for c, r in pool.slabs] == [
+        ((3, 1, 80, RANK), (3, 1, 80, ROPE))] * 2
+    assert pool.kv_bytes() == {"full": 0, "window": 0,
+                               "latent": 2 * 3 * 80 * (RANK + ROPE) * 4}
+    assert pool.recurrent_state_bytes == 0
+    assert "latent" not in _pool(kinds=(PAGED,)).kv_bytes()
+
+
+@pytest.mark.parametrize("operation", [
+    "attach_blocks", "cow_copy", "export_rows", "export_page",
+    "rewind_length", "defrag", "prefix cache hit"])
+def test_every_page_operation_works_on_a_latent_pool(operation):
+    """Latent pages are addressed by position like K/V pages: nothing is
+    refused, and each operation moves both slabs of the pair, each at its
+    own width."""
+    pool = _latent_pool()
+    _fill(pool)
+    a = pool.allocate(40)
+    pool.set_length(a, 40)
+    before = [tuple(np.asarray(x) for x in e) for e in pool.slabs]
+    if operation == "attach_blocks":
+        pages = [a * pool.n_blocks + j for j in range(3)]
+        for page in pages:
+            pool.register_cached(page)
+        b = pool.allocate(40)
+        pool.attach_blocks(b, pages)
+        pool.set_length(b, 30)
+        assert pool.block_table[b][:3] == pages
+        assert np.asarray(pool.device_block_table())[b, :3].tolist() == pages
+        assert all(pool.refcount[page] == 1 for page in pages)
+    elif operation == "cow_copy":
+        page = a * pool.n_blocks + 2
+        pool.register_cached(page)
+        b = pool.allocate(40)
+        pool.cow_copy(page, b)
+        for (c, r), (c0, r0) in zip(pool.slabs, before):
+            assert np.array_equal(np.asarray(c[b, :, 16:24]), c0[a, :, 16:24])
+            assert np.array_equal(np.asarray(r[b, :, 16:24]), r0[a, :, 16:24])
+            assert np.array_equal(np.asarray(c[b, :, :16]), c0[b, :, :16])
+    elif operation == "export_rows":
+        out = pool.export_rows([a])
+        (c, r), = out["rows"][a]["layers"][:1]
+        assert c.shape == (1, 40, RANK) and r.shape == (1, 40, ROPE)
+        assert np.array_equal(c, before[0][0][a, :, :40])
+        assert np.array_equal(r, before[0][1][a, :, :40])
+        other = _latent_pool()
+        dst = other.import_rows(out)[a]
+        for (c, r), (c0, r0) in zip(other.slabs, before):
+            assert np.array_equal(np.asarray(c[dst, :, :40]), c0[a, :, :40])
+            assert np.array_equal(np.asarray(r[dst, :, :40]), r0[a, :, :40])
+        # an active row of length 0 exports empties of both widths
+        e = pool.allocate(8)
+        (c, r), = pool.export_rows([e])["rows"][e]["layers"][:1]
+        assert c.shape == (1, 0, RANK) and r.shape == (1, 0, ROPE)
+    elif operation == "export_page":
+        layers = pool.export_page(a * pool.n_blocks + 1, width=5)
+        assert [(c.shape, r.shape) for c, r in layers] == [
+            ((1, 5, RANK), (1, 5, ROPE))] * 2
+        b = pool.allocate(16)
+        pool.import_page(b, 1, layers)
+        assert np.array_equal(np.asarray(pool.slabs[1][1][b, :, 8:13]),
+                              before[1][1][a, :, 8:13])
+    elif operation == "rewind_length":
+        pool.rewind_length(a, 17)
+        assert pool.lengths[a] == 17 and len(pool.block_table[a]) == 3
+    elif operation == "defrag":
+        keep = a * pool.n_blocks + 1
+        pool.register_cached(keep)
+        pool.free(a)
+        assert pool.defrag() == pool.n_blocks - 1
+        for (c, r), (c0, r0) in zip(pool.slabs, before):
+            # the cached page stays, the rest of the freed row is zeroed
+            assert np.array_equal(np.asarray(c[a, :, 8:16]), c0[a, :, 8:16])
+            assert np.array_equal(np.asarray(r[a, :, 8:16]), r0[a, :, 8:16])
+            assert not np.asarray(c[a, :, :8]).any()
+            assert not np.asarray(r[a, :, 16:]).any()
+            assert np.array_equal(np.asarray(c[1]), c0[1])
+    else:
+        cache = PrefixCache(pool)
+        tokens = np.arange(1, 41, dtype=np.int32)
+        cache.insert("t", tokens, a, [])
+        pool.free(a)
+        plan = cache.acquire("t", np.concatenate([tokens[:26], [99, 98]]),
+                             27)
+        # three whole pages of the shared 26 tokens are attached
+        assert plan.pages == [a * pool.n_blocks + j for j in range(3)]
+        assert plan.attach_len == 24 + plan.tail_len
+        b = pool.allocate(40)
+        pool.attach_blocks(b, plan.pages)
+        pool.set_length(b, 28)
+        assert cache.stats["hits"] == 1 and cache.stats["hit_tokens"] >= 24
+    pool.check_balance()
+
+
+def test_latent_ledger_balances_through_a_rows_life():
+    pool = _latent_pool()
+    a, b = pool.allocate(64), pool.allocate(30)
+    for n in (16, 33, 64):
+        pool.set_length(a, n)
+        assert pool.check_balance()
+    pool.set_length(b, 30)
+    assert pool.used_blocks() == 8 + 4
+    pool.rewind_length(a, 50)
+    pool.free(a)
+    pool.free(b)
+    assert pool.check_balance() and pool.defrag() == 16
+    assert pool.check_balance()

@@ -1,6 +1,7 @@
 """Tier-1 guard for the Mosaic lowering: tools/mosaic_aot_check.py compiles
-the flash, paged (the full walk and the windowed walk through a ring, at
-the window/full cell's shapes), grouped-matmul and state-recurrence Pallas
+the flash, paged (the full walk, the windowed walk through a ring at the
+window/full cell's shapes and the latent walk at the latent cell's),
+grouped-matmul and state-recurrence Pallas
 kernels for a TPU v5e through the installed libtpu, with no chip attached (ISSUE 21: CPU interpret-mode tests say
 nothing of whether Mosaic accepts a kernel). Runs in a subprocess so the
 libtpu lock and the TPU_* environment stay out of the test process."""
@@ -32,7 +33,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 29 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 34 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 13         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -58,8 +59,12 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     Mosaic body, that of an engine with window and full layers two, and
     the compiled step still a custom call a layer."""
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
-    assert len(steps) == 3
-    full, mixed, hybrid = steps
+    assert len(steps) == 4
+    full, mixed, hybrid, latent = steps
+    # three MLA layers share one `paged_latent` body; the grouped matmuls
+    # of the two sparse layers behind the dense one are a body a call site
+    assert "3 latent layers: 7 Mosaic bodies " in latent
+    assert "{'paged_latent': 3, 'moe_gmm': 6} in the compiled one" in latent
     assert "'ssm_update': 2" in hybrid and "'paged_attention': 1" in hybrid
     assert "3 full layers: 1 Mosaic body " in full
     assert "{'paged_attention': 3} in the compiled one" in full
@@ -73,7 +78,8 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
 def test_the_compiled_step_aliases_the_whole_pool_for_the_v5e(tool):
     """The step is donated its pool (PR 35): compiled for the chip, every
     byte of a full-length, a ring and a recurrent engine's slabs is
-    aliased to the result, or the step would copy the pool again."""
+    aliased to the result, or the step would copy the pool again; latent
+    pages (PR 36) likewise."""
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
     for ln in steps:
         m = re.search(r"; (\d+) bytes aliased of a pool of (\d+); ", ln)
@@ -91,3 +97,19 @@ def test_kernel_body_does_not_grow_with_the_pages_of_a_group(tool):
     assert set(body) == {"8", "16"}
     assert abs(body["8"] - body["16"]) <= 0.1 * body["16"]
     assert body["8"] < 700
+
+
+def test_the_latent_walk_compiles_at_the_latent_cells_shapes(tool):
+    """`paged_latent` for the v5e: 64 query heads over a 512-wide latent
+    and a rotary key stored in whole lane tiles (Mosaic refuses a page copy
+    of 64 lanes). The step's 16-wide rows split the heads over two tiles
+    of 512 rows inside the VMEM budget; a one-token row takes one tile."""
+    cases = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] paged latent bf16")]
+    assert len(cases) == 2 and all("{'paged_latent': 1}" in c for c in cases)
+    assert all("slab=[32, 1, 8304, 512 | 128]" in c for c in cases)
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling paged_latent")]
+    for grid, rows in (("(32, 2)", 512), ("(32, 1)", 64)):
+        assert any(f"'grid': {grid}, 'groups': 65, 'heads': 1, 'pages': 8, "
+                   f"'rows': {rows}" in t for t in tilings), (grid, tilings)

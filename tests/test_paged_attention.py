@@ -591,6 +591,130 @@ def test_chunked_prefill_bitwise_equals_whole_prompt(impl, bl, nb, L, C):
             f"chunk at offset {off} diverged from whole-prompt prefill"
 
 
+# ---- the latent walk (`paged_latent`, PR 36) ----
+
+def _ref_latent(q, qr, c, r, table, lens, q_pos, block_len, pages_per_row,
+                scale):
+    """Dense oracle, float64, a row and a query at a time: scores from the
+    two products over the keys the query may see, softmax, values = the
+    latent."""
+    q, qr, c, r = (np.asarray(a, np.float64) for a in (q, qr, c, r))
+    B, H, Tq, R = q.shape
+    out = np.zeros((B, H, Tq, R))
+    for b in range(B):
+        pages = [max(int(g), 0) for g in np.asarray(table)[b]]
+        cols = np.concatenate([
+            (g // pages_per_row, g % pages_per_row * block_len + i)
+            for g in pages for i in range(block_len)]).reshape(-1, 2)
+        cb, rb = c[cols[:, 0], 0, cols[:, 1]], r[cols[:, 0], 0, cols[:, 1]]
+        for t in range(Tq):
+            n = min(int(q_pos[b]) + t + 1, int(lens[b]))
+            if n <= 0:
+                continue
+            s = scale * (q[b, :, t] @ cb[:n].T + qr[b, :, t] @ rb[:n].T)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[b, :, t] = (p / p.sum(-1, keepdims=True)) @ cb[:n]
+    return out
+
+
+@pytest.mark.parametrize("impl", ["scan", "pallas"])
+@pytest.mark.parametrize("Tq,bl,layout", [
+    (1, 8, "identity"), (16, 8, "fragmented"), (16, 16, "identity"),
+    (1, 16, "fragmented")])
+def test_latent_walk_matches_the_dense_oracle(impl, Tq, bl, layout):
+    """Ragged lengths (one key, inside a page, a page's edge, past the
+    first group of 128 keys, the whole slot, an empty row), every query
+    head over the one latent, a write pad of NaN behind the pages, the
+    block table's indirection: the scan and the kernel (interpreted)
+    against the dense sum."""
+    from paddle_tpu.ops import pallas_mode
+    from paddle_tpu.ops.paged_attention import (LATENT_KERNEL,
+                                                ragged_paged_attention)
+    rng = np.random.RandomState(7)
+    H, R, Dr, nb, pad = 4, 16, 8, 160 // bl + 1, 8
+    cap = nb * bl
+    lens = np.array([1, bl + 3, 2 * bl, 128 + bl + 3, cap, 0], np.int32)
+    lens = np.maximum(lens, np.where(lens > 0, Tq, 0)).astype(np.int32)
+    B = len(lens)
+    q_pos = np.maximum(lens - Tq, 0).astype(np.int32)
+    c = _rand(rng, (B, 1, cap + pad, R)).at[:, :, cap:].set(jnp.nan)
+    r = _rand(rng, (B, 1, cap + pad, Dr)).at[:, :, cap:].set(jnp.nan)
+    q, qr = _rand(rng, (B, H, Tq, R)), _rand(rng, (B, H, Tq, Dr))
+    table = _identity_table(B, nb) if layout == "identity" \
+        else rng.permutation(B * nb).astype(np.int32).reshape(B, nb)
+    pallas_mode.KERNEL_TRACES.clear()
+    out = ragged_paged_attention(q, c, r, table, lens, q_pos, block_len=bl,
+                                 pages_per_row=nb, scale=0.2, impl=impl,
+                                 q_rope=qr)
+    path = "scan" if impl == "scan" else "interpret"
+    assert dict(pallas_mode.KERNEL_TRACES) == {(LATENT_KERNEL, path): 1}
+    want = _ref_latent(q, qr, c, r, table, lens, q_pos, bl, nb, 0.2)
+    live = (q_pos[:, None] + np.arange(Tq)[None] < lens[:, None])
+    err = np.abs(np.asarray(out) - want)[
+        np.broadcast_to(live[:, None, :, None], want.shape)]
+    assert np.isfinite(np.asarray(out)).all() and err.max() <= 2e-5
+
+
+@pytest.mark.parametrize("impl,bl,nb,L,C", [
+    ("scan", 8, 3, 20, 8),
+    ("pallas", 8, 3, 20, 8),
+    ("scan", 16, 19, 300, 16),
+    ("pallas", 16, 19, 300, 16),
+])
+def test_latent_chunked_prefill_bitwise_equals_whole_prompt(impl, bl, nb, L,
+                                                            C):
+    """Chunk invariance on a latent cache, in both implementations: the
+    exact-zero masking is the walk's, whatever the page holds."""
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+    rng = np.random.RandomState(8)
+    H, R, Dr = 4, 16, 8
+    c, r = _rand(rng, (1, 1, nb * bl, R)), _rand(rng, (1, 1, nb * bl, Dr))
+    q, qr = _rand(rng, (1, H, L, R)), _rand(rng, (1, H, L, Dr))
+    table = _identity_table(1, nb)
+    attend = jax.jit(lambda q, qr, lens, q_pos: ragged_paged_attention(
+        q, c, r, table, lens, q_pos, block_len=bl, scale=0.25, impl=impl,
+        q_rope=qr))
+    whole = attend(q, qr, np.array([L], np.int32), np.array([0], np.int32))
+    for off in range(0, L, C):
+        n = min(C, L - off)
+        qc, qrc = (jnp.zeros((1, H, C, x.shape[3]), x.dtype)
+                   .at[:, :, :n].set(x[:, :, off:off + n]) for x in (q, qr))
+        out = attend(qc, qrc, np.array([off + n], np.int32),
+                     np.array([off], np.int32))
+        assert np.array_equal(np.asarray(out[:, :, :n]),
+                              np.asarray(whole[:, :, off:off + n])), \
+            f"chunk at offset {off} diverged from whole-prompt prefill"
+
+
+def test_latent_walk_refuses_what_it_is_not():
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+    z = jnp.zeros
+    args = (z((1, 2, 1, 8)), z((1, 1, 16, 8)), z((1, 1, 16, 4)),
+            _identity_table(1, 2), np.array([3]), np.array([2]))
+    for kw in (dict(scale=None), dict(scale=1.0, window=4),
+               dict(scale=1.0, q_rope=z((1, 2, 1, 2)))):
+        with pytest.raises(ValueError, match="latent cache"):
+            ragged_paged_attention(*args, block_len=8, **{
+                "q_rope": z((1, 2, 1, 4)), **kw})
+    with pytest.raises(ValueError, match="latent cache"):      # two heads
+        ragged_paged_attention(
+            args[0], z((1, 2, 16, 8)), z((1, 2, 16, 4)), *args[3:],
+            block_len=8, scale=1.0, q_rope=z((1, 2, 1, 4)))
+
+
+def test_latent_tile_folds_heads_inside_the_budget():
+    """One KV "head": the tile takes as many query heads as the budget
+    holds in its rows. The serve cell's step (64 heads x 16 columns over
+    512 + 128 columns) splits in two; a decode row of `generate()` does
+    not."""
+    from paddle_tpu.ops import paged_attention as PA
+    assert PA._choose_tile(64, 1, 16, 16, 512 + 128, 2) == (1, 32)
+    assert PA._choose_tile(64, 1, 1, 16, 512 + 128, 2) == (1, 64)
+    assert PA._kernel_name(None, True) == "paged_latent"
+    for other in ("paged_attention", "paged_window"):
+        assert other not in PA.LATENT_KERNEL
+
+
 # ---- JitLRUCache: the one shared executable-cache policy ----
 
 def test_jit_lru_caches_hits_and_evicts_oldest():

@@ -249,14 +249,34 @@ def main() -> int:
             spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
             spec((B,), jnp.int32), spec((B,), jnp.int32),
             want={"paged_window" if window else "paged_attention": 1}))
+    # the latent walk (`paged_latent`) at the latent cell's shapes: 32
+    # slots, 64 query heads over one 512-wide latent and one rotary key
+    # stored in 128 lanes, 518 pages a slot; the step's 16-wide rows (the
+    # heads split over two tiles) and a one-token row (one tile)
+    for label, Tq in (("chunk rows", 16), ("Tq=1", 1)):
+        def latent(q, qr, c, r, t, sl, qp):
+            return ragged_paged_attention(
+                q, c, r, t, sl, qp, block_len=16, pages_per_row=518,
+                scale=0.1309, impl="pallas", q_rope=qr)
+        results.append(compile_case(
+            f"paged latent bf16 {label} q=[32, 64, {Tq}, 512 | 128] "
+            "slab=[32, 1, 8304, 512 | 128]", latent,
+            spec((32, 64, Tq, 512), jnp.bfloat16),
+            spec((32, 64, Tq, 128), jnp.bfloat16),
+            spec((32, 1, 8304, 512), jnp.bfloat16),
+            spec((32, 1, 8304, 128), jnp.bfloat16),
+            spec((32, 518), jnp.int32), spec((32,), jnp.int32),
+            spec((32,), jnp.int32), want={"paged_latent": 1}))
     # the grouped matmul of the dropless expert layer at OLMoE's widths and
     # the decode cell's rows (2,048 positions x 8 experts each)
     from paddle_tpu.ops.grouped_matmul import grouped_matmul
     # and at granite-4.0-h-small's (512 packed positions x 10, 18 held)
     # and at the window/full cell's (512 x 8, 16 of 64 held, width 896)
+    # and at the latent cell's (512 x 8, 12 of 192 held, width 2,048)
     for m, e, k, n in ((16384, 64, 2048, 1024), (16384, 64, 1024, 2048),
                        (5120, 18, 4096, 768), (5120, 18, 768, 4096),
-                       (4096, 16, 2304, 896), (4096, 16, 896, 2304)):
+                       (4096, 16, 2304, 896), (4096, 16, 896, 2304),
+                       (4096, 12, 7168, 2048), (4096, 12, 2048, 7168)):
         results.append(compile_case(
             f"moe_gmm bf16 [{m},{k}] x [{e},{k},{n}]",
             lambda lhs, rhs, gs: grouped_matmul(lhs, rhs, gs, impl="pallas"),
@@ -311,6 +331,21 @@ def main() -> int:
     model.eval()
     results.append(serve_step_case("serve step, 2 recurrent layers + 1 full",
                                    model, dev1[0], 12))
+    # latent pages in the donated pool: three MLA layers share one
+    # `paged_latent` body; two sparse layers' grouped matmuls, 3 each
+    from paddle_tpu.models.deepseek import (DeepseekConfig,
+                                            DeepseekForCausalLM)
+    model = DeepseekForCausalLM(DeepseekConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=256,
+        moe_intermediate_size=128, num_hidden_layers=3,
+        num_attention_heads=2, q_lora_rank=128, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=8, num_experts_per_tok=2, n_group=2, topk_group=1,
+        first_k_dense_replace=1, max_position_embeddings=1024,
+        dtype="bfloat16"))
+    model.eval()
+    results.append(serve_step_case("serve step, 3 latent layers", model,
+                                   dev1[0], 7))
     from paddle_tpu.ops import pallas_mode
     for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
         print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
