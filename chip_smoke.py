@@ -16,8 +16,9 @@ weights are random, from a seed):
 Each leg first checks the Pallas kernel it depends on against the repo's
 own reference ON THE DEVICE (flash fwd + dq/dk/dv vs `_attention_reference`;
 `ragged_paged_attention(impl="pallas")`, full walk and windowed walk through
-a ring, and `ssm_update(impl="pallas")` vs `impl="scan"`) and then requires
-that kernel's Mosaic custom calls in the compiled step it just ran. Any
+a ring, `ssm_update(impl="pallas")` vs `impl="scan"`, and the K/V write's
+`kv_write` bit for bit vs the vmapped `dynamic_update_slice`) and then
+requires that kernel's Mosaic custom calls in the compiled step it just ran. Any
 failed check raises; nothing is caught to let a leg fail while the run
 exits 0.
 
@@ -150,7 +151,8 @@ def _trace_paths() -> dict:
 
 def _tilings() -> list:
     """["kernel xN {grid, groups, pages, heads, rows}", ...]: the tile each
-    kernel call site traced so far chose from its shapes."""
+    kernel call site traced so far chose from its shapes (`kv_write`: grid,
+    rows a grid step, heads, the aligned window's columns, ring)."""
     from paddle_tpu.ops import pallas_mode
     return [f"{k} x{n} {dict(t)}"
             for (k, t), n in sorted(pallas_mode.KERNEL_TILINGS.items())]
@@ -563,6 +565,61 @@ def _paged_latent_parity(size: dict):
                  f"{LATENT_KERNEL} H={H} Tq={Tq} within {tol:g}")
 
 
+def _kv_write_parity(size: dict):
+    """The K/V write's kernel (`kv_write`) against the vmapped
+    `dynamic_update_slice` / `_ring_write` form on this device, bf16, at
+    the serve cells' head layouts and the window cell's ring: a copy, so
+    the whole slab must come out bit for bit. Rows at aligned and odd
+    columns, at 15 mod 16, at the slab's last stripe and past it (clamped),
+    and on the ring a stripe that wraps by 1 and by 15. Prints the
+    kernel's grid and rows a grid step."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import kv_write as kvw, pallas_mode
+    from paddle_tpu.ops.attention import _row_writes
+    T, B = 16, 16
+    w = size["paged_window"]
+    lat = size["paged_latent"]
+    ring = w["ring_pages"] * 16
+    cases = [(g["kv_heads"], g["pages"] * 16 + T, g["head_dim"],
+              g["head_dim"], None) for g in size["paged"]]
+    cases += [(w["kv_heads"], ring + T, w["head_dim"], w["head_dim"], ring),
+              (1, lat["pages"] * 16 + T, lat["latent"], lat["rope_cols"],
+               None)]
+    rng = np.random.RandomState(4)
+    for Hkv, L, Dk, Dv, r in cases:
+        kc = jnp.asarray(rng.randn(B, Hkv, L, Dk), jnp.bfloat16)
+        vc = jnp.asarray(rng.randn(B, Hkv, L, Dv), jnp.bfloat16)
+        kn = jnp.asarray(rng.randn(B, Hkv, T, Dk), jnp.bfloat16)
+        vn = jnp.asarray(rng.randn(B, Hkv, T, Dv), jnp.bfloat16)
+        edge = [0, 16, 7, 15, L - T, L - 3, L + 9] if r is None else \
+            [0, 16, 7, r - T, r - 15, r - 1, 3 * r - 8]
+        pos = jnp.asarray(edge + rng.randint(0, L, B - len(edge)).tolist(),
+                          jnp.int32)
+        if not kvw.kv_write_supported(kc, vc, kn, vn, r):
+            _say(f"{kvw.KERNEL}: slab {list(kc.shape)} ring={r} keeps the "
+                 "vmapped form (rehearsal shapes)")
+            continue
+        pallas_mode.KERNEL_TILINGS.clear()
+        want = jax.jit(lambda *a: _row_writes(*a, ring=r))(kc, vc, kn, vn,
+                                                           pos)
+        got = jax.jit(lambda *a: kvw.kv_write(*a, ring=r))(kc, vc, kn, vn,
+                                                           pos)
+        ((kernel, tiling),) = pallas_mode.KERNEL_TILINGS
+        tiling = dict(tiling)
+        same = all(bool(jnp.array_equal(a, b)) for a, b in zip(want, got))
+        _say(f"{kernel} pallas vs vmapped slab=[{B}, {Hkv}, {L}, {Dk} | "
+             f"{Dv}] T={T} ring={r} bf16: grid {tiling['grid']}, "
+             f"{tiling['rows']} rows a step, windows of "
+             f"{tiling['columns']} columns; whole slabs bit-identical "
+             f"{same}")
+        _require(kernel == kvw.KERNEL and same,
+                 f"{kvw.KERNEL} Hkv={Hkv} ring={r} bit-identical with the "
+                 "vmapped write")
+
+
 def _ssm_parity(size: dict):
     """`ssm_update(impl="pallas")` against `impl="scan"` on this device at
     `size["ssm"]`, bf16 state and inputs, 8 rows of 16, of 1 and of 80
@@ -632,6 +689,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
     _paged_parity(size)
     _paged_window_parity(size)
     _paged_latent_parity(size)
+    _kv_write_parity(size)
     _ssm_parity(size)
 
     import paddle_tpu as paddle
@@ -728,9 +786,10 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
              "scan path there by design)")
     else:
         n_layers = model.config.num_hidden_layers
-        _require(kernels.get("paged_attention", 0) == n_layers,
-                 f"compiled unified step holds {n_layers} paged_attention "
-                 f"custom calls (got {kernels.get('paged_attention', 0)})")
+        for kernel in ("paged_attention", "kv_write"):
+            _require(kernels.get(kernel, 0) == n_layers,
+                     f"compiled unified step holds {n_layers} {kernel} "
+                     f"custom calls (got {kernels.get(kernel, 0)})")
 
     # informational: the engine's greedy stream against one-shot generate().
     # Bit-identity is pinned on the CPU in tests (the scan on both sides at

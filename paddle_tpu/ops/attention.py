@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import pallas_mode
+from . import kv_write as kvw, pallas_mode
 
 # 256x256 is the block config chip_smoke.py compiles and checks against the
 # reference on a v5e; other sizes (the block_q / block_k arguments of
@@ -889,15 +889,32 @@ def _ring_write(cache, new, pos, ring: int):
     return cache.at[:, :T].set(head)
 
 
+def _row_writes(k_cache, v_cache, k_new, v_new, pos, ring=None):
+    """`update_kv_cache` at a `[B]` position vector as a vmapped
+    `dynamic_update_slice` (`_ring_write` on a ring): the CPU path, and
+    what the `kv_write` kernel is held to, bit for bit."""
+    from jax import lax
+    pos = jnp.broadcast_to(pos, k_cache.shape[:1]).astype(jnp.int32)
+    if ring is None:
+        write = jax.vmap(
+            lambda c, u, p: lax.dynamic_update_slice(c, u, (0, p, 0)))
+    else:
+        write = jax.vmap(lambda c, u, p: _ring_write(c, u, p, int(ring)))
+    return write(k_cache, k_new, pos), write(v_cache, v_new, pos)
+
+
 def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, ring=None):
     """Write k/v [B, Hkv, T, D] into static [B, Hkv, L, D] caches at `pos`.
 
     `pos` is the absolute position of the first new token: a scalar writes
     every row at the same offset (the batch-locked generate() path); a [B]
     vector writes each row at its own offset (slot-paged decode, where each
-    slot sits at a different sequence length). All shapes stay static —
-    vector writes are a vmapped dynamic_update_slice, not a gather/scatter
-    with dynamic extents.
+    slot sits at a different sequence length). All shapes stay static:
+    vector writes are a vmapped dynamic_update_slice on the CPU
+    (`_row_writes`, also the parity reference) and, on a TPU, the
+    `kv_write` kernel (`ops/kv_write.py`: K's and V's stripes of every row
+    in one call, the slabs aliased), which leaves the same bits in every
+    column.
 
     `ring` (a window layer's slab in the serving pool): the first `ring`
     columns of the cache are a ring, position p lives at column `p mod
@@ -907,21 +924,25 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, ring=None):
     pos = jnp.asarray(pos)
     k_new = k_new.astype(k_cache.dtype)
     v_new = v_new.astype(v_cache.dtype)
-    if ring is not None:
-        if k_cache.shape[2] < ring + k_new.shape[2]:
-            raise ValueError(
-                f"a ring of {ring} columns written {k_new.shape[2]} at a "
-                f"time needs {ring + k_new.shape[2]} columns, the cache "
-                f"has {k_cache.shape[2]}")
-        pos = jnp.broadcast_to(pos, k_cache.shape[:1]).astype(jnp.int32)
-        write = jax.vmap(lambda c, u, p: _ring_write(c, u, p, int(ring)))
-        return write(k_cache, k_new, pos), write(v_cache, v_new, pos)
-    if pos.ndim == 0:
+    if ring is not None and k_cache.shape[2] < ring + k_new.shape[2]:
+        raise ValueError(
+            f"a ring of {ring} columns written {k_new.shape[2]} at a "
+            f"time needs {ring + k_new.shape[2]} columns, the cache "
+            f"has {k_cache.shape[2]}")
+    if ring is None and pos.ndim == 0:
         return (lax.dynamic_update_slice(k_cache, k_new, (0, 0, pos, 0)),
                 lax.dynamic_update_slice(v_cache, v_new, (0, 0, pos, 0)))
-    row_write = jax.vmap(
-        lambda c, u, p: lax.dynamic_update_slice(c, u, (0, p, 0)))
-    return row_write(k_cache, k_new, pos), row_write(v_cache, v_new, pos)
+    if pallas_mode.platform() == "cpu":
+        return _row_writes(k_cache, v_cache, k_new, v_new, pos, ring)
+    # a stripe a row: for a TPU XLA makes the vmapped write a loop of one
+    # trip a row and cache
+    if kvw.kv_write_supported(k_cache, v_cache, k_new, v_new, ring):
+        return kvw.kv_write(k_cache, v_cache, k_new, v_new, pos,
+                            ring=None if ring is None else int(ring))
+    pallas_mode.note_reference(
+        kvw.KERNEL, "a slab the aligned windows do not fit",
+        k_cache.shape, v_cache.shape, k_new.shape[2], ring)
+    return _row_writes(k_cache, v_cache, k_new, v_new, pos, ring)
 
 
 def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,

@@ -1,7 +1,8 @@
 """Tier-1 guard for the Mosaic lowering: tools/mosaic_aot_check.py compiles
 the flash, paged (the full walk, the windowed walk through a ring at the
 window/full cell's shapes and the latent walk at the latent cell's),
-grouped-matmul and state-recurrence Pallas
+K/V-write (`kv_write`, at every serve cell's slabs), grouped-matmul and
+state-recurrence Pallas
 kernels for a TPU v5e through the installed libtpu, with no chip attached (ISSUE 21: CPU interpret-mode tests say
 nothing of whether Mosaic accepts a kernel). Runs in a subprocess so the
 libtpu lock and the TPU_* environment stay out of the test process."""
@@ -33,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 34 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 42 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 13         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -61,15 +62,21 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
     assert len(steps) == 4
     full, mixed, hybrid, latent = steps
-    # three MLA layers share one `paged_latent` body; the grouped matmuls
-    # of the two sparse layers behind the dense one are a body a call site
-    assert "3 latent layers: 7 Mosaic bodies " in latent
-    assert "{'paged_latent': 3, 'moe_gmm': 6} in the compiled one" in latent
+    # three MLA layers share one `paged_latent` body and one `kv_write`
+    # body; the grouped matmuls of the two sparse layers behind the dense
+    # one are a body a call site
+    assert "3 latent layers: 8 Mosaic bodies " in latent
+    assert "{'kv_write': 3, 'paged_latent': 3, 'moe_gmm': 6} in the " \
+        "compiled one" in latent
     assert "'ssm_update': 2" in hybrid and "'paged_attention': 1" in hybrid
-    assert "3 full layers: 1 Mosaic body " in full
-    assert "{'paged_attention': 3} in the compiled one" in full
-    assert "3 window layers + 1 full: 2 Mosaic bodies " in mixed
+    assert "'kv_write': 1" in hybrid and ": 13 Mosaic bodies " in hybrid
+    # a walk and a write: one body each for three layers
+    assert "3 full layers: 2 Mosaic bodies " in full
+    assert "{'kv_write': 3, 'paged_attention': 3} in the compiled one" in full
+    # a (shapes, ring) pair each: the ring's write is a body of its own
+    assert "3 window layers + 1 full: 4 Mosaic bodies " in mixed
     assert "'paged_window': 3" in mixed and "'paged_attention': 1" in mixed
+    assert "'kv_write': 4" in mixed
     for ln in steps:        # the three phases are timed apart
         assert re.search(r"trace \d+\.\ds \+ lower \d+\.\ds \+ compile "
                          r"\d+\.\ds$", ln), ln
@@ -84,6 +91,9 @@ def test_the_compiled_step_aliases_the_whole_pool_for_the_v5e(tool):
     for ln in steps:
         m = re.search(r"; (\d+) bytes aliased of a pool of (\d+); ", ln)
         assert m and int(m[1]) >= int(m[2]) > 0, ln
+        # nor does XLA loop over a K/V slab a row at a time (the vmapped
+        # write's scatter, PR 37) or copy one (PR 35)
+        assert "; 0 loops over a slab, 0 copies of one; " in ln, ln
 
 
 def test_kernel_body_does_not_grow_with_the_pages_of_a_group(tool):
@@ -113,3 +123,40 @@ def test_the_latent_walk_compiles_at_the_latent_cells_shapes(tool):
     for grid, rows in (("(32, 2)", 512), ("(32, 1)", 64)):
         assert any(f"'grid': {grid}, 'groups': 65, 'heads': 1, 'pages': 8, "
                    f"'rows': {rows}" in t for t in tilings), (grid, tilings)
+
+
+def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
+    """`kv_write` for the v5e (PR 37): K's and V's stripes of every row in
+    one Mosaic call a layer, both slabs aliased to the byte, no loop or
+    copy of a slab beside it; at Mistral's 128 and 32 rows, OLMoE's 16
+    heads (4 rows a grid step inside the VMEM budget, 8 elsewhere),
+    Mellum's full-length and ring slabs, A.X-K1's unequal latent pair,
+    granite's one attention layer and a batch of one. A direct copy of a
+    stripe into the slab is refused by Mosaic (a bf16 slab's tiled axis is
+    addressed 16 columns at a time, a row's position is any integer), so a
+    row is a read-modify-write of the 32 aligned columns that hold it."""
+    cases = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] kv_write bf16")]
+    assert len(cases) == 8
+    for ln in cases:
+        m = re.search(r": \{'kv_write': 1\}, (\d+) bytes aliased of (\d+), "
+                      r"0 loops over a slab, 0 copies of one", ln)
+        assert m and m[1] == m[2], ln
+    for slab in ("[128, 8, 240] x 128 | 128", "[32, 8, 1056] x 128 | 128",
+                 "[128, 16, 240] x 128 | 128", "[32, 4, 8304] x 128 | 128",
+                 "[32, 4, 1056] x 128 | 128 T=16 ring=1040",
+                 "[32, 1, 8304] x 512 | 128", "[1, 8, 2064] x 128 | 128"):
+        assert any(f"slab={slab}" in ln for ln in cases), slab
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling kv_write")]
+    for want in ("'grid': (16,), 'heads': 8, 'ring': 0, 'rows': 8",
+                 "'grid': (32,), 'heads': 16, 'ring': 0, 'rows': 4",
+                 "'grid': (4,), 'heads': 4, 'ring': 1040, 'rows': 8",
+                 "'grid': (4,), 'heads': 1, 'ring': 0, 'rows': 8",
+                 "'grid': (1,), 'heads': 8, 'ring': 0, 'rows': 1"):
+        assert any("'columns': 32, " + want in t for t in tilings), want
+    # what every process traces and lowers for it, the ring's second
+    # merge included: under the paged kernel's body
+    body = re.search(r"^body kv_write ring=1040: (\d+) equations$", tool,
+                     re.M)
+    assert body and int(body[1]) < 400

@@ -137,6 +137,84 @@ def test_the_compiled_step_aliases_every_slab(gpt_tiny, kind):
     eng.stop(drain=False)
 
 
+def _latent():
+    from paddle_tpu.models.deepseek import (DeepseekConfig,
+                                            DeepseekForCausalLM)
+    paddle.seed(0)
+    return DeepseekForCausalLM(DeepseekConfig(
+        vocab_size=128, hidden_size=48, intermediate_size=64,
+        moe_intermediate_size=32, num_hidden_layers=2,
+        num_attention_heads=4, q_lora_rank=24, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2,
+        first_k_dense_replace=1, n_group=2, topk_group=1,
+        max_position_embeddings=256))
+
+
+@pytest.mark.parametrize("kind", ["paged", "window", "latent"])
+def test_the_kv_write_kernel_in_the_step_writes_the_donated_pool_in_place(
+        monkeypatch, gpt_tiny, kind):
+    """On a TPU the step's K/V writes are the `kv_write` kernel
+    (`ops/kv_write.py`, PR 37), which aliases both slabs to its results: a
+    kernel that writes pool state aliases it, or donation moves the copy
+    behind the kernel (`ssm_update`, PR 35). Here the kernel stands in
+    for the vmapped write, interpreted: the step still aliases every byte
+    of a paged, a window and a latent pool, JAX finds every donated
+    buffer usable, the pool's buffers are one set for the engine's life
+    and the streams are `generate()`'s bit for bit."""
+    from paddle_tpu.ops import attention, kv_write, pallas_mode
+    calls = []
+
+    def through_the_kernel(k_cache, v_cache, k_new, v_new, pos, ring=None):
+        assert kv_write.kv_write_supported(k_cache, v_cache, k_new, v_new,
+                                           ring)
+        calls.append(ring)
+        return kv_write.kv_write(k_cache, v_cache, k_new, v_new, pos,
+                                 ring=ring)
+    monkeypatch.setattr(attention, "_row_writes", through_the_kernel)
+    model = {"paged": lambda: gpt_tiny, "window": _windowed,
+             "latent": _latent}[kind]()
+    eng = _engine(model, n_blocks=16)
+    assert kind in eng.pool.layer_kinds
+    eng.submit(_prompts([11], vocab=100)[0], max_new_tokens=2)
+    with eng._cond:
+        eng._admit()
+        toks, pos, adv, ctr, *_ = eng._build_rows_locked({})
+        args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(adv), eng.pool.device_block_table(),
+                eng.pool.slabs) + eng._sampling_args_locked(ctr) \
+            + eng._feedback_args() + eng._tail_args_locked()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        compiled = eng._step().lower(*args).compile()
+    pool_bytes = sum(a.nbytes for a in _leaves(eng.pool.slabs))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    eng.stop(drain=False)
+
+    eng = _engine(model, n_blocks=16)
+    prompts = _prompts([6, 43, 19], vocab=100)
+    calls.clear()
+    pallas_mode.KERNEL_TRACES.clear()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # "donated buffers not usable"
+        handles = [eng.submit(p, max_new_tokens=5) for p in prompts]
+        first = _buffers(eng.pool.slabs)
+        while eng.has_work():
+            eng.pump()
+            assert _buffers(eng.pool.slabs) == first
+    layers = len(eng.pool.layer_kinds)
+    assert len(calls) == layers
+    assert pallas_mode.KERNEL_TRACES[("kv_write", "interpret")] == layers
+    rings = {r for r in calls if r is not None}
+    assert rings == ({eng.pool.ring_len} if kind == "window" else set())
+    snap = eng.metrics.snapshot()
+    assert snap["pool_copies"] == 0 and snap["pool_lost"] == 0
+    for p, h in zip(prompts, handles):
+        np.testing.assert_array_equal(h.result(0), _reference(model, p, 5))
+    assert eng._step()._cache_size() == 1
+    eng.stop()
+
+
 # ---- (b) one set of buffers for the engine's life ---------------------------
 
 def test_the_pools_buffers_are_one_set_with_a_step_in_flight(gpt_tiny):

@@ -18,6 +18,7 @@ names these devices, then `.lower(...).compile()`).
 from __future__ import annotations
 
 import os
+import re
 import sys
 import time
 
@@ -144,16 +145,79 @@ def serve_step_case(name, model, device, want) -> bool:
     bodies = lowered.as_text().count("stablehlo.custom_call @tpu_custom_call")
     # the step is donated the pool: every slab's bytes must be aliased to
     # the result, or the executable copies the pool once a step
-    pool = sum(a.nbytes for a in jax.tree_util.tree_leaves(eng.pool.slabs))
+    slabs = jax.tree_util.tree_leaves(eng.pool.slabs)
+    pool = sum(a.nbytes for a in slabs)
     aliased = compiled.memory_analysis().alias_size_in_bytes
-    ok = bodies == want and aliased >= pool
+    loops, copies = slab_loops_and_copies(compiled.as_text(), slabs)
+    ok = bodies == want and aliased >= pool and not loops and not copies
     print(f"[{'OK' if ok else 'FAIL'}] {name}: {bodies} Mosaic "
           f"{'body' if bodies == 1 else 'bodies'} in the lowered step for "
           f"{pallas_kernel_census(compiled.as_text())} in the compiled one; "
-          f"{aliased} bytes aliased of a pool of {pool}; "
+          f"{aliased} bytes aliased of a pool of {pool}; {loops} loops over "
+          f"a slab, {copies} copies of one; "
           f"trace {t1 - t0:.1f}s + lower {t2 - t1:.1f}s + compile "
           f"{t3 - t2:.1f}s" + ("" if ok else f" (wanted {want})"),
           flush=True)
+    return ok
+
+
+def slab_loops_and_copies(hlo: str, slabs) -> tuple:
+    """(`while` instructions that carry an array of a K/V slab's shape,
+    `copy` instructions that make one) in compiled HLO text. A vmapped
+    `dynamic_update_slice` (a scatter) compiles for the v5e to such a loop,
+    one trip a row (PR 37); a result that cannot be written in place, to
+    such a copy (PR 35)."""
+    short = {"bfloat16": "bf16", "float32": "f32", "float16": "f16"}
+    # the K/V slabs `[slots, Hkv, L, D]` (a recurrent layer's small
+    # convolution state is rebuilt by a concatenate, not written in place)
+    shapes = {f"{short[str(a.dtype)]}[{','.join(map(str, a.shape))}]"
+              for a in slabs if len(a.shape) == 4}
+    loops = copies = 0
+    for line in hlo.splitlines():
+        _, _, rest = line.partition(" = ")
+        carried, is_loop, _ = rest.partition(" while(")
+        loops += bool(is_loop) and any(s in carried for s in shapes)
+        made = re.match(r"(\w+\[[\d,]*\])\{[^}]*\} copy\(", rest)
+        copies += bool(made) and made[1] in shapes
+    return loops, copies
+
+
+def kv_write_case(name, slab, widths, T, ring, sharding) -> bool:
+    """Compile `kv_write` alone, donated its slabs `[B, Hkv, L, widths[i]]`:
+    one Mosaic call for both caches, every byte aliased, and no loop or
+    copy of a slab beside it."""
+    from paddle_tpu.obs.compile_observatory import pallas_kernel_census
+    from paddle_tpu.ops.kv_write import kv_write, kv_write_supported
+    B, Hkv, L = slab
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    caches = [spec((B, Hkv, L, w)) for w in widths]
+    stripes = [spec((B, Hkv, T, w)) for w in widths]
+    name = (f"kv_write bf16 {name} slab={[B, Hkv, L]} x "
+            f"{' | '.join(map(str, widths))} T={T} ring={ring}")
+    t0 = time.perf_counter()
+    try:
+        assert kv_write_supported(*caches, *stripes, ring)
+        compiled = jax.jit(
+            lambda kc, vc, kn, vn, pos: kv_write(kc, vc, kn, vn, pos,
+                                                 ring=ring),
+            donate_argnums=(0, 1)).lower(
+                *caches, *stripes, spec((B,), jnp.int32)).compile()
+    except Exception as e:  # the tool's job is to report every case
+        print(f"[FAIL] {name}: {type(e).__name__}: {str(e)[:1500]}",
+              flush=True)
+        return False
+    hlo = compiled.as_text()
+    census = pallas_kernel_census(hlo)
+    pool = sum(int(np.prod(c.shape)) * 2 for c in caches)
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    loops, copies = slab_loops_and_copies(hlo, caches)
+    ok = census == {"kv_write": 1} and aliased == pool \
+        and not loops and not copies
+    print(f"[{'OK' if ok else 'FAIL'}] {name}: {census}, {aliased} bytes "
+          f"aliased of {pool}, {loops} loops over a slab, {copies} copies "
+          f"of one, in {time.perf_counter() - t0:.1f}s", flush=True)
     return ok
 
 
@@ -267,6 +331,29 @@ def main() -> int:
             spec((32, 1, 8304, 128), jnp.bfloat16),
             spec((32, 518), jnp.int32), spec((32,), jnp.int32),
             spec((32,), jnp.int32), want={"paged_latent": 1}))
+    # the K/V write (`kv_write`) at every serve cell's slabs: Mistral's
+    # decode and prefill cells, OLMoE's 16 heads (4 rows a grid step),
+    # the window/full cell's full-length and ring slabs, the latent cell's
+    # unequal pair, granite's one attention layer; and a 16-bit-odd batch
+    for label, slab, widths, ring in (
+            ("mistral decode cell", (128, 8, 240), (D, D), None),
+            ("mistral prefill cells", (32, 8, 1056), (D, D), None),
+            ("olmoe decode cell", (128, 16, 240), (D, D), None),
+            ("window/full cell, full layers", (32, 4, 8304), (D, D), None),
+            ("window/full cell, ring", (32, 4, 1056), (D, D), 1040),
+            ("latent cell", (32, 1, 8304), (512, D), None),
+            ("granite decode cell", (128, 8, 240), (D, D), None),
+            ("a batch of one", (1, 8, 2064), (D, D), None)):
+        results.append(kv_write_case(label, slab, widths, 16, ring, one))
+    body = kernel_equations(
+        lambda kc, vc, kn, vn, pos: A.update_kv_cache(kc, vc, kn, vn, pos,
+                                                      ring=1040),
+        spec((32, 4, 1056, D), jnp.bfloat16),
+        spec((32, 4, 1056, D), jnp.bfloat16),
+        spec((32, 4, 16, D), jnp.bfloat16), spec((32, 4, 16, D), jnp.bfloat16),
+        spec((32,), jnp.int32))
+    print(f"body kv_write ring=1040: {body['kv_write']} equations",
+          flush=True)
     # the grouped matmul of the dropless expert layer at OLMoE's widths and
     # the decode cell's rows (2,048 positions x 8 experts each)
     from paddle_tpu.ops.grouped_matmul import grouped_matmul
@@ -297,16 +384,17 @@ def main() -> int:
             spec((rows, T, 128), jnp.bfloat16),
             spec((rows, 128, 8192), jnp.bfloat16), spec((rows,), jnp.int32),
             spec((rows,), jnp.int32), want={"ssm_update": 1}))
-    # the unified step of an engine, its layers unrolled: the kernel's
+    # the unified step of an engine, its layers unrolled: a kernel's
     # jitted entry gives the lowered module one Mosaic body a distinct
-    # (shapes, window) pair, not one a layer (what every process traces
-    # and lowers before it can ask the compile cache)
+    # (shapes, window) pair (the paged kernels) or (shapes, ring) pair
+    # (`kv_write`), not one a layer (what every process traces and lowers
+    # before it can ask the compile cache)
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     paddle.seed(0)
     for label, want, kw in (
-            ("3 full layers", 1, dict(num_hidden_layers=3)),
-            ("3 window layers + 1 full", 2, dict(
+            ("3 full layers", 2, dict(num_hidden_layers=3)),
+            ("3 window layers + 1 full", 4, dict(
                 num_hidden_layers=4, sliding_window=128,
                 layer_types=["sliding_attention"] * 2 + ["full_attention"]
                 + ["sliding_attention"]))):
@@ -330,9 +418,10 @@ def main() -> int:
         max_position_embeddings=1024, dtype="bfloat16"))
     model.eval()
     results.append(serve_step_case("serve step, 2 recurrent layers + 1 full",
-                                   model, dev1[0], 12))
+                                   model, dev1[0], 13))
     # latent pages in the donated pool: three MLA layers share one
-    # `paged_latent` body; two sparse layers' grouped matmuls, 3 each
+    # `paged_latent` body and one `kv_write` body; two sparse layers'
+    # grouped matmuls, 3 each
     from paddle_tpu.models.deepseek import (DeepseekConfig,
                                             DeepseekForCausalLM)
     model = DeepseekForCausalLM(DeepseekConfig(
@@ -345,7 +434,7 @@ def main() -> int:
         dtype="bfloat16"))
     model.eval()
     results.append(serve_step_case("serve step, 3 latent layers", model,
-                                   dev1[0], 7))
+                                   dev1[0], 8))
     from paddle_tpu.ops import pallas_mode
     for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
         print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
