@@ -70,6 +70,10 @@ FULL = dict(
     # one 64-wide rotary key (stored in 128 lanes), 518 pages a slot
     paged_latent=dict(heads=64, latent=512, rope=64, rope_cols=128,
                       pages=518),
+    # the sessions cell's sparse layers: an indexer of 32 x 128 that keeps
+    # 2,048 keys, over the same latent pair, 2,304 pages a slot
+    sparse=dict(heads=64, latent=512, rope_cols=128, index_heads=32,
+                index_dim=128, topk=2048, pages=2304),
     # granite-4.0-h-small's Mamba-2 state: 128 heads x 64, 128 channels
     ssm=dict(heads=128, head_dim=64, state=128),
 )
@@ -82,6 +86,8 @@ TINY = dict(
     paged_window=dict(heads=4, kv_heads=2, head_dim=64, window=32,
                       ring_pages=3),
     paged_latent=dict(heads=4, latent=32, rope=8, rope_cols=8, pages=20),
+    sparse=dict(heads=4, latent=32, rope_cols=8, index_heads=2,
+                index_dim=128, topk=32, pages=20),
     ssm=dict(heads=4, head_dim=64, state=16),
 )
 
@@ -565,6 +571,75 @@ def _paged_latent_parity(size: dict):
                  f"{LATENT_KERNEL} H={H} Tq={Tq} within {tol:g}")
 
 
+def _sparse_parity(size: dict):
+    """Learned sparse attention's three kernels on this device at
+    `size["sparse"]`, each against its plain-XLA twin: `index_score`
+    (float32 scores of bf16 index queries over index-key pages; tolerance
+    the accumulation order's), `index_topk` (exact: the same 0/1 mask from
+    the same scores, bit for bit) and `paged_sparse` (a decode row's walk
+    over its gathered keys beside a chunk row's walk under its masks, one
+    selection for both sides; tolerance as `_paged_parity`'s)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import index_select as IX
+    from paddle_tpu.ops.paged_attention import sparse_latent_attention
+    g = size["sparse"]
+    H, R, cols, Hi, Di, K, pages = (
+        g["heads"], g["latent"], g["rope_cols"], g["index_heads"],
+        g["index_dim"], g["topk"], g["pages"])
+    N, bl, T = 4, 16, 16
+    L = pages * bl
+    rng = np.random.RandomState(5)
+
+    def bf(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+
+    c, r, ki = bf(N, 1, L + bl, R), bf(N, 1, L + bl, cols), \
+        bf(N, 1, L + bl, Di)
+    q, qr, qi = bf(N, H, T, R), bf(N, H, T, cols), bf(N, Hi, T, Di)
+    w = jnp.asarray(rng.randn(N, Hi, T), jnp.float32)
+    table = rng.permutation(N * pages).astype(np.int32).reshape(N, pages)
+    adv = np.array([1, T, T, 1], np.int32)      # decode, chunk, chunk, decode
+    lens = np.array([L // 3, K // 2, L, L], np.int32)
+    q_pos = (lens - adv).astype(np.int32)
+    paged = (table, lens, bl, pages)
+    scores = {impl: IX.index_scores(qi, w, ki, table, lens, q_pos,
+                                    block_len=bl, pages_per_row=pages,
+                                    impl=impl)
+              for impl in ("pallas", "reference")}
+    live = np.isfinite(np.asarray(scores["reference"]))
+    err = float(np.abs(np.where(live, np.asarray(scores["pallas"])
+                                - np.asarray(scores["reference"]), 0)).max())
+    same = bool((np.isfinite(np.asarray(scores["pallas"])) == live).all())
+    _say(f"index_score pallas vs XLA heads={Hi} x {Di} rows={N} x {T} "
+         f"seq_lens={lens.tolist()}: max abs err {err:.2e} of scores up to "
+         f"{float(np.abs(np.where(live, scores['reference'], 0)).max()):.1f}"
+         f"; the same keys visible {same}")
+    _require(same and err <= 5e-2, "index_score within 5e-2")
+    masks = {impl: np.asarray(IX.topk_mask(scores["reference"], K, impl=impl))
+             for impl in ("pallas", "reference")}
+    equal = bool((masks["pallas"] == masks["reference"]).all())
+    counts = sorted(set(masks["pallas"].sum(-1).astype(int).ravel().tolist()))
+    _say(f"index_topk pallas vs XLA k={K} of {L}: masks bit-identical "
+         f"{equal}; selected a query {counts[-3:]}")
+    _require(equal, "index_topk exact")
+    sel = IX.select(qi, w, ki, q_pos, K, paged=paged)
+    outs = {impl: sparse_latent_attention(
+        q, c, r, table, lens, q_pos, sel=sel, block_len=bl,
+        pages_per_row=pages, scale=(R + 64) ** -0.5, q_rope=qr, impl=impl)
+        for impl in ("pallas", "scan")}
+    cols_live = np.arange(T)[None] < adv[:, None]
+    err = float(np.abs(np.where(
+        cols_live[:, None, :, None],
+        np.asarray(outs["pallas"], np.float32)
+        - np.asarray(outs["scan"], np.float32), 0)).max())
+    _say(f"paged_sparse pallas vs scan H={H} latent={R} k={K} rows "
+         f"adv={adv.tolist()} seq_lens={lens.tolist()} bf16: max abs err "
+         f"{err:.2e} (tolerance 2e-02)")
+    _require(np.isfinite(err) and err <= 2e-2, "paged_sparse within 2e-2")
+
+
 def _kv_write_parity(size: dict):
     """The K/V write's kernel (`kv_write`) against the vmapped
     `dynamic_update_slice` / `_ring_write` form on this device, bf16, at
@@ -689,6 +764,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
     _paged_parity(size)
     _paged_window_parity(size)
     _paged_latent_parity(size)
+    _sparse_parity(size)
     _kv_write_parity(size)
     _ssm_parity(size)
 

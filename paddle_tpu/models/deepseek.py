@@ -1,7 +1,9 @@
 """The DeepSeek-V3 family of decoders (DeepSeek-V3, and models built on its
-block such as SK Telecom's A.X-K1, `model_type` "axk1"): multi-head latent
-attention, a router with sigmoid scores and a group-limited choice beside a
-shared expert, dense SwiGLU layers before the sparse ones.
+block such as SK Telecom's A.X-K1, `model_type` "axk1", and, with
+DeepSeek-V3.2's learned sparse attention on top, Z.ai's GLM-5.2,
+`model_type` "glm_moe_dsa"): multi-head latent attention, a router with
+sigmoid scores and a group-limited choice beside a shared expert, dense
+SwiGLU layers before the sparse ones.
 
 A file of its own and not `models/llama.py` grown: that file's attention is
 "q, k, v of one head width and a `(k, v)` cache", which every other decoder
@@ -44,6 +46,36 @@ key's slab is padded to whole lane tiles (`_lanes`: 64 -> 128 columns; a
 TPU array's minor dimension is stored in tiles of 128, so a 64-wide slab
 takes as much HBM, and the kernel's page copies want whole tiles).
 
+Learned sparse attention (`indexer_types`, one entry a layer; None: every
+layer attends to every key). A "full" layer carries an `Indexer` and keeps a
+third slab in its cache, one index key of `index_head_dim` a token. With
+`h` the block's normed input and `c_q` the query latent above:
+
+    q^I_j = RoPE((c_q W^I_q)[j])          j = 1..index_n_heads, RoPE on the
+                                          first qk_rope_head_dim columns
+    k^I   = RoPE(LayerNorm(h W^I_k))      one key a token: what is cached
+    w     = h W^I_w * index_n_heads^-0.5 * index_head_dim^-0.5
+    I[t, s] = sum_j w[t, j] relu(q^I[t, j] . k^I[s])      s <= t, float32
+    S_t   = the index_topk positions of largest I[t, .]   (every s <= t
+            while t < index_topk), ties to the lower position
+
+and the softmax of that layer, and of every "shared" layer up to the next
+"full" one (no indexer weights, no index keys: the selection rides from
+layer to layer inside one forward pass, `DeepseekDecoderLayer.forward`'s
+`sel`), is over `s in S_t` alone, the same for every head. No option
+chooses between a sparse and a dense form: a layer under an indexer is
+sparse at every length. It is in this file and not one of its own because
+everything but the `Indexer` and one argument of the attention call is the
+block above: a branch does not leave `MLAttention` two classes in one, it
+leaves it one class with a sublayer on some layers. Through the cache the
+selection is `ops.index_select.select` (the `index_score` and `index_topk`
+kernels) and the attention `ops.paged_attention.sparse_latent_attention`
+(`paged_sparse`); uncached, a dense score matrix and an additive mask.
+
+`rope_interleave`: the rotary columns pair (2i, 2i + 1), not (i, i + D/2).
+They are brought into the half-split order once (`_deinterleave`), in q and
+k alike, and rotated as ever: every q . k is the interleaved rotation's.
+
 FFN: layers `< first_k_dense_replace` a dense SwiGLU of `intermediate_size`;
 the rest `DroplessMoE` (`nn.layer.moe.route`: `scoring_func`, `n_group` /
 `topk_group`, `norm_topk_prob`, `routed_scaling_factor`, an optional
@@ -56,7 +88,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from types import SimpleNamespace
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +101,10 @@ from ..distributed.meta_parallel.mp_layers import (ColumnParallelLinear,
 from ..nn.layer.common import Linear
 from ..nn.layer.layers import Layer, LayerList, parameter_dtype
 from ..nn.layer.moe import DroplessMoE
-from ..ops.attention import decode_attention, flash_attention, \
-    update_kv_cache
+from ..nn.layer.norm import LayerNorm
+from ..ops.attention import _NEG_INF, decode_attention, flash_attention, \
+    update_caches, update_kv_cache
+from ..ops.index_select import Selection, select, topk_mask
 from .llama import LlamaMLP, RMSNorm, _apply_rope, _rope_cos_sin, \
     yarn_mscale
 
@@ -80,6 +114,25 @@ LANES = 128
 def _lanes(width: int) -> int:
     """`width` rounded up to whole lane tiles."""
     return -(-width // LANES) * LANES
+
+
+def _deinterleave(x):
+    """Columns (0, 1, 2, 3, ...) -> (0, 2, ..., 1, 3, ...): interleaved
+    rotary pairs brought into the half-split order `_apply_rope` rotates."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
+
+
+def _rope_rows(cos, sin, pos, T: int, dtype):
+    """The rotary tables' rows of a step: T positions from `pos`, a scalar
+    ([T, D]) or one a row ([B, T, D])."""
+    if jnp.ndim(pos) == 0:
+        cos_t = lax.dynamic_slice_in_dim(cos, pos, T, 0)
+        sin_t = lax.dynamic_slice_in_dim(sin, pos, T, 0)
+    else:
+        row = jax.vmap(lambda tab, p: lax.dynamic_slice_in_dim(tab, p, T, 0),
+                       in_axes=(None, 0))
+        cos_t, sin_t = row(cos, pos), row(sin, pos)
+    return cos_t.astype(dtype), sin_t.astype(dtype)
 
 
 @dataclass
@@ -114,6 +167,14 @@ class DeepseekConfig:
     # "original_max_position_embeddings", "beta_fast", "beta_slow",
     # "mscale", "mscale_all_dim"}
     rope_scaling: Optional[dict] = None
+    # rotary pairs (2i, 2i + 1) in place of (i, i + D/2)
+    rope_interleave: bool = False
+    # learned sparse attention: "full" | "shared" a layer (None: none)
+    indexer_types: Optional[Sequence[str]] = None
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    indexer_rope_interleave: bool = True
     tie_word_embeddings: bool = False
     dtype: str = "float32"
     # (first, count): every expert layer holds that share of
@@ -127,6 +188,15 @@ class DeepseekConfig:
             raise ValueError(
                 f"first_k_dense_replace {self.first_k_dense_replace} of "
                 f"{self.num_hidden_layers} layers")
+        kinds = self.indexer_types
+        if kinds is not None and (
+                len(kinds) != self.num_hidden_layers or kinds[0] != "full"
+                or set(kinds) - {"full", "shared"}):
+            raise ValueError(
+                f"indexer_types {list(kinds)}: one of \"full\" / "
+                f"\"shared\" for each of {self.num_hidden_layers} layers, "
+                "the first \"full\" (a shared layer reuses the selection "
+                "of the full layer above it)")
 
     @property
     def rope(self) -> dict:
@@ -145,12 +215,48 @@ class DeepseekConfig:
         return scale
 
 
-class MLAttention(Layer):
-    """Multi-head latent attention (module docstring)."""
+class Indexer(Layer):
+    """A "full" layer's index queries, index key and head weights (module
+    docstring): what the selection is computed from."""
 
     def __init__(self, config: DeepseekConfig):
         super().__init__()
         self.config = config
+        Hi, Di = config.index_n_heads, config.index_head_dim
+        self.wq_b = Linear(config.q_lora_rank, Hi * Di, bias_attr=False)
+        self.wk = Linear(config.hidden_size, Di, bias_attr=False)
+        self.k_norm = LayerNorm(Di, epsilon=1e-6)
+        self.weights_proj = Linear(config.hidden_size, Hi, bias_attr=False)
+
+    def forward(self, hidden, c_q):
+        """hidden [.., h], c_q [.., q_lora_rank] -> (q [.., Hi * Di],
+        k [.., Di], both before RoPE; w [.., Hi] float32)."""
+        cfg = self.config
+        gain = cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5
+        w = apply(lambda a: a.astype(jnp.float32) * gain,
+                  self.weights_proj(hidden))
+        return self.wq_b(c_q), self.k_norm(self.wk(hidden)), w
+
+    def rotate(self, x, cos, sin):
+        """x [B, heads, T, Di]: RoPE on its first `qk_rope_head_dim`
+        columns."""
+        dr = self.config.qk_rope_head_dim
+        rot = x[..., :dr]
+        if self.config.indexer_rope_interleave:
+            rot = _deinterleave(rot)
+        return jnp.concatenate([_apply_rope(rot, cos, sin), x[..., dr:]],
+                               -1)
+
+
+class MLAttention(Layer):
+    """Multi-head latent attention (module docstring). `kind`: None (every
+    key), or the layer's entry of `indexer_types`."""
+
+    def __init__(self, config: DeepseekConfig, kind: Optional[str] = None):
+        super().__init__()
+        self.config = config
+        self.kind = kind
+        self.indexer = Indexer(config) if kind == "full" else None
         h, H = config.hidden_size, config.num_attention_heads
         self.qk_dim = config.qk_nope_head_dim + config.qk_rope_head_dim
         self.q_a_proj = Linear(h, config.q_lora_rank, bias_attr=False)
@@ -173,17 +279,27 @@ class MLAttention(Layer):
 
     def _down(self, hidden):
         """(q [.., H * (nope + rope)], c_kv [.., rank] after its norm,
-        k_r [.., rope] before RoPE)."""
+        k_r [.., rope] before RoPE, the indexer's (q, k, w) or ())."""
         rank = self.config.kv_lora_rank
-        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(hidden)))
+        c_q = self.q_a_layernorm(self.q_a_proj(hidden))
+        q = self.q_b_proj(c_q)
         ckv = self.kv_a_proj_with_mqa(hidden)
         c = self.kv_a_layernorm(apply(lambda a: a[..., :rank], ckv))
-        return q, c, apply(lambda a: a[..., rank:], ckv)
+        index = () if self.indexer is None else self.indexer(hidden, c_q)
+        return q, c, apply(lambda a: a[..., rank:], ckv), index
 
-    def forward(self, hidden, cache=None, pos=None, paged=None, pack=None):
-        q, c, k_r = self._down(hidden)
+    def _rotary(self, x):
+        return _deinterleave(x) if self.config.rope_interleave else x
+
+    def forward(self, hidden, cache=None, pos=None, paged=None, pack=None,
+                sel=None):
+        """Returns the output, and with a cache the new cache; a layer of
+        `indexer_types` also the selection it used (made here if "full",
+        else `sel` as given): `out[, cache][, sel]`."""
+        q, c, k_r, index = self._down(hidden)
         if cache is not None:
-            return self._forward_cached(q, c, k_r, cache, pos, paged, pack)
+            return self._forward_cached(q, c, k_r, index, cache, pos, paged,
+                                        pack, sel)
         cfg = self.config
         H, nope, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                            cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -191,75 +307,124 @@ class MLAttention(Layer):
         # the kernels keep their own 1/sqrt(width): q carries the rest
         q_scale = cfg.softmax_scale * math.sqrt(qk)
 
-        def attn(qa, kv, kr):
+        Hi, Di, topk = cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk
+
+        def choose(qi, ki, w):
+            """The selection as an additive mask [B, 1, S, S]."""
+            B, S = qi.shape[:2]
+            cos, sin = (t.astype(qi.dtype)
+                        for t in _rope_cos_sin(S, dr, rope))
+            qi = self.indexer.rotate(
+                jnp.swapaxes(qi.reshape(B, S, Hi, Di), 1, 2), cos, sin)
+            ki = self.indexer.rotate(ki[:, None], cos, sin)[:, 0]
+            s = jnp.einsum("bhtd,bsd->bhts", qi, ki,
+                           preferred_element_type=jnp.float32)
+            scores = jnp.einsum("bhts,bth->bts", jnp.maximum(s, 0.0), w)
+            t = jnp.arange(S, dtype=jnp.int32)
+            scores = jnp.where(t[None, :, None] >= t[None, None, :], scores,
+                               -jnp.inf)
+            keep = topk_mask(scores, topk, impl="reference")
+            return jnp.where(keep > 0.5, 0.0, _NEG_INF)[:, None]
+
+        def attn(qa, kv, kr, *mask):
             B, S = qa.shape[:2]
             qh = jnp.swapaxes(qa.reshape(B, S, H, qk), 1, 2)   # [B,H,S,qk]
             kvh = jnp.swapaxes(kv.reshape(B, S, H, nope + dv), 1, 2)
             cos, sin = (t.astype(qa.dtype)
                         for t in _rope_cos_sin(S, dr, rope))
-            q_rot = _apply_rope(qh[..., nope:], cos, sin)
-            k_rot = _apply_rope(kr[:, None], cos, sin)         # [B,1,S,dr]
+            q_rot = _apply_rope(self._rotary(qh[..., nope:]), cos, sin)
+            k_rot = _apply_rope(self._rotary(kr[:, None]), cos, sin)
             qh = jnp.concatenate([qh[..., :nope], q_rot], -1) * q_scale
             kh = jnp.concatenate(
                 [kvh[..., :nope], jnp.broadcast_to(k_rot, (B, H, S, dr))],
                 -1)
             vh = jnp.pad(kvh[..., nope:],
-                         ((0, 0), (0, 0), (0, 0), (0, qk - dv)))
-            out = flash_attention(qh.astype(qa.dtype), kh, vh,
-                                  causal=True)[..., :dv]
+                         ((0, 0), (0, 0), (0, 0), (0, max(qk - dv, 0))))
+            out = flash_attention(qh.astype(qa.dtype), kh, vh, causal=True,
+                                  mask=mask[0] if mask else None)[..., :dv]
             return jnp.swapaxes(out, 1, 2).reshape(B, S, H * dv)
 
-        return self.o_proj(apply(attn, q, self.kv_b_proj(c), k_r))
+        if self.indexer is not None:
+            sel = apply(choose, *index)
+        more = () if sel is None else (sel,)
+        out = self.o_proj(apply(attn, q, self.kv_b_proj(c), k_r, *more))
+        return out if self.kind is None else (out, sel)
 
-    def _forward_cached(self, q, c, k_r, cache, pos, paged, pack):
+    def _forward_cached(self, q, c, k_r, index, cache, pos, paged, pack,
+                        sel):
         """The absorbed form through the latent cache `(c [B, 1, L, rank],
         r [B, 1, L, lanes(rope)])`: the step's latents and rotary keys are
         written at `pos`, then every query attends to the cache
-        (`decode_attention(q_rope=)`). `pack` as `LlamaAttention`'s."""
+        (`decode_attention(q_rope=)`). `pack` as `LlamaAttention`'s. A
+        "full" layer's cache has a third slab, `k_index [B, 1, L, Di]`: the
+        step's index keys are written with the other two and the selection
+        is made over it; a layer under an indexer attends to the selection
+        (`decode_attention(sel=)`)."""
         cfg = self.config
         H, nope, dr, dv, rank = (
             cfg.num_attention_heads, cfg.qk_nope_head_dim,
             cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank)
+        Hi, Di, topk = cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk
         rope, qk, scale = cfg.rope, self.qk_dim, cfg.softmax_scale
+        full, n_cache = self.indexer is not None, len(cache)
         if paged is not None:
             paged = paged[:4]
 
-        def attn(qa, ca, kr, w_kvb, c_cache, r_cache, pos_):
+        def attn(qa, ca, kr, w_kvb, *rest):
+            # behind the caches and the position: a "full" layer's index
+            # queries, index key and head weights; a "shared" layer's
+            # selection; nothing
+            caches, pos_, more = rest[:n_cache], rest[n_cache], \
+                rest[n_cache + 1:]
             if pack is not None:
-                qa, ca, kr = pack.unpack(qa), pack.unpack(ca), \
-                    pack.unpack(kr)
+                qa, ca, kr = (pack.unpack(a) for a in (qa, ca, kr))
+                if full:
+                    more = tuple(pack.unpack(a) for a in more)
                 pos_ = pack.slot_pos
             B, T = qa.shape[:2]
             qh = jnp.swapaxes(qa.reshape(B, T, H, qk), 1, 2)   # [B,H,T,qk]
-            cos, sin = _rope_cos_sin(c_cache.shape[2], dr, rope)
-            if jnp.ndim(pos_) == 0:
-                cos_t = lax.dynamic_slice_in_dim(cos, pos_, T, 0)
-                sin_t = lax.dynamic_slice_in_dim(sin, pos_, T, 0)
-            else:       # each row at its own position: [B, T, dr]
-                row = jax.vmap(
-                    lambda tab, p: lax.dynamic_slice_in_dim(tab, p, T, 0),
-                    in_axes=(None, 0))
-                cos_t, sin_t = row(cos, pos_), row(sin, pos_)
-            cos_t, sin_t = cos_t.astype(qa.dtype), sin_t.astype(qa.dtype)
-            pad = ((0, 0), (0, 0), (0, 0), (0, r_cache.shape[3] - dr))
-            q_rot = jnp.pad(_apply_rope(qh[..., nope:], cos_t, sin_t), pad)
-            k_rot = jnp.pad(_apply_rope(kr[:, None], cos_t, sin_t), pad)
-            c_cache, r_cache = update_kv_cache(c_cache, r_cache,
-                                               ca[:, None], k_rot, pos_)
+            cos_t, sin_t = _rope_rows(
+                *_rope_cos_sin(caches[0].shape[2], dr, rope), pos_, T,
+                qa.dtype)
+            pad = ((0, 0), (0, 0), (0, 0), (0, caches[1].shape[3] - dr))
+            q_rot = jnp.pad(_apply_rope(self._rotary(qh[..., nope:]), cos_t,
+                                        sin_t), pad)
+            k_rot = jnp.pad(_apply_rope(self._rotary(kr[:, None]), cos_t,
+                                        sin_t), pad)
+            if full:
+                qi, ki, w = more
+                qi = self.indexer.rotate(
+                    jnp.swapaxes(qi.reshape(B, T, Hi, Di), 1, 2), cos_t,
+                    sin_t)
+                ki = self.indexer.rotate(ki[:, None], cos_t, sin_t)
+                caches = update_caches(caches, (ca[:, None], k_rot, ki),
+                                       pos_)
+                chosen = select(qi, jnp.swapaxes(w, 1, 2), caches[2], pos_,
+                                topk, paged=paged)
+            else:
+                caches = update_kv_cache(*caches, ca[:, None], k_rot, pos_)
+                chosen = Selection(*more) if more else None
             w = w_kvb.reshape(rank, H, nope + dv)
             q_lat = jnp.einsum("bhtd,rhd->bhtr", qh[..., :nope],
                                w[..., :nope]).astype(qa.dtype)
-            out = decode_attention(q_lat, c_cache, r_cache, pos_,
-                                   scale=scale, paged=paged, q_rope=q_rot)
+            out = decode_attention(q_lat, caches[0], caches[1], pos_,
+                                   scale=scale, paged=paged, q_rope=q_rot,
+                                   sel=chosen)
             out = jnp.einsum("bhtr,rhd->bthd", out, w[..., nope:])
             out = out.reshape(B, T, H * dv).astype(qa.dtype)
             if pack is not None:
                 out = pack.pack(out)
-            return out, c_cache, r_cache
+            return (out,) + tuple(caches) + (tuple(chosen) if full else ())
 
-        ctx, new_c, new_r = apply(attn, q, c, k_r, self.kv_b_proj.weight,
-                                  *cache, pos)
-        return self.o_proj(ctx), (new_c, new_r)
+        given = index if full else tuple(sel or ())
+        out = apply(attn, q, c, k_r, self.kv_b_proj.weight, *cache, pos,
+                    *given)
+        ctx, new_cache = out[0], tuple(out[1:1 + n_cache])
+        if self.kind is None:
+            return self.o_proj(ctx), new_cache
+        # a shared layer hands on the selection it was handed
+        return self.o_proj(ctx), new_cache, \
+            Selection(*out[1 + n_cache:]) if full else sel
 
 
 class DeepseekMoE(Layer):
@@ -289,7 +454,9 @@ class DeepseekMoE(Layer):
 class DeepseekDecoderLayer(Layer):
     def __init__(self, config: DeepseekConfig, layer: int):
         super().__init__()
-        self.self_attn = MLAttention(config)
+        kinds = config.indexer_types
+        self.self_attn = MLAttention(
+            config, None if kinds is None else kinds[layer])
         self.sparse = layer >= config.first_k_dense_replace
         self.mlp = DeepseekMoE(config) if self.sparse else LlamaMLP(config)
         self.input_layernorm = RMSNorm(config.hidden_size,
@@ -298,10 +465,16 @@ class DeepseekDecoderLayer(Layer):
                                                 config.rms_norm_eps)
 
     def forward(self, hidden, cache=None, pos=None, paged=None, live=None,
-                pack=None):
+                pack=None, sel=None):
+        """`sel`: the selection of the "full" layer above (None above the
+        first, and in a model without indexers). Returns `(hidden,
+        new_cache or None, the selection this layer used)`."""
         h = self.self_attn(self.input_layernorm(hidden), cache=cache,
-                           pos=pos, paged=paged, pack=pack)
+                           pos=pos, paged=paged, pack=pack, sel=sel)
         new_cache = None
+        if self.self_attn.kind is not None:
+            h, sel = h[:-1], h[-1]
+            h = h[0] if cache is None else h
         if cache is not None:
             h, new_cache = h
         hidden = hidden + h
@@ -309,7 +482,7 @@ class DeepseekDecoderLayer(Layer):
         # a router must not send padding to experts: it is told what is live
         hidden = hidden + (self.mlp(h, live=live) if self.sparse
                            else self.mlp(h))
-        return hidden if cache is None else (hidden, new_cache)
+        return hidden, new_cache, sel
 
 
 class DeepseekModel(Layer):
@@ -325,9 +498,10 @@ class DeepseekModel(Layer):
     def forward(self, input_ids, caches=None, pos=None, paged=None,
                 pack=None):
         hidden = self.embed_tokens(input_ids)
+        sel = None
         if caches is None:
             for layer in self.layers:
-                hidden = layer(hidden)
+                hidden, _, sel = layer(hidden, sel=sel)
             return self.norm(hidden)
         live = None
         if pack is not None:
@@ -340,8 +514,9 @@ class DeepseekModel(Layer):
                 < jnp.reshape(paged[1], (-1, 1))
         new_caches = []
         for layer, cache in zip(self.layers, caches):
-            hidden, new_cache = layer(hidden, cache=cache, pos=pos,
-                                      paged=paged, live=live, pack=pack)
+            hidden, new_cache, sel = layer(hidden, cache=cache, pos=pos,
+                                           paged=paged, live=live,
+                                           pack=pack, sel=sel)
             new_caches.append(new_cache)
         return self.norm(hidden), new_caches
 
@@ -368,15 +543,23 @@ class DeepseekForCausalLM(Layer):
         """Per layer a `generation.LatentKV`: `c [batch, 1, max_len,
         kv_lora_rank]` and `r [batch, 1, max_len, lanes(qk_rope_head_dim)]`
         (the rotary key in the first `qk_rope_head_dim` columns, zeros
-        behind)."""
-        from .generation import LatentKV
+        behind); for a layer with an indexer a `generation.IndexedLatentKV`:
+        those two and `k_index [batch, 1, max_len, index_head_dim]`."""
+        from .generation import IndexedLatentKV, LatentKV
         cfg = self.config
         dt = dtype or self.model.embed_tokens.weight.dtype
-        return [LatentKV(
-            jnp.zeros((batch_size, 1, max_len, cfg.kv_lora_rank), dt),
-            jnp.zeros((batch_size, 1, max_len,
-                       _lanes(cfg.qk_rope_head_dim)), dt))
-            for _ in range(cfg.num_hidden_layers)]
+
+        def slab(width):
+            return jnp.zeros((batch_size, 1, max_len, width), dt)
+
+        kinds = cfg.indexer_types or [None] * cfg.num_hidden_layers
+        return [IndexedLatentKV(slab(cfg.kv_lora_rank),
+                                slab(_lanes(cfg.qk_rope_head_dim)),
+                                slab(cfg.index_head_dim))
+                if kind == "full" else
+                LatentKV(slab(cfg.kv_lora_rank),
+                         slab(_lanes(cfg.qk_rope_head_dim)))
+                for kind in kinds]
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
                            adapters=None, pack=None):

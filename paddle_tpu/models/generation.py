@@ -177,6 +177,19 @@ class LatentKV(NamedTuple):
     r: jax.Array
 
 
+class IndexedLatentKV(NamedTuple):
+    """What `init_cache` returns for a latent layer that carries an indexer
+    (learned sparse attention, `models/deepseek.py`): `LatentKV`'s `c` and
+    `r` and, beside them, `k_index [batch, 1, max_len, index_head_dim]`,
+    the one index key a token that the layer's indexer scores queries
+    against. Three slabs addressed by position alike, so a cache manager
+    pages, shares, copies and exports all three together; by this type it
+    knows that the third is there."""
+    c: jax.Array
+    r: jax.Array
+    k_index: jax.Array
+
+
 def make_decoder_fns(model):
     """Expose the prefill/decode-step builders for a cached-decode model.
 
@@ -193,7 +206,8 @@ def make_decoder_fns(model):
     generate() path) or a [B] int32 vector (per-row offsets — the
     slot-paged serving engine, where each cache row sits at its own
     length). `caches` is model.init_cache() layout: a list of
-    (k [B, Hkv, L, D], v) slabs, one per layer. The model is captured for
+    (k [B, Hkv, L, D], v) slabs, one per layer (a layer that keeps a third
+    slab a token hands a triple). The model is captured for
     its buffers/structure; call with the model already in eval mode.
 
     Both functions accept an optional `paged=(block_table [B, max_blocks],
@@ -224,18 +238,19 @@ def make_decoder_fns(model):
         with model._bound_state(p, buffers), no_grad():
             logits, new_caches = model.forward_with_cache(
                 Tensor(prompt),
-                [(Tensor(k), Tensor(v)) for k, v in caches_], pos,
+                [tuple(Tensor(a) for a in entry) for entry in caches_], pos,
                 paged=paged, adapters=adapters, pack=pack)
-        return logits.data, [(k.data, v.data) for k, v in new_caches]
+        return logits.data, [tuple(a.data for a in entry)
+                             for entry in new_caches]
 
     def decode_step(p, tok, pos, caches_, paged=None, adapters=None):
         with model._bound_state(p, buffers), no_grad():
             logits, new_caches = model.forward_with_cache(
                 Tensor(tok[:, None]),
-                [(Tensor(k), Tensor(v)) for k, v in caches_], pos,
+                [tuple(Tensor(a) for a in entry) for entry in caches_], pos,
                 paged=paged, adapters=adapters)
-        return logits.data[:, 0], [(k.data, v.data)
-                                   for k, v in new_caches]
+        return logits.data[:, 0], [tuple(a.data for a in entry)
+                                   for entry in new_caches]
 
     return params, prefill, decode_step
 

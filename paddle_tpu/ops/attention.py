@@ -945,8 +945,30 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, ring=None):
     return _row_writes(k_cache, v_cache, k_new, v_new, pos, ring)
 
 
+def update_caches(caches, news, pos):
+    """`update_kv_cache` for a layer that keeps more than two slabs a token
+    (`caches[j] [B, Hkv, L, Dj]`, `news[j] [B, Hkv, T, Dj]`, no ring): a
+    sparse-attention layer's latent, rotary key and index key. One
+    `kv_write` call takes them all on a TPU."""
+    from jax import lax
+    pos = jnp.asarray(pos)
+    news = tuple(n.astype(c.dtype) for n, c in zip(news, caches))
+    if pos.ndim == 0:
+        return tuple(lax.dynamic_update_slice(c, n, (0, 0, pos, 0))
+                     for c, n in zip(caches, news))
+    if pallas_mode.platform() != "cpu":
+        if kvw.kv_write_many_supported(caches, news):
+            return kvw.kv_write_many(caches, news, pos)
+        pallas_mode.note_reference(
+            kvw.KERNEL, "a slab the aligned windows do not fit",
+            *(c.shape for c in caches), news[0].shape[2])
+    pos = jnp.broadcast_to(pos, caches[0].shape[:1]).astype(jnp.int32)
+    write = jax.vmap(lambda c, u, p: lax.dynamic_update_slice(c, u, (0, p, 0)))
+    return tuple(write(c, n, pos) for c, n in zip(caches, news))
+
+
 def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
-                     window=None, q_rope=None):
+                     window=None, q_rope=None, sel=None):
     """Length-masked attention of q [B, H, T, D] over padded static caches
     [B, Hkv, L, D] (GQA: Hkv divides H; kv heads are repeated).
 
@@ -974,30 +996,44 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
     `q_rope`: the caches are a latent and its rotary key and q has the key
     projection absorbed (`ragged_paged_attention(q_rope=)`); `scale` is
     then required.
+
+    `sel` (an `ops.index_select.Selection`, with `q_rope`): the softmax is
+    over the selected keys alone (`paged_attention.sparse_latent_attention`;
+    the selection was made against the same `paged`, so its columns are
+    this walk's logical columns).
     """
     from .paged_attention import (DEFAULT_KV_BLOCK, ragged_paged_attention,
-                                  trivial_block_table)
+                                  sparse_latent_attention)
     B, H, T, D = q.shape
     if scale is None and q_rope is None:
         scale = 1.0 / (D ** 0.5)
+    walk = ragged_paged_attention if sel is None else functools.partial(
+        sparse_latent_attention, sel=sel)
     if paged is not None:
         # pool slabs may carry chunk write-padding past the page region,
         # so the caller names the addressable page geometry explicitly
         block_table, seq_lens, block_len, pages_per_row = paged
-        return ragged_paged_attention(
+        return walk(
             q, k_cache, v_cache, block_table, seq_lens, jnp.asarray(pos),
             block_len=int(block_len), pages_per_row=int(pages_per_row),
             scale=scale, window=window, q_rope=q_rope)
-    L = k_cache.shape[2]
+    (k_cache, v_cache), table, seq_lens, q_pos, nb = contiguous_paged(
+        (k_cache, v_cache), pos, T)
+    return walk(q, k_cache, v_cache, table, seq_lens, q_pos,
+                block_len=DEFAULT_KV_BLOCK, pages_per_row=nb, scale=scale,
+                window=window, q_rope=q_rope)
+
+
+def contiguous_paged(caches, pos, T: int):
+    """A contiguous per-row cache as the paged walks read one: (the caches
+    padded to whole pages of `DEFAULT_KV_BLOCK`, the trivial block table,
+    seq_lens `pos + T`, q_pos, pages a row)."""
+    from .paged_attention import DEFAULT_KV_BLOCK, trivial_block_table
+    B, L = caches[0].shape[0], caches[0].shape[2]
     table, nb = trivial_block_table(B, L, DEFAULT_KV_BLOCK)
     pad = nb * DEFAULT_KV_BLOCK - L
     if pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    pos = jnp.asarray(pos)
-    q_pos = jnp.broadcast_to(pos, (B,)).astype(jnp.int32)
-    seq_lens = q_pos + T
-    return ragged_paged_attention(q, k_cache, v_cache, table, seq_lens,
-                                  q_pos, block_len=DEFAULT_KV_BLOCK,
-                                  pages_per_row=nb, scale=scale,
-                                  window=window, q_rope=q_rope)
+        caches = tuple(jnp.pad(c, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                       for c in caches)
+    q_pos = jnp.broadcast_to(jnp.asarray(pos), (B,)).astype(jnp.int32)
+    return tuple(caches), table, q_pos + T, q_pos, nb

@@ -35,6 +35,10 @@ what runs past the ring is brought round to the ring's first columns
 read-modify-write of the slab row's first aligned columns under
 `pl.when(over > 0)`: one step in `ring / T` wraps.
 
+A layer that keeps a third slab a token (the index keys of a sparse-
+attention layer, `models/deepseek.py`) writes all three in the one call
+(`kv_write_many`): the body loops over its caches, two or three.
+
 Both slabs are aliased to the results (`input_output_aliases`), so a step
 that is donated its pool copies no slab round the call, and the
 `pallas_call` sits under one module-level `jax.jit` with static integers
@@ -96,9 +100,16 @@ def _choose_rows(B: int, Hkv: int, T: int, W: int, widths: int,
 
 def kv_write_supported(k_cache, v_cache, k_new, v_new, ring=None) -> bool:
     """Whether the kernel takes these shapes (module docstring)."""
-    if k_cache.ndim != 4 or k_cache.shape[:3] != v_cache.shape[:3] \
-            or k_new.shape[:3] != v_new.shape[:3] \
-            or k_cache.dtype != v_cache.dtype:
+    return kv_write_many_supported((k_cache, v_cache), (k_new, v_new), ring)
+
+
+def kv_write_many_supported(caches, news, ring=None) -> bool:
+    """`kv_write_supported` for any number of slabs a token."""
+    k_cache, k_new = caches[0], news[0]
+    if k_cache.ndim != 4 \
+            or any(c.shape[:3] != k_cache.shape[:3]
+                   or c.dtype != k_cache.dtype for c in caches) \
+            or any(n.shape[:3] != k_new.shape[:3] for n in news):
         return False
     L, T = k_cache.shape[2], k_new.shape[2]
     A = _tile(k_cache.dtype)
@@ -114,17 +125,18 @@ def kv_write_supported(k_cache, v_cache, k_new, v_new, ring=None) -> bool:
     return ring >= T and L >= ring + T and first >= head
 
 
-def _kernel(col_ref, knew_ref, vnew_ref, k_in, v_in, k_hbm, v_hbm, kwin,
-            vwin, in_sem, out_sem, *head, rows, T, A, ring):
+def _kernel(col_ref, *refs, n, rows, T, A, ring):
     """Grid (B / rows,); step i merges the stripes of slab rows
     [i * rows, (i + 1) * rows) into their aligned windows (module
     docstring). `col_ref [B]` (scalar prefetch) is each row's first column;
-    the stripes come through the pipeline; the slabs stay in HBM and are
-    read and written through the aliased results `k_hbm` / `v_hbm` alone."""
-    del k_in, v_in
-    i, n = pl.program_id(0), pl.num_programs(0)
-    L, W = k_hbm.shape[2], kwin.shape[3]
-    caches = ((knew_ref, k_hbm, kwin), (vnew_ref, v_hbm, vwin))
+    the `n` stripes come through the pipeline; the slabs stay in HBM and
+    are read and written through the aliased results alone."""
+    news, hbms, wins = refs[:n], refs[2 * n:3 * n], refs[3 * n:4 * n]
+    in_sem, out_sem = refs[4 * n:4 * n + 2]
+    head = refs[4 * n + 2:]
+    i, steps = pl.program_id(0), pl.num_programs(0)
+    L, W = hbms[0].shape[2], wins[0].shape[3]
+    caches = tuple(zip(news, hbms, wins))
 
     def start_of(row):
         return pl.multiple_of(jnp.minimum(col_ref[row] // A * A, L - W), A)
@@ -166,7 +178,7 @@ def _kernel(col_ref, knew_ref, vnew_ref, k_in, v_in, k_hbm, v_hbm, kwin,
     def _():
         fetch(0, 0)
 
-    @pl.when(i + 1 < n)
+    @pl.when(i + 1 < steps)
     def _():
         @pl.when(i + 1 >= _SETS)
         def _():
@@ -189,13 +201,13 @@ def _kernel(col_ref, knew_ref, vnew_ref, k_in, v_in, k_hbm, v_hbm, kwin,
                                   out_sem.at[s]).start()
         if ring is None:
             return
-        khead, vhead, head_sem = head
+        heads, head_sem = head[:n], head[n]
         over = col_ref[row] + T - ring      # columns past the ring's end
-        Wh = khead.shape[1]
+        Wh = heads[0].shape[1]
 
         @pl.when(over > 0)
         def _():
-            for (new_ref, hbm, _), buf in zip(caches, (khead, vhead)):
+            for (new_ref, hbm, _), buf in zip(caches, heads):
                 first = hbm.at[row, :, pl.ds(0, Wh), :]
                 fetch_head = pltpu.make_async_copy(first, buf, head_sem)
                 fetch_head.start()
@@ -211,7 +223,7 @@ def _kernel(col_ref, knew_ref, vnew_ref, k_in, v_in, k_hbm, v_hbm, kwin,
 
     each_row(merge)
 
-    @pl.when(i == n - 1)
+    @pl.when(i == steps - 1)
     def _():
         for k in range(_SETS):              # what is still being written
             @pl.when(i >= k)
@@ -220,15 +232,15 @@ def _kernel(col_ref, knew_ref, vnew_ref, k_in, v_in, k_hbm, v_hbm, kwin,
 
 
 @functools.partial(jax.jit, static_argnames=("ring", "rows", "interpret"))
-def _kv_write_call(k_cache, v_cache, k_new, v_new, col, *, ring, rows,
-                   interpret):
+def _kv_write_call(caches, news, col, *, ring, rows, interpret):
     """The kernel's `pallas_call`. Jitted at module level with every
     integer static, so the call sites of one traced program that agree on
     shapes and ring (a step's layers, unrolled) share one jaxpr, and the
     program lowers one kernel body for them, not one each."""
-    B, Hkv, _, _ = k_cache.shape
-    T = k_new.shape[2]
-    A = _tile(k_cache.dtype)
+    n = len(caches)
+    B, Hkv, _, _ = caches[0].shape
+    T = news[0].shape[2]
+    A = _tile(caches[0].dtype)
     W = _window(T, A)
 
     def stripes(new):
@@ -239,30 +251,46 @@ def _kv_write_call(k_cache, v_cache, k_new, v_new, col, *, ring, rows,
         return pltpu.VMEM((_SETS, rows, Hkv, W, cache.shape[3]), cache.dtype)
 
     slab = pl.BlockSpec(memory_space=pl.ANY)
-    scratch = [windows(k_cache), windows(v_cache),
-               pltpu.SemaphoreType.DMA((_SETS,)),
-               pltpu.SemaphoreType.DMA((_SETS,))]
+    scratch = [windows(c) for c in caches] + [
+        pltpu.SemaphoreType.DMA((_SETS,)),
+        pltpu.SemaphoreType.DMA((_SETS,))]
     if ring is not None:
         scratch += [pltpu.VMEM((Hkv, W - A, c.shape[3]), c.dtype)
-                    for c in (k_cache, v_cache)]
+                    for c in caches]
         scratch.append(pltpu.SemaphoreType.DMA(()))
     return pl.pallas_call(
-        functools.partial(_kernel, rows=rows, T=T, A=A, ring=ring),
+        functools.partial(_kernel, n=n, rows=rows, T=T, A=A, ring=ring),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B // rows,),
-            in_specs=[stripes(k_new), stripes(v_new), slab, slab],
-            out_specs=[slab, slab], scratch_shapes=scratch),
-        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+            in_specs=[stripes(x) for x in news] + [slab] * n,
+            out_specs=[slab] * n, scratch_shapes=scratch),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in caches],
         # the slabs are written where they lie: a step that is donated its
         # pool copies nothing round the call
-        input_output_aliases={3: 0, 4: 1},
+        input_output_aliases={1 + n + j: j for j in range(n)},
         # in order: a step sets the next one's windows going
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=KERNEL,
-    )(col, k_new, v_new, k_cache, v_cache)
+    )(col, *news, *caches)
+
+
+def kv_write_many(caches, news, pos, ring=None):
+    """`kv_write` for any number of slabs a token (`caches[j] [B, Hkv, L,
+    Dj]`, `news[j] [B, Hkv, T, Dj]`): one call, every slab aliased."""
+    B, Hkv, L, _ = caches[0].shape
+    T = news[0].shape[2]
+    pos = jnp.broadcast_to(jnp.asarray(pos), (B,)).astype(jnp.int32)
+    col = jnp.clip(pos, 0, L - T) if ring is None else pos % ring
+    W = _window(T, _tile(caches[0].dtype))
+    rows = _choose_rows(B, Hkv, T, W, sum(c.shape[3] for c in caches),
+                        caches[0].dtype.itemsize)
+    pallas_mode.note_tiling(KERNEL, grid=(B // rows,), rows=rows, heads=Hkv,
+                            columns=W, ring=ring or 0)
+    return tuple(_kv_write_call(
+        tuple(caches), tuple(news), col, ring=ring, rows=rows,
+        interpret=pallas_mode.interpret(KERNEL)))
 
 
 def kv_write(k_cache, v_cache, k_new, v_new, pos, ring=None):
@@ -272,16 +300,4 @@ def kv_write(k_cache, v_cache, k_new, v_new, pos, ring=None):
     or, with `ring`, at `pos[b] mod ring` with its overrun brought round.
     The caller has cast the stripes to the caches' type and checked
     `kv_write_supported`."""
-    B, Hkv, L, _ = k_cache.shape
-    T = k_new.shape[2]
-    pos = jnp.broadcast_to(jnp.asarray(pos), (B,)).astype(jnp.int32)
-    col = jnp.clip(pos, 0, L - T) if ring is None else pos % ring
-    W = _window(T, _tile(k_cache.dtype))
-    rows = _choose_rows(B, Hkv, T, W, k_cache.shape[3] + v_cache.shape[3],
-                        k_cache.dtype.itemsize)
-    pallas_mode.note_tiling(KERNEL, grid=(B // rows,), rows=rows, heads=Hkv,
-                            columns=W, ring=ring or 0)
-    k_cache, v_cache = _kv_write_call(
-        k_cache, v_cache, k_new, v_new, col, ring=ring, rows=rows,
-        interpret=pallas_mode.interpret(KERNEL))
-    return k_cache, v_cache
+    return kv_write_many((k_cache, v_cache), (k_new, v_new), pos, ring)

@@ -107,6 +107,26 @@ and masking under the `pallas_call` name `paged_latent`; `_choose_tile`
 folds as many heads into a tile's rows as the budget holds (32 x 16 rows at
 the serve cell's shape, so G = 2 and the small page is fetched twice).
 
+A selection (PR 39). A layer of learned sparse attention
+(`ops/index_select.py`) attends to the `topk` keys its indexer chose for
+each query, the same for every head, over the same latent pages
+(`sparse_latent_attention`, `pallas_call` name `paged_sparse`):
+
+- a row with one live column (a decode row) *gathers*: the selected tokens'
+  `[c | r]` are taken by position through the block table into a compact
+  cache of `topk` columns a row, and the latent walk runs over that: key
+  reads and arithmetic are bounded by `topk`, whatever the row's context;
+- a row with more (a prefill chunk: sixteen selections over one slab) is
+  *one walk over the union*: the latent walk with `sel=`, each query
+  column's own 0/1 mask of the group's columns brought beside the group's
+  pages and ANDed into `keep`. Sixteen gathers would copy 16 x `topk`
+  tokens of 1,280 B a row and layer through XLA's gather (a token is no
+  aligned window of a packed bf16 slab, so no DMA can take it); the walk
+  streams the row's pages once at HBM speed and the sixteen columns share
+  them. A masked key is an exact zero of the softmax, as a key past the
+  row's length is, so the result is the gather's up to the grouping of the
+  sum.
+
 Numerics: flash-style online softmax with the repo's exact-zero masking
 convention (ops/attention.py `_fwd_kernel`): masked scores sit at
 `_NEG_INF`, `p = where(s <= _NEG_INF/2, 0, exp(s - m_new))` contributes an
@@ -153,9 +173,14 @@ WINDOW_KERNEL = "paged_window"
 # The latent walk's name (multi-head latent attention's cache): likewise
 # neither of the other two's.
 LATENT_KERNEL = "paged_latent"
+# The latent walk of a layer whose keys an indexer selected (a mask a query
+# column, or a gathered compact cache): neither of the others' either.
+SPARSE_KERNEL = "paged_sparse"
 
 
-def _kernel_name(window, latent: bool) -> str:
+def _kernel_name(window, latent: bool, sparse: bool = False) -> str:
+    if sparse:
+        return SPARSE_KERNEL
     return LATENT_KERNEL if latent else \
         "paged_attention" if window is None else WINDOW_KERNEL
 
@@ -244,14 +269,15 @@ def _window_groups(window: int, Tq: int, block_len: int, ring_pages: int):
 
 def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                block_len: int, pages_per_row: int, scale: float,
-               window: int = None, q_rope=None):
+               window: int = None, q_rope=None, sel=None):
     """lax.scan over logical blocks, carrying (m, l, acc) — the same
     masked-score -> exact-zero-p -> alpha-rescale sequence as the kernel,
     one compiled program regardless of grid size. With `window` step i is
     row b's logical block `first[b] + i`, read from the ring. With
     `q_rope` the caches are a latent and its rotary key (module
     docstring): the scores are the sum of two products and the latent page
-    is the values' page too."""
+    is the values' page too. With `sel [B, Tq, L]` a query column sees the
+    columns its mask holds a 1 at, and no other."""
     B, H, Tq, D = q.shape
     Hkv = k_cache.shape[1]
     n_rep = H // Hkv
@@ -300,6 +326,9 @@ def _scan_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
         keep = (col <= row[:, :, None]) & (col < seq_lens[:, None, None])
         if window is not None:
             keep &= col > row[:, :, None] - window
+        if sel is not None:
+            keep &= jax.lax.dynamic_slice_in_dim(
+                sel, j * block_len, block_len, axis=2) > 0.5
         s = jnp.where(keep[:, None], s, _NEG_INF)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -327,7 +356,7 @@ def _head_dot(a, b, a_dim, b_dim):
 
 def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
                   block_len, pages, pages_per_row, n_groups, parts, scale,
-                  Tq, window=None, latent=False):
+                  Tq, window=None, latent=False, masked=False):
     """Grid (B, G); one step is one slot's whole walk for every head of
     the tile: a loop over the row's live groups of `pages` consecutive
     logical pages. q/o tiles [heads, fold*Tq, D] (a KV head's query heads
@@ -346,10 +375,17 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
     has a lower edge too. With `latent` a second q tile follows the first
     (`[1, rows, rope width]`), "K" is the latent slab and "V" the rotary
     key's: the scores add the second product, and the values are the
-    latent page that is already in VMEM."""
-    qr_ref = refs[0] if latent else None
-    (k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, slot_ref, acc_ref, m_ref,
-     l_ref) = refs[1:] if latent else refs
+    latent page that is already in VMEM. With `masked` a third slab in HBM
+    follows the two (`sel [B, Tq, L]`, a query column's 0/1 mask of the
+    logical columns) and a group's `[Tq, keys]` piece of it rides beside
+    the group's pages into a buffer of its own."""
+    refs = list(refs)
+    qr_ref = refs.pop(0) if latent else None
+    k_hbm, v_hbm = refs.pop(0), refs.pop(0)
+    sel_hbm = refs.pop(0) if masked else None
+    o_ref, kbuf, vbuf = refs.pop(0), refs.pop(0), refs.pop(0)
+    selbuf = refs.pop(0) if masked else None
+    sem, slot_ref, acc_ref, m_ref, l_ref = refs
     b, g = pl.program_id(0), pl.program_id(1)
     B, G = pl.num_programs(0), pl.num_programs(1)
     heads, rows = q_ref.shape[1], q_ref.shape[2]
@@ -403,6 +439,11 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
             issue(0, None)
         else:
             jax.lax.fori_loop(0, pages // at_once, issue, None)
+        if masked:
+            pltpu.make_async_copy(
+                sel_hbm.at[row, :, pl.ds(pl.multiple_of(j * keys, keys),
+                                         keys)],
+                selbuf.at[slot], sem.at[slot]).start()
 
     first, n = walk(b)
     # the grid step after this one, whose first group this one sets going
@@ -438,7 +479,7 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
         @pl.when((i >= 0) & (i < n))
         def _():
             slot = 1 - ahead
-            for buf in (kbuf, vbuf):
+            for buf in (kbuf, vbuf) + ((selbuf,) if masked else ()):
                 # the group's copies share the buffer's semaphore, which
                 # counts bytes: one wait for the buffer's size takes all
                 pltpu.make_async_copy(buf.at[slot], buf.at[slot],
@@ -447,6 +488,8 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
             keep = (col <= row_pos) & (col < lens_ref[b])
             if window is not None:
                 keep &= col > row_pos - window
+            if masked:                        # folded row r is token r mod Tq
+                keep &= jnp.tile(selbuf[slot], (rows // Tq, 1)) > 0.5
             vgrp = vbuf[slot]                             # [heads, keys, D]
             s = _head_dot(q_ref[0], kbuf[slot], 1, 1)
             if latent:
@@ -474,10 +517,10 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
 
 @functools.partial(jax.jit, static_argnames=(
     "block_len", "pages_per_row", "scale", "window", "heads", "fold",
-    "n_groups", "interpret"))
+    "n_groups", "interpret", "sparse"))
 def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
-                q_rope=None, *, block_len, pages_per_row, scale, window,
-                heads, fold, n_groups, interpret):
+                q_rope=None, sel=None, *, block_len, pages_per_row, scale,
+                window, heads, fold, n_groups, interpret, sparse=False):
     """The kernel's `pallas_call` at one tile. Jitted at module level with
     every integer static, so the call sites of one traced program that
     agree on shapes and window (a step's layers, unrolled) share one
@@ -488,7 +531,8 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
     P = _group_pages(block_len)
     rows = fold * Tq
     latent = q_rope is not None
-    name = _kernel_name(window, latent)
+    masked = sel is not None
+    name = _kernel_name(window, latent, sparse)
 
     def tile(width):
         return pl.BlockSpec((1, heads, rows, width),
@@ -507,12 +551,14 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, H // (heads * fold)),
-        in_specs=[tile(x.shape[3]) for x in queries] + [slab, slab],
+        in_specs=[tile(x.shape[3]) for x in queries]
+        + [slab] * (3 if masked else 2),
         out_specs=tile(D),
         scratch_shapes=[
             group(k_cache),
-            group(v_cache),
-            pltpu.SemaphoreType.DMA((2,)),
+            group(v_cache)]
+        + ([pltpu.VMEM((2, Tq, P * block_len), sel.dtype)] if masked else [])
+        + [pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),       # the buffer in flight
             pltpu.VMEM((heads, rows, D), jnp.float32),
             pltpu.VMEM((heads, rows, 1), jnp.float32),
@@ -523,7 +569,7 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
         _paged_kernel, block_len=block_len, pages=P,
         pages_per_row=pages_per_row, n_groups=n_groups,
         parts=n_rep // fold,       # tiles that share one KV head (1: none)
-        scale=scale, Tq=Tq, window=window, latent=latent)
+        scale=scale, Tq=Tq, window=window, latent=latent, masked=masked)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H // fold, rows, D), q.dtype),
@@ -533,13 +579,13 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
         interpret=interpret,
         name=name,
     )(jnp.maximum(block_table, 0), seq_lens, q_pos, *queries, k_cache,
-      v_cache)
+      v_cache, *((sel,) if masked else ()))
     return out.reshape(B, H, Tq, D)
 
 
 def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                  block_len: int, pages_per_row: int, scale: float,
-                 window: int = None, q_rope=None):
+                 window: int = None, q_rope=None, sel=None, sparse=False):
     """Choose the tile from the shapes, record it, and call the kernel
     through its one jitted entry."""
     B, H, Tq, D = q.shape
@@ -551,23 +597,28 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
     width = D if q_rope is None else D + q_rope.shape[3]
     heads, fold = _choose_tile(H, k_cache.shape[1], Tq, block_len, width,
                                q.dtype.itemsize)
-    name = _kernel_name(window, q_rope is not None)
+    name = _kernel_name(window, q_rope is not None, sparse)
     pallas_mode.note_tiling(name, grid=(B, H // (heads * fold)),
                             groups=n_groups, pages=P, heads=heads,
                             rows=fold * Tq)
     more = () if q_rope is None else (q_rope,)
+    if sel is not None:
+        # whole groups of columns: a group's piece is one aligned copy
+        short = n_groups * P * block_len - sel.shape[2]
+        more += (jnp.pad(sel, ((0, 0), (0, 0), (0, max(short, 0)))),)
     return _paged_call(
         q, k_cache, v_cache, block_table, seq_lens, q_pos, *more,
         block_len=block_len, pages_per_row=pages_per_row,
         scale=float(scale), window=window, heads=heads, fold=fold,
-        n_groups=n_groups, interpret=pallas_mode.interpret(name))
+        n_groups=n_groups, interpret=pallas_mode.interpret(name),
+        sparse=sparse)
 
 
 def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
                            q_pos, *, block_len: int,
                            pages_per_row: int = None, scale: float = None,
                            impl: str = None, window: int = None,
-                           q_rope=None):
+                           q_rope=None, sel=None, sparse: bool = False):
     """Attention of q [B, H, Tq, D] over block-table-addressed KV pages.
 
     k_cache/v_cache: [N, Hkv, L_slab, D] slabs (N need not equal B — block
@@ -587,8 +638,15 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
     cache"): scores `scale * (q . c + q_rope . r)`, values c; the result
     is [B, H, Tq, D] in the latent's space. `scale` is then the caller's
     to give.
+    sel [B, Tq, L >= max_blocks * block_len] float32, with `q_rope`: query
+    column t of row b sees logical column s only where `sel[b, t, s]` is 1
+    (module docstring, "A selection"). `sparse`: the call is a sparse
+    layer's and goes by the name `paged_sparse` (`sel` implies it).
     """
     B, H, Tq, D = q.shape
+    sparse = sparse or sel is not None
+    if sparse and q_rope is None:
+        raise ValueError("a selection is over a latent cache (q_rope=)")
     if q_rope is not None:
         if window is not None or scale is None or k_cache.shape[1] != 1 \
                 or v_cache.shape[3] != q_rope.shape[3]:
@@ -622,10 +680,16 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
             f"cache length {k_cache.shape[2]} cannot back {pages_per_row} "
             f"pages of {block_len} tokens")
     if impl == "scan":
-        pallas_mode.count(_kernel_name(window, q_rope is not None), "scan")
+        pallas_mode.count(_kernel_name(window, q_rope is not None, sparse),
+                          "scan")
         impl_fn = _scan_impl
     else:
         impl_fn = _pallas_impl
+    if sparse:
+        more = {} if impl == "scan" else {"sparse": True}
+        return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
+                       block_len, pages_per_row, scale, None, q_rope, sel,
+                       **more)
     if q_rope is not None:
         return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                        block_len, pages_per_row, scale, None, q_rope)
@@ -634,6 +698,53 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
                        block_len, pages_per_row, scale)
     return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                    block_len, pages_per_row, scale, int(window))
+
+
+def sparse_latent_attention(q, c_cache, r_cache, block_table, seq_lens,
+                            q_pos, *, sel, block_len: int,
+                            pages_per_row: int, scale: float, q_rope,
+                            window=None, impl: str = None):
+    """Latent attention over the keys an indexer selected (module
+    docstring, "A selection"): `ragged_paged_attention(q_rope=)`'s operands
+    and `sel`, an `ops.index_select.Selection` made against the same table.
+    A row whose step has one live column (`seq_lens - q_pos == 1`; every
+    row where Tq is 1) takes the gathered form for its column 0, every
+    other row the walk under its columns' masks; each call skips the other
+    kind's rows (a row of length 0 has no group to walk)."""
+    if window is not None:
+        raise ValueError("a selection and a window: not a layer kind")
+    B, H, Tq, D = q.shape
+    N, _, L_slab, _ = c_cache.shape
+    block_table = jnp.asarray(block_table, jnp.int32)
+    seq_lens = jnp.asarray(seq_lens, jnp.int32)
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    walk = functools.partial(ragged_paged_attention, scale=scale, impl=impl)
+    one = (seq_lens - q_pos == 1) | (Tq == 1)                  # [B]
+
+    # the gathered form: column 0's selected tokens, by position, through
+    # the table, into a compact cache of K columns a row
+    K = sel.idx.shape[1]
+    pad = -K % block_len
+    idx = jnp.pad(sel.idx, ((0, 0), (0, pad)))
+    page = jnp.take_along_axis(jnp.maximum(block_table, 0),
+                               idx // block_len, axis=1)
+    at = (page // pages_per_row) * L_slab \
+        + page % pages_per_row * block_len + idx % block_len   # [B, K]
+    compact = [jnp.take(cache.reshape(N * L_slab, -1), at, axis=0)[:, None]
+               for cache in (c_cache, r_cache)]                # [B, 1, K, .]
+    table, nb = trivial_block_table(B, K + pad, block_len)
+    # every gathered key is behind the query: q_pos past them all
+    out = walk(q[:, :, :1], *compact, table,
+               jnp.where(one, sel.count, 0),
+               jnp.full((B,), K + pad, jnp.int32), block_len=block_len,
+               pages_per_row=nb, q_rope=q_rope[:, :, :1], sparse=True)
+    if Tq == 1:
+        return out
+    union = walk(q, c_cache, r_cache, block_table,
+                 jnp.where(one, 0, seq_lens), q_pos, block_len=block_len,
+                 pages_per_row=pages_per_row, q_rope=q_rope, sel=sel.mask)
+    first = jnp.arange(Tq, dtype=jnp.int32)[None, None, :, None] == 0
+    return jnp.where(one[:, None, None, None] & first, out, union)
 
 
 def trivial_block_table(batch: int, cache_len: int,

@@ -91,6 +91,28 @@ by position exactly as `(k, v)` is. So it is paged like `paged`:
 `rewind_length` and `defrag` work on it as they are, the prefix cache
 stays on, nothing is refused; `layer_kinds` says `latent` and `kv_bytes()`
 counts it under its own name.
+
+A fifth kind: index-key pages (PR 39). A latent layer that carries an
+indexer (learned sparse attention; `init_cache` answers with a
+`models.generation.IndexedLatentKV`) keeps a third slab beside `c` and `r`:
+`k_index [slots, 1, slab_len, index_head_dim]`, the one key a token its
+indexer scores queries against. Its pages are numbered as the latent pages
+are (page p of the index slab is page p of `c` and of `r`: one block table,
+one ledger), so whatever moves, shares, pins, exports or scrubs a page does
+it to all three slabs of such a layer: `attach_blocks`, `cow_copy`,
+`register_cached` / `release_cached`, `export_rows` / `import_rows`,
+`export_page` / `import_page`, `defrag`. `layer_kinds` says `indexed`, and
+`kv_bytes()` counts `c` and `r` under "latent" and the third slab under
+"index". An entry of `slabs` is a tuple of two arrays or of three.
+
+Which row a request gets (PR 39). A request that attaches its leading `n`
+blocks writes only from block `n` on, so a free row is fit for it when none
+of the row's cached pages sits at block `n` or above: `allocate(need,
+keep_below=n, prefer=row)`. A session's next turn, which attaches what its
+last turn left cached in a row, so goes back into that row (`prefer`),
+cleared behind block `n` if need be (the tail of its last prompt, a stale
+continuation) where no row is free of cached pages; `keep_below=0` is the
+old rule (a row with any cached page is not handed out).
 """
 from __future__ import annotations
 
@@ -102,9 +124,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.generation import LatentKV, RecurrentState, WindowKV
+from ...models.generation import (IndexedLatentKV, LatentKV, RecurrentState,
+                                  WindowKV)
 
 PAGED, RECURRENT, WINDOW, LATENT = "paged", "recurrent", "window", "latent"
+INDEXED = "indexed"      # latent pages and, beside them, index-key pages
 
 
 class RecurrentStateError(NotImplementedError):
@@ -171,11 +195,13 @@ class SlotPagedKVPool:
         self.layer_kinds: List[str] = [
             RECURRENT if isinstance(e, RecurrentState)
             else WINDOW if isinstance(e, WindowKV)
-            else LATENT if isinstance(e, LatentKV) else PAGED
+            else LATENT if isinstance(e, LatentKV)
+            else INDEXED if isinstance(e, IndexedLatentKV) else PAGED
             for e in entries]
         self.recurrent = RECURRENT in self.layer_kinds
         self.windowed = WINDOW in self.layer_kinds
-        self.latent = LATENT in self.layer_kinds
+        self.indexed = INDEXED in self.layer_kinds
+        self.latent = self.indexed or LATENT in self.layer_kinds
         # the window layers' ring, in columns and pages (None: no such
         # layer); every window layer of a model has the one window
         rings = {int(e.k.shape[2]) - self.pad_tokens
@@ -190,8 +216,8 @@ class SlotPagedKVPool:
         # buffers, is assigned back: a reference kept across a step is a
         # deleted array. Read the attribute when you work, on the thread
         # that launches steps, or under `slabs_lock` on another
-        self.slabs: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
-            (a, b) for a, b in entries]
+        self.slabs: List[Tuple[jnp.ndarray, ...]] = [
+            tuple(e) for e in entries]
         self.slabs_lock = threading.Lock()
         self.lengths = np.zeros((self.num_slots,), np.int32)
         self.active = np.zeros((self.num_slots,), bool)
@@ -208,11 +234,15 @@ class SlotPagedKVPool:
         self._own_claimed: Dict[int, int] = {}      # slot -> own pages
         self.refcount: Dict[int, int] = {}          # page -> live readers
         self.cached: Set[int] = set()               # pages pinned by cache
+        # the same by row and block, and counted by row, for `_fit_rows`
+        self._cached_at = np.zeros((self.num_slots, self.n_blocks), bool)
+        self._cached_in = np.zeros((self.num_slots,), np.int32)
         self._cache_owned: Set[int] = set()         # cached, owner freed
         # cache-pressure hook: called by allocate() when free rows exist
-        # but every one is pinned; the prefix cache wires its LRU
-        # eviction here and returns the number of pages released
-        self.on_pressure: Optional[Callable[[], int]] = None
+        # but every one is pinned; the prefix cache wires its eviction
+        # here (`on_pressure()` for a fresh sequence, else
+        # `on_pressure(keep_below, free rows)`) and returns pages released
+        self.on_pressure: Optional[Callable[..., int]] = None
         self.stats = {"allocs": 0, "frees": 0, "reuses": 0,
                       "alloc_failures": 0, "defrags": 0, "peak_active": 0,
                       "blocks_allocated": 0, "blocks_freed": 0,
@@ -243,23 +273,29 @@ class SlotPagedKVPool:
     def kv_bytes(self) -> Dict[str, int]:
         """Bytes of the K/V slabs by what they are: "full" (a slot's whole
         context), "window" (a ring) and, on a pool that holds one,
-        "latent" (a slot's whole context as a latent and a rotary key),
-        all slots and layers."""
-        names = {PAGED: "full", WINDOW: "window", LATENT: "latent"}
+        "latent" (a slot's whole context as a latent and a rotary key) and
+        "index" (as an index key), all slots and layers."""
+        names = {PAGED: "full", WINDOW: "window", LATENT: "latent",
+                 INDEXED: "latent"}
         out = {"full": 0, "window": 0}
         if self.latent:
             out["latent"] = 0
-        for (a, b), kind in zip(self.slabs, self.layer_kinds):
-            if kind != RECURRENT:
-                out[names[kind]] += int(a.nbytes) + int(b.nbytes)
+        if self.indexed:
+            out["index"] = 0
+        for entry, kind in zip(self.slabs, self.layer_kinds):
+            if kind == RECURRENT:
+                continue
+            out[names[kind]] += int(entry[0].nbytes) + int(entry[1].nbytes)
+            if kind == INDEXED:
+                out["index"] += int(entry[2].nbytes)
         return out
 
     @property
     def recurrent_state_bytes(self) -> int:
         """Bytes of the recurrent layers' per-slot state, all slots."""
-        return sum(int(a.nbytes) + int(b.nbytes)
-                   for (a, b), kind in zip(self.slabs, self.layer_kinds)
-                   if kind == RECURRENT)
+        return sum(int(a.nbytes) for entry, kind
+                   in zip(self.slabs, self.layer_kinds)
+                   if kind == RECURRENT for a in entry)
 
     def consumed(self) -> bool:
         """Whether a dispatch that was donated the slabs took them: asked
@@ -299,25 +335,38 @@ class SlotPagedKVPool:
     def _identity_row(self, slot: int) -> List[int]:
         return [slot * self.n_blocks + j for j in range(self.n_blocks)]
 
-    def _row_pinned(self, row: int) -> bool:
-        """A row holding ANY cached page cannot be handed to a fresh
-        sequence: its prefill would overwrite shared KV in place."""
-        base = row * self.n_blocks
-        return any((base + j) in self.cached for j in range(self.n_blocks))
+    def _fit_rows(self, keep_below: int = 0) -> np.ndarray:
+        """[num_slots] bool: the free rows that can be handed to a sequence
+        which writes from block `keep_below` on. A row holding a cached
+        page at that block or above cannot: the prefill would overwrite
+        shared KV in place (0: a fresh sequence, any cached page pins the
+        row). The pressure hook asks once a page it frees, between two
+        steps and beside the clients' threads: a fresh sequence's answer
+        reads the rows' counts alone (a reduction over the whole ledger
+        lets go of the interpreter lock each time, and the other threads'
+        work then lands inside the admission)."""
+        if keep_below == 0:
+            return ~self.active & (self._cached_in == 0)
+        return ~self.active & ~self._cached_at[:, keep_below:].any(axis=1)
 
-    def has_allocatable_row(self) -> bool:
-        return any(not self.active[r] and not self._row_pinned(r)
-                   for r in range(self.num_slots))
+    def has_allocatable_row(self, keep_below: int = 0) -> bool:
+        return bool(self._fit_rows(keep_below).any())
 
     # ---- allocation ----
-    def allocate(self, need_tokens: int) -> int:
+    def allocate(self, need_tokens: int, keep_below: int = 0,
+                 prefer: Optional[int] = None) -> int:
         """Claim a free, unpinned slot for a sequence that will grow to
         `need_tokens` (prompt + max_new_tokens). Raises ValueError when
         the request can never fit and SlotsExhaustedError when the pool
         is momentarily full. When every free row is pinned by cached
-        blocks, the `on_pressure` hook (the prefix cache's LRU eviction)
+        blocks, the `on_pressure` hook (the prefix cache's eviction)
         gets one chance to release refcount-0 entries before the
-        exhaustion verdict — pages with live readers are never touched."""
+        exhaustion verdict — pages with live readers are never touched.
+
+        `keep_below`: the sequence attaches its leading `keep_below`
+        blocks and writes only behind them, so cached pages below that
+        block do not pin a row against it; `prefer`: the row to take if it
+        is fit (the row its attached pages live in)."""
         if need_tokens > self.capacity:
             raise ValueError(
                 f"sequence needs {need_tokens} tokens but slot capacity is "
@@ -328,11 +377,33 @@ class SlotPagedKVPool:
             self.stats["alloc_failures"] += 1
             raise SlotsExhaustedError(
                 f"all {self.num_slots} slots active")
-        slot = next((int(r) for r in free if not self._row_pinned(r)), None)
+        order = [int(r) for r in free]
+        mine = [int(prefer)] if prefer in order else []
+
+        def fit(rows, below=keep_below):
+            ok = self._fit_rows(below)
+            return next((r for r in rows if ok[r]), None)
+
+        # in order: the preferred row as it is; a row with no cached page
+        # at all (the rule for a fresh sequence); the preferred row cleared
+        # behind `keep_below` (what goes is this prefix's own stale
+        # continuation: a session's pages stay in one row); another row
+        # whose cached pages sit below `keep_below`; under pressure, the
+        # row that costs the fewest pages
+        slot = fit(mine) if mine else None
+        if slot is None:
+            slot = fit(order, 0)
+        if slot is None and mine and self.on_pressure is not None:
+            self.on_pressure(keep_below, mine)
+            slot = fit(mine)
+        if slot is None and keep_below:
+            slot = fit(order)
         if slot is None and self.on_pressure is not None:
-            self.on_pressure()
-            slot = next((int(r) for r in free if not self._row_pinned(r)),
-                        None)
+            if keep_below == 0 and prefer is None:
+                self.on_pressure()
+            else:
+                self.on_pressure(keep_below, order)
+            slot = fit(order)
         if slot is None:
             self.stats["alloc_failures"] += 1
             raise SlotsExhaustedError(
@@ -520,8 +591,8 @@ class SlotPagedKVPool:
         sr = jnp.int32(src_row)
         dr = jnp.int32(dst_slot)
         c0 = jnp.int32(block_idx * self.block_len)
-        self.slabs = [(self._cow(k, sr, dr, c0), self._cow(v, sr, dr, c0))
-                      for k, v in self.slabs]
+        self.slabs = [tuple(self._cow(a, sr, dr, c0) for a in entry)
+                      for entry in self.slabs]
         self.stats["cow_copies"] += 1
 
     def register_cached(self, page: int):
@@ -534,6 +605,9 @@ class SlotPagedKVPool:
         if page in self.cached:
             raise ValueError(f"page {page} already cache-registered")
         self.cached.add(page)
+        row, block = divmod(page, self.n_blocks)
+        self._cached_at[row, block] = True
+        self._cached_in[row] += 1
 
     def release_cached(self, page: int):
         """Cache eviction: unpin a page. Refuses while readers hold it.
@@ -546,6 +620,9 @@ class SlotPagedKVPool:
                 f"page {page} has {self.refcount[page]} live reader(s); "
                 "evicting it would corrupt active streams")
         self.cached.discard(page)
+        row, block = divmod(page, self.n_blocks)
+        self._cached_at[row, block] = False
+        self._cached_in[row] -= 1
         if page in self._cache_owned:
             self._cache_owned.discard(page)
             self.stats["blocks_freed"] += 1
@@ -679,6 +756,15 @@ class SlotPagedKVPool:
                 f"blocks_allocated={b_alloc} != blocks_freed={b_freed} + "
                 f"blocks_active={b_active} + blocks_cached={b_cached} "
                 f"(leaked {b_alloc - b_freed - b_active - b_cached})")
+        # the pinned pages as `_fit_rows` reads them: by row and block, and
+        # counted by row
+        at = np.flatnonzero(self._cached_at)
+        if set(at.tolist()) != self.cached or not np.array_equal(
+                self._cached_in, self._cached_at.sum(axis=1)):
+            raise AssertionError(
+                f"KV pool cached-page ledgers disagree: {len(self.cached)} "
+                f"pinned pages, {at.size} by row and block, "
+                f"{int(self._cached_in.sum())} counted by row")
         return True
 
     # ---- row serialization (ISSUE 14: KV handoff groundwork) ----
@@ -706,7 +792,7 @@ class SlotPagedKVPool:
                 length = int(self.lengths[slot])
                 pages = list(self.block_table.get(slot, []))
                 layers = []
-                for k, v in self.slabs:
+                for entry in self.slabs:
                     # ISSUE 19: length-trimmed fetch — slice each occupied
                     # page's columns on DEVICE and fetch only those, instead
                     # of materializing the whole [num_slots, Hkv, slab_len, D]
@@ -714,27 +800,27 @@ class SlotPagedKVPool:
                     # with the row's committed length, not the pool size; the
                     # payload is bit-identical to the untrimmed path (pinned
                     # in tests/test_router.py).
-                    kparts, vparts = [], []
+                    parts = [[] for _ in entry]
                     for j, p in enumerate(pages):
                         prow = p // self.n_blocks
                         c0 = (p % self.n_blocks) * self.block_len
                         w = min(self.block_len, length - j * self.block_len)
-                        kparts.append(np.asarray(k[prow, :, c0:c0 + w, :]))
-                        vparts.append(np.asarray(v[prow, :, c0:c0 + w, :]))
-                    if kparts:
-                        layers.append((np.concatenate(kparts, axis=1),
-                                       np.concatenate(vparts, axis=1)))
+                        for got, a in zip(parts, entry):
+                            got.append(np.asarray(a[prow, :, c0:c0 + w, :]))
+                    if pages:
+                        layers.append(tuple(np.concatenate(got, axis=1)
+                                            for got in parts))
                     else:
                         layers.append(tuple(
                             np.zeros((a.shape[1], 0, a.shape[3]), a.dtype)
-                            for a in (k, v)))
+                            for a in entry))
                 rows[slot] = {"length": length, "layers": layers}
         return {"block_len": self.block_len, "capacity": self.capacity,
                 "rows": rows}
 
     def export_page(self, page: int,
                     width: Optional[int] = None) -> List[Tuple[np.ndarray,
-                                                               np.ndarray]]:
+                                                               ...]]:
         """Fetch ONE page's occupied KV columns to host numpy: per layer
         an owned ([Hkv, width, D] K, same-shape V) pair, sliced on device
         so the transfer is exactly `width` tokens. This is the spill unit
@@ -749,9 +835,8 @@ class SlotPagedKVPool:
                 f"width must be in 1..{self.block_len}, got {w}")
         prow = page // self.n_blocks
         c0 = (page % self.n_blocks) * self.block_len
-        return [(np.asarray(k[prow, :, c0:c0 + w, :]),
-                 np.asarray(v[prow, :, c0:c0 + w, :]))
-                for k, v in self.slabs]
+        return [tuple(np.asarray(a[prow, :, c0:c0 + w, :]) for a in entry)
+                for entry in self.slabs]
 
     def import_page(self, slot: int, block_idx: int,
                     layers: List[Tuple[np.ndarray, np.ndarray]]):
@@ -773,17 +858,24 @@ class SlotPagedKVPool:
                 f"{len(self.slabs)}")
         c0 = block_idx * self.block_len
         new_slabs = []
-        for (k, v), (ke, ve) in zip(self.slabs, layers):
-            if ke.shape[1] > self.block_len:
+        for entry, payload in zip(self.slabs, layers):
+            if payload[0].shape[1] > self.block_len:
                 raise ValueError(
-                    f"page payload holds {ke.shape[1]} tokens, block_len "
-                    f"is {self.block_len}")
-            ku = jnp.asarray(ke, dtype=k.dtype)[None]
-            vu = jnp.asarray(ve, dtype=v.dtype)[None]
-            k = jax.lax.dynamic_update_slice(k, ku, (slot, 0, c0, 0))
-            v = jax.lax.dynamic_update_slice(v, vu, (slot, 0, c0, 0))
-            new_slabs.append((k, v))
+                    f"page payload holds {payload[0].shape[1]} tokens, "
+                    f"block_len is {self.block_len}")
+            new_slabs.append(self._land(entry, payload, slot, c0))
         self.slabs = new_slabs
+
+    @staticmethod
+    def _land(entry, payload, slot: int, c0: int):
+        """`entry`'s slabs with `payload`'s `[Hkv, w, D]` arrays written at
+        column `c0` of row `slot`."""
+        if len(entry) != len(payload):
+            raise ValueError(f"a layer of {len(entry)} slabs given "
+                             f"{len(payload)} arrays")
+        return tuple(jax.lax.dynamic_update_slice(
+            a, jnp.asarray(e, dtype=a.dtype)[None], (slot, 0, c0, 0))
+            for a, e in zip(entry, payload))
 
     def import_rows(self, exported: dict) -> Dict[int, int]:
         """Materialize `export_rows` payload rows into THIS pool: each
@@ -807,14 +899,9 @@ class SlotPagedKVPool:
             dst = self.allocate(length)
             self.set_length(dst, length)
             if length > 0:
-                new_slabs = []
-                for (k, v), (ke, ve) in zip(self.slabs, row["layers"]):
-                    ku = jnp.asarray(ke, dtype=k.dtype)[None]
-                    vu = jnp.asarray(ve, dtype=v.dtype)[None]
-                    k = jax.lax.dynamic_update_slice(k, ku, (dst, 0, 0, 0))
-                    v = jax.lax.dynamic_update_slice(v, vu, (dst, 0, 0, 0))
-                    new_slabs.append((k, v))
-                self.slabs = new_slabs
+                self.slabs = [self._land(entry, payload, dst, 0)
+                              for entry, payload
+                              in zip(self.slabs, row["layers"])]
             mapping[int(src)] = dst
         return mapping
 
@@ -850,14 +937,14 @@ class SlotPagedKVPool:
         # a ring holds no cached page (`register_cached` refuses): a freed
         # row's ring goes whole
         masks = {PAGED: jnp.asarray(keep)}
-        masks[LATENT] = masks[PAGED]
+        masks[LATENT] = masks[INDEXED] = masks[PAGED]
         if self.windowed:
             masks[WINDOW] = jnp.asarray(
                 np.repeat(keep[:, :1], self.ring_len + self.pad_tokens, 1))
-        self.slabs = [(self._scrub(k, masks[kind].astype(k.dtype)),
-                       self._scrub(v, masks[kind].astype(v.dtype)))
-                      if kind != RECURRENT else (k, v)
-                      for (k, v), kind in zip(self.slabs, self.layer_kinds)]
+        self.slabs = [tuple(self._scrub(a, masks[kind].astype(a.dtype))
+                            for a in entry)
+                      if kind != RECURRENT else entry
+                      for entry, kind in zip(self.slabs, self.layer_kinds)]
         self.dirty[:] = False
         self.stats["defrags"] += 1
         return reclaimed

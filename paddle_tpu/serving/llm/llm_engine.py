@@ -661,6 +661,19 @@ class LLMEngine:
                     "window layers, whose keys the pool keeps in a ring; "
                     "a shared prefix's pages have aged out of it")
                 self.enable_prefix_cache = False
+        # a model with learned sparse attention (PR 39): index-key pages
+        # ride with the latent pages through every page operation, so the
+        # prefix cache stays on; the host tier's pages are (k, v) pairs
+        self._sparse = None
+        if self.pool.indexed:
+            if self.config.host_kv_bytes > 0:
+                raise ValueError(
+                    "host_kv_bytes > 0 with a model that keeps index-key "
+                    "pages: the host tier holds (k, v) pairs, and sparse "
+                    "reads from it are later work")
+            kinds = list(model.config.indexer_types)
+            self._sparse = (int(model.config.index_topk),
+                            kinds.count("full"), kinds.count("shared"))
         # the slabs' bytes by kind, where the pool holds a second kind of
         # page beside (or in place of) full-length K/V: a ring, or latent
         # pages (PR 36: position-addressed like K/V, so nothing above is
@@ -2258,8 +2271,18 @@ class LLMEngine:
                 if req is None:
                     return
                 self.metrics.set_queue_depth(self._queue_len_locked())
+                # a prompt that the prefix cache covers writes only behind
+                # the blocks it attaches: a row is fit for it whose cached
+                # pages all sit below them, first of all the row they are
+                # in (a session's next turn goes back where its last one
+                # left its pages, and evicts nothing)
+                keep_below, prefer = 0, None
+                if self.prefix_cache is not None and req.kv_row is None:
+                    keep_below, prefer = self.prefix_cache.probe_row(
+                        self._kv_ns(req.tenant, req.adapter), req.prompt,
+                        max_tokens=len(req.prompt) - 1)
                 try:
-                    slot = self.pool.allocate(req.cost)
+                    slot = self.pool.allocate(req.cost, keep_below, prefer)
                 except SlotsExhaustedError:
                     # every free row is pinned by cached blocks with live
                     # readers (pressure eviction couldn't help); requeue
@@ -2290,8 +2313,8 @@ class LLMEngine:
                     layers = req.kv_row["layers"]
                     for j in range(0, klen, bl):
                         w = min(bl, klen - j)
-                        blk = [(k[:, j:j + w, :], v[:, j:j + w, :])
-                               for k, v in layers]
+                        blk = [tuple(a[:, j:j + w, :] for a in layer)
+                               for layer in layers]
                         self.pool.import_page(slot, j // bl, blk)
                     req.chunk_off = klen
                     self.kv_import_tokens += klen
@@ -2313,6 +2336,12 @@ class LLMEngine:
                     plan = self.prefix_cache.acquire(
                         self._kv_ns(req.tenant, req.adapter), req.prompt,
                         max_tokens=len(req.prompt) - 1)
+                    if len(plan.pages) < keep_below:
+                        # cannot be: nothing between the probe and here
+                        # evicts below `keep_below`
+                        raise RuntimeError(
+                            f"prefix plan of {len(plan.pages)} pages for a "
+                            f"row chosen to keep {keep_below}")
                     if plan.pages:
                         self.pool.attach_blocks(slot, plan.pages)
                         req.attached_pages = list(plan.pages)
@@ -2950,6 +2979,16 @@ class LLMEngine:
             if self.pool.latent:
                 span_args["latent_rows"] = int(after.size)
             kv_tokens = (in_window, int(after.sum()))
+            sparse_keys = None
+            if self._sparse is not None:
+                # per live query position p of a row: the keys it can see
+                # (p + 1) and of them the keys a sparse layer attends to
+                topk, n_full, n_shared = self._sparse
+                cols = np.arange(adv.max(initial=0))
+                seen = (pos[:, None] + cols + 1)[cols < adv[:, None]]
+                sparse_keys = (int(np.minimum(seen, topk).sum()),
+                               int(seen.sum()), n_full, n_shared)
+                span_args["sparse_rows"] = int(after.size)
             with RecordEvent(SPAN_SERVE_DISPATCH, **span_args):
                 t0 = self.clock.now()
                 fn = self._step()
@@ -3012,6 +3051,8 @@ class LLMEngine:
                     if started:
                         self.metrics.on_recurrent_rows_started(started)
                     self.metrics.on_kv_tokens(*kv_tokens)
+                    if sparse_keys is not None:
+                        self.metrics.on_sparse_keys(*sparse_keys)
                     if ahead_of is not None:
                         self.metrics.on_step_overlapped()
                     if decode_slots:

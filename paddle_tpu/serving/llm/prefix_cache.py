@@ -30,6 +30,14 @@ Lifecycle and safety:
 - Eviction is LRU over refcount-0 leaves and tails (a deterministic
   monotonic tick, no wall clock), driven by the pool's `on_pressure`
   hook from inside `allocate()`: evict just enough to unpin one row.
+- A request that attaches its leading blocks needs a row only from the
+  block behind them (`SlotPagedKVPool.allocate(keep_below=)`): pressure
+  then clears *one free row* from that block on (`evict_row`: the pages
+  of that row at or above the block, deepest first, found through
+  `_where`, no walk of the trie): the row the attached pages live in if it
+  can be cleared (asked for first), else the row that costs the fewest
+  pages. A session that starts over from its history so drops its own
+  stale turns and nobody's history.
 - Tenant namespacing is structural: each tenant gets its own root, so
   one tenant's prompts can never attach another tenant's KV.
 
@@ -110,6 +118,9 @@ class PrefixCache:
         self.name = name
         self.block_len = pool.block_len
         self._roots: Dict[str, _Node] = {}
+        # page -> (tenant, the node that names it, the key it hangs under
+        # in that node, or None for the node's tail)
+        self._where: Dict[int, Tuple[str, _Node, Optional[tuple]]] = {}
         self._tick = 0
         self.stats = _tenant_stats()
         self.tenant_stats: Dict[str, dict] = {}
@@ -197,6 +208,22 @@ class PrefixCache:
                 self.pool.refcount.get(tail_page, 0) + 1
         return AttachPlan(pages, attach_len + tail_len, tail_page, tail_len)
 
+    def _match(self, tenant: str, tokens, blocks: Optional[int] = None):
+        """Read-only walk: (the full blocks of `tokens` the tenant's trie
+        holds, at most `blocks`; the node of the last of them, or None)."""
+        node = self._roots.get(tenant)
+        bl = self.block_len
+        limit = len(tokens) // bl
+        if blocks is not None:
+            limit = min(limit, blocks)
+        i, last = 0, None
+        while node is not None and i < limit:
+            node = node.children.get(
+                tuple(int(t) for t in tokens[i * bl:(i + 1) * bl]))
+            if node is not None:
+                i, last = i + 1, node
+        return i, last
+
     def probe(self, tenant: str, tokens) -> int:
         """Read-only lookup: the longest block-aligned cached prefix of
         `tokens` in the tenant's trie, in tokens. Unlike `acquire` it
@@ -204,20 +231,16 @@ class PrefixCache:
         probes every candidate replica per admission, and a probe must
         never distort LRU order or hit-rate accounting, let alone pin
         pages on replicas that lose the election."""
-        node = self._roots.get(tenant)
-        if node is None:
-            return 0
-        bl = self.block_len
-        n = len(tokens)
-        i = 0
-        while i + bl <= n:
-            child = node.children.get(
-                tuple(int(t) for t in tokens[i:i + bl]))
-            if child is None:
-                break
-            node = child
-            i += bl
-        return i
+        return self._match(tenant, tokens)[0] * self.block_len
+
+    def probe_row(self, tenant: str, tokens, max_tokens: int):
+        """Read-only, before a row is chosen: (the full blocks `acquire`
+        will attach under the same cap, the pool row the last of them
+        lives in or None). What `SlotPagedKVPool.allocate` needs to hand
+        out a row that keeps its cached pages below those blocks."""
+        i, last = self._match(tenant, tokens,
+                              max(0, int(max_tokens)) // self.block_len)
+        return i, None if last is None else last.page // self.pool.n_blocks
 
     def release_tail(self, plan: AttachPlan):
         """Drop the transient tail refcount once its KV has been COW'd
@@ -266,6 +289,7 @@ class PrefixCache:
                 self.pool.register_cached(page)
                 child = _Node(page)
                 node.children[key] = child
+                self._where[page] = (tenant, node, key)
                 ts["insertions"] += 1
                 self.stats["insertions"] += 1
                 ts["cached_blocks"] += 1
@@ -282,9 +306,11 @@ class PrefixCache:
                     return
                 if node.tail_page is not None:
                     self.pool.release_cached(node.tail_page)
+                    self._where.pop(node.tail_page, None)
                     ts["cached_blocks"] -= 1
                     self.stats["cached_blocks"] -= 1
                 self.pool.register_cached(page)
+                self._where[page] = (tenant, node, None)
                 node.tail_tokens = rem
                 node.tail_page = page
                 node.tail_tick = self._tick
@@ -324,43 +350,96 @@ class PrefixCache:
                     stack.append((c, node, k, path + k))
         return best
 
-    def evict_for_pressure(self) -> int:
-        """Pool pressure hook: evict LRU refcount-0 entries until the
-        pool has an allocatable row (or nothing evictable remains).
-        Returns pages released. Pages with live readers never qualify,
-        so eviction under slot pressure cannot reclaim a block a stream
-        is still reading — the fault matrix proves this."""
+    def _drop(self, tenant: str, holder: _Node, key, path=None):
+        """Unlink one refcount-0 entry (`holder`'s tail where `key` is
+        None, else its childless child under `key`) and release its
+        page."""
+        ts = self._ts(tenant)
+        if key is None:
+            page = holder.tail_page
+            holder.tail_tokens = None
+            holder.tail_page = None
+            holder.tail_tick = 0
+        else:
+            page = holder.children.pop(key).page
+            if self.host_pool is not None and path is not None:
+                # spill the full block to the host tier before the page is
+                # released (refcount is provably 0 here, so the device copy
+                # is quiescent — the export is the exact KV the trie
+                # indexed)
+                t0 = self.clock() if self.clock is not None else None
+                self.host_pool.put(tenant, path, self.pool.export_page(page))
+                self.spilled_pages += 1
+                if t0 is not None:
+                    self.spill_seconds += self.clock() - t0
+        self.pool.release_cached(page)
+        self._where.pop(page, None)
+        ts["evictions"] += 1
+        self.stats["evictions"] += 1
+        ts["cached_blocks"] -= 1
+        self.stats["cached_blocks"] -= 1
+
+    def _row_victims(self, row: int, keep_below: int):
+        """The cached pages of `row` at block `keep_below` or above,
+        deepest first, if dropping them in that order is possible (each a
+        tail, or a node whose children and tail went before it, none with
+        a reader); else None."""
+        base = row * self.pool.n_blocks
+        blocks = np.flatnonzero(self.pool._cached_at[row, keep_below:])
+        pages = [base + keep_below + int(j) for j in blocks[::-1]]
+        going = set(pages)
+        for page in pages:
+            if self.pool.refcount.get(page, 0) > 0:
+                return None
+            _, holder, key = self._where[page]
+            if key is None:
+                continue
+            node = holder.children[key]
+            if any(c.page not in going for c in node.children.values()) \
+                    or (node.tail_page is not None
+                        and node.tail_page not in going):
+                return None
+        # a node's tail and children sit a block deeper: they went first
+        return pages
+
+    def evict_row(self, row: int, keep_below: int, victims=None) -> int:
+        """Clear free row `row` of cached pages from block `keep_below` on
+        (`victims`: `_row_victims`' answer, where the caller has it).
+        Returns pages released (0, and nothing touched, where a page there
+        has a reader or holds up an entry in another row)."""
+        if victims is None:
+            victims = self._row_victims(row, keep_below) or ()
+        for page in victims:
+            tenant, holder, key = self._where[page]
+            self._drop(tenant, holder, key)
+        return len(victims)
+
+    def evict_for_pressure(self, keep_below: int = 0, rows=None) -> int:
+        """Pool pressure hook. For a fresh sequence (`keep_below` 0, no
+        `rows`): evict LRU refcount-0 entries until the pool has an
+        allocatable row (or nothing evictable remains). For one that
+        attaches its leading `keep_below` blocks: clear one of the free
+        rows `rows` from that block on: the one that costs the fewest
+        pages (the caller asks for its preferred row alone first). Returns
+        pages released. Pages with live readers never qualify, so eviction
+        under slot pressure cannot reclaim a block a stream is still
+        reading — the fault matrix proves this."""
         with RecordEvent(SPAN_SERVE_EVICT):
+            if rows is not None:
+                plans = [(len(v), i, r, v) for i, r in enumerate(rows)
+                         for v in [self._row_victims(r, keep_below)]
+                         if v is not None]
+                if not plans:
+                    return 0
+                _, _, row, victims = min(plans, key=lambda p: p[:2])
+                return self.evict_row(row, keep_below, victims)
             released = 0
             while not self.pool.has_allocatable_row():
                 victim = self._lru_victim()
                 if victim is None:
                     break
                 _, kind, tenant, holder, key, path = victim
-                ts = self._ts(tenant)
-                if kind == "tail":
-                    self.pool.release_cached(holder.tail_page)
-                    holder.tail_tokens = None
-                    holder.tail_page = None
-                    holder.tail_tick = 0
-                else:
-                    child = holder.children.pop(key)
-                    if self.host_pool is not None:
-                        # spill the full block to the host tier before the
-                        # page is released (refcount is provably 0 here, so
-                        # the device copy is quiescent — the export is the
-                        # exact KV the trie indexed)
-                        t0 = self.clock() if self.clock is not None else None
-                        self.host_pool.put(
-                            tenant, path, self.pool.export_page(child.page))
-                        self.spilled_pages += 1
-                        if t0 is not None:
-                            self.spill_seconds += self.clock() - t0
-                    self.pool.release_cached(child.page)
-                ts["evictions"] += 1
-                self.stats["evictions"] += 1
-                ts["cached_blocks"] -= 1
-                self.stats["cached_blocks"] -= 1
+                self._drop(tenant, holder, key, path)
                 released += 1
         return released
 
@@ -403,6 +482,8 @@ class PrefixCache:
             self.stats["cached_blocks"] -= ts["cached_blocks"]
             ts["cached_blocks"] = 0
             del self._roots[tenant]
+            self._where = {p: w for p, w in self._where.items()
+                           if w[0] != tenant}
         if only is None:
             self._roots.clear()
             self.stats["cached_blocks"] = 0

@@ -258,8 +258,8 @@ def _quantile(sorted_vals, q: float) -> Optional[float]:
 RECURRENT_STATE_BYTES = 0
 # Likewise the K/V slabs of the newest LLM engine whose pool keeps window
 # layers in a ring or latent pages, by kind: {"full": bytes, "window":
-# bytes[, "latent": bytes]} (`LLMMetrics.set_kv_pool_bytes`; empty: no
-# such engine yet).
+# bytes[, "latent": bytes[, "index": bytes]]}
+# (`LLMMetrics.set_kv_pool_bytes`; empty: no such engine yet).
 KV_POOL_BYTES: dict = {}
 
 
@@ -294,6 +294,10 @@ class LLMMetrics(ServingMetrics):
                               "recurrent_rows_started": 0,
                               "window_kv_tokens": 0,
                               "full_kv_tokens": 0,
+                              "sparse_keys_selected": 0,
+                              "sparse_keys_resident": 0,
+                              "index_layers_full": 0,
+                              "index_layers_shared": 0,
                               "tokens_out": 0, "shed": 0, "quarantined": 0,
                               "brownout_entries": 0,
                               "prefix_hits": 0, "prefix_misses": 0,
@@ -577,6 +581,20 @@ class LLMMetrics(ServingMetrics):
             self.counters["window_kv_tokens"] += int(window)
             self.counters["full_kv_tokens"] += int(full)
 
+    def on_sparse_keys(self, selected: int, resident: int, full: int,
+                       shared: int):
+        """One committed unified step of a model with learned sparse
+        attention: summed over the step's live query positions, the keys
+        one sparse layer attends to (`min(position + 1, index_topk)`) and
+        the keys it could see (`position + 1`: what the indexer scored);
+        and the layers that made a selection in the step (`full`) and that
+        reused one (`shared`)."""
+        with self._lock:
+            self.counters["sparse_keys_selected"] += int(selected)
+            self.counters["sparse_keys_resident"] += int(resident)
+            self.counters["index_layers_full"] += int(full)
+            self.counters["index_layers_shared"] += int(shared)
+
     def on_recurrent_rows_started(self, n: int):
         """`n` rows of a committed step began at position 0: the step
         started them from a zero recurrent state."""
@@ -814,6 +832,11 @@ class LLMMetrics(ServingMetrics):
             b.sample(f"{px}_window_kv_tokens_total", s["window_kv_tokens"])
         b.family(f"{px}_full_kv_tokens_total", "counter")
         b.sample(f"{px}_full_kv_tokens_total", s["full_kv_tokens"])
+        if s["index_layers_full"]:
+            for name in ("sparse_keys_selected", "sparse_keys_resident",
+                         "index_layers_full", "index_layers_shared"):
+                b.family(f"{px}_{name}_total", "counter")
+                b.sample(f"{px}_{name}_total", s[name])
         if self.moe_source is not None:
             b.family(f"{px}_moe_assignments_total", "counter")
             b.sample(f"{px}_moe_assignments_total", s["moe_assignments"])

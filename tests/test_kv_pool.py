@@ -8,10 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.models.generation import LatentKV, RecurrentState, WindowKV
-from paddle_tpu.serving.llm.kv_pool import (LATENT, PAGED, WINDOW,
+from paddle_tpu.models.generation import (IndexedLatentKV, LatentKV,
+                                          RecurrentState, WindowKV)
+from paddle_tpu.serving.llm.kv_pool import (INDEXED, LATENT, PAGED, WINDOW,
                                             RecurrentStateError,
                                             SlotPagedKVPool,
+                                            SlotsExhaustedError,
                                             WindowRingError)
 from paddle_tpu.serving.llm.prefix_cache import PrefixCache
 
@@ -328,3 +330,221 @@ def test_latent_ledger_balances_through_a_rows_life():
     pool.free(b)
     assert pool.check_balance() and pool.defrag() == 16
     assert pool.check_balance()
+
+
+# ---- a fifth kind: index-key pages beside latent pages (PR 39) ----
+
+INDEX = 3
+
+
+def _indexed_pool(kinds=(INDEXED, LATENT, INDEXED), block_len=8, n_blocks=8,
+                  pad=16, slots=3):
+    """An `init_cache` like a sparse-attention `DeepseekForCausalLM`'s: a
+    layer with an indexer keeps a third slab, one index key a token."""
+    def init_cache(batch, max_len, dtype=None):
+        dt = dtype or jnp.float32
+
+        def slab(width):
+            return jnp.zeros((batch, 1, max_len, width), dt)
+        return [IndexedLatentKV(slab(RANK), slab(ROPE), slab(INDEX))
+                if kind == INDEXED else LatentKV(slab(RANK), slab(ROPE))
+                for kind in kinds]
+    return SlotPagedKVPool(init_cache, slots, block_len, n_blocks,
+                           pad_tokens=pad)
+
+
+def test_indexed_kind_and_bytes_by_kind():
+    pool = _indexed_pool()
+    assert pool.layer_kinds == [INDEXED, LATENT, INDEXED]
+    assert pool.indexed and pool.latent and not pool.windowed
+    assert [len(e) for e in pool.slabs] == [3, 2, 3]
+    assert pool.slabs[0][2].shape == (3, 1, 80, INDEX)
+    assert pool.kv_bytes() == {
+        "full": 0, "window": 0,
+        "latent": 3 * 3 * 80 * (RANK + ROPE) * 4,
+        "index": 2 * 3 * 80 * INDEX * 4}
+    assert "index" not in _latent_pool().kv_bytes()
+    assert not _latent_pool().indexed
+
+
+@pytest.mark.parametrize("operation", [
+    "cow_copy", "export_rows", "export_page", "defrag", "eviction"])
+def test_index_pages_go_where_their_latent_pages_go(operation):
+    """Page p of the index slab is page p of `c` and of `r`: whatever
+    copies, exports, scrubs or evicts a page does it to all three slabs of
+    a layer that has three."""
+    pool = _indexed_pool()
+    _fill(pool)
+    a = pool.allocate(40)
+    pool.set_length(a, 40)
+    before = [tuple(np.asarray(x) for x in e) for e in pool.slabs]
+    if operation == "cow_copy":
+        page = a * pool.n_blocks + 2
+        pool.register_cached(page)
+        b = pool.allocate(40)
+        pool.cow_copy(page, b)
+        for entry, old in zip(pool.slabs, before):
+            for x, x0 in zip(entry, old):
+                assert np.array_equal(np.asarray(x[b, :, 16:24]),
+                                      x0[a, :, 16:24])
+                assert np.array_equal(np.asarray(x[b, :, :16]), x0[b, :, :16])
+    elif operation == "export_rows":
+        out = pool.export_rows([a])
+        layers = out["rows"][a]["layers"]
+        assert [len(e) for e in layers] == [3, 2, 3]
+        assert layers[0][2].shape == (1, 40, INDEX)
+        assert np.array_equal(layers[2][2], before[2][2][a, :, :40])
+        other = _indexed_pool()
+        dst = other.import_rows(out)[a]
+        for entry, old in zip(other.slabs, before):
+            for x, x0 in zip(entry, old):
+                assert np.array_equal(np.asarray(x[dst, :, :40]),
+                                      x0[a, :, :40])
+        # a pool whose layers keep other slabs refuses the payload
+        with pytest.raises(ValueError, match="a layer of 2 slabs given 3"):
+            _latent_pool(layers=3).import_rows(out)
+    elif operation == "export_page":
+        layers = pool.export_page(a * pool.n_blocks + 1, width=5)
+        assert [tuple(x.shape for x in e) for e in layers] == [
+            ((1, 5, RANK), (1, 5, ROPE), (1, 5, INDEX)),
+            ((1, 5, RANK), (1, 5, ROPE)),
+            ((1, 5, RANK), (1, 5, ROPE), (1, 5, INDEX))]
+        b = pool.allocate(16)
+        pool.import_page(b, 1, layers)
+        assert np.array_equal(np.asarray(pool.slabs[2][2][b, :, 8:13]),
+                              before[2][2][a, :, 8:13])
+    elif operation == "defrag":
+        keep = a * pool.n_blocks + 1
+        pool.register_cached(keep)
+        pool.free(a)
+        assert pool.defrag() == pool.n_blocks - 1
+        for entry, old in zip(pool.slabs, before):
+            for x, x0 in zip(entry, old):
+                # the cached page stays, the rest of the freed row is zeroed
+                assert np.array_equal(np.asarray(x[a, :, 8:16]),
+                                      x0[a, :, 8:16])
+                assert not np.asarray(x[a, :, :8]).any()
+                assert not np.asarray(x[a, :, 16:]).any()
+                assert np.array_equal(np.asarray(x[1]), x0[1])
+    else:
+        cache = PrefixCache(pool)
+        tokens = np.arange(1, 41, dtype=np.int32)
+        cache.insert("t", tokens, a, [])
+        pool.free(a)
+        b, c = pool.allocate(8), pool.allocate(8)
+        pool.free(b)
+        pool.free(c)
+        # a fresh sequence for the one pinned row left: its pages go, LRU
+        for slot in (pool.allocate(8), pool.allocate(8)):
+            assert slot != a
+        assert pool.allocate(8) == a and cache.stats["evictions"] == 5
+        assert not pool.cached and not cache._where
+        # nothing was copied or scrubbed on the way: an evicted page's
+        # index keys lie where they lay until the row is written again
+        assert np.array_equal(np.asarray(pool.slabs[0][2]), before[0][2])
+    pool.check_balance()
+
+
+# ---- which row a request gets (PR 39) ----
+
+def _session(pool, cache, tokens, slot):
+    """A finished request of `tokens` in `slot`: its pages cached."""
+    pool.set_length(slot, len(tokens))
+    cache.insert("t", tokens, slot, pool._attached.get(slot, []))
+    pool.free(slot)
+
+
+def test_a_turn_goes_back_into_the_row_its_pages_are_in():
+    pool = _indexed_pool(slots=2, n_blocks=16)
+    cache = PrefixCache(pool)
+    rng = np.random.default_rng(3)
+    history = [rng.integers(1, 99, (n,)).astype(np.int32) for n in (56, 40)]
+    for tokens in history:
+        _session(pool, cache, tokens, pool.allocate(48))
+    assert not pool._fit_rows().any()
+    # a fresh sequence would have to evict a whole session; a turn of
+    # session 1 attaches its 5 blocks and fits its own row as it is
+    turn = np.concatenate([history[1], rng.integers(1, 99, (20,))])
+    assert cache.probe_row("t", turn, len(turn) - 1) == (5, 1)
+    assert pool._fit_rows(keep_below=5).tolist() == [False, True]
+    slot = pool.allocate(70, keep_below=5, prefer=1)
+    assert slot == 1 and cache.stats["evictions"] == 0
+    plan = cache.acquire("t", turn, len(turn) - 1)
+    assert plan.pages == [1 * 16 + j for j in range(5)]
+    pool.attach_blocks(slot, plan.pages)
+    cache.release(plan)
+    _session(pool, cache, turn, slot)
+    assert pool._cached_at[1].sum() == 8         # 7 blocks and a tail
+    # the session starts over from its history: no row is fit (row 0 holds
+    # the other session's blocks 5 and 6), and its own stale turn goes (the
+    # blocks behind the history, deepest first: three pages where row 0
+    # would cost two), nobody's history
+    again = np.concatenate([history[1], rng.integers(1, 99, (9,))])
+    assert cache.probe_row("t", again, len(again) - 1) == (5, 1)
+    slot = pool.allocate(60, keep_below=5, prefer=1)
+    assert slot == 1 and cache.stats["evictions"] == 3
+    assert pool._cached_at[1].tolist() == [True] * 5 + [False] * 11
+    assert pool._cached_at[0].sum() == 7
+    assert cache.probe("t", history[0]) == 56
+    pool.free(slot)
+    pool.check_balance()
+
+
+def test_pressure_clears_the_cheapest_free_row_and_never_a_reader():
+    pool = _indexed_pool(slots=3, n_blocks=16)
+    cache = PrefixCache(pool)
+    rng = np.random.default_rng(4)
+    long, short, base = (rng.integers(1, 99, (n,)).astype(np.int32)
+                         for n in (96, 24, 32))
+    for tokens in (long, short, base):
+        _session(pool, cache, tokens, pool.allocate(100))
+    # a request that attaches `base`'s 4 blocks while its row is taken by
+    # a reader: rows 0 and 1 are free, row 1 costs 3 pages less 0 below 4
+    reader = pool.allocate(40, keep_below=4, prefer=2)
+    assert reader == 2
+    plan = cache.acquire("t", base, 31)
+    pool.attach_blocks(reader, plan.pages)
+    cache.release(plan)
+    slot = pool.allocate(60, keep_below=4, prefer=2)
+    assert slot == 1 and cache.stats["evictions"] == 0   # 3 blocks: below 4
+    pool.free(slot)
+    slot = pool.allocate(60, keep_below=2, prefer=2)
+    assert slot == 1 and cache.stats["evictions"] == 1   # its third block
+    assert pool._cached_at[0].sum() == 12
+    # pages with a reader never go: the reader's row is not free, and a
+    # row whose page another row's entry hangs under is left alone
+    pool.free(slot)
+    with pytest.raises(SlotsExhaustedError):
+        for _ in range(3):
+            pool.allocate(8, keep_below=0, prefer=2)
+    assert all(pool.refcount.get(2 * 16 + j, 0) == 1 for j in range(3))
+    assert pool._cached_at[2].sum() == 4
+
+
+@pytest.mark.parametrize("keep_below", [0, 3])
+def test_the_fit_rows_follow_the_pinned_pages(keep_below):
+    """`_fit_rows` reads two ledgers of the pinned pages (by row and block;
+    counted by row, for a fresh sequence): both follow `cached` through
+    pins and evictions, and `check_balance` tells a ledger that does not."""
+    pool = _indexed_pool(slots=3, n_blocks=8)
+    taken = pool.allocate(8)
+    for page in (1 * 8 + 1, 1 * 8 + 5, 2 * 8 + 2, taken * 8):
+        pool.register_cached(page)
+
+    def by_hand():
+        return [not pool.active[r] and not any(
+            r * 8 + j in pool.cached for j in range(keep_below, 8))
+            for r in range(3)]
+    assert pool._fit_rows(keep_below).tolist() == by_hand() \
+        == [False, False, keep_below == 3]
+    pool.release_cached(1 * 8 + 5)
+    assert pool._fit_rows(keep_below).tolist() == by_hand() \
+        == [False, keep_below == 3, keep_below == 3]
+    pool.release_cached(1 * 8 + 1)
+    assert pool._fit_rows(keep_below).tolist() == by_hand() \
+        == [False, True, keep_below == 3]
+    assert pool.has_allocatable_row(keep_below)
+    pool.check_balance()
+    pool._cached_in[2] = 0
+    with pytest.raises(AssertionError, match="ledgers disagree"):
+        pool.check_balance()

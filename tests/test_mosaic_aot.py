@@ -34,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 42 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 49 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 13         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -60,8 +60,14 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     Mosaic body, that of an engine with window and full layers two, and
     the compiled step still a custom call a layer."""
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
-    assert len(steps) == 4
-    full, mixed, hybrid, latent = steps
+    assert len(steps) == 5
+    full, mixed, hybrid, latent, indexed = steps
+    # an indexer's layers (PR 39): the three-slab write and the two-slab
+    # one, the gathered and the masked walk, one scoring and one top-k for
+    # both "full" layers, three sparse layers' grouped matmuls
+    assert "2 indexed + 2 shared latent layers: 15 Mosaic bodies " in indexed
+    assert "{'kv_write': 4, 'index_score': 2, 'paged_sparse': 8, " \
+        "'index_topk': 2, 'moe_gmm': 9} in the compiled one" in indexed
     # three MLA layers share one `paged_latent` body and one `kv_write`
     # body; the grouped matmuls of the two sparse layers behind the dense
     # one are a body a call site
@@ -125,6 +131,33 @@ def test_the_latent_walk_compiles_at_the_latent_cells_shapes(tool):
                    f"'rows': {rows}" in t for t in tilings), (grid, tilings)
 
 
+def test_sparse_attention_compiles_at_the_sessions_cells_shapes(tool):
+    """`index_score`, `index_topk` and `paged_sparse` for the v5e (PR 39)
+    at 16 slots of 2,304 pages: an indexer of 32 x 128 over index-key
+    pages, the exact top-2,048 of sixteen score vectors of 36,864 as a mask
+    (a row's block in VMEM: the call raises Mosaic's scoped limit), a
+    chunk row's walk under its columns' masks beside a decode row's walk
+    over its gathered keys (two calls), and a one-token step's gathered
+    walk alone."""
+    lines = tool.splitlines()
+    assert any(ln.startswith("[OK] index_score bf16 q=[16, 32, 16, 128] "
+                             "slab=[16, 1, 36880, 128]: {'index_score': 1}")
+               for ln in lines)
+    assert any(ln.startswith("[OK] index_topk k=2048 scores=[16, 16, 36864]"
+                             ": {'index_topk': 1}") for ln in lines)
+    sparse = [ln for ln in lines if ln.startswith("[OK] paged sparse bf16")]
+    assert len(sparse) == 2
+    assert "chunk rows" in sparse[0] and "{'paged_sparse': 2}" in sparse[0]
+    assert "Tq=1" in sparse[1] and "{'paged_sparse': 1}" in sparse[1]
+    tilings = [ln for ln in lines if ln.startswith("tiling paged_sparse")]
+    # the masked walk over the slot's 288 groups, the heads in two tiles;
+    # the gathered walk over 16 groups, one tile
+    assert any("'grid': (16, 2), 'groups': 288, 'heads': 1, 'pages': 8, "
+               "'rows': 512" in t for t in tilings), tilings
+    assert any("'grid': (16, 1), 'groups': 16, 'heads': 1, 'pages': 8, "
+               "'rows': 64" in t for t in tilings), tilings
+
+
 def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
     """`kv_write` for the v5e (PR 37): K's and V's stripes of every row in
     one Mosaic call a layer, both slabs aliased to the byte, no loop or
@@ -137,7 +170,10 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
     row is a read-modify-write of the 32 aligned columns that hold it."""
     cases = [ln for ln in tool.splitlines()
              if ln.startswith("[OK] kv_write bf16")]
-    assert len(cases) == 8
+    assert len(cases) == 10
+    # a sparse layer's three slabs (latent, rotary key, index key) in the
+    # one call, at the sessions cell's 16 rows of 36,880 columns
+    assert any("slab=[16, 1, 36880] x 512 | 128 | 128" in ln for ln in cases)
     for ln in cases:
         m = re.search(r": \{'kv_write': 1\}, (\d+) bytes aliased of (\d+), "
                       r"0 loops over a slab, 0 copies of one", ln)

@@ -715,6 +715,142 @@ def test_latent_tile_folds_heads_inside_the_budget():
         assert other not in PA.LATENT_KERNEL
 
 
+# ---- a selection over the latent cache (`paged_sparse`, `index_score`,
+# ---- `index_topk`; PR 39) ----
+
+def _sparse_case(rng, Tq, bl, layout, H=4, R=16, Dr=8, Hi=2, Di=128):
+    """Rows that decode (one live column), chunk rows below and above the
+    selection's size, an empty row; index keys beside the latent pages."""
+    nb, pad = 160 // bl + 1, 8
+    cap = nb * bl
+    adv = np.array([1, Tq, Tq, 1, Tq, 0], np.int32)
+    lens = np.array([bl + 3, Tq, 128 + bl + 3, cap, cap, 0], np.int32)
+    lens = np.maximum(lens, adv).astype(np.int32)
+    B = len(lens)
+    q_pos = (lens - adv).astype(np.int32)
+    c = _rand(rng, (B, 1, cap + pad, R)).at[:, :, cap:].set(jnp.nan)
+    r = _rand(rng, (B, 1, cap + pad, Dr)).at[:, :, cap:].set(jnp.nan)
+    ki = _rand(rng, (B, 1, cap + pad, Di))
+    q, qr = _rand(rng, (B, H, Tq, R)), _rand(rng, (B, H, Tq, Dr))
+    qi, w = _rand(rng, (B, Hi, Tq, Di)), _rand(rng, (B, Hi, Tq))
+    table = _identity_table(B, nb) if layout == "identity" \
+        else rng.permutation(B * nb).astype(np.int32).reshape(B, nb)
+    return dict(c=c, r=r, ki=ki, q=q, qr=qr, qi=qi, w=w, table=table,
+                lens=lens, q_pos=q_pos, adv=adv, nb=nb, cap=cap)
+
+
+def _logical(cache, table, bl, nb):
+    rows, cols = table // nb, (table % nb * bl)[..., None] + np.arange(bl)
+    return np.asarray(cache, np.float64)[rows[..., None], 0, cols].reshape(
+        table.shape[0], table.shape[1] * bl, -1)
+
+
+def _ref_selection(case, bl, K):
+    """Dense oracle: the index scores of every live query and, by a stable
+    sort, its K best keys (ties to the lower position)."""
+    kl = _logical(case["ki"], case["table"], bl, case["nb"])
+    s = np.einsum("bhtd,bsd->bhts", np.asarray(case["qi"], np.float64), kl)
+    scores = np.einsum("bhts,bht->bts", np.maximum(s, 0),
+                       np.asarray(case["w"], np.float64))
+    chosen = {}
+    for b, (p, a) in enumerate(zip(case["q_pos"], case["adv"])):
+        for t in range(a):
+            order = np.argsort(-scores[b, t, :p + t + 1], kind="stable")
+            chosen[b, t] = np.sort(order[:K])
+    return scores, chosen
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+@pytest.mark.parametrize("Tq,bl,layout", [
+    (16, 8, "fragmented"), (16, 16, "identity"), (8, 16, "fragmented")])
+def test_index_scores_and_exact_topk_match_the_dense_oracle(impl, Tq, bl,
+                                                            layout):
+    from paddle_tpu.ops import index_select as IX, pallas_mode
+    case = _sparse_case(np.random.RandomState(11), Tq, bl, layout)
+    K = 24
+    want, chosen = _ref_selection(case, bl, K)
+    pallas_mode.KERNEL_TRACES.clear()
+    scores = IX.index_scores(case["qi"], case["w"], case["ki"],
+                             case["table"], case["lens"], case["q_pos"],
+                             block_len=bl, pages_per_row=case["nb"],
+                             impl=impl)
+    mask = np.asarray(IX.topk_mask(scores, K, impl=impl))
+    path = "scan" if impl == "reference" else "interpret"
+    assert dict(pallas_mode.KERNEL_TRACES) == {
+        (IX.SCORE_KERNEL, path): 1, (IX.TOPK_KERNEL, path): 1}
+    scores = np.asarray(scores)
+    assert scores.shape == (6, Tq, case["cap"])
+    for (b, t), keys in chosen.items():
+        p = case["q_pos"][b] + t
+        assert np.abs(scores[b, t, :p + 1] - want[b, t, :p + 1]).max() < 1e-3
+        assert np.isneginf(scores[b, t, p + 1:]).all()   # causal, and no key
+        assert np.flatnonzero(mask[b, t]).tolist() == keys.tolist()
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_topk_ties_go_to_the_lower_position_and_short_rows_keep_all(impl):
+    from paddle_tpu.ops import index_select as IX
+    scores = np.full((2, 8, 256), -np.inf, np.float32)
+    scores[0, :, :200] = 0.0                  # two hundred keys that tie
+    scores[0, :, 5], scores[0, :, 150] = 1.0, -1.0
+    scores[1, :, :3] = [0.5, -2.0, 0.5]       # fewer keys than k
+    mask = np.asarray(IX.topk_mask(jnp.asarray(scores), 4, impl=impl))
+    assert np.flatnonzero(mask[0, 0]).tolist() == [0, 1, 2, 5]
+    assert np.flatnonzero(mask[1, 7]).tolist() == [0, 1, 2]
+    with pytest.raises(ValueError, match="16 bits"):
+        IX.topk_mask(jnp.zeros((1, 1, 1 << 16)), 4, impl=impl)
+
+
+@pytest.mark.parametrize("impl", ["scan", "pallas"])
+@pytest.mark.parametrize("Tq,bl,layout", [
+    (16, 8, "fragmented"), (16, 16, "identity"), (1, 16, "fragmented")])
+def test_sparse_latent_attention_matches_the_dense_oracle(impl, Tq, bl,
+                                                          layout):
+    """A decode row's gathered walk and a chunk row's walk under its
+    columns' masks, in one call, against the dense softmax over each
+    query's selected keys alone."""
+    from paddle_tpu.ops import index_select as IX, pallas_mode
+    from paddle_tpu.ops.paged_attention import (SPARSE_KERNEL,
+                                                sparse_latent_attention)
+    case = _sparse_case(np.random.RandomState(13), Tq, bl, layout)
+    if Tq == 1:
+        case["adv"] = np.minimum(case["adv"], 1)
+    K = 32
+    _, chosen = _ref_selection(case, bl, K)
+    sel = IX.select(case["qi"], case["w"], case["ki"], case["q_pos"], K,
+                    paged=(case["table"], case["lens"], bl, case["nb"]))
+    pallas_mode.KERNEL_TRACES.clear()
+    out = np.asarray(sparse_latent_attention(
+        case["q"], case["c"], case["r"], case["table"], case["lens"],
+        case["q_pos"], sel=sel, block_len=bl, pages_per_row=case["nb"],
+        scale=0.2, q_rope=case["qr"], impl=impl))
+    path = "scan" if impl == "scan" else "interpret"
+    assert dict(pallas_mode.KERNEL_TRACES) == {
+        (SPARSE_KERNEL, path): 1 if Tq == 1 else 2}
+    cl = _logical(case["c"], case["table"], bl, case["nb"])
+    rl = _logical(case["r"], case["table"], bl, case["nb"])
+    q, qr = (np.asarray(case[k], np.float64) for k in ("q", "qr"))
+    assert np.isfinite(out).all()
+    for (b, t), keys in chosen.items():
+        s = 0.2 * (q[b, :, t] @ cl[b, keys].T + qr[b, :, t] @ rl[b, keys].T)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ cl[b, keys]
+        assert np.abs(out[b, :, t] - want).max() <= 2e-5, (b, t)
+
+
+def test_sparse_walk_refuses_what_it_is_not():
+    from paddle_tpu.ops import paged_attention as PA
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="a selection is over a latent"):
+        PA.ragged_paged_attention(
+            z((1, 2, 8, 8)), z((1, 2, 16, 8)), z((1, 2, 16, 8)),
+            z((1, 2), jnp.int32), z((1,), jnp.int32), z((1,), jnp.int32),
+            block_len=8, sel=z((1, 8, 16)))
+    assert PA._kernel_name(None, True, True) == "paged_sparse"
+    for other in ("paged_attention", "paged_window", "paged_latent"):
+        assert other not in PA.SPARSE_KERNEL and PA.SPARSE_KERNEL not in other
+
+
 # ---- JitLRUCache: the one shared executable-cache policy ----
 
 def test_jit_lru_caches_hits_and_evicts_oldest():

@@ -183,11 +183,12 @@ def slab_loops_and_copies(hlo: str, slabs) -> tuple:
 
 
 def kv_write_case(name, slab, widths, T, ring, sharding) -> bool:
-    """Compile `kv_write` alone, donated its slabs `[B, Hkv, L, widths[i]]`:
-    one Mosaic call for both caches, every byte aliased, and no loop or
-    copy of a slab beside it."""
+    """Compile `kv_write` alone, donated its slabs `[B, Hkv, L, widths[i]]`
+    (two, or a sparse layer's three): one Mosaic call for all the caches,
+    every byte aliased, and no loop or copy of a slab beside it."""
     from paddle_tpu.obs.compile_observatory import pallas_kernel_census
-    from paddle_tpu.ops.kv_write import kv_write, kv_write_supported
+    from paddle_tpu.ops.kv_write import (kv_write_many,
+                                         kv_write_many_supported)
     B, Hkv, L = slab
 
     def spec(shape, dtype=jnp.bfloat16):
@@ -197,12 +198,12 @@ def kv_write_case(name, slab, widths, T, ring, sharding) -> bool:
     name = (f"kv_write bf16 {name} slab={[B, Hkv, L]} x "
             f"{' | '.join(map(str, widths))} T={T} ring={ring}")
     t0 = time.perf_counter()
+    n = len(widths)
     try:
-        assert kv_write_supported(*caches, *stripes, ring)
+        assert kv_write_many_supported(caches, stripes, ring)
         compiled = jax.jit(
-            lambda kc, vc, kn, vn, pos: kv_write(kc, vc, kn, vn, pos,
-                                                 ring=ring),
-            donate_argnums=(0, 1)).lower(
+            lambda *a: kv_write_many(a[:n], a[n:2 * n], a[2 * n], ring=ring),
+            donate_argnums=tuple(range(n))).lower(
                 *caches, *stripes, spec((B,), jnp.int32)).compile()
     except Exception as e:  # the tool's job is to report every case
         print(f"[FAIL] {name}: {type(e).__name__}: {str(e)[:1500]}",
@@ -331,6 +332,47 @@ def main() -> int:
             spec((32, 1, 8304, 128), jnp.bfloat16),
             spec((32, 518), jnp.int32), spec((32,), jnp.int32),
             spec((32,), jnp.int32), want={"paged_latent": 1}))
+    # learned sparse attention at the sessions cell's shapes: 16 slots of
+    # 2,304 pages, an indexer of 32 x 128 over index-key pages
+    # (`index_score`), the exact top-2,048 of a row's 16 score vectors as a
+    # mask (`index_topk`), and the latent attention over the selection
+    # (`paged_sparse`: a decode row's walk over its gathered keys and a
+    # chunk row's walk under its columns' masks: two calls, two bodies)
+    from paddle_tpu.ops import index_select as IX
+    from paddle_tpu.ops.paged_attention import sparse_latent_attention
+    sB, sT, sP = 16, 16, 2304
+    sL = sP * 16
+    rows3 = (spec((sB, sP), jnp.int32), spec((sB,), jnp.int32),
+             spec((sB,), jnp.int32))
+    results.append(compile_case(
+        f"index_score bf16 q=[{sB}, 32, {sT}, 128] slab=[{sB}, 1, {sL + 16},"
+        " 128]",
+        lambda q, w, k, t, sl, qp: IX.index_scores(
+            q, w, k, t, sl, qp, block_len=16, pages_per_row=sP,
+            impl="pallas"),
+        spec((sB, 32, sT, 128), jnp.bfloat16),
+        spec((sB, 32, sT), jnp.float32),
+        spec((sB, 1, sL + 16, 128), jnp.bfloat16), *rows3,
+        want={"index_score": 1}))
+    results.append(compile_case(
+        f"index_topk k=2048 scores=[{sB}, {sT}, {sL}]",
+        lambda scores: IX.topk_mask(scores, 2048, impl="pallas"),
+        spec((sB, sT, sL), jnp.float32), want={"index_topk": 1}))
+    for label, Tq, want in (("chunk rows", sT, 2), ("Tq=1", 1, 1)):
+        def sparse(q, qr, c, r, t, sl, qp, mask, idx, count):
+            return sparse_latent_attention(
+                q, c, r, t, sl, qp, sel=IX.Selection(mask, idx, count),
+                block_len=16, pages_per_row=sP, scale=0.0625, q_rope=qr,
+                impl="pallas")
+        results.append(compile_case(
+            f"paged sparse bf16 {label} q=[{sB}, 64, {Tq}, 512 | 128] "
+            f"slab=[{sB}, 1, {sL + 16}, 512 | 128] k=2048", sparse,
+            spec((sB, 64, Tq, 512), jnp.bfloat16),
+            spec((sB, 64, Tq, 128), jnp.bfloat16),
+            spec((sB, 1, sL + 16, 512), jnp.bfloat16),
+            spec((sB, 1, sL + 16, 128), jnp.bfloat16), *rows3,
+            spec((sB, Tq, sL), jnp.float32), spec((sB, 2048), jnp.int32),
+            spec((sB,), jnp.int32), want={"paged_sparse": want}))
     # the K/V write (`kv_write`) at every serve cell's slabs: Mistral's
     # decode and prefill cells, OLMoE's 16 heads (4 rows a grid step),
     # the window/full cell's full-length and ring slabs, the latent cell's
@@ -342,6 +384,9 @@ def main() -> int:
             ("window/full cell, full layers", (32, 4, 8304), (D, D), None),
             ("window/full cell, ring", (32, 4, 1056), (D, D), 1040),
             ("latent cell", (32, 1, 8304), (512, D), None),
+            ("sessions cell, shared layers", (16, 1, 36880), (512, D), None),
+            ("sessions cell, full layers", (16, 1, 36880), (512, D, D),
+             None),
             ("granite decode cell", (128, 8, 240), (D, D), None),
             ("a batch of one", (1, 8, 2064), (D, D), None)):
         results.append(kv_write_case(label, slab, widths, 16, ring, one))
@@ -435,6 +480,25 @@ def main() -> int:
     model.eval()
     results.append(serve_step_case("serve step, 3 latent layers", model,
                                    dev1[0], 8))
+    # an indexer's selection shared by the layers behind it: two "full"
+    # layers (three slabs a token) and two "shared" ones in the donated
+    # pool: `kv_write` in two bodies (two slabs, three), `paged_sparse` in
+    # two (gathered, masked), one `index_score`, one `index_topk`, and
+    # three sparse layers' grouped matmuls, 3 each
+    model = DeepseekForCausalLM(DeepseekConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=256,
+        moe_intermediate_size=128, num_hidden_layers=4,
+        num_attention_heads=2, q_lora_rank=128, kv_lora_rank=128,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=8, num_experts_per_tok=2, n_group=1, topk_group=1,
+        select_bias=True, first_k_dense_replace=1, rope_interleave=True,
+        indexer_types=["full", "shared", "full", "shared"], index_n_heads=4,
+        index_head_dim=128, index_topk=64, max_position_embeddings=1024,
+        dtype="bfloat16"))
+    model.eval()
+    results.append(serve_step_case(
+        "serve step, 2 indexed + 2 shared latent layers", model, dev1[0],
+        15))
     from paddle_tpu.ops import pallas_mode
     for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
         print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
