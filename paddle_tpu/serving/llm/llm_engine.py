@@ -2218,7 +2218,9 @@ class LLMEngine:
                 self.prefix_cache.stats["cached_blocks"],
                 self.prefix_cache.stats["evictions"],
                 {t: s["cached_blocks"]
-                 for t, s in self.prefix_cache.tenant_stats.items()})
+                 for t, s in self.prefix_cache.tenant_stats.items()},
+                self.prefix_cache.stats["evict_pops"],
+                self.prefix_cache.stats["evict_stale"])
         if self.host_kv is not None:
             self.metrics.set_host_kv(self.host_kv.snapshot())
             if self.ledger is not None and self.prefix_cache is not None:

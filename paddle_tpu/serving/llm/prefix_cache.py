@@ -30,6 +30,13 @@ Lifecycle and safety:
 - Eviction is LRU over refcount-0 leaves and tails (a deterministic
   monotonic tick, no wall clock), driven by the pool's `on_pressure`
   hook from inside `allocate()`: evict just enough to unpin one row.
+  The victims come from `_order`, a min-heap of `(tick, page)` that the
+  cache keeps as it goes: an entry is pushed when it becomes a candidate
+  (an insert's terminal leaf or tail; a parent whose last child or tail
+  `_drop` just took) or is touched while one (an `acquire` that ends on
+  a leaf or matches a tail), and is checked when it is popped (still
+  indexed, still childless or still the tail, tick unchanged). A freed
+  page costs a few heap operations, whatever the trees hold.
 - A request that attaches its leading blocks needs a row only from the
   block behind them (`SlotPagedKVPool.allocate(keep_below=)`): pressure
   then clears *one free row* from that block on (`evict_row`: the pages
@@ -46,6 +53,7 @@ device work — sized by cached blocks, not tokens.
 """
 from __future__ import annotations
 
+import heapq
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -122,7 +130,12 @@ class PrefixCache:
         # in that node, or None for the node's tail)
         self._where: Dict[int, Tuple[str, _Node, Optional[tuple]]] = {}
         self._tick = 0
-        self.stats = _tenant_stats()
+        # the LRU order: (tick, page) of every entry that is evictable but
+        # for its readers, and of entries that were (checked on pop)
+        self._order: List[Tuple[int, int]] = []
+        # `evict_pops`: entries the pressure path took from the order;
+        # `evict_stale`: those of them that were no longer candidates
+        self.stats = {**_tenant_stats(), "evict_pops": 0, "evict_stale": 0}
         self.tenant_stats: Dict[str, dict] = {}
         # ISSUE 19 spill tier: when a HostKVPool is attached, pressure
         # eviction of a refcount-0 FULL block serializes its page to host
@@ -192,6 +205,11 @@ class PrefixCache:
                 tail_page = node.tail_page
                 tail_len = u
                 node.tail_tick = self._tick
+                self._offer(self._tick, tail_page)
+        if chain:
+            # of the nodes this lookup re-ticked only the last can be a
+            # leaf: the others have it, or its ancestors, below them
+            self._offer_leaf(node)
         hit_tokens = attach_len + tail_len
         if hit_tokens > 0:
             ts["hits"] += 1
@@ -297,63 +315,105 @@ class PrefixCache:
             child.tick = self._tick
             node = child
         rem = tuple(int(t) for t in tokens[n_full * bl:])
-        if rem:
-            if node.tail_tokens is None or (
-                    len(rem) > len(node.tail_tokens)
-                    and self.pool.refcount.get(node.tail_page, 0) == 0):
-                page = slot * nb_pool + n_full
-                if page in self.pool.cached or page == node.tail_page:
-                    return
-                if node.tail_page is not None:
-                    self.pool.release_cached(node.tail_page)
-                    self._where.pop(node.tail_page, None)
-                    ts["cached_blocks"] -= 1
-                    self.stats["cached_blocks"] -= 1
-                self.pool.register_cached(page)
-                self._where[page] = (tenant, node, None)
-                node.tail_tokens = rem
-                node.tail_page = page
-                node.tail_tick = self._tick
-                ts["insertions"] += 1
-                self.stats["insertions"] += 1
-                ts["cached_blocks"] += 1
-                self.stats["cached_blocks"] += 1
+        page = slot * nb_pool + n_full
+        if rem and (node.tail_tokens is None or (
+                len(rem) > len(node.tail_tokens)
+                and self.pool.refcount.get(node.tail_page, 0) == 0)) \
+                and page not in self.pool.cached:
+            if node.tail_page is not None:
+                self.pool.release_cached(node.tail_page)
+                self._where.pop(node.tail_page, None)
+                ts["cached_blocks"] -= 1
+                self.stats["cached_blocks"] -= 1
+            self.pool.register_cached(page)
+            self._where[page] = (tenant, node, None)
+            node.tail_tokens = rem
+            node.tail_page = page
+            node.tail_tick = self._tick
+            self._offer(self._tick, page)
+            ts["insertions"] += 1
+            self.stats["insertions"] += 1
+            ts["cached_blocks"] += 1
+            self.stats["cached_blocks"] += 1
+        # the path's last node is the one this insert may have left a leaf
+        self._offer_leaf(node)
 
     # ---- eviction ----
-    def _lru_victim(self):
-        """Least-recently-touched evictable entry across all tenants:
-        refcount-0 tails, and refcount-0 leaf nodes (no children AND no
-        tail — interior nodes and tailed nodes are structurally pinned
-        until their descendants go first). Each candidate carries the
-        victim block's FULL token path from the prefix start — the
-        content address the host spill tier is keyed by (ISSUE 19)."""
-        best = None   # (tick, kind, tenant, node_or_parent, key, path)
-        for tenant, root in self._roots.items():
-            stack: List[Tuple[_Node, Optional[_Node],
-                              Optional[Tuple[int, ...]],
-                              Tuple[int, ...]]] = \
-                [(root, None, None, ())]
-            while stack:
-                node, parent, key, path = stack.pop()
-                if (node.tail_page is not None
-                        and self.pool.refcount.get(node.tail_page, 0) == 0):
-                    cand = (node.tail_tick, "tail", tenant, node, None, path)
-                    if best is None or cand[0] < best[0]:
-                        best = cand
-                if (parent is not None and not node.children
-                        and node.tail_page is None
-                        and self.pool.refcount.get(node.page, 0) == 0):
-                    cand = (node.tick, "node", tenant, parent, key, path)
-                    if best is None or cand[0] < best[0]:
-                        best = cand
-                for k, c in node.children.items():
-                    stack.append((c, node, k, path + k))
-        return best
+    def _offer(self, tick: int, page: int):
+        """Put one entry into the LRU order. The order may hold entries
+        that are no longer candidates (`_candidate` tells on pop); it is
+        swept once they outnumber the cached blocks."""
+        heapq.heappush(self._order, (tick, page))
+        if len(self._order) > 2 * self.stats["cached_blocks"] + 64:
+            self._sweep()
 
-    def _drop(self, tenant: str, holder: _Node, key, path=None):
+    def _sweep(self):
+        """Keep the order's standing entries, once each (a sorted list is
+        a heap). That leaves at most one entry a cached page, so the next
+        sweep is `cached_blocks + 64` offers away or more: amortised, an
+        offer pays O(1) of it."""
+        self._order = sorted({e for e in self._order
+                              if self._candidate(*e) is not None})
+
+    def _offer_leaf(self, node: _Node):
+        """Offer `node` if it is a leaf: no children and no tail (interior
+        and tailed nodes are structurally pinned until their descendants
+        go first; a root names no page)."""
+        if (node.page is not None and not node.children
+                and node.tail_page is None):
+            self._offer(node.tick, node.page)
+
+    def _candidate(self, tick: int, page: int):
+        """`_where`'s (tenant, holder, key) for an entry of the order if
+        it still stands as it was offered — a tail that is still that
+        node's tail, or a node still without children and tail, and not
+        touched since — else None. Readers are the caller's to check."""
+        where = self._where.get(page)
+        if where is None:
+            return None
+        _, holder, key = where
+        if key is None:
+            if holder.tail_page != page or holder.tail_tick != tick:
+                return None
+        else:
+            node = holder.children[key]
+            if (node.tick != tick or node.children
+                    or node.tail_page is not None):
+                return None
+        return where
+
+    def _pop_victim(self, held: list):
+        """Take the coldest evictable entry from the order: `_where`'s
+        (tenant, holder, key) for it, or None when the order is out.
+        Entries that no longer stand are dropped on the way, those with a
+        reader put on `held` (the caller hands them back)."""
+        while self._order:
+            entry = heapq.heappop(self._order)
+            self.stats["evict_pops"] += 1
+            where = self._candidate(*entry)
+            if where is None:
+                self.stats["evict_stale"] += 1
+            elif self.pool.refcount.get(entry[1], 0) > 0:
+                held.append(entry)
+            else:
+                return where
+        return None
+
+    def _path(self, holder: _Node, key) -> Tuple[int, ...]:
+        """The FULL token path, from the prefix start, of the block under
+        `key` in `holder` — the content address the host spill tier is
+        keyed by (ISSUE 19) — read upwards through `_where`."""
+        keys = [key]
+        while holder.page is not None:
+            _, holder, key = self._where[holder.page]
+            keys.append(key)
+        return tuple(t for k in reversed(keys) for t in k)
+
+    def _drop(self, tenant: str, holder: _Node, key, spill: bool = False):
         """Unlink one refcount-0 entry (`holder`'s tail where `key` is
         None, else its childless child under `key`) and release its
-        page."""
+        page; `spill`: a full block goes to the host tier first, where
+        there is one."""
         ts = self._ts(tenant)
         if key is None:
             page = holder.tail_page
@@ -362,13 +422,14 @@ class PrefixCache:
             holder.tail_tick = 0
         else:
             page = holder.children.pop(key).page
-            if self.host_pool is not None and path is not None:
+            if spill and self.host_pool is not None:
                 # spill the full block to the host tier before the page is
                 # released (refcount is provably 0 here, so the device copy
                 # is quiescent — the export is the exact KV the trie
                 # indexed)
                 t0 = self.clock() if self.clock is not None else None
-                self.host_pool.put(tenant, path, self.pool.export_page(page))
+                self.host_pool.put(tenant, self._path(holder, key),
+                                   self.pool.export_page(page))
                 self.spilled_pages += 1
                 if t0 is not None:
                     self.spill_seconds += self.clock() - t0
@@ -378,6 +439,8 @@ class PrefixCache:
         self.stats["evictions"] += 1
         ts["cached_blocks"] -= 1
         self.stats["cached_blocks"] -= 1
+        # what held `holder` in place may just have gone
+        self._offer_leaf(holder)
 
     def _row_victims(self, row: int, keep_below: int):
         """The cached pages of `row` at block `keep_below` or above,
@@ -434,13 +497,17 @@ class PrefixCache:
                 _, _, row, victims = min(plans, key=lambda p: p[:2])
                 return self.evict_row(row, keep_below, victims)
             released = 0
-            while not self.pool.has_allocatable_row():
-                victim = self._lru_victim()
-                if victim is None:
-                    break
-                _, kind, tenant, holder, key, path = victim
-                self._drop(tenant, holder, key, path)
-                released += 1
+            held = []    # candidates with a reader: back into the order
+            try:
+                while not self.pool.has_allocatable_row():
+                    where = self._pop_victim(held)
+                    if where is None:
+                        break
+                    self._drop(*where, spill=True)
+                    released += 1
+            finally:
+                for entry in held:
+                    heapq.heappush(self._order, entry)
         return released
 
     def clear(self, only=None) -> int:
@@ -487,6 +554,9 @@ class PrefixCache:
         if only is None:
             self._roots.clear()
             self.stats["cached_blocks"] = 0
+        # what went left `_where`, so its entries in the order no longer
+        # stand: the trees were just walked, the order can be as well
+        self._sweep()
         if self.host_pool is not None:
             # spilled KV is a function of the weights that computed it —
             # a weight swap poisons the host tier the same way it poisons

@@ -333,6 +333,10 @@ class LLMMetrics(ServingMetrics):
         # reconciliation invariant
         self.cached_blocks = 0
         self.cache_evictions = 0
+        # the eviction order's work (ISSUE 40): entries the pressure path
+        # popped, and those of them that no longer stood
+        self.cache_evict_pops = 0
+        self.cache_evict_stale = 0
         self.tenants: Dict[str, Dict[str, int]] = {}
         # time-weighted slot occupancy (ISSUE 11 satellite): ∫occupancy·dt
         # integrated at pump granularity, so the average weighs each
@@ -441,10 +445,13 @@ class LLMMetrics(ServingMetrics):
                     t["inflight_tokens"] = int(tokens)
 
     def set_prefix_cache(self, cached_blocks: int, evictions: int,
-                         per_tenant_cached: Optional[Dict[str, int]] = None):
+                         per_tenant_cached: Optional[Dict[str, int]] = None,
+                         evict_pops: int = 0, evict_stale: int = 0):
         with self._lock:
             self.cached_blocks = int(cached_blocks)
             self.cache_evictions = int(evictions)
+            self.cache_evict_pops = int(evict_pops)
+            self.cache_evict_stale = int(evict_stale)
             for tenant, n in (per_tenant_cached or {}).items():
                 t = self._tenant(tenant)
                 if t is not None:
@@ -742,6 +749,8 @@ class LLMMetrics(ServingMetrics):
             s["kv_fragmentation"] = self.fragmentation
             s["cached_blocks"] = self.cached_blocks
             s["cache_evictions"] = self.cache_evictions
+            s["cache_evict_pops"] = self.cache_evict_pops
+            s["cache_evict_stale"] = self.cache_evict_stale
             s["tenants"] = {t: dict(v) for t, v in self.tenants.items()}
             s["slot_occupancy_avg"] = (
                 self._occ_integral / self._occ_wall
@@ -932,6 +941,10 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_cached_blocks", s["cached_blocks"])
         b.family(f"{px}_cache_evictions_total", "counter")
         b.sample(f"{px}_cache_evictions_total", s["cache_evictions"])
+        b.family(f"{px}_cache_evict_pops_total", "counter")
+        b.sample(f"{px}_cache_evict_pops_total", s["cache_evict_pops"])
+        b.family(f"{px}_cache_evict_stale_total", "counter")
+        b.sample(f"{px}_cache_evict_stale_total", s["cache_evict_stale"])
         if s["tenants"]:
             b.family(f"{px}_tenant_requests_total", "counter")
             for tenant in sorted(s["tenants"]):

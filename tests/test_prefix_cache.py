@@ -198,6 +198,337 @@ def test_lru_eviction_under_pressure_frees_coldest_first():
     p.check_balance()
 
 
+# ---- the LRU order against the tree scan it replaced (ISSUE 40) ----
+
+def _scan_lru_victim(cache):
+    """The pressure path's victim as it was found until PR 39, kept here as
+    the oracle: a depth-first walk of every tenant's tree for the smallest
+    tick among refcount-0 tails and refcount-0 leaves (no children, no
+    tail), each with the block's full token path (the host tier's key)."""
+    best = None   # (tick, kind, tenant, node_or_parent, key, path)
+    for tenant, root in cache._roots.items():
+        stack = [(root, None, None, ())]
+        while stack:
+            node, parent, key, path = stack.pop()
+            if (node.tail_page is not None
+                    and cache.pool.refcount.get(node.tail_page, 0) == 0):
+                cand = (node.tail_tick, "tail", tenant, node, None, path)
+                if best is None or cand[0] < best[0]:
+                    best = cand
+            if (parent is not None and not node.children
+                    and node.tail_page is None
+                    and cache.pool.refcount.get(node.page, 0) == 0):
+                cand = (node.tick, "node", tenant, parent, key, path)
+                if best is None or cand[0] < best[0]:
+                    best = cand
+            for k, c in node.children.items():
+                stack.append((c, node, k, path + k))
+    return best
+
+
+def _scan_candidates(cache):
+    """(tick, page) of every tail and every leaf, readers or none: what
+    the order has to hold."""
+    out = set()
+    for root in cache._roots.values():
+        stack = [(root, None)]
+        while stack:
+            node, parent = stack.pop()
+            if node.tail_page is not None:
+                out.add((node.tail_tick, node.tail_page))
+            elif parent is not None and not node.children:
+                out.add((node.tick, node.page))
+            stack.extend((c, node) for c in node.children.values())
+    return out
+
+
+class _History:
+    """A seeded history of what an engine does to its cache (admit: probe
+    the row, allocate under pressure, acquire, attach, release; index the
+    finished prefill; free the slot) and what its operators do (a reader
+    held across admissions, `evict_row`, `clear(only=)`), every pressure
+    call checked against the scan, drop by drop."""
+
+    BL, NB, SLOTS = 4, 6, 5
+
+    def __init__(self, seed, tenants, host):
+        from paddle_tpu.serving.llm import HostKVPool, PrefixCache
+        self.rng = np.random.default_rng(seed)
+        self.tenants = tenants
+        self.pool = _pool(self.SLOTS, self.BL, self.NB)
+        self.puts = []
+        host_pool = None
+        if host:
+            class Recorder(HostKVPool):
+                def put(_, tenant, path, layers):
+                    self.puts.append((tenant, tuple(path)))
+                    return super().put(tenant, path, layers)
+            host_pool = Recorder(1 << 20, self.BL)
+        self.cache = PrefixCache(self.pool, host_pool=host_pool)
+        self.live = []        # [tenant, prompt, slot, indexed]
+        self.plans = []       # (tenant, plan): readers held across calls
+        self.prompts = []     # (tenant, prompt) seen so far
+        self.victims = 0      # pressure-path drops, each checked
+        self.set_aside = 0    # pressure calls that met a candidate with a reader
+        self._want = []       # the scan's keys for the puts still to come
+        self._wrap()
+
+    def _wrap(self):
+        cache, pool = self.cache, self.pool
+        drop, pressure = cache._drop, cache.evict_for_pressure
+
+        def checked_drop(tenant, holder, key, spill=False):
+            if spill:
+                assert not pool.has_allocatable_row()
+                want = _scan_lru_victim(cache)
+                assert want is not None
+                _, _, w_tenant, w_holder, w_key, w_path = want
+                assert (w_tenant, w_key) == (tenant, key)
+                assert w_holder is holder
+                if key is not None and cache.host_pool is not None:
+                    self._want.append((tenant, w_path))
+                self.victims += 1
+            drop(tenant, holder, key, spill)
+
+        def checked_pressure(keep_below=0, rows=None):
+            if rows is None and any(
+                    pool.refcount.get(page, 0) > 0
+                    for _, page in _scan_candidates(cache)):
+                self.set_aside += 1
+            n = pressure(keep_below, rows)
+            if rows is None:
+                # the stop rule: a row came free, or the scan is out of
+                # victims as well
+                assert (pool.has_allocatable_row()
+                        or _scan_lru_victim(cache) is None)
+            assert self.puts == self._want
+            return n
+
+        cache._drop = checked_drop
+        cache.evict_for_pressure = pool.on_pressure = checked_pressure
+
+    def _prompt(self):
+        rng, cap = self.rng, self.BL * self.NB - 2
+        tenant = self.tenants[int(rng.integers(len(self.tenants)))]
+        mine = [p for t, p in self.prompts if t == tenant]
+        kind = rng.random()
+        if mine and kind < 0.25:                       # an exact duplicate
+            tokens = mine[int(rng.integers(len(mine)))]
+        elif mine and kind < 0.7:     # a shared prefix, another remainder
+            base = mine[int(rng.integers(len(mine)))]
+            keep = int(rng.integers(1, len(base) + 1))
+            more = int(rng.integers(0, cap - keep + 1))
+            tokens = np.concatenate(
+                [base[:keep], rng.integers(1, 5, (more,))]).astype(np.int32)
+        else:
+            tokens = rng.integers(1, 5, (int(rng.integers(1, cap + 1)),)
+                                  ).astype(np.int32)
+        self.prompts.append((tenant, tokens))
+        return tenant, tokens
+
+    def admit(self):
+        from paddle_tpu.serving.llm import SlotsExhaustedError
+        cache, pool = self.cache, self.pool
+        tenant, tokens = self._prompt()
+        keep_below, prefer = cache.probe_row(tenant, tokens, len(tokens) - 1)
+        try:
+            slot = pool.allocate(len(tokens) + 1, keep_below, prefer)
+        except SlotsExhaustedError:
+            return
+        plan = cache.acquire(tenant, tokens, len(tokens) - 1)
+        assert len(plan.pages) >= keep_below
+        if plan.pages:
+            pool.attach_blocks(slot, plan.pages)
+        cache.release(plan)
+        pool.set_length(slot, len(tokens))
+        self.live.append([tenant, tokens, slot, False])
+
+    def index(self):
+        todo = [r for r in self.live if not r[3]]
+        if todo:
+            req = todo[int(self.rng.integers(len(todo)))]
+            tenant, tokens, slot, _ = req
+            self.cache.insert(tenant, tokens, slot,
+                              self.pool._attached.get(slot, []))
+            req[3] = True
+
+    def free(self):
+        if self.live:
+            req = self.live.pop(int(self.rng.integers(len(self.live))))
+            if not req[3] and self.rng.random() < 0.8:
+                self.live.append(req)
+                return self.index()
+            self.pool.free(req[2])
+
+    def hold(self):
+        if self.prompts and len(self.plans) < 3:
+            tenant, tokens = self.prompts[
+                int(self.rng.integers(len(self.prompts)))]
+            self.plans.append(
+                (tenant, self.cache.acquire(tenant, tokens, len(tokens) - 1)))
+
+    def unhold(self):
+        if self.plans:
+            _, plan = self.plans.pop(int(self.rng.integers(len(self.plans))))
+            self.cache.release(plan)
+
+    def evict_row(self):
+        free = np.flatnonzero(~self.pool.active)
+        if free.size:
+            self.cache.evict_row(int(free[self.rng.integers(free.size)]),
+                                 int(self.rng.integers(self.NB)))
+
+    def clear_only(self):
+        # the caller holds that namespace idle (a hot swap's contract)
+        gone = self.tenants[-1]
+        if all(r[0] != gone for r in self.live) \
+                and all(t != gone for t, _ in self.plans):
+            self.cache.clear(only=lambda t: t == gone)
+
+    def pressure(self):
+        self.cache.evict_for_pressure()
+
+    def run(self, steps):
+        ops = [self.admit] * 8 + [self.index] * 4 + [self.free] * 6 + [
+            self.hold, self.hold, self.unhold, self.unhold, self.evict_row,
+            self.pressure, self.clear_only]
+        cache = self.cache
+        for step in range(steps):
+            ops[int(self.rng.integers(len(ops) - (step % 40 != 39)))]()
+            self.pool.check_balance()
+            # no entry is ever lost to the lazy structure, and the order
+            # stays the size of what is cached
+            assert _scan_candidates(cache) <= set(cache._order)
+            assert len(cache._order) <= 2 * cache.stats["cached_blocks"] + 64
+        for _, plan in self.plans:
+            cache.release(plan)
+        for _, _, slot, _ in self.live:
+            self.pool.free(slot)
+        # drain: every row handed to a fresh sequence at once, so what is
+        # left goes, in the scan's order, to the last page
+        self.plans, self.live = [], []
+        for slot in [self.pool.allocate(1) for _ in range(self.SLOTS)]:
+            self.pool.free(slot)
+        self.pool.check_balance()
+        assert not cache._where and not self.pool.cached
+
+
+@pytest.mark.parametrize("seed,tenants,host", [
+    (0, ("a",), False), (1, ("a",), True), (2, ("a", "b"), False),
+    (3, ("a", "b"), True), (4, ("a",), False), (5, ("a", "b"), True),
+    (6, ("a", "b"), False), (7, ("a",), True)])
+def test_pressure_victims_are_the_tree_scans(seed, tenants, host):
+    """The pressure path's victims, their order and the host tier's keys
+    are the old scan's at every drop of a seeded history, and the order
+    holds every candidate the scan can find."""
+    h = _History(seed, tenants, host)
+    h.run(600)
+    stats = h.cache.stats
+    assert h.victims > 100 and h.set_aside > 0
+    assert stats["evict_pops"] >= h.victims
+    assert stats["evict_pops"] > stats["evict_stale"] > 0
+    assert h.cache.snapshot()["evict_pops"] == stats["evict_pops"]
+    if host:
+        assert len(h.puts) > 20 and h.cache.spilled_pages == len(h.puts)
+
+
+def _counting_nodes(monkeypatch):
+    """Every read of a node's `children` counted: what a walk cannot do
+    without."""
+    from paddle_tpu.serving.llm import prefix_cache
+    plain = prefix_cache._Node
+
+    class Counted(plain):
+        __slots__ = ()
+        reads = 0
+
+        @property
+        def children(self):
+            Counted.reads += 1
+            return plain.children.__get__(self)
+
+        @children.setter
+        def children(self, value):
+            plain.children.__set__(self, value)
+
+    monkeypatch.setattr(prefix_cache, "_Node", Counted)
+    return Counted
+
+
+def test_a_freed_page_costs_pops_not_a_walk(monkeypatch):
+    """`mistral-7b.serve-prefill-cached`'s shape: 32 rows of 65 pages of
+    16, a 256-1,024-token prompt pinning every row, nothing shared. An
+    admission frees some thirty pages; each is a pop or two from the order
+    and a constant number of nodes, never the trees (≈1,000 nodes here).
+    Counters, no clock."""
+    from paddle_tpu.serving.llm import PrefixCache
+    nodes = _counting_nodes(monkeypatch)
+    pool = _pool(num_slots=32, block_len=16, n_blocks=65)
+    cache = PrefixCache(pool)
+    rng = np.random.default_rng(40)
+    in_pressure = [0]
+    pressure = cache.evict_for_pressure
+
+    def counted(*a, **kw):
+        before = nodes.reads
+        n = pressure(*a, **kw)
+        in_pressure[0] += nodes.reads - before
+        return n
+
+    pool.on_pressure = counted
+
+    def admission():
+        tokens = rng.integers(1, 30000, (int(np.exp(rng.uniform(
+            np.log(256), np.log(1024)))),)).astype(np.int32)
+        slot = pool.allocate(len(tokens) + 16)
+        cache.release(cache.acquire("t", tokens, len(tokens) - 1))
+        pool.set_length(slot, len(tokens))
+        cache.insert("t", tokens, slot, [])
+        pool.free(slot)
+
+    for _ in range(32):
+        admission()
+    assert cache.stats["evictions"] == 0 and not pool.has_allocatable_row()
+    assert cache.stats["cached_blocks"] > 900
+    for _ in range(40):
+        admission()
+    evictions, pops = cache.stats["evictions"], cache.stats["evict_pops"]
+    assert evictions > 40 * 16            # a prompt's pages an admission
+    assert pops <= 2 * evictions + 64
+    # a pop reads its holder's children and its own, a drop unlinks and
+    # looks at what it left: four reads a pop bound it, a walk would read
+    # a thousand nodes a page
+    assert in_pressure[0] <= 4 * pops
+    pool.check_balance()
+
+
+def test_a_lookup_over_a_long_chain_offers_one_entry(monkeypatch):
+    """A session's history is a chain of interior nodes: `acquire` re-ticks
+    2,000 of them and offers the order the one that is a candidate."""
+    from paddle_tpu.serving.llm import PrefixCache
+    pool = _pool(num_slots=2, block_len=4, n_blocks=2002)
+    cache = PrefixCache(pool)
+    offers = []
+    offer = cache._offer
+    monkeypatch.setattr(cache, "_offer",
+                        lambda tick, page: (offers.append(page),
+                                            offer(tick, page)))
+    tokens = np.arange(1, 8003, dtype=np.int32)      # 2,000 blocks + 2
+    slot = pool.allocate(8008)
+    pool.set_length(slot, len(tokens))
+    cache.insert("t", tokens, slot, [])
+    assert offers == [slot * 2002 + 2000]            # the tail alone
+    pool.free(slot)
+    plan = cache.acquire("t", tokens, len(tokens) - 1)
+    assert len(plan.pages) == 2000 and plan.tail_len == 1
+    assert offers == [slot * 2002 + 2000] * 2
+    cache.release(plan)
+    plan = cache.acquire("t", tokens[:8000], 7999)   # ends inside the chain
+    assert len(offers) == 2 and len(cache._order) == 2
+    cache.release(plan)
+    pool.check_balance()
+
+
 # ---- SimClock acceptance: N shared-prefix requests ~ 1 prefill ----
 
 def test_shared_prefix_requests_cost_one_prefill_bit_identically(gpt_tiny):
@@ -353,6 +684,13 @@ def test_eviction_under_pressure_never_reclaims_live_readers(gpt_tiny):
                               gpt_tiny, pC[None, :],
                               max_new_tokens=2).numpy())[0, 16:])
     assert eng.prefix_cache.stats["evictions"] >= 1
+    # the order's counters reach the engine's metrics: the reader's page
+    # was popped, set aside and handed back, so pops outnumber evictions
+    snap = eng.metrics.snapshot()
+    assert snap["cache_evict_pops"] == \
+        eng.prefix_cache.stats["evict_pops"] > snap["cache_evictions"]
+    assert snap["cache_evict_stale"] == \
+        eng.prefix_cache.stats["evict_stale"]
     eng.pool.check_balance()
     assert eng.pool.active_slots() == 0
     eng.stop()
@@ -476,6 +814,8 @@ def test_http_tenant_header_and_per_tenant_observability(gpt_tiny):
         assert 'pdtpu_llm_tenant_cache_hit_rate{tenant="alpha"}' in text
         assert "pdtpu_llm_prefix_misses_total 1" in text
         assert "pdtpu_llm_cached_blocks" in text
+        assert "pdtpu_llm_cache_evict_pops_total 0" in text
+        assert "pdtpu_llm_cache_evict_stale_total 0" in text
         with urllib.request.urlopen(f"{base}/healthz", timeout=30) as r:
             health = json.loads(r.read())
         assert "alpha" in health["llm_tenants"]
