@@ -29,6 +29,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -671,6 +672,11 @@ def _flash_vjp_fwd(q, k, v, mask, seed, causal, scale, block_q, block_k,
                    dropout_p):
     out, lse = _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k,
                           dropout_p, seed)
+    # named so that a per-layer recompute (KEEP_FLASH_RESIDUALS in
+    # fleet/utils/recompute.py) keeps the kernel's two results and its replay
+    # of the layer holds no second forward call; identities elsewhere
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, mask, seed, out, lse)
 
 

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
+from ..distributed.fleet.utils.recompute import KEEP_FLASH_RESIDUALS
 from ..nn.layer.layers import Layer
 from ..profiler import (SPAN_SETUP_FIRST_STEP, SPAN_SETUP_PARALLELIZE,
                         SPAN_TRAIN_CHUNK_DISPATCH, RecordEvent, SetupSpan)
@@ -194,7 +195,7 @@ def _wrap_forward_remat(layer: Layer):
             return tuple(l.data if isinstance(l, Tensor) else l
                          for l in leaves)
 
-        res = _jax.checkpoint(inner)(*arrs)
+        res = _jax.checkpoint(inner, policy=KEEP_FLASH_RESIDUALS)(*arrs)
         leaves = [Tensor(r) if t else r
                   for r, t in zip(res, out_kind["tensor_leaf"])]
         return _jax.tree_util.tree_unflatten(out_kind["treedef"], leaves)

@@ -202,3 +202,98 @@ def test_sdpa_dropout_trains():
     out.sum().backward()
     assert x.grad is not None
     assert np.isfinite(x.grad.numpy()).all()
+
+
+# ---- per-layer recompute keeps the forward kernel's two results ----
+
+_HEADS, _HEAD_DIM = 2, 32
+
+
+def _attn_block(a, w):
+    """A residual attention block on arrays, through the Pallas kernels
+    (interpreted here)."""
+    B, S, E = a.shape
+    qkv = (a @ w).reshape(B, S, 3, _HEADS, _HEAD_DIM).transpose(2, 0, 3, 1, 4)
+    o = flash_attention(qkv[0], qkv[1], qkv[2], causal=True, block_q=64,
+                        block_k=64, force_pallas=True)
+    return a + jnp.tanh(o.transpose(0, 2, 1, 3).reshape(B, S, E))
+
+
+def _pallas_calls(jaxpr):
+    """Names of the `pallas_call`s of a jaxpr, those inside the jaxprs it
+    holds (a checkpoint's replay, a `shard_map`'s body) included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["one_device",
+                                                        "spmd_island"])
+@pytest.mark.parametrize("wrapper", ["recompute", "selective_remat"])
+def test_layer_replay_holds_no_second_flash_forward(wrapper, on_mesh):
+    """The gradient of a block under `recompute()` / `_wrap_forward_remat`
+    holds three kernels (forward, dq, dkv): the replay takes the kept `out`
+    and `lse` where a `jax.checkpoint` without the policy runs the forward
+    kernel again. The gradients are the bits of both other forms."""
+    import contextlib
+    from jax.sharding import Mesh
+    from paddle_tpu.core.tensor import Tensor, apply, no_grad
+    from paddle_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle_tpu.nn.layer.layers import Layer
+    from paddle_tpu.ops.attention import spmd_mesh
+    from paddle_tpu.parallel.api import _wrap_forward_remat
+
+    class Block(Layer):
+        def __init__(self, w):
+            super().__init__()
+            self.w = w
+
+        def forward(self, x):
+            return apply(lambda a: _attn_block(a, self.w), x)
+
+    def kept(block, a):
+        if wrapper == "recompute":
+            return recompute(block, Tensor(a)).data
+        _wrap_forward_remat(block)
+        return block(Tensor(a)).data
+
+    forms = {
+        "kept": kept,
+        "unpoliced": lambda block, a: jax.checkpoint(
+            lambda a_: block(Tensor(a_)).data)(a),
+        "plain": lambda block, a: block(Tensor(a)).data,
+    }
+    trace_ctx = contextlib.nullcontext
+    if on_mesh:
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("data", "sharding"))
+
+        def trace_ctx():
+            return spmd_mesh(mesh, ("data", "sharding"))
+
+    def grad_of(form):
+        def loss(a, w):
+            with no_grad(), trace_ctx():
+                return jnp.sum(forms[form](Block(w), a) ** 2)
+        return jax.grad(loss, argnums=(0, 1))
+
+    rng = np.random.RandomState(3)
+    a = jnp.asarray(rng.randn(4, 128, _HEADS * _HEAD_DIM).astype(np.float32))
+    w = jnp.asarray(rng.randn(_HEADS * _HEAD_DIM, 3 * _HEADS * _HEAD_DIM)
+                    .astype(np.float32) * 0.1)
+    calls = {form: sorted(_pallas_calls(
+        jax.make_jaxpr(grad_of(form))(a, w).jaxpr)) for form in forms}
+    three = ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert calls["plain"] == three
+    assert calls["unpoliced"] == three + ["flash_fwd"]
+    assert calls["kept"] == three
+    grads = {form: jax.jit(grad_of(form))(a, w) for form in forms}
+    for form in ("unpoliced", "plain"):
+        for got, want in zip(grads["kept"], grads[form]):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

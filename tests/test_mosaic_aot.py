@@ -34,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 49 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 50 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 13         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -50,6 +50,20 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
                          ("(32, 1)", 10)):
         assert any(f"'grid': {grid}, 'groups': {groups}," in t
                    and "'pages': 8" in t for t in tilings), (grid, groups)
+
+
+def test_a_recomputed_layer_holds_one_flash_forward_for_the_v5e(tool):
+    """Per-layer recompute keeps the forward kernel's output and
+    log-sum-exp (PR 41): compiled for the chip at the train cells' shapes,
+    the gradient of two layers under `recompute()` holds two `flash_fwd`
+    custom calls, not four (the replay of a layer ran the kernel again),
+    and says what it holds."""
+    case, = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] recompute of 2 layers")]
+    assert "[8,16,2048,128]: {'flash_fwd': 2, 'flash_bwd_dq': 2, " \
+        "'flash_bwd_dkv': 2}; " in case
+    assert re.search(r"\d+ bytes of arguments, \d+ of results, \d+ of "
+                     r"temporaries in \d+\.\ds$", case), case
 
 
 def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):

@@ -55,7 +55,7 @@ def use_tpu_lowering():
     pallas_mode.platform = lambda: "tpu"
 
 
-def compile_case(name, fn, *specs, want=None) -> bool:
+def compile_case(name, fn, *specs, want=None, memory=False) -> bool:
     """Lower + compile `fn` for the specs' devices; `want` is the expected
     {kernel: count} of Mosaic custom calls in the compiled text."""
     from paddle_tpu.obs.compile_observatory import pallas_kernel_census
@@ -68,7 +68,13 @@ def compile_case(name, fn, *specs, want=None) -> bool:
         return False
     census = pallas_kernel_census(compiled.as_text())
     ok = want is None or census == want
-    print(f"[{'OK' if ok else 'FAIL'}] {name}: {census} in "
+    held = ""
+    if memory:
+        m = compiled.memory_analysis()
+        held = (f"; {m.argument_size_in_bytes} bytes of arguments, "
+                f"{m.output_size_in_bytes} of results, "
+                f"{m.temp_size_in_bytes} of temporaries")
+    print(f"[{'OK' if ok else 'FAIL'}] {name}: {census}{held} in "
           f"{time.perf_counter() - t0:.1f}s"
           + ("" if ok else f" (wanted {want})"), flush=True)
     return ok
@@ -104,6 +110,28 @@ def kernel_equations(fn, *specs) -> dict:
                     visit(sub)
     visit(jax.make_jaxpr(fn)(*specs).jaxpr)
     return found
+
+
+def recompute_grad(B, H, S, D, layers):
+    """The gradient of `layers` residual attention blocks, each under
+    `recompute()` as `models/gpt.py` wraps its decoder layers, with respect
+    to the input `[B, S, H * D]` and the stacked QKV weights."""
+    from paddle_tpu.core.tensor import Tensor, apply, no_grad
+    from paddle_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle_tpu.ops.attention import flash_attention
+
+    def block(a, w):
+        qkv = (a @ w).reshape(B, S, 3, H, D).transpose(2, 0, 3, 1, 4)
+        o = flash_attention(qkv[0], qkv[1], qkv[2], causal=True)
+        return a + o.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+    def loss(a, ws):
+        x = Tensor(a)
+        with no_grad():
+            for i in range(layers):
+                x = recompute(lambda t, w=ws[i]: apply(block, t, w), x)
+        return jnp.sum(x.data.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1))
 
 
 def serve_step_case(name, model, device, want) -> bool:
@@ -249,6 +277,17 @@ def main() -> int:
             f"flash fwd+bwd bf16 [2,16,1024,128]{label}",
             jax.grad(loss, argnums=(0, 1, 2)), *qkv,
             want={"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}))
+
+    # per-layer recompute at the train cells' shapes: a layer's replay in
+    # the backward pass takes the forward kernel's kept `out` and `lse`, so
+    # two layers hold two forward calls (four without the policy: PR 41)
+    results.append(compile_case(
+        "recompute of 2 layers, flash fwd+bwd bf16 [8,16,2048,128]",
+        recompute_grad(8, 16, 2048, 128, layers=2),
+        spec((8, 2048, 2048), jnp.bfloat16),
+        spec((2, 2048, 3 * 2048), jnp.bfloat16),
+        want={"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2},
+        memory=True))
 
     # (label, q [B, H, Tq, D], slab [N, Hkv, L_slab, D], block_len, pages a
     # row): MHA at both block sizes the repo runs and both query widths,
