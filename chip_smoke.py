@@ -583,6 +583,7 @@ def _sparse_parity(size: dict):
     import numpy as np
 
     from paddle_tpu.ops import index_select as IX
+    from paddle_tpu.ops.attention import PagedView
     from paddle_tpu.ops.paged_attention import sparse_latent_attention
     g = size["sparse"]
     H, R, cols, Hi, Di, K, pages = (
@@ -603,7 +604,7 @@ def _sparse_parity(size: dict):
     adv = np.array([1, T, T, 1], np.int32)      # decode, chunk, chunk, decode
     lens = np.array([L // 3, K // 2, L, L], np.int32)
     q_pos = (lens - adv).astype(np.int32)
-    paged = (table, lens, bl, pages)
+    paged = PagedView(table, lens, bl, pages)
     scores = {impl: IX.index_scores(qi, w, ki, table, lens, q_pos,
                                     block_len=bl, pages_per_row=pages,
                                     impl=impl)

@@ -367,8 +367,6 @@ class MLAttention(Layer):
         Hi, Di, topk = cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk
         rope, qk, scale = cfg.rope, self.qk_dim, cfg.softmax_scale
         full, n_cache = self.indexer is not None, len(cache)
-        if paged is not None:
-            paged = paged[:4]
 
         def attn(qa, ca, kr, w_kvb, *rest):
             # behind the caches and the position: a "full" layer's index
@@ -507,11 +505,7 @@ class DeepseekModel(Layer):
         if pack is not None:
             live = pack.live[:, None]
         elif paged is not None:
-            # column t of row b is a real token while pos[b] + t is short
-            # of the row's length after this step (`paged[1]`)
-            t = jnp.arange(input_ids.shape[1], dtype=jnp.int32)
-            live = jnp.reshape(getattr(pos, "data", pos), (-1, 1)) + t \
-                < jnp.reshape(paged[1], (-1, 1))
+            live = paged.live(getattr(pos, "data", pos), input_ids.shape[1])
         new_caches = []
         for layer, cache in zip(self.layers, caches):
             hidden, new_cache, sel = layer(hidden, cache=cache, pos=pos,

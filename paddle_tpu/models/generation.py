@@ -16,7 +16,7 @@ per row to batch-locked greedy generate().
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Dict, FrozenSet, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -190,6 +190,70 @@ class IndexedLatentKV(NamedTuple):
     k_index: jax.Array
 
 
+class RecurrentStateError(NotImplementedError):
+    """Asked of a cache manager that holds recurrent per-slot state: an
+    operation that rebuilds a row from its pages (a rewind, a page copy, an
+    export or import). Snapshots of the state are later work (ROADMAP)."""
+
+
+class WindowRingError(NotImplementedError):
+    """Asked of a cache manager that keeps a window layer's keys in a ring:
+    an operation that reads, copies or shares pages by their logical block
+    (prefix sharing, a page copy, an export or import), or a rewind past
+    the ring's slack. Prefix reuse over window layers is later work
+    (ROADMAP)."""
+
+
+# What a cache manager can be asked that a kind may have to refuse:
+REREAD = "pages"            # re-reading or restoring a row's pages by their
+#                             logical block: prefix sharing, an imported
+#                             `kv_row`, page copies, exports and imports, a
+#                             rewind past the step's write pad
+HOST_TIER = "host_kv_bytes"  # spilling pages to the host and back
+REWIND = "draft_model"      # taking back the positions of a rejected draft
+#                             window (at most the step's write pad)
+
+
+class CacheKind(NamedTuple):
+    """A row of `CACHE_KINDS`: all that the serving pool and the engine
+    know of what a layer keeps per slot."""
+    name: str                   # what `SlotPagedKVPool.layer_kinds` says
+    bytes_as: Tuple[Optional[str], ...]   # per array of the entry, the
+    #                             label its bytes are reported under
+    #                             (`kv_bytes()`); None: per-slot state,
+    #                             reported apart (`recurrent_state_bytes`)
+    refuses: FrozenSet[str] = frozenset()   # of the three above
+    why: str = ""               # the one sentence that says why
+    error: type = NotImplementedError   # what the pool raises with it
+
+
+# By the type of a layer's `init_cache` entry; a plain `(k, v)` is "paged".
+# A new kind is a NamedTuple above and a row here: the pool and the engine
+# read what they refuse, and what they call its bytes, from the row.
+CACHE_KINDS: Dict[type, CacheKind] = {
+    tuple: CacheKind("paged", ("full", "full")),
+    RecurrentState: CacheKind(
+        "recurrent", (None, None), frozenset({REREAD, HOST_TIER, REWIND}),
+        "a recurrence's state exists only at a row's committed length: it "
+        "cannot be rebuilt from pages, and pages carry none of it",
+        RecurrentStateError),
+    WindowKV: CacheKind(
+        "window", ("window", "window"), frozenset({REREAD, HOST_TIER}),
+        "a window layer's keys live in a ring, where a page older than the "
+        "window has been overwritten", WindowRingError),
+    LatentKV: CacheKind("latent", ("latent", "latent")),
+    IndexedLatentKV: CacheKind(
+        "indexed", ("latent", "latent", "index"), frozenset({HOST_TIER}),
+        "index-key pages have no place in the host tier, which holds (k, v) "
+        "pairs; sparse reads from it are later work"),
+}
+
+
+def kind_of(entry) -> CacheKind:
+    """The row of `CACHE_KINDS` for one layer's `init_cache` entry."""
+    return CACHE_KINDS.get(type(entry), CACHE_KINDS[tuple])
+
+
 def make_decoder_fns(model):
     """Expose the prefill/decode-step builders for a cached-decode model.
 
@@ -210,10 +274,10 @@ def make_decoder_fns(model):
     slab a token hands a triple). The model is captured for
     its buffers/structure; call with the model already in eval mode.
 
-    Both functions accept an optional `paged=(block_table [B, max_blocks],
-    seq_lens [B], block_len, pages_per_row)` routing attention through the
-    ragged paged kernel against slot-pool page tables (ISSUE 7; the
-    engine's chunked-prefill mixed dispatch). Left as None, attention runs
+    Both functions accept an optional `paged` (`ops.attention.PagedView`:
+    block table `[B, max_blocks]`, `seq_lens [B]`, the page geometry)
+    routing attention through the ragged paged kernel against slot-pool
+    page tables (ISSUE 7; the engine's chunked-prefill mixed dispatch). Left as None, attention runs
     the trivial contiguous-table path — the same kernel, so streams stay
     bit-identical across the two callers at a shared block size.
 

@@ -182,13 +182,13 @@ class GraniteMoeHybridModel(Layer):
                 hidden = layer(hidden)
             return self.norm(hidden)
         # what the token-wise code and the per-slot state need to know of a
-        # serving step (`paged[1]`: each row's length after the step): which
-        # positions hold a token, and how many of a row's columns do
+        # serving step: which positions hold a token, and how many of a
+        # row's columns do
         adv = live = None
         if paged is not None:
             slot_pos = pack.slot_pos if pack is not None \
                 else jnp.reshape(getattr(pos, "data", pos), (-1,))
-            adv = jnp.reshape(paged[1], (-1,)) - slot_pos
+            adv = paged.advance(slot_pos)
             live = pack.live[:, None] if pack is not None else \
                 jnp.arange(input_ids.shape[1], dtype=jnp.int32) \
                 < adv[:, None]

@@ -322,21 +322,17 @@ class LlamaAttention(Layer):
         behind it.
 
         A window layer (`self.window`): with `paged` (the serving pool's
-        step) its cache is a ring of `paged[4]` pages, written at `pos mod
-        ring` and read from the window's first block; without, a
+        step) its cache is a ring of `paged.ring_pages` pages, written at
+        `pos mod ring` and read from the window's first block; without, a
         full-length cache read the same way (`generate()`)."""
         k_cache, v_cache = cache
         window, rope_params = self.window, self.rope
         ring = None
         if window is not None and paged is not None:
-            _, seq_lens, block_len, pages_per_row, ring_pages = paged
-            ring = int(ring_pages) * int(block_len)
-            # positions the rotary table must reach: the slot's capacity
-            # and the chunk-wide stripe a free row writes past it
-            positions = int(pages_per_row) * int(block_len)
-            paged = (None, seq_lens, block_len, ring_pages)
-        elif paged is not None:
-            paged = paged[:4]
+            # the rotary table reaches the slot's capacity and the
+            # chunk-wide stripe a free row writes past it
+            ring, positions = paged.ring, paged.positions
+            paged = paged.window_view()
 
         def attn_dec(qa, ka, va, kc, vc, pos_):
             import jax.numpy as jnp
@@ -480,13 +476,8 @@ class LlamaModel(Layer):
             if pack is not None:
                 live = pack.live[:, None]
             elif paged is not None and self.config.num_experts:
-                # column t of row b is a real token while pos[b] + t is
-                # short of the row's length after this step (`paged[1]`):
-                # the rest of a decode row, and all of a free slot, is
-                # padding
-                t = jnp.arange(input_ids.shape[1], dtype=jnp.int32)
-                live = jnp.reshape(getattr(pos, "data", pos), (-1, 1)) + t \
-                    < jnp.reshape(paged[1], (-1, 1))
+                live = paged.live(getattr(pos, "data", pos),
+                                  input_ids.shape[1])
             new_caches = []
             for i, (layer, cache) in enumerate(zip(self.layers, caches)):
                 layer_ad = None if adapters is None else (

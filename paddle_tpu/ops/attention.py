@@ -879,6 +879,49 @@ def token_pack(adv, pos, chunk: int, step_tokens: int) -> TokenPack:
                      last=jnp.maximum(end - 1, 0), slot_pos=pos)
 
 
+class PagedView(NamedTuple):
+    """The page operand of one serving step, as every layer of the model is
+    handed it (`paged=`): where each row's pages lie and how long the row is
+    once the step has run. `SlotPagedKVPool.view` builds it inside the
+    step; the three integers are Python integers the trace closes over."""
+    table: Optional[jax.Array]   # [rows, pages_per_row] page ids; None: each
+    #                              row's own pages in their order (a ring)
+    seq_lens: jax.Array          # [rows] a row's length after this step
+    block_len: int               # tokens a page
+    pages_per_row: int           # pages a row's table addresses
+    ring_pages: Optional[int] = None   # pages of a window layer's ring, on
+    #                              a pool that keeps one
+
+    @property
+    def ring(self) -> int:
+        """Columns of a window layer's ring."""
+        return int(self.ring_pages) * int(self.block_len)
+
+    @property
+    def positions(self) -> int:
+        """Positions a row can hold: what a rotary table must reach, short
+        of the chunk-wide stripe a free row writes past it."""
+        return int(self.pages_per_row) * int(self.block_len)
+
+    def window_view(self) -> "PagedView":
+        """A window layer's view of itself: no table, the ring's pages."""
+        return PagedView(None, self.seq_lens, self.block_len,
+                         self.ring_pages)
+
+    def advance(self, pos):
+        """`[rows]`: the columns of a row at `pos [rows]` that hold a token
+        (none for a free slot)."""
+        return jnp.reshape(self.seq_lens, (-1,)) - pos
+
+    def live(self, pos, width: int):
+        """`[rows, width]` bool: column t of a row at `pos [rows]` holds a
+        token while `pos + t` is short of the row's length after the step;
+        the rest of a decode row, and all of a free slot, is padding."""
+        t = jnp.arange(width, dtype=jnp.int32)
+        return jnp.reshape(pos, (-1, 1)) + t \
+            < jnp.reshape(self.seq_lens, (-1, 1))
+
+
 def _ring_write(cache, new, pos, ring: int):
     """One row: `new [Hkv, T, D]` at column `pos mod ring` of a ring of
     `ring` columns that lies in `cache [Hkv, ring + T, D]`. The stripe is
@@ -985,17 +1028,17 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
 
     Both shapes route through `ops.paged_attention.ragged_paged_attention`
     (ISSUE 7): with `paged=None` each row attends its own contiguous cache
-    via a trivial block table at DEFAULT_KV_BLOCK; `paged=(block_table,
-    seq_lens, block_len)` addresses slot-pool pages directly (the serving
-    engine's chunked-prefill/decode mixed dispatch). One numeric path means
+    via a trivial block table at DEFAULT_KV_BLOCK; `paged` (a `PagedView`)
+    addresses slot-pool pages directly (the serving engine's
+    chunked-prefill/decode mixed dispatch). One numeric path means
     continuous-batched streams stay bit-identical to one-shot generate()
     whenever both sides use the same kv block size — the flash-accumulation
     grouping, and therefore the bits, depend on block_len alone.
 
     `window=W`: a query sees the W keys up to itself. With `paged` the
-    caches are then rings (`update_kv_cache(ring=)`): `pages_per_row` is
-    the ring's pages and a `block_table` of None each row's own ring; left
-    as None the contiguous cache is walked from the window's first block.
+    caches are then rings (`update_kv_cache(ring=)`) and `paged` the
+    layer's `PagedView.window_view()`; left as None the contiguous cache is
+    walked from the window's first block.
     Either way the blocks walked are the logical ones, so both give the
     same bits at one block size.
 
@@ -1018,11 +1061,11 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
     if paged is not None:
         # pool slabs may carry chunk write-padding past the page region,
         # so the caller names the addressable page geometry explicitly
-        block_table, seq_lens, block_len, pages_per_row = paged
         return walk(
-            q, k_cache, v_cache, block_table, seq_lens, jnp.asarray(pos),
-            block_len=int(block_len), pages_per_row=int(pages_per_row),
-            scale=scale, window=window, q_rope=q_rope)
+            q, k_cache, v_cache, paged.table, paged.seq_lens,
+            jnp.asarray(pos), block_len=int(paged.block_len),
+            pages_per_row=int(paged.pages_per_row), scale=scale,
+            window=window, q_rope=q_rope)
     (k_cache, v_cache), table, seq_lens, q_pos, nb = contiguous_paged(
         (k_cache, v_cache), pos, T)
     return walk(q, k_cache, v_cache, table, seq_lens, q_pos,

@@ -318,7 +318,8 @@ def select(q, w, k_cache, pos, topk: int, paged=None) -> Selection:
     `ops.attention.decode_attention` takes them."""
     B, _, T, _ = q.shape
     if paged is not None:
-        block_table, seq_lens, block_len, pages_per_row = paged[:4]
+        block_table, seq_lens = paged.table, paged.seq_lens
+        block_len, pages_per_row = paged.block_len, paged.pages_per_row
         q_pos = jnp.broadcast_to(jnp.asarray(pos), (B,)).astype(jnp.int32)
     else:
         block_len = DEFAULT_KV_BLOCK

@@ -43,8 +43,6 @@ table) redirects. Prefix sharing is therefore expressed as:
   invokes the `on_pressure` hook so the cache can evict refcount-0
   entries LRU-first, and pages with live readers are structurally
   un-evictable;
-- `defrag` is refcount-aware at PAGE granularity: it scrubs the stale
-  columns of freed rows while leaving cached pages bit-intact;
 - the ledger extends from slots to blocks: every page ever claimed is
   freed, active, or cached — `check_balance()` proves both ledgers.
 
@@ -54,56 +52,43 @@ lives. When the slot frees, each own page either transfers to the cache
 page frees it. `blocks_allocated == blocks_freed + blocks_active +
 blocks_cached` at every quiescent point.
 
-A second kind of per-slot state (PR 29). A layer whose `init_cache` entry
-is a `models.generation.RecurrentState` (a state-space mixer) keeps one
-fixed block per slot, `(conv [slots, K - 1, channels], ssm [slots, N,
-H * P])`: not paged, never shared, valid only at the row's committed
-length, zeroed inside the step when a row starts at position 0. It sits
-in `slabs` at its layer's index and rides the step as operand and result
-exactly as the K/V slabs do; `layer_kinds` says which is which. Nothing
-that re-reads, copies or trims pages can serve such a layer: on a pool
-that holds one (`recurrent`), `rewind_length`, `cow_copy`, `export_rows`
-/ `import_rows` and `export_page` / `import_page` raise
-`RecurrentStateError`, and `defrag` leaves the state alone (a freed
-slot's state is dead: the next row in it starts from zero).
+What a layer keeps per slot. `models.generation.CACHE_KINDS` declares each
+kind once, by the type of the layer's `init_cache` entry: its name
+(`layer_kinds`), the label each of its arrays' bytes are counted under
+(`kv_bytes()`, `recurrent_state_bytes`), what it refuses and the sentence
+that says why. The pool reads the row; `refusal(feature, what)` is the one
+place a refusal is worded, for the pool's own operations (which raise the
+row's error) and for the engine's features (which quote it). An entry of
+`slabs` is a tuple of the entry's arrays, at its layer's index, and rides
+the step as operand and result whatever its kind. The kinds:
 
-A third kind: a ring (PR 31). A layer that attends a sliding window
-(`init_cache(window_slab=)` answers with a `models.generation.WindowKV`)
-keeps `ring_len` = window + `pad_tokens` columns a slot, rounded up to
-whole pages and to whole chunks, not `capacity`: position p lives at
-column `p mod ring_len` of the slot's own row, and the step's chunk-wide
-stripe overwrites only keys that have left every live query's window
-(that is what the `pad_tokens` of slack are for). `lengths`, the block
-table and the ledger stay logical: they count the row's positions, as the
-full layers hold them. What the ring has overwritten cannot be re-read,
-so on a pool that holds one (`windowed`) prefix sharing (`attach_blocks`,
-`register_cached`), `cow_copy`, `export_rows` / `import_rows`,
-`export_page` / `import_page` and a `rewind_length` of more than the slack
-raise `WindowRingError`; `defrag` scrubs a freed row's whole ring.
-
-A fourth kind: latent pages (PR 36). A layer of multi-head latent
-attention (`init_cache` answers with a `models.generation.LatentKV`) keeps
-per token one compressed latent and one rotary key shared by every head,
-`(c [slots, 1, slab_len, kv_lora_rank], r [slots, 1, slab_len,
-qk_rope_head_dim])`: a pair of unequal widths with one "head", addressed
-by position exactly as `(k, v)` is. So it is paged like `paged`:
-`attach_blocks`, `register_cached`, `cow_copy`, `export_*` / `import_*`,
-`rewind_length` and `defrag` work on it as they are, the prefix cache
-stays on, nothing is refused; `layer_kinds` says `latent` and `kv_bytes()`
-counts it under its own name.
-
-A fifth kind: index-key pages (PR 39). A latent layer that carries an
-indexer (learned sparse attention; `init_cache` answers with a
-`models.generation.IndexedLatentKV`) keeps a third slab beside `c` and `r`:
-`k_index [slots, 1, slab_len, index_head_dim]`, the one key a token its
-indexer scores queries against. Its pages are numbered as the latent pages
-are (page p of the index slab is page p of `c` and of `r`: one block table,
-one ledger), so whatever moves, shares, pins, exports or scrubs a page does
-it to all three slabs of such a layer: `attach_blocks`, `cow_copy`,
-`register_cached` / `release_cached`, `export_rows` / `import_rows`,
-`export_page` / `import_page`, `defrag`. `layer_kinds` says `indexed`, and
-`kv_bytes()` counts `c` and `r` under "latent" and the third slab under
-"index". An entry of `slabs` is a tuple of two arrays or of three.
+- `paged`: `(k, v)` slabs `[slots, Hkv, slab_len, D]`. Nothing is refused.
+- `recurrent` (`RecurrentState`, a state-space mixer): one fixed block per
+  slot, `(conv [slots, K - 1, channels], ssm [slots, N, H * P])`: not paged,
+  never shared, valid only at the row's committed length, zeroed inside the
+  step when a row starts at position 0. Whatever re-reads, copies or trims
+  pages raises `RecurrentStateError`: `rewind_length`, `attach_blocks`,
+  `register_cached`, `cow_copy`, `export_rows`, `export_page` /
+  `import_page`.
+- `window` (`WindowKV`, answered to `init_cache(window_slab=)`): a ring of
+  `ring_len` = window + `pad_tokens` columns a slot, rounded up to whole
+  pages and to whole chunks, not `capacity`: position p lives at column
+  `p mod ring_len` of the slot's own row, and the step's chunk-wide stripe
+  overwrites only keys that have left every live query's window (that is
+  what the `pad_tokens` of slack are for). `lengths`, the block table and
+  the ledger stay logical: they count the row's positions, as the full
+  layers hold them. The same operations raise `WindowRingError`, but a
+  `rewind_length` of no more than the slack is served.
+- `latent` (`LatentKV`, multi-head latent attention): per token one
+  compressed latent and one rotary key shared by every head, `(c [slots,
+  1, slab_len, kv_lora_rank], r [slots, 1, slab_len, qk_rope_head_dim])`:
+  a pair of unequal widths with one "head", addressed by position exactly
+  as `(k, v)` is, so every page operation works on it as it is.
+- `indexed` (`IndexedLatentKV`, a latent layer with an indexer): a third
+  slab beside `c` and `r`, `k_index [slots, 1, slab_len, index_head_dim]`.
+  Its pages are numbered as the latent pages are (one block table, one
+  ledger), so whatever moves, shares, pins or exports a page does it to
+  all three slabs of such a layer.
 
 Which row a request gets (PR 39). A request that attaches its leading `n`
 blocks writes only from block `n` on, so a free row is fit for it when none
@@ -124,26 +109,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.generation import (IndexedLatentKV, LatentKV, RecurrentState,
-                                  WindowKV)
+from ...models.generation import (CACHE_KINDS, REREAD, REWIND,  # noqa: F401
+                                  RecurrentStateError, WindowRingError,
+                                  kind_of)
+from ...ops.attention import PagedView
 
+# the names `layer_kinds` holds (`models.generation.CACHE_KINDS`)
 PAGED, RECURRENT, WINDOW, LATENT = "paged", "recurrent", "window", "latent"
 INDEXED = "indexed"      # latent pages and, beside them, index-key pages
-
-
-class RecurrentStateError(NotImplementedError):
-    """Asked of a pool that holds recurrent per-slot state: an operation
-    that rebuilds a row from its pages (a rewind, a page copy, an export
-    or import). A recurrence's state exists only at the row's committed
-    length; snapshots of it are later work (ROADMAP)."""
-
-
-class WindowRingError(NotImplementedError):
-    """Asked of a pool that keeps a window layer's keys in a ring: an
-    operation that reads, copies or shares pages by their logical block
-    (prefix sharing, a page copy, an export or import), or a rewind past
-    the ring's slack. Keys older than the window have been overwritten;
-    prefix reuse over window layers is later work (ROADMAP)."""
 
 
 class SlotsExhaustedError(RuntimeError):
@@ -192,20 +165,16 @@ class SlotPagedKVPool:
         entries = list(init_cache_fn(self.num_slots, self.slab_len,
                                      **kwargs))
         # what each layer keeps per slot, told by its entry's type
-        self.layer_kinds: List[str] = [
-            RECURRENT if isinstance(e, RecurrentState)
-            else WINDOW if isinstance(e, WindowKV)
-            else LATENT if isinstance(e, LatentKV)
-            else INDEXED if isinstance(e, IndexedLatentKV) else PAGED
-            for e in entries]
+        self._kinds = [kind_of(e) for e in entries]
+        self.layer_kinds: List[str] = [kind.name for kind in self._kinds]
         self.recurrent = RECURRENT in self.layer_kinds
         self.windowed = WINDOW in self.layer_kinds
-        self.indexed = INDEXED in self.layer_kinds
-        self.latent = self.indexed or LATENT in self.layer_kinds
+        self._latent = bool({LATENT, INDEXED} & set(self.layer_kinds))
         # the window layers' ring, in columns and pages (None: no such
         # layer); every window layer of a model has the one window
-        rings = {int(e.k.shape[2]) - self.pad_tokens
-                 for e in entries if isinstance(e, WindowKV)}
+        rings = {int(e[0].shape[2]) - self.pad_tokens
+                 for e, kind in zip(entries, self.layer_kinds)
+                 if kind == WINDOW}
         if len(rings) > 1:
             raise ValueError(f"window layers of different rings: {rings}")
         self.ring_len: Optional[int] = rings.pop() if rings else None
@@ -221,9 +190,9 @@ class SlotPagedKVPool:
         self.slabs_lock = threading.Lock()
         self.lengths = np.zeros((self.num_slots,), np.int32)
         self.active = np.zeros((self.num_slots,), bool)
-        # freed-but-not-scrubbed rows: their non-cached pages still hold
-        # stale KV until defrag() zeroes them (hygiene, not correctness —
-        # prefill overwrites the written range on reuse)
+        # rows freed and not handed out again since: their pages hold the
+        # last sequence's KV until the next one's prefill overwrites it
+        # (`allocate` counts such a row as a reuse)
         self.dirty = np.zeros((self.num_slots,), bool)
         # slot -> global page ids backing its current length: leading
         # entries may be attached (shared) pages in other rows, the rest
@@ -244,10 +213,9 @@ class SlotPagedKVPool:
         # `on_pressure(keep_below, free rows)`) and returns pages released
         self.on_pressure: Optional[Callable[..., int]] = None
         self.stats = {"allocs": 0, "frees": 0, "reuses": 0,
-                      "alloc_failures": 0, "defrags": 0, "peak_active": 0,
+                      "alloc_failures": 0, "peak_active": 0,
                       "blocks_allocated": 0, "blocks_freed": 0,
                       "cow_copies": 0}
-        self._scrub = None   # lazily-jitted defrag kernel (page mask)
         self._cow = None     # lazily-jitted copy-on-write block copy
         # device-array mirrors for the ragged kernel: identity stripes
         # (slot s owns global pages s*n_blocks..s*n_blocks+n_blocks-1)
@@ -274,28 +242,23 @@ class SlotPagedKVPool:
         """Bytes of the K/V slabs by what they are: "full" (a slot's whole
         context), "window" (a ring) and, on a pool that holds one,
         "latent" (a slot's whole context as a latent and a rotary key) and
-        "index" (as an index key), all slots and layers."""
-        names = {PAGED: "full", WINDOW: "window", LATENT: "latent",
-                 INDEXED: "latent"}
+        "index" (as an index key), all slots and layers: each array under
+        the label its kind's row gives it."""
         out = {"full": 0, "window": 0}
-        if self.latent:
-            out["latent"] = 0
-        if self.indexed:
-            out["index"] = 0
-        for entry, kind in zip(self.slabs, self.layer_kinds):
-            if kind == RECURRENT:
-                continue
-            out[names[kind]] += int(entry[0].nbytes) + int(entry[1].nbytes)
-            if kind == INDEXED:
-                out["index"] += int(entry[2].nbytes)
+        for entry, kind in zip(self.slabs, self._kinds):
+            for a, label in zip(entry, kind.bytes_as):
+                if label is not None:
+                    out[label] = out.get(label, 0) + int(a.nbytes)
         return out
 
     @property
     def recurrent_state_bytes(self) -> int:
-        """Bytes of the recurrent layers' per-slot state, all slots."""
+        """Bytes of the per-slot state that is no page (a recurrent layer's:
+        the arrays whose row gives them no label), all slots."""
         return sum(int(a.nbytes) for entry, kind
-                   in zip(self.slabs, self.layer_kinds)
-                   if kind == RECURRENT for a in entry)
+                   in zip(self.slabs, self._kinds)
+                   for a, label in zip(entry, kind.bytes_as)
+                   if label is None)
 
     def consumed(self) -> bool:
         """Whether a dispatch that was donated the slabs took them: asked
@@ -310,22 +273,57 @@ class SlotPagedKVPool:
         self.slabs = [tuple(jnp.zeros(a.shape, a.dtype) for a in entry)
                       for entry in self.slabs]
 
-    def _refuse_reread(self, what: str):
+    def refusal(self, feature: str, what: str) -> Optional[Exception]:
+        """The error that refuses `what`, a use of `feature` (`REREAD`,
+        `HOST_TIER`, `REWIND`), on this pool: the sentence of the first
+        kind, in the table's order, that some layer here is of and that
+        refuses the feature. None where no layer does."""
+        for kind in CACHE_KINDS.values():
+            n = self.layer_kinds.count(kind.name)
+            if n and feature in kind.refuses:
+                ring = "" if self.ring_len is None else \
+                    f" (a ring of {self.ring_len} columns a slot)"
+                return kind.error(
+                    f"{what} of which {n} of {len(self.layer_kinds)} layers "
+                    f"are {kind.name} layers{ring}: {kind.why}")
+        return None
+
+    def _refuse_reread(self, what: str, feature: str = REREAD):
         """Refuse `what`, which re-reads a row's pages, on a pool some of
         whose layers do not keep them."""
+        err = self.refusal(feature, f"{what} on a pool")
+        if err is not None:
+            raise err
+
+    def view(self, table, seq_lens) -> PagedView:
+        """The page operand of a step over this pool: `table` and the
+        rows' lengths after the step with the pool's own geometry."""
+        return PagedView(table, seq_lens, self.block_len, self.n_blocks,
+                         self.ring_pages)
+
+    def step_counts(self, pos: np.ndarray, adv: np.ndarray
+                    ) -> Tuple[dict, int, Tuple[int, int]]:
+        """What a step over rows at `pos` with `adv` live columns means to
+        each kind of layer here: the kinds' arguments of the dispatch span,
+        the rows whose recurrent state the step starts from zero, and the
+        keys its attention calls must read, (one window layer's call, one
+        full or latent layer's): a row's length after the step and, on a
+        pool with a ring, the part of it inside the window."""
+        live = adv > 0
+        after = (pos + adv)[live]
+        args, started, in_window = {}, 0, 0
         if self.recurrent:
-            raise RecurrentStateError(
-                f"{what}: this pool holds recurrent state "
-                f"({self.layer_kinds.count(RECURRENT)} of "
-                f"{len(self.layer_kinds)} layers), which exists only at a "
-                "row's committed length and cannot be rebuilt from pages")
+            args["recurrent_rows"] = int(after.size)
+            started = int(np.count_nonzero(live & (pos == 0)))
         if self.windowed:
-            raise WindowRingError(
-                f"{what}: this pool keeps "
-                f"{self.layer_kinds.count(WINDOW)} of "
-                f"{len(self.layer_kinds)} layers' keys in a ring of "
-                f"{self.ring_len} columns a slot; a page older than the "
-                "window has been overwritten")
+            in_window = int(np.minimum(after, self.window).sum())
+            args["window_rows"] = int(after.size)
+            # rows whose ring has begun to overwrite its oldest keys
+            args["wrapped_rows"] = int(np.count_nonzero(
+                after > self.ring_len))
+        if self._latent:
+            args["latent_rows"] = int(after.size)
+        return args, started, (in_window, int(after.sum()))
 
     def _identity_table(self) -> np.ndarray:
         return (np.arange(self.num_slots, dtype=np.int32)[:, None]
@@ -490,12 +488,14 @@ class SlotPagedKVPool:
             raise ValueError(f"slot {slot} is not active")
         length = int(length)
         cur = int(self.lengths[slot])
-        # a ring gives back what its slack holds: the stripe of a rejected
-        # draft window overwrote nothing a query still sees (on a pool of
+        # inside the write pad it is a rejected draft window: a ring gives
+        # that back (the stripe overwrote nothing a query still sees), a
+        # recurrence cannot; past the pad it re-reads pages (on a pool of
         # paged layers alone nothing is refused)
-        if length < cur and (self.recurrent
-                             or cur - length > self.pad_tokens):
-            self._refuse_reread(f"rewind_length by {cur - length}")
+        if length < cur:
+            self._refuse_reread(
+                f"rewind_length by {cur - length}",
+                REWIND if cur - length <= self.pad_tokens else REREAD)
         if length > cur:
             raise ValueError(
                 f"rewind_length can only shrink: {length} > committed "
@@ -532,7 +532,7 @@ class SlotPagedKVPool:
         at its logical block offset (`page % n_blocks == j` — the write
         path guarantees a slot's block j is physically at column j of its
         own row, so cached pages always satisfy this)."""
-        if self.windowed and pages:
+        if pages:
             self._refuse_reread("attach_blocks")
         if not self.active[slot]:
             raise ValueError(f"slot {slot} is not active")
@@ -597,9 +597,8 @@ class SlotPagedKVPool:
 
     def register_cached(self, page: int):
         """Pin a page on behalf of the prefix cache: its row leaves the
-        allocatable set and defrag will never scrub its columns."""
-        if self.windowed:
-            self._refuse_reread("register_cached")
+        allocatable set."""
+        self._refuse_reread("register_cached")
         if not (0 <= page < self.num_slots * self.n_blocks):
             raise ValueError(f"page {page} out of range")
         if page in self.cached:
@@ -612,7 +611,7 @@ class SlotPagedKVPool:
     def release_cached(self, page: int):
         """Cache eviction: unpin a page. Refuses while readers hold it.
         A cache-owned page (its slot freed) settles to the freed side of
-        the block ledger; its row becomes scrub-eligible again."""
+        the block ledger; a free row it sat in is `dirty` again."""
         if page not in self.cached:
             raise ValueError(f"page {page} is not cache-registered")
         if self.refcount.get(page, 0) > 0:
@@ -696,7 +695,8 @@ class SlotPagedKVPool:
         return len(self.cached)
 
     def dirty_blocks(self) -> int:
-        """Scrubable pages: pages of freed rows NOT pinned by the cache."""
+        """Pages that hold a finished sequence's KV and nobody's claim:
+        those of `dirty` rows that the cache does not pin."""
         total = 0
         for r in np.flatnonzero(self.dirty):
             base = int(r) * self.n_blocks
@@ -774,8 +774,9 @@ class SlotPagedKVPool:
         arrays assembled page-by-page through the block table (attached
         shared pages read from their physical row, exactly as the ragged
         kernel would). The payload is self-describing enough for
-        `import_rows` on ANOTHER pool with the same slab geometry — the
-        groundwork for prefill/decode-disaggregated KV handoff. KV alone
+        ANOTHER pool with the same slab geometry to land it page by page
+        (`import_page`, the engine's `kv_row`): prefill/decode-
+        disaggregated KV handoff. KV alone
         is not enough to resume a SAMPLED stream bit-identically: pair
         this payload with `LLMEngine.export_sampling_lanes` (ISSUE 18),
         which carries each slot's RNG-lane index and grammar DFA state."""
@@ -876,75 +877,3 @@ class SlotPagedKVPool:
         return tuple(jax.lax.dynamic_update_slice(
             a, jnp.asarray(e, dtype=a.dtype)[None], (slot, 0, c0, 0))
             for a, e in zip(entry, payload))
-
-    def import_rows(self, exported: dict) -> Dict[int, int]:
-        """Materialize `export_rows` payload rows into THIS pool: each
-        exported row allocates a fresh slot, commits its length (own
-        identity pages — attachment structure is not preserved, the KV
-        bytes are), and lands the K/V columns bitwise via
-        dynamic_update_slice. Returns {source_slot: destination_slot}."""
-        self._refuse_reread("import_rows")
-        if int(exported["block_len"]) != self.block_len:
-            raise ValueError(
-                f"block_len mismatch: exported {exported['block_len']} "
-                f"vs pool {self.block_len}")
-        mapping: Dict[int, int] = {}
-        for src in sorted(exported["rows"]):
-            row = exported["rows"][src]
-            length = int(row["length"])
-            if length > self.capacity:
-                raise ValueError(
-                    f"row {src} holds {length} tokens but this pool's "
-                    f"capacity is {self.capacity}")
-            dst = self.allocate(length)
-            self.set_length(dst, length)
-            if length > 0:
-                self.slabs = [self._land(entry, payload, dst, 0)
-                              for entry, payload
-                              in zip(self.slabs, row["layers"])]
-            mapping[int(src)] = dst
-        return mapping
-
-    # ---- hygiene ----
-    def defrag(self) -> int:
-        """Scrub stale KV out of freed rows (one jitted masked multiply
-        over each slab) and return the number of pages reclaimed.
-        Refcount-aware at PAGE granularity: a freed row whose pages the
-        prefix cache still pins keeps those pages' columns bit-intact —
-        shared blocks are never scrubbed — while the rest of the row is
-        zeroed. Purely hygienic — correctness never depends on it because
-        prefill overwrites the written range on reuse — but it keeps
-        dirty blocks from aging in HBM snapshots and makes the free-block
-        gauge mean 'zeroed and ready'."""
-        rows = np.flatnonzero(self.dirty)
-        if rows.size == 0:
-            return 0
-        keep = np.ones((self.num_slots, self.slab_len), np.float32)
-        reclaimed = 0
-        for r in rows:
-            keep[r, :] = 0.0
-            base = int(r) * self.n_blocks
-            for j in range(self.n_blocks):
-                if (base + j) in self.cached:
-                    keep[r, j * self.block_len:(j + 1) * self.block_len] = 1.0
-                else:
-                    reclaimed += 1
-        if reclaimed == 0:
-            return 0
-        if self._scrub is None:
-            self._scrub = jax.jit(
-                lambda slab, keep: slab * keep[:, None, :, None])
-        # a ring holds no cached page (`register_cached` refuses): a freed
-        # row's ring goes whole
-        masks = {PAGED: jnp.asarray(keep)}
-        masks[LATENT] = masks[INDEXED] = masks[PAGED]
-        if self.windowed:
-            masks[WINDOW] = jnp.asarray(
-                np.repeat(keep[:, :1], self.ring_len + self.pad_tokens, 1))
-        self.slabs = [tuple(self._scrub(a, masks[kind].astype(a.dtype))
-                            for a in entry)
-                      if kind != RECURRENT else entry
-                      for entry, kind in zip(self.slabs, self.layer_kinds)]
-        self.dirty[:] = False
-        self.stats["defrags"] += 1
-        return reclaimed

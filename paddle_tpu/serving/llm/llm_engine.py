@@ -157,6 +157,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...models.generation import HOST_TIER, REREAD, REWIND
 from ...nn.layer import moe
 from ...obs.flight_recorder import flight_recorder
 from ...obs.trace import RequestTrace, TimelineStore, new_request_id
@@ -173,7 +174,7 @@ from ..metrics import LLMMetrics, SLO_CLASSES
 from ..supervisor import (DispatchFailedError, DispatchHungError,  # noqa: F401
                           EngineSupervisor)
 from .host_kv import HostKVPool
-from .kv_pool import SlotPagedKVPool, SlotsExhaustedError
+from .kv_pool import INDEXED, SlotPagedKVPool, SlotsExhaustedError
 from .lora import AdapterBank, AdapterError
 from .prefix_cache import PrefixCache
 from .sampling import (GREEDY, SamplingParams, SlotSamplingTable,
@@ -247,9 +248,6 @@ class LLMEngineConfig:
     dispatch_retries: int = 2      # whole-step retries before blame/fail
     breaker_threshold: int = 3     # consecutive engine-level failures that
     #                                open the circuit breaker
-    # ---- observability (ISSUE 9) ----
-    trace_buffer: int = 256        # finished request timelines kept for
-    #                                /debug/requests/<rid> (bounded LRU)
     # ---- serving economics (ISSUE 11) ----
     economics: bool = False        # arm the ServingLedger + SLOBurnMonitor;
     #                                off = one predicate per hook, no clock
@@ -288,8 +286,6 @@ class LLMEngineConfig:
     #                                signature, so it is pre-allocated — a
     #                                request needing a 9th grammar rejects
     #                                instead of recompiling the step
-    max_dfa_states: int = 128      # per-grammar token-DFA state ceiling
-    #                                (same fixed-shape reasoning)
     # ---- tiered KV cache (ISSUE 19) ----
     host_kv_bytes: int = 0         # host-RAM spill tier byte budget: > 0
     #                                arms a bounded LRU HostKVPool that
@@ -349,17 +345,11 @@ class LLMEngineConfig:
             raise ValueError(
                 f"breaker_threshold must be >= 1, got "
                 f"{self.breaker_threshold}")
-        if self.trace_buffer < 1:
-            raise ValueError(
-                f"trace_buffer must be >= 1, got {self.trace_buffer}")
         if self.spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {self.spec_k}")
         if self.max_grammars < 1:
             raise ValueError(
                 f"max_grammars must be >= 1, got {self.max_grammars}")
-        if self.max_dfa_states < 1:
-            raise ValueError(
-                f"max_dfa_states must be >= 1, got {self.max_dfa_states}")
         if self.host_kv_bytes < 0:
             raise ValueError(
                 f"host_kv_bytes must be >= 0, got {self.host_kv_bytes}")
@@ -584,7 +574,6 @@ class LLMEngine:
         self.metrics = metrics or LLMMetrics()
         self.params, self._prefill_fn, self._decode_fn = \
             make_decoder_fns(model)
-        _, self._verify_fn = make_verify_fn(model)
         # per-slot sampling + grammar bank (ISSUE 18): sized off the
         # model's vocab — the DFA bank's last axis is a legal-token mask
         vocab_size = int(getattr(getattr(model, "config", None),
@@ -595,8 +584,7 @@ class LLMEngine:
                 "subsystem's grammar mask")
         self.sampling_table = SlotSamplingTable(
             self.config.num_slots, vocab_size,
-            max_grammars=self.config.max_grammars,
-            max_dfa_states=self.config.max_dfa_states)
+            max_grammars=self.config.max_grammars)
         # multi-LoRA bank (ISSUE 20): K stacked adapter trees + a per-slot
         # adapter_idx lane appended to the unified step's operands. None
         # unless armed, so an unarmed engine's step signature — and its
@@ -618,68 +606,40 @@ class LLMEngine:
             model.init_cache, self.config.num_slots, self.config.block_len,
             self.config.n_blocks, dtype=self.config.cache_dtype,
             pad_tokens=self.config.prefill_chunk)
-        # a model with recurrent layers (state-space mixers; told by what
-        # its `init_cache` returns, PR 29): their per-slot state exists
-        # only at a row's committed length, so nothing that restarts a row
-        # from pages it did not compute in this slot can serve it. Prefix
-        # sharing is switched off (`enable_prefix_cache` is the effective
-        # setting); the host tier, a draft model, `kv_row` imports and
-        # stream exports are refused by name
+        # what the layers keep per slot decides what can be asked of the
+        # engine (`models.generation.CACHE_KINDS`, through the pool): the
+        # host tier and a draft model are refused in the kind's own words;
+        # prefix sharing is switched off (`enable_prefix_cache` is the
+        # effective setting); `kv_row` imports and stream exports are
+        # refused where they are asked for
         self.enable_prefix_cache = self.config.enable_prefix_cache
-        if self.pool.recurrent:
-            if self.config.host_kv_bytes > 0:
-                raise ValueError(
-                    "host_kv_bytes > 0 with a model that has recurrent "
-                    "layers: a page brought back from the host tier has "
-                    "no recurrent state to go with it")
-            if draft_model is not None:
-                raise ValueError(
-                    "draft_model with a target that has recurrent layers: "
-                    "a rejected draft position cannot be rolled back out "
-                    "of a recurrence's state")
-            if self.enable_prefix_cache:
-                _log.warning(
-                    "enable_prefix_cache is switched off: the model has "
-                    "recurrent layers, and a shared prefix's pages carry "
-                    "no recurrent state")
-                self.enable_prefix_cache = False
-            self.metrics.set_recurrent_state(
-                self.pool.recurrent_state_bytes)
-        # a model with window layers (PR 31): the pool keeps their keys in
-        # a ring, and what the ring has overwritten cannot be re-read:
-        # prefix sharing is switched off, the host tier refused by name
-        # (`kv_row` imports and stream exports where they are asked for)
-        if self.pool.windowed:
-            if self.config.host_kv_bytes > 0:
-                raise ValueError(
-                    "host_kv_bytes > 0 with a model that has window "
-                    "layers: a page brought back from the host tier has "
-                    "aged out of the ring it would be written to")
-            if self.enable_prefix_cache:
-                _log.warning(
-                    "enable_prefix_cache is switched off: the model has "
-                    "window layers, whose keys the pool keeps in a ring; "
-                    "a shared prefix's pages have aged out of it")
-                self.enable_prefix_cache = False
-        # a model with learned sparse attention (PR 39): index-key pages
-        # ride with the latent pages through every page operation, so the
-        # prefix cache stays on; the host tier's pages are (k, v) pairs
+        if self.config.host_kv_bytes > 0:
+            self._refuse(self.pool, HOST_TIER,
+                         "host_kv_bytes > 0 with a model")
+        if draft_model is not None:
+            self._refuse(self.pool, REWIND, "draft_model with a target")
+        no_sharing = self.pool.refusal(REREAD, "a shared prefix in a model")
+        if self.enable_prefix_cache and no_sharing is not None:
+            _log.warning("enable_prefix_cache is switched off: %s",
+                         no_sharing)
+            self.enable_prefix_cache = False
+        # the pool's bytes by what they are: per-slot state that is no
+        # page, and the slabs by kind where the pool holds a second kind
+        # of page beside (or in place of) full-length K/V
+        state_bytes = self.pool.recurrent_state_bytes
+        if state_bytes:
+            self.metrics.set_recurrent_state(state_bytes)
+        by_kind = self.pool.kv_bytes()
+        if any(n for label, n in by_kind.items() if label != "full"):
+            self.metrics.set_kv_pool_bytes(by_kind)
+        # learned sparse attention: what the step's sparse layers select
+        # from is the model's to say (top-k, layers with an indexer and
+        # layers that share one's selection)
         self._sparse = None
-        if self.pool.indexed:
-            if self.config.host_kv_bytes > 0:
-                raise ValueError(
-                    "host_kv_bytes > 0 with a model that keeps index-key "
-                    "pages: the host tier holds (k, v) pairs, and sparse "
-                    "reads from it are later work")
+        if INDEXED in self.pool.layer_kinds:
             kinds = list(model.config.indexer_types)
             self._sparse = (int(model.config.index_topk),
                             kinds.count("full"), kinds.count("shared"))
-        # the slabs' bytes by kind, where the pool holds a second kind of
-        # page beside (or in place of) full-length K/V: a ring, or latent
-        # pages (PR 36: position-addressed like K/V, so nothing above is
-        # switched off or refused for them)
-        if self.pool.windowed or self.pool.latent:
-            self.metrics.set_kv_pool_bytes(self.pool.kv_bytes())
         # host-RAM spill tier (ISSUE 19): a byte-budgeted LRU the prefix
         # cache spills refcount-0 pages into on pressure eviction; the
         # admission path re-onboards covered blocks instead of
@@ -748,11 +708,9 @@ class LLMEngine:
                 self.config.block_len, self.config.n_blocks,
                 dtype=self.config.cache_dtype,
                 pad_tokens=self.config.prefill_chunk)
-            if self.draft_pool.recurrent:
-                raise ValueError(
-                    "draft_model with recurrent layers: the draft pool "
-                    "rewinds to the verified stream after every window, "
-                    "and a recurrence's state cannot be rewound")
+            # the draft pool rewinds to the verified stream after every
+            # window
+            self._refuse(self.draft_pool, REWIND, "draft_model with a draft")
             if self.enable_prefix_cache:
                 self.draft_prefix_cache = PrefixCache(self.draft_pool,
                                                       name="draft")
@@ -817,7 +775,7 @@ class LLMEngine:
         self._dispatch_idx = 0       # lifetime dispatch attempts (fault
         #                              clauses key on this index)
         # finished request timelines for /debug/requests/<rid> (ISSUE 9)
-        self.timelines = TimelineStore(self.config.trace_buffer)
+        self.timelines = TimelineStore()
         # serving economics (ISSUE 11): both None unless armed, so every
         # hot-path hook costs exactly one predicate when disabled
         self.ledger = None
@@ -913,9 +871,7 @@ class LLMEngine:
         unpacks `sel` and `lp` on the device: a decode row costs one
         position, not C."""
         if self._step_jit is None:
-            block_len = self.pool.block_len
-            pages_per_row = self.pool.n_blocks
-            ring = self._ring_operand(self.pool)
+            view = self.pool.view
             prefill = self._prefill_fn
             chunk = self.config.prefill_chunk
             step_tokens = self.step_tokens
@@ -944,8 +900,7 @@ class LLMEngine:
                     prev_sel, jnp.maximum(feed, 0)[:, None], axis=1)[:, 0]
                 toks = toks.at[:, 0].set(
                     jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
-                seq_lens = (pos + adv).astype(jnp.int32)
-                paged = (table, seq_lens, block_len, pages_per_row) + ring
+                paged = view(table, (pos + adv).astype(jnp.int32))
                 pack = None
                 rows_adv, rows_dstate = adv, dstate
                 if packed:
@@ -1000,12 +955,12 @@ class LLMEngine:
         return self._step_jit
 
     @staticmethod
-    def _ring_operand(pool) -> tuple:
-        """What a step's `paged` tuple carries past its four fields: the
-        pages of the window layers' ring, on a pool that holds one. ()
-        otherwise: the operand, and the executable, of a model without
-        window layers are what they were."""
-        return (pool.ring_pages,) if pool.windowed else ()
+    def _refuse(pool, feature: str, what: str):
+        """Raise the pool's refusal of `what`, a use of `feature`, if some
+        layer's kind refuses it, as the engine's `ValueError`."""
+        err = pool.refusal(feature, what)
+        if err is not None:
+            raise ValueError(str(err))
 
     def _sampling_args_locked(self, ctr):
         """The unified step's per-slot sampling operands: the live table
@@ -1060,14 +1015,11 @@ class LLMEngine:
         its KV tracks the true stream. Output tokens are discarded — only
         the written KV stripes matter."""
         if self._draft_step_jit is None:
-            block_len = self.draft_pool.block_len
-            pages_per_row = self.draft_pool.n_blocks
-            ring = self._ring_operand(self.draft_pool)
+            view = self.draft_pool.view
             vfy = self._draft_verify_fn
 
             def step(params, toks, pos, adv, table, slabs):
-                seq_lens = (pos + adv).astype(jnp.int32)
-                paged = (table, seq_lens, block_len, pages_per_row) + ring
+                paged = view(table, (pos + adv).astype(jnp.int32))
                 return vfy(params, toks, slabs, pos, paged=paged)
 
             self._draft_step_jit = jax.jit(step)
@@ -1095,9 +1047,7 @@ class LLMEngine:
         requests. Greedy rows still argmax. Grammar-constrained rows
         never reach this scan (spec-ineligible)."""
         if self._draft_propose_jit is None:
-            block_len = self.draft_pool.block_len
-            pages_per_row = self.draft_pool.n_blocks
-            ring = self._ring_operand(self.draft_pool)
+            view = self.draft_pool.view
             K = self.config.spec_k
             dprefill = self._draft_prefill_fn
 
@@ -1105,9 +1055,8 @@ class LLMEngine:
                         topk, topp, samp, seed, ctr):
                 def body(carry, j):
                     tok, off, slabs_c = carry
-                    seq_lens = (pos + off + act).astype(jnp.int32)
-                    paged = (table, seq_lens, block_len, pages_per_row) \
-                        + ring
+                    paged = view(table,
+                                 (pos + off + act).astype(jnp.int32))
                     lg, slabs_c = dprefill(params, tok[:, None], slabs_c,
                                            pos + off, paged=paged)
                     nxt = select_next(lg[:, 0], temp, topk, topp, samp,
@@ -1924,14 +1873,7 @@ class LLMEngine:
                         q = nq
                     dstate0 = q
         if kv_row is not None:
-            if self.pool.recurrent:
-                raise ValueError(
-                    "kv_row with a model that has recurrent layers: "
-                    "imported pages carry no recurrent state")
-            if self.pool.windowed:
-                raise ValueError(
-                    "kv_row with a model that has window layers: their "
-                    "keys live in a ring that imported pages cannot fill")
+            self._refuse(self.pool, REREAD, "kv_row with a model")
             if int(kv_row.get("block_len", -1)) != self.pool.block_len:
                 raise ValueError(
                     f"kv_row block_len {kv_row.get('block_len')!r} does "
@@ -2961,26 +2903,8 @@ class LLMEngine:
                              step_tokens=self.step_tokens,
                              deferred_rows=deferred,
                              in_flight=int(ahead_of is not None))
-            started = 0
-            if self.pool.recurrent:
-                # rows whose recurrent state this step advances, and those
-                # of them it starts from zero (position 0)
-                span_args["recurrent_rows"] = int(np.count_nonzero(adv))
-                started = int(np.count_nonzero((adv > 0) & (pos == 0)))
-            # what this step's attention calls must read: a row's keys
-            # after the step (one full or latent layer's call) and, on a
-            # pool with a ring, the part of them inside the window
-            after = (pos + adv)[adv > 0]
-            in_window = 0
-            if self.pool.windowed:
-                # rows whose ring has begun to overwrite its oldest keys
-                in_window = int(np.minimum(after, self.pool.window).sum())
-                span_args["window_rows"] = int(after.size)
-                span_args["wrapped_rows"] = int(np.count_nonzero(
-                    after > self.pool.ring_len))
-            if self.pool.latent:
-                span_args["latent_rows"] = int(after.size)
-            kv_tokens = (in_window, int(after.sum()))
+            kind_args, started, kv_tokens = self.pool.step_counts(pos, adv)
+            span_args.update(kind_args)
             sparse_keys = None
             if self._sparse is not None:
                 # per live query position p of a row: the keys it can see
@@ -2990,7 +2914,7 @@ class LLMEngine:
                 seen = (pos[:, None] + cols + 1)[cols < adv[:, None]]
                 sparse_keys = (int(np.minimum(seen, topk).sum()),
                                int(seen.sum()), n_full, n_shared)
-                span_args["sparse_rows"] = int(after.size)
+                span_args["sparse_rows"] = int(np.count_nonzero(adv))
             with RecordEvent(SPAN_SERVE_DISPATCH, **span_args):
                 t0 = self.clock.now()
                 fn = self._step()

@@ -674,8 +674,8 @@ def test_the_host_tier_and_imported_pages_are_refused(tiny):
 
 
 @pytest.mark.parametrize("what", ["rewind_length", "export_rows",
-                                  "import_rows", "export_page",
-                                  "import_page", "cow_copy"])
+                                  "export_page", "import_page", "cow_copy",
+                                  "attach_blocks", "register_cached"])
 def test_the_pool_refuses_to_rebuild_a_recurrent_row(tiny, what):
     pool = SlotPagedKVPool(tiny.init_cache, 2, 8, 4, pad_tokens=8)
     slot = pool.allocate(16)
@@ -683,18 +683,17 @@ def test_the_pool_refuses_to_rebuild_a_recurrent_row(tiny, what):
     calls = {
         "rewind_length": lambda: pool.rewind_length(slot, 4),
         "export_rows": lambda: pool.export_rows([slot]),
-        "import_rows": lambda: pool.import_rows(
-            {"block_len": 8, "capacity": 32, "rows": {}}),
         "export_page": lambda: pool.export_page(0),
         "import_page": lambda: pool.import_page(slot, 0, []),
         "cow_copy": lambda: pool.cow_copy(5, slot),
+        "attach_blocks": lambda: pool.attach_blocks(slot, [4]),
+        "register_cached": lambda: pool.register_cached(4),
     }
     with pytest.raises(RecurrentStateError, match=what):
         calls[what]()
     pool.rewind_length(slot, 12)              # not a rewind: allowed
     pool.free(slot)
-    assert pool.defrag() > 0                  # the K/V pages are scrubbed,
-    assert pool.check_balance()               # the state is left alone
+    assert pool.check_balance()
 
 
 def test_a_live_stream_cannot_be_exported(tiny):

@@ -2,16 +2,26 @@
 ring of window + one chunk of columns beside the other layers' full-length
 pages. The ring's geometry as the pool derives it, the byte gauges, what a
 ring cannot serve refused by name (`WindowRingError`), the ledger through
-allocate / grow / rewind / free / defrag, and a model without window layers
-building the pool it built."""
+allocate / grow / rewind / free, and a model without window layers
+building the pool it built. Then the seam itself: the kinds' table
+(`models.generation.CACHE_KINDS`) as the pool reads it, a kind the pool has
+never seen, the step's page operand (`PagedView`) and the per-kind counts of
+a step."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.models.generation import (IndexedLatentKV, LatentKV,
-                                          RecurrentState, WindowKV)
-from paddle_tpu.serving.llm.kv_pool import (INDEXED, LATENT, PAGED, WINDOW,
-                                            RecurrentStateError,
+from typing import NamedTuple
+
+import jax
+
+from paddle_tpu.models import generation
+from paddle_tpu.models.generation import (CACHE_KINDS, HOST_TIER, REREAD,
+                                          REWIND, CacheKind, IndexedLatentKV,
+                                          LatentKV, RecurrentState, WindowKV,
+                                          kind_of)
+from paddle_tpu.serving.llm.kv_pool import (INDEXED, LATENT, PAGED, RECURRENT,
+                                            WINDOW, RecurrentStateError,
                                             SlotPagedKVPool,
                                             SlotsExhaustedError,
                                             WindowRingError)
@@ -105,8 +115,6 @@ def test_every_refusal_is_by_name():
         "register_cached": lambda: pool.register_cached(0),
         "cow_copy": lambda: pool.cow_copy(20, s),
         "export_rows": lambda: pool.export_rows([s]),
-        "import_rows": lambda: pool.import_rows(
-            {"block_len": 8, "capacity": 128, "rows": {}}),
         "export_page": lambda: pool.export_page(0),
         "import_page": lambda: pool.import_page(s, 0, layers),
         "rewind_length by 17": lambda: pool.rewind_length(s, 53),
@@ -123,10 +131,9 @@ def test_every_refusal_is_by_name():
     assert pool.check_balance()
 
 
-def test_ledger_balances_through_grow_rewind_free_and_defrag():
+def test_ledger_balances_through_grow_rewind_and_free():
     pool = _pool()
     a, b = pool.allocate(128), pool.allocate(64)
-    pool.slabs = [(k + 1, v + 1) for k, v in pool.slabs]   # stale keys
     for n in (16, 48, 97, 128):                  # round the ring and on
         pool.set_length(a, n)
         assert pool.check_balance()
@@ -136,12 +143,10 @@ def test_ledger_balances_through_grow_rewind_free_and_defrag():
     assert pool.check_balance()
     pool.free(a)
     assert pool.check_balance() and pool.dirty_blocks() == 16
-    assert pool.defrag() == 16
-    for (k, v), kind in zip(pool.slabs, pool.layer_kinds):
-        # the freed row's slab is scrubbed whole, ring or not; the live
-        # row's and the free row's are not touched
-        assert not np.asarray(k[a]).any() and not np.asarray(v[a]).any()
-        assert np.asarray(k[b]).all() and np.asarray(k[2]).all(), kind
+    # the freed row is handed out again as it lies, and counted
+    assert pool.allocate(8) == a and pool.stats["reuses"] == 1
+    assert pool.dirty_blocks() == 0
+    pool.free(a)
     pool.free(b)
     assert pool.check_balance()
     assert pool.layer_kinds == [WINDOW, WINDOW, PAGED]
@@ -183,7 +188,6 @@ def test_a_recurrent_layer_beside_a_ring_refuses_as_recurrent():
     pool.set_length(s, 20)
     with pytest.raises(RecurrentStateError):
         pool.rewind_length(s, 19)
-    assert pool.defrag() == 0
 
 
 def test_rings_of_different_windows_are_refused():
@@ -233,7 +237,7 @@ def test_latent_kind_and_bytes_by_kind():
 
 @pytest.mark.parametrize("operation", [
     "attach_blocks", "cow_copy", "export_rows", "export_page",
-    "rewind_length", "defrag", "prefix cache hit"])
+    "rewind_length", "prefix cache hit"])
 def test_every_page_operation_works_on_a_latent_pool(operation):
     """Latent pages are addressed by position like K/V pages: nothing is
     refused, and each operation moves both slabs of the pair, each at its
@@ -268,11 +272,6 @@ def test_every_page_operation_works_on_a_latent_pool(operation):
         assert c.shape == (1, 40, RANK) and r.shape == (1, 40, ROPE)
         assert np.array_equal(c, before[0][0][a, :, :40])
         assert np.array_equal(r, before[0][1][a, :, :40])
-        other = _latent_pool()
-        dst = other.import_rows(out)[a]
-        for (c, r), (c0, r0) in zip(other.slabs, before):
-            assert np.array_equal(np.asarray(c[dst, :, :40]), c0[a, :, :40])
-            assert np.array_equal(np.asarray(r[dst, :, :40]), r0[a, :, :40])
         # an active row of length 0 exports empties of both widths
         e = pool.allocate(8)
         (c, r), = pool.export_rows([e])["rows"][e]["layers"][:1]
@@ -288,18 +287,6 @@ def test_every_page_operation_works_on_a_latent_pool(operation):
     elif operation == "rewind_length":
         pool.rewind_length(a, 17)
         assert pool.lengths[a] == 17 and len(pool.block_table[a]) == 3
-    elif operation == "defrag":
-        keep = a * pool.n_blocks + 1
-        pool.register_cached(keep)
-        pool.free(a)
-        assert pool.defrag() == pool.n_blocks - 1
-        for (c, r), (c0, r0) in zip(pool.slabs, before):
-            # the cached page stays, the rest of the freed row is zeroed
-            assert np.array_equal(np.asarray(c[a, :, 8:16]), c0[a, :, 8:16])
-            assert np.array_equal(np.asarray(r[a, :, 8:16]), r0[a, :, 8:16])
-            assert not np.asarray(c[a, :, :8]).any()
-            assert not np.asarray(r[a, :, 16:]).any()
-            assert np.array_equal(np.asarray(c[1]), c0[1])
     else:
         cache = PrefixCache(pool)
         tokens = np.arange(1, 41, dtype=np.int32)
@@ -328,8 +315,7 @@ def test_latent_ledger_balances_through_a_rows_life():
     pool.rewind_length(a, 50)
     pool.free(a)
     pool.free(b)
-    assert pool.check_balance() and pool.defrag() == 16
-    assert pool.check_balance()
+    assert pool.check_balance() and pool.dirty_blocks() == 16
 
 
 # ---- a fifth kind: index-key pages beside latent pages (PR 39) ----
@@ -356,7 +342,7 @@ def _indexed_pool(kinds=(INDEXED, LATENT, INDEXED), block_len=8, n_blocks=8,
 def test_indexed_kind_and_bytes_by_kind():
     pool = _indexed_pool()
     assert pool.layer_kinds == [INDEXED, LATENT, INDEXED]
-    assert pool.indexed and pool.latent and not pool.windowed
+    assert not pool.windowed and not pool.recurrent
     assert [len(e) for e in pool.slabs] == [3, 2, 3]
     assert pool.slabs[0][2].shape == (3, 1, 80, INDEX)
     assert pool.kv_bytes() == {
@@ -364,15 +350,14 @@ def test_indexed_kind_and_bytes_by_kind():
         "latent": 3 * 3 * 80 * (RANK + ROPE) * 4,
         "index": 2 * 3 * 80 * INDEX * 4}
     assert "index" not in _latent_pool().kv_bytes()
-    assert not _latent_pool().indexed
 
 
 @pytest.mark.parametrize("operation", [
-    "cow_copy", "export_rows", "export_page", "defrag", "eviction"])
+    "cow_copy", "export_rows", "export_page", "eviction"])
 def test_index_pages_go_where_their_latent_pages_go(operation):
     """Page p of the index slab is page p of `c` and of `r`: whatever
-    copies, exports, scrubs or evicts a page does it to all three slabs of
-    a layer that has three."""
+    copies, exports or evicts a page does it to all three slabs of a layer
+    that has three."""
     pool = _indexed_pool()
     _fill(pool)
     a = pool.allocate(40)
@@ -394,15 +379,6 @@ def test_index_pages_go_where_their_latent_pages_go(operation):
         assert [len(e) for e in layers] == [3, 2, 3]
         assert layers[0][2].shape == (1, 40, INDEX)
         assert np.array_equal(layers[2][2], before[2][2][a, :, :40])
-        other = _indexed_pool()
-        dst = other.import_rows(out)[a]
-        for entry, old in zip(other.slabs, before):
-            for x, x0 in zip(entry, old):
-                assert np.array_equal(np.asarray(x[dst, :, :40]),
-                                      x0[a, :, :40])
-        # a pool whose layers keep other slabs refuses the payload
-        with pytest.raises(ValueError, match="a layer of 2 slabs given 3"):
-            _latent_pool(layers=3).import_rows(out)
     elif operation == "export_page":
         layers = pool.export_page(a * pool.n_blocks + 1, width=5)
         assert [tuple(x.shape for x in e) for e in layers] == [
@@ -413,19 +389,10 @@ def test_index_pages_go_where_their_latent_pages_go(operation):
         pool.import_page(b, 1, layers)
         assert np.array_equal(np.asarray(pool.slabs[2][2][b, :, 8:13]),
                               before[2][2][a, :, 8:13])
-    elif operation == "defrag":
-        keep = a * pool.n_blocks + 1
-        pool.register_cached(keep)
-        pool.free(a)
-        assert pool.defrag() == pool.n_blocks - 1
-        for entry, old in zip(pool.slabs, before):
-            for x, x0 in zip(entry, old):
-                # the cached page stays, the rest of the freed row is zeroed
-                assert np.array_equal(np.asarray(x[a, :, 8:16]),
-                                      x0[a, :, 8:16])
-                assert not np.asarray(x[a, :, :8]).any()
-                assert not np.asarray(x[a, :, 16:]).any()
-                assert np.array_equal(np.asarray(x[1]), x0[1])
+        # a pool whose layers keep other slabs refuses the payload
+        other = _latent_pool(layers=3)
+        with pytest.raises(ValueError, match="a layer of 2 slabs given 3"):
+            other.import_page(other.allocate(16), 1, layers)
     else:
         cache = PrefixCache(pool)
         tokens = np.arange(1, 41, dtype=np.int32)
@@ -548,3 +515,216 @@ def test_the_fit_rows_follow_the_pinned_pages(keep_below):
     pool._cached_in[2] = 0
     with pytest.raises(AssertionError, match="ledgers disagree"):
         pool.check_balance()
+
+
+# ---- the seam: kinds declared once, read by the pool (PR 42) ----
+
+def _pool_of(kind, slots=3, block_len=8, n_blocks=16, pad=16):
+    """A pool whose first layer is of `kind` and whose second is paged."""
+    def init_cache(batch, max_len, dtype=None, window_slab=None):
+        def slab(cols, width=D, heads=HKV):
+            return jnp.zeros((batch, heads, cols, width), jnp.float32)
+        first = {
+            PAGED: lambda: (slab(max_len), slab(max_len)),
+            RECURRENT: lambda: RecurrentState(jnp.zeros((batch, 3, 8)),
+                                              jnp.zeros((batch, 4, 8))),
+            WINDOW: lambda: WindowKV(slab(window_slab(32)),
+                                     slab(window_slab(32))),
+            LATENT: lambda: LatentKV(slab(max_len, RANK, 1),
+                                     slab(max_len, ROPE, 1)),
+            INDEXED: lambda: IndexedLatentKV(slab(max_len, RANK, 1),
+                                             slab(max_len, ROPE, 1),
+                                             slab(max_len, INDEX, 1)),
+        }[kind]()
+        return [first, (slab(max_len), slab(max_len))]
+    return SlotPagedKVPool(init_cache, slots, block_len, n_blocks,
+                           pad_tokens=pad)
+
+
+KINDS = (PAGED, RECURRENT, WINDOW, LATENT, INDEXED)
+
+
+def test_the_table_names_every_kind_by_its_entrys_type():
+    assert [row.name for row in CACHE_KINDS.values()] == list(KINDS)
+    z = jnp.zeros((1, 1, 8, 2))
+    assert kind_of((z, z)).name == PAGED and kind_of([z, z]).name == PAGED
+    for entry, name in ((RecurrentState(z, z), RECURRENT),
+                        (WindowKV(z, z), WINDOW), (LatentKV(z, z), LATENT),
+                        (IndexedLatentKV(z, z, z), INDEXED)):
+        assert kind_of(entry).name == name
+        assert len(kind_of(entry).bytes_as) == len(entry)
+    # a kind that refuses something says why, and only such a kind
+    for row in CACHE_KINDS.values():
+        assert bool(row.refuses) == bool(row.why)
+        assert row.refuses <= {REREAD, HOST_TIER, REWIND}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_pool_refuses_what_the_kinds_row_refuses_in_its_words(kind):
+    row = next(r for r in CACHE_KINDS.values() if r.name == kind)
+    pool = _pool_of(kind)
+    assert pool.layer_kinds == [kind, PAGED]
+    for feature in (REREAD, HOST_TIER, REWIND):
+        err = pool.refusal(feature, "a thing asked")
+        if feature not in row.refuses:
+            assert err is None
+            continue
+        assert type(err) is row.error
+        assert str(err).startswith(
+            f"a thing asked of which 1 of 2 layers are {kind} layers")
+        assert str(err).endswith(row.why)
+    # and the pool's own operations ask the same table
+    s = pool.allocate(100)
+    pool.set_length(s, 70)
+    calls = {
+        REREAD: {"export_rows": lambda: pool.export_rows([s]),
+                 "cow_copy": lambda: pool.cow_copy(40, s),
+                 "register_cached": lambda: pool.register_cached(0),
+                 "rewind_length by 30": lambda: pool.rewind_length(s, 40)},
+        REWIND: {"rewind_length by 2": lambda: pool.rewind_length(s, 68)},
+    }
+    for feature, ops in calls.items():
+        for what, call in ops.items():
+            if feature in row.refuses:
+                with pytest.raises(row.error, match=what) as e:
+                    call()
+                assert row.why in str(e.value)
+    if not row.refuses - {HOST_TIER}:
+        pool.rewind_length(s, 68)
+        pool.rewind_length(s, 10)
+        assert pool.export_rows([s])["rows"][s]["length"] == 10
+    pool.free(s)
+    assert pool.check_balance()
+
+
+class MatrixState(NamedTuple):
+    """A kind this repository does not have: one fixed `[rows, rows]`
+    matrix a slot (a linear-attention layer's state), no pages."""
+    m: jax.Array
+
+
+class MatrixStateError(NotImplementedError):
+    pass
+
+
+MATRIX = CacheKind(
+    "matrix", ("matrix",), frozenset({REREAD, HOST_TIER, REWIND}),
+    "a matrix state sums every token the row has seen and keeps none of "
+    "them", MatrixStateError)
+
+
+def test_a_kind_the_pool_has_never_seen_is_a_type_and_a_row(monkeypatch):
+    """The next kind costs a NamedTuple and a row of the table: the pool
+    names it, counts its bytes under its label, keeps the ledger through a
+    row's life and refuses what the row refuses, and `kv_pool.py` holds
+    nothing about it."""
+    monkeypatch.setitem(generation.CACHE_KINDS, MatrixState, MATRIX)
+
+    def init_cache(batch, max_len, dtype=None):
+        z = jnp.zeros((batch, HKV, max_len, D), jnp.float32)
+        return [MatrixState(jnp.zeros((batch, 5, 5), jnp.float32)), (z, z)]
+    pool = SlotPagedKVPool(init_cache, 3, 8, 8, pad_tokens=16)
+    assert pool.layer_kinds == ["matrix", PAGED]
+    assert not pool.recurrent and not pool.windowed
+    assert pool.ring_len is None
+    assert pool.kv_bytes() == {"full": 2 * 3 * HKV * 80 * D * 4,
+                               "window": 0, "matrix": 3 * 5 * 5 * 4}
+    assert pool.recurrent_state_bytes == 0
+    assert pool.view("t", "l") == ("t", "l", 8, 8, None)
+    a, b = pool.allocate(64), pool.allocate(30)
+    for n in (16, 33, 64):
+        pool.set_length(a, n)
+        assert pool.check_balance()
+    pool.set_length(b, 30)
+    assert pool.used_blocks() == 8 + 4
+    for what, call in {
+        "rewind_length by 1": lambda: pool.rewind_length(a, 63),
+        "rewind_length by 40": lambda: pool.rewind_length(a, 24),
+        "export_rows": lambda: pool.export_rows([a]),
+        "export_page": lambda: pool.export_page(0),
+        "attach_blocks": lambda: pool.attach_blocks(b, [0]),
+    }.items():
+        with pytest.raises(MatrixStateError, match=what) as e:
+            call()
+        assert str(e.value).endswith(MATRIX.why)
+        assert "1 of 2 layers are matrix layers" in str(e.value)
+    args, started, kv_tokens = pool.step_counts(
+        np.array([64, 30, 0], np.int32), np.array([1, 1, 0], np.int32))
+    assert (args, started, kv_tokens) == ({}, 0, (0, 65 + 31))
+    # the step's donated slabs and a lost pool, as for any kind
+    assert not pool.consumed()
+    pool.reset_slabs()
+    assert pool.slabs[0][0].shape == (3, 5, 5)
+    pool.free(a)
+    pool.free(b)
+    assert pool.check_balance() and pool.dirty_blocks() == 16
+
+
+# ---- the step's page operand, as the pool builds it ----
+
+@pytest.mark.parametrize("kinds", [(PAGED, PAGED), (WINDOW, WINDOW, PAGED)])
+def test_the_pools_view_is_the_old_tuple_field_for_field(kinds):
+    from paddle_tpu.ops.attention import PagedView
+    pool = _pool(kinds=kinds)
+    table, lens = pool.device_block_table(), jnp.arange(3, dtype=jnp.int32)
+    view = pool.view(table, lens)
+    assert isinstance(view, PagedView)
+    # what `LLMEngine._step` built: four fields and, on a pool with a
+    # ring, its pages
+    old = (table, lens, pool.block_len, pool.n_blocks)
+    assert tuple(view)[:4] == old
+    assert (view.table, view.seq_lens, view.block_len,
+            view.pages_per_row) == old
+    if pool.windowed:
+        assert tuple(view) == old + (pool.ring_pages,) == old + (6,)
+        assert (view.ring, view.positions) == (pool.ring_len, pool.capacity)
+    else:
+        assert tuple(view) == old + (None,)
+    assert type(view.block_len) is int and type(view.pages_per_row) is int
+
+
+# ---- what a step means to each kind ----
+
+def _old_step_counts(pool, pos, adv):
+    """`LLMEngine._launch`'s arithmetic as it stood inline (PR 41)."""
+    span_args, started = {}, 0
+    if RECURRENT in pool.layer_kinds:
+        span_args["recurrent_rows"] = int(np.count_nonzero(adv))
+        started = int(np.count_nonzero((adv > 0) & (pos == 0)))
+    after = (pos + adv)[adv > 0]
+    in_window = 0
+    if WINDOW in pool.layer_kinds:
+        in_window = int(np.minimum(after, pool.window).sum())
+        span_args["window_rows"] = int(after.size)
+        span_args["wrapped_rows"] = int(np.count_nonzero(
+            after > pool.ring_len))
+    if LATENT in pool.layer_kinds or INDEXED in pool.layer_kinds:
+        span_args["latent_rows"] = int(after.size)
+    return span_args, started, (in_window, int(after.sum()))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_steps_counts_by_kind_are_the_old_inline_arithmetic(kind):
+    pool = _pool_of(kind, slots=16)
+    rng = np.random.default_rng(KINDS.index(kind))
+    seen = set()
+    for _ in range(40):
+        # free slots, rows that start, chunks, decode rows, rows past the
+        # ring (48 columns) and inside the window (32)
+        adv = rng.choice([0, 0, 1, 1, 5, 16], 16).astype(np.int32)
+        pos = np.where(rng.random(16) < 0.3, 0,
+                       rng.integers(0, 100, 16)).astype(np.int32)
+        got = pool.step_counts(pos, adv)
+        assert got == _old_step_counts(pool, pos, adv)
+        assert list(got[0]) == list(_old_step_counts(pool, pos, adv)[0])
+        seen.add(got[1] > 0)
+        seen.add(("wrapped", got[0].get("wrapped_rows", 0) > 0))
+    assert pool.step_counts(np.zeros(16, np.int32), np.zeros(16, np.int32)) \
+        == _old_step_counts(pool, np.zeros(16, np.int32),
+                            np.zeros(16, np.int32))
+    assert (True in seen) == (kind == RECURRENT)
+    assert (("wrapped", True) in seen) == (kind == WINDOW)
+    keys = {PAGED: [], RECURRENT: ["recurrent_rows"],
+            WINDOW: ["window_rows", "wrapped_rows"],
+            LATENT: ["latent_rows"], INDEXED: ["latent_rows"]}[kind]
+    assert list(got[0]) == keys

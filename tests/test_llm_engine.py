@@ -73,19 +73,152 @@ def test_pool_alloc_free_reuse_accounting():
     assert snap["allocs"] == 5 and snap["peak_active"] == 4
 
 
-def test_pool_defrag_scrubs_dirty_slots():
+# ---- what a layer keeps per slot, as the engine reads it (PR 42) ----
+# The engine never runs a step here: what it refuses, switches off and
+# publishes is decided at construction and at `submit`, from the pool's
+# answer (`SlotPagedKVPool.refusal`) about the kinds' table.
+
+class _KeepsPerSlot:
+    """`gpt_tiny` to the engine, but `init_cache` answers with one layer of
+    `kind` (an entry built by `entry(batch, max_len, window_slab)`) before
+    the model's own."""
+
+    def __init__(self, model, entry, windowed=False):
+        self._model, self._entry = model, entry
+        base = model.init_cache
+        if windowed:
+            self.init_cache = lambda batch, max_len, dtype=None, \
+                window_slab=None: [entry(batch, max_len, window_slab)] \
+                + base(batch, max_len, dtype)
+        else:
+            self.init_cache = lambda batch, max_len, dtype=None: \
+                [entry(batch, max_len, None)] + base(batch, max_len, dtype)
+
+    def __getattr__(self, name):
+        return getattr(self._model, name)
+
+
+def _kind_entries():
     import jax.numpy as jnp
-    p = _pool(num_slots=2, block_len=4, n_blocks=2)
-    s = p.allocate(4)
-    k, v = p.slabs[0]
-    p.slabs[0] = (k.at[s].set(7.0), v.at[s].set(7.0))
-    p.free(s)
-    assert p.dirty_blocks() == 2
-    assert p.defrag() == 2                 # blocks reclaimed (zeroed)
-    assert p.dirty_blocks() == 0 and p.stats["defrags"] == 1
-    assert float(jnp.abs(p.slabs[0][0]).sum()) == 0.0
-    assert float(jnp.abs(p.slabs[0][1]).sum()) == 0.0
-    assert p.defrag() == 0                 # nothing dirty: no-op
+    from paddle_tpu.models.generation import (IndexedLatentKV, LatentKV,
+                                              RecurrentState, WindowKV)
+
+    def slab(batch, cols, width=4):
+        return jnp.zeros((batch, 1, cols, width), jnp.float32)
+    return {
+        "paged": lambda b, n, w: (slab(b, n), slab(b, n)),
+        "recurrent": lambda b, n, w: RecurrentState(
+            jnp.zeros((b, 3, 8)), jnp.zeros((b, 4, 8))),
+        "window": lambda b, n, w: WindowKV(slab(b, w(32)), slab(b, w(32))),
+        "latent": lambda b, n, w: LatentKV(slab(b, n, 6), slab(b, n, 2)),
+        "indexed": lambda b, n, w: IndexedLatentKV(
+            slab(b, n, 6), slab(b, n, 2), slab(b, n, 3)),
+    }
+
+
+def _refusing_engine(model, draft=None, **cfg):
+    from paddle_tpu import serving
+    return serving.LLMEngine(
+        model, serving.LLMEngineConfig(num_slots=2, block_len=8, n_blocks=8,
+                                       **cfg),
+        clock=serving.SimClock(), draft_model=draft)
+
+
+def _check_engine_refusals(model, target, row, caplog):
+    """`target` (a model one of whose layers is of the kind `row`
+    describes) through every feature a kind can refuse: refused, or
+    switched off, in the row's sentence where the row says so, served
+    where it does not."""
+    import logging
+    from paddle_tpu.models.generation import HOST_TIER, REREAD, REWIND
+    layers = f"1 of 3 layers are {row.name} layers"
+    with caplog.at_level(logging.WARNING, logger="paddle_tpu.serving.llm"):
+        caplog.clear()
+        eng = _refusing_engine(target)
+    said = [r.getMessage() for r in caplog.records
+            if "enable_prefix_cache is switched off" in r.getMessage()]
+    assert eng.pool.layer_kinds == [row.name, "paged", "paged"]
+    if REREAD in row.refuses:
+        assert len(said) == 1 and said[0].endswith(row.why)
+        assert layers in said[0]
+        assert eng.enable_prefix_cache is False and eng.prefix_cache is None
+        with pytest.raises(ValueError, match="kv_row with a model") as e:
+            eng.submit(np.arange(1, 13, dtype=np.int32), max_new_tokens=2,
+                       kv_row={"block_len": 8, "length": 8, "layers": []})
+        assert str(e.value).endswith(row.why) and layers in str(e.value)
+    else:
+        assert not said and eng.prefix_cache is not None
+        with pytest.raises(ValueError, match="block_len"):   # past the kind
+            eng.submit(np.arange(1, 13, dtype=np.int32), max_new_tokens=2,
+                       kv_row={"block_len": 4, "length": 8, "layers": []})
+    assert eng.config.enable_prefix_cache is True
+    if HOST_TIER in row.refuses:
+        with pytest.raises(ValueError, match="host_kv_bytes > 0") as e:
+            _refusing_engine(target, host_kv_bytes=1 << 20)
+        assert str(e.value).endswith(row.why) and layers in str(e.value)
+    else:
+        assert _refusing_engine(target, host_kv_bytes=1 << 20).host_kv \
+            is not None
+    for which, (tgt, draft) in {"target": (target, model),
+                                "draft": (model, target)}.items():
+        if REWIND in row.refuses:
+            with pytest.raises(ValueError,
+                               match=f"draft_model with a {which}") as e:
+                _refusing_engine(tgt, draft=draft)
+            assert str(e.value).endswith(row.why) and layers in str(e.value)
+        else:
+            assert _refusing_engine(tgt, draft=draft).draft_pool is not None
+    return eng
+
+
+@pytest.mark.parametrize("kind", ["paged", "recurrent", "window", "latent",
+                                  "indexed"])
+def test_engine_refuses_what_a_kinds_row_refuses_in_its_words(
+        gpt_tiny, kind, caplog):
+    from types import SimpleNamespace
+    from paddle_tpu.models.generation import CACHE_KINDS
+    row = next(r for r in CACHE_KINDS.values() if r.name == kind)
+    target = _KeepsPerSlot(gpt_tiny, _kind_entries()[kind],
+                           windowed=kind == "window")
+    if kind == "indexed":       # what the engine asks of a sparse model
+        target.config = SimpleNamespace(
+            vocab_size=gpt_tiny.config.vocab_size, index_topk=16,
+            indexer_types=["full", "shared", "shared"])
+    eng = _check_engine_refusals(gpt_tiny, target, row, caplog)
+    # the bytes the engine publishes are the pool's, under the row's labels
+    snap = eng.metrics.snapshot()
+    assert snap["recurrent_state_bytes"] == (
+        eng.pool.recurrent_state_bytes if kind == "recurrent" else None)
+    assert snap["kv_pool_bytes"] == (
+        None if kind in ("paged", "recurrent") else eng.pool.kv_bytes())
+    assert (eng._sparse is not None) == (kind == "indexed")
+
+
+def test_engine_takes_a_kind_it_has_never_seen_from_the_table(
+        gpt_tiny, monkeypatch, caplog):
+    """A NamedTuple defined here and its row of the table: the engine
+    refuses by name what the row refuses and publishes its bytes under its
+    label, with `llm_engine.py` and `kv_pool.py` as they are."""
+    import jax.numpy as jnp
+    from typing import NamedTuple
+    from paddle_tpu.models import generation
+    from paddle_tpu.models.generation import (HOST_TIER, REREAD, REWIND,
+                                              CacheKind)
+
+    class MatrixState(NamedTuple):
+        m: object
+
+    row = CacheKind(
+        "matrix", ("matrix",), frozenset({REREAD, HOST_TIER, REWIND}),
+        "a matrix state sums every token the row has seen and keeps none "
+        "of them")
+    monkeypatch.setitem(generation.CACHE_KINDS, MatrixState, row)
+    target = _KeepsPerSlot(
+        gpt_tiny, lambda b, n, w: MatrixState(jnp.zeros((b, 5, 5))))
+    eng = _check_engine_refusals(gpt_tiny, target, row, caplog)
+    assert eng.metrics.snapshot()["kv_pool_bytes"]["matrix"] == 2 * 5 * 5 * 4
+    with pytest.raises(NotImplementedError, match="export_rows on a pool"):
+        eng.pool.export_rows([eng.pool.allocate(8)])
 
 
 # ---- the acceptance proof (SimClock, threadless, provable) ----

@@ -194,8 +194,7 @@ def test_chunked_prefill_and_decode_through_the_ring_equal_reference(
     seqs = _prompts([n + decode for n in lengths], seed=4)
     paged = jax.jit(lambda toks, pos, adv, slabs: prefill(
         params, toks, slabs, pos,
-        paged=(pool.device_block_table(), pos + adv, block_len,
-               pool.n_blocks, pool.ring_pages)))
+        paged=pool.view(pool.device_block_table(), pos + adv)))
     got = [[] for _ in lengths]
     done = np.zeros(len(lengths), np.int32)
     slabs = pool.slabs
@@ -353,7 +352,8 @@ def test_a_model_without_window_layers_builds_the_pool_it_built():
     assert pool.ring_len is None and pool.ring_pages is None
     assert all(k.shape == (3, 2, 256 + 16, 16) for k, _ in pool.slabs)
     assert not any(isinstance(e, WindowKV) for e in pool.slabs)
-    assert eng._ring_operand(pool) == ()         # the step's old operand
+    assert pool.view("table", "lens") == (       # no ring in the operand
+        "table", "lens", pool.block_len, pool.n_blocks, None)
     assert eng.enable_prefix_cache is True
     assert eng.metrics.snapshot()["kv_pool_bytes"] is None
     assert "kv_pool_bytes" not in eng.metrics.render()

@@ -396,7 +396,7 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
     args, text = _lowered(eng, prompt)
     monkeypatch.undo()
 
-    block_len, pages = eng.pool.block_len, eng.pool.n_blocks
+    view = eng.pool.view
     prefill = eng._prefill_fn
 
     def step(params, toks, pos, adv, table, slabs, temp, topk, topp, samp,
@@ -407,7 +407,7 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
             prev_sel, jnp.maximum(feed, 0)[:, None], axis=1)[:, 0]
         toks = toks.at[:, 0].set(
             jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
-        paged = (table, (pos + adv).astype(jnp.int32), block_len, pages)
+        paged = view(table, (pos + adv).astype(jnp.int32))
         with llm_engine.moe.collect_expert_counts() as counts:
             logits, new_slabs = prefill(params, toks, slabs, pos,
                                         paged=paged, adapters=None)

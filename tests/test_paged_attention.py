@@ -810,6 +810,7 @@ def test_sparse_latent_attention_matches_the_dense_oracle(impl, Tq, bl,
     columns' masks, in one call, against the dense softmax over each
     query's selected keys alone."""
     from paddle_tpu.ops import index_select as IX, pallas_mode
+    from paddle_tpu.ops.attention import PagedView
     from paddle_tpu.ops.paged_attention import (SPARSE_KERNEL,
                                                 sparse_latent_attention)
     case = _sparse_case(np.random.RandomState(13), Tq, bl, layout)
@@ -818,7 +819,8 @@ def test_sparse_latent_attention_matches_the_dense_oracle(impl, Tq, bl,
     K = 32
     _, chosen = _ref_selection(case, bl, K)
     sel = IX.select(case["qi"], case["w"], case["ki"], case["q_pos"], K,
-                    paged=(case["table"], case["lens"], bl, case["nb"]))
+                    paged=PagedView(case["table"], case["lens"], bl,
+                                    case["nb"]))
     pallas_mode.KERNEL_TRACES.clear()
     out = np.asarray(sparse_latent_attention(
         case["q"], case["c"], case["r"], case["table"], case["lens"],
@@ -1140,3 +1142,81 @@ def bad_slot(eng, handle):
         if req.handle is handle:
             return slot
     raise AssertionError("request not active")
+
+
+# ---- the step's page operand as a named view (PR 42) ----
+
+def test_paged_view_is_a_window_layers_view_of_itself():
+    """What `LlamaAttention._forward_cached` worked out by position: the
+    ring's columns, the positions the rotary table must reach, and the
+    operand without a table over the ring's pages."""
+    from paddle_tpu.ops.attention import PagedView
+    table, lens = jnp.zeros((3, 20), jnp.int32), jnp.arange(3)
+    view = PagedView(table, lens, 8, 20, 6)
+    assert (view.ring, view.positions) == (6 * 8, 20 * 8)
+    own = view.window_view()
+    assert own.table is None and own.seq_lens is lens
+    assert tuple(own) == (None, lens, 8, 6, None)
+    # a pool without a ring builds the view without one, and no layer asks
+    plain = PagedView(table, lens, 8, 20)
+    assert plain.ring_pages is None and plain.positions == 160
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_paged_view_live_mask_is_the_three_models_old_expressions(seed):
+    """`live` against the masks `LlamaModel`, `DeepseekModel` and
+    `GraniteMoeHybridModel` each computed from `paged[1]`, and `advance`
+    against the last one's `adv`, on seeded steps with free slots, decode
+    rows and chunks."""
+    from paddle_tpu.ops.attention import PagedView
+    rng = np.random.default_rng(seed)
+    rows, width = 12, 16
+    adv = rng.choice([0, 0, 1, 1, 7, 16], rows).astype(np.int32)
+    pos = rng.integers(0, 200, rows).astype(np.int32)
+    lens = jnp.asarray(pos + adv)
+    view = PagedView(None, lens, 8, 32)
+    pos_j = jnp.asarray(pos)
+    live = np.asarray(view.live(pos_j, width))
+    assert live.shape == (rows, width) and live.dtype == bool
+    # llama.py / deepseek.py: pos[b] + t short of the row's length
+    t = jnp.arange(width, dtype=jnp.int32)
+    old = jnp.reshape(pos_j, (-1, 1)) + t < jnp.reshape(lens, (-1, 1))
+    assert np.array_equal(live, np.asarray(old))
+    # granitemoehybrid.py: t short of the row's live columns
+    old_adv = jnp.reshape(lens, (-1,)) - pos_j
+    assert np.array_equal(np.asarray(view.advance(pos_j)), adv)
+    assert np.array_equal(np.asarray(old_adv), adv)
+    assert np.array_equal(
+        live, np.asarray(jnp.arange(width, dtype=jnp.int32)
+                         < old_adv[:, None]))
+    assert live.sum(axis=1).tolist() == adv.tolist()
+    # a `[rows, 1]` position, as a model hands it, is the same mask
+    assert np.array_equal(np.asarray(view.live(pos_j[:, None], width)), live)
+    # and the same equations: the view adds nothing to the trace
+    def models(p, s):
+        t = jnp.arange(width, dtype=jnp.int32)
+        return jnp.reshape(p, (-1, 1)) + t < jnp.reshape(s, (-1, 1))
+    assert str(jax.make_jaxpr(models)(pos_j, lens)) == str(jax.make_jaxpr(
+        lambda p, s: PagedView(None, s, 8, 32).live(p, width))(pos_j, lens))
+
+
+def test_decode_attention_and_select_take_the_view_and_nothing_else():
+    """No plain tuple is accepted beside the view."""
+    from paddle_tpu.ops import index_select as IX
+    from paddle_tpu.ops.attention import PagedView, decode_attention
+    rng = np.random.RandomState(3)
+    B, H, T, D, bl, nb = 2, 2, 4, 8, 8, 3
+    q = _rand(rng, (B, H, T, D))
+    k, v = _rand(rng, (B, H, bl * nb, D)), _rand(rng, (B, H, bl * nb, D))
+    table = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    pos = jnp.asarray([3, 9], jnp.int32)
+    lens = pos + T
+    out = decode_attention(q, k, v, pos, paged=PagedView(table, lens, bl, nb))
+    ringed = decode_attention(q, k, v, pos,
+                              paged=PagedView(table, lens, bl, nb, 2))
+    assert np.array_equal(np.asarray(out), np.asarray(ringed))
+    with pytest.raises(AttributeError):
+        decode_attention(q, k, v, pos, paged=(table, lens, bl, nb))
+    with pytest.raises(AttributeError):
+        IX.select(q, jnp.ones((B, H, T)), k[:, :1], pos, 4,
+                  paged=(table, lens, bl, nb))

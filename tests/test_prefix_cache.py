@@ -1,6 +1,6 @@
 """Prefix-sharing radix KV cache + multi-tenant serving (ISSUE 8):
 shared-block-pool accounting (attach/refcount/COW/block ledger),
-refcount-aware defrag, radix lookup/insert/LRU-eviction, the SimClock
+radix lookup/insert/LRU-eviction, the SimClock
 acceptance proof (N shared-prefix requests cost ~1 prefill with streams
 bit-identical to cold greedy generate()), the fault-matrix scenarios
 (poisoned sibling quarantined without corrupting shared blocks; eviction
@@ -95,27 +95,6 @@ def test_cow_copy_moves_one_block_between_rows():
     assert float(jnp.abs(v[s1, :, 4:8] - 3.0).max()) == 0.0
     assert float(jnp.abs(k[s1, :, :4]).max()) == 0.0   # only that block
     assert p.stats["cow_copies"] == 1
-
-
-def test_defrag_is_refcount_aware_at_page_granularity():
-    import jax.numpy as jnp
-    p = _pool(num_slots=2, block_len=4, n_blocks=2)
-    s = p.allocate(8)
-    k, v = p.slabs[0]
-    p.slabs[0] = (k.at[s].set(7.0), v.at[s].set(7.0))
-    p.set_length(s, 8)
-    p.register_cached(s * 2)          # pin the FIRST page only
-    p.free(s)
-    assert p.dirty_blocks() == 1      # second page is scrubable
-    assert p.defrag() == 1
-    k, _ = p.slabs[0]
-    assert float(jnp.abs(k[s, :, :4] - 7.0).max()) == 0.0   # cached: intact
-    assert float(jnp.abs(k[s, :, 4:8]).max()) == 0.0        # scrubbed
-    p.release_cached(s * 2)           # unpin -> row scrubable again
-    assert p.dirty_blocks() == 2
-    assert p.defrag() == 2
-    assert float(jnp.abs(p.slabs[0][0]).sum()) == 0.0
-    p.check_balance()
 
 
 def test_allocate_skips_pinned_rows_and_calls_pressure_hook():

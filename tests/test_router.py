@@ -312,55 +312,6 @@ def test_fleet_brownout_shed_confined_to_best_effort(gpt_tiny):
 
 # ---- KV row serialization (failover handoff groundwork) ----
 
-def test_kv_pool_export_import_rows_bitwise_roundtrip():
-    """export_rows -> import_rows into a second pool round-trips KV
-    bit-for-bit (re-exporting the imported rows yields byte-identical
-    layers), across multi-block rows and non-block-aligned lengths."""
-    import jax.numpy as jnp
-    from paddle_tpu.serving.llm import SlotPagedKVPool
-
-    def init_cache(b, max_len):
-        return [(jnp.zeros((b, 2, max_len, 3), jnp.float32),
-                 jnp.zeros((b, 2, max_len, 3), jnp.float32))
-                for _ in range(2)]
-
-    def mk():
-        return SlotPagedKVPool(init_cache, 3, 4, 4)   # capacity 16/slot
-
-    rng = np.random.RandomState(5)
-    src = mk()
-    lengths = {src.allocate(11): 11, src.allocate(4): 4}
-    for slot, ln in lengths.items():
-        src.set_length(slot, ln)
-    for li in range(len(src.slabs)):
-        k, v = src.slabs[li]
-        src.slabs[li] = (
-            jnp.asarray(rng.randn(*k.shape).astype(np.float32)),
-            jnp.asarray(rng.randn(*v.shape).astype(np.float32)))
-
-    exported = src.export_rows(list(lengths))
-    assert set(exported["rows"]) == set(lengths)
-    for slot, ln in lengths.items():
-        row = exported["rows"][slot]
-        assert row["length"] == ln
-        assert all(np.asarray(ke).shape == (2, ln, 3)
-                   for ke, _ in row["layers"])
-
-    dst = mk()
-    mapping = dst.import_rows(exported)
-    assert sorted(mapping) == sorted(lengths)
-    back = dst.export_rows([mapping[s] for s in sorted(lengths)])
-    for s in sorted(lengths):
-        a, b = exported["rows"][s], back["rows"][mapping[s]]
-        assert a["length"] == b["length"]
-        for (ak, av), (bk, bv) in zip(a["layers"], b["layers"]):
-            np.testing.assert_array_equal(np.asarray(ak), np.asarray(bk))
-            np.testing.assert_array_equal(np.asarray(av), np.asarray(bv))
-
-    with pytest.raises(ValueError, match="block_len"):
-        SlotPagedKVPool(init_cache, 3, 8, 2).import_rows(exported)
-
-
 def test_export_rows_length_trimmed_bitwise_parity():
     """export_rows ships ONLY the occupied prefix (ISSUE 19: a handoff
     payload must not drag a row's full static capacity across the wire).
