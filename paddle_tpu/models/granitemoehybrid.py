@@ -17,33 +17,25 @@ over all, renormalised over the top-k). Written from the published
 `config.json` and from memory of Hugging Face's
 `modeling_granitemoehybrid.py`; composed from the layers the other decoders
 use (`models/llama.py`'s attention, norm and SwiGLU, `nn/layer/moe.py`'s
-dropless experts). Serving only: `forward(labels=...)` raises.
+dropless experts) over the stack every hybrid decoder shares
+(`models/hybrid.py`: the loop over the layers, the residual path, the
+cached-decode contract). Serving only: `forward(labels=...)` raises.
 
 `experts_held=(first, count)` gives every expert layer one chip's share of
 the `num_local_experts` (expert parallelism's unit; `DroplessMoE(held=)`):
 the router stays as wide as published and the layer returns its share's
 part. The shared expert is whole on every share.
-
-The cached-decode contract (`init_cache` / `forward_with_cache`): a mamba
-layer's cache is a `models.generation.RecurrentState`, fixed in size,
-where an attention layer's is its `(k, v)` slabs.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
-import jax.numpy as jnp
-
-from ..core.tensor import apply
-from ..distributed.meta_parallel.mp_layers import VocabParallelEmbedding
-from ..nn.layer.layers import Layer, LayerList, parameter_dtype
 from ..nn.layer.mamba import Mamba2Mixer
 from ..nn.layer.moe import DroplessMoE
-from .generation import RecurrentState
-from .llama import LlamaAttention, LlamaConfig, LlamaMLP, RMSNorm
-
-MAMBA, ATTENTION = "mamba", "attention"
+from .hybrid import (ATTENTION, MAMBA, HybridDecoderLayer,
+                     HybridForCausalLM, HybridModel, check_kinds)
+from .llama import LlamaAttention, LlamaConfig, LlamaMLP
 
 
 @dataclass
@@ -81,13 +73,8 @@ class GraniteMoeHybridConfig:
         if self.layer_types is None:
             self.layer_types = [ATTENTION if i % 10 == 5 else MAMBA
                                 for i in range(self.num_hidden_layers)]
-        self.layer_types = list(self.layer_types)
-        bad = set(self.layer_types) - {MAMBA, ATTENTION}
-        if bad or len(self.layer_types) != self.num_hidden_layers:
-            raise ValueError(
-                f"layer_types must name {self.num_hidden_layers} layers, "
-                f"each {MAMBA!r} or {ATTENTION!r}; got "
-                f"{len(self.layer_types)} with {sorted(bad)}")
+        self.layer_types = check_kinds(
+            self.layer_types, self.num_hidden_layers, "layer_types")
         if self.mamba_expand * self.hidden_size \
                 != self.mamba_n_heads * self.mamba_d_head:
             raise ValueError(
@@ -113,11 +100,9 @@ class GraniteMoeHybridConfig:
             attention_multiplier=self.attention_multiplier)
 
 
-class GraniteMoeHybridDecoderLayer(Layer):
+class GraniteMoeHybridDecoderLayer(HybridDecoderLayer):
     def __init__(self, config: GraniteMoeHybridConfig, kind: str):
-        super().__init__()
-        self.kind = kind
-        self.residual_multiplier = config.residual_multiplier
+        super().__init__(kind, config.residual_multiplier)
         if kind == MAMBA:
             self.mamba = Mamba2Mixer(
                 config.hidden_size, config.mamba_n_heads,
@@ -133,117 +118,22 @@ class GraniteMoeHybridDecoderLayer(Layer):
             norm_topk_prob=True, held=config.experts_held)
         self.shared_mlp = LlamaMLP(
             config._llama(config.shared_intermediate_size))
-        self.input_layernorm = RMSNorm(config.hidden_size,
-                                       config.rms_norm_eps)
-        self.post_attention_layernorm = RMSNorm(config.hidden_size,
-                                                config.rms_norm_eps)
+        self._norms(config.hidden_size, config.rms_norm_eps)
 
-    def forward(self, hidden, cache=None, pos=None, paged=None, adv=None,
-                live=None, pack=None):
-        h = self.input_layernorm(hidden)
-        new_cache = None
-        if self.kind == MAMBA:
-            h = self.mamba(h, cache=cache, pos=pos, adv=adv, pack=pack)
-        else:
-            h = self.self_attn(h, cache=cache, pos=pos, paged=paged,
-                               pack=pack)
-        if cache is not None:
-            h, new_cache = h
-        hidden = self._residual(hidden, h)
-        h = self.post_attention_layernorm(hidden)
-        h = self.block_sparse_moe(h, live=live) + self.shared_mlp(h)
-        hidden = self._residual(hidden, h)
-        return hidden if cache is None else (hidden, new_cache)
-
-    def _residual(self, hidden, branch):
-        # a Python scalar inside the traced function: the activations keep
-        # their type (bfloat16 stays bfloat16)
-        rm = self.residual_multiplier
-        return apply(lambda x, b: x + b * rm, hidden, branch)
+    def ffn(self, h, live=None):
+        return self.block_sparse_moe(h, live=live) + self.shared_mlp(h)
 
 
-class GraniteMoeHybridModel(Layer):
+def _layers(config: GraniteMoeHybridConfig):
+    return [GraniteMoeHybridDecoderLayer(config, kind)
+            for kind in config.layer_types]
+
+
+class GraniteMoeHybridModel(HybridModel):
     def __init__(self, config: GraniteMoeHybridConfig):
-        super().__init__()
-        self.config = config
-        self.embed_tokens = VocabParallelEmbedding(config.vocab_size,
-                                                   config.hidden_size)
-        self.layers = LayerList([
-            GraniteMoeHybridDecoderLayer(config, kind)
-            for kind in config.layer_types])
-        self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
-
-    def forward(self, input_ids, caches=None, pos=None, paged=None,
-                pack=None):
-        em = self.config.embedding_multiplier
-        hidden = apply(lambda e: e * em, self.embed_tokens(input_ids))
-        if caches is None:
-            for layer in self.layers:
-                hidden = layer(hidden)
-            return self.norm(hidden)
-        # what the token-wise code and the per-slot state need to know of a
-        # serving step: which positions hold a token, and how many of a
-        # row's columns do
-        adv = live = None
-        if paged is not None:
-            slot_pos = pack.slot_pos if pack is not None \
-                else jnp.reshape(getattr(pos, "data", pos), (-1,))
-            adv = paged.advance(slot_pos)
-            live = pack.live[:, None] if pack is not None else \
-                jnp.arange(input_ids.shape[1], dtype=jnp.int32) \
-                < adv[:, None]
-        new_caches = []
-        for layer, cache in zip(self.layers, caches):
-            hidden, new_cache = layer(hidden, cache=cache, pos=pos,
-                                      paged=paged, adv=adv, live=live,
-                                      pack=pack)
-            new_caches.append(new_cache)
-        return self.norm(hidden), new_caches
+        super().__init__(config, _layers(config))
 
 
-class GraniteMoeHybridForCausalLM(Layer):
+class GraniteMoeHybridForCausalLM(HybridForCausalLM):
     def __init__(self, config: GraniteMoeHybridConfig):
-        super().__init__()
-        self.config = config
-        with parameter_dtype(config.dtype):
-            self.model = GraniteMoeHybridModel(config)
-
-    def _logits(self, hidden):
-        """The tied head: the embedding's rows are the output's columns."""
-        scale = 1.0 / self.config.logits_scaling
-        return apply(lambda h, e: (h @ e.T) * scale, hidden,
-                     self.model.embed_tokens.weight)
-
-    def forward(self, input_ids, labels=None):
-        if labels is not None:
-            raise NotImplementedError(
-                "training GraniteMoeHybridForCausalLM is not wired: the "
-                "loss would lack the router's auxiliary loss, and the "
-                "recurrence (ops/ssm.py) has no backward kernel")
-        return self._logits(self.model(input_ids))
-
-    # ---- the cached-decode contract (models/generation.py) ----
-    def init_cache(self, batch_size: int, max_len: int, dtype=None):
-        cfg = self.config
-        dt = dtype or self.model.embed_tokens.weight.dtype
-        kv = (batch_size, cfg.num_key_value_heads, max_len, cfg.head_dim)
-        return [RecurrentState(*layer.mamba.init_state(batch_size, dt))
-                if layer.kind == MAMBA
-                else (jnp.zeros(kv, dt), jnp.zeros(kv, dt))
-                for layer in self.model.layers]
-
-    def forward_with_cache(self, input_ids, caches, pos, paged=None,
-                           adapters=None, pack=None):
-        if adapters is not None:
-            raise NotImplementedError(
-                "LoRA adapters are not wired into GraniteMoeHybrid")
-        hidden, new_caches = self.model(input_ids, caches=caches, pos=pos,
-                                        paged=paged, pack=pack)
-        return self._logits(hidden), new_caches
-
-    def generate(self, input_ids, max_new_tokens=32, do_sample=False,
-                 temperature=1.0, top_k=0, eos_token_id=None, seed=0):
-        from .generation import generate
-        return generate(self, input_ids, max_new_tokens, do_sample,
-                        temperature, top_k, eos_token_id=eos_token_id,
-                        seed=seed)
+        super().__init__(config, lambda: _layers(config))

@@ -1,6 +1,6 @@
 """Mamba-2 mixer (Dao & Gu 2024, as Hugging Face's `modeling_bamba.py` /
 `modeling_granitemoehybrid.py` run it; written from memory): a state-space
-layer that takes the place of attention in a decoder block.
+layer in the place of attention in a decoder block (Mamba-1's: below).
 
     [z | u | dt] = h W_in                    widths d_inner, d_conv, heads
     u'  = silu(conv_bias + causal depthwise conv of width K over u)
@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...core.tensor import apply
-from ...ops.ssm import causal_conv_update, ssm_update
+from ...ops.ssm import (causal_conv_tokens, causal_conv_update,
+                        selective_scan, selective_scan_rows, ssm_update)
 from .. import initializer as I
 from .common import Linear
 from .layers import Layer
@@ -122,6 +123,133 @@ class Mamba2Mixer(Layer):
             mix, zxbcdt, self.conv_weight, self.conv_bias, self.dt_bias,
             self.A_log, self.D, self.norm_weight, conv_state, ssm_state,
             pos)
+        out = self.out_proj(y)
+        if cache is None:
+            return out
+        return out, (new_conv, new_ssm)
+
+
+class Mamba1Mixer(Layer):
+    """Mamba-1's mixer (Gu & Dao 2023) as Jamba runs it (Hugging Face's
+    `modeling_jamba.py`, written from memory): no heads, a step size a
+    channel from a low-rank bottleneck, a decay a channel and state
+    element, and RMSNorms on the step size's bottleneck, on B and on C.
+
+        [u | z] = h W_in                         widths d_inner, d_inner
+        c   = silu(conv_bias + causal depthwise conv of width K over u)
+        [r | B | C] = c W_x                      widths dt_rank, N, N
+        r, B, C = RMSNorm(r), RMSNorm(B), RMSNorm(C)
+        dt  = softplus(r W_dt + dt_bias);  A = -exp(A_log)     [d_inner, N]
+        h_t = exp(dt_t A) h_{t-1} + (dt_t c_t) (x) B_t;  y_t = h_t C_t + D c_t
+        out = (y * silu(z)) W_out                (no norm after the gate)
+
+    What a sequence carries from one call to the next: the last K - 1
+    columns of `u` in the model's type and the state `h` in float32
+    (`ops/ssm.py` says how both are laid out). `forward` is
+    `Mamba2Mixer.forward`'s contract."""
+
+    def __init__(self, hidden_size, d_inner, state_size, dt_rank,
+                 conv_kernel=4, rms_norm_eps=1e-6):
+        super().__init__()
+        self.d_inner, self.state_size = d_inner, state_size
+        self.dt_rank, self.conv_kernel = dt_rank, conv_kernel
+        self.eps = rms_norm_eps
+        normal = I.Normal(0.0, 0.02)
+        self.in_proj = Linear(hidden_size, 2 * d_inner, weight_attr=normal,
+                              bias_attr=False)
+        self.conv_weight = self.create_parameter(
+            [d_inner, conv_kernel], default_initializer=normal)
+        self.conv_bias = self.create_parameter([d_inner], is_bias=True)
+        self.x_proj = Linear(d_inner, dt_rank + 2 * state_size,
+                             weight_attr=normal, bias_attr=False)
+        self.dt_proj = Linear(dt_rank, d_inner, weight_attr=normal)
+        # A = -exp(A_log), as published [d_inner, N]: A_log = 0 forgets
+        # with rate dt
+        self.A_log = self.create_parameter(
+            [d_inner, state_size], default_initializer=I.Constant(0.0))
+        self.D = self.create_parameter(
+            [d_inner], default_initializer=I.Constant(1.0))
+        one = I.Constant(1.0)
+        self.dt_layernorm = self.create_parameter(
+            [dt_rank], default_initializer=one)
+        self.b_layernorm = self.create_parameter(
+            [state_size], default_initializer=one)
+        self.c_layernorm = self.create_parameter(
+            [state_size], default_initializer=one)
+        self.out_proj = Linear(d_inner, hidden_size, weight_attr=normal,
+                               bias_attr=False)
+        for p in (self.conv_bias, self.dt_proj.bias, self.D,
+                  self.dt_layernorm, self.b_layernorm, self.c_layernorm):
+            p.partition_spec = P(None)
+
+    def init_state(self, batch_size: int, dtype):
+        """(conv `[batch, K - 1, d_inner]` in `dtype`, ssm `[batch, N,
+        d_inner]` float32), zeros: the recurrence's state is held in
+        float32 between steps whatever the model's type."""
+        return (jnp.zeros((batch_size, self.conv_kernel - 1, self.d_inner),
+                          dtype),
+                jnp.zeros((batch_size, self.state_size, self.d_inner),
+                          jnp.float32))
+
+    def forward(self, hidden, cache=None, pos=None, adv=None, pack=None):
+        """As `Mamba2Mixer.forward`. With `pack` nothing is unpacked: the
+        conv and the recurrence take a step's packed block as it stands,
+        each token a column of its slot (`ops/ssm.py`)."""
+        uz = self.in_proj(hidden)
+        d_inner, n_state, rank = self.d_inner, self.state_size, self.dt_rank
+        eps = self.eps
+
+        def norm(v, w):
+            v = v.astype(jnp.float32)
+            v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
+            return v * w.astype(jnp.float32)
+
+        def mix(proj, conv_w, conv_b, x_w, dt_w, dt_b, a_log, d_skip, dt_n,
+                b_n, c_n, conv_state, ssm_state, pos_):
+            u, z = proj[..., :d_inner], proj[..., d_inner:]
+            rows = conv_state.shape[0]
+            if pack is not None:
+                pos_ = pack.slot_pos
+            fresh = None
+            if pos_ is not None:
+                fresh = jnp.broadcast_to(jnp.asarray(pos_) == 0, (rows,))
+            if pack is None:
+                c, new_conv = causal_conv_update(u, conv_state, conv_w,
+                                                 conv_b, adv, fresh)
+            else:
+                start = pack.last + 1 - adv
+                c, new_conv = causal_conv_tokens(
+                    u[:, 0], conv_state, conv_w, conv_b, pack.slot,
+                    pack.col, start, adv, fresh)
+            c = c.astype(proj.dtype)
+            rbc = c @ x_w
+            r = norm(rbc[..., :rank], dt_n).astype(proj.dtype)
+            b = norm(rbc[..., rank:rank + n_state], b_n)
+            c_t = norm(rbc[..., rank + n_state:], c_n)
+            dt = jax.nn.softplus((r @ dt_w).astype(jnp.float32)
+                                 + dt_b.astype(jnp.float32))
+            a = -jnp.exp(a_log.astype(jnp.float32)).T
+            if pack is None:
+                y, new_ssm = selective_scan_rows(c, dt, a, b, c_t,
+                                                 ssm_state, adv, fresh)
+            else:
+                y, new_ssm = selective_scan(
+                    c, dt, a, b, c_t, ssm_state, start, adv, fresh,
+                    columns=pack.dst.shape[1])
+            y = y + d_skip.astype(jnp.float32) * c.astype(jnp.float32)
+            y = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+            return y.astype(proj.dtype), new_conv, new_ssm
+
+        if cache is None:
+            conv_state, ssm_state = self.init_state(
+                hidden.shape[0], hidden.dtype)
+        else:
+            conv_state, ssm_state = cache
+        y, new_conv, new_ssm = apply(
+            mix, uz, self.conv_weight, self.conv_bias, self.x_proj.weight,
+            self.dt_proj.weight, self.dt_proj.bias, self.A_log, self.D,
+            self.dt_layernorm, self.b_layernorm, self.c_layernorm,
+            conv_state, ssm_state, pos)
         out = self.out_proj(y)
         if cache is None:
             return out

@@ -75,10 +75,10 @@ sequence:
   `pallas_mode.KERNEL_TILINGS` records the choice of each trace (`grid`,
   `groups` = the most a row's loop takes, `pages`, `heads`, `rows`).
   Mosaic (jax 0.9.0 / libtpu 0.0.34, v5e) lowers it in bf16 at `block_len`
-  8 and 16 (the two sizes the repo runs), query widths 1 to 2,048, MHA and
-  GQA — including the 8-row bf16 page (half a packed sublane tile) of
-  `DEFAULT_KV_BLOCK` and the 1-row q tile of the MHA `generate()` decode
-  loop; tests/test_mosaic_aot.py pins those and the serve cells' shapes.
+  8 and 16, query widths 1 to 2,048, MHA, GQA and 20:1 multi-query (a
+  one-token row folds 20 rows, 32 in packed bf16 tiles: 37.5% of them pad;
+  a step's 16-wide rows fold 320, whole tiles) — with the 8-row bf16 page
+  and the 1-row q tile; tests/test_mosaic_aot.py pins those and the cells'.
 
 A window (PR 31). With `window=W` a query at position p sees the W keys
 `p - W < col <= p` (itself included) and the cache may be a *ring*: logical

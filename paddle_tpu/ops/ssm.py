@@ -1,6 +1,6 @@
 """The selective state recurrence of a Mamba-2 layer over the columns of one
-step, with the state carried between steps, and the causal depthwise
-convolution in front of it with its own carried columns.
+step, with the state carried between steps, the causal depthwise convolution
+in front of it with its own carried columns, and below both Mamba-1's.
 
 For one row (a sequence, or a serving slot), head h of width P and a state
 of N channels, column t of the step's T columns:
@@ -266,3 +266,422 @@ def causal_conv_update(u, conv_state, weight, bias, adv=None, fresh=None):
             lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, K - 1, 0)
         )(window, adv.astype(jnp.int32))
     return jax.nn.silu(out), carried.astype(conv_state.dtype)
+
+
+# ---- Mamba-1: a step size a channel, a decay a channel and state element ----
+#
+# For one row, channel c of `channels` and a state of N elements a channel,
+# column t of the row's live columns (`nn/layer/mamba.py::Mamba1Mixer`):
+#
+#     h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+#     y_t[c]    = sum_n h_t[n, c] C_t[n]
+#
+# `ssm_update`'s contract (`adv` live columns, `fresh` rows start from zero
+# inside the call, the `D` skip and the gate stay with the layer) and its
+# layout of the state (`[rows, N, channels]`: the N elements on the sublanes,
+# the channels on the lanes, so that `dt_t`, `x_t` and `y_t` are rows of lanes
+# and `B_t`, `C_t` columns broadcast along them). What differs:
+#
+# - there are no heads, so nothing rides in SMEM: `dt` is a row of lanes like
+#   `x`, `A` is a `[N, lanes]` float32 tile held beside the state, and the
+#   decay `exp(dt_t A)` is computed inside the column loop (one transcendental
+#   a state element a column: precomputed it would be T times the state);
+# - **the columns are token rows**: x, dt, B, C and y are `[tokens, .]`, row r's
+#   live columns the `adv[r]` consecutive token rows from `start[r]`. A serving
+#   step's packed block (`ops.attention.TokenPack`: 512 tokens for 256 slots
+#   x 16 columns) is that as it stands, so the kernel reads and writes it in
+#   place and nothing of a Mamba-1 layer is ever laid out `[slots, chunk, .]`
+#   (eight times the packed block at a decode-heavy step, once a layer); an
+#   unpacked `[rows, T, .]` call is the same with `start[r] = r T`;
+# - a row's tile is small (`[16, 1280]` float32 is 82 KB, 0.1 us of HBM time
+#   against ~0.35 us a grid step), so a grid step takes `ROWS_BLOCK` rows where
+#   `ssm_update` takes one: grid (lane block, row block), the lane block
+#   outermost so that `A`'s tile and the tokens' are fetched once a lane block
+#   and not once a grid step; a last row block that is ragged reads rows nobody
+#   owns (their `adv` is padded to 0) and its writes past the end are dropped.
+#   Inside, a row's state lives in the output block and each live column is
+#   one pass over the block's 128-lane registers (a decode row: one pass).
+#
+# One path per platform, as above: on a TPU the Mosaic kernel
+# `selective_scan`; on the CPU the same arithmetic in `jax.numpy`, counted
+# `selective_scan/scan`. The `pallas_call` sits under one module-level
+# `jax.jit` whose integers are static (`_scan_call`, as `ops/paged_attention.py`
+# keeps its own), so that the Mamba layers of one traced step share one jaxpr
+# and the lowered module holds one kernel body for them, not one a layer.
+
+SCAN_KERNEL = "selective_scan"
+# lanes a grid step holds of each of its rows: ten 128-lane registers' worth,
+# unrolled in the kernel's body (5,120 channels are four such blocks)
+SCAN_LANE_BLOCK = 1280
+# rows a grid step takes at the most
+ROWS_BLOCK = 32
+# what the tokens' blocks (x, dt and y over a lane block, float32, each held
+# twice by the pipeline) may take of VMEM: a lane block narrows to fit
+_SCAN_TOKEN_BUDGET = 20 << 20
+
+
+def _token_row(row):
+    """(the aligned eight token rows round token row `row`, which of a
+    register's sublanes it is): Mosaic loads and stores a row at any offset
+    only as that group, with the one sublane picked."""
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
+    return pl.ds(pl.multiple_of(row // 8 * 8, 8), 8), sublane == row % 8
+
+
+def _eights(arr):
+    """Whole groups of eight token rows, float32."""
+    return jnp.pad(arr.astype(F32), ((0, -arr.shape[0] % 8), (0, 0)))
+
+
+def _scan_kernel(start_ref, adv_ref, fresh_ref, a_ref, dt_ref, x_ref, b_ref,
+                 c_ref, s_ref, y_ref, out_ref):
+    block = pl.program_id(1)
+    first = block * s_ref.shape[0]
+    N, tokens = b_ref.shape
+    along = jax.lax.broadcasted_iota(jnp.int32, (N, tokens), 1)
+
+    @pl.when(block == 0)
+    def _unread():         # token rows no row owns: finite, nobody reads them
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    def one_row(r, _):
+        start = start_ref[first + r]
+        keep = fresh_ref[first + r] == 0
+        out_ref[r] = jnp.where(keep, s_ref[r], 0.0).astype(out_ref.dtype)
+
+        def one_column(t, _):
+            here = along == start + t
+
+            def column(ref):       # [N, 1] -> along the sublanes, every lane
+                return jnp.broadcast_to(
+                    jnp.sum(jnp.where(here, ref[...], 0.0), axis=1,
+                            keepdims=True), (N, LANES))
+
+            b_t, c_t = column(b_ref), column(c_ref)
+            group, pick = _token_row(start + t)
+
+            def picked(ref, sl):                               # [1, 128]
+                return jnp.sum(jnp.where(pick, ref[group, sl], 0.0), axis=0,
+                               keepdims=True)
+
+            for chunk in range(out_ref.shape[2] // LANES):
+                sl = pl.ds(chunk * LANES, LANES)
+                dt_t = picked(dt_ref, sl)
+                state = jnp.exp(dt_t * a_ref[:, sl]) \
+                    * out_ref[r, :, sl].astype(F32) \
+                    + b_t * (dt_t * picked(x_ref, sl))
+                y_t = jnp.sum(state * c_t, axis=0, keepdims=True)
+                y_ref[group, sl] = jnp.where(pick, y_t, y_ref[group, sl])
+                out_ref[r, :, sl] = state.astype(out_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, adv_ref[first + r], one_column, 0)
+        return 0
+
+    jax.lax.fori_loop(0, s_ref.shape[0], one_row, 0)
+
+
+def _scan_lane_block(lanes: int, tokens: int) -> int:
+    """The widest block of whole 128-lane registers, at most
+    `SCAN_LANE_BLOCK`, that divides `lanes` and under which the tokens'
+    three float32 blocks fit their budget."""
+    most = min(lanes, SCAN_LANE_BLOCK,
+               max(LANES, _SCAN_TOKEN_BUDGET // (24 * tokens)
+                   // LANES * LANES))
+    for b in range(most // LANES * LANES, 0, -LANES):
+        if lanes % b == 0:
+            return b
+
+
+@functools.partial(jax.jit, static_argnames=("lb", "rb", "interpret"))
+def _scan_call(x, dt, a, b, c, state, start, adv, fresh, *, lb, rb,
+               interpret):
+    """The kernel's `pallas_call` at one tiling; x, dt `[tokens, lanes]`
+    float32, b, c `[N, tokens]` float32."""
+    tokens, lanes = x.shape
+    rows, N, _ = state.shape
+    blocks = -(-rows // rb)
+    # a ragged last block reads the scalars of rows that are not there
+    pad = blocks * rb - rows
+    start, adv, fresh = (jnp.pad(v, (0, pad)) for v in (start, adv, fresh))
+    wide = pl.BlockSpec((tokens, lb), lambda g, r, *_: (0, g))
+    narrow = pl.BlockSpec((N, tokens), lambda g, r, *_: (0, 0))
+    tile = pl.BlockSpec((rb, N, lb), lambda g, r, *_: (r, 0, g))
+    return pl.pallas_call(
+        _scan_kernel,
+        out_shape=(jax.ShapeDtypeStruct(x.shape, F32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(lanes // lb, blocks),
+            in_specs=[pl.BlockSpec((N, lb), lambda g, r, *_: (0, g)),
+                      wide, wide, narrow, narrow, tile],
+            out_specs=[wide, tile]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        # as `ssm_update`: the new state takes the state's buffer
+        input_output_aliases={8: 1},
+        interpret=interpret,
+        name=SCAN_KERNEL,
+    )(start, adv, fresh, a, dt, x, b, c, state)
+
+
+def _scan_columns(x, dt, a, b, c, state, start, adv, fresh, columns):
+    """The kernel's arithmetic, column after column, in `jax.numpy`: each
+    row's columns gathered out of the token rows, and y scattered back."""
+    tokens = x.shape[0]
+    t = jnp.arange(columns, dtype=jnp.int32)
+    live = t[None, :] < adv[:, None]                       # [rows, columns]
+    at = jnp.where(live, start[:, None] + t[None, :], tokens)
+
+    def of_rows(arr):       # [tokens, w] -> [columns, rows, w], 0 where dead
+        arr = jnp.pad(arr.astype(F32), ((0, 1), (0, 0)))
+        return jnp.swapaxes(arr[at], 0, 1)
+
+    def one_column(s, col):
+        x_t, dt_t, b_t, c_t = col
+        # a dead column's step is 0: its decay 1, its input nothing
+        s = jnp.exp(dt_t[:, None, :] * a) * s \
+            + b_t[:, :, None] * (dt_t * x_t)[:, None, :]
+        return s, jnp.sum(s * c_t[:, :, None], axis=1)
+
+    s0 = jnp.where(fresh[:, None, None] != 0, 0.0, state.astype(F32))
+    s, ys = jax.lax.scan(one_column, s0,
+                         tuple(of_rows(arr) for arr in (x, dt, b, c)))
+    y = jnp.zeros((tokens + 1, x.shape[1]), F32).at[at].set(
+        jnp.swapaxes(ys, 0, 1))[:tokens]
+    return y, s.astype(state.dtype)
+
+
+def selective_scan(x, dt, a, b, c, state, start, adv, fresh=None, *,
+                   columns: int, impl: str = None):
+    """x, dt `[tokens, channels]` (dt positive: after its softplus); a
+    `[N, channels]` float32, negative (`A` transposed: the state's layout);
+    b, c `[tokens, N]`; state `[rows, N, channels]` in its storage type
+    (float32 in a serving pool); start `[rows]` the token row of each row's
+    first column, adv `[rows]` how many consecutive token rows from there
+    are its live columns (at most `columns`, a static bound; rows' runs do
+    not overlap); fresh `[rows]` rows that start from zero (None: none).
+    Returns (y `[tokens, channels]` float32, without the `D` skip, zero on a
+    token row that is no row's live column; the state after each row's `adv`
+    columns, in `state.dtype`).
+    impl: as `ssm_update` (the parity test narrows `SCAN_LANE_BLOCK` and
+    `ROWS_BLOCK` so that a small call has several blocks of each)."""
+    tokens, lanes = x.shape
+    rows, N = state.shape[:2]
+    if dt.shape != x.shape or b.shape != (tokens, N) or c.shape != b.shape \
+            or a.shape != (N, lanes) or state.shape != (rows, N, lanes) \
+            or start.shape != (rows,) or adv.shape != (rows,):
+        raise ValueError(f"selective_scan: x {x.shape}, dt {dt.shape}, a "
+                         f"{a.shape}, b {b.shape}, c {c.shape}, state "
+                         f"{state.shape}, start {start.shape}, adv "
+                         f"{adv.shape}")
+    if impl is None:
+        impl = "scan" if pallas_mode.platform() == "cpu" else "pallas"
+    if impl not in ("scan", "pallas"):
+        raise ValueError(f'impl must be "scan" or "pallas", got {impl!r}')
+    start, adv = start.astype(jnp.int32), adv.astype(jnp.int32)
+    fresh = jnp.zeros((rows,), jnp.int32) if fresh is None \
+        else fresh.astype(jnp.int32)
+    a = a.astype(F32)
+    if impl == "scan":
+        pallas_mode.count(SCAN_KERNEL, "scan")
+        return _scan_columns(x, dt, a, b, c, state, start, adv, fresh,
+                             columns)
+    if lanes % LANES or N % 8:
+        raise ValueError(
+            f"selective_scan kernel: {lanes} channels must fill whole "
+            f"{LANES}-lane registers, and the state's {N} elements a "
+            "channel whole sublane tiles")
+    lb = _scan_lane_block(lanes, tokens)
+    rb = min(rows, ROWS_BLOCK)
+    pallas_mode.note_tiling(SCAN_KERNEL, grid=(lanes // lb, -(-rows // rb)),
+                            columns=columns, state_tile=(rb, N, lb))
+    y, state = _scan_call(
+        _eights(x), _eights(dt), a, _eights(b).T, _eights(c).T, state, start,
+        adv, fresh, lb=lb, rb=rb,
+        interpret=pallas_mode.interpret(SCAN_KERNEL))
+    return y[:tokens], state
+
+
+CONV_KERNEL = "conv_tokens"
+
+
+def _conv_kernel(start_ref, adv_ref, fresh_ref, w_ref, bias_ref, u_ref,
+                 prev_ref, out_ref, new_ref):
+    """`_scan_kernel`'s walk (rows of a block, a row's live columns, a
+    column's 128-lane registers) for the convolution: a row's K - 1 carried
+    columns live in the output block and move up one a live column."""
+    block = pl.program_id(1)
+    first = block * prev_ref.shape[0]
+    K = w_ref.shape[0]
+
+    @pl.when(block == 0)
+    def _unread():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    def one_row(r, _):
+        start = start_ref[first + r]
+        keep = fresh_ref[first + r] == 0
+        new_ref[r] = jnp.where(keep, prev_ref[r], 0.0)
+
+        def one_column(t, _):
+            group, pick = _token_row(start + t)
+            for chunk in range(new_ref.shape[2] // LANES):
+                sl = pl.ds(chunk * LANES, LANES)
+                u_t = jnp.sum(jnp.where(pick, u_ref[group, sl], 0.0), axis=0,
+                              keepdims=True)
+                older = [new_ref[r, j:j + 1, sl] for j in range(K - 1)]
+                acc = bias_ref[:, sl] + w_ref[K - 1:K, sl] * u_t
+                for j in range(K - 1):
+                    acc = acc + w_ref[j:j + 1, sl] * older[j]
+                out_ref[group, sl] = jnp.where(pick, jax.nn.silu(acc),
+                                               out_ref[group, sl])
+                for j, column in enumerate(older[1:] + [u_t]):
+                    new_ref[r, j:j + 1, sl] = column
+            return 0
+
+        jax.lax.fori_loop(0, adv_ref[first + r], one_column, 0)
+        return 0
+
+    jax.lax.fori_loop(0, prev_ref.shape[0], one_row, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("lb", "rb", "interpret"))
+def _conv_call(u, prev, w, bias, start, adv, fresh, *, lb, rb, interpret):
+    """The conv's `pallas_call` at one tiling; u `[tokens, lanes]`, prev
+    `[rows, K - 1, lanes]`, w `[K, lanes]`, bias `[1, lanes]`, all float32."""
+    tokens, lanes = u.shape
+    rows, carried, _ = prev.shape
+    blocks = -(-rows // rb)
+    pad = blocks * rb - rows
+    start, adv, fresh = (jnp.pad(v, (0, pad)) for v in (start, adv, fresh))
+    wide = pl.BlockSpec((tokens, lb), lambda g, r, *_: (0, g))
+    tile = pl.BlockSpec((rb, carried, lb), lambda g, r, *_: (r, 0, g))
+    return pl.pallas_call(
+        _conv_kernel,
+        out_shape=(jax.ShapeDtypeStruct(u.shape, F32),
+                   jax.ShapeDtypeStruct(prev.shape, F32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(lanes // lb, blocks),
+            in_specs=[pl.BlockSpec((carried + 1, lb), lambda g, r, *_: (0, g)),
+                      pl.BlockSpec((1, lb), lambda g, r, *_: (0, g)),
+                      wide, tile],
+            out_specs=[wide, tile]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name=CONV_KERNEL,
+    )(start, adv, fresh, w, bias, u, prev)
+
+
+def _conv_gathers(u, prev, w, bias, slot, col, start, adv, fresh):
+    """The kernel's arithmetic in `jax.numpy`: a token's older inputs are
+    the token rows above it or its row's carried columns."""
+    tokens, K = u.shape[0], w.shape[0]
+    prev = jnp.where(fresh[:, None, None] != 0, 0.0, prev)
+    out = bias + w[K - 1] * u
+    own = jnp.take(prev, slot, axis=0)                 # [tokens, K - 1, D]
+    for back in range(1, K):
+        older = jnp.pad(u, ((back, 0), (0, 0)))[:tokens]
+        for c in range(back):      # in the row's first `back` columns
+            older = jnp.where((col == c)[:, None], own[:, K - 1 - back + c],
+                              older)
+        out = out + w[K - 1 - back] * older
+    # the last K - 1 of concat(carried, live columns), oldest first
+    p = adv[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+    live = jnp.take(u, jnp.clip(start[:, None] + p - (K - 1), 0, tokens - 1),
+                    axis=0)
+    carried = jnp.where(
+        (p >= K - 1)[..., None], live,
+        jnp.take_along_axis(prev, jnp.minimum(p, K - 2)[..., None], axis=1))
+    return jax.nn.silu(out), carried
+
+
+def causal_conv_tokens(u, conv_state, weight, bias, slot, col, start, adv,
+                       fresh=None, impl: str = None):
+    """`causal_conv_update` over token rows: u `[tokens, D]`, token t the
+    column `col[t]` of row `slot[t]`, row r's live columns the `adv[r]`
+    token rows from `start[r]` (`ops.attention.TokenPack`'s layout);
+    conv_state `[rows, K - 1, D]`. Returns (the activated convolution
+    `[tokens, D]` float32, whatever on a token row that is no live column;
+    the carried columns after each row's live ones, in `conv_state.dtype`).
+    On a TPU the Mosaic kernel `conv_tokens` (the tiling and the walk of
+    `selective_scan`: XLA's row gathers of the same took a quarter of a
+    millisecond a layer); on the CPU the same in `jax.numpy`. impl: as
+    `ssm_update`."""
+    tokens, lanes = u.shape
+    rows = conv_state.shape[0]
+    if impl is None:
+        impl = "scan" if pallas_mode.platform() == "cpu" else "pallas"
+    if impl not in ("scan", "pallas"):
+        raise ValueError(f'impl must be "scan" or "pallas", got {impl!r}')
+    start, adv = start.astype(jnp.int32), adv.astype(jnp.int32)
+    fresh = jnp.zeros((rows,), jnp.int32) if fresh is None \
+        else fresh.astype(jnp.int32)
+    # the inputs as the model holds them (rounded to its type), in float32
+    u32 = u.astype(F32)
+    prev = conv_state.astype(u.dtype).astype(F32)
+    w, b = weight.astype(F32).T, bias.astype(F32)
+    if impl == "scan" or lanes % LANES:
+        pallas_mode.count(CONV_KERNEL, "scan")
+        out, carried = _conv_gathers(u32, prev, w, b, slot, col, start, adv,
+                                     fresh)
+        return out, carried.astype(conv_state.dtype)
+    lb = _scan_lane_block(lanes, tokens)
+    rb = min(rows, ROWS_BLOCK)
+    pallas_mode.note_tiling(CONV_KERNEL, grid=(lanes // lb, -(-rows // rb)),
+                            tile=(rb, w.shape[0] - 1, lb))
+    out, carried = _conv_call(
+        _eights(u32), prev, w, b[None], start, adv, fresh, lb=lb, rb=rb,
+        interpret=pallas_mode.interpret(CONV_KERNEL))
+    return out[:tokens], carried.astype(conv_state.dtype)
+
+
+# tokens one call of the kernel takes at the most (their blocks live in
+# VMEM); a longer `[rows, T]` block is walked in chunks of columns, state
+# carried
+MAX_TOKENS = 4096
+
+
+def selective_scan_rows(x, dt, a, b, c, state, adv=None, fresh=None,
+                        impl: str = None):
+    """`selective_scan` for columns laid out `[rows, T, .]` (one-shot
+    `generate()`, an unpacked step): x, dt `[rows, T, channels]`, b, c
+    `[rows, T, N]`, adv `[rows]` live columns of each row (None: all T).
+    Returns (y `[rows, T, channels]` float32, the new state)."""
+    rows, T, lanes = x.shape
+    adv = jnp.full((rows,), T, jnp.int32) if adv is None \
+        else adv.astype(jnp.int32)
+    fresh = jnp.zeros((rows,), jnp.int32) if fresh is None \
+        else fresh.astype(jnp.int32)
+    step = max(1, min(T, MAX_TOKENS // rows))
+    start = jnp.arange(rows, dtype=jnp.int32) * step
+
+    def call(x_k, dt_k, b_k, c_k, s, n, new):
+        y, s = selective_scan(
+            *(arr.reshape(rows * step, arr.shape[2])
+              for arr in (x_k, dt_k)), a,
+            *(arr.reshape(rows * step, arr.shape[2]) for arr in (b_k, c_k)),
+            s, start, n, new, columns=step, impl=impl)
+        return y.reshape(rows, step, lanes), s
+
+    if T == step:
+        return call(x, dt, b, c, state, adv, fresh)
+    pad = -T % step
+
+    def chunks(arr):
+        arr = jnp.pad(arr, ((0, 0), (0, pad), (0, 0)))
+        return jnp.swapaxes(arr.reshape(rows, -1, step, arr.shape[2]), 0, 1)
+
+    def one_chunk(carry, chunk):
+        s, left, new = carry
+        y_k, s = call(*chunk, s, jnp.clip(left, 0, step), new)
+        return (s, left - step, jnp.zeros_like(new)), y_k
+
+    (state, _, _), ys = jax.lax.scan(
+        one_chunk, (state, adv, fresh),
+        tuple(chunks(arr) for arr in (x, dt, b, c)))
+    y = jnp.swapaxes(ys, 0, 1).reshape(rows, T + pad, lanes)
+    return y[:, :T], state

@@ -34,9 +34,9 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 50 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 59 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
-    assert len(paged) == 13         # tools/mosaic_aot_check.py's two lists
+    assert len(paged) == 15         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
     assert len(window) == 2 and all("slab=[32, 4, 1056, 128]" in c
                                     for c in window)
@@ -47,9 +47,41 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     assert tilings and all("'pages': 8" in t or "'pages': 16" in t
                            for t in tilings)
     for grid, groups in (("(128, 1)", 2), ("(32, 1)", 9), ("(32, 1)", 65),
-                         ("(32, 1)", 10)):
+                         ("(32, 1)", 10), ("(256, 1)", 20)):
         assert any(f"'grid': {grid}, 'groups': {groups}," in t
                    and "'pages': 8" in t for t in tilings), (grid, groups)
+    # multi-query 20:1: the whole group folds into one tile's rows, 320 at
+    # the step's 16-wide rows and 20 at a one-token row
+    for rows in (320, 20):
+        assert any(f"'heads': 1, 'pages': 8, 'rows': {rows}" in t
+                   for t in tilings), rows
+
+
+def test_the_mamba1_recurrence_compiles_at_the_reasoning_cells_shapes(tool):
+    """`selective_scan` for the v5e at Jamba2-3B's widths: a float32 state
+    of 16 x 5,120 a row, the columns the packed step's 512 token rows, 32
+    rows and 1,280 lanes a grid step at the cell's 256 slots; a prompt of
+    3,000 columns in chunks whose tokens narrow the lane block; and in an
+    engine's step the Mamba-1 layers share one body."""
+    cases = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] selective_scan bf16")]
+    assert len(cases) == 3 and all("{'selective_scan': 1}" in c
+                                   for c in cases)
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling selective_scan")]
+    assert any("'columns': 16, 'grid': (4, 8), 'state_tile': (32, 16, 1280)"
+               in t for t in tilings), tilings
+    assert any("'columns': 2048, 'grid': (40, 1), 'state_tile': (2, 16, 128)"
+               in t for t in tilings), tilings
+    conv = [ln for ln in tool.splitlines()
+            if ln.startswith("[OK] conv_tokens bf16")]
+    assert len(conv) == 2 and all("{'conv_tokens': 1}" in c for c in conv)
+    assert any(ln.startswith("tiling conv_tokens") and "'grid': (4, 8), "
+               "'tile': (32, 3, 1280)" in ln for ln in tool.splitlines())
+    step, = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] serve step, 3 Mamba-1 layers")]
+    assert ": 3 Mosaic bodies " in step
+    assert "'selective_scan': 3" in step and "'paged_attention': 1" in step
 
 
 def test_a_recomputed_layer_holds_one_flash_forward_for_the_v5e(tool):
@@ -74,8 +106,8 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     Mosaic body, that of an engine with window and full layers two, and
     the compiled step still a custom call a layer."""
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
-    assert len(steps) == 5
-    full, mixed, hybrid, latent, indexed = steps
+    assert len(steps) == 6
+    full, mixed, hybrid, _, latent, indexed = steps
     # an indexer's layers (PR 39): the three-slab write and the two-slab
     # one, the gathered and the masked walk, one scoring and one top-k for
     # both "full" layers, three sparse layers' grouped matmuls
@@ -184,7 +216,7 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
     row is a read-modify-write of the 32 aligned columns that hold it."""
     cases = [ln for ln in tool.splitlines()
              if ln.startswith("[OK] kv_write bf16")]
-    assert len(cases) == 10
+    assert len(cases) == 11
     # a sparse layer's three slabs (latent, rotary key, index key) in the
     # one call, at the sessions cell's 16 rows of 36,880 columns
     assert any("slab=[16, 1, 36880] x 512 | 128 | 128" in ln for ln in cases)
@@ -195,7 +227,8 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
     for slab in ("[128, 8, 240] x 128 | 128", "[32, 8, 1056] x 128 | 128",
                  "[128, 16, 240] x 128 | 128", "[32, 4, 8304] x 128 | 128",
                  "[32, 4, 1056] x 128 | 128 T=16 ring=1040",
-                 "[32, 1, 8304] x 512 | 128", "[1, 8, 2064] x 128 | 128"):
+                 "[32, 1, 8304] x 512 | 128", "[256, 1, 2576] x 128 | 128",
+                 "[1, 8, 2064] x 128 | 128"):
         assert any(f"slab={slab}" in ln for ln in cases), slab
     tilings = [ln for ln in tool.splitlines()
                if ln.startswith("tiling kv_write")]

@@ -206,6 +206,14 @@ _KERNEL_CASES = [
     pytest.param(4, 2, 4, 16, 16, "identity", None, id="bl16-width16-whole"),
     pytest.param(8, 4, 4, 16, 11, "fragmented", (2, 2),
                  id="bl16-width11-G2"),
+    # multi-query, 20 query heads on one KV head (a ratio that is no power
+    # of two): the step's chunk rows (80 folded rows), a one-token row (20:
+    # not a whole sublane tile), and the group split over tiles of 5 heads
+    pytest.param(20, 1, 4, 16, 3, "fragmented", None,
+                 id="mqa20-chunk-bl16-frag"),
+    pytest.param(20, 1, 1, 16, 11, "shared", None, id="mqa20-tq1-width11"),
+    pytest.param(20, 1, 4, 8, 3, "identity", (1, 5),
+                 id="mqa20-chunk-bl8-G4-split-group"),
 ]
 
 
@@ -489,6 +497,9 @@ def test_ring_write_splits_a_stripe_that_straddles_the_rings_end():
     ("mistral prefill", (32, 32, 16, 128), 8, 16, (8, 4)),
     ("olmoe decode", (128, 16, 16, 128), 16, 16, (16, 1)),
     ("mellum step", (32, 32, 16, 128), 4, 16, (4, 8)),
+    ("mqa 20:1 reasoning step", (256, 20, 16, 128), 1, 16, (1, 20)),
+    ("mqa 20:1 decode loop", (8, 20, 1, 128), 1, 16, (1, 20)),
+    ("mqa 20:1 prompt 512", (1, 20, 512, 128), 1, 16, (1, 4)),
     # one-shot generate(): the decode loop, then whole-prompt prefills
     ("gqa decode loop", (8, 32, 1, 128), 8, 8, (8, 4)),
     ("gqa prompt 512", (2, 32, 512, 128), 8, 8, (1, 4)),

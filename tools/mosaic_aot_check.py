@@ -308,6 +308,12 @@ def main() -> int:
          64),
         ("GQA block_len=16 Tq=512, G>1", (2, 32, 512, D), (2, 8, 528, D), 16,
          33),
+        # multi-query, 20 query heads on one KV head: the reasoning cell's
+        # step (320 folded rows a tile) and a one-token row (20 rows: two
+        # and a half sublane tiles, padded to 24)
+        ("MQA 20:1 reasoning cell", (256, 20, 16, D), (256, 1, 2576, D), 16,
+         160),
+        ("MQA 20:1 Tq=1", (8, 20, 1, D), (8, 1, 2576, D), 16, 160),
     ]
     for label, q_shape, slab, bl, ppr in paged_cases:
         def paged(q, k, v, t, sl, qp, bl=bl, ppr=ppr):
@@ -427,6 +433,7 @@ def main() -> int:
             ("sessions cell, full layers", (16, 1, 36880), (512, D, D),
              None),
             ("granite decode cell", (128, 8, 240), (D, D), None),
+            ("reasoning cell", (256, 1, 2576), (D, D), None),
             ("a batch of one", (1, 8, 2064), (D, D), None)):
         results.append(kv_write_case(label, slab, widths, 16, ring, one))
     body = kernel_equations(
@@ -468,6 +475,43 @@ def main() -> int:
             spec((rows, T, 128), jnp.bfloat16),
             spec((rows, 128, 8192), jnp.bfloat16), spec((rows,), jnp.int32),
             spec((rows,), jnp.int32), want={"ssm_update": 1}))
+    # the Mamba-1 recurrence at Jamba2-3B's widths (a float32 state, the
+    # columns token rows): the reasoning cell's packed step (256 slots, 512
+    # tokens, 32 rows a grid step), one-shot generate()'s decode step and a
+    # whole prompt walked in chunks of columns
+    import functools
+    from paddle_tpu.ops.ssm import selective_scan, selective_scan_rows
+    for rows, tokens, columns in ((256, 512, 16), (2, 2, 1)):
+        results.append(compile_case(
+            f"selective_scan bf16 rows={rows} tokens={tokens} "
+            "state=f32[16,5120]",
+            functools.partial(selective_scan, columns=columns),
+            spec((tokens, 5120), jnp.bfloat16),
+            spec((tokens, 5120), jnp.float32), spec((16, 5120), jnp.float32),
+            spec((tokens, 16), jnp.float32), spec((tokens, 16), jnp.float32),
+            spec((rows, 16, 5120), jnp.float32), spec((rows,), jnp.int32),
+            spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+            want={"selective_scan": 1}))
+    # the conv over the same token rows, the carried columns as the pool
+    # holds them
+    from paddle_tpu.ops.ssm import causal_conv_tokens
+    for rows, tokens in ((256, 512), (2, 2)):
+        results.append(compile_case(
+            f"conv_tokens bf16 rows={rows} tokens={tokens} "
+            "carried=[3,5120]", causal_conv_tokens,
+            spec((tokens, 5120), jnp.bfloat16),
+            spec((rows, 3, 5120), jnp.bfloat16),
+            spec((5120, 4), jnp.bfloat16), spec((5120,), jnp.bfloat16),
+            spec((tokens,), jnp.int32), spec((tokens,), jnp.int32),
+            spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+            spec((rows,), jnp.int32), want={"conv_tokens": 1}))
+    results.append(compile_case(
+        "selective_scan bf16 rows=2 T=3000 (chunks of 2048 columns) "
+        "state=f32[16,5120]", selective_scan_rows,
+        spec((2, 3000, 5120), jnp.bfloat16),
+        spec((2, 3000, 5120), jnp.float32), spec((16, 5120), jnp.float32),
+        spec((2, 3000, 16), jnp.float32), spec((2, 3000, 16), jnp.float32),
+        spec((2, 16, 5120), jnp.float32), want={"selective_scan": 1}))
     # the unified step of an engine, its layers unrolled: a kernel's
     # jitted entry gives the lowered module one Mosaic body a distinct
     # (shapes, window) pair (the paged kernels) or (shapes, ring) pair
@@ -503,6 +547,20 @@ def main() -> int:
     model.eval()
     results.append(serve_step_case("serve step, 2 recurrent layers + 1 full",
                                    model, dev1[0], 13))
+    # Mamba-1 layers round a multi-query layer: the three recurrent layers
+    # share one `selective_scan` body (its entry is jitted, where
+    # `ssm_update`'s is a body a layer), and the float32 state is aliased
+    # beside the bfloat16 conv columns and pages (8 slots x 16 columns are
+    # not wider than a packed step: the unpacked form, without `conv_tokens`)
+    from paddle_tpu.models.jamba import JambaConfig, JambaForCausalLM
+    model = JambaForCausalLM(JambaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=4, num_attention_heads=2, num_key_value_heads=1,
+        attn_layer_period=4, attn_layer_offset=1, mamba_dt_rank=16,
+        max_position_embeddings=1024, dtype="bfloat16"))
+    model.eval()
+    results.append(serve_step_case(
+        "serve step, 3 Mamba-1 layers + 1 multi-query", model, dev1[0], 3))
     # latent pages in the donated pool: three MLA layers share one
     # `paged_latent` body and one `kv_write` body; two sparse layers'
     # grouped matmuls, 3 each
