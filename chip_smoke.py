@@ -53,7 +53,9 @@ BUDGET_S = 1140.0
 # 128, FFN 8192, vocab 50,304 (models/gpt.py GPT_PRESETS).
 FULL = dict(
     preset="gpt3-1.3b", batch_per_chip=4, seq=1024, scan_steps=4,
-    flash_shape=(2, 16, 1024, 128),
+    # the smoke's own training shape, then the train cells' (a grid step
+    # there holds K and V of 2,048 keys and loops over four sub-tiles)
+    flash_shapes=((2, 16, 1024, 128), (8, 16, 2048, 128)),
     # 127 pages x 16 tokens + the pool's 16-token write pad = the model's
     # 2,048 positions (init_cache refuses a slab longer than the learned
     # position table, so "8 x 2,048" is 2,032 addressable tokens per slot)
@@ -79,7 +81,7 @@ FULL = dict(
 )
 TINY = dict(
     preset="gpt2-tiny", batch_per_chip=2, seq=128, scan_steps=2,
-    flash_shape=(1, 2, 512, 64),
+    flash_shapes=((1, 2, 512, 64),),
     slots=4, n_blocks=31, prompts=(12, 40, 100, 200), max_new=8,
     paged=(dict(heads=4, kv_heads=2, head_dim=64, pages=4),
            dict(heads=2, kv_heads=2, head_dim=64, pages=4)),
@@ -203,6 +205,11 @@ def _max_err(a, b) -> float:
 # --------------------------------------------------------------------------
 
 def _flash_parity(size: dict, rehearsal: bool):
+    for shape in size["flash_shapes"]:
+        _flash_parity_at(shape, rehearsal)
+
+
+def _flash_parity_at(shape: tuple, rehearsal: bool):
     """Flash fwd + dq/dk/dv against `_attention_reference` on this device,
     bf16 causal. Tolerance: both sides feed bf16 operands to fp32-
     accumulating dots and round the result to bf16, which keeps 8
@@ -210,8 +217,8 @@ def _flash_parity(size: dict, rehearsal: bool):
     in [4, 8). Outputs are softmax averages of unit-normal v (the first
     rows see only a few keys, so |o| reaches 4-5); gradients of
     sum(o * w) with unit-normal w have the same scale. The two paths round
-    p to bf16 at different points of the accumulation (per 256-wide block
-    after the running rescale vs once per row), so results may differ by
+    p to bf16 at different points of the accumulation (per sub-tile of
+    `_choose_tiles` after the running rescale vs once per row), so results may differ by
     one ulp: 4e-2 absolute admits one ulp anywhere below 8 and is ~25x
     below what a wrong mask, offset or block would produce (O(1)). (First
     chip run, PR 21: o 1.56e-2, dq 1.86e-2, dk 1.56e-2, dv 1.56e-2.)"""
@@ -220,7 +227,7 @@ def _flash_parity(size: dict, rehearsal: bool):
     import numpy as np
 
     from paddle_tpu.ops import attention as A
-    B, H, S, D = size["flash_shape"]
+    B, H, S, D = shape
     rng = np.random.RandomState(0)
     q, k, v, w = (jnp.asarray(rng.randn(B, H, S, D), jnp.bfloat16)
                   for _ in range(4))
@@ -272,6 +279,27 @@ def _flash_parity(size: dict, rehearsal: bool):
              "flash dropout_p=0.1 output differs from dropout 0")
     _require(_max_err(first[0], again[0]) == 0.0,
              "flash dropout is a function of the seed (same seed, same bits)")
+    # the three kernels regenerate one keep-mask: with o linear in v,
+    # <dv, v> = <w, o> only if the dkv kernel drew the forward's bits, and
+    # with the scores bilinear in q and k, <dq, q> = <dk, k> only if dq and
+    # dkv drew the same. Both sides are sums of bf16 results, so they agree
+    # to ~1e-6 of |grad| |operand| (PR 45's chip run: 1.0e-6 / 1.7e-7 here,
+    # 7e-7 with no dropout at all); another seed's bits on one side read
+    # 1.5e-4 / 7.0e-4 at [2, 16, 1024, 128] (5e-6 / 2.1e-4 at the cells'
+    # shape, where the first sum averages the noise away)
+    def f32(x):
+        return x.astype(jnp.float32)
+    o, dq, dk, dv = map(f32, first)
+
+    def gap(a, b, grad, operand):
+        return float(jnp.abs(a - b) / (jnp.linalg.norm(grad)
+                                       * jnp.linalg.norm(f32(operand))))
+    v_gap = gap(jnp.sum(dv * f32(v)), jnp.sum(f32(w) * o), dv, v)
+    qk_gap = gap(jnp.sum(dq * f32(q)), jnp.sum(dk * f32(k)), dq, q)
+    _say(f"  dropout adjoint identities: <dv,v>-<w,o> {v_gap:.2e}, "
+         f"<dq,q>-<dk,k> {qk_gap:.2e} of |grad||operand| (tolerance 1e-5)")
+    _require(v_gap <= 1e-5 and qk_gap <= 1e-5,
+             "flash dropout draws the same keep-bits in forward, dq and dkv")
 
 
 def _train_strategy(n_dev: int, scan_steps: int, layout: str = ""):
@@ -374,9 +402,10 @@ def leg_train(size: dict, rehearsal: bool, layout: str = "") -> dict:
              "the XLA reference there by design)")
     else:
         n_layers = model.config.num_hidden_layers
-        # per layer: one forward, one recomputed forward (use_recompute),
+        # per layer: one forward (the recomputed layer keeps its output
+        # and log-sum-exp since PR 41, so the replay holds no second one),
         # one dq and one dkv kernel — in the compiled text, not the trace
-        for name, want in (("flash_fwd", 2 * n_layers),
+        for name, want in (("flash_fwd", n_layers),
                            ("flash_bwd_dq", n_layers),
                            ("flash_bwd_dkv", n_layers)):
             _require(kernels.get(name, 0) == want,

@@ -5,21 +5,42 @@ mask+softmax for GPT) and multihead_matmul_op.cu — the reference has NO flash
 attention (SURVEY header); this is a parity-plus op named in the north star.
 
 Design (pallas_guide.md):
-- forward: Pallas kernel, grid (batch*heads, q_blocks, k_blocks), online-softmax
-  with VMEM scratch carried across the innermost k steps; QK^T and PV hit the
-  MXU with fp32 accumulation; causal blocks strictly in the future are skipped
-  entirely (not just masked) so the causal path does ~half the FLOPs.
-- backward: two Pallas kernels — dq over (bh, q_blocks, k_blocks) and dk/dv
-  over (bh, k_blocks, q_blocks) — recomputing probabilities from the saved row
-  logsumexp, O(S·block) memory. delta = rowsum(dO·O) is one cheap XLA reduce.
+- a grid step holds a causal ROW of score tiles, not one tile: the forward
+  kernel (and dq) owns a block of queries, keeps K and V of the batch-head
+  whole in VMEM (fetched once a batch-head) and loops inside the kernel over
+  key sub-tiles up to the diagonal; dk/dv owns a block of keys, keeps q and
+  dO whole and loops over query sub-tiles from the diagonal on. The trip
+  counts are the causal bounds, so a dead tile is neither visited nor
+  fetched and the causal path does half the FLOPs. Extents too long for
+  VMEM are cut into chunks the grid's last axis walks (`_choose_tiles`
+  says where).
+- the causal iota/compare/select runs only on the sub-tiles the diagonal
+  cuts; tiles wholly under it take an unmasked body. Masked entries score
+  `_NEG_INF` and contribute exactly 0.
+- forward: online softmax with VMEM scratch (acc, m, l) carried across the
+  loop; QK^T and PV hit the MXU with fp32 accumulation. The row logsumexp
+  is written lane-dense, `[bh, 1, Sq]`.
+- backward: two Pallas kernels recomputing probabilities from the saved
+  logsumexp, O(S·block) memory. Per-query statistics stay lane-dense end
+  to end: dq relays its block's `[1, block_q]` slice of lse to a column
+  once a grid step and makes delta = rowsum(dO·O) from its own dO and O
+  blocks (also writing it out as a row); dk/dv computes the tile
+  transposed, sT = k qT, so lse and delta broadcast as the rows they are
+  and dv += pT dO, dk += dsT q are plain products. No `[bh, Sq, 1]` array
+  (128 x padded on a TPU) exists.
+- tile sizes come from the shapes (`_choose_tiles`): one sub-tile shared by
+  the three kernels, so that dropout regenerates the same bits in each.
 - rectangular (cross) attention: causal masking uses the bottom-right offset
-  (q_offset = Sk - Sq), matching the XLA reference path.
-- additive mask: [B, 1|H, Sq, Sk] streamed blockwise into both kernels.
-- dropout: in-kernel TPU PRNG seeded per (bh, q_block, k_block) so forward and
-  backward regenerate identical keep-masks without storing O(S²) bits. The
-  keep-mask applies to the normalized probs (acc uses dropped p, the softmax
-  denominator uses undropped p — algebraically identical to dropout(softmax)).
-  Not available in CPU interpret mode (pltpu.prng has no CPU lowering).
+  (q_offset = Sk - Sq), matching the XLA reference path; the diagonal's
+  place and the loops' bounds take the offset.
+- additive mask: [B, 1|H, Sq, Sk] read a sub-tile at a time in every kernel
+  (every sub-tile then takes the masked body).
+- dropout: in-kernel TPU PRNG seeded per (bh, q sub-tile, k sub-tile) so
+  forward and backward regenerate identical keep-masks without storing
+  O(S²) bits. The keep-mask applies to the normalized probs (acc uses
+  dropped p, the softmax denominator uses undropped p — algebraically
+  identical to dropout(softmax)). Not available in CPU interpret mode
+  (pltpu.prng has no CPU lowering).
 """
 from __future__ import annotations
 
@@ -34,12 +55,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import kv_write as kvw, pallas_mode
-
-# 256x256 is the block config chip_smoke.py compiles and checks against the
-# reference on a v5e; other sizes (the block_q / block_k arguments of
-# flash_attention) have not been run on this kernel
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 256
 
 # trace-time flag: the SPMD step sets this while the sequence dim is
 # GSPMD-sharded over the `sep` axis. With a mesh attached, attention drops
@@ -237,203 +252,377 @@ def _dot(a, b, a_dim, b_dim):
 
 
 def _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, shape, dropout_p):
-    """Deterministic per-block dropout keep-mask from the TPU PRNG; the same
-    (seed, block) pair regenerates the same bits in forward and backward.
-    seed_ref is a traced SMEM scalar, so a fresh per-step seed does NOT
-    retrace/recompile the kernel."""
+    """Deterministic per-tile dropout keep-mask from the TPU PRNG; the same
+    (seed, tile) pair regenerates the same bits in forward and backward:
+    the three kernels share one sub-tile (`FlashTiles`), count its index
+    over the whole sequence and draw `shape` = `[block_q, block_k]`
+    (`flash_bwd_dkv`, which computes the tile transposed, transposes the
+    bits). seed_ref is a traced SMEM scalar, so a fresh per-step seed does
+    NOT retrace/recompile the kernel."""
     pltpu.prng_seed(seed_ref[0] + ((b * n_qb + qi) * n_kb + kb))
     bits = pltpu.prng_random_bits(shape)  # uint32
     thresh = jnp.uint32(int(dropout_p * (2 ** 32 - 1)))
     return bits >= thresh
 
 
-def _apply_mask_block(s, mask_ref, causal, block_q, block_k, q_start, k_start,
-                      causal_offset):
-    if causal:
-        s = jnp.where(
-            causal_mask(block_q, block_k, q_start + causal_offset, k_start),
-            s, _NEG_INF)
-    if mask_ref is not None:
-        s = s + mask_ref[0].astype(jnp.float32)
-    return s
+class FlashTiles(NamedTuple):
+    """What one grid step of each flash kernel holds (`_choose_tiles`).
+
+    A score tile is `block_q` queries by `block_k` keys in all three
+    kernels. `flash_fwd` and `flash_bwd_dq` own `block_q` queries a grid
+    step and loop over the key sub-tiles of the `chunk_k` keys they hold;
+    `flash_bwd_dkv` owns `block_k` keys and loops over the query sub-tiles
+    of the `chunk_q` queries it holds. A chunk is the whole extent unless
+    that does not fit (the grid's last axis then walks the chunks)."""
+    block_q: int
+    block_k: int
+    chunk_q: int
+    chunk_k: int
+    vmem_limit: Optional[int]   # Mosaic's scoped limit, where the default
+    #                             16 MB is short; None leaves it alone
 
 
-def _fwd_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
-                has_mask, dropout_p, n_qb, n_kb):
-    """Grid (batch*heads, q_blocks, k_blocks), k innermost; online-softmax
-    state in VMEM scratch across the k steps of one (bh, qi) cell."""
-    i = 3
-    q_ref, k_ref, v_ref = refs[:3]
+# the edge a sub-tile takes where the sequence allows: at 2,048 x 128 on a
+# v5e (PR 45's chip runs, PERF.md §6) all three kernels are fastest here. A
+# smaller tile pays a loop trip's fixed cost more often; of a larger one
+# the diagonal block, computed whole, wastes more (80% of the computed
+# scores are live at 512, 89% at 256, 67% at 1,024)
+_TILE = 512
+# operand bytes one grid step may keep double-buffered: the other side's
+# K and V (q and dO for `flash_bwd_dkv`), and an additive mask's block
+_OPERAND_BUDGET = 8 << 20
+_SCOPED_DEFAULT = 16 << 20
+_VMEM_MOST = 96 << 20      # of the 128 MB a v5e core has
+
+
+def _divisor_tile(extent: int, want: int) -> Optional[int]:
+    """The largest lane-aligned size up to `want` that divides `extent`;
+    an extent no longer than `want` is one tile (a whole dimension is
+    always a legal block)."""
+    if extent <= want:
+        return extent
+    for size in range(want - want % 128, 0, -128):
+        if extent % size == 0:
+            return size
+    return None
+
+
+def _chunk(extent: int, tile: int, most: int) -> int:
+    """The most tiles of `extent` (a divisor of their number) that stay
+    within `most` rows; one tile at the least."""
+    n = extent // tile
+    return tile * max(per for per in range(1, n + 1)
+                      if n % per == 0 and (per * tile <= most or per == 1))
+
+
+def _choose_tiles(Sq: int, Sk: int, D: int, itemsize: int,
+                  has_mask: bool = False, block_q: Optional[int] = None,
+                  block_k: Optional[int] = None) -> Optional[FlashTiles]:
+    """The tiles of the three flash kernels, from the shapes alone; None
+    where no tile divides a sequence (the caller takes the XLA reference).
+
+    Sub-tiles are `_TILE` on a side, or the largest multiple of 128 under
+    it that divides the sequence (768 takes 384), or the sequence itself
+    when shorter; `block_q` / `block_k` name them instead where a caller
+    does. The other operand's extent is held whole while K and V (q and dO)
+    double-buffered stay inside `_OPERAND_BUDGET`: up to 8,192 rows at D =
+    128 in bf16. Beyond that (or beyond 2,048 keys under an additive mask,
+    whose float32 block rides along) the extent is cut into chunks that do
+    fit, the grid's last axis walks them with the softmax state carried in
+    scratch, and the index maps stop at the diagonal so that a causally
+    dead chunk is never fetched. Sized for the extents the cells run
+    (2,048); the chunked form is there so that 8k-32k compile, not tuned."""
+    bq = min(block_q, Sq) if block_q else _divisor_tile(Sq, _TILE)
+    bk = min(block_k, Sk) if block_k else _divisor_tile(Sk, _TILE)
+    if not bq or not bk or Sq % bq or Sk % bk:
+        return None
+    rows = _OPERAND_BUDGET // (4 * D * itemsize)   # two arrays, two buffers
+    chunk_k = _chunk(Sk, bk, min(rows, _OPERAND_BUDGET // (8 * bq))
+                     if has_mask else rows)
+    chunk_q = _chunk(Sq, bq, min(rows, _OPERAND_BUDGET // (8 * bk))
+                     if has_mask else rows)
+    # what a step holds of the other operand: K and V double-buffered and
+    # the forward kernel's transposed V (dkv: q and dO, the lse and delta
+    # rows), with an additive mask's float32 block
+    held = max(chunk_k * (5 * D * itemsize + (8 * bq if has_mask else 0)),
+               chunk_q * (4 * D * itemsize + (8 * bk if has_mask else 0)
+                          + 128))
+    own = 8 * max(bq, bk) * D * itemsize           # q o / k v dk dv blocks
+    state = 2 * max(bq, bk) * (D + 128) * 4        # accumulators, m and l
+    scores = 6 * bq * bk * 4       # s, p, dp, ds and the bf16 copies of two
+    need = held + own + state + scores
+    limit = None if need <= _SCOPED_DEFAULT // 2 \
+        else min(max(2 * need, 2 * _SCOPED_DEFAULT), _VMEM_MOST)
+    return FlashTiles(bq, bk, chunk_q, chunk_k, limit)
+
+
+def _tile_counts(Sq: int, Sk: int, bq: int, bk: int, causal: bool,
+                 has_mask: bool) -> tuple:
+    """(live, masked): the sub-tiles one batch-head of a call computes and
+    those of them that take the masked body, counted from the definition
+    (a tile is live if one of its queries sees one of its keys, cut by the
+    diagonal if one does not) rather than from the kernels' loop bounds."""
+    off = Sk - Sq
+    live = cut = 0
+    for q0 in range(0, Sq, bq):
+        for k0 in range(0, Sk, bk):
+            sees_some = not causal or q0 + bq - 1 + off >= k0
+            sees_all = not causal or q0 + off >= k0 + bk - 1
+            live += sees_some
+            cut += sees_some and not sees_all
+    return live, (live if has_mask else cut)
+
+
+def _key_tile_bounds(q_start, bq: int, bk: int, n_kb: int, off: int):
+    """`(full, live)` for the `bq` queries from `q_start`: key sub-tiles
+    `[0, full)` lie wholly under the diagonal, `[full, live)` are cut by
+    it, the rest are dead."""
+    Sk = n_kb * bk
+    return (jnp.clip(q_start + off + 1, 0, Sk) // bk,
+            (jnp.clip(q_start + bq + off, 0, Sk) + bk - 1) // bk)
+
+
+def _query_tile_bounds(k_start, bq: int, bk: int, n_qb: int, off: int):
+    """`(first, full)` for the `bk` keys from `k_start`: query sub-tiles
+    `[first, full)` are cut by the diagonal, `[full, n_qb)` lie wholly
+    under it, those before `first` are dead."""
+    Sq = n_qb * bq
+    return (jnp.clip(k_start - off, 0, Sq) // bq,
+            (jnp.clip(k_start + bk - 1 - off, 0, Sq) + bq - 1) // bq)
+
+
+def _key_tile_spans(causal, q_start, bq, bk, n_kb, off) -> tuple:
+    """`_tile_loops`' (full, cut) spans of key sub-tiles; none if the call
+    is not causal."""
+    if not causal:
+        return ()
+    full, live = _key_tile_bounds(q_start, bq, bk, n_kb, off)
+    return (0, full), (full, live)
+
+
+def _causal_keep(shape, q_pos, k_pos, q_axis: int):
+    """Boolean score tile: True where the query sees the key. Axis `q_axis`
+    runs over queries from `q_pos` (the causal offset included), the other
+    over keys from `k_pos`."""
+    qs = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) + q_pos
+    ks = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) + k_pos
+    return qs >= ks
+
+
+def _tile_loops(body, held, full=None, cut=None):
+    """Run `body(t, diag)` over the sub-tiles a grid step holds, `held` =
+    `(lo, hi)`: the unmasked body on those of `full`, the masked one on
+    those of `cut` (both `(lo, hi)`; without them, a call that is not
+    causal, the unmasked body on all). The trip counts are the causal
+    bounds, so a dead tile is not visited."""
+    def loop(span, diag):
+        jax.lax.fori_loop(jnp.maximum(span[0], held[0]),
+                          jnp.minimum(span[1], held[1]),
+                          lambda t, c: (body(t, diag), c)[1], 0)
+    if full is None:
+        loop(held, False)
+    else:
+        loop(full, False)
+        loop(cut, True)
+
+
+def _split_refs(refs, n_in, has_mask, dropout_p):
+    """(inputs, mask_ref, seed_ref, outputs and scratch)."""
+    i = n_in
     mask_ref = refs[i] if has_mask else None
     i += 1 if has_mask else 0
     seed_ref = refs[i] if dropout_p > 0.0 else None
     i += 1 if dropout_p > 0.0 else 0
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[i:]
-    b = pl.program_id(0)
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-    num_kb = pl.num_programs(2)
-    q_start = qi * block_q
-    k_start = kb * block_k
+    return refs[:n_in], mask_ref, seed_ref, refs[i:]
 
-    @pl.when(kb == 0)
+
+def _fwd_kernel(*refs, scale, causal, tiles, causal_offset, has_mask,
+                dropout_p, n_qb, n_kb):
+    """Grid (batch*heads, q blocks, key chunks): a step owns `block_q`
+    queries, holds a chunk of K and V and loops over its key sub-tiles up
+    to the diagonal. The tile is computed transposed, sT = k qT
+    `[block_k, block_q]`, so that the online-softmax state (running max and
+    sum, one value a query) is lane-dense rows `[1, block_q]`, the max and
+    sum over keys run down sublanes, and lse leaves as the row it is stored
+    as; the accumulator is oT `[D, block_q]` += vT pT, with V transposed
+    once a chunk held (not once a tile) and oT once a grid step."""
+    (q_ref, k_ref, v_ref), mask_ref, seed_ref, rest = _split_refs(
+        refs, 3, has_mask, dropout_p)
+    o_ref, lse_ref, acc_ref, m_ref, l_ref, vt_ref = rest
+    bq, bk = tiles.block_q, tiles.block_k
+    per = tiles.chunk_k // bk
+    b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    q_start = qi * bq
+
+    @pl.when(c == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # causal: skip blocks strictly in the future (offset-aware for Sq != Sk)
-    run = (q_start + causal_offset + block_q - 1 >= k_start) if causal \
-        else True
+    # the chunk held changes with the batch-head alone when it is the whole
+    # extent (the q blocks of a batch-head run in order: "arbitrary")
+    @pl.when(qi == 0 if per == n_kb else True)
+    def _transpose_v():
+        vt_ref[...] = v_ref[0].T
 
-    @pl.when(run)
-    def _compute():
+    def tile(kb, diag):
         # dots take the input dtype (bf16 on TPU — full MXU rate; fp32 dots
         # run at a fraction of it) and accumulate fp32 via
         # preferred_element_type; scale applies post-dot in fp32
-        q = q_ref[0]
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        s = _dot(q, kblk, 1, 1) * scale
-        s = _apply_mask_block(s, mask_ref, causal, block_q, block_k, q_start,
-                              k_start, causal_offset)
+        at = pl.ds(pl.multiple_of((kb - c * per) * bk, bk), bk)
+        sT = _dot(k_ref[0, at, :], q_ref[0], 1, 1) * scale      # [bk, bq]
+        if diag:
+            sT = jnp.where(_causal_keep(sT.shape, q_start + causal_offset,
+                                        kb * bk, 1), sT, _NEG_INF)
+        if has_mask:
+            sT = sT + mask_ref[0, :, at].astype(jnp.float32).T
         m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # structurally-masked entries contribute exactly 0 even when a whole
-        # row is masked (else exp(s - m) with m == s == -1e30 would give 1
-        # for every key and rows with no visible key would emit mean(v))
-        p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+        m_new = jnp.maximum(m_prev, jnp.max(sT, axis=0, keepdims=True))
+        pT = jnp.exp(sT - m_new)
+        if diag or has_mask:
+            # structurally-masked entries contribute exactly 0 even when a
+            # whole row is masked (else exp(s - m) with m == s == -1e30
+            # would give 1 for every key and rows with no visible key would
+            # emit mean(v)); a tile under the diagonal has none
+            pT = jnp.where(sT <= _NEG_INF / 2, 0.0, pT)
         alpha = jnp.exp(m_prev - m_new)
         # denominator uses the full p; dropout applies only to the numerator
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pT, axis=0, keepdims=True)
         if dropout_p > 0.0:
-            keep = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, p.shape,
-                               dropout_p)
-            p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-        acc_ref[...] = acc_ref[...] * alpha + _dot(p.astype(vblk.dtype),
-                                                   vblk, 1, 0)
+            keepT = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, (bq, bk),
+                                dropout_p).astype(jnp.int32).T > 0
+            pT = jnp.where(keepT, pT / (1.0 - dropout_p), 0.0)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(
+            vt_ref[:, at], pT.astype(vt_ref.dtype), 1, 0)
         m_ref[...] = m_new
 
-    @pl.when(kb == num_kb - 1)
+    _tile_loops(tile, (c * per, (c + 1) * per), *_key_tile_spans(
+        causal, q_start, bq, bk, n_kb, causal_offset))
+
+    @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).T.astype(o_ref.dtype)
         # lse buffer is [bh, 1, Sq]: a trailing dim of 1 would get a
         # T(8,128) padded layout (128x HBM expansion — OOMs 1B+ models),
-        # so the whole row lives in lanes and each q block ds-writes its
-        # slice of the revisited (b, 0, 0) block
-        lse_ref[0, 0, :] = (
-            m_ref[...] + jnp.log(l)).astype(jnp.float32).reshape(block_q)
+        # so the whole row lives in lanes and each q block writes its
+        # slice of it
+        lse_ref[0] = (m_ref[...] + jnp.log(l)).astype(jnp.float32)
 
 
-def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
-                   has_mask, dropout_p, n_qb, n_kb):
-    """Grid (bh, q_blocks, k_blocks): accumulate dq for one q block."""
-    i = 6
-    q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref = refs[:6]
-    mask_ref = refs[i] if has_mask else None
-    i += 1 if has_mask else 0
-    seed_ref = refs[i] if dropout_p > 0.0 else None
-    i += 1 if dropout_p > 0.0 else 0
-    dq_ref, acc_ref = refs[i:]
-    b = pl.program_id(0)
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-    num_kb = pl.num_programs(2)
-    q_start = qi * block_q
-    k_start = kb * block_k
+def _bwd_dq_kernel(*refs, scale, causal, tiles, causal_offset, has_mask,
+                   dropout_p, n_qb, n_kb):
+    """Grid (bh, q blocks, key chunks): dq of one q block, over the key
+    sub-tiles up to the diagonal. The step's first chunk makes what the
+    tiles need as columns, once: `lse` relaid from its lane-dense row, and
+    delta = rowsum(dO * O), which it also hands to `flash_bwd_dkv` as a
+    lane-dense row."""
+    (q_ref, k_ref, v_ref, g_ref, out_ref, lse_ref), mask_ref, seed_ref, \
+        rest = _split_refs(refs, 6, has_mask, dropout_p)
+    dq_ref, delta_ref, acc_ref, lse_col, delta_col = rest
+    bq, bk = tiles.block_q, tiles.block_k
+    per = tiles.chunk_k // bk
+    b, qi, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    q_start = qi * bq
 
-    @pl.when(kb == 0)
+    @pl.when(c == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        lse_col[...] = lse_ref[0].reshape(bq, 1)
+        delta = jnp.sum(g_ref[0].astype(jnp.float32)
+                        * out_ref[0].astype(jnp.float32), axis=-1,
+                        keepdims=True)
+        delta_col[...] = delta
+        delta_ref[0, 0, :] = delta.reshape(bq)
 
-    run = (q_start + causal_offset + block_q - 1 >= k_start) if causal \
-        else True
-
-    @pl.when(run)
-    def _compute():
+    def tile(kb, diag):
         # bf16-in/fp32-accum dots (see _fwd_kernel note)
-        q = q_ref[0]
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        g = g_ref[0]
-        s = _dot(q, kblk, 1, 1) * scale
-        s = _apply_mask_block(s, mask_ref, causal, block_q, block_k, q_start,
-                              k_start, causal_offset)
-        lse_col = lse_ref[0]
-        delta_col = delta_ref[0]
-        p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - lse_col))
-        dp = _dot(g, vblk, 1, 1)
+        at = pl.ds(pl.multiple_of((kb - c * per) * bk, bk), bk)
+        kblk = k_ref[0, at, :]
+        s = _dot(q_ref[0], kblk, 1, 1) * scale
+        if diag:
+            s = jnp.where(_causal_keep(s.shape, q_start + causal_offset,
+                                       kb * bk, 0), s, _NEG_INF)
+        if has_mask:
+            s = s + mask_ref[0, :, at].astype(jnp.float32)
+        p = jnp.exp(s - lse_col[...])
+        if diag or has_mask:
+            p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
+        dp = _dot(g_ref[0], v_ref[0, at, :], 1, 1)
         if dropout_p > 0.0:
             keep = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, p.shape,
                                dropout_p)
             dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
-        ds = p * (dp - delta_col) * scale
+        # ds without its factor `scale`: the sum takes it once, below
+        ds = p * (dp - delta_col[...])
         acc_ref[...] += _dot(ds.astype(kblk.dtype), kblk, 1, 0)
 
-    @pl.when(kb == num_kb - 1)
+    _tile_loops(tile, (c * per, (c + 1) * per), *_key_tile_spans(
+        causal, q_start, bq, bk, n_kb, causal_offset))
+
+    @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, causal_offset,
-                    has_mask, dropout_p, n_qb, n_kb):
-    """Grid (bh, k_blocks, q_blocks): accumulate dk/dv for one k block."""
-    i = 6
-    q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref = refs[:6]
-    mask_ref = refs[i] if has_mask else None
-    i += 1 if has_mask else 0
-    seed_ref = refs[i] if dropout_p > 0.0 else None
-    i += 1 if dropout_p > 0.0 else 0
-    dk_ref, dv_ref, dk_acc, dv_acc = refs[i:]
-    b = pl.program_id(0)
-    kb = pl.program_id(1)
-    qi = pl.program_id(2)
-    num_qb = pl.num_programs(2)
-    q_start = qi * block_q
-    k_start = kb * block_k
+def _bwd_dkv_kernel(*refs, scale, causal, tiles, causal_offset, has_mask,
+                    dropout_p, n_qb, n_kb):
+    """Grid (bh, k blocks, query chunks): dk/dv of one k block, over the
+    query sub-tiles from the diagonal on. The tile is computed transposed,
+    sT = k qT `[block_k, block_q]`, so that the per-query statistics
+    broadcast as the rows `[1, block_q]` they are stored as and dv += pT dO,
+    dk += dsT q are plain products."""
+    (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref), mask_ref, seed_ref, \
+        rest = _split_refs(refs, 6, has_mask, dropout_p)
+    dk_ref, dv_ref, dk_acc, dv_acc = rest
+    bq, bk = tiles.block_q, tiles.block_k
+    per = tiles.chunk_q // bq
+    b, kb, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    k_start = kb * bk
 
-    @pl.when(qi == 0)
+    @pl.when(c == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = (q_start + causal_offset + block_q - 1 >= k_start) if causal \
-        else True
-
-    @pl.when(run)
-    def _compute():
+    def tile(qi, diag):
         # bf16-in/fp32-accum dots (see _fwd_kernel note)
-        q = q_ref[0]
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        g = g_ref[0]
-        s = _dot(q, kblk, 1, 1) * scale
-        s = _apply_mask_block(s, mask_ref, causal, block_q, block_k, q_start,
-                              k_start, causal_offset)
-        lse_col = lse_ref[0]
-        delta_col = delta_ref[0]
-        p = jnp.where(s <= _NEG_INF / 2, 0.0,
-                      jnp.exp(s - lse_col))  # [bq, bk]
-        dp = _dot(g, vblk, 1, 1)
+        at = pl.ds(pl.multiple_of((qi - c * per) * bq, bq), bq)
+        q = q_ref[0, at, :]
+        g = g_ref[0, at, :]
+        sT = _dot(k_ref[0], q, 1, 1) * scale               # [bk, bq]
+        if diag:
+            sT = jnp.where(_causal_keep(sT.shape, qi * bq + causal_offset,
+                                        k_start, 1), sT, _NEG_INF)
+        if has_mask:
+            sT = sT + mask_ref[0, at, :].astype(jnp.float32).T
+        pT = jnp.exp(sT - lse_ref[0, :, at])
+        if diag or has_mask:
+            pT = jnp.where(sT <= _NEG_INF / 2, 0.0, pT)
+        dpT = _dot(v_ref[0], g, 1, 1)
         if dropout_p > 0.0:
-            keep = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, p.shape,
-                               dropout_p)
+            keepT = _block_keep(seed_ref, b, qi, kb, n_qb, n_kb, (bq, bk),
+                                dropout_p).astype(jnp.int32).T > 0
             inv = 1.0 - dropout_p
-            p_drop = jnp.where(keep, p / inv, 0.0)
-            dp = jnp.where(keep, dp / inv, 0.0)
+            dpT = jnp.where(keepT, dpT / inv, 0.0)
+            p_drop = jnp.where(keepT, pT / inv, 0.0)
         else:
-            p_drop = p
-        ds = p * (dp - delta_col) * scale
-        # dv += p_drop^T @ g ; dk += ds^T @ q
-        dv_acc[...] += _dot(p_drop.astype(g.dtype), g, 0, 0)
-        dk_acc[...] += _dot(ds.astype(q.dtype), q, 0, 0)
+            p_drop = pT
+        dsT = pT * (dpT - delta_ref[0, :, at])     # `scale`: once, below
+        dv_acc[...] += _dot(p_drop.astype(g.dtype), g, 1, 0)
+        dk_acc[...] += _dot(dsT.astype(q.dtype), q, 1, 0)
 
-    @pl.when(qi == num_qb - 1)
+    spans = ()
+    if causal:
+        first, full = _query_tile_bounds(k_start, bq, bk, n_qb,
+                                         causal_offset)
+        spans = (full, n_qb), (first, full)
+    _tile_loops(tile, (c * per, (c + 1) * per), *spans)
+
+    @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
@@ -455,148 +644,201 @@ def _mask_3d(mask, B, H, Sq, Sk):
     return flat, 1
 
 
-# every flash grid is (batch*heads, outer blocks, inner blocks): the scratch
-# accumulators carry across the innermost axis only
-_GRID_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+def _compiler_params(tiles: FlashTiles, q_blocks_in_order: bool = False):
+    """Every flash grid is (batch*heads, owned blocks, chunks of the other
+    operand): the scratch accumulators carry across the last axis; the
+    forward kernel also keeps V transposed across a batch-head's blocks."""
+    return pltpu.CompilerParams(
+        dimension_semantics=(
+            "parallel", "arbitrary" if q_blocks_in_order else "parallel",
+            "arbitrary"),
+        vmem_limit_bytes=tiles.vmem_limit)
+
+
+def _tiles_for(q, k, mask, block_q, block_k) -> FlashTiles:
+    tiles = _choose_tiles(q.shape[2], k.shape[2], q.shape[3],
+                          q.dtype.itemsize, mask is not None, block_q,
+                          block_k)
+    assert tiles is not None, (
+        "flash_attention requires a tile that divides the sequence; "
+        "callers fall back to the XLA reference otherwise")
+    return tiles
+
+
+def _key_chunk_specs(tiles: FlashTiles, Sq, Sk, D, causal):
+    """Block specs of a grid (bh, q block i, key chunk c), the forward's
+    and dq's: a block of queries, a chunk of keys, and the additive mask's
+    block for both (given its bh -> row divisor). Past the diagonal the key
+    chunk's index stays at the last live one, so a dead chunk is not
+    fetched."""
+    bq, ck, off = tiles.block_q, tiles.chunk_k, Sk - Sq
+
+    def chunk(i, c):
+        if not causal or ck == Sk:
+            return c
+        return jnp.minimum(c, (jnp.clip(i * bq + bq + off, 1, Sk) - 1) // ck)
+    return (pl.BlockSpec((1, bq, D), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, ck, D), lambda b, i, c: (b, chunk(i, c), 0)),
+            lambda div: pl.BlockSpec(
+                (1, bq, ck), lambda b, i, c: (b // div, i, chunk(i, c))))
+
+
+def _extra_operands(mask, seed, dropout_p, B, H, Sq, Sk, mask_spec):
+    """The specs and operands an additive mask and a dropout seed add."""
+    specs, operands = [], []
+    if mask is not None:
+        mflat, div = _mask_3d(mask, B, H, Sq, Sk)
+        specs.append(mask_spec(div))
+        operands.append(mflat)
+    if dropout_p > 0.0:
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(jnp.asarray(seed, jnp.int32).reshape(1))
+    return specs, operands
+
+
+def _note_tiling(kernel, q, k, mask, causal, tiles: FlashTiles):
+    """Record what `kernel` computes at `tiles` and say where it runs
+    (`interpret=` of its pallas_call). Outside the jitted calls below, so
+    that every traced call site counts."""
+    (B, H, Sq, _), Sk = q.shape, k.shape[2]
+    bq, bk = tiles.block_q, tiles.block_k
+    live, masked = _tile_counts(Sq, Sk, bq, bk, causal, mask is not None)
+    if kernel == "flash_bwd_dkv":
+        held = dict(chunk_q=tiles.chunk_q,
+                    grid=(B * H, Sk // bk, Sq // tiles.chunk_q))
+    else:
+        held = dict(chunk_k=tiles.chunk_k,
+                    grid=(B * H, Sq // bq, Sk // tiles.chunk_k))
+    pallas_mode.note_tiling(kernel, block_q=bq, block_k=bk, live_tiles=live,
+                            masked_tiles=masked, **held)
+    return pallas_mode.interpret(kernel)
 
 
 def _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k, dropout_p,
                seed):
+    tiles = _tiles_for(q, k, mask, block_q, block_k)
+    return _fwd_call(
+        q, k, v, mask, seed, causal=causal, scale=scale, tiles=tiles,
+        dropout_p=dropout_p,
+        interpret=_note_tiling("flash_fwd", q, k, mask, causal, tiles))
+
+
+# The pallas_calls sit under module-level jits whose integers are static,
+# as `paged_attention._paged_call` does: the call sites of one traced
+# program that agree on shapes (a model's layers) share one jaxpr and the
+# program lowers one kernel body for them, not one a layer. Tracing and
+# lowering run in every process before the compile cache can be asked.
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "tiles", "dropout_p", "interpret"))
+def _fwd_call(q, k, v, mask, seed, *, causal, scale, tiles, dropout_p,
+              interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, (
-        "flash_attention requires sequence divisible by block size; "
-        "callers fall back to the XLA reference otherwise")
-    qr = q.reshape(B * H, Sq, D)
-    kr = k.reshape(B * H, Sk, D)
-    vr = v.reshape(B * H, Sk, D)
-    n_qb, n_kb = Sq // bq, Sk // bk
-
+    bq, bk, ck = tiles.block_q, tiles.block_k, tiles.chunk_k
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
+        _fwd_kernel, scale=scale, causal=causal, tiles=tiles,
         causal_offset=Sk - Sq, has_mask=mask is not None,
-        dropout_p=dropout_p, n_qb=n_qb, n_kb=n_kb)
-    in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-    ]
-    operands = [qr, kr, vr]
-    if mask is not None:
-        mflat, div = _mask_3d(mask, B, H, Sq, Sk)
-        in_specs.append(pl.BlockSpec(
-            (1, bq, bk), lambda b, i, j, d=div: (b // d, i, j)))
-        operands.append(mflat)
-    if dropout_p > 0.0:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(jnp.asarray(seed, jnp.int32).reshape(1))
+        dropout_p=dropout_p, n_qb=Sq // bq, n_kb=Sk // bk)
+    q_spec, kv_spec, mask_spec = _key_chunk_specs(tiles, Sq, Sk, D, causal)
+    extra_specs, extra = _extra_operands(mask, seed, dropout_p, B, H, Sq, Sk,
+                                         mask_spec)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, n_qb, n_kb),
-        in_specs=in_specs,
+        grid=(B * H, Sq // bq, Sk // ck),
+        in_specs=[q_spec, kv_spec, kv_spec] + extra_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+            q_spec,
+            pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),   # acc
-            pltpu.VMEM((bq, 1), jnp.float32),   # running max
-            pltpu.VMEM((bq, 1), jnp.float32),   # running sum
+            pltpu.VMEM((D, bq), jnp.float32),   # acc, transposed
+            pltpu.VMEM((1, bq), jnp.float32),   # running max
+            pltpu.VMEM((1, bq), jnp.float32),   # running sum
+            pltpu.VMEM((D, ck), v.dtype),       # V of the chunk, transposed
         ],
-        compiler_params=_GRID_SEMANTICS,
-        interpret=pallas_mode.interpret("flash_fwd"),
+        compiler_params=_compiler_params(tiles, q_blocks_in_order=True),
+        interpret=interpret,
         name="flash_fwd",
-    )(*operands)
+    )(q.reshape(B * H, Sq, D), k.reshape(B * H, Sk, D),
+      v.reshape(B * H, Sk, D), *extra)
     return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
 
 def _flash_bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
                dropout_p, seed):
+    """(dq, dk, dv, delta `[B, H, Sq]`). `lse` and delta = rowsum(dO * O)
+    stay lane-dense `[bh, 1, Sq]` from the forward kernel's result to the
+    backward kernels' operands: `flash_bwd_dq` makes delta from its dO and
+    O blocks and hands it on, and no `[bh, Sq, 1]` column (128 x padded on
+    a TPU) is materialized."""
+    tiles = _tiles_for(q, k, mask, block_q, block_k)
+    return _bwd_call(
+        q, k, v, mask, out, lse, g, seed, causal=causal, scale=scale,
+        tiles=tiles, dropout_p=dropout_p, interpret=tuple(
+            _note_tiling(kernel, q, k, mask, causal, tiles)
+            for kernel in ("flash_bwd_dq", "flash_bwd_dkv")))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "tiles", "dropout_p", "interpret"))
+def _bwd_call(q, k, v, mask, out, lse, g, seed, *, causal, scale, tiles,
+              dropout_p, interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
-    n_qb, n_kb = Sq // bq, Sk // bk
+    bq, bk, cq, ck = tiles.block_q, tiles.block_k, tiles.chunk_q, \
+        tiles.chunk_k
+    off = Sk - Sq
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * H, Sk, D)
     vr = v.reshape(B * H, Sk, D)
     gr = g.reshape(B * H, Sq, D)
-    # the residual lse is stored compactly as [B,H,Sq]; the kernels want a
-    # [bh, Sq, 1] column operand (its size-1 minor dim is legal because the
-    # block's trailing dim equals the array's) — materialize it transiently
-    # here (an XLA relayout, ~2x the unpadded lse bytes of traffic) rather
-    # than paying an in-kernel lane->sublane relayout every grid step
-    lser = lse.reshape(B * H, Sq, 1)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
-                    keepdims=True).reshape(B * H, Sq, 1)
-    common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
-                  causal_offset=Sk - Sq, has_mask=mask is not None,
-                  dropout_p=dropout_p, n_qb=n_qb, n_kb=n_kb)
+    lser = lse.reshape(B * H, 1, Sq)
+    common = dict(scale=scale, causal=causal, tiles=tiles, causal_offset=off,
+                  has_mask=mask is not None, dropout_p=dropout_p,
+                  n_qb=Sq // bq, n_kb=Sk // bk)
 
-    base_specs_q = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),   # q
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),   # k
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),   # v
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),   # g
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # lse
-        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # delta
-    ]
-    operands = [qr, kr, vr, gr, lser, delta]
-    if mask is not None:
-        mflat, div = _mask_3d(mask, B, H, Sq, Sk)
-        base_specs_q.append(pl.BlockSpec(
-            (1, bq, bk), lambda b, i, j, d=div: (b // d, i, j)))
-        operands.append(mflat)
-    if dropout_p > 0.0:
-        base_specs_q.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(jnp.asarray(seed, jnp.int32).reshape(1))
-
-    dq = pl.pallas_call(
+    q_spec, kv_spec, mask_spec = _key_chunk_specs(tiles, Sq, Sk, D, causal)
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i))
+    extra_specs, extra = _extra_operands(mask, seed, dropout_p, B, H, Sq, Sk,
+                                         mask_spec)
+    dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
-        grid=(B * H, n_qb, n_kb),
-        in_specs=base_specs_q,
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_GRID_SEMANTICS,
-        interpret=pallas_mode.interpret("flash_bwd_dq"),
+        grid=(B * H, Sq // bq, Sk // ck),
+        # q, k, v, dO, O, lse
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec]
+        + extra_specs,
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+                   jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),   # dq
+                        pltpu.VMEM((bq, 1), jnp.float32),   # lse, a column
+                        pltpu.VMEM((bq, 1), jnp.float32)],  # delta, a column
+        compiler_params=_compiler_params(tiles),
+        interpret=interpret[0],
         name="flash_bwd_dq",
-    )(*operands)
+    )(qr, kr, vr, gr, out.reshape(B * H, Sq, D), lser, *extra)
 
-    # dkv grid: (bh, k_blocks, q_blocks) — q innermost, accumulators per k blk
-    base_specs_kv = [
-        pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),   # q
-        pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),   # v
-        pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),   # g
-        pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),   # lse
-        pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),   # delta
-    ]
-    operands_kv = [qr, kr, vr, gr, lser, delta]
-    if mask is not None:
-        mflat, div = _mask_3d(mask, B, H, Sq, Sk)
-        base_specs_kv.append(pl.BlockSpec(
-            (1, bq, bk), lambda b, j, i, d=div: (b // d, i, j)))
-        operands_kv.append(mflat)
-    if dropout_p > 0.0:
-        base_specs_kv.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands_kv.append(jnp.asarray(seed, jnp.int32).reshape(1))
-
+    # dkv grid: (bh, k blocks, query chunks); before the diagonal the query
+    # chunk's index stays at the first live one
+    def q_chunk(j, c):
+        if not causal or cq == Sq:
+            return c
+        return jnp.maximum(c, jnp.clip(j * bk - off, 0, Sq - 1) // cq)
+    own = pl.BlockSpec((1, bk, D), lambda b, j, c: (b, j, 0))
+    held = pl.BlockSpec((1, cq, D), lambda b, j, c: (b, q_chunk(j, c), 0))
+    rows = pl.BlockSpec((1, 1, cq), lambda b, j, c: (b, 0, q_chunk(j, c)))
+    extra_specs, extra = _extra_operands(
+        mask, seed, dropout_p, B, H, Sq, Sk, lambda div: pl.BlockSpec(
+            (1, cq, bk), lambda b, j, c: (b // div, q_chunk(j, c), j)))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
-        grid=(B * H, n_kb, n_qb),
-        in_specs=base_specs_kv,
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-        ],
+        grid=(B * H, Sk // bk, Sq // cq),
+        in_specs=[held, own, own, held, rows, rows] + extra_specs,
+        out_specs=[own, own],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sk, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, Sk, D), v.dtype),
@@ -605,12 +847,12 @@ def _flash_bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        compiler_params=_GRID_SEMANTICS,
-        interpret=pallas_mode.interpret("flash_bwd_dkv"),
+        compiler_params=_compiler_params(tiles),
+        interpret=interpret[1],
         name="flash_bwd_dkv",
-    )(*operands_kv)
+    )(qr, kr, vr, gr, lser, delta, *extra)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
-            dv.reshape(B, H, Sk, D))
+            dv.reshape(B, H, Sk, D), delta.reshape(B, H, Sq))
 
 
 def _mask_grad(q, k, v, mask, lse, g, delta, causal, scale, block_k):
@@ -683,8 +925,8 @@ def _flash_vjp_fwd(q, k, v, mask, seed, causal, scale, block_q, block_k,
 def _flash_vjp_bwd(causal, scale, block_q, block_k, dropout_p, res, g):
     import numpy as np
     q, k, v, mask, seed, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, g, causal, scale,
-                            block_q, block_k, dropout_p, seed)
+    dq, dk, dv, delta = _flash_bwd(q, k, v, mask, out, lse, g, causal, scale,
+                                   block_q, block_k, dropout_p, seed)
     if mask is None:
         dmask = None
     elif dropout_p > 0.0:
@@ -696,10 +938,8 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, dropout_p, res, g):
             "flash_attention(), which falls back to the XLA reference for "
             "mask + dropout")
     else:
-        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1, keepdims=True)
         dmask = _mask_grad(q, k, v, mask, lse, g, delta, causal, scale,
-                           block_k)
+                           _tiles_for(q, k, mask, block_q, block_k).block_k)
     dseed = np.zeros(np.shape(seed), jax.dtypes.float0)
     return dq, dk, dv, dmask, dseed
 
@@ -728,10 +968,6 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if block_q is None:
-        block_q = DEFAULT_BLOCK_Q
-    if block_k is None:
-        block_k = DEFAULT_BLOCK_K
     on_cpu = pallas_mode.platform() == "cpu"
     Sq, Sk = q.shape[2], k.shape[2]
     if window is not None:
@@ -753,8 +989,10 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                 and dropout_p == 0.0 and Sq == Sk and impl != "gspmd"):
             return _sequence_parallel_island(q, k, v, causal, scale, impl)
         reason = "sequence-sharded trace, no ring island"
-    elif Sq % min(block_q, Sq) or Sk % min(block_k, Sk):
-        reason = f"sequence not divisible by block {block_q}x{block_k}"
+    elif _choose_tiles(Sq, Sk, q.shape[-1], q.dtype.itemsize,
+                       mask is not None, block_q, block_k) is None:
+        reason = ("no tile divides the sequence" if block_q is block_k is None
+                  else f"sequence not divisible by block {block_q}x{block_k}")
     elif dropout_p > 0.0 and on_cpu:
         reason = "in-kernel dropout needs the TPU PRNG"
     elif dropout_p > 0.0 and mask is not None:

@@ -23,32 +23,148 @@ def _flash(q, k, v, causal, scale, bq=128, bk=128, mask=None):
                             bk, 0.0)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_forward_matches_reference(causal):
-    q, k, v = _rand_qkv()
+# (Sq, Sk, block_q, block_k, causal, additive mask, live, masked): the
+# sub-tiles one batch-head computes and those of them under the masked
+# body, counted by hand. The first two are the square 2 x 2 grids the
+# kernels have always been held to; "loop-4x2" is four query blocks over
+# two key sub-tiles of [64, 128] (the diagonal cuts tiles (0,0) (1,0) (2,1)
+# (3,1); (2,0) and (3,0) lie under it; (0,1) and (1,1) are dead); the
+# rectangular ones put the diagonal at Sk - Sq; "chunks" cuts the other
+# operand's extent into two chunks so that the state is carried over the
+# grid's last axis
+_CASES = {
+    "noncausal": (128, 128, 64, 64, False, False, 4, 0),
+    "causal": (128, 128, 64, 64, True, False, 3, 2),
+    "loop-4x2": (256, 256, 64, 128, True, False, 6, 4),
+    "wide-q": (256, 256, 128, 64, True, False, 6, 4),
+    "Sq<Sk": (128, 256, 64, 64, True, False, 7, 2),
+    "Sq>Sk": (256, 128, 128, 64, True, False, 2, 2),
+    "mask": (128, 128, 64, 64, False, True, 4, 4),
+    "causal+mask": (128, 256, 64, 128, True, True, 4, 4),
+    "chunks": (256, 256, 64, 64, True, False, 10, 4),
+    "chunks-noncausal": (128, 256, 64, 64, False, False, 8, 0),
+}
+
+
+def _case(name, monkeypatch):
+    """(q, k, v, flash, reference, live, masked) of a case."""
+    from paddle_tpu.ops import attention as A
+    Sq, Sk, bq, bk, causal, masked_case, live, masked = _CASES[name]
+    q, k, v = _rand_qkv(Sq=Sq, Sk=Sk, D=32, seed=len(name))
     scale = 1.0 / np.sqrt(q.shape[-1])
-    ref = _attention_reference(q, k, v, causal, scale)
-    out = _flash(q, k, v, causal, scale)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3,
+    mask = None
+    if masked_case:
+        rng = np.random.RandomState(1)
+        mask = jnp.asarray(np.where(rng.rand(2, 1, Sq, Sk) > 0.1, 0.0, -1e9)
+                           .astype(np.float32))
+    if name.startswith("chunks"):
+        # K and V (q and dO) of 128 rows, double-buffered: two chunks
+        monkeypatch.setattr(A, "_OPERAND_BUDGET", 128 * 4 * 32 * 4)
+        tiles = A._choose_tiles(Sq, Sk, 32, 4, False, bq, bk)
+        assert (Sk // tiles.chunk_k, Sq // tiles.chunk_q) == (2, Sq // 128)
+
+    def flash(q_, k_, v_):
+        return _flash(q_, k_, v_, causal, scale, bq, bk, mask=mask)
+
+    def reference(q_, k_, v_):
+        return _attention_reference(q_, k_, v_, causal, scale, mask=mask)
+    return q, k, v, flash, reference, live, masked
+
+
+@pytest.mark.parametrize("case", list(_CASES))
+def test_flash_forward_matches_reference(case, monkeypatch):
+    q, k, v, flash, reference, _, _ = _case(case, monkeypatch)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(reference(q, k, v)), rtol=2e-3,
                                atol=2e-3)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_backward_matches_reference(causal):
-    q, k, v = _rand_qkv(Sq=128, D=32)
-    scale = 1.0 / np.sqrt(q.shape[-1])
-
-    def loss_flash(q_, k_, v_):
-        return jnp.sum(_flash(q_, k_, v_, causal, scale, 64, 64) ** 2)
-
-    def loss_ref(q_, k_, v_):
-        return jnp.sum(_attention_reference(q_, k_, v_, causal, scale) ** 2)
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+@pytest.mark.parametrize("case", list(_CASES))
+def test_flash_backward_matches_reference(case, monkeypatch):
+    """dq, dk and dv of every case against the reference's, and what the
+    three kernels said they computed against the count by hand."""
+    from paddle_tpu.ops import pallas_mode
+    q, k, v, flash, reference, live, masked = _case(case, monkeypatch)
+    pallas_mode.KERNEL_TILINGS.clear()
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(reference(*a) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3,
                                    atol=5e-3)
+    said = {kernel: dict(tiling)
+            for kernel, tiling in pallas_mode.KERNEL_TILINGS}
+    assert sorted(said) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    for kernel, tiling in said.items():
+        assert (tiling["live_tiles"], tiling["masked_tiles"]) \
+            == (live, masked), (kernel, tiling)
+
+
+# (Sq, Sk, D, itemsize): the cells' shape, its half, a length 512 does not
+# divide, the smoke's rehearsal shape, a rectangle, and the long extents
+# that no longer fit whole
+_SHAPES = [(2048, 2048, 128, 2), (1024, 1024, 128, 2), (768, 768, 128, 2),
+           (512, 512, 64, 2), (512, 2048, 128, 2), (2048, 512, 128, 2),
+           (8192, 8192, 128, 2), (32768, 32768, 128, 2),
+           (16384, 16384, 64, 4)]
+
+
+@pytest.mark.parametrize("has_mask", [False, True], ids=["plain", "mask"])
+@pytest.mark.parametrize("shape", _SHAPES, ids=lambda s: "x".join(
+    map(str, s)))
+def test_chosen_tiles_divide_the_extents_and_fit(shape, has_mask):
+    from paddle_tpu.ops import attention as A
+    Sq, Sk, D, itemsize = shape
+    t = A._choose_tiles(Sq, Sk, D, itemsize, has_mask)
+    assert Sq % t.chunk_q == 0 and t.chunk_q % t.block_q == 0
+    assert Sk % t.chunk_k == 0 and t.chunk_k % t.block_k == 0
+    # lane-dense rows and aligned sub-tile offsets
+    assert t.block_q % 128 == 0 and t.block_k % 128 == 0
+    for chunk, tile, other in ((t.chunk_k, t.block_k, t.block_q),
+                               (t.chunk_q, t.block_q, t.block_k)):
+        held = chunk * (4 * D * itemsize + (8 * other if has_mask else 0))
+        assert chunk == tile or held <= 2 * A._OPERAND_BUDGET
+    if Sq == Sk == 2048 and not has_mask:
+        # the train cells: K and V whole, a grid step a query block
+        assert t == A.FlashTiles(512, 512, 2048, 2048, t.vmem_limit)
+    if Sq == 768:
+        assert (t.block_q, t.block_k) == (384, 384)
+    if Sq == 32768:
+        assert t.chunk_k < Sk and t.chunk_q < Sq       # chunks are walked
+    assert t.vmem_limit is None or 16 << 20 < t.vmem_limit <= 96 << 20
+
+
+def test_every_shape_the_old_blocks_took_is_still_taken():
+    """The kernels took a sequence that 256 (or the sequence, if shorter)
+    divides; the chooser takes every one of those, explicit blocks as
+    before, and the closed-form loop bounds agree with the count from the
+    definition at each."""
+    from paddle_tpu.ops import attention as A
+    extents = [s for s in range(8, 4097, 8) if s % min(256, s) == 0]
+    for Sq in extents:
+        for Sk in {Sq, 256, 2048, extents[-1 - extents.index(Sq)]}:
+            t = A._choose_tiles(Sq, Sk, 128, 2)
+            assert t is not None and Sq % t.block_q == 0 \
+                and Sk % t.block_k == 0, (Sq, Sk)
+            assert A._choose_tiles(Sq, Sk, 128, 2, False, 256, 256)[:2] \
+                == (min(256, Sq), min(256, Sk))
+    assert A._choose_tiles(1000, 1000, 128, 2) is None      # as before
+    assert A._choose_tiles(512, 512, 128, 2, False, 200, 256) is None
+    for Sq, Sk, bq, bk in [(2048, 2048, 512, 512), (256, 256, 64, 128),
+                           (128, 256, 64, 64), (256, 128, 128, 64),
+                           (256, 192, 128, 64), (768, 768, 384, 384)]:
+        n_qb, n_kb, off = Sq // bq, Sk // bk, Sk - Sq
+        live = cut = live_t = cut_t = 0
+        for i in range(n_qb):
+            full, end = A._key_tile_bounds(i * bq, bq, bk, n_kb, off)
+            live, cut = live + int(end), cut + int(end) - int(full)
+        for j in range(n_kb):
+            first, full = A._query_tile_bounds(j * bk, bq, bk, n_qb, off)
+            live_t += n_qb - int(first)
+            cut_t += int(full) - int(first)
+        assert (live, cut) == (live_t, cut_t) \
+            == A._tile_counts(Sq, Sk, bq, bk, True, False), (Sq, Sk, bq, bk)
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -34,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 59 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 64 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 15         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -96,6 +96,42 @@ def test_a_recomputed_layer_holds_one_flash_forward_for_the_v5e(tool):
         "'flash_bwd_dkv': 2}; " in case
     assert re.search(r"\d+ bytes of arguments, \d+ of results, \d+ of "
                      r"temporaries in \d+\.\ds$", case), case
+    # `lse` and delta reach the backward kernels as the lane-dense rows the
+    # forward kernel wrote (PR 45): no `[128, 2048, 1]` float32 column, 134
+    # MB on a v5e for 1 MB of values, and none of the copies that made them
+    assert r"; no f32\[\d+,2048,1\]; " in case, case
+
+
+def test_the_flash_kernels_compile_at_the_tiles_their_shapes_choose(tool):
+    """PR 45: a grid step owns a row of sub-tiles. At the train cells'
+    shape every kernel's step holds the other operand whole (`chunk` =
+    2,048: K and V, or q and dO, fetched once a batch-head), computes the
+    ten 512 x 512 sub-tiles the causal mask leaves of sixteen (none dead
+    is visited: the loops' trip counts are the bounds) and masks the four
+    on the diagonal alone; the narrow-head rehearsal shape, a length 512
+    does not divide, a rectangle and a chunked 32k compile too."""
+    flash = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] flash fwd+bwd bf16")]
+    assert len(flash) == 7 and all(
+        "{'flash_fwd': 1, 'flash_bwd_dq': 1, 'flash_bwd_dkv': 1}" in c
+        for c in flash)
+    for shape in ("[8, 16, 2048, 128]:", "[1, 2, 512, 64]:",
+                  "[2, 4, 768, 128]:", "[1, 2, 32768, 128] in chunks:"):
+        assert any(shape in c for c in flash), shape
+    tilings = [ln for ln in tool.splitlines() if ln.startswith("tiling flash")]
+    for kernel, chunk in (("flash_fwd", "chunk_k"), ("flash_bwd_dq", "chunk_k"),
+                          ("flash_bwd_dkv", "chunk_q")):
+        assert any(
+            ln.startswith(f"tiling {kernel} ")
+            and f"'block_k': 512, 'block_q': 512, '{chunk}': 2048, 'grid': "
+                "(128, 4, 1), 'live_tiles': 10, 'masked_tiles': 4" in ln
+            for ln in tilings), (kernel, tilings)
+        assert any(ln.startswith(f"tiling {kernel} ")
+                   and f"'block_k': 384, 'block_q': 384, '{chunk}': 768" in ln
+                   for ln in tilings), kernel
+        assert any(ln.startswith(f"tiling {kernel} ")
+                   and f"'{chunk}': 8192, 'grid': (2, 64, 4)" in ln
+                   for ln in tilings), kernel
 
 
 def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):

@@ -55,9 +55,11 @@ def use_tpu_lowering():
     pallas_mode.platform = lambda: "tpu"
 
 
-def compile_case(name, fn, *specs, want=None, memory=False) -> bool:
+def compile_case(name, fn, *specs, want=None, memory=False,
+                 forbid=None) -> bool:
     """Lower + compile `fn` for the specs' devices; `want` is the expected
-    {kernel: count} of Mosaic custom calls in the compiled text."""
+    {kernel: count} of Mosaic custom calls in the compiled text, `forbid` a
+    pattern no line of it may hold."""
     from paddle_tpu.obs.compile_observatory import pallas_kernel_census
     t0 = time.perf_counter()
     try:
@@ -66,17 +68,20 @@ def compile_case(name, fn, *specs, want=None, memory=False) -> bool:
         print(f"[FAIL] {name}: {type(e).__name__}: {str(e)[:1500]}",
               flush=True)
         return False
-    census = pallas_kernel_census(compiled.as_text())
-    ok = want is None or census == want
-    held = ""
+    text = compiled.as_text()
+    census = pallas_kernel_census(text)
+    found = re.findall(forbid, text) if forbid else []
+    ok = (want is None or census == want) and not found
+    held = f"; no {forbid}" if forbid and not found else ""
     if memory:
         m = compiled.memory_analysis()
-        held = (f"; {m.argument_size_in_bytes} bytes of arguments, "
+        held += (f"; {m.argument_size_in_bytes} bytes of arguments, "
                 f"{m.output_size_in_bytes} of results, "
                 f"{m.temp_size_in_bytes} of temporaries")
     print(f"[{'OK' if ok else 'FAIL'}] {name}: {census}{held} in "
           f"{time.perf_counter() - t0:.1f}s"
-          + ("" if ok else f" (wanted {want})"), flush=True)
+          + ("" if ok else f" (wanted {want}; forbidden, found: "
+             f"{found[:3]})"), flush=True)
     return ok
 
 
@@ -267,27 +272,43 @@ def main() -> int:
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     results = []
-    qkv = [spec((2, 16, 1024, 128), jnp.bfloat16)] * 3
-    for label, kw in (("", {}), (" dropout", dict(dropout_p=0.1,
-                                                  dropout_seed=7))):
+    # the three flash kernels at the tiles `_choose_tiles` gives (the
+    # `tiling flash_*` lines below): chip_smoke's parity shape plain and
+    # with dropout, the train cells' shape, the rehearsal's narrow heads,
+    # a length 512 does not divide, a rectangle with its diagonal at
+    # Sk - Sq, and 32k, whose K and V no longer fit whole
+    for label, shape_q, shape_k, kw in (
+            ("", (2, 16, 1024, 128), None, {}),
+            (" dropout", (2, 16, 1024, 128), None,
+             dict(dropout_p=0.1, dropout_seed=7)),
+            ("", (8, 16, 2048, 128), None, {}),
+            ("", (1, 2, 512, 64), None, {}),
+            ("", (2, 4, 768, 128), None, {}),
+            (" over 2048 keys", (2, 4, 512, 128), (2, 4, 2048, 128), {}),
+            (" in chunks", (1, 2, 32768, 128), None, {})):
         def loss(q, k, v, kw=kw):
             return jnp.sum(A.flash_attention(q, k, v, causal=True, **kw)
                            .astype(jnp.float32))
         results.append(compile_case(
-            f"flash fwd+bwd bf16 [2,16,1024,128]{label}",
-            jax.grad(loss, argnums=(0, 1, 2)), *qkv,
+            f"flash fwd+bwd bf16 {list(shape_q)}{label}",
+            jax.grad(loss, argnums=(0, 1, 2)),
+            *[spec(s, jnp.bfloat16)
+              for s in (shape_q, shape_k or shape_q, shape_k or shape_q)],
             want={"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}))
 
     # per-layer recompute at the train cells' shapes: a layer's replay in
     # the backward pass takes the forward kernel's kept `out` and `lse`, so
-    # two layers hold two forward calls (four without the policy: PR 41)
+    # two layers hold two forward calls (four without the policy: PR 41);
+    # `lse` and delta go from kernel to kernel as lane-dense rows, so the
+    # compiled gradient holds no `f32[128,2048,1]` (134 MB where the values
+    # are 1 MB, relaid out by a `copy` each: four a layer before PR 45)
     results.append(compile_case(
         "recompute of 2 layers, flash fwd+bwd bf16 [8,16,2048,128]",
         recompute_grad(8, 16, 2048, 128, layers=2),
         spec((8, 2048, 2048), jnp.bfloat16),
         spec((2, 2048, 3 * 2048), jnp.bfloat16),
         want={"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2},
-        memory=True))
+        memory=True, forbid=r"f32\[\d+,2048,1\]"))
 
     # (label, q [B, H, Tq, D], slab [N, Hkv, L_slab, D], block_len, pages a
     # row): MHA at both block sizes the repo runs and both query widths,
