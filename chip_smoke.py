@@ -35,6 +35,7 @@ Last line of stdout on success:
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import signal
@@ -727,50 +728,91 @@ def _kv_write_parity(size: dict):
 
 def _ssm_parity(size: dict):
     """`ssm_update(impl="pallas")` against `impl="scan"` on this device at
-    `size["ssm"]`, bf16 state and inputs, 8 rows of 16, of 1 and of 80
+    `size["ssm"]`, bf16 state and inputs: 8 rows of 16, of 1 and of 80
     columns (80: past `MAX_COLUMNS`, the chunked walk `generate()`'s whole
     prompt takes) with ragged `adv` (a dead row, a row that starts from
-    zero, a row that ends inside the second chunk). Both run
-    the same float32 arithmetic column after column and differ in the
-    order of one sum over the state's channels and one bf16 rounding of
-    `y` and of the state: 2e-2 of the largest value. Prints the kernel's
-    grid and state tile."""
+    zero, a row that ends inside the second chunk), then the prefill
+    cell's step (32 rows of 16 live columns: every row in matrix form),
+    32 rows that alternate 16 and 1, and the decode cell's (128 rows, six
+    of them chunks). Both run the same float32 arithmetic and differ in
+    the order of their sums and one bf16 rounding of `y` and of the state:
+    2e-2 of the largest value. The two cells' shapes are also timed, a
+    call alone, with `MATRIX_COLUMNS` as it is set and with the column
+    loop alone (the threshold past T: PR 45's body), which is where the
+    threshold's number comes from. Prints the kernel's grid, state tile
+    and threshold."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from paddle_tpu.ops import pallas_mode
-    from paddle_tpu.ops.ssm import ssm_update
+    from paddle_tpu.ops import pallas_mode, ssm
     g = size["ssm"]
-    H, P, N, rows, tol = g["heads"], g["head_dim"], g["state"], 8, 2e-2
+    H, P, N, tol = g["heads"], g["head_dim"], g["state"], 2e-2
     rng = np.random.RandomState(2)
-    state = jnp.asarray(rng.randn(rows, N, H * P), jnp.bfloat16)
     a = -jnp.exp(jnp.asarray(rng.randn(H), jnp.float32))
-    for T in (16, 1, 80):
+    ragged = [16, 1, 0, 16, 3, 70, 16, 1]
+    cases = [(T, np.minimum(ragged, T), [0, 0, 0, 1, 0, 1, 0, 0], False)
+             for T in (16, 1, 80)]
+    cases += [(16, np.full(32, 16), np.arange(32) % 5 == 0, True),
+              (16, np.tile([16, 1], 16), np.arange(32) % 5 == 0, False),
+              (16, np.r_[np.full(6, 16), np.ones(122, int)],
+               np.arange(128) % 40 == 0, True)]
+
+    @jax.jit
+    def ten_calls(x, dt, b, c, state, adv, fresh):
+        return jax.lax.fori_loop(0, 10, lambda _, s: ssm.ssm_update(
+            x, dt, a, b, c, s, adv, fresh, impl="pallas")[1], state)
+
+    for T, adv, fresh, timed in cases:
+        rows = len(adv)
+        state = jnp.asarray(rng.randn(rows, N, H * P), jnp.bfloat16)
         x = jnp.asarray(rng.randn(rows, T, H * P), jnp.bfloat16)
         dt = jax.nn.softplus(jnp.asarray(rng.randn(rows, T, H), jnp.float32))
         b = jnp.asarray(rng.randn(rows, T, N), jnp.bfloat16)
         c = jnp.asarray(rng.randn(rows, T, N), jnp.bfloat16)
-        adv = jnp.asarray(np.minimum([T, 1, 0, T, 3, 70, T, 1], T), jnp.int32)
-        fresh = jnp.asarray([0, 0, 0, 1, 0, 1, 0, 0], jnp.int32)
+        adv, fresh = jnp.asarray(adv, jnp.int32), jnp.asarray(fresh, jnp.int32)
         pallas_mode.KERNEL_TILINGS.clear()
-        outs = {impl: ssm_update(x, dt, a, b, c, state, adv, fresh,
-                                 impl=impl) for impl in ("pallas", "scan")}
+        outs = {impl: ssm.ssm_update(x, dt, a, b, c, state, adv, fresh,
+                                     impl=impl) for impl in ("pallas", "scan")}
         (_, tiling), = pallas_mode.KERNEL_TILINGS
         tiling = dict(tiling)
         live = (np.arange(T)[None, :] < np.asarray(adv)[:, None])[..., None]
         y = {k: np.where(live, np.asarray(v[0], np.float32), 0.0)
              for k, v in outs.items()}
-        scale = max(float(np.abs(y["scan"]).max()), 1.0)
-        err_y = _max_err(y["pallas"], y["scan"]) / scale
-        err_s = _max_err(outs["pallas"][1], outs["scan"][1]) / max(
-            float(jnp.abs(outs["scan"][1].astype(jnp.float32)).max()), 1.0)
-        _say(f"ssm_update pallas vs scan H={H} P={P} N={N} T={T} adv="
-             f"{np.asarray(adv).tolist()} bf16: grid {tiling['grid']}, "
-             f"state tile {tiling['state_tile']}; max err / largest value "
-             f"y {err_y:.2e}, state {err_s:.2e} (tolerance {tol:g})")
-        _require(np.isfinite(err_y + err_s) and max(err_y, err_s) <= tol,
-                 f"ssm_update T={T} within {tol:g}")
+        y_most = max(float(np.abs(y["scan"]).max()), 1.0)
+        s_most = max(float(jnp.abs(outs["scan"][1].astype(jnp.float32))
+                           .max()), 1.0)
+        err_y = _max_err(y["pallas"], y["scan"])
+        err_s = _max_err(outs["pallas"][1], outs["scan"][1])
+        by_adv = dict(sorted(collections.Counter(adv.tolist()).items()))
+        _say(f"ssm_update pallas vs scan H={H} P={P} N={N} T={T} rows by "
+             f"live columns {by_adv} bf16: grid {tiling['grid']}, state tile "
+             f"{tiling['state_tile']}, matrix form from "
+             f"{tiling['matrix_from']} columns; max abs err y {err_y:.3e} "
+             f"of {y_most:.1f}, state {err_s:.3e} of {s_most:.1f} "
+             f"(tolerance {tol:g} of the largest)")
+        _require(np.isfinite(err_y + err_s)
+                 and max(err_y / y_most, err_s / s_most) <= tol,
+                 f"ssm_update T={T} x {rows} rows within {tol:g}")
+        if not timed or pallas_mode.platform() == "cpu":
+            continue        # a time is the chip's to give
+        took = {}
+        for name, threshold in (("as set", ssm.MATRIX_COLUMNS),
+                                ("the loop alone", T + 1)):
+            set_to, ssm.MATRIX_COLUMNS = ssm.MATRIX_COLUMNS, threshold
+            try:
+                args = (x, dt, b, c, state, adv, fresh)
+                ten_calls.clear_cache()
+                jax.block_until_ready(ten_calls(*args))
+                t0 = time.perf_counter()
+                for _ in range(5):
+                    out = ten_calls(*args)
+                jax.block_until_ready(out)
+                took[name] = (time.perf_counter() - t0) / 50 * 1e3
+            finally:
+                ssm.MATRIX_COLUMNS = set_to
+        _say(f"ssm_update {rows} rows x {T}: " + ", ".join(
+            f"{ms:.3f} ms a call {name}" for name, ms in took.items()))
 
 
 def _post(port: int, path: str, payload: dict, timeout: float):

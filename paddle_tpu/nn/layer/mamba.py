@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...core.tensor import apply
+from ...ops import ssm
 from ...ops.ssm import (causal_conv_tokens, causal_conv_update,
                         selective_scan, selective_scan_rows, ssm_update)
 from .. import initializer as I
@@ -63,6 +64,12 @@ class Mamba2Mixer(Layer):
         for p in (self.conv_bias, self.dt_bias, self.A_log, self.D,
                   self.norm_weight):
             p.partition_spec = P(None)
+
+    @property
+    def matrix_columns(self) -> int:
+        """Live columns from which the recurrence's kernel advances a row
+        in matrix form (a serving engine counts its rows by it)."""
+        return ssm.MATRIX_COLUMNS
 
     def init_state(self, batch_size: int, dtype):
         """(conv `[batch, K - 1, d_conv]`, ssm `[batch, N, H * P]`), zeros."""

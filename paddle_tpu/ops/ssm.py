@@ -26,17 +26,63 @@ needs `x_t` along sublanes and a lane reduction for every `y`.)
 One path per platform, as `ops/grouped_matmul.py`:
 
 - on a TPU the Mosaic kernel `ssm_update`: grid (row, lane block); a row's
-  state tile is read once, held in registers 128 lanes at a time while the
-  row's live columns are applied one after the other (a loop of `adv`
-  trips: a decode row costs one column, not T), and written once. The
-  per-head scalars ride in SMEM. State in its storage type between steps
-  (bfloat16 in a bfloat16 model), float32 inside;
-- on the CPU the same arithmetic in `jax.numpy` (`lax.scan` over the
-  columns), counted `ssm_update/scan`.
+  state tile is read once and written once, 128 lanes at a time, by one of
+  two bodies that the kernel chooses from the row's `adv` on the scalar
+  core (both behind a `pl.when` in the one `pallas_call`; a call too narrow
+  for any row to reach the threshold traces the first alone). The
+  `pallas_call` sits under one module-level `jax.jit` whose integers are
+  static (`_ssm_call`), so the layers of one traced step share one jaxpr
+  and the lowered module holds one kernel body for them;
+- on the CPU the recurrence in `jax.numpy` (`lax.scan` over the columns),
+  counted `ssm_update/scan`: the kernel's plain reference.
+
+**The column loop** (`adv < MATRIX_COLUMNS`: a decode row, a short tail).
+The state tile sits in registers while the row's live columns are applied
+one after the other (a loop of `adv` trips: a decode row costs one column,
+not T), each a full pass over the chunk's sixteen registers: a multiply by
+the decay, a multiply-add of `B_t`, a multiply by `C_t` and a sublane sum.
+The per-head scalars ride in SMEM. State in its storage type between steps
+(bfloat16 in a bfloat16 model), float32 inside.
+
+**The matrix body** (`adv >= MATRIX_COLUMNS`: a prefill chunk). With
+`d_u = dt_u A[h] <= 0` (0 on a dead column) and `L_t = sum_{u <= t} d_u`,
+
+    y_t = exp(L_t) (C S_0)_t + sum_{s <= t} (C_t . B_s) exp(L_t - L_s) dt_s x_s
+    S_T = exp(L_T) S_0       + B^T [exp(L_T - L_s) dt_s x_s]_s
+
+so a 128-lane chunk (two heads) costs, instead of `adv` passes over its
+state: `C [T, N] . S_0 [N, 128]` on the MXU (both bfloat16 as stored: one
+pass, exact products, float32 sums; a float32 state or float32 `B`, `C`
+take the compiler's float32 product: the operand's type decides);
+`G = C B^T [T, T]` once a row (every head shares it), masked to `s <= t`
+and the live columns, each column of it laid along the lanes; the `[T, T]`
+product with `dt x` on the vector unit, an 8-row tile of `y` at a time (a
+tile's own columns one by one with `exp(min(within_t - within_s, 0))`, an
+earlier tile's columns summed as they leave that tile and brought here by
+one `exp`; tiles above the diagonal are skipped); `B^T [N, T] . w [T, 128]`
+on the MXU with `w` float32 as three bfloat16 terms down the contraction
+(`B` is exact in bfloat16, so that is a float32 product), and **one** pass
+over the state. What a head's decays give (`_head_rows`: sums of log-decays
+inside and across tiles of eight columns, their `exp`s, `dt`) is computed
+per head by XLA on `[rows, T, H]` and laid over the head's lanes inside the
+kernel by a product with a 0 / 1 matrix on the MXU, again as three bfloat16
+terms. Every decay is the `exp` of a sum of log-decays (all <= 0) or, inside
+a tile, of a difference of two sums of at most eight: never a ratio of two
+`exp`s (sixteen columns of a strongly decaying head underflow float32),
+never a difference of two long sums (a slow head's low bits). Dead columns
+have `dt` 0, `B` and `x` masked: what they hold reaches nothing; a `fresh`
+row starts from zero in either body. No operand is rounded to bfloat16 that
+the loop keeps in float32.
+
+**What chooses.** In the loop a row costs ~5 us whatever it holds (its
+4.2 MB of bfloat16 state in and out) plus ~3.2 us a live column; the matrix
+body is one cost for up to T columns, ~10 us at T = 16. `MATRIX_COLUMNS` is
+the crossover measured on a v5e (the numbers stand beside it).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -53,58 +99,204 @@ LANE_BLOCK = 2048
 # columns one call of the kernel takes (its column tables live in VMEM);
 # a longer sequence is walked in chunks of this many, state carried
 MAX_COLUMNS = 64
+# live columns from which a row takes the matrix body, under which the column
+# loop. On a v5e at granite-4.0-h-small's widths, 32 rows of a 16-column
+# call (my chip runs, PR 46): the loop 5.0 us a row + 3.17 us a live column
+# (263 us a call at one column, 365 at two, 462 at three, 1,786 at
+# sixteen), the matrix body 10.2 us a row whatever the row holds (326 us a
+# call): they cross at 1.6 columns. (A 64-column call's matrix body costs
+# four times as much; its rows are whole chunks of a prompt but the last.)
+MATRIX_COLUMNS = 2
+# columns x 128-lane chunks the matrix body writes out a trip of its loop
+# (8 chunks of a 16-column call, 2 of a 64-column one). A chunk alone is a
+# chain of latencies; the same call with 1 / 2 / 4 / 8 / 16 chunks a trip:
+# 514 / 419 / 349 / 326 / 289 us, the body traced and lowered in 0.6 / - /
+# 0.8 / 1.3 / 1.9 s (sandbox) where PR 45's nine bodies took 3.3
+TRIP_COLUMNS = 128
 F32 = jnp.float32
+# the package's default precision asks the compiler for float32 products;
+# a product of bfloat16 operands is exact in one pass of the MXU
+_ONE_PASS = jax.lax.Precision.DEFAULT
+
+
+def _bf16_terms(a, axis: int, in_kernel: bool):
+    """float32 `a` as three bfloat16 terms side by side along `axis` whose
+    sum is `a` to 2**-24 of it: one bfloat16 pass of the MXU over them,
+    against an operand that is exact in bfloat16 and repeated three times
+    along the contraction, is a float32 product. Outside a kernel each
+    term is rounded by `reduce_precision`, which XLA must honour: it is
+    allowed excess precision and reads `a - float32(bfloat16(a))` inside
+    one fusion as 0, which leaves the first term alone (2**-8 of `a`: seen
+    on the chip). Mosaic has no such primitive and converts as written."""
+    def rounded(v):
+        if in_kernel:
+            return v.astype(jnp.bfloat16).astype(F32)
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+    hi = rounded(a)
+    mid = rounded(a - hi)
+    lo = a - hi - mid
+    return jnp.concatenate([hi, mid, lo], axis=axis).astype(jnp.bfloat16)
+
+
+def _product(a, b, dims=(((1,), (0,)), ((), ()))):
+    """`a . b` with a float32 accumulator that rounds nothing its operands
+    hold: one pass where both are bfloat16 (the products are exact), the
+    compiler's float32 product where either is float32."""
+    if a.dtype == b.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(a, b, dims, precision=_ONE_PASS,
+                                   preferred_element_type=F32)
+    return jax.lax.dot_general(a.astype(F32), b.astype(F32), dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=F32)
 
 
 def _kernel(adv_ref, fresh_ref, da_ref, dt_ref, x_ref, b_ref, c_ref, s_ref,
-            y_ref, out_ref, bcol, ccol, *, heads_per_chunk, head_dim):
+            *rest, heads_per_chunk, head_dim, matrix_from):
     row, block = pl.program_id(0), pl.program_id(1)
     adv = adv_ref[row]
     T, N = b_ref.shape[1], b_ref.shape[2]
-
-    # B_t and C_t as columns along the sublanes, once a row: every lane
-    # block of the row (and every head: one group) uses the same ones
-    @pl.when(block == 0)
-    def _columns():
-        for t in range(T):
-            @pl.when(t < adv)
-            def _():
-                for ref, col in ((b_ref, bcol), (c_ref, ccol)):
-                    col[t] = jnp.broadcast_to(
-                        ref[0, t:t + 1, :].astype(F32).reshape(N, 1),
-                        (N, LANES))
-
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
-    column = jax.lax.broadcasted_iota(jnp.int32, (T, LANES), 0)
     keep = fresh_ref[row] == 0
+    if matrix_from is None:
+        (y_ref, out_ref, bcol, ccol), by_loop = rest, None
+    else:
+        heads_ref, spread_ref, bt_ref, y_ref, out_ref, bcol, ccol, gcol, \
+            wide = rest
+        by_loop = adv < matrix_from
 
-    def per_head(ref, t, chunk):
-        """The heads' scalars of column t as one row of lanes."""
-        first = chunk * heads_per_chunk
-        out = jnp.full((1, LANES), ref[0, 0, t, first], F32)
-        for k in range(1, heads_per_chunk):
-            out = jnp.where(lane >= k * head_dim,
-                            ref[0, 0, t, first + k], out)
-        return out
+    # ---- a row of few live columns: one pass over the state a column ----
+    def loop_columns_body():
+        # B_t and C_t as columns along the sublanes, once a row: every lane
+        # block of the row (and every head: one group) uses the same ones
+        @pl.when(block == 0)
+        def _columns():
+            for t in range(bcol.shape[0]):      # what a loop row can have
+                @pl.when(t < adv)
+                def _():
+                    for ref, col in ((b_ref, bcol), (c_ref, ccol)):
+                        col[t] = jnp.broadcast_to(
+                            ref[0, t:t + 1, :].astype(F32).reshape(N, 1),
+                            (N, LANES))
 
-    for chunk in range(s_ref.shape[2] // LANES):
-        sl = pl.ds(chunk * LANES, LANES)
-        state = jnp.where(keep, s_ref[0, :, sl].astype(F32), 0.0)
-        xs = x_ref[0, :, sl].astype(F32)                       # [T, 128]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+        column = jax.lax.broadcasted_iota(jnp.int32, (T, LANES), 0)
 
-        def one_column(t, carry, chunk=chunk, xs=xs):
-            state, ys = carry
-            here = column == t
-            x_t = jnp.sum(jnp.where(here, xs, 0.0), axis=0, keepdims=True)
-            state = state * per_head(da_ref, t, chunk) \
-                + bcol[t] * (x_t * per_head(dt_ref, t, chunk))
-            y_t = jnp.sum(state * ccol[t], axis=0, keepdims=True)
-            return state, jnp.where(here, y_t, ys)
+        def per_head(ref, t, chunk):
+            """The heads' scalars of column t as one row of lanes."""
+            first = chunk * heads_per_chunk
+            out = jnp.full((1, LANES), ref[0, 0, t, first], F32)
+            for k in range(1, heads_per_chunk):
+                out = jnp.where(lane >= k * head_dim,
+                                ref[0, 0, t, first + k], out)
+            return out
 
-        state, ys = jax.lax.fori_loop(
-            0, adv, one_column, (state, jnp.zeros((T, LANES), F32)))
-        y_ref[0, :, sl] = ys.astype(y_ref.dtype)
-        out_ref[0, :, sl] = state.astype(out_ref.dtype)
+        for chunk in range(s_ref.shape[2] // LANES):
+            sl = pl.ds(chunk * LANES, LANES)
+            state = jnp.where(keep, s_ref[0, :, sl].astype(F32), 0.0)
+            xs = x_ref[0, :, sl].astype(F32)                   # [T, 128]
+
+            def one_column(t, carry, chunk=chunk, xs=xs):
+                state, ys = carry
+                here = column == t
+                x_t = jnp.sum(jnp.where(here, xs, 0.0), axis=0,
+                              keepdims=True)
+                state = state * per_head(da_ref, t, chunk) \
+                    + bcol[t] * (x_t * per_head(dt_ref, t, chunk))
+                y_t = jnp.sum(state * ccol[t], axis=0, keepdims=True)
+                return state, jnp.where(here, y_t, ys)
+
+            state, ys = jax.lax.fori_loop(
+                0, adv, one_column, (state, jnp.zeros((T, LANES), F32)))
+            y_ref[0, :, sl] = ys.astype(y_ref.dtype)
+            out_ref[0, :, sl] = state.astype(out_ref.dtype)
+
+    if by_loop is None:
+        return loop_columns_body()
+    pl.when(by_loop)(loop_columns_body)
+
+    # ---- a row of many: its T columns at once (the module docstring) ----
+    @pl.when(jnp.logical_not(by_loop))
+    def _matrix():
+        # G[t, s] = C_t . B_s for s <= t, once a row (every head shares it),
+        # each of its columns laid along the lanes
+        @pl.when(block == 0)
+        def _gram():
+            g = _product(c_ref[0], b_ref[0], (((1,), (1,)), ((), ())))
+            t_of = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+            s_of = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+            g = jnp.where((s_of <= t_of) & (s_of < adv), g, 0.0)
+            for s in range(T):
+                first = s // 8 * 8      # the 8-row tiles above hold zeros
+                gcol[s, first:] = jnp.broadcast_to(g[first:, s:s + 1],
+                                                   (T - first, LANES))
+
+        # the block's per-head rows (`_head_rows`), each head over its own
+        # lanes: a product with a 0 / 1 matrix, the float32 values as three
+        # bfloat16 terms along the contraction
+        wide[...] = _product(heads_ref[0, block], spread_ref[...])
+        live = jax.lax.broadcasted_iota(jnp.int32, (T, LANES), 0) < adv
+        tiles = T // 8
+        c, b_t = c_ref[0], bt_ref[0]      # the same for every lane chunk
+
+        def one_chunk(chunk):
+            sl = pl.ds(pl.multiple_of(chunk * LANES, LANES), LANES)
+
+            def of_tile(which, k):        # a `[1, 128]` row of `_head_rows`
+                at = 2 * T + which * tiles + k
+                return wide[at:at + 1, sl]
+
+            within = [wide[8 * k:8 * k + 8, sl] for k in range(tiles)]
+            xs = jnp.where(live, x_ref[0, :, sl].astype(F32), 0.0)
+            xdt = wide[T:2 * T, sl] * xs                           # [T, 128]
+            s0 = s_ref[0, :, sl]
+            from_s0 = _product(c, s0)
+            # exp(L_t) by tiles: the tiles before t's, then t's own columns
+            risen = [jnp.exp(w) for w in within]
+            grown = [risen[k] * of_tile(0, k) for k in range(tiles)]
+            # a column's input as it leaves its tile
+            leaving = [xdt[8 * k:8 * k + 8]
+                       * jnp.exp(within[k][7:8] - within[k])
+                       for k in range(tiles)]
+            # y by 8-row tiles: what the state the row came with gives each
+            # column; a tile's own columns one by one; an earlier tile's
+            # summed as they leave that tile and brought here by one decay
+            y = [jnp.where(keep, grown[k] * from_s0[8 * k:8 * k + 8], 0.0)
+                 for k in range(tiles)]
+            for j in range(tiles):
+                own = slice(8 * j, 8 * j + 8)
+                for i in range(8):
+                    s = 8 * j + i
+                    decay = jnp.exp(jnp.minimum(
+                        within[j] - within[j][i:i + 1], 0.0))
+                    y[j] = y[j] + gcol[s, own] * decay * xdt[s:s + 1]
+                for k in range(j + 1, tiles):
+                    rows = slice(8 * k, 8 * k + 8)
+                    arriving = sum(gcol[8 * j + i, rows] * leaving[j][i:i + 1]
+                                   for i in range(8))
+                    reach = risen[k] if k == j + 1 else jnp.exp(
+                        within[k] + of_tile(2 + k, j))   # the tiles between
+                    y[k] = y[k] + reach * arriving
+            y_ref[0, :, sl] = jnp.concatenate(y, axis=0).astype(y_ref.dtype)
+            # the state after the row's columns: one pass
+            w = jnp.concatenate([leaving[k] * of_tile(1, k)
+                                 for k in range(tiles)], axis=0)
+            new = _product(b_t, _bf16_terms(w, 0, True)
+                           if b_t.dtype == jnp.bfloat16 else w)
+            out_ref[0, :, sl] = (
+                jnp.where(keep, s0.astype(F32), 0.0) * grown[-1][7:8]
+                + new).astype(out_ref.dtype)
+
+        # a few chunks a trip, written out: one chunk's products and `exp`s
+        # run under another's vector work (a chunk alone is a chain of
+        # latencies: load, latch, product, pop, pass, store)
+        chunks = s_ref.shape[2] // LANES
+        a_trip = math.gcd(chunks, max(TRIP_COLUMNS // T, 1))
+
+        def some_chunks(trip, _):
+            for k in range(a_trip):
+                one_chunk(trip * a_trip + k)
+            return 0
+
+        jax.lax.fori_loop(0, chunks // a_trip, some_chunks, 0)
 
 
 def _lane_block(lanes: int) -> int:
@@ -117,7 +309,114 @@ def _lane_block(lanes: int) -> int:
                      f"multiple of {LANES} and at most {LANE_BLOCK}")
 
 
-def _mosaic(x, dt_live, decay, b, c, state, adv, fresh):
+def _head_rows(log_decay, dt_live):
+    """What the matrix body needs of a head's decays, `[rows, 2 T + 2 tiles
+    (+ tiles squared), H]` float32, with `d_u = dt_u A <= 0` a column's
+    log-decay (0 on a dead column) and the T columns cut into tiles of
+    eight. Every decay the body takes is the `exp` of a sum of log-decays,
+    or inside one tile of a difference of two sums of at most eight: never
+    a ratio of two `exp`s (sixteen columns of a strongly decaying head
+    underflow float32), never a difference of two long sums (which would
+    lose the low bits of a slow head's decay).
+    T rows each: `within_t`, the sum of d from t's tile's first column to
+    t; `dt_t`. A row a tile each: `exp` of the sum of d over the tiles
+    before it; over the tiles after it. Then, beyond two tiles, row
+    k * tiles + j: the sum of d over the whole tiles between j and k."""
+    rows, T, H = log_decay.shape
+    tiles = T // 8
+    within = jnp.cumsum(log_decay.reshape(rows, tiles, 8, H), axis=2)
+    whole = within[:, :, -1]                              # a tile's sum
+    before = jnp.cumsum(whole, axis=1) - whole
+    after = jnp.cumsum(whole[:, ::-1], axis=1)[:, ::-1] - whole
+    parts = [within.reshape(rows, T, H), dt_live, jnp.exp(before),
+             jnp.exp(after)]
+    if tiles > 2:
+        j = jnp.arange(tiles)
+        between = ((j[None, :, None] > j[None, None, :])     # [k, m, j]
+                   & (j[None, :, None] < j[:, None, None])).astype(F32)
+        parts.append(jnp.einsum(
+            "kmj,rmh->rkjh", between, whole,
+            precision=jax.lax.Precision.HIGHEST).reshape(rows, -1, H))
+    out = jnp.concatenate(parts, axis=1)
+    return jnp.pad(out, ((0, 0), (0, -out.shape[1] % 16), (0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("lb", "matrix_from",
+                                             "interpret"))
+def _ssm_call(x, dt_live, log_decay, b, c, state, adv, fresh, *, lb,
+              matrix_from, interpret):
+    """The kernel's `pallas_call` at one tiling. Jitted at module level
+    with its integers static, so the layers of one traced step share one
+    jaxpr and the lowered module holds one kernel body for them.
+    `matrix_from`: rows of at least so many live columns take the matrix
+    body (None: no row of this call can; the body is the loop alone)."""
+    rows, T, lanes = x.shape
+    heads, N = dt_live.shape[2], state.shape[1]
+    head_dim = lanes // heads
+    blocks, heads_per_block = lanes // lb, lb // head_dim
+
+    def scalars(a):       # [rows, n, H] -> [rows, blocks, n, heads a block]
+        return a.reshape(rows, -1, blocks, heads_per_block).transpose(
+            0, 2, 1, 3)
+
+    smem = pl.BlockSpec((1, 1, T, heads_per_block),
+                        lambda r, g, *_: (r, g, 0, 0),
+                        memory_space=pltpu.SMEM)
+    wide = pl.BlockSpec((1, T, lb), lambda r, g, *_: (r, 0, g))
+    narrow = pl.BlockSpec((1, T, N), lambda r, g, *_: (r, 0, 0))
+    tile = pl.BlockSpec((1, N, lb), lambda r, g, *_: (r, 0, g))
+    operands = [scalars(jnp.exp(log_decay)), scalars(dt_live), x, b, c,
+                state]
+    in_specs = [smem, smem, wide, narrow, narrow, tile]
+    # the loop's column tables hold the columns a loop row can have
+    loop_columns = T if matrix_from is None else max(matrix_from - 1, 1)
+    scratch = [pltpu.VMEM((loop_columns, N, LANES), F32),
+               pltpu.VMEM((loop_columns, N, LANES), F32)]
+    if matrix_from is not None:
+        per_head = scalars(_head_rows(log_decay, dt_live))
+        # head h of a block over its own lanes, three times down the rows
+        spread = (jnp.arange(lb, dtype=jnp.int32)[None, :] // head_dim
+                  == jnp.arange(3 * heads_per_block, dtype=jnp.int32)[:, None]
+                  % heads_per_block).astype(jnp.bfloat16)
+        # B^T [rows, N, T], a dead column's B zero (it adds nothing to the
+        # state); against `w`'s three bfloat16 terms, three times over
+        live = jnp.arange(T, dtype=jnp.int32)[None, None, :] \
+            < adv[:, None, None]
+        b_t = jnp.where(live, jnp.swapaxes(b, 1, 2), 0)
+        if b.dtype == jnp.bfloat16:
+            b_t = jnp.broadcast_to(b_t[:, :, None], (rows, N, 3, T)).reshape(
+                rows, N, 3 * T)
+        operands += [_bf16_terms(per_head, -1, False), spread, b_t]
+        in_specs += [
+            # a row's blocks in one piece: fetched once a row
+            pl.BlockSpec((1, blocks, per_head.shape[2], 3 * heads_per_block),
+                         lambda r, g, *_: (r, 0, 0, 0)),
+            pl.BlockSpec(spread.shape, lambda r, g, *_: (0, 0)),
+            pl.BlockSpec((1, N, b_t.shape[2]), lambda r, g, *_: (r, 0, 0))]
+        scratch += [pltpu.VMEM((T, T, LANES), F32),
+                    pltpu.VMEM((per_head.shape[2], lb), F32)]
+    return pl.pallas_call(
+        functools.partial(_kernel, heads_per_chunk=LANES // head_dim,
+                          head_dim=head_dim, matrix_from=matrix_from),
+        out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(rows, blocks),
+            in_specs=in_specs, out_specs=[wide, tile],
+            scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        # the new state takes the state's buffer: a grid step reads and
+        # writes its own tile alone, so where the caller donates the state
+        # (the serving step its pool) nothing is copied round the kernel
+        input_output_aliases={7: 1},
+        interpret=interpret,
+        name=KERNEL,
+    )(adv, fresh, *operands)
+
+
+def _mosaic(x, dt_live, log_decay, b, c, state, adv, fresh):
     rows, T, lanes = x.shape
     heads, N = dt_live.shape[2], state.shape[1]
     head_dim = lanes // heads
@@ -127,42 +426,20 @@ def _mosaic(x, dt_live, decay, b, c, state, adv, fresh):
             f"fill whole {LANES}-lane registers with whole heads, and the "
             f"state's {N} channels whole sublane tiles")
     lb = _lane_block(lanes)
-    blocks, heads_per_block = lanes // lb, lb // head_dim
-    pallas_mode.note_tiling(KERNEL, grid=(rows, blocks), columns=T,
-                            state_tile=(N, lb))
-
-    def scalars(a):       # [rows, T, H] -> [rows, blocks, T, heads a block]
-        return a.reshape(rows, T, blocks, heads_per_block).transpose(
-            0, 2, 1, 3)
-
-    smem = pl.BlockSpec((1, 1, T, heads_per_block),
-                        lambda r, g, *_: (r, g, 0, 0),
-                        memory_space=pltpu.SMEM)
-    wide = pl.BlockSpec((1, T, lb), lambda r, g, *_: (r, 0, g))
-    narrow = pl.BlockSpec((1, T, N), lambda r, g, *_: (r, 0, 0))
-    tile = pl.BlockSpec((1, N, lb), lambda r, g, *_: (r, 0, g))
-    y, new_state = pl.pallas_call(
-        functools.partial(_kernel, heads_per_chunk=LANES // head_dim,
-                          head_dim=head_dim),
-        out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(rows, blocks),
-            in_specs=[smem, smem, wide, narrow, narrow, tile],
-            out_specs=[wide, tile],
-            scratch_shapes=[pltpu.VMEM((T, N, LANES), F32),
-                            pltpu.VMEM((T, N, LANES), F32)]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=64 << 20),
-        # the new state takes the state's buffer: a grid step reads and
-        # writes its own tile alone, so where the caller donates the state
-        # (the serving step its pool) nothing is copied round the kernel
-        input_output_aliases={7: 1},
-        interpret=pallas_mode.interpret(KERNEL),
-        name=KERNEL,
-    )(adv, fresh, scalars(decay), scalars(dt_live), x, b, c, state)
-    return y, new_state
+    # a call too narrow for any row to reach the matrix body traces the
+    # loop alone; one that can pads its columns to whole packed tiles
+    matrix_from = MATRIX_COLUMNS if T >= MATRIX_COLUMNS else None
+    pad = -T % 16 if matrix_from else 0
+    pallas_mode.note_tiling(KERNEL, grid=(rows, lanes // lb), columns=T,
+                            state_tile=(N, lb), matrix_from=matrix_from or 0)
+    if pad:
+        x, dt_live, log_decay, b, c = (
+            jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+            for a in (x, dt_live, log_decay, b, c))
+    y, new_state = _ssm_call(
+        x, dt_live, log_decay, b, c, state, adv, fresh, lb=lb,
+        matrix_from=matrix_from, interpret=pallas_mode.interpret(KERNEL))
+    return y[:, :T], new_state
 
 
 def _scan(x, dt_live, decay, b, c, state, fresh):
@@ -214,12 +491,12 @@ def ssm_update(x, dt, a, b, c, state, adv=None, fresh=None,
         else fresh.astype(jnp.int32)
     live = jnp.arange(T, dtype=jnp.int32)[None, :] < adv[:, None]
     dt_live = jnp.where(live[..., None], dt.astype(F32), 0.0)
-    decay = jnp.exp(dt_live * a.astype(F32))         # 1 on a dead column
+    log_decay = dt_live * a.astype(F32)              # 0 on a dead column
     if impl == "scan":
         pallas_mode.count(KERNEL, "scan")
-        return _scan(x, dt_live, decay, b, c, state, fresh)
+        return _scan(x, dt_live, jnp.exp(log_decay), b, c, state, fresh)
     if T <= MAX_COLUMNS:
-        return _mosaic(x, dt_live, decay, b, c, state, adv, fresh)
+        return _mosaic(x, dt_live, log_decay, b, c, state, adv, fresh)
     # a long sequence (a whole prompt): chunks of MAX_COLUMNS, state carried
     pad = -T % MAX_COLUMNS
 
@@ -237,7 +514,7 @@ def ssm_update(x, dt, a, b, c, state, adv=None, fresh=None,
 
     (state, _, _), ys = jax.lax.scan(
         one_chunk, (state, adv, fresh),
-        tuple(chunks(arr) for arr in (x, dt_live, decay, b, c)))
+        tuple(chunks(arr) for arr in (x, dt_live, log_decay, b, c)))
     y = jnp.swapaxes(ys, 0, 1).reshape(rows, T + pad, x.shape[2])
     return y[:, :T], state
 

@@ -629,6 +629,12 @@ class LLMEngine:
         state_bytes = self.pool.recurrent_state_bytes
         if state_bytes:
             self.metrics.set_recurrent_state(state_bytes)
+        # live columns from which the recurrent layers' kernel advances a
+        # row in matrix form (`Mamba2Mixer.matrix_columns`); a model whose
+        # layers walk every row a column at a time names none
+        self._matrix_columns = next(
+            (m.matrix_columns for m in model.sublayers()
+             if getattr(m, "matrix_columns", None)), None)
         by_kind = self.pool.kv_bytes()
         if any(n for label, n in by_kind.items() if label != "full"):
             self.metrics.set_kv_pool_bytes(by_kind)
@@ -2976,6 +2982,11 @@ class LLMEngine:
                                                 self.step_tokens, deferred)
                     if started:
                         self.metrics.on_recurrent_rows_started(started)
+                    if self.pool.recurrent:
+                        matrix = 0 if self._matrix_columns is None else int(
+                            np.count_nonzero(adv >= self._matrix_columns))
+                        self.metrics.on_recurrent_rows(
+                            matrix, kind_args["recurrent_rows"] - matrix)
                     self.metrics.on_kv_tokens(*kv_tokens)
                     if sparse_keys is not None:
                         self.metrics.on_sparse_keys(*sparse_keys)

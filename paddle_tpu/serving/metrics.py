@@ -292,6 +292,8 @@ class LLMMetrics(ServingMetrics):
                               "pool_copies": 0, "pool_lost": 0,
                               "moe_assignments": 0,
                               "recurrent_rows_started": 0,
+                              "recurrent_rows_matrix": 0,
+                              "recurrent_rows_loop": 0,
                               "window_kv_tokens": 0,
                               "full_kv_tokens": 0,
                               "sparse_keys_selected": 0,
@@ -608,6 +610,16 @@ class LLMMetrics(ServingMetrics):
         with self._lock:
             self.counters["recurrent_rows_started"] += int(n)
 
+    def on_recurrent_rows(self, matrix: int, loop: int):
+        """Of the rows whose recurrent state a committed step advanced (a
+        recurrent layer's call; every such layer sees the same rows):
+        `matrix` had enough live columns for the recurrence's kernel to
+        advance them at once, in matrix form (`ops/ssm.py`:
+        `MATRIX_COLUMNS`), `loop` were walked a column at a time."""
+        with self._lock:
+            self.counters["recurrent_rows_matrix"] += int(matrix)
+            self.counters["recurrent_rows_loop"] += int(loop)
+
     def on_moe_assignments(self, n: int):
         """A fetch of the device's per-expert totals
         (`LLMEngine.moe_expert_tokens()`: on /metrics, at `stop()`, on
@@ -830,9 +842,10 @@ class LLMMetrics(ServingMetrics):
             b.family(f"{px}_recurrent_state_bytes", "gauge")
             b.sample(f"{px}_recurrent_state_bytes",
                      s["recurrent_state_bytes"])
-            b.family(f"{px}_recurrent_rows_started_total", "counter")
-            b.sample(f"{px}_recurrent_rows_started_total",
-                     s["recurrent_rows_started"])
+            for name in ("recurrent_rows_started", "recurrent_rows_matrix",
+                         "recurrent_rows_loop"):
+                b.family(f"{px}_{name}_total", "counter")
+                b.sample(f"{px}_{name}_total", s[name])
         if s["kv_pool_bytes"] is not None:
             b.family(f"{px}_kv_pool_bytes", "gauge")
             for kind, nbytes in sorted(s["kv_pool_bytes"].items()):

@@ -37,7 +37,7 @@ from paddle_tpu.models.granitemoehybrid import (GraniteMoeHybridConfig,
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.nn.layer import moe
 from paddle_tpu.nn.layer.mamba import Mamba2Mixer
-from paddle_tpu.ops import ssm
+from paddle_tpu.ops import pallas_mode, ssm
 from paddle_tpu.profiler import SPAN_SERVE_DISPATCH
 from paddle_tpu.serving.llm.kv_pool import (RecurrentStateError,
                                             SlotPagedKVPool)
@@ -158,19 +158,30 @@ def test_ssm_update_is_the_per_head_recurrence():
 
 @pytest.mark.parametrize("T,lane_block,dtype", [
     (5, 128, jnp.float32), (16, 256, jnp.float32), (1, 128, jnp.float32),
-    (70, 128, jnp.float32), (16, 128, jnp.bfloat16)])
+    (70, 128, jnp.float32), (16, 128, jnp.bfloat16),
+    (64, 128, jnp.float32), (64, 256, jnp.bfloat16), (70, 256, jnp.float32),
+    (16, 256, jnp.bfloat16), (3, 128, jnp.float32)])
 def test_ssm_kernel_equals_its_scan(T, lane_block, dtype, monkeypatch):
     """The Mosaic kernel, interpreted, against the scan it stands beside:
-    the same float32 arithmetic column after column, so the same numbers
-    (more than one lane block a row, ragged `adv`, a fresh row, a sequence
-    longer than one call's columns)."""
-    k = _ssm_inputs(3, T, dtype=dtype)
-    adv = jnp.asarray([T, min(T, 3), 0], jnp.int32)
-    fresh = jnp.asarray([0, 1, 0], jnp.int32)
+    the same float32 arithmetic, column after column in a row of few live
+    columns and all of a row's columns at once from `MATRIX_COLUMNS` on, so
+    the same numbers. One call holds rows on both sides of that threshold
+    (`adv` 0, 1, one under it, at it, every column), rows that start from
+    zero in either body, more than one lane block a row, and a sequence
+    longer than one call's columns."""
+    m = ssm.MATRIX_COLUMNS
+    k = _ssm_inputs(7, T, dtype=dtype)
+    adv = jnp.asarray([min(n, T) for n in (T, 3, 0, 1, m - 1, m, T)],
+                      jnp.int32)
+    fresh = jnp.asarray([0, 1, 0, 0, 0, 1, 1], jnp.int32)
     args = (k["x"], k["dt"], k["a"], k["b"], k["c"], k["state"], adv, fresh)
     y0, s0 = ssm.ssm_update(*args, impl="scan")
     monkeypatch.setattr(ssm, "LANE_BLOCK", lane_block)
+    pallas_mode.KERNEL_TILINGS.clear()
     y1, s1 = ssm.ssm_update(*args, impl="pallas")
+    # a call none of whose rows can reach the threshold traces the loop alone
+    assert all(dict(t)["matrix_from"] == (m if T >= m else 0)
+               for _, t in pallas_mode.KERNEL_TILINGS)
     live = (np.arange(T)[None] < np.asarray(adv)[:, None])[..., None]
     tol = dict(rtol=1e-5, atol=1e-5) if dtype == jnp.float32 \
         else dict(rtol=2e-2, atol=2e-2)
@@ -182,21 +193,89 @@ def test_ssm_kernel_equals_its_scan(T, lane_block, dtype, monkeypatch):
     assert s1.dtype == k["state"].dtype and y1.dtype == k["x"].dtype
 
 
-def test_ssm_kernel_writes_the_new_state_into_the_states_buffer():
+@pytest.mark.parametrize("T", [16, 64])
+def test_a_head_that_forgets_everything_in_a_column_stays_finite(T):
+    """Decays are `exp` of differences of log-decays, never a ratio of two
+    `exp`s: a head that decays by e^-20 a column (its sixteen columns
+    underflow float32: a ratio would read 0 / 0) beside one that hardly
+    decays at all (whose long sums must keep their low bits) gives finite
+    `y` and state, the scan's; what a dead column holds, NaN included,
+    reaches nothing."""
+    k = _ssm_inputs(3, T, H=4)
+    k["a"] = jnp.asarray([-20.0, -1e-3, -1.0, -5.0], jnp.float32)
+    k["dt"] = k["dt"].at[..., 0].set(1.0)
+    adv = jnp.asarray([T, T - 3, T], jnp.int32)
+    fresh = jnp.asarray([0, 0, 1], jnp.int32)
+    dead = np.arange(T)[None, :, None] >= np.asarray(adv)[:, None, None]
+    y0, s0 = ssm.ssm_update(k["x"], k["dt"], k["a"], k["b"], k["c"],
+                            k["state"], adv, fresh, impl="scan")
+    x, b, c = (jnp.where(dead, jnp.nan, k[name]) for name in "xbc")
+    y1, s1 = ssm.ssm_update(x, k["dt"], k["a"], b, c, k["state"], adv,
+                            fresh, impl="pallas")
+    y0, y1 = (np.where(dead, 0, np.asarray(y)) for y in (y0, y1))
+    assert np.isfinite(y1).all() and np.isfinite(np.asarray(s1)).all()
+    # the slow head's y reaches 100-150 out of sums that cancel: either
+    # implementation is 1.4e-5 to 3.0e-5 from the float64 recurrence
+    tol = dict(rtol=1e-5, atol=4e-7 * float(np.abs(y0).max()))
+    np.testing.assert_allclose(y1, y0, **tol)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), **tol)
+    # the first head's state is its last live column's input alone
+    assert float(jnp.abs(s1[0, :, :64]).max()) > 0
+
+
+def _calls(jaxpr, name):
+    """Equations of `jaxpr`, nested ones included, of primitive `name`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for value in eqn.params.values():
+            sub = getattr(value, "jaxpr", value)
+            if hasattr(sub, "eqns") and eqn.primitive.name != "pallas_call":
+                found += _calls(sub, name)
+    return found
+
+
+@pytest.mark.parametrize("T", [2, 16])
+def test_ssm_kernel_writes_the_new_state_into_the_states_buffer(T):
     """A grid step reads and writes its own state tile alone, so the
     kernel aliases the state to the new state: inside a step that is
     donated its pool nothing is copied round the call (without it XLA
     copies the whole state behind every layer: +6.8 ms a step in
-    `granite-4.0-h-small.serve-decode`, my chip run, PR 35)."""
-    k = _ssm_inputs(3, 4, dtype=jnp.bfloat16)
-    adv, fresh = jnp.asarray([4, 2, 0]), jnp.asarray([0, 1, 0])
+    `granite-4.0-h-small.serve-decode`, my chip run, PR 35). One
+    `pallas_call` named `ssm_update`, with the matrix body (T = 16) and
+    without it."""
+    k = _ssm_inputs(3, T, dtype=jnp.bfloat16)
+    adv, fresh = jnp.asarray([T, 2, 0]), jnp.asarray([0, 1, 0])
     jaxpr = jax.make_jaxpr(lambda *a: ssm.ssm_update(*a, impl="pallas"))(
         k["x"], k["dt"], k["a"], k["b"], k["c"], k["state"], adv, fresh)
-    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
-    assert len(calls) == 1
-    (src, dst), = calls[0].params["input_output_aliases"]
-    assert calls[0].invars[src].aval.shape == k["state"].shape
-    assert calls[0].outvars[dst].aval.shape == k["state"].shape
+    call, = _calls(jaxpr.jaxpr, "pallas_call")
+    assert call.params["name"] == ssm.KERNEL == "ssm_update"
+    (src, dst), = call.params["input_output_aliases"]
+    assert call.invars[src].aval.shape == k["state"].shape
+    assert call.outvars[dst].aval.shape == k["state"].shape
+
+
+def test_the_layers_of_a_step_share_one_ssm_kernel_body():
+    """The `pallas_call` sits under one module-level `jax.jit` whose
+    integers are static (`_ssm_call`), so a step's nine layers trace and
+    lower the kernel, both of its bodies, once (PR 32's lesson: a body a
+    call site is traced and lowered in every process, before the compile
+    cache can be asked)."""
+    k = _ssm_inputs(3, 16, dtype=jnp.bfloat16)
+    adv, fresh = jnp.asarray([16, 2, 0]), jnp.asarray([0, 1, 0])
+
+    def three_layers(x, dt, a, b, c, state):
+        for _ in range(3):
+            x, state = ssm.ssm_update(x, dt, a, b, c, state, adv, fresh,
+                                      impl="pallas")
+        return x, state
+    jaxpr = jax.make_jaxpr(three_layers)(
+        k["x"], k["dt"], k["a"], k["b"], k["c"], k["state"])
+    sites = [e for e in jaxpr.jaxpr.eqns
+             if e.params.get("name") == "_ssm_call"]
+    assert len(sites) == 3
+    assert len({id(e.params["jaxpr"]) for e in sites}) == 1
 
 
 def test_ssm_update_refuses_what_it_cannot_tile():
@@ -544,6 +623,34 @@ def test_engine_streams_are_generates(tiny, streams, slots):
     live = sum(LENGTHS) + len(prompts) * 9       # the last token is not fed
     assert (totals.sum(1) == live * 2).all()
     assert eng.metrics.snapshot()["moe_assignments"] == totals.sum()
+
+
+def test_rows_are_counted_by_the_body_that_advances_them(tiny, streams):
+    """The recurrence's kernel advances a row of `MATRIX_COLUMNS` live
+    columns or more in matrix form and walks a shorter one column by
+    column; the host knows each row's `adv` when it builds the step, so it
+    counts them: a prompt's whole chunks and its longer tails on one side,
+    its short tails and every decode row on the other, together the rows
+    the steps advanced."""
+    prompts, want = streams
+    eng = _engine(tiny, 3)
+    handles = [eng.submit(p, max_new_tokens=10) for p in prompts]
+    _drain(eng)
+    assert all(np.array_equal(np.asarray(h.result(timeout=5)), w)
+               for h, w in zip(handles, want))
+    m = ssm.MATRIX_COLUMNS
+    assert eng._matrix_columns == m == tiny.model.layers[0].mamba \
+        .matrix_columns
+    tails = [n % 16 for n in LENGTHS]
+    matrix = sum(n // 16 for n in LENGTHS) + sum(t >= m for t in tails)
+    loop = sum(0 < t < m for t in tails) + 9 * len(prompts)
+    snap = eng.metrics.snapshot()
+    assert (snap["recurrent_rows_matrix"], snap["recurrent_rows_loop"]) \
+        == (matrix, loop) == (12, 73)
+    assert snap["rows_discarded"] == 0
+    text = eng.metrics.render()
+    assert f"pdtpu_llm_recurrent_rows_matrix_total {matrix}" in text
+    assert f"pdtpu_llm_recurrent_rows_loop_total {loop}" in text
 
 
 def test_a_zeroed_state_changes_the_streams(tiny, streams):

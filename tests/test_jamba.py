@@ -309,6 +309,9 @@ def test_engine_streams_are_the_references(tiny, streams, slots):
                                    rtol=1e-4, atol=1e-4)
     snap = eng.metrics.snapshot()
     assert snap["recurrent_rows_started"] == len(prompts)
+    # Mamba-1's kernel walks every row a column at a time: no row is
+    # counted as advanced in matrix form
+    assert snap["recurrent_rows_matrix"] == 0 < snap["recurrent_rows_loop"]
     # each array at its own width: the conv's columns in the model's type
     # (float32 here), the state in float32
     assert snap["recurrent_state_bytes"] == eng.pool.recurrent_state_bytes \

@@ -34,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 64 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 66 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 15         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -55,6 +55,27 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     for rows in (320, 20):
         assert any(f"'heads': 1, 'pages': 8, 'rows': {rows}" in t
                    for t in tilings), rows
+
+
+def test_the_mamba2_recurrence_compiles_with_both_bodies(tool):
+    """`ssm_update` for the v5e at granite-4.0-h-small's widths (a bf16
+    state of 128 x 8,192 a row, 2,048 lanes a grid step): the prefill and
+    the decode cell's steps (32 and 128 rows of 16 columns) hold the column
+    loop and the matrix body, each behind its `pl.when`, in one kernel; a
+    call of 64 columns the matrix body over eight tiles of columns; a
+    one-column call the loop alone."""
+    from paddle_tpu.ops.ssm import MATRIX_COLUMNS
+    cases = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] ssm_update bf16")]
+    assert len(cases) == 5 and all("{'ssm_update': 1}" in c for c in cases)
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling ssm_update")]
+    for columns, grid, matrix_from in (
+            (16, "(32, 4)", MATRIX_COLUMNS), (16, "(128, 4)", MATRIX_COLUMNS),
+            (64, "(2, 4)", MATRIX_COLUMNS), (1, "(2, 4)", 0)):
+        assert any(f"'columns': {columns}, 'grid': {grid}, 'matrix_from': "
+                   f"{matrix_from}, 'state_tile': (128, 2048)" in t
+                   for t in tilings), (columns, grid, tilings)
 
 
 def test_the_mamba1_recurrence_compiles_at_the_reasoning_cells_shapes(tool):
@@ -157,7 +178,8 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     assert "{'kv_write': 3, 'paged_latent': 3, 'moe_gmm': 6} in the " \
         "compiled one" in latent
     assert "'ssm_update': 2" in hybrid and "'paged_attention': 1" in hybrid
-    assert "'kv_write': 1" in hybrid and ": 13 Mosaic bodies " in hybrid
+    # two state-space layers share one `ssm_update` body since PR 46
+    assert "'kv_write': 1" in hybrid and ": 12 Mosaic bodies " in hybrid
     # a walk and a write: one body each for three layers
     assert "3 full layers: 2 Mosaic bodies " in full
     assert "{'kv_write': 3, 'paged_attention': 3} in the compiled one" in full

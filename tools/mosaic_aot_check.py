@@ -483,10 +483,12 @@ def main() -> int:
             spec((e,), jnp.int32), want={"moe_gmm": 1}))
 
     # the Mamba-2 recurrence at granite-4.0-h-small's widths: the decode
-    # cell's step (128 slots x 16 columns), one-shot generate()'s decode
-    # step and a whole prompt walked in chunks
+    # and the prefill cell's steps (128 and 32 slots x 16 columns: the loop
+    # and the matrix body behind one `pl.when` each), one-shot generate()'s
+    # decode step (the loop alone), a call of `MAX_COLUMNS` (the matrix
+    # body's eight tiles of columns) and a whole prompt walked in chunks
     from paddle_tpu.ops.ssm import ssm_update
-    for rows, T in ((128, 16), (2, 1), (2, 128)):
+    for rows, T in ((128, 16), (32, 16), (2, 1), (2, 64), (2, 128)):
         results.append(compile_case(
             f"ssm_update bf16 rows={rows} T={T} state=[128,8192]",
             ssm_update,
@@ -567,10 +569,10 @@ def main() -> int:
         max_position_embeddings=1024, dtype="bfloat16"))
     model.eval()
     results.append(serve_step_case("serve step, 2 recurrent layers + 1 full",
-                                   model, dev1[0], 13))
+                                   model, dev1[0], 12))
     # Mamba-1 layers round a multi-query layer: the three recurrent layers
-    # share one `selective_scan` body (its entry is jitted, where
-    # `ssm_update`'s is a body a layer), and the float32 state is aliased
+    # share one `selective_scan` body (its entry is jitted, as
+    # `ssm_update`'s is since PR 46), and the float32 state is aliased
     # beside the bfloat16 conv columns and pages (8 slots x 16 columns are
     # not wider than a packed step: the unpacked form, without `conv_tokens`)
     from paddle_tpu.models.jamba import JambaConfig, JambaForCausalLM
