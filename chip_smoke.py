@@ -79,6 +79,9 @@ FULL = dict(
                 index_dim=128, topk=2048, pages=2304),
     # granite-4.0-h-small's Mamba-2 state: 128 heads x 64, 128 channels
     ssm=dict(heads=128, head_dim=64, state=128),
+    # the hyper-connected cell's residual path: 4 streams of 3,584 over a
+    # packed step's 512 positions
+    hyper=dict(streams=4, hidden=3584, rows=512),
 )
 TINY = dict(
     preset="gpt2-tiny", batch_per_chip=2, seq=128, scan_steps=2,
@@ -92,6 +95,7 @@ TINY = dict(
     sparse=dict(heads=4, latent=32, rope_cols=8, index_heads=2,
                 index_dim=128, topk=32, pages=20),
     ssm=dict(heads=4, head_dim=64, state=16),
+    hyper=dict(streams=4, hidden=128, rows=40),
 )
 
 
@@ -815,6 +819,83 @@ def _ssm_parity(size: dict):
             f"{ms:.3f} ms a call {name}" for name, ms in took.items()))
 
 
+def _hyper_connection_parity(size: dict):
+    """The two hyper-connection kernels (`hc_pre`, `hc_post`) against their
+    plain `jax.numpy` forms on this device at `size["hyper"]`: bf16 streams
+    under float32 coefficients (`phi` N(0, 0.02), gains U(0.5, 1.5), a bias
+    N(0, 1): the benchmark's seeding), 20 Sinkhorn passes. The coefficients
+    are float32 on both sides and differ by the order of the sums in
+    `x phi` (1e-4 of a coefficient at the most); `u` and `X'` are rounded to
+    bf16 once on each side: 2e-2 of the largest value. On a chip each
+    kernel is also timed, ten calls in one program, beside the least time
+    for its bytes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import hyper_connection as hc
+    from paddle_tpu.ops import pallas_mode
+    g = size["hyper"]
+    n, C, R = g["streams"], g["hidden"], g["rows"]
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(R, n * C), jnp.bfloat16)
+    y = jnp.asarray(rng.randn(R, C), jnp.bfloat16)
+    phi = jnp.asarray(0.02 * rng.randn(n * C, n * n + 2 * n), jnp.float32)
+    bias = jnp.asarray(rng.randn(n * n + 2 * n), jnp.float32)
+    alpha = jnp.asarray(rng.uniform(0.5, 1.5, 3), jnp.float32)
+    kw = dict(n=n, iters=20, eps=1e-6, clamp=30.0, norm_eps=1e-6)
+    pallas_mode.KERNEL_TILINGS.clear()
+    u, post, res = hc.pre_kernel(x, phi, bias, alpha, **kw)
+    u0, post0, res0 = hc._pre_plain(x, phi, bias, alpha, **kw)
+    out, out0 = hc.post_kernel(x, y, post0, res0), \
+        hc._post_plain(x, y, post0, res0)
+    tilings = {k: dict(t) for k, t in pallas_mode.KERNEL_TILINGS}
+    err_c = max(_max_err(post, post0), _max_err(res, res0))
+    most_u = max(float(jnp.abs(u0.astype(jnp.float32)).max()), 1.0)
+    most_x = max(float(jnp.abs(out0.astype(jnp.float32)).max()), 1.0)
+    err_u, err_x = _max_err(u, u0), _max_err(out, out0)
+    sums = max(float(jnp.abs(res.sum(1) - 1).max()),
+               float(jnp.abs(res.sum(2) - 1).max()))
+    _say(f"hc_pre / hc_post pallas vs jax.numpy rows={R} streams={n} x {C} "
+         f"bf16: grid {tilings[hc.PRE_KERNEL]['grid']}, tile "
+         f"{tilings[hc.PRE_KERNEL]['tile']}; max abs err coefficients "
+         f"{err_c:.2e} (tolerance 1e-4), u {err_u:.2e} of {most_u:.1f}, X' "
+         f"{err_x:.2e} of {most_x:.1f} (tolerance 2e-2 of the largest); "
+         f"H_res rows and columns sum to 1 within {sums:.1e}")
+    _require(np.isfinite(err_c + err_u + err_x) and err_c <= 1e-4
+             and max(err_u / most_u, err_x / most_x) <= 2e-2,
+             "hc_pre / hc_post within tolerance")
+    if pallas_mode.platform() == "cpu":
+        return              # a time is the chip's to give
+
+    # each pass depends on the one before, or XLA would run the call once
+    @jax.jit
+    def ten_pre(x):
+        return jax.lax.fori_loop(0, 10, lambda _, u: hc.pre_kernel(
+            x, phi, bias, alpha + u[0, 0].astype(jnp.float32) * 0, **kw)[0],
+            jnp.zeros((R, C), x.dtype))
+
+    @jax.jit
+    def ten_post(x):
+        return jax.lax.fori_loop(0, 10, lambda _, s: hc.post_kernel(
+            s, y, post0, res0), x)
+
+    took = {}
+    for name, fn in (("hc_pre", ten_pre), ("hc_post", ten_post)):
+        jax.block_until_ready(fn(x))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            last = fn(x)
+        jax.block_until_ready(last)
+        took[name] = (time.perf_counter() - t0) / 50 * 1e6
+    item = x.dtype.itemsize
+    least = {"hc_pre": R * (n * C + C) * item / 819e9 * 1e6,
+             "hc_post": R * (2 * n * C + C) * item / 819e9 * 1e6}
+    _say("hc kernels a call alone: " + ", ".join(
+        f"{name} {us:.1f} us (its bytes at 819 GB/s: {least[name]:.1f} us)"
+        for name, us in took.items()))
+
+
 def _post(port: int, path: str, payload: dict, timeout: float):
     import urllib.request
     req = urllib.request.Request(
@@ -839,6 +920,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
     _sparse_parity(size)
     _kv_write_parity(size)
     _ssm_parity(size)
+    _hyper_connection_parity(size)
 
     import paddle_tpu as paddle
     from paddle_tpu import serving

@@ -1,9 +1,11 @@
 """The DeepSeek-V3 family of decoders (DeepSeek-V3, and models built on its
 block such as SK Telecom's A.X-K1, `model_type` "axk1", and, with
 DeepSeek-V3.2's learned sparse attention on top, Z.ai's GLM-5.2,
-`model_type` "glm_moe_dsa"): multi-head latent attention, a router with
-sigmoid scores and a group-limited choice beside a shared expert, dense
-SwiGLU layers before the sparse ones.
+`model_type` "glm_moe_dsa", and, with a hyper-connected residual path of
+several streams round the block, XingChen-AGI's Xing4.0, `model_type`
+"xing4_0"): multi-head latent attention, a router with sigmoid scores and a
+group-limited choice beside a shared expert, dense SwiGLU layers before the
+sparse ones.
 
 A file of its own and not `models/llama.py` grown: that file's attention is
 "q, k, v of one head width and a `(k, v)` cache", which every other decoder
@@ -82,6 +84,33 @@ the rest `DroplessMoE` (`nn.layer.moe.route`: `scoring_func`, `n_group` /
 selection bias) plus `n_shared_experts` shared experts as one SwiGLU of
 `n_shared_experts * moe_intermediate_size`, weight 1. `experts_held` as
 `LlamaConfig`'s. Serving only: no load-balancing loss is wired.
+
+The residual path (`hc_mult`; 1: the two adds above). With n = `hc_mult` > 1
+a token's residual state is n streams, `X [n, C]`, carried from layer to
+layer side by side as `[..., n C]` (stream j the columns j C .. (j + 1) C).
+The embedding's row is copied into the n streams and the final RMSNorm
+reads their sum. Each sublayer F (attention behind `input_layernorm`, the
+FFN behind `post_attention_layernorm`; F itself is unchanged) stands inside
+one manifold-constrained hyper-connection (`nn.layer.hyper_connection`,
+`ops.hyper_connection`: `phi [n C, n^2 + 2 n]`, `bias`, three gains
+`alpha`, all float32 whatever the model's type):
+
+    x'  = vec(X) / sqrt(mean(vec(X)^2) + rms_norm_eps)       [n C], float32
+    [h_pre | h_post | h_res] = x' phi, split n | n | n^2
+    H_pre  = sigmoid(alpha_pre h_pre + b_pre)                [n]
+    H_post = 2 sigmoid(alpha_post h_post + b_post)           [n]
+    M^0    = exp(clip(alpha_res mat(h_res) + b_res, -hc_res_clamp,
+                      hc_res_clamp))                         [n, n]
+    M^t    = T_r(T_c(M^(t-1))), t = 1..hc_sinkhorn_iters; T_c: each column
+             over (its sum + hc_eps), T_r: each row likewise; H_res = M^last
+    u      = sum_j H_pre[j] X[j]               F reads norm(u)      [C]
+    X'[i]  = sum_j H_res[i, j] X[j] + H_post[i] F(norm(u))
+
+so a sublayer reads a learned, input-dependent mixture of the streams and
+writes back through a doubly stochastic matrix made per token. Nothing a
+slot keeps changes: the cache kinds, `init_cache`, `forward_with_cache` and
+`generate()` are the one-stream model's. A one-stream configuration builds
+no connection and imports none of it (`DeepseekDecoderLayer.__init__`).
 """
 from __future__ import annotations
 
@@ -180,10 +209,18 @@ class DeepseekConfig:
     # (first, count): every expert layer holds that share of
     # `n_routed_experts` (`DroplessMoE(held=)`)
     experts_held: Optional[Tuple[int, int]] = None
+    # residual streams a token (1: `x += F(norm(x))`); the Sinkhorn passes,
+    # the epsilon in their denominators and the clamp before their `exp`
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
 
     def __post_init__(self):
         if self.tie_word_embeddings:
             raise NotImplementedError("a tied head is not wired")
+        if self.hc_mult < 1:
+            raise ValueError(f"hc_mult {self.hc_mult}: residual streams")
         if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
             raise ValueError(
                 f"first_k_dense_replace {self.first_k_dense_replace} of "
@@ -461,26 +498,51 @@ class DeepseekDecoderLayer(Layer):
                                        config.rms_norm_eps)
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
                                                 config.rms_norm_eps)
+        self.attn_hc = self.mlp_hc = None
+        if config.hc_mult > 1:
+            # here and nowhere else: a one-stream model loads no line of it
+            from ..nn.layer.hyper_connection import HyperConnection
+            self.attn_hc, self.mlp_hc = (HyperConnection(
+                config.hidden_size, config.hc_mult,
+                config.hc_sinkhorn_iters, config.hc_eps,
+                config.hc_res_clamp, config.rms_norm_eps) for _ in "am")
 
     def forward(self, hidden, cache=None, pos=None, paged=None, live=None,
                 pack=None, sel=None):
         """`sel`: the selection of the "full" layer above (None above the
         first, and in a model without indexers). Returns `(hidden,
-        new_cache or None, the selection this layer used)`."""
-        h = self.self_attn(self.input_layernorm(hidden), cache=cache,
-                           pos=pos, paged=paged, pack=pack, sel=sel)
-        new_cache = None
-        if self.self_attn.kind is not None:
-            h, sel = h[:-1], h[-1]
-            h = h[0] if cache is None else h
-        if cache is not None:
-            h, new_cache = h
-        hidden = hidden + h
-        h = self.post_attention_layernorm(hidden)
-        # a router must not send padding to experts: it is told what is live
-        hidden = hidden + (self.mlp(h, live=live) if self.sparse
-                           else self.mlp(h))
-        return hidden, new_cache, sel
+        new_cache or None, the selection this layer used)`. With
+        `hc_mult` streams `hidden` is `[..., hc_mult * hidden_size]`."""
+        def attend(x):
+            nonlocal sel
+            h = self.self_attn(self.input_layernorm(x), cache=cache,
+                               pos=pos, paged=paged, pack=pack, sel=sel)
+            new_cache = None
+            if self.self_attn.kind is not None:
+                h, sel = h[:-1], h[-1]
+                h = h[0] if cache is None else h
+            if cache is not None:
+                h, new_cache = h
+            return h, new_cache
+
+        def ffn(x):
+            h = self.post_attention_layernorm(x)
+            # a router must not send padding to experts: it is told what is
+            # live
+            return self.mlp(h, live=live) if self.sparse else self.mlp(h)
+
+        if self.attn_hc is None:
+            h, new_cache = attend(hidden)
+            hidden = hidden + h
+            return hidden + ffn(hidden), new_cache, sel
+        # a hyper-connection in each residual add's place: a sublayer reads
+        # `pre`'s mixture of the streams, `post` writes its result back into
+        # every stream
+        u, carry = self.attn_hc.pre(hidden)
+        h, new_cache = attend(u)
+        hidden = self.attn_hc.post(hidden, h, carry)
+        u, carry = self.mlp_hc.pre(hidden)
+        return self.mlp_hc.post(hidden, ffn(u), carry), new_cache, sel
 
 
 class DeepseekModel(Layer):
@@ -493,14 +555,32 @@ class DeepseekModel(Layer):
                                  for i in range(config.num_hidden_layers)])
         self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
 
+    def _streams_in(self, hidden):
+        """The embedding's row copied into every residual stream."""
+        n = self.config.hc_mult
+        return hidden if n == 1 else apply(
+            lambda a: jnp.concatenate([a] * n, -1), hidden)
+
+    def _streams_out(self, hidden):
+        """The residual streams' sum (float32, then the model's type)."""
+        n = self.config.hc_mult
+        if n == 1:
+            return hidden
+
+        def total(a):
+            parts = jnp.split(a.astype(jnp.float32), n, -1)
+            return sum(parts[1:], parts[0]).astype(a.dtype)
+
+        return apply(total, hidden)
+
     def forward(self, input_ids, caches=None, pos=None, paged=None,
                 pack=None):
-        hidden = self.embed_tokens(input_ids)
+        hidden = self._streams_in(self.embed_tokens(input_ids))
         sel = None
         if caches is None:
             for layer in self.layers:
                 hidden, _, sel = layer(hidden, sel=sel)
-            return self.norm(hidden)
+            return self.norm(self._streams_out(hidden))
         live = None
         if pack is not None:
             live = pack.live[:, None]
@@ -512,7 +592,7 @@ class DeepseekModel(Layer):
                                            paged=paged, live=live,
                                            pack=pack, sel=sel)
             new_caches.append(new_cache)
-        return self.norm(hidden), new_caches
+        return self.norm(self._streams_out(hidden)), new_caches
 
 
 class DeepseekForCausalLM(Layer):

@@ -102,6 +102,7 @@ def test_kv_write_brings_a_rings_overrun_round(name, T, dtype, pos):
     ("mellum full layers", 32, 4, 8304, 128, 128, None, 8),
     ("mellum window layers", 32, 4, 1056, 128, 128, 1040, 8),
     ("a.x-k1 latent pair", 32, 1, 8304, 512, 128, None, 8),
+    ("xing4.0 latent pair", 256, 1, 2576, 512, 128, None, 8),
     ("a batch of one", 1, 8, 240, 128, 128, None, 1),
     ("rows that no 8 divides", 14, 8, 240, 128, 128, None, 7),
     ("a prime batch", 13, 8, 240, 128, 128, None, 1),

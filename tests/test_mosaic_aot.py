@@ -34,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 66 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 73 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 15         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -163,8 +163,14 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     Mosaic body, that of an engine with window and full layers two, and
     the compiled step still a custom call a layer."""
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
-    assert len(steps) == 6
-    full, mixed, hybrid, _, latent, indexed = steps
+    assert len(steps) == 7
+    full, mixed, hybrid, _, latent, indexed, streams = steps
+    # four residual streams (PR 47): two layers' four connections share one
+    # `hc_pre` and one `hc_post` body; the walk, the write and the sparse
+    # layer's three grouped matmuls as in the latent engine
+    assert "in 4 hyper-connected streams: 7 Mosaic bodies " in streams
+    assert "{'hc_pre': 4, 'kv_write': 2, 'paged_latent': 2, 'hc_post': 4, " \
+        "'moe_gmm': 3} in the compiled one" in streams
     # an indexer's layers (PR 39): the three-slab write and the two-slab
     # one, the gathered and the masked walk, one scoring and one top-k for
     # both "full" layers, three sparse layers' grouped matmuls
@@ -226,13 +232,38 @@ def test_the_latent_walk_compiles_at_the_latent_cells_shapes(tool):
     of 512 rows inside the VMEM budget; a one-token row takes one tile."""
     cases = [ln for ln in tool.splitlines()
              if ln.startswith("[OK] paged latent bf16")]
-    assert len(cases) == 2 and all("{'paged_latent': 1}" in c for c in cases)
-    assert all("slab=[32, 1, 8304, 512 | 128]" in c for c in cases)
+    assert len(cases) == 3 and all("{'paged_latent': 1}" in c for c in cases)
+    assert sum("slab=[32, 1, 8304, 512 | 128]" in c for c in cases) == 2
+    # the hyper-connected cell's decode-heavy walk (PR 47): 256 slots of 160
+    # pages, 32 heads x 16 columns in one tile of 512 rows
+    assert sum("slab=[256, 1, 2576, 512 | 128]" in c for c in cases) == 1
     tilings = [ln for ln in tool.splitlines()
                if ln.startswith("tiling paged_latent")]
-    for grid, rows in (("(32, 2)", 512), ("(32, 1)", 64)):
-        assert any(f"'grid': {grid}, 'groups': 65, 'heads': 1, 'pages': 8, "
-                   f"'rows': {rows}" in t for t in tilings), (grid, tilings)
+    for grid, groups, rows in (("(32, 2)", 65, 512), ("(32, 1)", 65, 64),
+                               ("(256, 1)", 20, 512)):
+        assert any(f"'grid': {grid}, 'groups': {groups}, 'heads': 1, "
+                   f"'pages': 8, 'rows': {rows}" in t
+                   for t in tilings), (grid, tilings)
+
+
+def test_the_hyper_connection_kernels_compile_at_the_cells_shapes(tool):
+    """`hc_pre` and `hc_post` for the v5e (PR 47) at a packed step's 512
+    positions of 4 streams x 3,584 in bf16 with the parameters as the
+    benchmark holds them, and at an unpacked step's 48 rows: a grid step
+    holds 128 rows of the streams whole (the coefficients' tokens lie along
+    the lanes), so 512 rows are four grid steps and 48 one."""
+    for kernel in ("hc_pre", "hc_post"):
+        cases = [ln for ln in tool.splitlines()
+                 if ln.startswith(f"[OK] {kernel} bf16 rows=")]
+        assert len(cases) == 2 and all(f"{{'{kernel}': 1}}" in c
+                                       for c in cases)
+        tilings = [ln for ln in tool.splitlines()
+                   if ln.startswith(f"tiling {kernel} ")]
+        for grid in ("(4,)", "(1,)"):
+            assert any(f"'grid': {grid}" in t and "'tile': (128, 14336)" in t
+                       for t in tilings), (kernel, grid, tilings)
+    assert any("'coefficients': 24" in t and "'passes': 20" in t
+               for t in tool.splitlines() if t.startswith("tiling hc_pre"))
 
 
 def test_sparse_attention_compiles_at_the_sessions_cells_shapes(tool):
@@ -274,7 +305,7 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
     row is a read-modify-write of the 32 aligned columns that hold it."""
     cases = [ln for ln in tool.splitlines()
              if ln.startswith("[OK] kv_write bf16")]
-    assert len(cases) == 11
+    assert len(cases) == 12
     # a sparse layer's three slabs (latent, rotary key, index key) in the
     # one call, at the sessions cell's 16 rows of 36,880 columns
     assert any("slab=[16, 1, 36880] x 512 | 128 | 128" in ln for ln in cases)
@@ -286,6 +317,7 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
                  "[128, 16, 240] x 128 | 128", "[32, 4, 8304] x 128 | 128",
                  "[32, 4, 1056] x 128 | 128 T=16 ring=1040",
                  "[32, 1, 8304] x 512 | 128", "[256, 1, 2576] x 128 | 128",
+                 "[256, 1, 2576] x 512 | 128",
                  "[1, 8, 2064] x 128 | 128"):
         assert any(f"slab={slab}" in ln for ln in cases), slab
     tilings = [ln for ln in tool.splitlines()

@@ -398,6 +398,38 @@ def main() -> int:
             spec((32, 1, 8304, 128), jnp.bfloat16),
             spec((32, 518), jnp.int32), spec((32,), jnp.int32),
             spec((32,), jnp.int32), want={"paged_latent": 1}))
+    # the hyper-connected cell's walk: 256 slots of 160 pages, 32 query
+    # heads, the decode-heavy step's 16-wide rows
+    results.append(compile_case(
+        "paged latent bf16 decode-heavy rows q=[256, 32, 16, 512 | 128] "
+        "slab=[256, 1, 2576, 512 | 128]",
+        lambda q, qr, c, r, t, sl, qp: ragged_paged_attention(
+            q, c, r, t, sl, qp, block_len=16, pages_per_row=160,
+            scale=0.1544, impl="pallas", q_rope=qr),
+        spec((256, 32, 16, 512), jnp.bfloat16),
+        spec((256, 32, 16, 128), jnp.bfloat16),
+        spec((256, 1, 2576, 512), jnp.bfloat16),
+        spec((256, 1, 2576, 128), jnp.bfloat16),
+        spec((256, 160), jnp.int32), spec((256,), jnp.int32),
+        spec((256,), jnp.int32), want={"paged_latent": 1}))
+    # the two halves of a hyper-connection (`hc_pre`, `hc_post`) at the
+    # hyper-connected cell's shapes: a packed step's 512 positions of 4
+    # streams x 3,584 in bf16, the parameters as the benchmark holds them;
+    # and an unpacked step's 48 rows (brought up to one grid step of 128)
+    from paddle_tpu.ops import hyper_connection as HC
+    for rows in (512, 48):
+        results.append(compile_case(
+            f"hc_pre bf16 rows={rows} streams=[4, 3584] phi=[14336, 24]",
+            lambda x, phi, b, a: HC.hc_pre(x, phi, b, a, n=4),
+            spec((rows, 1, 4 * 3584), jnp.bfloat16),
+            spec((4 * 3584, 24), jnp.bfloat16), spec((24,), jnp.bfloat16),
+            spec((3,), jnp.bfloat16), want={"hc_pre": 1}))
+        results.append(compile_case(
+            f"hc_post bf16 rows={rows} streams=[4, 3584]", HC.hc_post,
+            spec((rows, 1, 4 * 3584), jnp.bfloat16),
+            spec((rows, 1, 3584), jnp.bfloat16),
+            spec((rows, 1, 4), jnp.float32),
+            spec((rows, 1, 4, 4), jnp.float32), want={"hc_post": 1}))
     # learned sparse attention at the sessions cell's shapes: 16 slots of
     # 2,304 pages, an indexer of 32 x 128 over index-key pages
     # (`index_score`), the exact top-2,048 of a row's 16 score vectors as a
@@ -455,6 +487,7 @@ def main() -> int:
              None),
             ("granite decode cell", (128, 8, 240), (D, D), None),
             ("reasoning cell", (256, 1, 2576), (D, D), None),
+            ("hyper-connected cell", (256, 1, 2576), (512, D), None),
             ("a batch of one", (1, 8, 2064), (D, D), None)):
         results.append(kv_write_case(label, slab, widths, 16, ring, one))
     body = kernel_equations(
@@ -619,6 +652,22 @@ def main() -> int:
     results.append(serve_step_case(
         "serve step, 2 indexed + 2 shared latent layers", model, dev1[0],
         15))
+    # four residual streams round two latent layers: the four connections
+    # share one `hc_pre` and one `hc_post` body (their entries are jitted),
+    # beside one `kv_write`, one `paged_latent` and the sparse layer's
+    # three grouped matmuls; no copy of the streams round a call
+    model = DeepseekForCausalLM(DeepseekConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=256,
+        moe_intermediate_size=128, num_hidden_layers=2,
+        num_attention_heads=2, q_lora_rank=128, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=8, num_experts_per_tok=2, n_group=1, topk_group=1,
+        select_bias=True, first_k_dense_replace=1,
+        max_position_embeddings=1024, dtype="bfloat16", hc_mult=4))
+    model.eval()
+    results.append(serve_step_case(
+        "serve step, 2 latent layers in 4 hyper-connected streams", model,
+        dev1[0], 7))
     from paddle_tpu.ops import pallas_mode
     for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
         print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
