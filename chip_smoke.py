@@ -205,6 +205,32 @@ def _max_err(a, b) -> float:
                                  - b.astype(jnp.float32))))
 
 
+# a paged walk's steps, (query width, mixed): one-token rows, a step whose
+# rows hold sixteen live columns each, and a mixed step (PR 48): every
+# other row has one live column of the sixteen and takes the kernel's
+# one-column body where its trace holds one, beside chunk rows in the wide
+PAGED_STEPS = ((1, False), (16, False), (16, True))
+
+
+def _live_columns(N: int, Tq: int, mixed: bool):
+    """adv [N]: each row's live columns in a step of `PAGED_STEPS`."""
+    import numpy as np
+    return np.where(np.arange(N) % 2 == 0, 1, Tq).astype(np.int32) \
+        if mixed else np.full(N, Tq, np.int32)
+
+
+def _live_err(outs: dict, adv) -> float:
+    """`_max_err` of the kernel's and the scan's results over each row's
+    `adv` live columns (a one-column row's dead columns are zeros in the
+    kernel and a key-less query's values in the scan)."""
+    import jax.numpy as jnp
+    Tq = outs["scan"].shape[2]
+    live = (jnp.arange(Tq)[None, :] < jnp.asarray(adv)[:, None])[
+        :, None, :, None]
+    return _max_err(*(jnp.where(live, outs[impl].astype(jnp.float32), 0.0)
+                      for impl in ("pallas", "scan")))
+
+
 # --------------------------------------------------------------------------
 # train leg
 # --------------------------------------------------------------------------
@@ -492,24 +518,27 @@ def _paged_parity(size: dict):
         # each row's pages live in another row's slab: a real indirection
         table = ((np.arange(N)[:, None] + 1) % N * nb
                  + np.arange(nb)[None, :]).astype(np.int32)
-        for Tq in (1, 16):
+        for Tq, mixed in PAGED_STEPS:
             q = jnp.asarray(rng.randn(N, H, Tq, D), jnp.bfloat16)
             q_pos = np.array([0, L // 7, L // 2 + 3, L - Tq, 1, bl - 1, bl,
                               L // 3], np.int32)
-            lens = q_pos + Tq
+            adv = _live_columns(N, Tq, mixed)
+            lens = q_pos + adv
             pallas_mode.KERNEL_TILINGS.clear()
             outs = {impl: ragged_paged_attention(
                 q, k, v, table, lens, q_pos, block_len=bl, pages_per_row=nb,
                 impl=impl) for impl in ("pallas", "scan")}
             (_, tiling), = pallas_mode.KERNEL_TILINGS
             tiling = dict(tiling)
-            err = _max_err(outs["pallas"], outs["scan"])
+            err = _live_err(outs, adv)
             _say(f"paged pallas vs scan H={H} Hkv={Hkv} D={D} block_len={bl} "
-                 f"Tq={Tq} seq_lens={lens.tolist()} bf16: grid "
+                 f"Tq={Tq} seq_lens={lens.tolist()} live columns "
+                 f"{adv.tolist()} bf16: grid "
                  f"{tiling['grid']} (G={tiling['grid'][1]}), up to "
                  f"{tiling['groups']} groups of pages={tiling['pages']} a "
                  f"row, tile {tiling['heads']} KV heads x {tiling['rows']} "
-                 f"rows; max abs err {err:.2e} (tolerance {tol:g})")
+                 f"rows, one-column body {tiling['one_column_rows']} rows; "
+                 f"max abs err {err:.2e} (tolerance {tol:g})")
             _require(np.isfinite(err) and err <= tol,
                      f"paged H={H}/{Hkv} Tq={Tq} within {tol:g}")
 
@@ -535,24 +564,27 @@ def _paged_window_parity(size: dict):
     rng = np.random.RandomState(2)
     k = jnp.asarray(rng.randn(N, Hkv, ring + bl, D), jnp.bfloat16)
     v = jnp.asarray(rng.randn(N, Hkv, ring + bl, D), jnp.bfloat16)
-    for Tq in (1, 16):
+    for Tq, mixed in PAGED_STEPS:
         q = jnp.asarray(rng.randn(N, H, Tq, D), jnp.bfloat16)
-        lens = np.maximum(Tq, np.array(
+        adv = _live_columns(N, Tq, mixed)
+        lens = np.maximum(adv, np.array(
             [1, W // 3, W - 1, W + bl + 1, ring, 2 * ring + 5,
              5 * ring + bl - 1, 8 * ring - 3], np.int32))
-        q_pos = (lens - Tq).astype(np.int32)
+        q_pos = (lens - adv).astype(np.int32)
         pallas_mode.KERNEL_TILINGS.clear()
         outs = {impl: ragged_paged_attention(
             q, k, v, None, lens, q_pos, block_len=bl, pages_per_row=pages,
             impl=impl, window=W) for impl in ("pallas", "scan")}
         ((kernel, tiling),) = pallas_mode.KERNEL_TILINGS
         tiling = dict(tiling)
-        err = _max_err(outs["pallas"], outs["scan"])
+        err = _live_err(outs, adv)
         _say(f"{kernel} pallas vs scan H={H} Hkv={Hkv} D={D} window={W} "
-             f"ring={pages} pages Tq={Tq} seq_lens={lens.tolist()} bf16: "
+             f"ring={pages} pages Tq={Tq} seq_lens={lens.tolist()} live "
+             f"columns {adv.tolist()} bf16: "
              f"grid {tiling['grid']}, up to {tiling['groups']} groups of "
              f"pages={tiling['pages']} a row, tile {tiling['heads']} KV "
-             f"heads x {tiling['rows']} rows; max abs err {err:.2e} "
+             f"heads x {tiling['rows']} rows, one-column body "
+             f"{tiling['one_column_rows']} rows; max abs err {err:.2e} "
              f"(tolerance {tol:g})")
         _require(kernel == WINDOW_KERNEL and np.isfinite(err) and err <= tol,
                  f"{WINDOW_KERNEL} H={H}/{Hkv} Tq={Tq} within {tol:g}")
@@ -582,24 +614,27 @@ def _paged_latent_parity(size: dict):
     r = jnp.pad(jnp.asarray(rng.randn(N, 1, L + bl, Dr), jnp.bfloat16), pad)
     table = np.arange(N * pages, dtype=np.int32).reshape(N, pages)
     scale = (R + Dr) ** -0.5
-    for Tq in (1, 16):
+    for Tq, mixed in PAGED_STEPS:
         q = jnp.asarray(rng.randn(N, H, Tq, R), jnp.bfloat16)
         qr = jnp.pad(jnp.asarray(rng.randn(N, H, Tq, Dr), jnp.bfloat16), pad)
-        lens = np.maximum(Tq, np.array(
+        adv = _live_columns(N, Tq, mixed)
+        lens = np.maximum(adv, np.array(
             [1, bl - 1, 127, 129, L // 7, L // 3, L - bl - 1, L],
             np.int32))
-        q_pos = (lens - Tq).astype(np.int32)
+        q_pos = (lens - adv).astype(np.int32)
         pallas_mode.KERNEL_TILINGS.clear()
         outs = {impl: ragged_paged_attention(
             q, c, r, table, lens, q_pos, block_len=bl, pages_per_row=pages,
             scale=scale, impl=impl, q_rope=qr) for impl in ("pallas", "scan")}
         ((kernel, tiling),) = pallas_mode.KERNEL_TILINGS
         tiling = dict(tiling)
-        err = _max_err(outs["pallas"], outs["scan"])
+        err = _live_err(outs, adv)
         _say(f"{kernel} pallas vs scan H={H} latent={R} rope={Dr} (in "
-             f"{cols} columns) Tq={Tq} seq_lens={lens.tolist()} bf16: grid "
+             f"{cols} columns) Tq={Tq} seq_lens={lens.tolist()} live "
+             f"columns {adv.tolist()} bf16: grid "
              f"{tiling['grid']}, up to {tiling['groups']} groups of "
-             f"pages={tiling['pages']} a row, tile {tiling['rows']} rows; "
+             f"pages={tiling['pages']} a row, tile {tiling['rows']} rows, "
+             f"one-column body {tiling['one_column_rows']} rows; "
              f"max abs err {err:.2e} (tolerance {tol:g})")
         _require(kernel == LATENT_KERNEL and np.isfinite(err) and err <= tol,
                  f"{LATENT_KERNEL} H={H} Tq={Tq} within {tol:g}")

@@ -73,12 +73,35 @@ sequence:
   keys: the group is a constant of the kernel, so the accumulation
   grouping follows from nothing a caller chooses, `block_len` included.
   `pallas_mode.KERNEL_TILINGS` records the choice of each trace (`grid`,
-  `groups` = the most a row's loop takes, `pages`, `heads`, `rows`).
+  `groups` = the most a row's loop takes, `pages`, `heads`, `rows`,
+  `one_column_rows`).
   Mosaic (jax 0.9.0 / libtpu 0.0.34, v5e) lowers it in bf16 at `block_len`
   8 and 16, query widths 1 to 2,048, MHA, GQA and 20:1 multi-query (a
   one-token row folds 20 rows, 32 in packed bf16 tiles: 37.5% of them pad;
   a step's 16-wide rows fold 320, whole tiles) — with the 8-row bf16 page
   and the 1-row q tile; tests/test_mosaic_aot.py pins those and the cells'.
+
+A row with one live column (PR 48). A step's decode rows, and a prompt's
+one-token tail, hold one live column of the tile's `Tq`: fifteen sixteenths
+of the rows a group computes would be read by nobody. Where the tile is
+smaller with one column than with all of them (`_one_column_rows`, from the
+shapes alone: `Tq` > 1, no `sel`, and `fold` rows take fewer packed sublane
+tiles than `fold * Tq`: every GQA, multi-query and latent tile of a step,
+not an MHA tile in bf16, whose sixteen rows are one packed tile either way)
+the kernel's trace holds the group's arithmetic twice, each behind a
+`pl.when` on `lens - pos == 1`, read on the scalar core per grid step: such
+a row takes column 0 of its q tile out once, into `[heads, fold, D]`
+scratch, runs its groups over `fold` rows a head in the first `fold` rows
+of the softmax state and the accumulator, and writes column 0's rows of
+its o tile, zeros in the dead columns'. Every other row (a chunk, a verify
+window, a whole prompt) runs the wide body. The grid, the copies, the wait
+and the groups' boundaries are shared, and a (query, key) pair goes through
+the same arithmetic in either, so a live position's bits do not depend on
+which body its row took (on a TPU; on the CPU the interpreted kernel's
+products are XLA's CPU dots, which round a float32 tile of one row
+otherwise). `KERNEL_TILINGS`' `one_column_rows` is `fold` for a trace that
+holds both bodies and 0 for one that holds the wide one alone; no caller
+chooses.
 
 A window (PR 31). With `window=W` a query at position p sees the W keys
 `p - W < col <= p` (itself included) and the cache may be a *ring*: logical
@@ -244,6 +267,18 @@ def _choose_tile(H: int, Hkv: int, Tq: int, block_len: int, D: int,
     return tiles[-1]
 
 
+def _one_column_rows(fold: int, Tq: int, itemsize: int, masked: bool) -> int:
+    """Rows a head of the kernel's one-column body (`fold`), or 0 where the
+    trace holds the wide body alone: a one-token call, a walk under a
+    selection's masks (its one-column rows have length 0 and reach no
+    group), and a tile that takes as many packed sublane tiles (16 rows in
+    bf16, 8 in float32) with one column as with all of them."""
+    packed = 32 // itemsize
+    if Tq == 1 or masked or -(-fold // packed) == -(-fold * Tq // packed):
+        return 0
+    return fold
+
+
 def _first_block(pos, window: int, block_len: int):
     """The first logical block that cuts the window of a row whose first
     query sits at `pos`."""
@@ -356,7 +391,7 @@ def _head_dot(a, b, a_dim, b_dim):
 
 def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
                   block_len, pages, pages_per_row, n_groups, parts, scale,
-                  Tq, window=None, latent=False, masked=False):
+                  Tq, window=None, latent=False, masked=False, narrow=0):
     """Grid (B, G); one step is one slot's whole walk for every head of
     the tile: a loop over the row's live groups of `pages` consecutive
     logical pages. q/o tiles [heads, fold*Tq, D] (a KV head's query heads
@@ -378,14 +413,24 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
     latent page that is already in VMEM. With `masked` a third slab in HBM
     follows the two (`sel [B, Tq, L]`, a query column's 0/1 mask of the
     logical columns) and a group's `[Tq, keys]` piece of it rides beside
-    the group's pages into a buffer of its own."""
+    the group's pages into a buffer of its own. With `narrow` (the
+    `fold` of `_one_column_rows`) the trace holds a second body of the
+    group's arithmetic, over `narrow` rows a head: a row with one live
+    column (`lens - pos == 1`, read on the scalar core) takes its column 0
+    out of the q tile once, into `[heads, narrow, D]` scratch, runs its
+    groups over that in the first `narrow` rows of the softmax state and
+    the accumulator, and writes the result to column 0's rows of the o
+    tile and zeros to the fifteen dead columns'; every other row runs the
+    wide body. The walk, the copies and the wait are shared."""
     refs = list(refs)
     qr_ref = refs.pop(0) if latent else None
     k_hbm, v_hbm = refs.pop(0), refs.pop(0)
     sel_hbm = refs.pop(0) if masked else None
     o_ref, kbuf, vbuf = refs.pop(0), refs.pop(0), refs.pop(0)
     selbuf = refs.pop(0) if masked else None
-    sem, slot_ref, acc_ref, m_ref, l_ref = refs
+    sem, slot_ref, acc_ref, m_ref, l_ref = refs[:5]
+    tiles = [q_ref] + ([qr_ref] if latent else [])
+    column0 = refs[5:]        # column 0 of each: the one-column body's q
     b, g = pl.program_id(0), pl.program_id(1)
     B, G = pl.num_programs(0), pl.num_programs(1)
     heads, rows = q_ref.shape[1], q_ref.shape[2]
@@ -457,15 +502,74 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
     hi = jnp.maximum(n, lo + 1)
     slot0 = jnp.where(opening, 0, slot_ref[0])   # holds this row's group 0
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    # which body a grid step runs: decorators round each body's pieces (a
+    # trace that holds the wide body alone runs it for every row)
+    if narrow:
+        one = lens_ref[b] - pos_ref[b] == 1
+        wide_row, one_column_row = pl.when(jnp.logical_not(one)), pl.when(one)
+    else:
+        def wide_row(body):
+            body()
+
+    def state(n):
+        """The first `n` rows a head of the accumulator and the softmax
+        state: all of them for the wide body."""
+        if n == rows:
+            return acc_ref, m_ref, l_ref
+        return acc_ref.at[:, :n], m_ref.at[:, :n], l_ref.at[:, :n]
+
+    def reset(n):
+        acc, m, l = state(n)
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+        m[...] = jnp.full(m.shape, _NEG_INF, m.dtype)
+        l[...] = jnp.zeros(l.shape, l.dtype)
+
+    wide_row(lambda: reset(rows))
+    if narrow:
+        @one_column_row
+        def _():
+            reset(narrow)
+            for ref, to in zip(tiles, column0):   # row r is token r mod Tq
+                to[...] = ref[0].reshape(
+                    heads, narrow, Tq, ref.shape[3])[:, :, 0]
 
     t = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
     if rows != Tq:                            # folded row r is token r mod Tq
         t = jax.lax.rem(t, Tq)
     row_pos = pos_ref[b] + t
     lane = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+    lane1 = jax.lax.broadcasted_iota(jnp.int32, (narrow, keys), 1) \
+        if narrow else None
+
+    def attend(i, slot, n, q_of, row_pos, lane):
+        """Group i's arithmetic over `n` rows a head, `q_of(k)` the
+        queries of `tiles[k]`: the masked scores, the online-softmax update
+        and the p.V product. What a (query, key) pair goes through does not
+        depend on `n`."""
+        acc, m, l = state(n)
+        col = (first + i) * keys + lane
+        keep = (col <= row_pos) & (col < lens_ref[b])
+        if window is not None:
+            keep &= col > row_pos - window
+        if masked:                            # folded row r is token r mod Tq
+            keep &= jnp.tile(selbuf[slot], (n // Tq, 1)) > 0.5
+        vgrp = vbuf[slot]                                 # [heads, keys, D]
+        s = _head_dot(q_of(0), kbuf[slot], 1, 1)
+        if latent:
+            s = s + _head_dot(q_of(1), vgrp, 1, 1)
+            vgrp = kbuf[slot]
+        s = s * scale
+        s = jnp.where(keep[None], s, _NEG_INF)            # [heads, n, keys]
+        m_prev = m[...]
+        l_prev = l[...]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+        alpha = jnp.exp(m_prev - m_new)
+        l[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc[...] = acc[...] * alpha + _head_dot(
+            p.astype(vgrp.dtype), vgrp, 1, 0)
+        m[...] = m_new
 
     def group(i, carry):
         ahead = (slot0 + i + 1) % 2           # the buffer group i is not in
@@ -484,43 +588,44 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
                 # counts bytes: one wait for the buffer's size takes all
                 pltpu.make_async_copy(buf.at[slot], buf.at[slot],
                                       sem.at[slot]).wait()
-            col = (first + i) * keys + lane
-            keep = (col <= row_pos) & (col < lens_ref[b])
-            if window is not None:
-                keep &= col > row_pos - window
-            if masked:                        # folded row r is token r mod Tq
-                keep &= jnp.tile(selbuf[slot], (rows // Tq, 1)) > 0.5
-            vgrp = vbuf[slot]                             # [heads, keys, D]
-            s = _head_dot(q_ref[0], kbuf[slot], 1, 1)
-            if latent:
-                s = s + _head_dot(qr_ref[0], vgrp, 1, 1)
-                vgrp = kbuf[slot]
-            s = s * scale
-            s = jnp.where(keep[None], s, _NEG_INF)        # [heads, rows, keys]
-            m_prev = m_ref[...]
-            l_prev = l_ref[...]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + _head_dot(
-                p.astype(vgrp.dtype), vgrp, 1, 0)
-            m_ref[...] = m_new
+            wide_row(lambda: attend(
+                i, slot, rows, lambda k: tiles[k][0], row_pos, lane))
+            if narrow:                        # every row is token 0
+                one_column_row(lambda: attend(
+                    i, slot, narrow, lambda k: column0[k][...],
+                    pos_ref[b], lane1))
         return carry
 
     jax.lax.fori_loop(lo, hi, group, None)
     slot_ref[0] = (slot0 + hi) % 2
-    l = jnp.maximum(l_ref[...], 1e-30)
-    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    def result(n):
+        acc, _, l = state(n)
+        l = jnp.maximum(l[...], 1e-30)
+        return acc[...] / l
+
+    @wide_row
+    def _():
+        o_ref[0] = result(rows).astype(o_ref.dtype)
+
+    if narrow:
+        @one_column_row
+        def _():
+            # column 0's rows of the o tile; the dead columns hold zeros
+            first_col = jax.lax.broadcasted_iota(
+                jnp.int32, (heads, narrow, Tq, o_ref.shape[3]), 2) == 0
+            o_ref[0] = jnp.where(
+                first_col, result(narrow)[:, :, None], 0.0).reshape(
+                    o_ref.shape[1:]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "block_len", "pages_per_row", "scale", "window", "heads", "fold",
-    "n_groups", "interpret", "sparse"))
+    "n_groups", "interpret", "sparse", "narrow"))
 def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                 q_rope=None, sel=None, *, block_len, pages_per_row, scale,
-                window, heads, fold, n_groups, interpret, sparse=False):
+                window, heads, fold, n_groups, interpret, sparse=False,
+                narrow=0):
     """The kernel's `pallas_call` at one tile. Jitted at module level with
     every integer static, so the call sites of one traced program that
     agree on shapes and window (a step's layers, unrolled) share one
@@ -563,13 +668,15 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
             pltpu.VMEM((heads, rows, D), jnp.float32),
             pltpu.VMEM((heads, rows, 1), jnp.float32),
             pltpu.VMEM((heads, rows, 1), jnp.float32),
-        ],
+        ] + ([pltpu.VMEM((heads, narrow, x.shape[3]), x.dtype)
+              for x in queries] if narrow else []),
     )
     kernel = functools.partial(
         _paged_kernel, block_len=block_len, pages=P,
         pages_per_row=pages_per_row, n_groups=n_groups,
         parts=n_rep // fold,       # tiles that share one KV head (1: none)
-        scale=scale, Tq=Tq, window=window, latent=latent, masked=masked)
+        scale=scale, Tq=Tq, window=window, latent=latent, masked=masked,
+        narrow=narrow)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H // fold, rows, D), q.dtype),
@@ -598,9 +705,10 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
     heads, fold = _choose_tile(H, k_cache.shape[1], Tq, block_len, width,
                                q.dtype.itemsize)
     name = _kernel_name(window, q_rope is not None, sparse)
+    narrow = _one_column_rows(fold, Tq, q.dtype.itemsize, sel is not None)
     pallas_mode.note_tiling(name, grid=(B, H // (heads * fold)),
                             groups=n_groups, pages=P, heads=heads,
-                            rows=fold * Tq)
+                            rows=fold * Tq, one_column_rows=narrow)
     more = () if q_rope is None else (q_rope,)
     if sel is not None:
         # whole groups of columns: a group's piece is one aligned copy
@@ -611,7 +719,7 @@ def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
         block_len=block_len, pages_per_row=pages_per_row,
         scale=float(scale), window=window, heads=heads, fold=fold,
         n_groups=n_groups, interpret=pallas_mode.interpret(name),
-        sparse=sparse)
+        sparse=sparse, narrow=narrow)
 
 
 def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,

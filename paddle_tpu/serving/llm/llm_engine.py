@@ -2902,10 +2902,14 @@ class LLMEngine:
             # rest is arithmetic, a sparse one must keep them out of its
             # experts
             live_tokens = int(adv.sum())
+            # rows with one live column: the paged kernels run their groups
+            # over that column alone (`ops/paged_attention.py`)
+            one_column = int(np.count_nonzero(adv == 1))
             span_args = dict(prefill_rows=len(prefill_slots),
                              decode_rows=len(decode_slots),
                              sampled_rows=sampled_rows,
                              live_tokens=live_tokens,
+                             one_column_rows=one_column,
                              step_tokens=self.step_tokens,
                              deferred_rows=deferred,
                              in_flight=int(ahead_of is not None))
@@ -2980,6 +2984,8 @@ class LLMEngine:
                         self._moe_routed += live_tokens
                     self.metrics.on_step_tokens(live_tokens,
                                                 self.step_tokens, deferred)
+                    self.metrics.on_paged_rows(
+                        one_column, int(np.count_nonzero(adv > 1)))
                     if started:
                         self.metrics.on_recurrent_rows_started(started)
                     if self.pool.recurrent:

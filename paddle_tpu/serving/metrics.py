@@ -287,6 +287,8 @@ class LLMMetrics(ServingMetrics):
                               "step_tokens_live": 0,
                               "step_tokens_computed": 0,
                               "prefill_rows_deferred": 0,
+                              "paged_rows_one_column": 0,
+                              "paged_rows_wide": 0,
                               "steps_overlapped": 0,
                               "rows_discarded": 0,
                               "pool_copies": 0, "pool_lost": 0,
@@ -604,6 +606,18 @@ class LLMMetrics(ServingMetrics):
             self.counters["index_layers_full"] += int(full)
             self.counters["index_layers_shared"] += int(shared)
 
+    def on_paged_rows(self, one_column: int, wide: int):
+        """Of a committed step's rows, by their live columns: `one_column`
+        have one (a decode row, a prompt's one-token tail), and the paged
+        kernels run their groups over that column alone where their trace
+        holds the one-column body (`ops/paged_attention.py`;
+        `pallas_mode.KERNEL_TILINGS`' `one_column_rows` says where);
+        `wide` have two or more (a prefill chunk, a verify window). Free
+        and deferred rows are in neither."""
+        with self._lock:
+            self.counters["paged_rows_one_column"] += int(one_column)
+            self.counters["paged_rows_wide"] += int(wide)
+
     def on_recurrent_rows_started(self, n: int):
         """`n` rows of a committed step began at position 0: the step
         started them from a zero recurrent state."""
@@ -832,7 +846,8 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_sampler_filter_steps_total",
                  s["sampler_filter_steps"])
         for name in ("step_tokens_live", "step_tokens_computed",
-                     "prefill_rows_deferred", "steps_overlapped",
+                     "prefill_rows_deferred", "paged_rows_one_column",
+                     "paged_rows_wide", "steps_overlapped",
                      "rows_discarded", "pool_copies", "pool_lost"):
             b.family(f"{px}_{name}_total", "counter")
             b.sample(f"{px}_{name}_total", s[name])

@@ -52,9 +52,51 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
                    and "'pages': 8" in t for t in tilings), (grid, groups)
     # multi-query 20:1: the whole group folds into one tile's rows, 320 at
     # the step's 16-wide rows and 20 at a one-token row
-    for rows in (320, 20):
-        assert any(f"'heads': 1, 'pages': 8, 'rows': {rows}" in t
-                   for t in tilings), rows
+    for rows, one in ((320, 20), (20, 0)):
+        assert any(f"'heads': 1, 'one_column_rows': {one}, 'pages': 8, "
+                   f"'rows': {rows}" in t for t in tilings), rows
+
+
+def test_the_paged_walks_hold_the_one_column_body_where_the_tile_shrinks(
+        tool):
+    """PR 48: a trace whose tile takes fewer packed sublane tiles with one
+    column than with sixteen holds the group's arithmetic twice, and both
+    bodies compile for the v5e in one kernel at the cells' shapes: Xing's
+    32 of 512 rows, A.X-K1's 32 of 512 in two tiles, Jamba's 20 of 320,
+    Mistral's 4 of 64 a KV head (decode and prefill cells), Mellum's 8 of
+    128 in the ring and the full walk, and the whole-prompt tiles. A
+    one-token call, OLMoE's MHA tile (sixteen bf16 rows are one packed tile
+    either way) and both of the sparse layer's calls hold one body: their
+    kernels are the parent's to the equation (493 at 8 pages a group; the
+    gathered and the masked latent walk 497 and 505)."""
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling paged_")]
+    rows_of = {("paged_latent", "(256, 1)", 512): 32,
+               ("paged_latent", "(32, 2)", 512): 32,
+               ("paged_attention", "(256, 1)", 320): 20,
+               ("paged_attention", "(128, 1)", 64): 4,
+               ("paged_attention", "(32, 1)", 64): 4,
+               ("paged_window", "(32, 1)", 128): 8,
+               ("paged_attention", "(32, 1)", 128): 8,
+               ("paged_attention", "(2, 8)", 2048): 4,
+               # one body: OLMoE's step and the one-token calls
+               ("paged_attention", "(128, 1)", 16): 0,
+               ("paged_attention", "(8, 1)", 20): 0,
+               ("paged_attention", "(8, 1)", 4): 0,
+               ("paged_window", "(32, 1)", 8): 0,
+               ("paged_latent", "(32, 1)", 64): 0}
+    for (kernel, grid, rows), one in rows_of.items():
+        assert any(t.startswith(f"tiling {kernel} ") and f"'grid': {grid}, "
+                   in t and f"'one_column_rows': {one}, " in t
+                   and f"'rows': {rows}}}" in t for t in tilings), \
+            (kernel, grid, rows, tilings)
+    assert all("'one_column_rows': 0, " in t for t in tilings
+               if t.startswith("tiling paged_sparse"))
+    body = {m[1]: int(m[2]) for m in re.finditer(
+        r"^body paged_attention (mistral decode|olmoe decode) cell: (\d+) "
+        r"equations$", tool, re.M)}
+    assert body["olmoe decode"] == 493 < body["mistral decode"] < 650
+    assert "body paged_sparse chunk rows: [497, 505] equations" in tool
 
 
 def test_the_mamba2_recurrence_compiles_with_both_bodies(tool):
@@ -242,7 +284,7 @@ def test_the_latent_walk_compiles_at_the_latent_cells_shapes(tool):
     for grid, groups, rows in (("(32, 2)", 65, 512), ("(32, 1)", 65, 64),
                                ("(256, 1)", 20, 512)):
         assert any(f"'grid': {grid}, 'groups': {groups}, 'heads': 1, "
-                   f"'pages': 8, 'rows': {rows}" in t
+                   in t and f"'pages': 8, 'rows': {rows}" in t
                    for t in tilings), (grid, tilings)
 
 
@@ -287,10 +329,12 @@ def test_sparse_attention_compiles_at_the_sessions_cells_shapes(tool):
     tilings = [ln for ln in lines if ln.startswith("tiling paged_sparse")]
     # the masked walk over the slot's 288 groups, the heads in two tiles;
     # the gathered walk over 16 groups, one tile
-    assert any("'grid': (16, 2), 'groups': 288, 'heads': 1, 'pages': 8, "
-               "'rows': 512" in t for t in tilings), tilings
-    assert any("'grid': (16, 1), 'groups': 16, 'heads': 1, 'pages': 8, "
-               "'rows': 64" in t for t in tilings), tilings
+    assert any("'grid': (16, 2), 'groups': 288, 'heads': 1, "
+               "'one_column_rows': 0, 'pages': 8, 'rows': 512" in t
+               for t in tilings), tilings
+    assert any("'grid': (16, 1), 'groups': 16, 'heads': 1, "
+               "'one_column_rows': 0, 'pages': 8, 'rows': 64" in t
+               for t in tilings), tilings
 
 
 def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):

@@ -72,6 +72,16 @@ def _ref_paged(q, k_cache, v_cache, table, seq_lens, q_pos, block_len,
     return jnp.concatenate(outs, 0)
 
 
+def _one_column_dead(lens, q_pos, fold, Tq):
+    """[B, 1, Tq, 1]: the dead columns of the rows with one live column of
+    several. Where the kernel's float32 trace holds the one-column body (PR
+    48) they are zeros, in the scan a key-less query's finite values."""
+    from paddle_tpu.ops.paged_attention import _one_column_rows
+    one = (lens - q_pos == 1) & bool(_one_column_rows(fold, Tq, 4, False))
+    return jnp.asarray(one)[:, None, None, None] \
+        & (jnp.arange(Tq) > 0)[None, None, :, None]
+
+
 def _identity_table(batch, n_blocks):
     return (np.arange(batch, dtype=np.int32)[:, None] * n_blocks
             + np.arange(n_blocks, dtype=np.int32)[None, :])
@@ -267,11 +277,15 @@ def test_pallas_interpret_matches_scan_and_reference(monkeypatch, H, Hkv, Tq,
     G = H // (heads * fold)
     assert (G > 1) == bool(tile)
     assert dict(pallas_mode.KERNEL_TILINGS) == {
-        ("paged_attention", (("grid", (B, G)), ("groups", -(-nb // P)),
-                             ("heads", heads), ("pages", P),
-                             ("rows", fold * Tq))): 1}
+        ("paged_attention", (
+            ("grid", (B, G)), ("groups", -(-nb // P)), ("heads", heads),
+            ("one_column_rows", PA._one_column_rows(fold, Tq, 4, False)),
+            ("pages", P), ("rows", fold * Tq))): 1}
     assert bool(jnp.all(jnp.isfinite(run["pallas"])))
-    assert float(jnp.max(jnp.abs(run["pallas"] - run["scan"]))) <= 1e-6
+    dead = _one_column_dead(lens, q_pos, fold, Tq)
+    assert not bool(jnp.any(jnp.where(dead, run["pallas"], 0.0)))
+    assert float(jnp.max(jnp.abs(jnp.where(
+        dead, 0.0, run["pallas"] - run["scan"])))) <= 1e-6
     assert not np.asarray(run["pallas"][2]).any()      # the empty row
     ref = _ref_paged(q, jnp.nan_to_num(k), jnp.nan_to_num(v), table, lens,
                      q_pos, bl, nb)
@@ -409,9 +423,11 @@ def test_window_kernel_matches_scan_and_reference(H, Hkv, Tq, bl, window,
     P = 128 // bl
     groups = min(-(-(window + Tq - 1) // 128), -(-ring_pages // P)) + 1
     assert dict(pallas_mode.KERNEL_TILINGS) == {
-        (PA.WINDOW_KERNEL, (("grid", (B, 1)), ("groups", groups),
-                            ("heads", Hkv), ("pages", P),
-                            ("rows", H // Hkv * Tq))): 1}
+        (PA.WINDOW_KERNEL, (
+            ("grid", (B, 1)), ("groups", groups), ("heads", Hkv),
+            ("one_column_rows",
+             PA._one_column_rows(H // Hkv, Tq, 4, False)),
+            ("pages", P), ("rows", H // Hkv * Tq))): 1}
     ref = _ref_window(q, jnp.asarray(k_log), jnp.asarray(v_log), lens,
                       q_pos, window)
     for b in range(B):
@@ -421,8 +437,11 @@ def test_window_kernel_matches_scan_and_reference(H, Hkv, Tq, bl, window,
             assert bool(jnp.all(jnp.isfinite(got))), (impl, b)
             assert float(jnp.max(jnp.abs(got - ref[b, :, :n]),
                                  initial=0.0)) <= 1e-5, (impl, b)
-    assert float(jnp.max(jnp.abs(jnp.nan_to_num(run["pallas"])
-                                 - jnp.nan_to_num(run["scan"])))) <= 1e-6
+    dead = _one_column_dead(lens, q_pos, H // Hkv, Tq)
+    assert not bool(jnp.any(jnp.where(dead, run["pallas"], 0.0)))
+    assert float(jnp.max(jnp.abs(jnp.where(
+        dead, 0.0, jnp.nan_to_num(run["pallas"])
+        - jnp.nan_to_num(run["scan"]))))) <= 1e-6
     assert not np.asarray(run["pallas"][4]).any()      # the empty row
 
 
@@ -726,6 +745,147 @@ def test_latent_tile_folds_heads_inside_the_budget():
         assert other not in PA.LATENT_KERNEL
 
 
+# ---- the one-column body (PR 48): a row with one live column runs its
+# ---- groups over `fold` rows a head, not `fold x Tq` ----
+
+def _mixed_step(walk, dtype, seed=5):
+    """A unified step's rows at Tq = 16 over pages of 16: one-column rows
+    (a decode row one key long, inside the first group, past it, at the
+    slot's end), chunk rows (every column live), a verify-width row (three
+    live columns), a short prompt tail, and free rows (length 0, one
+    between live rows and one at the grid's end). Returns (call, q,
+    (q_pos, adv), fold): `call(q, impl)` runs `walk` over the step."""
+    rng = np.random.RandomState(seed)
+    Tq, bl, D = 16, 16, 64
+    H, Hkv = {"gqa4": (8, 2), "mqa20": (20, 1), "mha": (4, 4),
+              "window-ring": (8, 2), "latent": (32, 1)}[walk]
+    #                one  chunk one  free verify one  chunk tail one  free
+    ends = np.array([1,   48,   130, 0,   77,    37,  259,  5,   272, 0],
+                    np.int32)
+    adv = np.array([1,    16,   1,   0,   3,     1,   16,   5,   1,   0],
+                   np.int32)
+    B, nb = len(ends), 17
+    q_pos = ends - adv
+    q = _rand(rng, (B, H, Tq, D), dtype)
+    kw = dict(block_len=bl)
+    if walk == "window-ring":
+        window, ring_pages = 40, 4             # a ring of 64 >= 40 + 16
+        ring = ring_pages * bl
+        logical = rng.standard_normal((2, B, Hkv, nb * bl, D)) \
+            .astype(np.float32)
+        k, v = (jnp.asarray(np.stack([
+            _ring_of(x[b], ends[b], ring, Tq) for b in range(B)]), dtype)
+            for x in logical)
+        table = None
+        kw.update(pages_per_row=ring_pages, window=window)
+    else:
+        Dv = 16 if walk == "latent" else D       # latent: the rotary key
+        k = _rand(rng, (B, Hkv, nb * bl, D), dtype)
+        v = _rand(rng, (B, Hkv, nb * bl, Dv), dtype)
+        table = rng.permutation(B * nb).astype(np.int32).reshape(B, nb)
+        kw.update(pages_per_row=nb)
+    qr = _rand(rng, (B, H, Tq, 16), dtype) if walk == "latent" else None
+
+    def call(q, impl, columns=slice(None)):
+        from paddle_tpu.ops.paged_attention import ragged_paged_attention
+        more = {} if qr is None else dict(q_rope=qr[:, :, columns],
+                                          scale=0.2)
+        return np.asarray(ragged_paged_attention(
+            q[:, :, columns], k, v, table, ends, q_pos, impl=impl, **kw,
+            **more).astype(jnp.float32))
+    return call, q, (q_pos, adv), H // Hkv
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("walk", ["gqa4", "mqa20", "mha", "window-ring",
+                                  "latent"])
+def test_one_column_rows_take_the_one_column_body_and_keep_every_bit(
+        monkeypatch, walk, dtype):
+    """A mixed step through the kernel (interpreted) with the one-column
+    body in its trace, against the same step with the wide body alone (the
+    static rule `_one_column_rows` patched to 0: there is no run-time
+    switch): every live position the same bits; a one-column row also the
+    bits of today's one-token call on `q[:, :, :1]`; its fifteen dead
+    columns zeros; everything within the documented tolerance of the scan.
+    `KERNEL_TILINGS` says which traces hold the second body: `fold` rows a
+    head, and none where one column takes as many packed sublane tiles as
+    sixteen (MHA in bf16: 1 row of 16 beside 16 of 16).
+
+    On the CPU the kernel's products are XLA's CPU dots, which contract a
+    float32 tile of one row (MHA: `fold` = 1) as a matrix-vector product,
+    with other roundings than a tile of sixteen: there the wide body's
+    bits are the one-token call's neither today, and the comparison with
+    the wide body is by tolerance. On a TPU a row of the MXU's result does
+    not depend on how many rows ride with it."""
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
+    call, q, (q_pos, adv), fold = _mixed_step(walk, dtype)
+    name = {"window-ring": PA.WINDOW_KERNEL, "latent": PA.LATENT_KERNEL} \
+        .get(walk, "paged_attention")
+    engaged = not (walk == "mha" and dtype == jnp.bfloat16)
+
+    def one_column_rows():
+        (kernel, tiling), = pallas_mode.KERNEL_TILINGS
+        assert kernel == name
+        return dict(tiling)["one_column_rows"]
+
+    pallas_mode.KERNEL_TILINGS.clear()
+    both = call(q, "pallas")
+    assert one_column_rows() == (fold if engaged else 0)
+    pallas_mode.KERNEL_TILINGS.clear()
+    token = call(q, "pallas", slice(0, 1))
+    assert one_column_rows() == 0                       # Tq == 1: one body
+    monkeypatch.setattr(PA, "_one_column_rows", lambda *a: 0)
+    pallas_mode.KERNEL_TILINGS.clear()
+    wide = call(q, "pallas")
+    assert one_column_rows() == 0
+    scan = call(q, "scan")
+
+    exact = not (walk == "mha" and dtype == jnp.float32)
+    # the latent's values are 32 heads' sums over one 64-wide page
+    tol = 2e-2 if dtype == jnp.bfloat16 else 5e-6 if walk == "latent" \
+        else 1e-6
+    assert np.isfinite(both).all()
+    for b in range(len(adv)):
+        live = both[b, :, :adv[b]]
+        if exact:
+            assert np.array_equal(live, wide[b, :, :adv[b]]), b
+        assert np.abs(live - wide[b, :, :adv[b]]).max(initial=0) <= tol, b
+        assert np.abs(live - scan[b, :, :adv[b]]).max(initial=0) <= tol, b
+        if adv[b] == 1:
+            assert live.any()
+            assert np.array_equal(live, token[b]), b
+            if engaged:
+                assert not both[b, :, 1:].any(), b
+    assert not both[adv == 0].any()                     # the free rows
+
+
+def test_a_walk_under_a_selection_holds_the_wide_body_alone():
+    """The union walk's `sel=` call: its one-column rows have length 0 and
+    reach no group, so its trace holds one body (`one_column_rows` 0) at a
+    shape where the plain latent walk holds two; the gathered call is a
+    one-token call."""
+    from paddle_tpu.ops import index_select as IX
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
+    from paddle_tpu.ops.attention import PagedView
+    case = _sparse_case(np.random.RandomState(3), 16, 16, "identity")
+    sel = IX.select(case["qi"], case["w"], case["ki"], case["q_pos"], 32,
+                    paged=PagedView(case["table"], case["lens"], 16,
+                                    case["nb"]))
+    pallas_mode.KERNEL_TILINGS.clear()
+    PA.sparse_latent_attention(
+        case["q"], case["c"], case["r"], case["table"], case["lens"],
+        case["q_pos"], sel=sel, block_len=16, pages_per_row=case["nb"],
+        scale=0.2, q_rope=case["qr"], impl="pallas")
+    said = {dict(t)["rows"]: dict(t)["one_column_rows"]
+            for k, t in pallas_mode.KERNEL_TILINGS if k == PA.SPARSE_KERNEL}
+    H = case["q"].shape[1]
+    assert said == {H: 0, H * 16: 0}
+    assert PA._one_column_rows(H, 16, 4, False) == H
+
+
 # ---- a selection over the latent cache (`paged_sparse`, `index_score`,
 # ---- `index_topk`; PR 39) ----
 
@@ -1008,6 +1168,47 @@ def test_engine_chunked_streams_bit_identical_to_generate(gpt_tiny):
         + eng.prefill_dispatches
     assert eng.metrics.snapshot()["kv_fragmentation"] == 0.0  # idle again
     eng.pool.check_balance()
+    eng.stop()
+
+
+def test_engine_counts_its_rows_by_their_live_columns(gpt_tiny):
+    """`paged_rows_one_column` / `paged_rows_wide`: the rows of committed
+    steps with one live column (every decode row, a prompt's one-token
+    tail: what the paged kernels give the one-column body) and with more (a
+    chunk, a longer tail); free rows in neither. The host knows `adv` when
+    it builds a step, and the `dispatch` span carries the step's
+    `one_column_rows`."""
+    from paddle_tpu import profiler, serving
+    from paddle_tpu.profiler import SPAN_SERVE_DISPATCH
+    # chunks of 8: 4 -> [4]; 12 -> [8, 4]; 9 -> [8, 1]; 17 -> [8, 8, 1]
+    lengths, new = (4, 12, 9, 17), 6
+    prompts = [np.arange(1, n + 1, dtype=np.int32) for n in lengths]
+    eng = serving.LLMEngine(gpt_tiny, _cfg(n_blocks=6),
+                            clock=serving.SimClock())
+    profiler.start_profiler()           # the in-memory sink only
+    try:
+        handles = [eng.submit(p, max_new_tokens=new) for p in prompts]
+        while eng.has_work():
+            eng.pump()
+        spans = [e["args"] for e in profiler.get_events()
+                 if e["name"] == SPAN_SERVE_DISPATCH]
+    finally:
+        profiler._SINK.enabled = False
+    assert all(len(h.result(timeout=0)) == new for h in handles)
+    tails = [n % 8 for n in lengths]
+    # the last token is sampled, not fed: new - 1 decode rows a request
+    one = sum(t == 1 for t in tails) + (new - 1) * len(prompts)
+    wide = sum(n // 8 for n in lengths) + sum(t > 1 for t in tails)
+    snap = eng.metrics.snapshot()
+    assert (snap["paged_rows_one_column"], snap["paged_rows_wide"]) \
+        == (one, wide) == (22, 6)
+    assert snap["rows_discarded"] == 0
+    assert sum(s["one_column_rows"] for s in spans) == one
+    assert all(s["one_column_rows"] <= s["prefill_rows"] + s["decode_rows"]
+               for s in spans)
+    text = eng.metrics.render()
+    assert f"pdtpu_llm_paged_rows_one_column_total {one}" in text
+    assert f"pdtpu_llm_paged_rows_wide_total {wide}" in text
     eng.stop()
 
 

@@ -101,15 +101,16 @@ def _equations(jaxpr) -> int:
 
 
 def kernel_equations(fn, *specs) -> dict:
-    """{kernel: equations of its body} for every `pallas_call` that
-    tracing `fn` reaches: what a process traces and lowers for a kernel
-    before the compile cache can be asked."""
+    """{kernel: [equations of its body, a call in order]} for every
+    `pallas_call` that tracing `fn` reaches: what a process traces and
+    lowers for a kernel before the compile cache can be asked."""
     found = {}
 
     def visit(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] = _equations(eqn.params["jaxpr"])
+                found.setdefault(eqn.params["name"], []).append(
+                    _equations(eqn.params["jaxpr"]))
             else:
                 for sub in _subjaxprs(eqn):
                     visit(sub)
@@ -348,15 +349,19 @@ def main() -> int:
             spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
             spec((B,), jnp.int32), spec((B,), jnp.int32),
             want={"paged_attention": 1}))
-        if label.startswith("block_len="):
+        if label.startswith(("block_len=", "mistral decode", "olmoe")):
             # the body a process traces and lowers does not grow with the
-            # pages of a group: the copies are issued by a loop
+            # pages of a group: the copies are issued by a loop. A trace
+            # whose tile is smaller with one column than with sixteen
+            # (`one_column_rows` in its tiling) holds the group's
+            # arithmetic twice, Mistral's GQA tile; OLMoE's MHA tile holds
+            # the one body it held before PR 48
             body = kernel_equations(
                 paged, spec(q_shape, jnp.bfloat16), spec(slab, jnp.bfloat16),
                 spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
                 spec((B,), jnp.int32), spec((B,), jnp.int32))
             print(f"body paged_attention {label}: "
-                  f"{body['paged_attention']} equations", flush=True)
+                  f"{body['paged_attention'][0]} equations", flush=True)
     # the windowed walk through a ring (`paged_window`) and the full walk at
     # the window/full GQA cell's shapes: 32 slots, 32/4 heads x 128, a ring
     # of 65 pages (window 1,024 + a chunk) beside 518 full-length pages
@@ -462,15 +467,20 @@ def main() -> int:
                 q, c, r, t, sl, qp, sel=IX.Selection(mask, idx, count),
                 block_len=16, pages_per_row=sP, scale=0.0625, q_rope=qr,
                 impl="pallas")
+        specs = (spec((sB, 64, Tq, 512), jnp.bfloat16),
+                 spec((sB, 64, Tq, 128), jnp.bfloat16),
+                 spec((sB, 1, sL + 16, 512), jnp.bfloat16),
+                 spec((sB, 1, sL + 16, 128), jnp.bfloat16), *rows3,
+                 spec((sB, Tq, sL), jnp.float32),
+                 spec((sB, 2048), jnp.int32), spec((sB,), jnp.int32))
         results.append(compile_case(
             f"paged sparse bf16 {label} q=[{sB}, 64, {Tq}, 512 | 128] "
-            f"slab=[{sB}, 1, {sL + 16}, 512 | 128] k=2048", sparse,
-            spec((sB, 64, Tq, 512), jnp.bfloat16),
-            spec((sB, 64, Tq, 128), jnp.bfloat16),
-            spec((sB, 1, sL + 16, 512), jnp.bfloat16),
-            spec((sB, 1, sL + 16, 128), jnp.bfloat16), *rows3,
-            spec((sB, Tq, sL), jnp.float32), spec((sB, 2048), jnp.int32),
-            spec((sB,), jnp.int32), want={"paged_sparse": want}))
+            f"slab=[{sB}, 1, {sL + 16}, 512 | 128] k=2048", sparse, *specs,
+            want={"paged_sparse": want}))
+        if Tq > 1:      # the gathered walk's body, then the masked walk's
+            body = kernel_equations(sparse, *specs)
+            print(f"body paged_sparse {label}: "
+                  f"{body['paged_sparse']} equations", flush=True)
     # the K/V write (`kv_write`) at every serve cell's slabs: Mistral's
     # decode and prefill cells, OLMoE's 16 heads (4 rows a grid step),
     # the window/full cell's full-length and ring slabs, the latent cell's
@@ -497,7 +507,7 @@ def main() -> int:
         spec((32, 4, 1056, D), jnp.bfloat16),
         spec((32, 4, 16, D), jnp.bfloat16), spec((32, 4, 16, D), jnp.bfloat16),
         spec((32,), jnp.int32))
-    print(f"body kv_write ring=1040: {body['kv_write']} equations",
+    print(f"body kv_write ring=1040: {body['kv_write'][0]} equations",
           flush=True)
     # the grouped matmul of the dropless expert layer at OLMoE's widths and
     # the decode cell's rows (2,048 positions x 8 experts each)
