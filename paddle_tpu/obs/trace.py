@@ -4,7 +4,10 @@ request moves through the engine — admission, queue wait, prefix-cache
 lookup, each prefill chunk, decode-iteration participation, eviction.
 
 Cost discipline: a request that did not opt in carries `trace=None`, so
-every hot-path hook is exactly one predicate (`if req.trace is not None`).
+every hot-path hook for an EVENT of its timeline is exactly one predicate
+(`if req.trace is not None`). The phase boundaries are not the trace's
+own: the LLM engine stamps every request (four clock reads) and a traced
+one copies the stamps into its marks.
 All timestamps are the owning engine's `clock.now()` seconds, so SimClock
 tests get deterministic timelines and MonotonicClock timelines interleave
 with `RecordEvent` spans (both CLOCK_MONOTONIC) in the chrome export.
@@ -29,8 +32,13 @@ _TRACEPARENT_RE = re.compile(
 
 # phase name -> the mark that *starts* it; a phase ends where the next
 # present phase starts (or at "finished"). Order matters.
+# The LLM engine sets the four marks after "submitted" from the stamps
+# every request carries (`_GenRequest`), traced or not, at the places it
+# takes them: a traced request's phases are the ones `LLMMetrics` and the
+# `pdtpu/serve/request/*` spans count every request under.
 LLM_PHASES: Tuple[Tuple[str, str], ...] = (
-    ("queued", "submitted"), ("prefill", "admitted"),
+    ("queued", "submitted"), ("bound", "admitted"),
+    ("prefill", "first_launch"), ("first_fetch", "final_launch"),
     ("decode", "first_token"))
 SERVING_PHASES: Tuple[Tuple[str, str], ...] = (
     ("queued", "submitted"), ("dispatch", "dispatched"))

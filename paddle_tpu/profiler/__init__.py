@@ -43,10 +43,35 @@ SPAN_SERVE_BUILD_ROWS = "pdtpu/serve/build_rows"  # rows, kinds, operands
 SPAN_SERVE_DISPATCH = "pdtpu/serve/dispatch"      # upload + launch;
 #                                                   prefill_rows=, decode_rows=,
 #                                                   sampled_rows=, live_tokens=,
-#                                                   step_tokens=, deferred_rows=
+#                                                   step_tokens=, deferred_rows=,
+#                                                   slots_vacant_queued=
 SPAN_SERVE_FETCH = "pdtpu/serve/fetch"            # host waits for the device
 SPAN_SERVE_COMMIT = "pdtpu/serve/commit"          # acceptance .. retire
 SPAN_SERVE_PUBLISH = "pdtpu/serve/publish"        # gauges after the step
+# one request's way to its first token (`REQUEST_SPANS`): four events a
+# request, none a step or a token, each with the request's `rid`. What
+# they say is read off the stamps every request carries (`_GenRequest`:
+# arrival, admitted, first_launch, final_launch, first_token), which cut
+# its TTFT into queued + bound + prefill + first_fetch
+SPAN_REQUEST_SUBMIT = "pdtpu/serve/request/submit"  # submit()'s body, on
+#                                                   the caller's thread;
+#                                                   rid=, prompt_tokens=
+SPAN_REQUEST_ADMIT = "pdtpu/serve/request/admit"  # in `admit`: probe_row +
+#                                                   allocate + attach; rid=,
+#                                                   slot=, queued_ms=,
+#                                                   cached_tokens=
+SPAN_REQUEST_FIRST_LAUNCH = "pdtpu/serve/request/first_launch"  # in
+#                                                   `build_rows`: the first
+#                                                   step that carries a chunk
+#                                                   of it; rid=, step=,
+#                                                   bound_ms=
+SPAN_REQUEST_FIRST_TOKEN = "pdtpu/serve/request/first_token"  # in `commit`,
+#                                                   round the first _emit;
+#                                                   rid=, step=, ttft_ms=,
+#                                                   queued_ms=, bound_ms=,
+#                                                   prefill_ms=,
+#                                                   first_fetch_ms=, chunks=,
+#                                                   steps_to_first_token=
 SPAN_TRAIN_BATCH_WAIT = "pdtpu/train/batch_wait"  # ChunkPrefetcher get
 SPAN_TRAIN_CHUNK_DISPATCH = "pdtpu/train/chunk_dispatch"  # ScanTrainStep call
 # start-up phases (`SetupSpan`): each also adds its seconds to
@@ -60,6 +85,8 @@ SPAN_SETUP_FIRST_STEP = "pdtpu/setup/first_step"  # the step's first call,
 SERVE_SPANS = (SPAN_SERVE_PUMP, SPAN_SERVE_ADMIT, SPAN_SERVE_EVICT,
                SPAN_SERVE_DRAFT, SPAN_SERVE_BUILD_ROWS, SPAN_SERVE_DISPATCH,
                SPAN_SERVE_FETCH, SPAN_SERVE_COMMIT, SPAN_SERVE_PUBLISH)
+REQUEST_SPANS = (SPAN_REQUEST_SUBMIT, SPAN_REQUEST_ADMIT,
+                 SPAN_REQUEST_FIRST_LAUNCH, SPAN_REQUEST_FIRST_TOKEN)
 TRAIN_SPANS = (SPAN_TRAIN_BATCH_WAIT, SPAN_TRAIN_CHUNK_DISPATCH)
 SETUP_SPANS = (SPAN_SETUP_IMPORT, SPAN_SETUP_ENGINE_INIT,
                SPAN_SETUP_PARALLELIZE, SPAN_SETUP_FIRST_STEP)
@@ -133,6 +160,12 @@ class RecordEvent:
     def __exit__(self, *exc):
         self.end()
         return False
+
+    def set(self, **args):
+        """Arguments that are known only once the span's work is done (the
+        slot an admission was given): call before the span ends."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
 
     def end(self):
         if not self._open:

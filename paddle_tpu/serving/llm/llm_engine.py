@@ -162,7 +162,9 @@ from ...nn.layer import moe
 from ...obs.flight_recorder import flight_recorder
 from ...obs.trace import RequestTrace, TimelineStore, new_request_id
 from ...ops.attention import token_pack
-from ...profiler import (SPAN_SERVE_ADMIT, SPAN_SERVE_BUILD_ROWS,
+from ...profiler import (SPAN_REQUEST_ADMIT, SPAN_REQUEST_FIRST_LAUNCH,
+                         SPAN_REQUEST_FIRST_TOKEN, SPAN_REQUEST_SUBMIT,
+                         SPAN_SERVE_ADMIT, SPAN_SERVE_BUILD_ROWS,
                          SPAN_SERVE_COMMIT, SPAN_SERVE_DISPATCH,
                          SPAN_SERVE_DRAFT, SPAN_SERVE_FETCH,
                          SPAN_SERVE_PUBLISH, SPAN_SERVE_PUMP,
@@ -407,6 +409,11 @@ class GenerationHandle:
         self.slo = slo
         self.future: Future = Future()
         self.ttft_ms: Optional[float] = None
+        # with the first token: where `ttft_ms` went ({"queued_ms",
+        # "bound_ms", "prefill_ms", "first_fetch_ms"}: they add up to it)
+        # and the unified steps committed since a slot was bound
+        self.ttft_phases_ms: Optional[Dict[str, float]] = None
+        self.steps_to_first_token: Optional[int] = None
         self.rid: Optional[str] = None       # request id (always assigned)
         self.trace: Optional[RequestTrace] = None   # when tracing opted in
         self._lock = threading.Lock()
@@ -446,7 +453,9 @@ class _GenRequest:
                  "attached_pages", "rid", "trace", "draft_slot",
                  "spec_off", "draft_attached", "sampling",
                  "sample_offset", "gid", "dfa_state0",
-                 "want_logprobs", "kv_row", "adapter")
+                 "want_logprobs", "kv_row", "adapter", "admitted",
+                 "first_launch", "final_launch", "first_token",
+                 "admit_step", "chunks")
 
     def __init__(self, prompt, max_new_tokens, eos_token_id, arrival,
                  deadline, slo, submit_idx, tenant="default"):
@@ -454,6 +463,18 @@ class _GenRequest:
         self.max_new_tokens = max_new_tokens
         self.eos_token_id = eos_token_id
         self.arrival = arrival            # clock seconds
+        # the stamps of its way to the first token, every request's, each
+        # set once on the engine's clock; with `arrival` they cut
+        # `handle.ttft_ms` into queued + bound + prefill + first_fetch
+        # (`_ttft_phases_ms`)
+        self.admitted: Optional[float] = None      # `_admit` bound a slot
+        self.first_launch: Optional[float] = None  # rows of a launched
+        #                                   step first held a chunk of it
+        self.final_launch: Optional[float] = None  # ... the chunk that
+        #                                   reaches the prompt's end
+        self.first_token: Optional[float] = None   # that step committed
+        self.admit_step: int = 0          # `unified_steps` at `admitted`
+        self.chunks: int = 0              # prefill chunks committed
         self.deadline = deadline          # absolute clock seconds or None
         self.slo = slo                    # SLO class name
         self.submit_idx = submit_idx      # lifetime admission index (fault
@@ -1793,6 +1814,21 @@ class LLMEngine:
         lower-priority can be shed, the grammar bank is full, the engine
         is draining, or the circuit breaker is open."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
+        rid = rid or new_request_id()
+        # on the caller's thread: validation and the wait for the
+        # engine's lock, which no other span covers
+        with RecordEvent(SPAN_REQUEST_SUBMIT, rid=rid,
+                         prompt_tokens=int(prompt.size)):
+            return self._submit(prompt, max_new_tokens, eos_token_id,
+                                deadline_ms, slo, tenant, rid, trace,
+                                sampling, sample_offset, logprobs, kv_row,
+                                lane, adapter)
+
+    def _submit(self, prompt, max_new_tokens, eos_token_id, deadline_ms,
+                slo, tenant, rid, trace, sampling, sample_offset, logprobs,
+                kv_row, lane, adapter) -> GenerationHandle:
+        """`submit`'s body, inside its span: `prompt` is the int32 row,
+        `rid` is set."""
         if prompt.size < 1:
             raise ValueError("prompt must contain at least one token")
         sample_offset = int(sample_offset)
@@ -1810,7 +1846,6 @@ class LLMEngine:
         tenant = self.config.default_tenant if tenant is None else tenant
         if not isinstance(tenant, str) or not tenant:
             raise ValueError("tenant must be a non-empty string")
-        rid = rid or new_request_id()
         if adapter is not None:
             if self.adapter_bank is None:
                 self.metrics.on_reject("adapter_unavailable", tenant=tenant)
@@ -2207,6 +2242,143 @@ class LLMEngine:
                 self.metrics.on_expire(expired)
                 self.metrics.set_queue_depth(self._queue_len_locked())
 
+    def _bind_row_locked(self, req: _GenRequest,
+                         span: RecordEvent) -> Optional[int]:
+        """Give `req` a row of the pool and what the caches hold of its
+        prompt: `probe_row`, `allocate`, then a `kv_row` import, or the
+        prefix cache's attach and copy and the host tier's onboard.
+        Returns the slot, or None where every free row is pinned (the
+        request is back at the head of its queue). Stamps `admitted`;
+        `span` is the request's `admit` span, which it gives the slot, the
+        wait and the tokens that will not be prefilled."""
+        # a prompt that the prefix cache covers writes only behind
+        # the blocks it attaches: a row is fit for it whose cached
+        # pages all sit below them, first of all the row they are
+        # in (a session's next turn goes back where its last one
+        # left its pages, and evicts nothing)
+        keep_below, prefer = 0, None
+        if self.prefix_cache is not None and req.kv_row is None:
+            keep_below, prefer = self.prefix_cache.probe_row(
+                self._kv_ns(req.tenant, req.adapter), req.prompt,
+                max_tokens=len(req.prompt) - 1)
+        try:
+            slot = self.pool.allocate(req.cost, keep_below, prefer)
+        except SlotsExhaustedError:
+            # every free row is pinned by cached blocks with live
+            # readers (pressure eviction couldn't help); requeue
+            # at the front and retry once readers drain
+            self._queues[req.slo].appendleft(req)
+            self.metrics.set_queue_depth(self._queue_len_locked())
+            span.set(requeued=1)
+            return None
+        req.slot = slot
+        req.chunk_off = 0
+        req.attached_pages = []
+        # `queued` ends and `bound` begins
+        req.admitted = self.clock.now()
+        req.admit_step = self.unified_steps
+        queued_ms = (req.admitted - req.arrival) * 1e3
+        if req.trace is not None:
+            req.trace.mark("admitted", req.admitted)
+            req.trace.event("admitted", req.admitted, slot=slot,
+                            queue_wait_ms=queued_ms)
+        if req.kv_row is not None:
+            # prefill→decode handoff import (ISSUE 19): upload the
+            # exported row into this slot's own identity pages and
+            # start chunked prefill past the covered span. No
+            # set_length here — the next chunk commit's
+            # set_length claims the own pages exactly as a cold
+            # prefill would, so check_balance holds without a
+            # special ledger path.
+            t0 = self.clock.now()
+            bl = self.pool.block_len
+            klen = int(req.kv_row["length"])
+            layers = req.kv_row["layers"]
+            for j in range(0, klen, bl):
+                w = min(bl, klen - j)
+                blk = [tuple(a[:, j:j + w, :] for a in layer)
+                       for layer in layers]
+                self.pool.import_page(slot, j // bl, blk)
+            req.chunk_off = klen
+            self.kv_import_tokens += klen
+            if self.ledger is not None:
+                self.ledger.book("kv_onboard", self.clock.now() - t0)
+            flight_recorder().record("kv_import", engine="llm", rid=req.rid,
+                                     tokens=klen)
+            if req.trace is not None:
+                req.trace.event("kv_import", self.clock.now(), tokens=klen)
+        elif self.prefix_cache is not None:
+            # cap at plen-1 so at least one prompt token always
+            # prefills (that step produces the first output
+            # token's logits); an over-cap full block degrades to
+            # a COW tail, so an exact-duplicate prompt still
+            # costs only a one-token prefill
+            plan = self.prefix_cache.acquire(
+                self._kv_ns(req.tenant, req.adapter), req.prompt,
+                max_tokens=len(req.prompt) - 1)
+            if len(plan.pages) < keep_below:
+                # cannot be: nothing between the probe and here
+                # evicts below `keep_below`
+                raise RuntimeError(
+                    f"prefix plan of {len(plan.pages)} pages for a "
+                    f"row chosen to keep {keep_below}")
+            if plan.pages:
+                self.pool.attach_blocks(slot, plan.pages)
+                req.attached_pages = list(plan.pages)
+            if plan.tail_page is not None:
+                self.pool.cow_copy(plan.tail_page, slot)
+            req.chunk_off = plan.attach_len
+            # the slot now holds its own refs (attach_blocks) and
+            # its own copy of the tail — drop acquire's transient
+            # refcounts so eviction sees the true reader count
+            self.prefix_cache.release(plan)
+            self.metrics.on_prefix_lookup(
+                req.tenant, plan.attach_len, len(req.prompt))
+            if req.trace is not None:
+                req.trace.event("prefix_lookup", self.clock.now(),
+                                attach_len=plan.attach_len,
+                                prompt_len=len(req.prompt))
+        # host-tier onboard (ISSUE 19): where the device radix
+        # cache's coverage ends on a block boundary, keep walking
+        # block-by-block through the host spill pool and upload
+        # covered pages into the slot's own identity pages —
+        # chunked prefill then starts past everything either tier
+        # held. A COW tail (non-aligned chunk_off) ends the walk:
+        # that block is already mid-copy. Onboarded blocks are
+        # re-indexed into the device trie for free when the
+        # completed prefill runs `prefix_cache.insert`.
+        if (self.host_kv is not None and req.kv_row is None
+                and req.chunk_off % self.pool.block_len == 0):
+            bl = self.pool.block_len
+            t0 = self.clock.now()
+            j = req.chunk_off // bl
+            onboarded = 0
+            # same cap as the device acquire: at least one prompt
+            # token always prefills
+            while (j + 1) * bl <= len(req.prompt) - 1:
+                layers = self.host_kv.get(
+                    self._kv_ns(req.tenant, req.adapter),
+                    req.prompt[:(j + 1) * bl])
+                if layers is None:
+                    break
+                self.pool.import_page(slot, j, layers)
+                j += 1
+                onboarded += 1
+            if onboarded:
+                req.chunk_off = j * bl
+                self.host_onboard_tokens += onboarded * bl
+                if self.ledger is not None:
+                    self.ledger.book("kv_onboard", self.clock.now() - t0)
+                flight_recorder().record(
+                    "kv_onboard", engine="llm", rid=req.rid,
+                    blocks=onboarded, tokens=onboarded * bl)
+                if req.trace is not None:
+                    req.trace.event("kv_onboard", self.clock.now(),
+                                    blocks=onboarded, tokens=onboarded * bl)
+        span.set(slot=slot, queued_ms=queued_ms,
+                 cached_tokens=int(req.chunk_off))
+        return slot
+
     def _admit(self):
         """Move queued requests into free slots, highest SLO class first —
         pure bookkeeping (slot allocation + chunk_off=0); their prompt
@@ -2221,133 +2393,10 @@ class LLMEngine:
                 if req is None:
                     return
                 self.metrics.set_queue_depth(self._queue_len_locked())
-                # a prompt that the prefix cache covers writes only behind
-                # the blocks it attaches: a row is fit for it whose cached
-                # pages all sit below them, first of all the row they are
-                # in (a session's next turn goes back where its last one
-                # left its pages, and evicts nothing)
-                keep_below, prefer = 0, None
-                if self.prefix_cache is not None and req.kv_row is None:
-                    keep_below, prefer = self.prefix_cache.probe_row(
-                        self._kv_ns(req.tenant, req.adapter), req.prompt,
-                        max_tokens=len(req.prompt) - 1)
-                try:
-                    slot = self.pool.allocate(req.cost, keep_below, prefer)
-                except SlotsExhaustedError:
-                    # every free row is pinned by cached blocks with live
-                    # readers (pressure eviction couldn't help); requeue
-                    # at the front and retry once readers drain
-                    self._queues[req.slo].appendleft(req)
-                    self.metrics.set_queue_depth(self._queue_len_locked())
+                with RecordEvent(SPAN_REQUEST_ADMIT, rid=req.rid) as span:
+                    slot = self._bind_row_locked(req, span)
+                if slot is None:
                     return
-                req.slot = slot
-                req.chunk_off = 0
-                req.attached_pages = []
-                if req.trace is not None:
-                    t_adm = self.clock.now()
-                    req.trace.mark("admitted", t_adm)
-                    req.trace.event(
-                        "admitted", t_adm, slot=slot,
-                        queue_wait_ms=(t_adm - req.arrival) * 1e3)
-                if req.kv_row is not None:
-                    # prefill→decode handoff import (ISSUE 19): upload the
-                    # exported row into this slot's own identity pages and
-                    # start chunked prefill past the covered span. No
-                    # set_length here — the next chunk commit's
-                    # set_length claims the own pages exactly as a cold
-                    # prefill would, so check_balance holds without a
-                    # special ledger path.
-                    t0 = self.clock.now()
-                    bl = self.pool.block_len
-                    klen = int(req.kv_row["length"])
-                    layers = req.kv_row["layers"]
-                    for j in range(0, klen, bl):
-                        w = min(bl, klen - j)
-                        blk = [tuple(a[:, j:j + w, :] for a in layer)
-                               for layer in layers]
-                        self.pool.import_page(slot, j // bl, blk)
-                    req.chunk_off = klen
-                    self.kv_import_tokens += klen
-                    if self.ledger is not None:
-                        self.ledger.book("kv_onboard",
-                                         self.clock.now() - t0)
-                    flight_recorder().record(
-                        "kv_import", engine="llm", rid=req.rid,
-                        tokens=klen)
-                    if req.trace is not None:
-                        req.trace.event("kv_import", self.clock.now(),
-                                        tokens=klen)
-                elif self.prefix_cache is not None:
-                    # cap at plen-1 so at least one prompt token always
-                    # prefills (that step produces the first output
-                    # token's logits); an over-cap full block degrades to
-                    # a COW tail, so an exact-duplicate prompt still
-                    # costs only a one-token prefill
-                    plan = self.prefix_cache.acquire(
-                        self._kv_ns(req.tenant, req.adapter), req.prompt,
-                        max_tokens=len(req.prompt) - 1)
-                    if len(plan.pages) < keep_below:
-                        # cannot be: nothing between the probe and here
-                        # evicts below `keep_below`
-                        raise RuntimeError(
-                            f"prefix plan of {len(plan.pages)} pages for a "
-                            f"row chosen to keep {keep_below}")
-                    if plan.pages:
-                        self.pool.attach_blocks(slot, plan.pages)
-                        req.attached_pages = list(plan.pages)
-                    if plan.tail_page is not None:
-                        self.pool.cow_copy(plan.tail_page, slot)
-                    req.chunk_off = plan.attach_len
-                    # the slot now holds its own refs (attach_blocks) and
-                    # its own copy of the tail — drop acquire's transient
-                    # refcounts so eviction sees the true reader count
-                    self.prefix_cache.release(plan)
-                    self.metrics.on_prefix_lookup(
-                        req.tenant, plan.attach_len, len(req.prompt))
-                    if req.trace is not None:
-                        req.trace.event(
-                            "prefix_lookup", self.clock.now(),
-                            attach_len=plan.attach_len,
-                            prompt_len=len(req.prompt))
-                # host-tier onboard (ISSUE 19): where the device radix
-                # cache's coverage ends on a block boundary, keep walking
-                # block-by-block through the host spill pool and upload
-                # covered pages into the slot's own identity pages —
-                # chunked prefill then starts past everything either tier
-                # held. A COW tail (non-aligned chunk_off) ends the walk:
-                # that block is already mid-copy. Onboarded blocks are
-                # re-indexed into the device trie for free when the
-                # completed prefill runs `prefix_cache.insert`.
-                if (self.host_kv is not None and req.kv_row is None
-                        and req.chunk_off % self.pool.block_len == 0):
-                    bl = self.pool.block_len
-                    t0 = self.clock.now()
-                    j = req.chunk_off // bl
-                    onboarded = 0
-                    # same cap as the device acquire: at least one prompt
-                    # token always prefills
-                    while (j + 1) * bl <= len(req.prompt) - 1:
-                        layers = self.host_kv.get(
-                            self._kv_ns(req.tenant, req.adapter),
-                            req.prompt[:(j + 1) * bl])
-                        if layers is None:
-                            break
-                        self.pool.import_page(slot, j, layers)
-                        j += 1
-                        onboarded += 1
-                    if onboarded:
-                        req.chunk_off = j * bl
-                        self.host_onboard_tokens += onboarded * bl
-                        if self.ledger is not None:
-                            self.ledger.book("kv_onboard",
-                                             self.clock.now() - t0)
-                        flight_recorder().record(
-                            "kv_onboard", engine="llm", rid=req.rid,
-                            blocks=onboarded, tokens=onboarded * bl)
-                        if req.trace is not None:
-                            req.trace.event(
-                                "kv_onboard", self.clock.now(),
-                                blocks=onboarded, tokens=onboarded * bl)
                 # per-slot sampling state (ISSUE 18): bind the request's
                 # params + grammar/DFA row for the slot's lifetime
                 self.sampling_table.bind(slot, req.sampling or GREEDY,
@@ -2780,6 +2829,7 @@ class LLMEngine:
         # keeps admission order), each its whole chunk or none of it
         budget = self.step_tokens - int(adv.sum())
         deferred = 0
+        now = None
         for slot, off, emitted in waiting:
             req = self._active[slot]
             n = min(C, len(req.prompt) - off)
@@ -2792,6 +2842,25 @@ class LLMEngine:
             adv[slot] = n
             ctr[slot] = req.sample_offset + emitted - (n - 1)
             prefill_slots.append(slot)
+            # the request's stamps: `bound` ends with the first step that
+            # carries a chunk of it, `prefill` with the one that carries
+            # its last (the same step for a prompt of one chunk)
+            if req.first_launch is None:
+                now = self.clock.now() if now is None else now
+                req.first_launch = now
+                bound_ms = (now - req.admitted) * 1e3
+                if req.trace is not None:
+                    req.trace.mark("first_launch", now)
+                with RecordEvent(
+                        SPAN_REQUEST_FIRST_LAUNCH, rid=req.rid,
+                        step=self.unified_steps + (ahead_of is not None),
+                        bound_ms=bound_ms):
+                    pass
+            if req.final_launch is None and off + n >= len(req.prompt):
+                now = self.clock.now() if now is None else now
+                req.final_launch = now
+                if req.trace is not None:
+                    req.trace.mark("final_launch", now)
         return (toks, pos, adv, ctr, prefill_slots, decode_slots, deferred,
                 feed)
 
@@ -2879,6 +2948,14 @@ class LLMEngine:
                     return None     # every row ends with the step in flight
                 reqs = {s: self._active[s]
                         for s in prefill_slots + decode_slots}
+                # what the step leaves on the table while somebody waits:
+                # slots that carry no row in it (free, or their request's
+                # last token is in flight; a deferred row's slot is taken),
+                # as far as requests are queued for them. A slot freed at
+                # `_retire(k)`, after `_launch(k+1)`, counts one
+                vacant_queued = min(
+                    self._queue_len_locked(),
+                    self.pool.num_slots - len(reqs) - deferred)
                 kinds = self._kinds_of(prefill_slots, decode_slots)
                 # rows of this step that draw: what the step's sampler
                 # branches on (a freed slot is cleared to greedy, so the
@@ -2912,7 +2989,8 @@ class LLMEngine:
                              one_column_rows=one_column,
                              step_tokens=self.step_tokens,
                              deferred_rows=deferred,
-                             in_flight=int(ahead_of is not None))
+                             in_flight=int(ahead_of is not None),
+                             slots_vacant_queued=vacant_queued)
             kind_args, started, kv_tokens = self.pool.step_counts(pos, adv)
             span_args.update(kind_args)
             sparse_keys = None
@@ -2983,7 +3061,8 @@ class LLMEngine:
                         self._moe_totals, = moe_out
                         self._moe_routed += live_tokens
                     self.metrics.on_step_tokens(live_tokens,
-                                                self.step_tokens, deferred)
+                                                self.step_tokens, deferred,
+                                                vacant_queued)
                     self.metrics.on_paged_rows(
                         one_column, int(np.count_nonzero(adv > 1)))
                     if started:
@@ -3137,11 +3216,13 @@ class LLMEngine:
                 self.pool.set_length(slot, off + n)
                 req.chunk_off = off + n
                 self.prefill_tokens += n
+                req.chunks += 1
                 if req.trace is not None:
                     req.trace.event("prefill_chunk", now, off=off, n=n)
                 if req.chunk_off >= len(req.prompt):
                     # final chunk landed: first token emitted, TTFT
                     # ends here
+                    req.first_token = now
                     req.handle.ttft_ms = (now - req.arrival) * 1e3
                     if req.trace is not None:
                         # same instant as ttft_ms, so the trace's TTFT
@@ -3149,6 +3230,19 @@ class LLMEngine:
                         req.trace.mark("first_token", now)
                     self.metrics.on_prefill(req.handle.ttft_ms,
                                             slo=req.slo)
+                    # where that time went: the stamps' four phases,
+                    # and the steps committed since a slot was bound
+                    # (this one counted above)
+                    phases = req.handle.ttft_phases_ms = {
+                        "queued_ms": (req.admitted - req.arrival) * 1e3,
+                        "bound_ms":
+                            (req.first_launch - req.admitted) * 1e3,
+                        "prefill_ms":
+                            (req.final_launch - req.first_launch) * 1e3,
+                        "first_fetch_ms": (now - req.final_launch) * 1e3}
+                    steps = req.handle.steps_to_first_token = \
+                        self.unified_steps - req.admit_step
+                    self.metrics.on_first_token(*phases.values(), steps)
                     if self.burn is not None:
                         target = (self.config.slo_ttft_target_ms
                                   or {}).get(req.slo)
@@ -3164,8 +3258,13 @@ class LLMEngine:
                         self.prefix_cache.insert(
                             self._kv_ns(req.tenant, req.adapter),
                             req.prompt, slot, req.attached_pages)
-                    self._emit(req, int(nxt[slot, int(adv[slot]) - 1]),
-                               float(lps[slot, int(adv[slot]) - 1]))
+                    with RecordEvent(SPAN_REQUEST_FIRST_TOKEN, rid=req.rid,
+                                     step=self.unified_steps - 1,
+                                     ttft_ms=req.handle.ttft_ms, **phases,
+                                     chunks=req.chunks,
+                                     steps_to_first_token=steps):
+                        self._emit(req, int(nxt[slot, int(adv[slot]) - 1]),
+                                   float(lps[slot, int(adv[slot]) - 1]))
                     if req.gid:
                         # first constrained emission: commit the DFA
                         # state advanced in-step past that token

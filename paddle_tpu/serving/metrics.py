@@ -30,6 +30,9 @@ BATCH_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)
 # scheduler admits interactive before batch before best_effort, and load
 # shedding walks the same list from the BOTTOM up.
 SLO_CLASSES = ("interactive", "batch", "best_effort")
+# the phases a request's time to its first token is the sum of, in order
+# (`LLMEngine`: the stamps of `_GenRequest`)
+TTFT_PHASES = ("queued", "bound", "prefill", "first_fetch")
 
 
 class ServingMetrics:
@@ -278,6 +281,11 @@ class LLMMetrics(ServingMetrics):
     def __init__(self, window: int = 4096):
         super().__init__(window)
         self._ttft_ms: deque = deque(maxlen=self.window)
+        # where a first token's time went, a window a phase: in a class
+        # queue, bound to a slot that no launched step carried yet, in
+        # the chunks but the last, in the last chunk's step
+        self._ttft_phase_ms: Dict[str, deque] = {
+            p: deque(maxlen=self.window) for p in TTFT_PHASES}
         self._intertoken_ms: deque = deque(maxlen=self.window)
         # (active_rows, step_ms) pairs: tokens/sec over the recent window
         self._decode_window: deque = deque(maxlen=self.window)
@@ -287,6 +295,8 @@ class LLMMetrics(ServingMetrics):
                               "step_tokens_live": 0,
                               "step_tokens_computed": 0,
                               "prefill_rows_deferred": 0,
+                              "slot_steps_vacant_queued": 0,
+                              "first_tokens": 0, "ttft_steps": 0,
                               "paged_rows_one_column": 0,
                               "paged_rows_wide": 0,
                               "steps_overlapped": 0,
@@ -532,17 +542,36 @@ class LLMMetrics(ServingMetrics):
         with self._lock:
             self.counters["sampler_filter_steps"] += 1
 
-    def on_step_tokens(self, live: int, computed: int, deferred: int):
+    def on_step_tokens(self, live: int, computed: int, deferred: int,
+                       vacant_queued: int = 0):
         """One committed unified step: `live` of the `computed` positions
         it ran held a token (`computed` is the engine's `step_tokens`:
         the packed width, or slots x chunk where nothing is packed), and
         `deferred` prefill rows waited for a later step because their
         chunk did not fit. `step_tokens_live / step_tokens_computed` is
-        the share of the step's arithmetic that somebody reads."""
+        the share of the step's arithmetic that somebody reads.
+        `vacant_queued` of its slots carried no row while as many
+        requests were queued: over `unified_steps` x slots, the share of
+        the step's rows lost to slot turnover."""
         with self._lock:
             self.counters["step_tokens_live"] += int(live)
             self.counters["step_tokens_computed"] += int(computed)
             self.counters["prefill_rows_deferred"] += int(deferred)
+            self.counters["slot_steps_vacant_queued"] += int(vacant_queued)
+
+    def on_first_token(self, queued_ms: float, bound_ms: float,
+                       prefill_ms: float, first_fetch_ms: float,
+                       steps: int):
+        """One request's first token: the four phases its TTFT is the sum
+        of (`TTFT_PHASES`), and the unified steps committed from its
+        admission to it. `ttft_steps / first_tokens` is the mean number
+        of steps a first token costs."""
+        with self._lock:
+            for phase, ms in zip(TTFT_PHASES, (queued_ms, bound_ms,
+                                               prefill_ms, first_fetch_ms)):
+                self._ttft_phase_ms[phase].append(float(ms))
+            self.counters["first_tokens"] += 1
+            self.counters["ttft_steps"] += int(steps)
 
     def on_step_overlapped(self):
         """One unified step launched while its predecessor was still
@@ -747,6 +776,13 @@ class LLMMetrics(ServingMetrics):
             vals = sorted(src)
         return _quantile(vals, q)
 
+    def ttft_phase_quantiles_ms(self,
+                                phase: str) -> Dict[str, Optional[float]]:
+        """{"p50", "p99"} of one of `TTFT_PHASES` over the recent window."""
+        with self._lock:
+            vals = sorted(self._ttft_phase_ms[phase])
+        return {"p50": _quantile(vals, 0.5), "p99": _quantile(vals, 0.99)}
+
     def intertoken_quantile_ms(self, q: float) -> Optional[float]:
         with self._lock:
             vals = sorted(self._intertoken_ms)
@@ -810,6 +846,8 @@ class LLMMetrics(ServingMetrics):
             s[f"intertoken_{key}_ms"] = self.intertoken_quantile_ms(q)
         for c in SLO_CLASSES:
             s[f"ttft_p99_ms_{c}"] = self.ttft_quantile_ms(0.99, slo=c)
+        s["ttft_phase_ms"] = {p: self.ttft_phase_quantiles_ms(p)
+                              for p in TTFT_PHASES}
         return s
 
     def _render_into(self, b: PromBuilder):
@@ -825,6 +863,11 @@ class LLMMetrics(ServingMetrics):
             for q, key in ((0.5, "p50"), (0.95, "p95"), (0.99, "p99")):
                 b.sample(fam, s[f"{prefix}_{key}_ms"], {"quantile": q},
                          round_to=3)
+        b.family(f"{px}_ttft_phase_ms", "summary")
+        for phase, qs in s["ttft_phase_ms"].items():
+            for q, key in ((0.5, "p50"), (0.99, "p99")):
+                b.sample(f"{px}_ttft_phase_ms", qs[key],
+                         {"phase": phase, "quantile": q}, round_to=3)
         b.family(f"{px}_tokens_per_s", "gauge")
         b.sample(f"{px}_tokens_per_s", s["tokens_per_s"], round_to=3)
         b.family(f"{px}_slots_active", "gauge")
@@ -846,7 +889,8 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_sampler_filter_steps_total",
                  s["sampler_filter_steps"])
         for name in ("step_tokens_live", "step_tokens_computed",
-                     "prefill_rows_deferred", "paged_rows_one_column",
+                     "prefill_rows_deferred", "slot_steps_vacant_queued",
+                     "first_tokens", "ttft_steps", "paged_rows_one_column",
                      "paged_rows_wide", "steps_overlapped",
                      "rows_discarded", "pool_copies", "pool_lost"):
             b.family(f"{px}_{name}_total", "counter")

@@ -58,6 +58,8 @@ def test_request_trace_phases_tile_latency():
     tr = obs.RequestTrace("ab" * 16, 10.0, slo="interactive", tenant="t0")
     tr.mark("admitted", 10.004)
     tr.mark("admitted", 99.0)           # marks record at most once
+    tr.mark("first_launch", 10.005)
+    tr.mark("final_launch", 10.007)
     tr.mark("first_token", 10.010)
     tr.event("decode_step", 10.011, tok=7)
     tr.finish(10.020, "completed")
@@ -65,8 +67,8 @@ def test_request_trace_phases_tile_latency():
     d = tr.to_dict()
     assert d["outcome"] == "completed"
     assert d["slo"] == "interactive" and d["tenant"] == "t0"
-    assert [p["name"] for p in d["phases"]] == ["queued", "prefill",
-                                                "decode"]
+    assert [p["name"] for p in d["phases"]] == [
+        "queued", "bound", "prefill", "first_fetch", "decode"]
     # the tiling contract: phase durations sum EXACTLY to the latency
     assert sum(p["dur_ms"] for p in d["phases"]) == \
         pytest.approx(d["latency_ms"])
@@ -78,7 +80,7 @@ def test_request_trace_phases_tile_latency():
     # chrome view: one X span per phase + an instant per event, one lane
     ev = tr.chrome_events()
     xs = [e for e in ev if e["ph"] == "X"]
-    assert len(xs) == 3
+    assert len(xs) == 5
     assert all(e["name"].startswith("req/abababab/") for e in ev)
     assert len({e["tid"] for e in ev}) == 1
 
@@ -367,8 +369,8 @@ def test_llm_traced_request_timeline_reconciles(gpt_tiny):
     assert len(h.result(timeout=0)) == 4
     tl = h.timeline()
     assert tl["rid"] == h.rid and tl["outcome"] == "completed"
-    assert [p["name"] for p in tl["phases"]] == ["queued", "prefill",
-                                                 "decode"]
+    assert [p["name"] for p in tl["phases"]] == [
+        "queued", "bound", "prefill", "first_fetch", "decode"]
     # span-sum == latency, and the trace's TTFT boundary IS the handle's
     # ttft_ms (recorded at the same clock instant)
     assert sum(p["dur_ms"] for p in tl["phases"]) == \

@@ -1,22 +1,9 @@
-"""Profiler spans + cross-rank aggregation (reference: platform/profiler.h
-RecordEvent; tools/CrossStackProfiler/CspReporter.py merged timelines)."""
+"""Profiler spans (reference: platform/profiler.h RecordEvent)."""
 import json
-import subprocess
-import sys
 
 import numpy as np
 
 from paddle_tpu import profiler
-from paddle_tpu.profiler.cross_stack import CrossStackReporter
-
-
-def _rank_trace(tmp_path, rank, t0, spans):
-    """spans: list of (name, start_us, dur_us)."""
-    events = [{"name": n, "ts": t0 + s, "dur": d, "ph": "X", "pid": 0,
-               "tid": 1} for n, s, d in spans]
-    p = tmp_path / f"rank{rank}.json"
-    p.write_text(json.dumps({"traceEvents": events}))
-    return str(p)
 
 
 def test_record_event_spans_and_summary(tmp_path):
@@ -91,52 +78,3 @@ def test_stop_profiler_from_another_thread_sees_trace_dir(tmp_path,
     t.join()
     assert calls == [("start", str(tmp_path / "xprof")), ("stop", None)]
     assert (tmp_path / "t.json").exists()
-
-
-def test_cross_stack_merges_with_rank_lanes(tmp_path):
-    p0 = _rank_trace(tmp_path, 0, t0=1_000_000,
-                     spans=[("step", 0, 100), ("allreduce", 100, 20)])
-    p1 = _rank_trace(tmp_path, 1, t0=9_000_000,  # different clock domain
-                     spans=[("step", 0, 140), ("allreduce", 140, 20)])
-    rep = CrossStackReporter.from_paths([p0, p1])
-    merged = rep.merged_events()
-    # one metadata lane per rank + every span, pid == rank
-    meta = [e for e in merged if e["ph"] == "M"]
-    assert [m["args"]["name"] for m in meta] == ["rank 0", "rank 1"]
-    spans = [e for e in merged if e["ph"] == "X"]
-    assert {e["pid"] for e in spans} == {0, 1}
-    # clock domains rebased: both ranks start at ts 0, not 9e6 apart
-    assert min(e["ts"] for e in spans if e["pid"] == 1) == 0
-    out = rep.write_merged(str(tmp_path / "merged.json"))
-    assert json.load(open(out))["traceEvents"]
-
-
-def test_cross_stack_op_stats_and_straggler(tmp_path):
-    p0 = _rank_trace(tmp_path, 0, 0, [("step", 0, 100), ("step", 200, 100),
-                                      ("allreduce", 100, 10)])
-    p1 = _rank_trace(tmp_path, 1, 0, [("step", 0, 160), ("step", 200, 160),
-                                      ("allreduce", 160, 10)])
-    rep = CrossStackReporter.from_paths([p0, p1])
-    stats = rep.op_stats()
-    assert stats["step"]["calls"] == 4
-    assert stats["step"]["per_rank_us"] == [200.0, 320.0]
-    assert stats["step"]["skew_us"] == 120.0  # the straggler signal
-    assert stats["allreduce"]["skew_us"] == 0.0
-    busy = rep.rank_busy_us()
-    assert busy == [210.0, 330.0]
-    rpt = rep.straggler_report()
-    assert "slowest: rank 1" in rpt
-    summ = rep.op_summary()
-    assert "step" in summ and "Skew" in summ
-
-
-def test_cross_stack_cli(tmp_path):
-    p0 = _rank_trace(tmp_path, 0, 0, [("step", 0, 50)])
-    p1 = _rank_trace(tmp_path, 1, 0, [("step", 0, 80)])
-    out = str(tmp_path / "merged.json")
-    r = subprocess.run(
-        [sys.executable, "-m", "paddle_tpu.profiler.cross_stack", out,
-         p0, p1], capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stderr
-    assert "slowest: rank 1" in r.stdout
-    assert len(json.load(open(out))["traceEvents"]) == 4  # 2 meta + 2 spans

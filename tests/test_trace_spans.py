@@ -14,7 +14,12 @@ import pytest
 import jax
 
 from paddle_tpu import profiler
-from paddle_tpu.profiler import (SERVE_SPANS, SPAN_SERVE_ADMIT,
+from paddle_tpu.profiler import (REQUEST_SPANS, SERVE_SPANS,
+                                 SPAN_REQUEST_ADMIT,
+                                 SPAN_REQUEST_FIRST_LAUNCH,
+                                 SPAN_REQUEST_FIRST_TOKEN,
+                                 SPAN_REQUEST_SUBMIT, SPAN_SERVE_ADMIT,
+                                 SPAN_SERVE_BUILD_ROWS, SPAN_SERVE_COMMIT,
                                  SPAN_SERVE_DRAFT, SPAN_SERVE_EVICT,
                                  SPAN_SERVE_PUMP)
 
@@ -129,7 +134,9 @@ def test_span_with_the_sink_off_keeps_no_state():
 
 def test_one_pump_yields_the_tables_spans_nested(gpt_tiny, sink):
     """Every pump pass holds the table's spans: children inside `pump`,
-    `evict` inside `admit`; `draft` only with a draft model attached."""
+    `evict` inside one request's `admit` inside `admit`, a request's
+    other events inside `build_rows` and `commit`, its `submit` on the
+    caller's thread; `draft` only with a draft model attached."""
     from paddle_tpu import serving
     clock = serving.SimClock()
     eng = _engine(gpt_tiny, clock)
@@ -142,19 +149,23 @@ def test_one_pump_yields_the_tables_spans_nested(gpt_tiny, sink):
     events = [e for e in sink.get_events()
               if e["name"].startswith("pdtpu/serve/")]
     names = {e["name"] for e in events}
-    assert names == set(SERVE_SPANS) - {SPAN_SERVE_DRAFT}
+    assert names == (set(SERVE_SPANS) | set(REQUEST_SPANS)) \
+        - {SPAN_SERVE_DRAFT}
+    inside = {SPAN_SERVE_EVICT: SPAN_REQUEST_ADMIT,
+              SPAN_REQUEST_ADMIT: SPAN_SERVE_ADMIT,
+              SPAN_REQUEST_FIRST_LAUNCH: SPAN_SERVE_BUILD_ROWS,
+              SPAN_REQUEST_FIRST_TOKEN: SPAN_SERVE_COMMIT}
     by_id = {e["args"]["id"]: e for e in events}
     pumps = [e for e in events if e["name"] == SPAN_SERVE_PUMP]
     assert [e["args"]["step"] for e in pumps] == sorted(
         e["args"]["step"] for e in pumps)
     assert pumps[-1]["args"]["step"] == eng.unified_steps - 1
     for e in events:
-        if e["name"] == SPAN_SERVE_PUMP:
+        if e["name"] in (SPAN_SERVE_PUMP, SPAN_REQUEST_SUBMIT):
             assert e["args"]["parent"] == 0
             continue
         parent = by_id[e["args"]["parent"]]
-        want = SPAN_SERVE_ADMIT if e["name"] == SPAN_SERVE_EVICT \
-            else SPAN_SERVE_PUMP
+        want = inside.get(e["name"], SPAN_SERVE_PUMP)
         assert parent["name"] == want, (e["name"], parent["name"])
         assert parent["ts"] <= e["ts"]
         assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1e-3
