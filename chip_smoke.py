@@ -69,10 +69,13 @@ FULL = dict(
     # a ring of 65 pages (window + one chunk)
     paged_window=dict(heads=32, kv_heads=4, head_dim=128, window=1024,
                       ring_pages=65),
-    # the latent cell's MLA layers: 64 heads over one 512-wide latent and
-    # one 64-wide rotary key (stored in 128 lanes), 518 pages a slot
-    paged_latent=dict(heads=64, latent=512, rope=64, rope_cols=128,
-                      pages=518),
+    # the two latent cells' MLA layers, over one 512-wide latent and one
+    # 64-wide rotary key (stored in 128 lanes): 64 heads in two tiles of
+    # 32, 518 pages a slot; 32 heads in one tile, 160 pages a slot
+    paged_latent=(dict(heads=64, latent=512, rope=64, rope_cols=128,
+                       pages=518),
+                  dict(heads=32, latent=512, rope=64, rope_cols=128,
+                       pages=160)),
     # the sessions cell's sparse layers: an indexer of 32 x 128 that keeps
     # 2,048 keys, over the same latent pair, 2,304 pages a slot
     sparse=dict(heads=64, latent=512, rope_cols=128, index_heads=32,
@@ -91,7 +94,8 @@ TINY = dict(
            dict(heads=2, kv_heads=2, head_dim=64, pages=4)),
     paged_window=dict(heads=4, kv_heads=2, head_dim=64, window=32,
                       ring_pages=3),
-    paged_latent=dict(heads=4, latent=32, rope=8, rope_cols=8, pages=20),
+    paged_latent=(dict(heads=4, latent=32, rope=8, rope_cols=8, pages=20),
+                  dict(heads=2, latent=32, rope=8, rope_cols=8, pages=9)),
     sparse=dict(heads=4, latent=32, rope_cols=8, index_heads=2,
                 index_dim=128, topk=32, pages=20),
     ssm=dict(heads=4, head_dim=64, state=16),
@@ -591,53 +595,66 @@ def _paged_window_parity(size: dict):
 
 
 def _paged_latent_parity(size: dict):
-    """The latent walk (`paged_latent`) `impl="pallas"` against
-    `impl="scan"` on this device at `size["paged_latent"]`: bf16, block_len
+    """The latent walk (`paged_latent`) in its packed form
+    (`packed_latent_attention`: queries and result token-major on a step's
+    packed block, a start a row from a `TokenPack`) `impl="pallas"` against
+    `impl="scan"` (the rows unpacked, `_scan_impl` as it stands) on this
+    device at each head layout of `size["paged_latent"]`: bf16, block_len
     16, the latent `c` and the rotary key `r` (its first `rope` columns;
     zeros behind, as `models/deepseek.py` stores it), every query head over
-    the one latent, query widths 1 and 16, ragged lengths from one key to
-    the whole slot. Tolerance as `_paged_parity`'s."""
+    the one latent, the steps of `PAGED_STEPS`, ragged lengths from one key
+    to the whole slot. Compared over the live positions. Tolerance as
+    `_paged_parity`'s."""
     import jax.numpy as jnp
     import numpy as np
 
     from paddle_tpu.ops import pallas_mode
+    from paddle_tpu.ops.attention import token_pack
     from paddle_tpu.ops.paged_attention import (LATENT_KERNEL,
-                                                ragged_paged_attention)
-    g = size["paged_latent"]
-    H, R, Dr, cols, pages = (g["heads"], g["latent"], g["rope"],
-                             g["rope_cols"], g["pages"])
+                                                packed_latent_attention)
     N, bl, tol = 8, 16, 2e-2
-    L = pages * bl
     rng = np.random.RandomState(3)
-    pad = ((0, 0), (0, 0), (0, 0), (0, cols - Dr))
-    c = jnp.asarray(rng.randn(N, 1, L + bl, R), jnp.bfloat16)
-    r = jnp.pad(jnp.asarray(rng.randn(N, 1, L + bl, Dr), jnp.bfloat16), pad)
-    table = np.arange(N * pages, dtype=np.int32).reshape(N, pages)
-    scale = (R + Dr) ** -0.5
-    for Tq, mixed in PAGED_STEPS:
-        q = jnp.asarray(rng.randn(N, H, Tq, R), jnp.bfloat16)
-        qr = jnp.pad(jnp.asarray(rng.randn(N, H, Tq, Dr), jnp.bfloat16), pad)
-        adv = _live_columns(N, Tq, mixed)
-        lens = np.maximum(adv, np.array(
-            [1, bl - 1, 127, 129, L // 7, L // 3, L - bl - 1, L],
-            np.int32))
-        q_pos = (lens - adv).astype(np.int32)
-        pallas_mode.KERNEL_TILINGS.clear()
-        outs = {impl: ragged_paged_attention(
-            q, c, r, table, lens, q_pos, block_len=bl, pages_per_row=pages,
-            scale=scale, impl=impl, q_rope=qr) for impl in ("pallas", "scan")}
-        ((kernel, tiling),) = pallas_mode.KERNEL_TILINGS
-        tiling = dict(tiling)
-        err = _live_err(outs, adv)
-        _say(f"{kernel} pallas vs scan H={H} latent={R} rope={Dr} (in "
-             f"{cols} columns) Tq={Tq} seq_lens={lens.tolist()} live "
-             f"columns {adv.tolist()} bf16: grid "
-             f"{tiling['grid']}, up to {tiling['groups']} groups of "
-             f"pages={tiling['pages']} a row, tile {tiling['rows']} rows, "
-             f"one-column body {tiling['one_column_rows']} rows; "
-             f"max abs err {err:.2e} (tolerance {tol:g})")
-        _require(kernel == LATENT_KERNEL and np.isfinite(err) and err <= tol,
-                 f"{LATENT_KERNEL} H={H} Tq={Tq} within {tol:g}")
+    for g in size["paged_latent"]:
+        H, R, Dr, cols, pages = (g["heads"], g["latent"], g["rope"],
+                                 g["rope_cols"], g["pages"])
+        L = pages * bl
+        c = jnp.asarray(rng.randn(N, 1, L + bl, R), jnp.bfloat16)
+        r = jnp.pad(jnp.asarray(rng.randn(N, 1, L + bl, Dr), jnp.bfloat16),
+                    ((0, 0), (0, 0), (0, 0), (0, cols - Dr)))
+        table = np.arange(N * pages, dtype=np.int32).reshape(N, pages)
+        scale = (R + Dr) ** -0.5
+        for Tq, mixed in PAGED_STEPS:
+            adv = _live_columns(N, Tq, mixed)
+            lens = np.maximum(adv, np.array(
+                [1, bl - 1, 127, 129, L // 7, L // 3, L - bl - 1, L],
+                np.int32))
+            q_pos = (lens - adv).astype(np.int32)
+            live = int(adv.sum())
+            pack = token_pack(jnp.asarray(adv), jnp.asarray(q_pos), Tq, live)
+            # the step's block and the pad a last row's window runs into
+            q = jnp.asarray(rng.randn(live + Tq - 1, H, R), jnp.bfloat16)
+            qr = jnp.pad(jnp.asarray(rng.randn(live + Tq - 1, H, Dr),
+                                     jnp.bfloat16),
+                         ((0, 0), (0, 0), (0, cols - Dr)))
+            pallas_mode.KERNEL_TILINGS.clear()
+            outs = {impl: packed_latent_attention(
+                q, qr, c, r, table, lens, q_pos, pack.dst[:, 0], width=Tq,
+                block_len=bl, pages_per_row=pages, scale=scale, impl=impl)
+                for impl in ("pallas", "scan")}
+            ((kernel, tiling),) = pallas_mode.KERNEL_TILINGS
+            tiling = dict(tiling)
+            err = _max_err(outs["pallas"][:live], outs["scan"][:live])
+            _say(f"{kernel} pallas vs scan, packed queries "
+                 f"[{tiling['packed_queries']}, {H}, {R} | {cols}] "
+                 f"(rope={Dr}) Tq={Tq} seq_lens={lens.tolist()} live "
+                 f"columns {adv.tolist()} bf16: grid {tiling['grid']}, up "
+                 f"to {tiling['groups']} groups of pages={tiling['pages']} "
+                 f"a row, tile {tiling['rows']} rows, one-column body "
+                 f"{tiling['one_column_rows']} rows; max abs err {err:.2e} "
+                 f"(tolerance {tol:g})")
+            _require(kernel == LATENT_KERNEL and np.isfinite(err)
+                     and err <= tol,
+                     f"{LATENT_KERNEL} H={H} Tq={Tq} within {tol:g}")
 
 
 def _sparse_parity(size: dict):
@@ -726,7 +743,7 @@ def _kv_write_parity(size: dict):
     from paddle_tpu.ops.attention import _row_writes
     T, B = 16, 16
     w = size["paged_window"]
-    lat = size["paged_latent"]
+    lat = size["paged_latent"][0]
     ring = w["ring_pages"] * 16
     cases = [(g["kv_heads"], g["pages"] * 16 + T, g["head_dim"],
               g["head_dim"], None) for g in size["paged"]]

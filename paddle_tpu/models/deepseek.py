@@ -131,8 +131,8 @@ from ..nn.layer.common import Linear
 from ..nn.layer.layers import Layer, LayerList, parameter_dtype
 from ..nn.layer.moe import DroplessMoE
 from ..nn.layer.norm import LayerNorm
-from ..ops.attention import _NEG_INF, decode_attention, flash_attention, \
-    update_caches, update_kv_cache
+from ..ops.attention import _NEG_INF, decode_attention, \
+    decode_attention_packed, flash_attention, update_caches, update_kv_cache
 from ..ops.index_select import Selection, select, topk_mask
 from .llama import LlamaMLP, RMSNorm, _apply_rope, _rope_cos_sin, \
     yarn_mscale
@@ -387,6 +387,47 @@ class MLAttention(Layer):
         out = self.o_proj(apply(attn, q, self.kv_b_proj(c), k_r, *more))
         return out if self.kind is None else (out, sel)
 
+    def _attend_packed(self, qa, w_kvb, caches, pos, paged, pack, B, T, cos,
+                       sin):
+        """A layer that attends to every key: the queries stay where the
+        step's tokens lie, `[positions, H, .]` (`ops.paged_attention`,
+        "Packed queries"). Under a `TokenPack` that is the packed block,
+        `qa [step_tokens, 1, H * qk]`, padded so that a row's window of T
+        positions from its first fits; else `qa [B, T, H * qk]` as it lies,
+        row b's tokens from b * T. Each token is rotated at its own
+        position, and the absorption, the walk and the value projection run
+        once a position. Returns the context in `qa`'s layout."""
+        cfg = self.config
+        H, nope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                       cfg.v_head_dim)
+        w = w_kvb.reshape(cfg.kv_lora_rank, H, nope + dv)
+        if pack is None:
+            qt = qa.reshape(B * T, H, self.qk_dim)
+            at = jnp.broadcast_to(
+                jnp.reshape(pos, (-1, 1)) + jnp.arange(T, dtype=jnp.int32),
+                (B, T)).reshape(-1)
+            starts = jnp.arange(B, dtype=jnp.int32) * T
+        else:
+            qt = jnp.pad(qa[:, 0], ((0, T - 1), (0, 0))).reshape(
+                -1, H, self.qk_dim)
+            at, starts = jnp.pad(pack.pos, (0, T - 1)), pack.dst[:, 0]
+        cos_t, sin_t = (jnp.take(t, at, axis=0, mode="clip").astype(
+            qa.dtype)[:, None] for t in (cos, sin))            # [P, 1, dr]
+        # to RoPE a position is a sequence of one token
+        q_rot = _apply_rope(self._rotary(qt[:, :, None, nope:]), cos_t,
+                            sin_t)[:, :, 0]
+        q_rot = jnp.pad(q_rot, ((0, 0), (0, 0),
+                                (0, caches[1].shape[3] - q_rot.shape[2])))
+        q_lat = jnp.einsum("thd,rhd->thr", qt[..., :nope],
+                           w[..., :nope]).astype(qa.dtype)
+        out = decode_attention_packed(
+            q_lat, q_rot, caches[0], caches[1], pos, starts, T,
+            scale=cfg.softmax_scale, paged=paged)
+        out = jnp.einsum("thr,rhd->thd", out, w[..., nope:])
+        out = out.reshape(-1, H * dv).astype(qa.dtype)
+        return out.reshape(B, T, H * dv) if pack is None \
+            else out[:qa.shape[0], None]
+
     def _forward_cached(self, q, c, k_r, index, cache, pos, paged, pack,
                         sel):
         """The absorbed form through the latent cache `(c [B, 1, L, rank],
@@ -412,19 +453,27 @@ class MLAttention(Layer):
             caches, pos_, more = rest[:n_cache], rest[n_cache], \
                 rest[n_cache + 1:]
             if pack is not None:
-                qa, ca, kr = (pack.unpack(a) for a in (qa, ca, kr))
+                # the step's latents and keys go to their slots' rows; the
+                # queries of a layer that attends to every key stay packed
+                ca, kr = (pack.unpack(a) for a in (ca, kr))
+                if self.kind is not None:
+                    qa = pack.unpack(qa)
                 if full:
                     more = tuple(pack.unpack(a) for a in more)
                 pos_ = pack.slot_pos
-            B, T = qa.shape[:2]
-            qh = jnp.swapaxes(qa.reshape(B, T, H, qk), 1, 2)   # [B,H,T,qk]
-            cos_t, sin_t = _rope_rows(
-                *_rope_cos_sin(caches[0].shape[2], dr, rope), pos_, T,
-                qa.dtype)
+            B, T = ca.shape[:2]
+            cos, sin = _rope_cos_sin(caches[0].shape[2], dr, rope)
+            cos_t, sin_t = _rope_rows(cos, sin, pos_, T, qa.dtype)
             pad = ((0, 0), (0, 0), (0, 0), (0, caches[1].shape[3] - dr))
-            q_rot = jnp.pad(_apply_rope(self._rotary(qh[..., nope:]), cos_t,
-                                        sin_t), pad)
             k_rot = jnp.pad(_apply_rope(self._rotary(kr[:, None]), cos_t,
+                                        sin_t), pad)
+            if self.kind is None:
+                caches = update_kv_cache(*caches, ca[:, None], k_rot, pos_)
+                return (self._attend_packed(qa, w_kvb, caches, pos_, paged,
+                                            pack, B, T, cos, sin),) \
+                    + tuple(caches)
+            qh = jnp.swapaxes(qa.reshape(B, T, H, qk), 1, 2)   # [B,H,T,qk]
+            q_rot = jnp.pad(_apply_rope(self._rotary(qh[..., nope:]), cos_t,
                                         sin_t), pad)
             if full:
                 qi, ki, w = more

@@ -1069,12 +1069,17 @@ class TokenPack(NamedTuple):
     block, T rows of width one, token t at its own position `pos[t]`: to
     that code a packed step is a batch of T one-token sequences. Attention
     alone needs a token's slot (its cache row, its page table, its chunk
-    mates), so the two decoder stacks `unpack` q/k/v into `[N, C, ·]` just
+    mates), so the decoder stacks `unpack` q/k/v into `[N, C, ·]` just
     before RoPE, the KV write and `decode_attention`, run those at
     `slot_pos` exactly as an unpacked step does, and `pack` the context
-    again. Packed positions past the live ones repeat slot 0's column 0
-    and columns past `adv` read packed position T - 1: both hold finite
-    values nobody reads, as the padding of an unpacked step does.
+    again. A latent layer that attends to every key (`models/deepseek.py`)
+    unpacks only what it writes to the cache: its queries stay packed
+    through RoPE (each token at its own `pos`) and the walk, which finds a
+    slot's queries by their first packed position, `dst[:, 0]`
+    (`packed_latent_attention`). Packed positions past the live ones
+    repeat slot 0's column 0 and columns past `adv` read packed position
+    T - 1: both hold finite values nobody reads, as the padding of an
+    unpacked step does.
     """
     src: jax.Array       # [T] n * C + c of the column token t holds
     dst: jax.Array       # [N, C] packed position of column c of slot n
@@ -1309,6 +1314,28 @@ def decode_attention(q, k_cache, v_cache, pos, scale=None, paged=None,
     return walk(q, k_cache, v_cache, table, seq_lens, q_pos,
                 block_len=DEFAULT_KV_BLOCK, pages_per_row=nb, scale=scale,
                 window=window, q_rope=q_rope)
+
+
+def decode_attention_packed(q, q_rope, c_cache, r_cache, pos, starts,
+                            width: int, scale: float, paged=None):
+    """`decode_attention(q_rope=)` for a layer whose queries stay
+    token-major, `q [P, H, R]` and `q_rope [P, H, Dr]`: row b's are the
+    positions from `starts[b]`, `width` at most
+    (`ops.paged_attention.packed_latent_attention`). `pos`, `paged` and the
+    caches as `decode_attention`'s; the result is `[P, H, R]`."""
+    from . import paged_attention as pa
+    if paged is not None:
+        return pa.packed_latent_attention(
+            q, q_rope, c_cache, r_cache, paged.table, paged.seq_lens,
+            jnp.asarray(pos), starts, width=width,
+            block_len=int(paged.block_len),
+            pages_per_row=int(paged.pages_per_row), scale=scale)
+    (c_cache, r_cache), table, seq_lens, q_pos, nb = contiguous_paged(
+        (c_cache, r_cache), pos, width)
+    return pa.packed_latent_attention(
+        q, q_rope, c_cache, r_cache, table, seq_lens, q_pos, starts,
+        width=width, block_len=pa.DEFAULT_KV_BLOCK, pages_per_row=nb,
+        scale=scale)
 
 
 def contiguous_paged(caches, pos, T: int):

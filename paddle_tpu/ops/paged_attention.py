@@ -86,8 +86,9 @@ one-token tail, hold one live column of the tile's `Tq`: fifteen sixteenths
 of the rows a group computes would be read by nobody. Where the tile is
 smaller with one column than with all of them (`_one_column_rows`, from the
 shapes alone: `Tq` > 1, no `sel`, and `fold` rows take fewer packed sublane
-tiles than `fold * Tq`: every GQA, multi-query and latent tile of a step,
-not an MHA tile in bf16, whose sixteen rows are one packed tile either way)
+tiles than `fold * Tq`: every GQA and multi-query tile of a step,
+not an MHA tile in bf16, whose sixteen rows are one packed tile either way;
+a dense latent layer's tile always, in its own way: "Packed queries" below)
 the kernel's trace holds the group's arithmetic twice, each behind a
 `pl.when` on `lens - pos == 1`, read on the scalar core per grid step: such
 a row takes column 0 of its q tile out once, into `[heads, fold, D]`
@@ -128,7 +129,42 @@ query heads are one "GQA group" over one KV head whose K is 576 wide and
 whose V is its first 512 columns, already in VMEM. The same walk, pipeline
 and masking under the `pallas_call` name `paged_latent`; `_choose_tile`
 folds as many heads into a tile's rows as the budget holds (32 x 16 rows at
-the serve cell's shape, so G = 2 and the small page is fetched twice).
+the serve cell's shape, so G = 2 and the small page is fetched twice). In
+the kernel this head-major form is the sparse layers' alone ("A
+selection"); a layer that attends to every key has the next one.
+
+Packed queries (PR 50). A dense latent layer's queries never leave the
+token block they were projected on (`packed_latent_attention`): `q [P, H,
+R]` and `q_rope [P, H, Dr]` are token-major, a `start` a row (scalar-
+prefetched beside table / lens / pos) names the row's first position, and
+the result comes back `[P, H, R]`. Under a `TokenPack` P is the step's
+packed block (512 positions for 256 slots x 16 columns, and `Tq` - 1 of
+pad for the last row's window); an engine that does not pack hands its
+`[N, C, .]` block viewed `[N x C, .]`, row n from n x C; the head-major
+entry (`ragged_paged_attention(q_rope=)`, no selection) transposes into it.
+So RoPE and the two absorbed products round the walk run once a packed
+position, not once a column of every slot. In that layout the token axis
+is the array's leading axis and the tiled axes are `(H, width)`: a window
+of `Tq` positions at any start is an aligned window (what a token of a
+packed bf16 slab is not), and flattened it is `[Tq x heads, width]`, row r
+token `r // heads` (the one thing the mask arithmetic needs to know). The
+one KV head makes the transposition a GQA tile needs unnecessary. Queries
+and result stay in HBM and move by the kernel's own copies: a row's q
+tiles are set going a grid step ahead (two buffers), the result's write is
+awaited before the next one starts. A row with one live column moves one
+position in and one out, and its queries are the tile's first `heads` rows
+as they lie: no `column0` scratch, no reshape, no zero columns. A wide row
+writes all `Tq` positions from its start; those past its live columns are
+the next rows' and are written again, after it (the grid is sequential and
+the writes are ordered), so no row's live position holds a neighbour's dead
+column; a row with no live column (a free slot, a deferred prefill row)
+walks no group and writes nothing, and the result starts as zeros aliased
+to an operand. A tile's head slice is every
+head or whole packed sublane tiles of heads (32 of A.X-K1's 64); a row
+wider than `_PACKED_COLUMNS` (a whole prompt through `generate()`) is
+walked as rows of that many columns, so the tile is the engine's whatever
+the prompt. On the CPU the entry unpacks the rows and calls `_scan_impl` as
+it stands, which is also what the kernel is compared with.
 
 A selection (PR 39). A layer of learned sparse attention
 (`ops/index_select.py`) attends to the `topk` keys its indexer chose for
@@ -248,7 +284,7 @@ def _tile_bytes(heads: int, rows: int, block_len: int, D: int,
 
 
 def _choose_tile(H: int, Hkv: int, Tq: int, block_len: int, D: int,
-                 itemsize: int):
+                 itemsize: int, whole: int = 1):
     """(heads, fold): the tile of one grid step, from the shapes alone.
 
     A tile holds `heads` KV heads, each with `fold` of its `n_rep` query
@@ -256,10 +292,13 @@ def _choose_tile(H: int, Hkv: int, Tq: int, block_len: int, D: int,
     wins: every KV head with its whole GQA group where that fits (G = 1),
     else fewer KV heads, else one KV head with a part of its group (the
     group's pages are then fetched once per part). G = H // (heads*fold).
-    The keys of a step are not its to choose (`_GROUP_KEYS`)."""
+    The keys of a step are not its to choose (`_GROUP_KEYS`). A part of
+    a group is a multiple of `whole` heads (a packed tile's head slice is
+    whole sublane tiles of `[positions, H, .]`)."""
     n_rep = H // Hkv
     tiles = [(h, n_rep) for h in range(Hkv, 0, -1) if Hkv % h == 0]
-    tiles += [(1, f) for f in range(n_rep - 1, 0, -1) if n_rep % f == 0]
+    tiles += [(1, f) for f in range(n_rep - 1, 0, -1)
+              if n_rep % f == 0 and f % whole == 0]
     for heads, fold in tiles:
         if (_tile_bytes(heads, fold * Tq, block_len, D, itemsize)
                 <= _VMEM_BUDGET):
@@ -389,9 +428,10 @@ def _head_dot(a, b, a_dim, b_dim):
     return jax.vmap(lambda x, y: _dot(x, y, a_dim, b_dim))(a, b)
 
 
-def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
+def _paged_kernel(table_ref, lens_ref, pos_ref, *refs,
                   block_len, pages, pages_per_row, n_groups, parts, scale,
-                  Tq, window=None, latent=False, masked=False, narrow=0):
+                  Tq, window=None, latent=False, masked=False, narrow=0,
+                  packed=0):
     """Grid (B, G); one step is one slot's whole walk for every head of
     the tile: a loop over the row's live groups of `pages` consecutive
     logical pages. q/o tiles [heads, fold*Tq, D] (a KV head's query heads
@@ -421,19 +461,44 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
     groups over that in the first `narrow` rows of the softmax state and
     the accumulator, and writes the result to column 0's rows of the o
     tile and zeros to the fifteen dead columns'; every other row runs the
-    wide body. The walk, the copies and the wait are shared."""
+    wide body. The walk, the copies and the wait are shared.
+
+    With `packed` (a dense latent layer's call: the query heads of a
+    tile) the queries and the result are token-major and stay in HBM,
+    `[positions, H, .]`, and a fourth prefetched vector, `start`, names
+    each row's first position: a row's q tile is the `Tq` positions from
+    there, `packed` heads of each, `[Tq, packed, .]` in VMEM and flattened
+    `[Tq * packed, .]`, row r token `r // packed`. The tiles come by the
+    kernel's own copies, set going a grid step ahead into one of two
+    buffers, and the result leaves by one, so a row with one live column
+    moves one position in and one out and its queries are the tile's first
+    `packed` rows as they lie: no `column0` scratch. A wide row writes all
+    `Tq` positions from its start; those past its live columns are the next
+    rows', which write after it: the grid is sequential and a write is
+    awaited before the next one starts. A row with no live column walks no
+    group and writes nothing (the result arrives as zeros, aliased to an
+    operand)."""
     refs = list(refs)
+    start_ref = refs.pop(0) if packed else None
+    q_ref = refs.pop(0)
     qr_ref = refs.pop(0) if latent else None
     k_hbm, v_hbm = refs.pop(0), refs.pop(0)
     sel_hbm = refs.pop(0) if masked else None
+    if packed:
+        refs.pop(0)           # the zeros the result is aliased to
     o_ref, kbuf, vbuf = refs.pop(0), refs.pop(0), refs.pop(0)
     selbuf = refs.pop(0) if masked else None
     sem, slot_ref, acc_ref, m_ref, l_ref = refs[:5]
     tiles = [q_ref] + ([qr_ref] if latent else [])
-    column0 = refs[5:]        # column 0 of each: the one-column body's q
+    if packed:
+        # the two q tiles' buffers (one a parity of the grid step), the
+        # result's, their semaphores, and the kind of write in flight
+        qbufs, (obuf, qsem, osem, out_ref) = refs[5:7], refs[7:]
+    else:
+        column0 = refs[5:]    # column 0 of each: the one-column body's q
     b, g = pl.program_id(0), pl.program_id(1)
     B, G = pl.num_programs(0), pl.num_programs(1)
-    heads, rows = q_ref.shape[1], q_ref.shape[2]
+    heads, rows = (1, Tq * packed) if packed else q_ref.shape[1:3]
     ring_pages = table_ref.shape[1]
     keys = pages * block_len
 
@@ -442,6 +507,8 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
         row's length cannot contribute (every column masked -> exact
         no-op), so the walk ends with the group that holds the length."""
         end = (lens_ref[row] + keys - 1) // keys
+        if packed:            # no live column: nobody to walk for
+            end = jnp.where(lens_ref[row] > pos_ref[row], end, 0)
         if window is None:
             return 0, jnp.minimum(end, n_groups)
         first = _first_block(pos_ref[row], window, block_len) // pages
@@ -502,9 +569,71 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
     hi = jnp.maximum(n, lo + 1)
     slot0 = jnp.where(opening, 0, slot_ref[0])   # holds this row's group 0
 
+    if packed:
+        par = (b * G + g) % 2                 # this grid step's q buffers
+
+        def q_copies(row, tile, buf, n):
+            """`row`'s first `n` positions, the heads of `tile`, of each
+            query operand into buffer `buf`."""
+            return [pltpu.make_async_copy(
+                hbm.at[pl.ds(start_ref[row], n),
+                       pl.ds(pl.multiple_of(tile * packed, packed), packed)],
+                to.at[buf, pl.ds(0, n)], qsem.at[buf])
+                for hbm, to in zip(tiles, qbufs)]
+
+        def fetch_q(row, tile, buf):
+            """Set `row`'s q tiles going: one position of a row with one
+            live column, `Tq` of any other."""
+            one = (lens_ref[row] - pos_ref[row] == 1) if narrow else False
+
+            @pl.when(jnp.logical_not(one))
+            def _():
+                for copy in q_copies(row, tile, buf, Tq):
+                    copy.start()
+
+            if narrow:
+                @pl.when(one)
+                def _():
+                    for copy in q_copies(row, tile, buf, 1):
+                        copy.start()
+
+        def o_copy(n):
+            """The first `n` positions of the result's buffer to this
+            row's place in the result."""
+            return pltpu.make_async_copy(
+                obuf.at[pl.ds(0, n)],
+                o_ref.at[pl.ds(start_ref[b], n),
+                         pl.ds(pl.multiple_of(g * packed, packed), packed)],
+                osem.at[0])
+
+        def drain():
+            """Await the write in flight, if any: `out_ref` holds the
+            positions it carries."""
+            for n in (1, Tq) if narrow else (Tq,):
+                @pl.when(out_ref[0] == n)
+                def _():
+                    o_copy(n).wait()
+
+        def put(n, value):
+            """`value [1, n * packed, D]` to the row's first `n` positions,
+            behind every earlier row's write."""
+            drain()
+            obuf[pl.ds(0, n)] = value.reshape(n, packed, -1).astype(
+                obuf.dtype)
+            o_copy(n).start()
+            out_ref[0] = n
+
+        @pl.when(opening)
+        def _():
+            out_ref[0] = 0
+
     # which body a grid step runs: decorators round each body's pieces (a
     # trace that holds the wide body alone runs it for every row)
-    if narrow:
+    if packed:
+        adv = lens_ref[b] - pos_ref[b]
+        wide_row = pl.when(adv > (1 if narrow else 0))
+        one_column_row = pl.when(adv == 1)
+    elif narrow:
         one = lens_ref[b] - pos_ref[b] == 1
         wide_row, one_column_row = pl.when(jnp.logical_not(one)), pl.when(one)
     else:
@@ -529,12 +658,16 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
         @one_column_row
         def _():
             reset(narrow)
+            if packed:        # its queries: the tile's first rows
+                return
             for ref, to in zip(tiles, column0):   # row r is token r mod Tq
                 to[...] = ref[0].reshape(
                     heads, narrow, Tq, ref.shape[3])[:, :, 0]
 
     t = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
-    if rows != Tq:                            # folded row r is token r mod Tq
+    if packed:                                # row r is token r // packed
+        t = t // packed
+    elif rows != Tq:                          # folded row r is token r mod Tq
         t = jax.lax.rem(t, Tq)
     row_pos = pos_ref[b] + t
     lane = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
@@ -588,6 +721,16 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
                 # counts bytes: one wait for the buffer's size takes all
                 pltpu.make_async_copy(buf.at[slot], buf.at[slot],
                                       sem.at[slot]).wait()
+            if packed:
+                wide_row(lambda: attend(
+                    i, slot, rows,
+                    lambda k: qbufs[k][par].reshape(1, rows, -1), row_pos,
+                    lane))
+                if narrow:                    # the tile's first rows
+                    one_column_row(lambda: attend(
+                        i, slot, narrow, lambda k: qbufs[k][par, 0][None],
+                        pos_ref[b], lane1))
+                return
             wide_row(lambda: attend(
                 i, slot, rows, lambda k: tiles[k][0], row_pos, lane))
             if narrow:                        # every row is token 0
@@ -596,6 +739,25 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
                     pos_ref[b], lane1))
         return carry
 
+    if packed:
+        # the next grid step's q tiles, a whole row ahead, into the buffers
+        # the last step has done with (the grid's first step brings its
+        # own); then this row's, long since there
+        @pl.when(opening & (n > 0))
+        def _():
+            fetch_q(b, g, par)
+
+        @pl.when(has_next)
+        def _():
+            fetch_q(nrow, ng, 1 - par)
+
+        for kind, n_q in ((wide_row, Tq),) + (
+                ((one_column_row, 1),) if narrow else ()):
+            @kind
+            def _():
+                for copy in q_copies(b, g, par, n_q):
+                    copy.wait()
+
     jax.lax.fori_loop(lo, hi, group, None)
     slot_ref[0] = (slot0 + hi) % 2
 
@@ -603,6 +765,16 @@ def _paged_kernel(table_ref, lens_ref, pos_ref, q_ref, *refs,
         acc, _, l = state(n)
         l = jnp.maximum(l[...], 1e-30)
         return acc[...] / l
+
+    if packed:
+        wide_row(lambda: put(Tq, result(rows)))
+        if narrow:
+            one_column_row(lambda: put(1, result(narrow)))
+
+        @pl.when((b == B - 1) & (g == G - 1))
+        def _():
+            drain()
+        return
 
     @wide_row
     def _():
@@ -688,6 +860,59 @@ def _paged_call(q, k_cache, v_cache, block_table, seq_lens, q_pos,
     )(jnp.maximum(block_table, 0), seq_lens, q_pos, *queries, k_cache,
       v_cache, *((sel,) if masked else ()))
     return out.reshape(B, H, Tq, D)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_len", "pages_per_row", "scale", "fold", "n_groups", "interpret",
+    "narrow", "Tq"))
+def _packed_call(q, q_rope, c_cache, r_cache, block_table, seq_lens, q_pos,
+                 starts, *, block_len, pages_per_row, scale, fold,
+                 n_groups, interpret, narrow, Tq):
+    """`_paged_call` for a dense latent layer: `q [positions, H, R]` and
+    `q_rope [positions, H, Dr]` token-major, row b's tile the `Tq`
+    positions from `starts[b]`, `fold` heads of each. Queries and result
+    stay in HBM and move by the kernel's own copies; the result starts as
+    zeros (positions no row writes stay so), aliased to an operand."""
+    P, H, D = q.shape
+    B = block_table.shape[0]
+    pages = _group_pages(block_len)
+    keys, rows = pages * block_len, fold * Tq
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, H // fold),
+        in_specs=[hbm] * 5,
+        out_specs=hbm,
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, keys, c_cache.shape[3]), c_cache.dtype),
+            pltpu.VMEM((2, 1, keys, r_cache.shape[3]), r_cache.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),       # the buffer in flight
+            pltpu.VMEM((1, rows, D), jnp.float32),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+            pltpu.VMEM((1, rows, 1), jnp.float32),
+            pltpu.VMEM((2, Tq, fold, D), q.dtype),
+            pltpu.VMEM((2, Tq, fold, q_rope.shape[2]), q_rope.dtype),
+            pltpu.VMEM((Tq, fold, D), q.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SMEM((1,), jnp.int32),       # positions being written
+        ],
+    )
+    kernel = functools.partial(
+        _paged_kernel, block_len=block_len, pages=pages,
+        pages_per_row=pages_per_row, n_groups=n_groups, parts=H // fold,
+        scale=scale, Tq=Tq, latent=True, narrow=narrow, packed=fold)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((P, H, D), q.dtype),
+        input_output_aliases={8: 0},           # behind the four vectors
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=LATENT_KERNEL,
+    )(jnp.maximum(block_table, 0), seq_lens, q_pos, starts, q, q_rope,
+      c_cache, r_cache, jnp.zeros((P, H, D), q.dtype))
 
 
 def _pallas_impl(q, k_cache, v_cache, block_table, seq_lens, q_pos,
@@ -798,6 +1023,16 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
         return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                        block_len, pages_per_row, scale, None, q_rope, sel,
                        **more)
+    if q_rope is not None and impl == "pallas":
+        # the kernel's one form for a layer that attends to every key:
+        # token-major, row b's positions from b * Tq
+        out = packed_latent_attention(
+            *(jnp.swapaxes(x, 1, 2).reshape(B * Tq, H, -1)
+              for x in (q, q_rope)), k_cache, v_cache, block_table,
+            seq_lens, q_pos, jnp.arange(B, dtype=jnp.int32) * Tq, width=Tq,
+            block_len=block_len, pages_per_row=pages_per_row, scale=scale,
+            impl=impl)
+        return jnp.swapaxes(out.reshape(B, Tq, H, D), 1, 2)
     if q_rope is not None:
         return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                        block_len, pages_per_row, scale, None, q_rope)
@@ -806,6 +1041,85 @@ def ragged_paged_attention(q, k_cache, v_cache, block_table, seq_lens,
                        block_len, pages_per_row, scale)
     return impl_fn(q, k_cache, v_cache, block_table, seq_lens, q_pos,
                    block_len, pages_per_row, scale, int(window))
+
+
+# Columns of a packed latent row in the kernel: a wider row (a whole prompt
+# through `generate()`) is walked as rows of this many, each with its own
+# start, position and length over the one table row. Chunks of a prompt
+# give every position the bits of the whole (module docstring, Numerics),
+# and the tile stays the engine's whatever the prompt.
+_PACKED_COLUMNS = 16
+
+
+def packed_latent_attention(q, q_rope, c_cache, r_cache, block_table,
+                            seq_lens, q_pos, starts, *, width: int,
+                            block_len: int, pages_per_row: int,
+                            scale: float, impl: str = None):
+    """`ragged_paged_attention(q_rope=)` with the queries left where a
+    step's tokens lie (module docstring, "Packed queries").
+
+    q [P, H, R] (the key projection absorbed) and q_rope [P, H, Dr] are
+    token-major: row b's queries are the `adv[b] = seq_lens[b] - q_pos[b]`
+    positions from `starts[b] [B]`, at most `width` of them, and every
+    window `[starts[b], starts[b] + width)` of a row with a live column
+    lies inside P (the caller pads). c_cache / r_cache, block_table,
+    seq_lens, q_pos, block_len, pages_per_row, scale, impl: as there.
+    Returns [P, H, R]: at `starts[b] + t`, t < adv[b], what
+    `ragged_paged_attention` gives row b's column t; a position no row owns
+    holds a finite value nobody reads."""
+    P, H, D = q.shape
+    B = block_table.shape[0]
+    block_table = jnp.asarray(block_table, jnp.int32)
+    seq_lens = jnp.asarray(seq_lens, jnp.int32)
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    starts = jnp.asarray(starts, jnp.int32)
+    if impl is None:
+        impl = "scan" if pallas_mode.platform() == "cpu" else "pallas"
+    if impl == "scan":
+        # the reference: the windows unpacked, the scan as it stands
+        pallas_mode.count(LATENT_KERNEL, "scan")
+        t = jnp.arange(width, dtype=jnp.int32)
+        at = starts[:, None] + t                              # [B, width]
+        rows = [jnp.swapaxes(jnp.take(x, at, axis=0, mode="clip"), 1, 2)
+                for x in (q, q_rope)]                   # [B, H, width, .]
+        out = _scan_impl(rows[0], c_cache, r_cache, block_table, seq_lens,
+                         q_pos, block_len, pages_per_row, scale, None,
+                         rows[1])
+        live = t < (seq_lens - q_pos)[:, None]
+        return jnp.zeros_like(q).at[jnp.where(live, at, P)].set(
+            jnp.swapaxes(out, 1, 2), mode="drop")
+    if impl != "pallas":
+        raise ValueError(f'impl must be "scan" or "pallas", got {impl!r}')
+    if width > _PACKED_COLUMNS:
+        j = jnp.arange(-(-width // _PACKED_COLUMNS),
+                       dtype=jnp.int32) * _PACKED_COLUMNS
+        starts, q_pos = ((x[:, None] + j).reshape(-1)
+                         for x in (starts, q_pos))
+        seq_lens = jnp.minimum(jnp.repeat(seq_lens, len(j)),
+                               q_pos + _PACKED_COLUMNS)
+        block_table = jnp.repeat(block_table, len(j), axis=0)
+        # the last piece's window may run past the prompt's positions
+        pad = ((0, _PACKED_COLUMNS - 1), (0, 0), (0, 0))
+        return packed_latent_attention(
+            jnp.pad(q, pad), jnp.pad(q_rope, pad), c_cache, r_cache,
+            block_table, seq_lens, q_pos, starts, width=_PACKED_COLUMNS,
+            block_len=block_len, pages_per_row=pages_per_row, scale=scale,
+            impl=impl)[:P]
+    pages = _group_pages(block_len)
+    n_groups = -(-block_table.shape[1] // pages)
+    _, fold = _choose_tile(H, 1, width, block_len, D + q_rope.shape[2],
+                           q.dtype.itemsize, whole=32 // q.dtype.itemsize)
+    narrow = fold if width > 1 else 0
+    pallas_mode.note_tiling(LATENT_KERNEL, grid=(B, H // fold),
+                            groups=n_groups, pages=pages, heads=1,
+                            rows=fold * width, one_column_rows=narrow,
+                            packed_queries=P)
+    return _packed_call(
+        q, q_rope, c_cache, r_cache, block_table, seq_lens, q_pos, starts,
+        block_len=block_len, pages_per_row=pages_per_row,
+        scale=float(scale), fold=fold, n_groups=n_groups,
+        interpret=pallas_mode.interpret(LATENT_KERNEL), narrow=narrow,
+        Tq=width)
 
 
 def sparse_latent_attention(q, c_cache, r_cache, block_table, seq_lens,

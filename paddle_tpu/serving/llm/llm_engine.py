@@ -176,7 +176,8 @@ from ..metrics import LLMMetrics, SLO_CLASSES
 from ..supervisor import (DispatchFailedError, DispatchHungError,  # noqa: F401
                           EngineSupervisor)
 from .host_kv import HostKVPool
-from .kv_pool import INDEXED, SlotPagedKVPool, SlotsExhaustedError
+from .kv_pool import (INDEXED, LATENT, RECURRENT, SlotPagedKVPool,
+                      SlotsExhaustedError)
 from .lora import AdapterBank, AdapterError
 from .prefix_cache import PrefixCache
 from .sampling import (GREEDY, SamplingParams, SlotSamplingTable,
@@ -767,6 +768,14 @@ class LLMEngine:
             self.config.num_slots * self.config.prefill_chunk,
             max(self.config.num_slots * window + self.config.prefill_chunk,
                 MIN_STEP_TOKENS))
+        # query positions a step's attention computes, summed over the
+        # layers that attend: a latent layer that attends to every key
+        # keeps its queries on the packed block, every other kind unpacks
+        # them to `[slots, chunk]`
+        self._attn_positions = sum(
+            self.step_tokens if kind == LATENT
+            else self.config.num_slots * self.config.prefill_chunk
+            for kind in self.pool.layer_kinds if kind != RECURRENT)
         # sparse experts: the model's dropless expert layers, found by
         # their type (nothing here knows which model holds them). Their
         # per-layer per-expert totals of live assignments `[L, E]` live on
@@ -3062,7 +3071,8 @@ class LLMEngine:
                         self._moe_routed += live_tokens
                     self.metrics.on_step_tokens(live_tokens,
                                                 self.step_tokens, deferred,
-                                                vacant_queued)
+                                                vacant_queued,
+                                                self._attn_positions)
                     self.metrics.on_paged_rows(
                         one_column, int(np.count_nonzero(adv > 1)))
                     if started:

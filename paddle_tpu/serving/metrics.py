@@ -294,6 +294,7 @@ class LLMMetrics(ServingMetrics):
                               "sampler_filter_steps": 0,
                               "step_tokens_live": 0,
                               "step_tokens_computed": 0,
+                              "attn_query_positions": 0,
                               "prefill_rows_deferred": 0,
                               "slot_steps_vacant_queued": 0,
                               "first_tokens": 0, "ttft_steps": 0,
@@ -543,7 +544,7 @@ class LLMMetrics(ServingMetrics):
             self.counters["sampler_filter_steps"] += 1
 
     def on_step_tokens(self, live: int, computed: int, deferred: int,
-                       vacant_queued: int = 0):
+                       vacant_queued: int = 0, attn_positions: int = 0):
         """One committed unified step: `live` of the `computed` positions
         it ran held a token (`computed` is the engine's `step_tokens`:
         the packed width, or slots x chunk where nothing is packed), and
@@ -552,10 +553,15 @@ class LLMMetrics(ServingMetrics):
         the share of the step's arithmetic that somebody reads.
         `vacant_queued` of its slots carried no row while as many
         requests were queued: over `unified_steps` x slots, the share of
-        the step's rows lost to slot turnover."""
+        the step's rows lost to slot turnover. `attn_positions`: the query
+        positions its attention computed, summed over the layers that
+        attend (`step_tokens` for a layer whose queries stay on the packed
+        block, slots x chunk for one that unpacks them); `step_tokens_live`
+        x those layers over it is the share somebody reads there."""
         with self._lock:
             self.counters["step_tokens_live"] += int(live)
             self.counters["step_tokens_computed"] += int(computed)
+            self.counters["attn_query_positions"] += int(attn_positions)
             self.counters["prefill_rows_deferred"] += int(deferred)
             self.counters["slot_steps_vacant_queued"] += int(vacant_queued)
 
@@ -889,7 +895,8 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_sampler_filter_steps_total",
                  s["sampler_filter_steps"])
         for name in ("step_tokens_live", "step_tokens_computed",
-                     "prefill_rows_deferred", "slot_steps_vacant_queued",
+                     "attn_query_positions", "prefill_rows_deferred",
+                     "slot_steps_vacant_queued",
                      "first_tokens", "ttft_steps", "paged_rows_one_column",
                      "paged_rows_wide", "steps_overlapped",
                      "rows_discarded", "pool_copies", "pool_lost"):

@@ -262,7 +262,8 @@ def test_engine_streams_equal_generate(tiny, block_len):
 
 def test_packed_step_serves_latent_layers(tiny):
     """An engine wider than 512 positions packs its live tokens: the MLA
-    layer unpacks for RoPE, the cache write and the walk."""
+    layer unpacks the latents and rotary keys for the cache write; its
+    queries stay on the packed block through RoPE and the walk."""
     eng = _engine(tiny, num_slots=40, tokens=64)
     assert eng.step_tokens == 512 < 40 * 16
     prompts = _prompts([24] * 40, seed=7)
@@ -272,6 +273,116 @@ def test_packed_step_serves_latent_layers(tiny):
         want = np.asarray(generate(tiny, p[None], max_new_tokens=3).data)
         assert np.asarray(h.result(timeout=0)).tolist() \
             == want[0, len(p):].tolist()
+    # three latent layers, each over the packed block's 512 positions and
+    # not the slots' 640: what attention computes is what the step computes
+    snap = eng.metrics.snapshot()
+    assert snap["attn_query_positions"] == 3 * snap["step_tokens_computed"] \
+        == 3 * 512 * snap["unified_steps"]
+
+
+def _parents_cached_attention(layer, hidden, caches, pos, paged):
+    """PR 49's `MLAttention._forward_cached` for a layer without a
+    selection, written out: the step's queries `[N, C, .]` heads first,
+    RoPE, the absorption and the value projection over every column of
+    every slot round `decode_attention(q_rope=)`."""
+    from paddle_tpu.models import deepseek as D
+    from paddle_tpu.ops.attention import decode_attention, update_kv_cache
+    cfg = layer.config
+    H, nope, dr, dv, rank = (
+        cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+        cfg.v_head_dim, cfg.kv_lora_rank)
+    qa, ca, kr = (t.data for t in layer._down(paddle.to_tensor(hidden))[:3])
+    B, T = qa.shape[:2]
+    qh = jnp.swapaxes(qa.reshape(B, T, H, nope + dr), 1, 2)
+    cos_t, sin_t = D._rope_rows(
+        *D._rope_cos_sin(caches[0].shape[2], dr, cfg.rope), pos, T, qa.dtype)
+    pad = ((0, 0), (0, 0), (0, 0), (0, caches[1].shape[3] - dr))
+    q_rot = jnp.pad(D._apply_rope(qh[..., nope:], cos_t, sin_t), pad)
+    k_rot = jnp.pad(D._apply_rope(kr[:, None], cos_t, sin_t), pad)
+    caches = update_kv_cache(*caches, ca[:, None], k_rot, pos)
+    w = layer.kv_b_proj.weight.data.reshape(rank, H, nope + dv)
+    q_lat = jnp.einsum("bhtd,rhd->bhtr", qh[..., :nope], w[..., :nope])
+    out = decode_attention(q_lat, caches[0], caches[1], pos,
+                           scale=cfg.softmax_scale, paged=paged, q_rope=q_rot)
+    out = jnp.einsum("bhtr,rhd->bthd", out, w[..., nope:])
+    return layer.o_proj(paddle.to_tensor(
+        out.reshape(B, T, H * dv))).data, caches
+
+
+@pytest.mark.parametrize("adv", [
+    [1, 16, 3, 1, 0, 16, 1, 7], [0, 1, 1, 0, 16, 5], [16, 16, 1]],
+    ids=["mixed", "free slots", "a full block"])
+def test_packed_queries_give_the_unpacked_forms_context(tiny, adv):
+    """`MLAttention._forward_cached` under a `TokenPack` (queries left on
+    the packed block, one position each) and without one (`[N x C, H, .]`
+    as it lies) against the parent's unpacked form: the same context at
+    every live token and the same caches."""
+    from paddle_tpu.ops.attention import PagedView, token_pack
+    layer = tiny.model.layers[1].self_attn
+    rng = np.random.default_rng(4)
+    adv = np.asarray(adv, np.int32)
+    N, C, bl, nb = len(adv), 16, 8, 12
+    pos = np.where(adv > 0, rng.integers(0, nb * bl - C, N), 0) \
+        .astype(np.int32)
+    T = int(adv.sum())
+    pack = token_pack(jnp.asarray(adv), jnp.asarray(pos), C, T)
+    hidden = jnp.asarray(rng.normal(0, 1, (T, 1, 48)), jnp.float32)
+    caches = tuple(jnp.asarray(rng.normal(0, 1, (N, 1, nb * bl + C, w)),
+                               jnp.float32) for w in (32, 128))
+    # a slot writes its own slab row: its pages are that row's
+    table = np.arange(N * nb, dtype=np.int32).reshape(N, nb)
+    paged = PagedView(jnp.asarray(table), jnp.asarray(pos + adv), bl, nb)
+
+    def run(x, pack):
+        out, new = layer(paddle.to_tensor(x), cache=tuple(
+            paddle.to_tensor(a) for a in caches), pos=jnp.asarray(pos),
+            paged=paged, pack=pack)
+        return out.data, tuple(a.data for a in new)
+    want, want_caches = _parents_cached_attention(
+        layer, pack.unpack(hidden), caches, jnp.asarray(pos), paged)
+    live = np.asarray(pack.live)
+    assert live.all() and np.abs(np.asarray(want)).max() > 0.1
+    for got, got_caches in (run(hidden, pack),
+                            (lambda o, c: (pack.pack(o), c))(
+                                *run(pack.unpack(hidden), None))):
+        np.testing.assert_allclose(np.asarray(got), pack.pack(want),
+                                   atol=1e-5)
+        for a, b in zip(got_caches, want_caches):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5)
+
+
+@pytest.mark.parametrize("num_slots", [3, 34], ids=["unpacked", "packed"])
+def test_the_kernel_serves_the_engines_latent_layers(tiny, monkeypatch,
+                                                     num_slots):
+    """The engine's step with the `paged_latent` kernel in it (interpreted;
+    the CPU's own path is the scan): the model hands it the packed block
+    and the rows' starts (a `TokenPack`'s under 34 slots, n x 16 under 3),
+    prompts in chunks beside decode rows and free slots, and every
+    log-probability is the reference's."""
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
+    walk = PA.packed_latent_attention
+    monkeypatch.setattr(
+        PA, "packed_latent_attention",
+        lambda *a, **kw: walk(*a, **{**kw, "impl": "pallas"}))
+    pallas_mode.KERNEL_TRACES.clear()
+    eng = _engine(tiny, num_slots=num_slots, tokens=64)
+    assert (eng.step_tokens < num_slots * 16) == (num_slots == 34)
+    prompts = _prompts([9, 43, 20, 33][:min(num_slots, 4)], seed=2)
+    handles = [eng.submit(p, max_new_tokens=4, logprobs=True)
+               for p in prompts]
+    _drain(eng)
+    assert pallas_mode.KERNEL_TRACES[(PA.LATENT_KERNEL, "interpret")] >= 3
+    assert not pallas_mode.KERNEL_TRACES[(PA.LATENT_KERNEL, "scan")]
+    for p, h in zip(prompts, handles):
+        out = np.asarray(h.result(timeout=0))
+        ids = np.concatenate([p, out])[None]
+        lg = np.asarray(ref.logits(_weights(tiny), jnp.asarray(ids), REF))[0]
+        lp = np.asarray(jax.nn.log_softmax(lg, -1))
+        np.testing.assert_allclose(
+            h.logprobs_so_far(),
+            [lp[len(p) - 1 + i, t] for i, t in enumerate(out)], atol=TOL)
 
 
 def test_prefix_cache_hits_on_latent_pages(tiny):

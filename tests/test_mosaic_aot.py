@@ -99,6 +99,37 @@ def test_the_paged_walks_hold_the_one_column_body_where_the_tile_shrinks(
     assert "body paged_sparse chunk rows: [497, 505] equations" in tool
 
 
+def test_the_latent_walk_compiles_in_its_packed_layout(tool):
+    """PR 50: a dense latent layer's queries and result are token-major
+    `[positions, H, .]` and move by the kernel's own copies. Pinned for
+    the v5e at the two cells' shapes: Xing's 512 packed positions and 15
+    of pad, 32 heads in one tile, 256 rows whose starts are a
+    `TokenPack`'s; A.X-K1's 32 x 16 positions as they lie, 64 heads in two
+    tiles of 32 (a head slice of whole sublane tiles); and the one-token
+    rows of `generate()`. A call without `q_rope` (Mistral's, OLMoE's,
+    Jamba's, Mellum's shapes) and the sparse layers' two record what they
+    recorded before: no `packed_queries`."""
+    cases = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] paged latent bf16")]
+    assert len(cases) == 3 and all("{'paged_latent': 1}" in c for c in cases)
+    assert any("q=[527, 32, 512 | 128] rows=256 x 16" in c for c in cases)
+    assert any("q=[512, 64, 512 | 128] rows=32 x 16" in c for c in cases)
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling paged_")]
+    latent = [t for t in tilings if t.startswith("tiling paged_latent ")]
+    for grid, one, positions, rows in (("(256, 1)", 32, 527, 512),
+                                       ("(32, 2)", 32, 512, 512),
+                                       ("(32, 1)", 0, 32, 64)):
+        assert any(f"'grid': {grid}, " in t and f"'heads': 1, "
+                   f"'one_column_rows': {one}, 'packed_queries': "
+                   f"{positions}, 'pages': 8, 'rows': {rows}}}" in t
+                   for t in latent), (grid, latent)
+    assert all("packed_queries" in t for t in latent)
+    others = [t for t in tilings if t not in latent]
+    assert len(others) >= 15 and not any("packed_queries" in t
+                                         for t in others)
+
+
 def test_the_mamba2_recurrence_compiles_with_both_bodies(tool):
     """`ssm_update` for the v5e at granite-4.0-h-small's widths (a bf16
     state of 128 x 8,192 a row, 2,048 lanes a grid step): the prefill and

@@ -20,6 +20,15 @@ import jax
 import jax.numpy as jnp
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _drop_compiled_programs():
+    """This file's interpreted kernels are a hundred large CPU programs
+    held by module-level `jax.jit` caches: let them go with the file, so
+    that the worker's next files compile in a process that holds none."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="module")
 def gpt_tiny():
     import paddle_tpu as paddle
@@ -758,7 +767,7 @@ def _mixed_step(walk, dtype, seed=5):
     rng = np.random.RandomState(seed)
     Tq, bl, D = 16, 16, 64
     H, Hkv = {"gqa4": (8, 2), "mqa20": (20, 1), "mha": (4, 4),
-              "window-ring": (8, 2), "latent": (32, 1)}[walk]
+              "window-ring": (8, 2)}[walk]
     #                one  chunk one  free verify one  chunk tail one  free
     ends = np.array([1,   48,   130, 0,   77,    37,  259,  5,   272, 0],
                     np.int32)
@@ -779,27 +788,22 @@ def _mixed_step(walk, dtype, seed=5):
         table = None
         kw.update(pages_per_row=ring_pages, window=window)
     else:
-        Dv = 16 if walk == "latent" else D       # latent: the rotary key
         k = _rand(rng, (B, Hkv, nb * bl, D), dtype)
-        v = _rand(rng, (B, Hkv, nb * bl, Dv), dtype)
+        v = _rand(rng, (B, Hkv, nb * bl, D), dtype)
         table = rng.permutation(B * nb).astype(np.int32).reshape(B, nb)
         kw.update(pages_per_row=nb)
-    qr = _rand(rng, (B, H, Tq, 16), dtype) if walk == "latent" else None
 
     def call(q, impl, columns=slice(None)):
         from paddle_tpu.ops.paged_attention import ragged_paged_attention
-        more = {} if qr is None else dict(q_rope=qr[:, :, columns],
-                                          scale=0.2)
         return np.asarray(ragged_paged_attention(
-            q[:, :, columns], k, v, table, ends, q_pos, impl=impl, **kw,
-            **more).astype(jnp.float32))
+            q[:, :, columns], k, v, table, ends, q_pos, impl=impl,
+            **kw).astype(jnp.float32))
     return call, q, (q_pos, adv), H // Hkv
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("walk", ["gqa4", "mqa20", "mha", "window-ring",
-                                  "latent"])
+@pytest.mark.parametrize("walk", ["gqa4", "mqa20", "mha", "window-ring"])
 def test_one_column_rows_take_the_one_column_body_and_keep_every_bit(
         monkeypatch, walk, dtype):
     """A mixed step through the kernel (interpreted) with the one-column
@@ -821,8 +825,7 @@ def test_one_column_rows_take_the_one_column_body_and_keep_every_bit(
     from paddle_tpu.ops import paged_attention as PA
     from paddle_tpu.ops import pallas_mode
     call, q, (q_pos, adv), fold = _mixed_step(walk, dtype)
-    name = {"window-ring": PA.WINDOW_KERNEL, "latent": PA.LATENT_KERNEL} \
-        .get(walk, "paged_attention")
+    name = PA.WINDOW_KERNEL if walk == "window-ring" else "paged_attention"
     engaged = not (walk == "mha" and dtype == jnp.bfloat16)
 
     def one_column_rows():
@@ -843,9 +846,7 @@ def test_one_column_rows_take_the_one_column_body_and_keep_every_bit(
     scan = call(q, "scan")
 
     exact = not (walk == "mha" and dtype == jnp.float32)
-    # the latent's values are 32 heads' sums over one 64-wide page
-    tol = 2e-2 if dtype == jnp.bfloat16 else 5e-6 if walk == "latent" \
-        else 1e-6
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-6
     assert np.isfinite(both).all()
     for b in range(len(adv)):
         live = both[b, :, :adv[b]]
@@ -884,6 +885,175 @@ def test_a_walk_under_a_selection_holds_the_wide_body_alone():
     H = case["q"].shape[1]
     assert said == {H: 0, H * 16: 0}
     assert PA._one_column_rows(H, 16, 4, False) == H
+
+
+# ---- packed queries (PR 50): a dense latent layer's queries and result
+# ---- stay token-major on the step's packed block ----
+
+# (live columns a row): ISSUE 50's mixed step; free slots between live ones
+# and at both ends; a step that fills its block, whose last row's window
+# runs into the pad; decode rows alone; chunk rows alone
+_PACKED_STEPS = {
+    "mixed": [1, 16, 3, 1, 0, 16, 1, 7, 1, 1, 16, 2],
+    "free-slots": [0, 1, 0, 0, 16, 0, 1, 5, 0],
+    "last-into-pad": [16, 1, 1, 3],
+    "decode-rows": [1] * 9,
+    "chunk-rows": [16] * 3,
+}
+
+
+def _packed_step(adv, H, dtype, seed=11, slack=0):
+    """A packed step's operands for the latent walk at chunk 16 over pages
+    of 16: `adv [N]` live columns a slot at random positions of fragmented
+    tables, the live tokens packed into `T = sum(adv) + slack` positions
+    (`token_pack`) and padded by 15. Returns (call, pack, operands): `call(
+    impl, lens=, starts=, width=, q=, rows=)` runs the packed walk (over
+    the slots `rows`)."""
+    from paddle_tpu.ops.attention import token_pack
+    from paddle_tpu.ops.paged_attention import packed_latent_attention
+    rng = np.random.RandomState(seed)
+    C, bl, nb, R, Dr = 16, 16, 19, 32, 16
+    adv = np.asarray(adv, np.int32)
+    N, T = len(adv), int(adv.sum()) + slack
+    # one key long, inside the first group, past it, at the slot's end
+    ends = np.resize(np.array([0, 37, 130, 259, nb * bl, 48], np.int32), N)
+    ends = np.where(adv > 0, np.maximum(ends, adv), 0).astype(np.int32)
+    ends[np.flatnonzero(adv == 1)[:1]] = 1
+    pos = ends - adv
+    pack = token_pack(jnp.asarray(adv), jnp.asarray(pos), C, T)
+    q, qr = _rand(rng, (T + C - 1, H, R), dtype), \
+        _rand(rng, (T + C - 1, H, Dr), dtype)
+    c = _rand(rng, (N, 1, nb * bl + C, R), dtype)
+    r = _rand(rng, (N, 1, nb * bl + C, Dr), dtype)
+    table = rng.permutation(N * nb).astype(np.int32).reshape(N, nb)
+
+    def call(impl, lens=ends, starts=pack.dst[:, 0], width=C, q=(q, qr),
+             rows=slice(None)):
+        return np.asarray(packed_latent_attention(
+            *q, c, r, table[rows], lens[rows], pos[rows], starts,
+            width=width, block_len=bl, pages_per_row=nb, scale=0.2,
+            impl=impl).astype(jnp.float32))
+    return call, pack, (q, qr, c, r, table, ends, pos)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("heads,tiles", [(32, 1), (64, 2)],
+                         ids=["32-heads-G1", "64-heads-G2"])
+@pytest.mark.parametrize("step", list(_PACKED_STEPS))
+def test_packed_latent_walk_matches_the_scan(monkeypatch, step, heads, tiles,
+                                             dtype):
+    """The kernel (interpreted) over a packed step against `_scan_impl` on
+    the same rows unpacked: every live position within the documented
+    tolerance, whatever its neighbours' dead columns wrote before it; a
+    row with one live column has the bits of today's one-token call (its
+    queries are the tile's first rows as they lie: PR 48's column-0
+    cases); positions no row writes arrive as zeros; `KERNEL_TILINGS`
+    records the packed positions and both bodies; 64 heads split into two
+    tiles of 32 (a tile's head slice is whole sublane tiles)."""
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
+    adv = np.asarray(_PACKED_STEPS[step], np.int32)
+    call, pack, (q, qr, c, r, table, ends, pos) = _packed_step(
+        adv, heads, dtype)
+    if tiles == 2:      # a budget that holds 32 heads' 512 rows, not 64's
+        item = jnp.dtype(dtype).itemsize
+        monkeypatch.setattr(PA, "_VMEM_BUDGET",
+                            PA._tile_bytes(1, 32 * 16, 16, 48, item))
+    pallas_mode.KERNEL_TILINGS.clear()
+    got = call("pallas")
+    (name, tiling), = pallas_mode.KERNEL_TILINGS
+    tiling = dict(tiling)
+    assert name == PA.LATENT_KERNEL
+    assert (tiling["grid"], tiling["rows"], tiling["one_column_rows"],
+            tiling["packed_queries"]) == (
+        (len(adv), tiles), 32 * 16, 32, q.shape[0])
+
+    # the reference: the rows unpacked, the scan as it stands
+    def unpack(x):
+        return jnp.swapaxes(pack.unpack(x[:-15, None]), 1, 2)
+    want = np.asarray(PA._scan_impl(
+        unpack(q), c, r, *map(jnp.asarray, (table, ends, pos)), 16,
+        table.shape[1], 0.2, None,
+        unpack(qr)).astype(jnp.float32))                   # [N, H, 16, R]
+    scan = call("scan")
+    n_live = int(adv.sum())
+    live = np.asarray(pack.live)[:n_live]
+    slot, col = np.asarray(pack.slot)[:n_live], np.asarray(pack.col)[:n_live]
+    assert live.all()
+    assert np.array_equal(scan[:n_live], want[slot, :, col])
+    tol = 2e-2 if dtype == jnp.bfloat16 else 5e-6
+    assert np.isfinite(got).all()
+    assert np.abs(got[:n_live] - want[slot, :, col]).max() <= tol
+    # behind the last row's window nobody writes
+    end = max(int(s) + (16 if a > 1 else 1)
+              for s, a in zip(np.asarray(pack.dst[:, 0]), adv) if a)
+    assert not got[end:].any() and not scan[n_live:].any()
+    # a one-column row: the one-token call's bits at its position
+    ones = np.flatnonzero(adv == 1)
+    if len(ones):
+        at = np.asarray(pack.dst[:, 0])[ones]
+        token = call("pallas", rows=ones, width=1, q=(q[at], qr[at]),
+                     starts=np.arange(len(ones), dtype=np.int32))
+        # (on the CPU a float32 product of 64 rows, the one-token call's
+        # whole tile, rounds otherwise than the same rows 32 at a time)
+        assert np.abs(got[at] - token).max() <= tol
+        if tiles == 1 or dtype == jnp.bfloat16:
+            assert np.array_equal(got[at], token)
+
+
+@pytest.mark.parametrize("heads,tiles", [(32, 1), (64, 2)],
+                         ids=["32-heads-G1", "64-heads-G2"])
+def test_no_live_packed_position_is_changed_by_a_neighbours_dead_columns(
+        monkeypatch, heads, tiles):
+    """A wide row writes sixteen positions from its start whatever its
+    live columns, so those past them are the next rows'. The whole step
+    against its rows run one at a time (every other row given no live
+    column: such a row walks no group and writes nothing): each live
+    position the same bits, and a row alone leaves every position outside
+    its window zero."""
+    from paddle_tpu.ops import paged_attention as PA
+    adv = np.asarray(_PACKED_STEPS["mixed"], np.int32)
+    call, pack, (q, *_, ends, pos) = _packed_step(adv, heads, jnp.float32,
+                                                  slack=3)
+    if tiles == 2:
+        monkeypatch.setattr(PA, "_VMEM_BUDGET",
+                            PA._tile_bytes(1, 32 * 16, 16, 48, 4))
+    whole = call("pallas")
+    starts = np.asarray(pack.dst[:, 0])
+    for n in np.flatnonzero(adv):
+        alone = np.array(call(
+            "pallas", lens=np.where(np.arange(len(adv)) == n, ends, pos)))
+        mine = slice(starts[n], starts[n] + adv[n])
+        assert np.array_equal(alone[mine], whole[mine]), n
+        window = slice(starts[n], starts[n] + (16 if adv[n] > 1 else 1))
+        alone[window] = 0
+        assert not alone.any(), n
+
+
+def test_a_whole_prompt_is_walked_as_rows_of_sixteen_columns():
+    """`generate()`'s prefill through the kernel: a row wider than
+    `_PACKED_COLUMNS` is cut into rows of that many, each with its own
+    start, position and length over the one table row, so the tile is the
+    engine's whatever the prompt; every position within tolerance of the
+    scan over the whole width, a tail that is no whole piece included."""
+    from paddle_tpu.ops import paged_attention as PA
+    from paddle_tpu.ops import pallas_mode
+    rng = np.random.RandomState(4)
+    B, W, H, R, Dr, bl, nb = 2, 37, 32, 32, 16, 8, 6
+    q, qr = _rand(rng, (B * W, H, R)), _rand(rng, (B * W, H, Dr))
+    c, r = _rand(rng, (B, 1, nb * bl, R)), _rand(rng, (B, 1, nb * bl, Dr))
+    args = (c, r, _identity_table(B, nb), np.full(B, W, np.int32),
+            np.zeros(B, np.int32), np.arange(B, dtype=np.int32) * W)
+    kw = dict(width=W, block_len=bl, pages_per_row=nb, scale=0.2)
+    pallas_mode.KERNEL_TILINGS.clear()
+    got = PA.packed_latent_attention(q, qr, *args, impl="pallas", **kw)
+    (_, tiling), = pallas_mode.KERNEL_TILINGS
+    assert dict(tiling)["grid"] == (B * 3, 1)
+    assert dict(tiling)["rows"] == H * PA._PACKED_COLUMNS
+    want = PA.packed_latent_attention(q, qr, *args, impl="scan", **kw)
+    assert got.shape == want.shape == q.shape
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 5e-6
 
 
 # ---- a selection over the latent cache (`paged_sparse`, `index_score`,
@@ -1209,6 +1379,12 @@ def test_engine_counts_its_rows_by_their_live_columns(gpt_tiny):
     text = eng.metrics.render()
     assert f"pdtpu_llm_paged_rows_one_column_total {one}" in text
     assert f"pdtpu_llm_paged_rows_wide_total {wide}" in text
+    # a `(k, v)` layer unpacks its queries: slots x chunk positions a layer
+    layers = len(eng.pool.layer_kinds)
+    positions = layers * eng.config.num_slots * eng.config.prefill_chunk \
+        * snap["unified_steps"]
+    assert snap["attn_query_positions"] == positions > 0
+    assert f"pdtpu_llm_attn_query_positions_total {positions}" in text
     eng.stop()
 
 

@@ -278,6 +278,45 @@ def test_engine_logprobs_equal_the_references_full_forward(tiny, num_slots):
     eng.pool.check_balance()
 
 
+@pytest.mark.parametrize("adv", [[1, 16, 3, 1, 0, 16, 1, 7], [16, 1, 16]],
+                         ids=["mixed", "a full block"])
+def test_a_packed_step_gives_the_unpacked_steps_logits(tiny, adv):
+    """The whole model over one step's tokens packed (`TokenPack`: the
+    latent layers' queries stay on the packed block) and as `[slots, 16]`
+    rows: the same logits at every live token, the same cache columns up
+    to each row's length."""
+    from paddle_tpu.ops.attention import PagedView, token_pack
+    rng = np.random.default_rng(6)
+    adv = np.asarray(adv, np.int32)
+    N, C, bl, nb = len(adv), 16, 8, 10
+    pos = np.where(adv > 0, rng.integers(0, nb * bl - C, N), 0) \
+        .astype(np.int32)
+    T = int(adv.sum())
+    pack = token_pack(jnp.asarray(adv), jnp.asarray(pos), C, T)
+    ids = jnp.asarray(rng.integers(0, VOCAB, (T, 1)), jnp.int32)
+    caches = [tuple(paddle.to_tensor(jnp.asarray(
+        rng.normal(0, 1, (N, 1, nb * bl + C, a.shape[3])), jnp.float32))
+        for a in entry) for entry in tiny.init_cache(N, 8)]
+    # a slot writes its own slab row: its pages are that row's
+    table = np.arange(N * nb, dtype=np.int32).reshape(N, nb)
+    paged = PagedView(jnp.asarray(table), jnp.asarray(pos + adv), bl, nb)
+    got, got_caches = tiny.forward_with_cache(
+        paddle.to_tensor(ids), caches, jnp.asarray(pos), paged=paged,
+        pack=pack)
+    want, want_caches = tiny.forward_with_cache(
+        paddle.to_tensor(pack.unpack(ids)), caches, jnp.asarray(pos),
+        paged=paged)
+    np.testing.assert_allclose(got.numpy(), pack.pack(want.data), atol=TOL)
+    assert np.abs(got.numpy()).max() > 1.0
+    # (the columns past a row's live ones hold what nobody reads)
+    held = (np.arange(nb * bl + C) < (pos + adv)[:, None])[:, None, :, None]
+    for a, b in zip(got_caches, want_caches):
+        for x, y in zip(a, b):
+            np.testing.assert_allclose(np.where(held, x.numpy(), 0),
+                                       np.where(held, y.numpy(), 0),
+                                       atol=TOL)
+
+
 def test_engine_streams_equal_generate(tiny):
     eng = _engine(tiny)
     prompts = _prompts([5, 16, 17, 60, 129], seed=6)

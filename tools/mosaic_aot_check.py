@@ -385,38 +385,32 @@ def main() -> int:
             spec(slab, jnp.bfloat16), spec((B, ppr), jnp.int32),
             spec((B,), jnp.int32), spec((B,), jnp.int32),
             want={"paged_window" if window else "paged_attention": 1}))
-    # the latent walk (`paged_latent`) at the latent cell's shapes: 32
-    # slots, 64 query heads over one 512-wide latent and one rotary key
-    # stored in 128 lanes, 518 pages a slot; the step's 16-wide rows (the
-    # heads split over two tiles) and a one-token row (one tile)
-    for label, Tq in (("chunk rows", 16), ("Tq=1", 1)):
-        def latent(q, qr, c, r, t, sl, qp):
-            return ragged_paged_attention(
-                q, c, r, t, sl, qp, block_len=16, pages_per_row=518,
-                scale=0.1309, impl="pallas", q_rope=qr)
+    # the latent walk (`paged_latent`) in its one layout, queries and
+    # result token-major `[positions, H, 512 | 128]` with a start a row
+    # (`packed_latent_attention`), at the two latent cells' shapes. The
+    # latent cell: 32 slots of 518 pages, 64 query heads in two tiles of
+    # 32, an engine that does not pack (32 x 16 positions, row n from
+    # n x 16), and `generate()`'s one-token rows (one tile). The
+    # hyper-connected cell: 256 slots of 160 pages, 32 heads, the packed
+    # step's 512 positions and the 15 of pad a last row's window may run
+    # into, the rows' starts a `TokenPack`'s
+    from paddle_tpu.ops.paged_attention import packed_latent_attention
+    for label, n, H, B, L, ppr, Tq, scale in (
+            ("chunk rows", 512, 64, 32, 8304, 518, 16, 0.1309),
+            ("Tq=1", 32, 64, 32, 8304, 518, 1, 0.1309),
+            ("decode-heavy rows", 527, 32, 256, 2576, 160, 16, 0.1544)):
+        def latent(q, qr, c, r, t, sl, qp, st, ppr=ppr, Tq=Tq, scale=scale):
+            return packed_latent_attention(
+                q, qr, c, r, t, sl, qp, st, width=Tq, block_len=16,
+                pages_per_row=ppr, scale=scale, impl="pallas")
         results.append(compile_case(
-            f"paged latent bf16 {label} q=[32, 64, {Tq}, 512 | 128] "
-            "slab=[32, 1, 8304, 512 | 128]", latent,
-            spec((32, 64, Tq, 512), jnp.bfloat16),
-            spec((32, 64, Tq, 128), jnp.bfloat16),
-            spec((32, 1, 8304, 512), jnp.bfloat16),
-            spec((32, 1, 8304, 128), jnp.bfloat16),
-            spec((32, 518), jnp.int32), spec((32,), jnp.int32),
-            spec((32,), jnp.int32), want={"paged_latent": 1}))
-    # the hyper-connected cell's walk: 256 slots of 160 pages, 32 query
-    # heads, the decode-heavy step's 16-wide rows
-    results.append(compile_case(
-        "paged latent bf16 decode-heavy rows q=[256, 32, 16, 512 | 128] "
-        "slab=[256, 1, 2576, 512 | 128]",
-        lambda q, qr, c, r, t, sl, qp: ragged_paged_attention(
-            q, c, r, t, sl, qp, block_len=16, pages_per_row=160,
-            scale=0.1544, impl="pallas", q_rope=qr),
-        spec((256, 32, 16, 512), jnp.bfloat16),
-        spec((256, 32, 16, 128), jnp.bfloat16),
-        spec((256, 1, 2576, 512), jnp.bfloat16),
-        spec((256, 1, 2576, 128), jnp.bfloat16),
-        spec((256, 160), jnp.int32), spec((256,), jnp.int32),
-        spec((256,), jnp.int32), want={"paged_latent": 1}))
+            f"paged latent bf16 {label} q=[{n}, {H}, 512 | 128] rows={B} x "
+            f"{Tq} slab=[{B}, 1, {L}, 512 | 128]", latent,
+            spec((n, H, 512), jnp.bfloat16), spec((n, H, 128), jnp.bfloat16),
+            spec((B, 1, L, 512), jnp.bfloat16),
+            spec((B, 1, L, 128), jnp.bfloat16), spec((B, ppr), jnp.int32),
+            spec((B,), jnp.int32), spec((B,), jnp.int32),
+            spec((B,), jnp.int32), want={"paged_latent": 1}))
     # the two halves of a hyper-connection (`hc_pre`, `hc_post`) at the
     # hyper-connected cell's shapes: a packed step's 512 positions of 4
     # streams x 3,584 in bf16, the parameters as the benchmark holds them;
