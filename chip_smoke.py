@@ -63,12 +63,21 @@ FULL = dict(
     slots=8, n_blocks=127, prompts=(12, 200, 700, 1500), max_new=32,
     # the serve cells' head layouts and slot geometry: Mistral (GQA 32/8)
     # and OLMoE (MHA 16/16), 14 pages of 16 a slot + a chunk of write-padding
+    # + the heads-by-layer cell's full layers: GQA 48/8 (fold 6: 6 of a
+    # packed sublane tile's 16 rows in the one-column body), 160 pages
     paged=(dict(heads=32, kv_heads=8, head_dim=128, pages=14),
-           dict(heads=16, kv_heads=16, head_dim=128, pages=14)),
+           dict(heads=16, kv_heads=16, head_dim=128, pages=14),
+           dict(heads=48, kv_heads=8, head_dim=128, pages=160)),
     # the window/full cell's window layers: GQA 32/4, a window of 1,024 in
-    # a ring of 65 pages (window + one chunk)
-    paged_window=dict(heads=32, kv_heads=4, head_dim=128, window=1024,
-                      ring_pages=65),
+    # a ring of 65 pages (window + one chunk); the heads-by-layer cell's:
+    # GQA 64/8, a window of 512 in a ring of 33 pages
+    paged_window=(dict(heads=32, kv_heads=4, head_dim=128, window=1024,
+                       ring_pages=65),
+                  dict(heads=64, kv_heads=8, head_dim=128, window=512,
+                       ring_pages=33)),
+    # the same cell's expert layer: 256 groups of [2048, 512] and back over
+    # a step's 512 positions x 8 (16 rows a group, uneven)
+    moe_gmm=dict(rows=4096, experts=256, hidden=2048, width=512),
     # the two latent cells' MLA layers, over one 512-wide latent and one
     # 64-wide rotary key (stored in 128 lanes): 64 heads in two tiles of
     # 32, 518 pages a slot; 32 heads in one tile, 160 pages a slot
@@ -91,9 +100,13 @@ TINY = dict(
     flash_shapes=((1, 2, 512, 64),),
     slots=4, n_blocks=31, prompts=(12, 40, 100, 200), max_new=8,
     paged=(dict(heads=4, kv_heads=2, head_dim=64, pages=4),
-           dict(heads=2, kv_heads=2, head_dim=64, pages=4)),
-    paged_window=dict(heads=4, kv_heads=2, head_dim=64, window=32,
-                      ring_pages=3),
+           dict(heads=2, kv_heads=2, head_dim=64, pages=4),
+           dict(heads=12, kv_heads=2, head_dim=64, pages=4)),
+    paged_window=(dict(heads=4, kv_heads=2, head_dim=64, window=32,
+                       ring_pages=3),
+                  dict(heads=16, kv_heads=2, head_dim=64, window=32,
+                       ring_pages=3)),
+    moe_gmm=dict(rows=256, experts=16, hidden=128, width=128),
     paged_latent=(dict(heads=4, latent=32, rope=8, rope_cols=8, pages=20),
                   dict(heads=2, latent=32, rope=8, rope_cols=8, pages=9)),
     sparse=dict(heads=4, latent=32, rope_cols=8, index_heads=2,
@@ -548,8 +561,13 @@ def _paged_parity(size: dict):
 
 
 def _paged_window_parity(size: dict):
+    for layout in size["paged_window"]:
+        _paged_window_parity_at(layout)
+
+
+def _paged_window_parity_at(g: dict):
     """The windowed walk (`paged_window`) `impl="pallas"` against
-    `impl="scan"` on this device at `size["paged_window"]`: bf16, block_len
+    `impl="scan"` on this device at one layout of `size["paged_window"]`: bf16, block_len
     16, each row its own ring (position p at column p mod ring, so rows
     past the ring have wrapped), query widths 1 and 16, rows shorter than
     the window, across it, twice and five times round the ring. Tolerance
@@ -560,7 +578,6 @@ def _paged_window_parity(size: dict):
     from paddle_tpu.ops import pallas_mode
     from paddle_tpu.ops.paged_attention import (WINDOW_KERNEL,
                                                 ragged_paged_attention)
-    g = size["paged_window"]
     H, Hkv, D = g["heads"], g["kv_heads"], g["head_dim"]
     W, pages = g["window"], g["ring_pages"]
     N, bl, tol = 8, 16, 2e-2
@@ -592,6 +609,61 @@ def _paged_window_parity(size: dict):
              f"(tolerance {tol:g})")
         _require(kernel == WINDOW_KERNEL and np.isfinite(err) and err <= tol,
                  f"{WINDOW_KERNEL} H={H}/{Hkv} Tq={Tq} within {tol:g}")
+
+
+def _moe_gmm_parity(size: dict):
+    """`grouped_matmul(impl="pallas")` (`moe_gmm`) against a plain XLA form
+    on this device at `size["moe_gmm"]`: bf16, both projections of an expert
+    layer of many small experts, uneven groups of which some are empty and
+    which leave the last rows to no group. The plain form is a scan over
+    the groups, each a whole `lhs @ rhs[g]` kept for the group's own rows
+    (`jax.lax.ragged_dot`, the CPU's path, is itself a Mosaic kernel on a
+    TPU and refuses bf16 operands under the package's "highest"). Both
+    accumulate in float32 over the same bf16 operands, so what remains is
+    the order of the sum over K and one bf16 rounding of a result of spread
+    ~sqrt(K): 0.5% of the largest value, which is a little over one step of
+    bf16 there (the chip read half a step at both shapes, 0.23% and 0.21%
+    of the largest value, PR 51)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul
+    g = size["moe_gmm"]
+    rows, experts = g["rows"], g["experts"]
+    rng = np.random.RandomState(3)
+    sizes = rng.multinomial(rows - rows // 8, np.ones(experts) / experts)
+    sizes[rng.choice(experts, experts // 16, replace=False)] = 0
+    live = int(sizes.sum())
+    ends = np.cumsum(sizes).astype(np.int32)
+
+    @jax.jit
+    def plain(lhs, rhs):
+        row = jnp.arange(lhs.shape[0])[:, None]
+
+        def one(out, group):
+            w, start, end = group
+            y = jnp.dot(lhs, w, preferred_element_type=jnp.float32)
+            return jnp.where((row >= start) & (row < end), y, out), None
+
+        out, _ = jax.lax.scan(
+            one, jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32),
+            (rhs, jnp.asarray(ends - sizes), jnp.asarray(ends)))
+        return out
+
+    for k, n in ((g["hidden"], g["width"]), (g["width"], g["hidden"])):
+        lhs = jnp.asarray(rng.randn(rows, k), jnp.bfloat16)
+        rhs = jnp.asarray(rng.randn(experts, k, n), jnp.bfloat16)
+        got = grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
+                             impl="pallas")[:live]
+        want = plain(lhs, rhs)[:live]
+        err, top = _max_err(got, want), float(jnp.max(jnp.abs(want)))
+        _say(f"moe_gmm pallas vs plain XLA [{rows},{k}] x [{experts},{k},"
+             f"{n}] bf16, groups of {int(sizes.min())}-{int(sizes.max())} "
+             f"rows, {live} live: max abs err {err:.2e} of {top:.1f} "
+             "(tolerance 0.5%)")
+        _require(np.isfinite(err) and err <= 0.005 * top,
+                 f"moe_gmm [{experts},{k},{n}] within 0.5%")
 
 
 def _paged_latent_parity(size: dict):
@@ -742,13 +814,13 @@ def _kv_write_parity(size: dict):
     from paddle_tpu.ops import kv_write as kvw, pallas_mode
     from paddle_tpu.ops.attention import _row_writes
     T, B = 16, 16
-    w = size["paged_window"]
     lat = size["paged_latent"][0]
-    ring = w["ring_pages"] * 16
     cases = [(g["kv_heads"], g["pages"] * 16 + T, g["head_dim"],
               g["head_dim"], None) for g in size["paged"]]
-    cases += [(w["kv_heads"], ring + T, w["head_dim"], w["head_dim"], ring),
-              (1, lat["pages"] * 16 + T, lat["latent"], lat["rope_cols"],
+    cases += [(w["kv_heads"], w["ring_pages"] * 16 + T, w["head_dim"],
+               w["head_dim"], w["ring_pages"] * 16)
+              for w in size["paged_window"]]
+    cases += [(1, lat["pages"] * 16 + T, lat["latent"], lat["rope_cols"],
                None)]
     rng = np.random.RandomState(4)
     for Hkv, L, Dk, Dv, r in cases:
@@ -968,6 +1040,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
     _say("[serve] kernel parity on this device")
     _paged_parity(size)
     _paged_window_parity(size)
+    _moe_gmm_parity(size)
     _paged_latent_parity(size)
     _sparse_parity(size)
     _kv_write_parity(size)

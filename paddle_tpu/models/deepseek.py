@@ -116,7 +116,6 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from types import SimpleNamespace
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -134,8 +133,8 @@ from ..nn.layer.norm import LayerNorm
 from ..ops.attention import _NEG_INF, decode_attention, \
     decode_attention_packed, flash_attention, update_caches, update_kv_cache
 from ..ops.index_select import Selection, select, topk_mask
-from .llama import LlamaMLP, RMSNorm, _apply_rope, _rope_cos_sin, \
-    yarn_mscale
+from .llama import LlamaMLP, RMSNorm, SharedExpertMoE, _apply_rope, \
+    _rope_cos_sin, yarn_mscale
 
 LANES = 128
 
@@ -511,28 +510,18 @@ class MLAttention(Layer):
             Selection(*out[1 + n_cache:]) if full else sel
 
 
-class DeepseekMoE(Layer):
+class DeepseekMoE(SharedExpertMoE):
     """The routed experts' part (held here) plus the shared expert's."""
 
     def __init__(self, config: DeepseekConfig):
-        super().__init__()
-        self.experts = DroplessMoE(
+        super().__init__(DroplessMoE(
             config.hidden_size, config.moe_intermediate_size,
             config.n_routed_experts, config.num_experts_per_tok,
             config.norm_topk_prob, held=config.experts_held,
             scoring=config.scoring_func, n_group=config.n_group,
             topk_group=config.topk_group, select_bias=config.select_bias,
-            routed_scale=config.routed_scaling_factor)
-        self.shared_experts = LlamaMLP(SimpleNamespace(
-            hidden_size=config.hidden_size,
-            intermediate_size=config.n_shared_experts
-            * config.moe_intermediate_size)) \
-            if config.n_shared_experts else None
-
-    def forward(self, x, live=None):
-        out = self.experts(x, live=live)
-        return out if self.shared_experts is None \
-            else out + self.shared_experts(x)
+            routed_scale=config.routed_scaling_factor),
+            config, config.n_shared_experts * config.moe_intermediate_size)
 
 
 class DeepseekDecoderLayer(Layer):
@@ -683,6 +672,11 @@ class DeepseekForCausalLM(Layer):
                 LatentKV(slab(cfg.kv_lora_rank),
                          slab(_lanes(cfg.qk_rope_head_dim)))
                 for kind in kinds]
+
+    def query_heads_by_layer(self):
+        """Each layer's query heads, one entry an `init_cache` entry."""
+        return [self.config.num_attention_heads] \
+            * self.config.num_hidden_layers
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
                            adapters=None, pack=None):

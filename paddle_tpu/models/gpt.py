@@ -352,6 +352,11 @@ class GPTForCausalLM(Layer):
         return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
                 for _ in range(cfg.num_hidden_layers)]
 
+    def query_heads_by_layer(self):
+        """Each layer's query heads, one entry an `init_cache` entry."""
+        return [self.config.num_attention_heads] \
+            * self.config.num_hidden_layers
+
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
                            adapters=None, pack=None):
         """`pack`: see `LlamaForCausalLM.forward_with_cache`."""

@@ -168,6 +168,12 @@ class HybridForCausalLM(Layer):
                 else (jnp.zeros(kv, dt), jnp.zeros(kv, dt))
                 for layer in self.model.layers]
 
+    def query_heads_by_layer(self):
+        """Each layer's query heads, one entry an `init_cache` entry: a
+        recurrent layer has none."""
+        return [0 if layer.kind == MAMBA else layer.self_attn.num_heads
+                for layer in self.model.layers]
+
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
                            adapters=None, pack=None):
         if adapters is not None:

@@ -34,6 +34,7 @@ from ..ops.lora import add_lora_delta
 
 
 FULL, SLIDING = "full_attention", "sliding_attention"
+DENSE, SPARSE = "dense", "sparse"
 
 
 @dataclass
@@ -85,10 +86,50 @@ class LlamaConfig:
     # (first, count): every expert layer holds that share of `num_experts`
     # (one chip's under expert parallelism; `DroplessMoE(held=)`)
     experts_held: Optional[Tuple[int, int]] = None
+    # one entry a layer: that layer's query heads, where they differ by
+    # layer (q, o and the GQA group are then the layer's own; the KV heads
+    # and the head width are the model's). None: `num_attention_heads`
+    num_attention_heads_per_layer: Optional[Sequence[int]] = None
+    # a sigmoid gate a query head on the attention output, computed from
+    # the layer's normed input by `g_proj [hidden, heads]` ("Gated
+    # Attention for Large Language Models", the head-wise form)
+    attn_output_gate: bool = False
+    # one entry a layer, DENSE or SPARSE; None: every layer sparse where
+    # `num_experts` > 0. A DENSE layer's FFN is the SwiGLU MLP of width
+    # `intermediate_size`
+    mlp_layer_types: Optional[Sequence[str]] = None
+    # width of a routed expert where it is not `intermediate_size`
+    moe_intermediate_size: Optional[int] = None
+    # > 0: a sparse layer adds one SwiGLU expert of this width that every
+    # position takes at weight 1, beside the routed ones
+    shared_expert_intermediate_size: int = 0
+    # the router's scores ("softmax" | "sigmoid") and the factor on the
+    # chosen experts' gates (`nn/layer/moe.py::route`)
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_attention_heads
+        if self.num_attention_heads_per_layer is not None:
+            self.num_attention_heads_per_layer = tuple(
+                int(h) for h in self.num_attention_heads_per_layer)
+            if len(self.num_attention_heads_per_layer) \
+                    != self.num_hidden_layers or any(
+                        h <= 0 or h % self.num_key_value_heads
+                        for h in self.num_attention_heads_per_layer):
+                raise ValueError(
+                    f"num_attention_heads_per_layer: "
+                    f"{self.num_hidden_layers} multiples of "
+                    f"{self.num_key_value_heads} KV heads, got "
+                    f"{self.num_attention_heads_per_layer}")
+        if self.mlp_layer_types is not None:
+            self.mlp_layer_types = tuple(self.mlp_layer_types)
+            bad = set(self.mlp_layer_types) - {DENSE, SPARSE}
+            if bad or len(self.mlp_layer_types) != self.num_hidden_layers:
+                raise ValueError(
+                    f"mlp_layer_types: {self.num_hidden_layers} entries of "
+                    f"{DENSE!r} / {SPARSE!r}, got {self.mlp_layer_types}")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             bad = set(self.layer_types) - {FULL, SLIDING}
@@ -114,6 +155,19 @@ class LlamaConfig:
             else self.layer_types[layer]
         return dict((self.rope_parameters or {}).get(
             kind, {"rope_type": "default", "rope_theta": self.rope_theta}))
+
+    def heads_of(self, layer: Optional[int]) -> int:
+        """The query heads of layer `layer`."""
+        if layer is None or self.num_attention_heads_per_layer is None:
+            return self.num_attention_heads
+        return self.num_attention_heads_per_layer[layer]
+
+    def sparse_at(self, layer: Optional[int]) -> bool:
+        """Whether layer `layer`'s FFN is a router over experts."""
+        if not self.num_experts:
+            return False
+        return layer is None or self.mlp_layer_types is None \
+            or self.mlp_layer_types[layer] == SPARSE
 
 
 LLAMA_PRESETS = {
@@ -217,8 +271,13 @@ def _rope_cos_sin(seq_len, head_dim, rope: dict):
 
 
 def _apply_rope(x, cos, sin):
-    # x: [B, H, S, D]; cos/sin [S, D] (shared positions) or [B, S, D]
-    # (per-row positions, slot-paged decode)
+    # x: [B, H, S, D]; cos/sin [S, R] (shared positions) or [B, S, R]
+    # (per-row positions, slot-paged decode). R < D (a partial rotary
+    # embedding): the first R dimensions of a head turn, the rest pass
+    rotary = cos.shape[-1]
+    if rotary < x.shape[-1]:
+        return jnp.concatenate(
+            [_apply_rope(x[..., :rotary], cos, sin), x[..., rotary:]], -1)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate([-x2, x1], -1)
@@ -235,9 +294,15 @@ class LlamaAttention(Layer):
         # rotary parameters
         self.window = config.window_of(layer)
         self.rope = config.rope_of(layer)
-        self.num_heads = config.num_attention_heads
+        self.num_heads = config.heads_of(layer)
         self.num_kv_heads = config.num_key_value_heads
         self.head_dim = config.head_dim
+        # the dimensions of a head the rotary embedding turns: the first
+        # `head_dim * partial_rotary_factor` of the layer type's rotary
+        # parameters, the rest passed through (YaRN's ramp is computed over
+        # the turned ones)
+        self.rotary_dim = int(self.head_dim * self.rope.get(
+            "partial_rotary_factor", 1.0))
         h = config.hidden_size
         self.q_proj = ColumnParallelLinear(h, self.num_heads * self.head_dim,
                                            has_bias=False, gather_output=False)
@@ -252,6 +317,28 @@ class LlamaAttention(Layer):
                                   config.rms_norm_eps)
             self.k_norm = RMSNorm(self.num_kv_heads * self.head_dim,
                                   config.rms_norm_eps)
+        if config.attn_output_gate:
+            self.g_proj = ColumnParallelLinear(h, self.num_heads,
+                                               has_bias=False,
+                                               gather_output=False)
+
+    def _gated(self, ctx, hidden):
+        """`ctx [..., heads * head_dim]` with each head's part times that
+        head's gate, `sigmoid(hidden g_proj)` in float32 (`hidden` the
+        layer's normed input, in ctx's layout); ctx itself where the model
+        has no gate."""
+        if not self.config.attn_output_gate:
+            return ctx
+        hd = self.head_dim
+
+        def gate(c, g):
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid(g.astype(jnp.float32))
+                c = c.reshape(*c.shape[:-1], -1, hd)
+                return (c * g[..., None].astype(c.dtype)).reshape(
+                    *c.shape[:-2], -1)
+
+        return apply(gate, ctx, self.g_proj(hidden))
 
     def forward(self, hidden, attn_mask=None, cache=None, pos=None,
                 paged=None, adapters=None, pack=None):
@@ -285,7 +372,7 @@ class LlamaAttention(Layer):
         if cache is not None:
             return self._forward_cached(q, k, v, cache, pos, n_rep, hd,
                                         paged=paged, adapters=adapters,
-                                        pack=pack)
+                                        pack=pack, hidden=hidden)
 
         def attn(qa, ka, va):
             qh = qa.reshape(qa.shape[0], qa.shape[1], -1, hd)
@@ -295,7 +382,8 @@ class LlamaAttention(Layer):
             kh = jnp.swapaxes(kh, 1, 2)
             vh = jnp.swapaxes(vh, 1, 2)
             if rope:
-                cos, sin = _rope_cos_sin(qa.shape[1], hd, rope_params)
+                cos, sin = _rope_cos_sin(qa.shape[1], self.rotary_dim,
+                                         rope_params)
                 cos = cos.astype(qh.dtype)[None].squeeze(0)
                 sin = sin.astype(qh.dtype)[None].squeeze(0)
                 qh = _apply_rope(qh, cos, sin)
@@ -308,10 +396,10 @@ class LlamaAttention(Layer):
             return out.reshape(out.shape[0], out.shape[1], -1)
 
         ctx = apply(attn, q, k, v)
-        return self.o_proj(ctx)
+        return self.o_proj(self._gated(ctx, hidden))
 
     def _forward_cached(self, q, k, v, cache, pos, n_rep, hd, paged=None,
-                        adapters=None, pack=None):
+                        adapters=None, pack=None, hidden=None):
         """Static-shape KV-cache decode/prefill step (jit/scan friendly):
         new k/v are written into the [B, Hkv, Lmax, D] cache at `pos`,
         attention runs over the FULL cache with an absolute-position causal
@@ -347,8 +435,8 @@ class LlamaAttention(Layer):
             vh = jnp.swapaxes(va.reshape(B, T, -1, hd), 1, 2)
             if self.config.rope:
                 cos, sin = _rope_cos_sin(
-                    Lmax if ring is None else positions + T, hd,
-                    rope_params)
+                    Lmax if ring is None else positions + T,
+                    self.rotary_dim, rope_params)
                 if jnp.ndim(pos_) == 0:
                     cos_t = lax.dynamic_slice_in_dim(cos, pos_, T, 0)
                     sin_t = lax.dynamic_slice_in_dim(sin, pos_, T, 0)
@@ -377,6 +465,7 @@ class LlamaAttention(Layer):
             return out, kc, vc
 
         ctx, new_k, new_v = apply(attn_dec, q, k, v, k_cache, v_cache, pos)
+        ctx = self._gated(ctx, hidden)
         out = self.o_proj(ctx)
         if adapters is not None:
             amap, aidx, ascale = adapters
@@ -385,9 +474,9 @@ class LlamaAttention(Layer):
 
 
 class LlamaMLP(Layer):
-    def __init__(self, config: LlamaConfig):
+    def __init__(self, config: LlamaConfig, width: Optional[int] = None):
         super().__init__()
-        h, i = config.hidden_size, config.intermediate_size
+        h, i = config.hidden_size, width or config.intermediate_size
         self.gate_proj = ColumnParallelLinear(h, i, has_bias=False,
                                               gather_output=False)
         self.up_proj = ColumnParallelLinear(h, i, has_bias=False,
@@ -411,16 +500,44 @@ class LlamaMLP(Layer):
         return down
 
 
+class SharedExpertMoE(Layer):
+    """A sparse FFN beside a shared expert: the routed experts' part
+    (`experts`, a `DroplessMoE`, held here) plus one SwiGLU MLP of width
+    `shared_width` that every position takes at weight 1
+    (`shared_experts`; None at width 0)."""
+
+    def __init__(self, experts: DroplessMoE, config, shared_width: int):
+        super().__init__()
+        self.experts = experts
+        self.shared_experts = LlamaMLP(config, shared_width) \
+            if shared_width else None
+
+    def forward(self, x, live=None):
+        out = self.experts(x, live=live)
+        if self.shared_experts is None:
+            return out
+        with jax.named_scope("shared_expert"):
+            return out + self.shared_experts(x)
+
+
 class LlamaDecoderLayer(Layer):
     def __init__(self, config: LlamaConfig, layer: Optional[int] = None):
         super().__init__()
         self.self_attn = LlamaAttention(config, layer)
-        self.sparse = config.num_experts > 0
-        self.mlp = DroplessMoE(
-            config.hidden_size, config.intermediate_size,
-            config.num_experts, config.num_experts_per_tok,
-            config.norm_topk_prob, held=config.experts_held) \
-            if self.sparse else LlamaMLP(config)
+        self.sparse = config.sparse_at(layer)
+        if self.sparse:
+            shared = config.shared_expert_intermediate_size
+            experts = DroplessMoE(
+                config.hidden_size,
+                config.moe_intermediate_size or config.intermediate_size,
+                config.num_experts, config.num_experts_per_tok,
+                config.norm_topk_prob, held=config.experts_held,
+                scoring=config.router_scoring,
+                routed_scale=config.routed_scaling_factor)
+            self.mlp = SharedExpertMoE(experts, config, shared) \
+                if shared else experts
+        else:
+            self.mlp = LlamaMLP(config)
         self.input_layernorm = RMSNorm(config.hidden_size,
                                        config.rms_norm_eps)
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
@@ -549,6 +666,13 @@ class LlamaForCausalLM(Layer):
             else:
                 entries.append(WindowKV(*slab(int(window_slab(window)))))
         return entries
+
+    def query_heads_by_layer(self):
+        """Each layer's query heads, one entry an `init_cache` entry (part
+        of the cached-decode contract: a cache manager knows a layer's kind
+        by its cache, never its heads)."""
+        return [self.config.heads_of(i)
+                for i in range(self.config.num_hidden_layers)]
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
                            adapters=None, pack=None):

@@ -176,7 +176,8 @@ from ..metrics import LLMMetrics, SLO_CLASSES
 from ..supervisor import (DispatchFailedError, DispatchHungError,  # noqa: F401
                           EngineSupervisor)
 from .host_kv import HostKVPool
-from .kv_pool import (INDEXED, LATENT, RECURRENT, SlotPagedKVPool,
+from .kv_pool import (INDEXED, LATENT, PAGED, RECURRENT, WINDOW,
+                      SlotPagedKVPool,
                       SlotsExhaustedError)
 from .lora import AdapterBank, AdapterError
 from .prefix_cache import PrefixCache
@@ -776,6 +777,14 @@ class LLMEngine:
             self.step_tokens if kind == LATENT
             else self.config.num_slots * self.config.prefill_chunk
             for kind in self.pool.layer_kinds if kind != RECURRENT)
+        # query-head rows a step's full and windowed walks compute: those
+        # positions x the layer's own query heads, over the layers of each
+        # kind (0: the model has no layer of that kind)
+        heads = model.query_heads_by_layer()
+        self._attn_heads = tuple(
+            self.config.num_slots * self.config.prefill_chunk * sum(
+                h for h, k in zip(heads, self.pool.layer_kinds) if k == kind)
+            for kind in (PAGED, WINDOW))
         # sparse experts: the model's dropless expert layers, found by
         # their type (nothing here knows which model holds them). Their
         # per-layer per-expert totals of live assignments `[L, E]` live on
@@ -3072,7 +3081,8 @@ class LLMEngine:
                     self.metrics.on_step_tokens(live_tokens,
                                                 self.step_tokens, deferred,
                                                 vacant_queued,
-                                                self._attn_positions)
+                                                self._attn_positions,
+                                                *self._attn_heads)
                     self.metrics.on_paged_rows(
                         one_column, int(np.count_nonzero(adv > 1)))
                     if started:

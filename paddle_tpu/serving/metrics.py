@@ -295,6 +295,8 @@ class LLMMetrics(ServingMetrics):
                               "step_tokens_live": 0,
                               "step_tokens_computed": 0,
                               "attn_query_positions": 0,
+                              "attn_query_heads_full": 0,
+                              "attn_query_heads_window": 0,
                               "prefill_rows_deferred": 0,
                               "slot_steps_vacant_queued": 0,
                               "first_tokens": 0, "ttft_steps": 0,
@@ -544,7 +546,8 @@ class LLMMetrics(ServingMetrics):
             self.counters["sampler_filter_steps"] += 1
 
     def on_step_tokens(self, live: int, computed: int, deferred: int,
-                       vacant_queued: int = 0, attn_positions: int = 0):
+                       vacant_queued: int = 0, attn_positions: int = 0,
+                       attn_heads_full: int = 0, attn_heads_window: int = 0):
         """One committed unified step: `live` of the `computed` positions
         it ran held a token (`computed` is the engine's `step_tokens`:
         the packed width, or slots x chunk where nothing is packed), and
@@ -557,11 +560,18 @@ class LLMMetrics(ServingMetrics):
         positions its attention computed, summed over the layers that
         attend (`step_tokens` for a layer whose queries stay on the packed
         block, slots x chunk for one that unpacks them); `step_tokens_live`
-        x those layers over it is the share somebody reads there."""
+        x those layers over it is the share somebody reads there.
+        `attn_heads_full` / `attn_heads_window`: the query-head rows the
+        full and the windowed walk computed, positions x the layer's own
+        query heads over the layers of that kind (the two differ where a
+        model's head count does by layer type)."""
         with self._lock:
             self.counters["step_tokens_live"] += int(live)
             self.counters["step_tokens_computed"] += int(computed)
             self.counters["attn_query_positions"] += int(attn_positions)
+            self.counters["attn_query_heads_full"] += int(attn_heads_full)
+            self.counters["attn_query_heads_window"] += \
+                int(attn_heads_window)
             self.counters["prefill_rows_deferred"] += int(deferred)
             self.counters["slot_steps_vacant_queued"] += int(vacant_queued)
 
@@ -895,7 +905,8 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_sampler_filter_steps_total",
                  s["sampler_filter_steps"])
         for name in ("step_tokens_live", "step_tokens_computed",
-                     "attn_query_positions", "prefill_rows_deferred",
+                     "attn_query_positions", "attn_query_heads_full",
+                     "attn_query_heads_window", "prefill_rows_deferred",
                      "slot_steps_vacant_queued",
                      "first_tokens", "ttft_steps", "paged_rows_one_column",
                      "paged_rows_wide", "steps_overlapped",

@@ -103,6 +103,8 @@ def test_kv_write_brings_a_rings_overrun_round(name, T, dtype, pos):
     ("mellum window layers", 32, 4, 1056, 128, 128, 1040, 8),
     ("a.x-k1 latent pair", 32, 1, 8304, 512, 128, None, 8),
     ("xing4.0 latent pair", 256, 1, 2576, 512, 128, None, 8),
+    ("laguna full layers", 128, 8, 2576, 128, 128, None, 8),
+    ("laguna window layers", 128, 8, 544, 128, 128, 528, 8),
     ("a batch of one", 1, 8, 240, 128, 128, None, 1),
     ("rows that no 8 divides", 14, 8, 240, 128, 128, None, 7),
     ("a prime batch", 13, 8, 240, 128, 128, None, 1),

@@ -34,12 +34,12 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 73 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 81 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
-    assert len(paged) == 15         # tools/mosaic_aot_check.py's two lists
+    assert len(paged) == 19         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
-    assert len(window) == 2 and all("slab=[32, 4, 1056, 128]" in c
-                                    for c in window)
+    assert [c.split("slab=")[1].split(":")[0] for c in window] == [
+        "[32, 4, 1056, 128]"] * 2 + ["[128, 8, 544, 128]"] * 2
     # a grid step's loop takes 128 keys: 8 pages of 16 at every cell's
     # shape, 16 pages of 8 on the one-shot path
     tilings = [ln for ln in tool.splitlines()
@@ -47,7 +47,8 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     assert tilings and all("'pages': 8" in t or "'pages': 16" in t
                            for t in tilings)
     for grid, groups in (("(128, 1)", 2), ("(32, 1)", 9), ("(32, 1)", 65),
-                         ("(32, 1)", 10), ("(256, 1)", 20)):
+                         ("(32, 1)", 10), ("(256, 1)", 20), ("(128, 1)", 20),
+                         ("(128, 1)", 6)):
         assert any(f"'grid': {grid}, 'groups': {groups}," in t
                    and "'pages': 8" in t for t in tilings), (grid, groups)
     # multi-query 20:1: the whole group folds into one tile's rows, 320 at
@@ -55,6 +56,13 @@ def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     for rows, one in ((320, 20), (20, 0)):
         assert any(f"'heads': 1, 'one_column_rows': {one}, 'pages': 8, "
                    f"'rows': {rows}" in t for t in tilings), rows
+    # the grouped matmul at 256 groups of [2048, 512] (PR 51): 16 rows a
+    # group at 512 positions x 8, less than one row tile of 128
+    gmm = [c for c in cases if c.startswith("[OK] moe_gmm bf16")]
+    assert len(gmm) == 10
+    for shape in ("[4096,2048] x [256,2048,512]",
+                  "[4096,512] x [256,512,2048]"):
+        assert any(shape in c for c in gmm), shape
 
 
 def test_the_paged_walks_hold_the_one_column_body_where_the_tile_shrinks(
@@ -64,7 +72,9 @@ def test_the_paged_walks_hold_the_one_column_body_where_the_tile_shrinks(
     bodies compile for the v5e in one kernel at the cells' shapes: Xing's
     32 of 512 rows, A.X-K1's 32 of 512 in two tiles, Jamba's 20 of 320,
     Mistral's 4 of 64 a KV head (decode and prefill cells), Mellum's 8 of
-    128 in the ring and the full walk, and the whole-prompt tiles. A
+    128 in the ring and the full walk, Laguna's 6 of 96 in the full walk
+    and 8 of 128 in its ring (two group sizes in one model's step), and the
+    whole-prompt tiles. A
     one-token call, OLMoE's MHA tile (sixteen bf16 rows are one packed tile
     either way) and both of the sparse layer's calls hold one body: their
     kernels are the parent's to the equation (493 at 8 pages a group; the
@@ -79,6 +89,13 @@ def test_the_paged_walks_hold_the_one_column_body_where_the_tile_shrinks(
                ("paged_window", "(32, 1)", 128): 8,
                ("paged_attention", "(32, 1)", 128): 8,
                ("paged_attention", "(2, 8)", 2048): 4,
+               # head counts by layer type (PR 51): 6 of 96 rows a KV head
+               # in the full walk (6 of a packed tile's 16 rows), 8 of 128
+               # in a ring of 33 pages, every KV head in one tile
+               ("paged_attention", "(128, 1)", 96): 6,
+               ("paged_window", "(128, 1)", 128): 8,
+               ("paged_attention", "(128, 1)", 6): 0,
+               ("paged_window", "(128, 1)", 8): 0,
                # one body: OLMoE's step and the one-token calls
                ("paged_attention", "(128, 1)", 16): 0,
                ("paged_attention", "(8, 1)", 20): 0,
@@ -380,7 +397,7 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
     row is a read-modify-write of the 32 aligned columns that hold it."""
     cases = [ln for ln in tool.splitlines()
              if ln.startswith("[OK] kv_write bf16")]
-    assert len(cases) == 12
+    assert len(cases) == 14
     # a sparse layer's three slabs (latent, rotary key, index key) in the
     # one call, at the sessions cell's 16 rows of 36,880 columns
     assert any("slab=[16, 1, 36880] x 512 | 128 | 128" in ln for ln in cases)
@@ -393,6 +410,8 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
                  "[32, 4, 1056] x 128 | 128 T=16 ring=1040",
                  "[32, 1, 8304] x 512 | 128", "[256, 1, 2576] x 128 | 128",
                  "[256, 1, 2576] x 512 | 128",
+                 "[128, 8, 2576] x 128 | 128",
+                 "[128, 8, 544] x 128 | 128 T=16 ring=528",
                  "[1, 8, 2064] x 128 | 128"):
         assert any(f"slab={slab}" in ln for ln in cases), slab
     tilings = [ln for ln in tool.splitlines()

@@ -336,6 +336,13 @@ def main() -> int:
         ("MQA 20:1 reasoning cell", (256, 20, 16, D), (256, 1, 2576, D), 16,
          160),
         ("MQA 20:1 Tq=1", (8, 20, 1, D), (8, 1, 2576, D), 16, 160),
+        # 6 query heads a KV head (48 over 8), the full layers of a model
+        # whose head count differs by layer type: 96 folded rows a tile at
+        # the step's rows, and the one-column body at fold 6 (6 of a packed
+        # sublane tile's 16 rows); a one-token row folds 6
+        ("GQA 6:1 reasoning cell, full layers", (128, 48, 16, D),
+         (128, 8, 2576, D), 16, 160),
+        ("GQA 6:1 Tq=1", (128, 48, 1, D), (128, 8, 2576, D), 16, 160),
     ]
     for label, q_shape, slab, bl, ppr in paged_cases:
         def paged(q, k, v, t, sl, qp, bl=bl, ppr=ppr):
@@ -373,7 +380,13 @@ def main() -> int:
             ("window ring, Tq=1", (32, 32, 1, D), (32, 4, 1056, D), 65,
              1024),
             ("window/full cell, full layers", (32, 32, 16, D),
-             (32, 4, 8304, D), 518, None)):
+             (32, 4, 8304, D), 518, None),
+            # the same model's window layers at 64 heads over 8 (fold 8): a
+            # ring of 33 pages (window 512 + a chunk), 128 slots
+            ("GQA 8:1 window ring of 33 pages, chunk rows",
+             (128, 64, 16, D), (128, 8, 544, D), 33, 512),
+            ("GQA 8:1 window ring of 33 pages, Tq=1", (128, 64, 1, D),
+             (128, 8, 544, D), 33, 512)):
         def windowed(q, k, v, t, sl, qp, ppr=ppr, window=window):
             return ragged_paged_attention(
                 q, k, v, t, sl, qp, block_len=16, pages_per_row=ppr,
@@ -492,6 +505,9 @@ def main() -> int:
             ("granite decode cell", (128, 8, 240), (D, D), None),
             ("reasoning cell", (256, 1, 2576), (D, D), None),
             ("hyper-connected cell", (256, 1, 2576), (512, D), None),
+            ("heads-by-layer cell, full layers", (128, 8, 2576), (D, D),
+             None),
+            ("heads-by-layer cell, ring", (128, 8, 544), (D, D), 528),
             ("a batch of one", (1, 8, 2064), (D, D), None)):
         results.append(kv_write_case(label, slab, widths, 16, ring, one))
     body = kernel_equations(
@@ -509,10 +525,13 @@ def main() -> int:
     # and at granite-4.0-h-small's (512 packed positions x 10, 18 held)
     # and at the window/full cell's (512 x 8, 16 of 64 held, width 896)
     # and at the latent cell's (512 x 8, 12 of 192 held, width 2,048)
+    # and at 256 small experts, every one held (512 x 8, width 512: 16
+    # rows a group, less than a row tile)
     for m, e, k, n in ((16384, 64, 2048, 1024), (16384, 64, 1024, 2048),
                        (5120, 18, 4096, 768), (5120, 18, 768, 4096),
                        (4096, 16, 2304, 896), (4096, 16, 896, 2304),
-                       (4096, 12, 7168, 2048), (4096, 12, 2048, 7168)):
+                       (4096, 12, 7168, 2048), (4096, 12, 2048, 7168),
+                       (4096, 256, 2048, 512), (4096, 256, 512, 2048)):
         results.append(compile_case(
             f"moe_gmm bf16 [{m},{k}] x [{e},{k},{n}]",
             lambda lhs, rhs, gs: grouped_matmul(lhs, rhs, gs, impl="pallas"),
