@@ -131,7 +131,8 @@ from ..nn.layer.layers import Layer, LayerList, parameter_dtype
 from ..nn.layer.moe import DroplessMoE
 from ..nn.layer.norm import LayerNorm
 from ..ops.attention import _NEG_INF, decode_attention, \
-    decode_attention_packed, flash_attention, update_caches, update_kv_cache
+    decode_attention_packed, flash_attention, take_positions, \
+    update_caches, update_kv_cache
 from ..ops.index_select import Selection, select, topk_mask
 from .llama import LlamaMLP, RMSNorm, SharedExpertMoE, _apply_rope, \
     _rope_cos_sin, yarn_mscale
@@ -679,12 +680,15 @@ class DeepseekForCausalLM(Layer):
             * self.config.num_hidden_layers
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
-                           adapters=None, pack=None):
+                           adapters=None, pack=None, emit=None):
+        """`pack`, `emit`: see `LlamaForCausalLM.forward_with_cache`."""
         if adapters is not None:
             raise NotImplementedError(
                 "LoRA adapters are not wired into DeepseekForCausalLM")
         hidden, new_caches = self.model(input_ids, caches=caches, pos=pos,
                                         paged=paged, pack=pack)
+        if emit is not None:
+            hidden = apply(take_positions, hidden, emit)
         return self.lm_head(hidden), new_caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,

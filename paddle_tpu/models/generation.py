@@ -294,16 +294,22 @@ def make_decoder_fns(model):
     `pos [T]` each token's own position and `adapter_idx [T]` its slot's;
     the logits come back `[T, 1, V]` (the engine's `_step`). Left as None
     it is not traced either.
+
+    And `emit` (`[E]` int32, the engine's `_step` again): flat positions of
+    the block viewed `[positions, hidden]`. The vocabulary head runs on
+    those rows alone and the logits come back `[E, V]`; every position
+    still writes its cache. Left as None every position gets its logits
+    and the trace is the one it was.
     """
     params, buffers = model.functional_state()
 
     def prefill(p, prompt, caches_, pos, paged=None, adapters=None,
-                pack=None):
+                pack=None, emit=None):
         with model._bound_state(p, buffers), no_grad():
             logits, new_caches = model.forward_with_cache(
                 Tensor(prompt),
                 [tuple(Tensor(a) for a in entry) for entry in caches_], pos,
-                paged=paged, adapters=adapters, pack=pack)
+                paged=paged, adapters=adapters, pack=pack, emit=emit)
         return logits.data, [tuple(a.data for a in entry)
                              for entry in new_caches]
 

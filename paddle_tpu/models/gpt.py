@@ -21,7 +21,7 @@ from ..nn import functional as F
 from ..nn.layer.layers import Layer, LayerList
 from ..ops.lora import add_lora_delta
 from ..ops.attention import decode_attention, flash_attention, \
-    update_kv_cache
+    take_positions, update_kv_cache
 
 
 @dataclass
@@ -358,11 +358,13 @@ class GPTForCausalLM(Layer):
             * self.config.num_hidden_layers
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
-                           adapters=None, pack=None):
-        """`pack`: see `LlamaForCausalLM.forward_with_cache`."""
+                           adapters=None, pack=None, emit=None):
+        """`pack`, `emit`: see `LlamaForCausalLM.forward_with_cache`."""
         hidden, new_caches = self.gpt(input_ids, caches=caches, pos=pos,
                                       paged=paged, adapters=adapters,
                                       pack=pack)
+        if emit is not None:
+            hidden = apply(take_positions, hidden, emit)
         return self.lm_head(hidden), new_caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,

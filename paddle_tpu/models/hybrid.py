@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ..core.tensor import apply
 from ..distributed.meta_parallel.mp_layers import VocabParallelEmbedding
 from ..nn.layer.layers import Layer, LayerList, parameter_dtype
+from ..ops.attention import take_positions
 from .generation import RecurrentState
 from .llama import RMSNorm
 
@@ -175,12 +176,15 @@ class HybridForCausalLM(Layer):
                 for layer in self.model.layers]
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
-                           adapters=None, pack=None):
+                           adapters=None, pack=None, emit=None):
+        """`pack`, `emit`: see `LlamaForCausalLM.forward_with_cache`."""
         if adapters is not None:
             raise NotImplementedError(
                 f"LoRA adapters are not wired into {type(self).__name__}")
         hidden, new_caches = self.model(input_ids, caches=caches, pos=pos,
                                         paged=paged, pack=pack)
+        if emit is not None:
+            hidden = apply(take_positions, hidden, emit)
         return self._logits(hidden), new_caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,

@@ -29,7 +29,7 @@ from ..nn import functional as F
 from ..nn.layer.layers import Layer, LayerList, parameter_dtype
 from ..nn.layer.moe import DroplessMoE
 from ..ops.attention import decode_attention, flash_attention, \
-    update_kv_cache
+    take_positions, update_kv_cache
 from ..ops.lora import add_lora_delta
 
 
@@ -675,14 +675,21 @@ class LlamaForCausalLM(Layer):
                 for i in range(self.config.num_hidden_layers)]
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,
-                           adapters=None, pack=None):
+                           adapters=None, pack=None, emit=None):
         """`pack` (`ops.attention.TokenPack`, the serving step's): the
         rows of `input_ids [T, 1]` are a step's live tokens, each at its
         own `pos [T]`, and `pack` tells attention which slot and column
-        each belongs to. The logits come back packed, `[T, 1, V]`."""
+        each belongs to. The logits come back packed, `[T, 1, V]`.
+
+        `emit [E]` (the serving step's too): the flat positions of the
+        block, viewed `[positions, hidden]`, whose logits somebody reads.
+        The head runs on those rows alone and the logits come back
+        `[E, V]`; the caches are written for every position as before."""
         hidden, new_caches = self.llama(input_ids, caches=caches, pos=pos,
                                         paged=paged, adapters=adapters,
                                         pack=pack)
+        if emit is not None:
+            hidden = apply(take_positions, hidden, emit)
         return self.lm_head(hidden), new_caches
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,

@@ -1122,6 +1122,15 @@ def token_pack(adv, pos, chunk: int, step_tokens: int) -> TokenPack:
                      last=jnp.maximum(end - 1, 0), slot_pos=pos)
 
 
+def take_positions(block, positions):
+    """Rows `positions [E]` of `block [..., H]` viewed `[positions, H]`, as
+    `[E, H]`: a serving step's emission rows, the only ones whose hidden
+    state the vocabulary head reads (`LLMEngine._step`). Flat positions:
+    `TokenPack.dst[n, c]` into a packed block, `n * C + c` into `[N, C]`."""
+    return jnp.take(block.reshape((-1, block.shape[-1])), positions, axis=0,
+                    mode="clip")
+
+
 class PagedView(NamedTuple):
     """The page operand of one serving step, as every layer of the model is
     handed it (`paged=`): where each row's pages lie and how long the row is

@@ -34,12 +34,15 @@ continuously-batched service:
   `MIN_STEP_TOKENS` positions packs the columns that hold a token into one
   `[step_tokens, 1]` block inside the executable (`ops.attention.
   token_pack`, from `adv` and `pos` alone) and runs every token-wise
-  operation — embedding, norms, projections, MLP or experts, LoRA deltas,
-  the head, the sampler, the log-softmax — on that; attention alone
-  unpacks to the slots' layout. `step_tokens = min(slots * chunk,
-  max(slots * (1 + draft window) + chunk, MIN_STEP_TOKENS))` follows from
-  the engine's shapes, so a decode row costs one position and not
-  `prefill_chunk`. The scheduler keeps a step's live tokens within it:
+  operation of the body — embedding, norms, projections, MLP or experts,
+  LoRA deltas — on that; attention alone unpacks to the slots' layout.
+  `step_tokens = min(slots * chunk, max(slots * (1 + draft window) +
+  chunk, MIN_STEP_TOKENS))` follows from the engine's shapes, so a decode
+  row costs one position and not `prefill_chunk`. The step's tail — the
+  vocabulary head, the grammar mask, the selection, the log-softmax —
+  runs on the columns the host reads, `slots x (1 + draft window)`
+  emission rows gathered behind the body (`head_positions` a step), packed
+  or not. The scheduler keeps a step's live tokens within it:
   decode rows always fit, prefill rows ride oldest first with their whole
   chunk or wait a step (`prefill_rows_deferred`);
 - **one step in flight**: a pump pass launches step k+1 (`_launch`) before
@@ -769,6 +772,12 @@ class LLMEngine:
             self.config.num_slots * self.config.prefill_chunk,
             max(self.config.num_slots * window + self.config.prefill_chunk,
                 MIN_STEP_TOKENS))
+        # columns of a row the host reads (its emission column, or a draft
+        # window's), and the positions the step's tail computes for them:
+        # slots x window, or the whole block where that is no fewer
+        self._window = window
+        self._head_positions = min(self.config.num_slots * window,
+                                   self.step_tokens)
         # query positions a step's attention computes, summed over the
         # layers that attend: a latent layer that attends to every key
         # keeps its queries on the packed block, every other kind unpacks
@@ -911,18 +920,36 @@ class LLMEngine:
         inside. Where `step_tokens < N * C` the executable packs the live
         columns (`sum(adv) <= step_tokens`, the scheduler's budget) into
         `step_tokens` rows of width one (`ops.attention.token_pack`, from
-        `adv` and `pos` alone), runs the model, the sampler and the
-        log-softmax on those, attention alone in the slots' layout, and
-        unpacks `sel` and `lp` on the device: a decode row costs one
-        position, not C."""
+        `adv` and `pos` alone) and runs the model's body on those,
+        attention alone in the slots' layout: a decode row costs one
+        position, not C.
+
+        The step's tail (the vocabulary head, the grammar mask, the
+        selection and the log-softmax) works for the columns somebody
+        reads: a row's last `window` live columns, `window` the draft
+        window's 1 + k columns where a draft model is armed and 1
+        otherwise. Their hidden states are gathered behind the body
+        (`emit`: `pack.dst[n, c]` packed, `n * C + c` unpacked, `c =
+        max(adv - window, 0) + j`), the tail runs on `[N, window, V]` in
+        slot order with the slots' own sampling operands, and `sel` / `lp`
+        are laid out `[N, C]` again on the device, the tail's columns at
+        their own places and zeros elsewhere. Where `N * window` is not
+        smaller than the positions the step computes (an unpacked engine
+        whose draft window is as wide as its chunk) nothing is gathered
+        and the tail runs on the block."""
         if self._step_jit is None:
             view = self.pool.view
             prefill = self._prefill_fn
             chunk = self.config.prefill_chunk
             step_tokens = self.step_tokens
-            # a Python branch on static shapes: at the block's own width
-            # the pack is not traced and the step is the program it was
-            packed = step_tokens < self.pool.num_slots * chunk
+            slots, window = self.pool.num_slots, self._window
+            # Python branches on static shapes: at the block's own width
+            # the pack is not traced, and where the tail's rows are no
+            # fewer than the block's positions neither is the gather. A
+            # packed step always gathers (`step_tokens` holds every slot's
+            # window and a chunk besides)
+            packed = step_tokens < slots * chunk
+            narrow = self._head_positions < step_tokens
 
             def step(params, toks, pos, adv, table, slabs, temp, topk,
                      topp, samp, seed, ctr, dstate, gid, bank, feed,
@@ -946,28 +973,38 @@ class LLMEngine:
                 toks = toks.at[:, 0].set(
                     jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
                 paged = view(table, (pos + adv).astype(jnp.int32))
-                pack = None
-                rows_adv, rows_dstate = adv, dstate
+                pack = emit = None
                 if packed:
                     # the live tokens as `step_tokens` rows of width one,
-                    # each with its slot's operands: what follows reads
-                    # them as it reads an unpacked step's rows
+                    # each at its own position: the body reads them as it
+                    # reads an unpacked step's rows
                     pack = token_pack(adv, pos, chunk, step_tokens)
                     toks, pos = pack.pack(toks), pack.pos
-                    adv = pack.live.astype(jnp.int32)
-                    temp, topk, topp, seed, dstate, gid = (
-                        a[pack.slot]
-                        for a in (temp, topk, topp, seed, dstate, gid))
-                    samp = samp[pack.slot] & pack.live
-                    ctr = ctr[pack.slot] + pack.col
                     if adapters is not None:
                         banks, adapter_idx, scale = adapters
                         adapters = (banks, adapter_idx[pack.slot], scale)
+                if narrow:
+                    # a row's last `window` live columns, as flat positions
+                    # of the body's block (a dead column names a position
+                    # that holds finite values nobody reads)
+                    first = jnp.maximum(adv - window, 0)
+                    cols = first[:, None] + jnp.arange(window,
+                                                       dtype=jnp.int32)
+                    emit = (jnp.take_along_axis(pack.dst, cols, axis=1)
+                            if packed else cols + chunk * jnp.arange(
+                                slots, dtype=jnp.int32)[:, None]
+                            ).reshape(-1)
                 with moe.collect_expert_counts() as expert_counts:
                     logits, new_slabs = prefill(params, toks, slabs, pos,
                                                 paged=paged,
                                                 adapters=adapters,
-                                                pack=pack)
+                                                pack=pack, emit=emit)
+                if narrow:
+                    # tail column j is the row's column `first + j`: its
+                    # stream index follows, and `window` columns at most
+                    # are live
+                    logits = logits.reshape(slots, window, -1)
+                    adv, ctr = jnp.minimum(adv, window), ctr + first
                 sel, new_state = select_tokens(
                     logits, adv, temp, topk, topp, samp, seed, ctr,
                     dstate, gid, bank)
@@ -982,12 +1019,15 @@ class LLMEngine:
                 lp = jnp.take_along_axis(
                     jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
                     sel[..., None].astype(jnp.int32), axis=-1)[..., 0]
-                if packed:
-                    # back to the slots' layout the host reads; a slot's
-                    # DFA state is the one behind its last live token
-                    sel, lp = pack.unpack(sel), pack.unpack(lp)
-                    new_state = jnp.where(rows_adv > 0, new_state[pack.last],
-                                          rows_dstate)
+                if narrow:
+                    # back to the `[N, C]` the host and the next step's
+                    # `feed` read: column c holds tail column `c - first`
+                    j = jnp.arange(chunk, dtype=jnp.int32) - first[:, None]
+                    read = (j >= 0) & (j < adv[:, None])
+                    j = jnp.clip(j, 0, window - 1)
+                    sel, lp = (
+                        jnp.where(read, jnp.take_along_axis(a, j, axis=1), 0)
+                        for a in (sel, lp))
                 if moe_totals is None:
                     return sel, lp, new_state, new_slabs
                 # a sparse model (the only kind that is handed totals):
@@ -3082,7 +3122,8 @@ class LLMEngine:
                                                 self.step_tokens, deferred,
                                                 vacant_queued,
                                                 self._attn_positions,
-                                                *self._attn_heads)
+                                                *self._attn_heads,
+                                                self._head_positions)
                     self.metrics.on_paged_rows(
                         one_column, int(np.count_nonzero(adv > 1)))
                     if started:

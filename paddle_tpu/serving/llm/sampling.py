@@ -555,11 +555,28 @@ def select_tokens(logits, adv, temperature, top_k, top_p, do_sample,
     of the CURRENT state applies to every column: constrained rows
     never speculate, so their single emission column is the only one
     consumed; unconstrained rows ride the pass-through row of `bank`.
+
+    The bank is read only by a step that holds a constrained row: the
+    `[N, V]` mask sits in the taken branch of one conditional on
+    `grammar_id` itself (`_select_token`'s pattern: decided on the device
+    from the operands, one executable whatever the mix). Every row on the
+    pass-through row 0 is allowed every token, so the other branch is the
+    float32 logits, which is what the mask gives bit for bit. Outside a
+    conditional the gather runs in every step, and XLA's TPU gather over
+    `[G, S, V]` splits a vocabulary wider than 32,768 and copies the WHOLE
+    bank, piece by piece, to pick N of its rows: 604 MB read and written a
+    step at V = 131,072, for rows that are all on row 0. (Slicing the N
+    rows in a loop instead holds no copy, but a trip costs 19 us on a v5e:
+    4.8 ms for 256 rows against the copy's 2.5.)
     """
     N, C, V = logits.shape
-    allowed = bank[grammar_id, dfa_state] >= 0          # [N, V]
-    masked = jnp.where(allowed[:, None, :],
-                       logits.astype(jnp.float32), -1e30)
+
+    def mask(lg):
+        allowed = bank[grammar_id, dfa_state] >= 0      # [N, V]
+        return jnp.where(allowed[:, None, :], lg, -1e30)
+
+    masked = jax.lax.cond(jnp.any(grammar_id > 0), mask, lambda lg: lg,
+                          logits.astype(jnp.float32))
 
     cols = jnp.arange(C, dtype=jnp.int32)
     keys = jax.vmap(

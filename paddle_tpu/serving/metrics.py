@@ -295,6 +295,7 @@ class LLMMetrics(ServingMetrics):
                               "step_tokens_live": 0,
                               "step_tokens_computed": 0,
                               "attn_query_positions": 0,
+                              "head_positions": 0,
                               "attn_query_heads_full": 0,
                               "attn_query_heads_window": 0,
                               "prefill_rows_deferred": 0,
@@ -547,7 +548,8 @@ class LLMMetrics(ServingMetrics):
 
     def on_step_tokens(self, live: int, computed: int, deferred: int,
                        vacant_queued: int = 0, attn_positions: int = 0,
-                       attn_heads_full: int = 0, attn_heads_window: int = 0):
+                       attn_heads_full: int = 0, attn_heads_window: int = 0,
+                       head_positions: int = 0):
         """One committed unified step: `live` of the `computed` positions
         it ran held a token (`computed` is the engine's `step_tokens`:
         the packed width, or slots x chunk where nothing is packed), and
@@ -564,11 +566,17 @@ class LLMMetrics(ServingMetrics):
         `attn_heads_full` / `attn_heads_window`: the query-head rows the
         full and the windowed walk computed, positions x the layer's own
         query heads over the layers of that kind (the two differ where a
-        model's head count does by layer type)."""
+        model's head count does by layer type). `head_positions`: the
+        positions the step's tail computed (the vocabulary head, the
+        selection, the log-softmax): slots x (1 + draft window) emission
+        rows, or the block's positions where nothing is gathered; over
+        `step_tokens_computed` it is the share of the step the tail still
+        works on."""
         with self._lock:
             self.counters["step_tokens_live"] += int(live)
             self.counters["step_tokens_computed"] += int(computed)
             self.counters["attn_query_positions"] += int(attn_positions)
+            self.counters["head_positions"] += int(head_positions)
             self.counters["attn_query_heads_full"] += int(attn_heads_full)
             self.counters["attn_query_heads_window"] += \
                 int(attn_heads_window)
@@ -905,7 +913,8 @@ class LLMMetrics(ServingMetrics):
         b.sample(f"{px}_sampler_filter_steps_total",
                  s["sampler_filter_steps"])
         for name in ("step_tokens_live", "step_tokens_computed",
-                     "attn_query_positions", "attn_query_heads_full",
+                     "attn_query_positions", "head_positions",
+                     "attn_query_heads_full",
                      "attn_query_heads_window", "prefill_rows_deferred",
                      "slot_steps_vacant_queued",
                      "first_tokens", "ttft_steps", "paged_rows_one_column",

@@ -290,6 +290,62 @@ def test_continuous_batching_beats_batch_locked_bit_identically(gpt_tiny):
     eng.stop()
 
 
+def test_the_steps_tail_on_emission_rows_serves_what_the_block_served(
+        gpt_tiny):
+    """PR 52: the default engine (4 slots x 16, nothing packed, prefix
+    cache on) runs the head, the selection and the log-softmax on its 4
+    emission rows, not on 64 positions. Staggered arrivals, prompts that
+    share a cached prefix (only the tail is prefilled), chunks of 16, 3 and
+    1 columns, decode rows launched ahead through `feed`, free slots: every
+    stream and first token is what the step with its tail on the whole
+    block gives and what `generate()` gives, the log-probabilities to
+    float32 rounding, and `head_positions` counts 4 a step."""
+    from paddle_tpu import serving
+    from paddle_tpu.models.generation import generate
+    from test_packed_step import block_tail_step
+
+    rng = np.random.RandomState(2)
+    shared = rng.randint(1, 500, size=(16,)).astype(np.int32)
+    prompts = [rng.randint(1, 500, size=(n,)).astype(np.int32)
+               for n in (19, 4, 33, 17)]
+    prompts += [np.concatenate([shared, t]) for t in
+                (prompts[0][:3], prompts[1][:1], prompts[2][:9])]
+    news = [5, 9, 3, 7, 4, 6, 8]
+    runs = []
+    for block_tail in (False, True):
+        clock = serving.SimClock()
+        eng = serving.LLMEngine(
+            gpt_tiny,
+            serving.LLMEngineConfig(num_slots=4, block_len=8, n_blocks=8,
+                                    max_queue_depth=64),
+            clock=clock)
+        if block_tail:
+            eng._step_jit = block_tail_step(eng)
+        handles = []
+        for prompt, n in zip(prompts, news):
+            clock.advance(0.01)
+            handles.append(eng.submit(prompt, max_new_tokens=n,
+                                      logprobs=True))
+            eng.pump()
+        while eng.has_work():
+            eng.pump()
+        snap = eng.metrics.snapshot()
+        assert snap["prefix_hit_tokens"] >= 16
+        assert snap["steps_overlapped"] > 0
+        assert snap["head_positions"] == 4 * snap["unified_steps"]
+        assert snap["step_tokens_computed"] == 64 * snap["unified_steps"]
+        runs.append([(h.tokens_so_far(), h.logprobs_so_far())
+                     for h in handles])
+        eng.stop()
+    for (toks, lps), (want, want_lps), prompt, n in zip(*runs, prompts,
+                                                       news):
+        assert toks == want and len(lps) == n
+        np.testing.assert_allclose(lps, want_lps, rtol=1e-5)
+        ref = np.asarray(generate(gpt_tiny, prompt[None],
+                                  max_new_tokens=n).numpy())[0, len(prompt):]
+        assert np.array_equal(toks, ref)
+
+
 def test_eos_retires_row_early_and_frees_its_slot(gpt_tiny):
     """A per-request eos ends the stream at the token that emitted it; the
     slot frees immediately (no decode-to-max), matching generate()'s

@@ -34,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 81 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 83 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 19         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -427,3 +427,26 @@ def test_the_kv_write_compiles_at_every_serve_cells_slabs(tool):
     body = re.search(r"^body kv_write ring=1040: (\d+) equations$", tool,
                      re.M)
     assert body and int(body[1]) < 400
+
+
+def test_the_steps_tail_compiles_on_its_emission_rows_without_the_bank(tool):
+    """PR 52: the unified step's tail at the two reasoning cells' widths.
+    The head's product is `[rows, hidden] x [hidden, vocab]` over the 256 /
+    128 emission rows, not the block's 512 positions; no instruction of the
+    entry computation makes an array of the grammar bank's shape or of a
+    piece of it (XLA's gather copies the bank: inside the conditional's
+    branch alone); beyond the bank the temporaries are under two float32
+    `[rows, vocab]` arrays (the 512-row tail held 1.21 GB at Xing's
+    widths)."""
+    tails = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] step tail bf16")]
+    assert len(tails) == 2
+    for line, rows, vocab in zip(tails, (256, 128), (131072, 100352)):
+        assert f"head product [[{rows}, {vocab}]] over [{rows}, " in line
+        found = re.search(
+            r"(\d+) instructions of the entry computation make an array of "
+            rf"the bank's shape \[9, 128, {vocab}\] or a piece of it, (\d+) "
+            r"inside the conditional; (\d+) bytes of temporaries", line)
+        entry, inside, temps = map(int, found.groups())
+        assert entry == 0 and inside > 0
+        assert temps < (9 * 128 + 2 * rows) * vocab * 4

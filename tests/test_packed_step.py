@@ -5,6 +5,8 @@ gives; the scheduler keeps `sum(adv)` inside the budget, a prefill row that
 does not fit waits a step; at `step_tokens == slots x chunk` nothing of the
 pack is traced. CPU, float32, tiny models under a `SimClock`.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -363,18 +365,96 @@ def test_decode_rows_are_placed_first_and_a_smaller_chunk_may_fill(gpt_tiny):
     eng.stop()
 
 
-# ---- (d) at step_tokens == slots x chunk nothing is packed ------------------
+# ---- (d) what a step lowers to ----------------------------------------------
 
-def _lowered(eng, prompt):
-    eng.submit(prompt, max_new_tokens=2)
+def step_args(eng, drafts=None):
+    """(`adv`, the operands of the next step as `_launch` would build
+    them): the queue admitted, rows from the committed state."""
     with eng._cond:
         eng._admit()
-        toks, pos, adv, ctr, *_ = eng._build_rows_locked({})
+        toks, pos, adv, ctr, *_ = eng._build_rows_locked(drafts or {})
         args = (eng.params, jnp.asarray(toks), jnp.asarray(pos),
                 jnp.asarray(adv), eng.pool.device_block_table(),
                 eng.pool.slabs) + eng._sampling_args_locked(ctr) \
             + eng._feedback_args() + eng._tail_args_locked()
+    return adv, args
+
+
+def both_tails(eng, args):
+    """One step's `(sel, lp, state)` through `block_tail_step` and through
+    the engine's own step, each donated a copy of the pool."""
+    copy = lambda: jax.tree_util.tree_map(jnp.copy, eng.pool.slabs)
+    return [tuple(np.asarray(a) for a in fn(*args[:5], copy(), *args[6:])[:3])
+            for fn in (block_tail_step(eng), eng._step())]
+
+
+def _lowered(eng, prompt):
+    eng.submit(prompt, max_new_tokens=2)
+    _, args = step_args(eng)
     return args, eng._step().lower(*args).as_text()
+
+
+def _exp_shapes(text, vocab):
+    """Shapes of the float32 exponentials over the vocabulary in a lowered
+    step: the log-softmax's, so the rows the tail runs on."""
+    return set(re.findall(
+        rf"stablehlo\.exponential %\d+ : tensor<(\d+x\d+x{vocab})xf32>", text))
+
+
+def block_tail_step(eng):
+    """The unified step with its tail over the whole block, written out: the
+    form every step had before the tail was narrowed (PR 52) and the one an
+    engine still traces where `slots x window` is no fewer than the
+    positions it computes. The head, the selection and the log-softmax run
+    on every computed position (`[step_tokens, 1, V]` under a pack, `[N, C,
+    V]` without), with each slot's sampling operands gathered per packed
+    token, and `sel` / `lp` hold a selection at every column. Takes
+    `eng._step()`'s operands and gives its results: set as `eng._step_jit`
+    before an engine's first step, the engine runs it instead."""
+    view, prefill = eng.pool.view, eng._prefill_fn
+    chunk, step_tokens = eng.config.prefill_chunk, eng.step_tokens
+    packed = step_tokens < eng.pool.num_slots * chunk
+
+    def step(params, toks, pos, adv, table, slabs, temp, topk, topp, samp,
+             seed, ctr, dstate, gid, bank, feed, prev_sel, adapters=None,
+             moe_totals=None):
+        # `slabs` is donated: the new slabs take its buffers
+        # the input token of a row launched ahead of its predecessor
+        fed = jnp.take_along_axis(
+            prev_sel, jnp.maximum(feed, 0)[:, None], axis=1)[:, 0]
+        toks = toks.at[:, 0].set(
+            jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
+        paged = view(table, (pos + adv).astype(jnp.int32))
+        pack = None
+        rows_adv, rows_dstate = adv, dstate
+        if packed:
+            pack = token_pack(adv, pos, chunk, step_tokens)
+            toks, pos = pack.pack(toks), pack.pos
+            adv = pack.live.astype(jnp.int32)
+            temp, topk, topp, seed, dstate, gid = (
+                a[pack.slot] for a in (temp, topk, topp, seed, dstate, gid))
+            samp = samp[pack.slot] & pack.live
+            ctr = ctr[pack.slot] + pack.col
+            if adapters is not None:
+                banks, adapter_idx, scale = adapters
+                adapters = (banks, adapter_idx[pack.slot], scale)
+        with llm_engine.moe.collect_expert_counts() as counts:
+            logits, new_slabs = prefill(params, toks, slabs, pos,
+                                        paged=paged, adapters=adapters,
+                                        pack=pack)
+        sel, state = select_tokens(logits, adv, temp, topk, topp, samp,
+                                   seed, ctr, dstate, gid, bank)
+        lp = jnp.take_along_axis(
+            jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
+            sel[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        if packed:
+            sel, lp = pack.unpack(sel), pack.unpack(lp)
+            state = jnp.where(rows_adv > 0, state[pack.last], rows_dstate)
+        if moe_totals is None:
+            return sel, lp, state, new_slabs
+        return sel, lp, state, new_slabs, moe_totals + jnp.stack(counts)
+
+    return jax.jit(step, donate_argnames=("slabs",))
 
 
 @pytest.mark.parametrize("family", ["gpt2-tiny", "llama-tiny", "olmoe-tiny"])
@@ -382,8 +462,10 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
         models, family, monkeypatch):
     """`step_tokens == slots x chunk` (every engine the suite built before
     this file, and the benchmark's prefill cells at 32 x 16): the step's
-    text is the text of the step as it was, written out here without a
-    pack; `token_pack` is never called; a packed engine's text differs."""
+    text is the text of the step written out here without a pack, its tail
+    on the slots' emission rows (`n * C + adv - 1`); `token_pack` is never
+    called; a packed engine's text differs, and its float32 logits are
+    `[slots, 1, V]`, not the 512 packed positions'."""
     model = models(family)
     prompt = _prompts(model.config.vocab_size, [11])[0]
     eng = _engine(model, slots=4)
@@ -408,14 +490,28 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
         toks = toks.at[:, 0].set(
             jnp.where(feed >= 0, fed.astype(toks.dtype), toks[:, 0]))
         paged = view(table, (pos + adv).astype(jnp.int32))
+        # the one column a row's host reads, as a position of `[N * C]`
+        first = jnp.maximum(adv - 1, 0)
+        cols = first[:, None] + jnp.arange(1, dtype=jnp.int32)
+        emit = (cols + CHUNK * jnp.arange(4, dtype=jnp.int32)[:, None]
+                ).reshape(-1)
         with llm_engine.moe.collect_expert_counts() as counts:
             logits, new_slabs = prefill(params, toks, slabs, pos,
-                                        paged=paged, adapters=None)
+                                        paged=paged, adapters=None,
+                                        pack=None, emit=emit)
+        logits = logits.reshape(4, 1, -1)
+        adv, ctr = jnp.minimum(adv, 1), ctr + first
         sel, state = select_tokens(logits, adv, temp, topk, topp, samp,
                                    seed, ctr, dstate, gid, bank)
         lp = jnp.take_along_axis(
             jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
             sel[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        # `[N, C]` again: the column at its own place, zeros elsewhere
+        j = jnp.arange(CHUNK, dtype=jnp.int32) - first[:, None]
+        read = (j >= 0) & (j < adv[:, None])
+        j = jnp.clip(j, 0, 0)
+        sel, lp = (jnp.where(read, jnp.take_along_axis(a, j, axis=1), 0)
+                   for a in (sel, lp))
         if moe_totals is None:
             return sel, lp, state, new_slabs
         return sel, lp, state, new_slabs, moe_totals + jnp.stack(counts)
@@ -425,12 +521,121 @@ def test_an_engine_no_wider_than_step_tokens_lowers_to_the_old_step(
     assert text == jax.jit(
         step, donate_argnames=("slabs",)).lower(*args).as_text()
 
+    vocab = model.config.vocab_size
     packed_eng = _engine(model)
     _, packed_text = _lowered(packed_eng, prompt)
-    assert f"tensor<512x1x{model.config.vocab_size}xf32>" in packed_text
-    assert f"tensor<512x1x{model.config.vocab_size}xf32>" not in text
+    # the log-softmax's exponential says what the tail runs on (gpt2-tiny's
+    # MLP is as wide as its vocabulary: a bare shape would not)
+    assert _exp_shapes(packed_text, vocab) == {f"{SLOTS}x1x{vocab}"}
+    assert _exp_shapes(text, vocab) == {f"4x1x{vocab}"}
     assert packed_text.count("stablehlo.gather") > text.count(
         "stablehlo.gather")
+
+
+@pytest.mark.parametrize("family", ["gpt2-tiny", "llama-tiny"])
+def test_a_tail_no_narrower_than_the_block_lowers_to_the_block_tail_step(
+        models, family, monkeypatch):
+    """`slots x window >= step_tokens`: an unpacked engine whose draft
+    window is as wide as its chunk reads every column of every row.
+    Nothing is gathered (`take_positions` is never called) and the step's
+    text is, to the character, the step whose tail runs on the block; one
+    column narrower, the tail is `[slots, window, V]`."""
+    model = models(family)
+    prompt = _prompts(model.config.vocab_size, [11])[0]
+    eng = _engine(model, slots=4, draft=model, prefill_chunk=4, spec_k=3)
+    assert eng.step_tokens == 4 * 4 == eng._head_positions
+
+    def no_gather(*a, **k):
+        raise AssertionError("emission rows gathered at slots x window "
+                             ">= step_tokens")
+
+    from paddle_tpu.models import gpt, llama
+    monkeypatch.setattr(gpt, "take_positions", no_gather)
+    monkeypatch.setattr(llama, "take_positions", no_gather)
+    args, text = _lowered(eng, prompt)
+    monkeypatch.undo()
+    assert text == block_tail_step(eng).lower(*args).as_text()
+    vocab = model.config.vocab_size
+    assert _exp_shapes(text, vocab) == {f"4x4x{vocab}"}
+
+    narrow = _engine(model, slots=4, draft=model, prefill_chunk=4, spec_k=2)
+    assert narrow._head_positions == 4 * 3 < narrow.step_tokens
+    _, narrow_text = _lowered(narrow, prompt)
+    assert _exp_shapes(narrow_text, vocab) == {f"4x3x{vocab}"}
+
+
+def _run(eng, prompts, news, **kw):
+    hs = [eng.submit(p, max_new_tokens=n, logprobs=True, **kw)
+          for p, n in zip(prompts, news)]
+    _drain(eng)
+    return [(h.tokens_so_far(), h.logprobs_so_far()) for h in hs]
+
+
+@pytest.mark.parametrize("slots", [4, SLOTS])
+def test_the_narrowed_tail_reads_what_the_block_tail_read(gpt_tiny, slots):
+    """Every kind of row in one run, through the step as it is and through
+    `block_tail_step`: a prompt's middle chunk and its last (24 and 40
+    tokens), plain decode rows, rows launched ahead through `feed` (every
+    step but the first), slots that stand free while others still decode
+    (streams of 3 to 9 tokens), and on the packed engine rows deferred for
+    room (40 prompts want 640 positions). Tokens and first tokens are the
+    same and `generate()`'s; the log-probabilities agree to float32
+    rounding (the CPU's matrix product gives a row of `[slots, hidden] x
+    [hidden, V]` other last bits than the same row of `[512, hidden] x
+    [hidden, V]`; given the same logits the tail is bit for bit the
+    block's, `tests/test_sampling.py`)."""
+    vocab = gpt_tiny.config.vocab_size
+    lengths = ([24, 40, 5, 19, 33, 17] * 7)[:slots + 2]
+    prompts = _prompts(vocab, lengths, seed=11)
+    news = [3 + i % 7 for i in range(len(prompts))]
+    runs = []
+    for block_tail in (False, True):
+        eng = _engine(gpt_tiny, slots=slots)
+        if block_tail:
+            eng._step_jit = block_tail_step(eng)
+        runs.append(_run(eng, prompts, news))
+        snap = eng.metrics.snapshot()
+        assert snap["steps_overlapped"] == snap["unified_steps"] - 1
+        assert (snap["prefill_rows_deferred"] > 0) == (slots == SLOTS)
+        assert snap["head_positions"] == slots * snap["unified_steps"]
+        eng.stop()
+    for (toks, lps), (want_toks, want_lps), p, n in zip(*runs, prompts,
+                                                        news):
+        assert toks == want_toks and len(toks) == n
+        np.testing.assert_allclose(lps, want_lps, rtol=1e-5)
+    for (toks, _), p, n in list(zip(runs[0], prompts, news))[:6]:
+        np.testing.assert_array_equal(toks, _generate(gpt_tiny, p, n))
+
+
+@pytest.mark.parametrize("slots", [4, SLOTS])
+def test_columns_nobody_reads_hold_zeros(gpt_tiny, slots):
+    """One step over chunks, a decode row, a free slot and (packed) deferred
+    rows: `sel` and `lp` hold at column `adv - 1` what the block's tail
+    gives there (the token; the log-probability to float32 rounding),
+    zeros in every other column, and all zeros in a row that rode with
+    `adv` 0; the DFA states are the same."""
+    vocab = gpt_tiny.config.vocab_size
+    eng = _engine(gpt_tiny, slots=slots)
+    first = eng.submit(_prompts(vocab, [3])[0], max_new_tokens=4)
+    eng._admit()
+    eng._retire(eng._launch())          # one row decodes from here on
+    assert len(first.tokens_so_far()) == 1
+    lengths = [24] * (slots - 2) if slots == SLOTS else [24, 7]
+    for p in _prompts(vocab, lengths, seed=2):
+        eng.submit(p, max_new_tokens=2)
+    adv, args = step_args(eng)
+    kinds = {int(a) for a in adv}
+    assert kinds == ({0, 1, 16} if slots == SLOTS else {0, 1, 7, 16})
+    if slots == SLOTS:                  # 38 chunks want 608 positions
+        assert int(adv.sum()) <= 512 and (adv == 0).sum() > 1
+    (want_sel, want_lp, want_state), (sel, lp, state) = both_tails(eng, args)
+    read = np.arange(CHUNK)[None, :] == (adv - 1)[:, None]
+    np.testing.assert_array_equal(sel[read], want_sel[read])
+    np.testing.assert_allclose(lp[read], want_lp[read], rtol=1e-5)
+    assert read.sum() == (adv > 0).sum() and (lp[read] < 0).all()
+    assert not sel[~read].any() and not lp[~read].any()
+    np.testing.assert_array_equal(state, want_state)
+    eng.stop()
 
 
 def test_an_unpacked_engine_counts_the_whole_block_a_step(gpt_tiny):

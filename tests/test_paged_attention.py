@@ -1385,6 +1385,13 @@ def test_engine_counts_its_rows_by_their_live_columns(gpt_tiny):
         * snap["unified_steps"]
     assert snap["attn_query_positions"] == positions > 0
     assert f"pdtpu_llm_attn_query_positions_total {positions}" in text
+    # the step's tail runs on the emission rows: one a slot (no draft
+    # window), a sixteenth of what the step computes
+    head = eng.config.num_slots * snap["unified_steps"]
+    assert snap["head_positions"] == head > 0
+    assert snap["head_positions"] * eng.config.prefill_chunk \
+        == snap["step_tokens_computed"]
+    assert f"pdtpu_llm_head_positions_total {head}" in text
     eng.stop()
 
 

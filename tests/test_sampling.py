@@ -602,13 +602,159 @@ def test_sampler_holds_one_sort_and_only_inside_a_cond(fn):
         jaxpr = jax.make_jaxpr(sampling.select_next)(
             f32(N, V), f32(N), i32(N), f32(N), samp, i32(N), i32(N))
     assert _count_eqns(jaxpr.jaxpr, "sort") == (1, 0)
-    # one three-way conditional: greedy / draw / filter and draw
-    assert _count_eqns(jaxpr.jaxpr, "cond") == (1, 1)
+    # one three-way conditional: greedy / draw / filter and draw; and, in
+    # `select_tokens`, the one that keeps the grammar bank out of a step
+    # with no constrained row (PR 52)
+    conds = 2 if fn == "select_tokens" else 1
+    assert _count_eqns(jaxpr.jaxpr, "cond") == (conds, conds)
     for prim in ("cumsum", "random_bits", "div"):
         total, outside = _count_eqns(jaxpr.jaxpr, prim)
         assert total > 0 and outside == 0, prim
     # the argmax every row needs stays outside
     assert _count_eqns(jaxpr.jaxpr, "argmax")[1] == 1
+
+
+def _masked_select_tokens(logits, adv, temperature, top_k, top_p, do_sample,
+                          seed, ctr, dfa_state, grammar_id, bank):
+    """`select_tokens` as it was before PR 52, written out: every row's mask
+    gathered from the bank in every step, the pass-through row included."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.generation import _select_token
+    from paddle_tpu.serving.llm.sampling import lane_key
+    N, C, V = logits.shape
+    allowed = bank[grammar_id, dfa_state] >= 0
+    masked = jnp.where(allowed[:, None, :], logits.astype(jnp.float32),
+                       -1e30)
+    cols = jnp.arange(C, dtype=jnp.int32)
+    keys = jax.vmap(
+        lambda s, c0: jax.vmap(lambda t: lane_key(s, c0 + t))(cols)
+    )(seed, ctr)
+    rep = lambda a: jnp.repeat(a, C)
+    toks = _select_token(
+        masked.reshape(N * C, V), rep(jnp.asarray(do_sample, bool)),
+        rep(temperature), rep(top_k), keys.reshape(N * C, 2),
+        rep(top_p)).reshape(N, C)
+    tok_e = jnp.take_along_axis(
+        toks, jnp.maximum(adv - 1, 0)[:, None], axis=1)[:, 0]
+    stepped = bank[grammar_id, dfa_state, tok_e]
+    return toks, jnp.where((grammar_id > 0) & (adv > 0),
+                           jnp.maximum(stepped, 0), dfa_state)
+
+
+def _tail_operands(constrained, dtype):
+    """Six rows of three columns over 64 tokens: greedy and drawing rows,
+    free ones, and (`constrained`) two rows held to grammar 1 or 2."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(3)
+    N, C, V = 6, 3, 64
+    bank = np.full((3, 4, V), -1, np.int32)
+    bank[0, 0] = 0
+    # grammar 1: token v moves state s to (s + v) % 3, odd tokens illegal;
+    # grammar 2: only tokens 8..15, each to state 1
+    bank[1, :3] = (np.arange(3)[:, None] + np.arange(V)[None, :]) % 3
+    bank[1, :, 1::2] = -1
+    bank[2, :2, 8:16] = 1
+    gid = [0, 1, 0, 0, 2, 0] if constrained else [0] * N
+    return (jnp.asarray(rng.normal(size=(N, C, V)) * 3, dtype),
+            jnp.asarray([0, 3, 1, 0, 2, 3], jnp.int32),
+            jnp.asarray(rng.uniform(0.5, 1.5, N), jnp.float32),
+            jnp.asarray([0, 5, 0, 0, 9, 0], jnp.int32),
+            jnp.asarray([1, 0.9, 1, 1, 1, 0.8], jnp.float32),
+            jnp.asarray([False, True, False, False, True, True]),
+            jnp.asarray(rng.integers(0, 1 << 30, N), jnp.int32),
+            jnp.asarray(rng.integers(0, 50, N), jnp.int32),
+            jnp.asarray([0, 2, 0, 0, 1, 0], jnp.int32) * (1 if constrained
+                                                         else 0),
+            jnp.asarray(gid, jnp.int32), jnp.asarray(bank))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("constrained", [False, True])
+@pytest.mark.parametrize("jitted", [False, True])
+def test_the_bank_is_read_only_for_a_constrained_row_and_changes_no_bit(
+        constrained, jitted, dtype):
+    """PR 52: with every row on the pass-through row 0 the mask is not
+    built and the selections are the masked form's, bit for bit; a step
+    with a grammar row builds it and every
+    row, constrained or not, selects what it selected, with the same new
+    DFA states."""
+    import jax
+    from paddle_tpu.serving.llm import sampling
+    args = _tail_operands(constrained, dtype)
+    want, want_state = _masked_select_tokens(*args)
+    fn = jax.jit(sampling.select_tokens) if jitted \
+        else sampling.select_tokens
+    got, state = fn(*args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(state), np.asarray(want_state))
+    if constrained:
+        got = np.asarray(got)
+        assert (got[1] % 2 == 0).all() and ((got[4] >= 8) & (got[4] < 16)
+                                            ).all()
+        assert np.asarray(state).tolist() == [
+            0, (2 + int(got[1, 2])) % 3, 0, 0, 1, 0]
+
+
+def test_the_grammar_bank_is_gathered_only_inside_the_conditional():
+    """The `[N, V]` mask is no equation of `select_tokens`' own jaxpr: the
+    rows of the bank are gathered inside a branch of the conditional on
+    `grammar_id`, and what stays outside reads N scalars of it, the
+    stepped states."""
+    import jax
+    from paddle_tpu.serving.llm import sampling
+    args = _tail_operands(True, "float32")
+    jaxpr = jax.make_jaxpr(sampling.select_tokens)(*args).jaxpr
+    N, _, V = args[0].shape
+
+    def gathers(jp, inside_cond=False):
+        for eqn in jp.eqns:
+            if eqn.primitive.name in ("gather", "dynamic_slice"):
+                yield (inside_cond, eqn.invars[0].aval.shape,
+                       eqn.outvars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from gathers(
+                    sub, inside_cond or eqn.primitive.name == "cond")
+
+    of_bank = [g for g in gathers(jaxpr) if g[1] == args[-1].shape]
+    assert sorted(of_bank) == [(False, args[-1].shape, (N,)),
+                               (True, args[-1].shape, (N, V))]
+
+
+def test_a_prompts_last_chunk_draws_on_its_streams_own_lane(gpt_tiny):
+    """A seeded sampling request whose prompt ends in a chunk of 3 and one
+    of 16 columns: the tail's one column is the chunk's last, and its
+    lane `(seed, stream index)` is the one the block's tail gave that
+    column (`ctr` of the tail's column 0 is `ctr + adv - window`): the
+    streams through the step and through `block_tail_step` are the same,
+    a draft window's verify columns included."""
+    from paddle_tpu import serving
+    from test_packed_step import block_tail_step
+    sp = _params(temperature=0.9, top_k=16, top_p=0.95, seed=4242)
+    prompts = [np.arange(1, 20, dtype=np.int32),        # 16 + 3
+               np.arange(3, 35, dtype=np.int32)]        # 16 + 16
+    for draft in (None, gpt_tiny):
+        outs = []
+        for block_tail in (False, True):
+            clock = serving.SimClock()
+            eng = _engine(gpt_tiny, clock, draft=draft, n_blocks=16,
+                          enable_prefix_cache=False)
+            if block_tail:
+                eng._step_jit = block_tail_step(eng)
+            hs = [eng.submit(p, max_new_tokens=9, sampling=sp)
+                  for p in prompts]
+            _drain(eng, clock)
+            outs.append([h.tokens_so_far() for h in hs])
+            if draft is not None:
+                assert eng.metrics.snapshot()["spec_windows"] > 0
+            eng.stop()
+        assert outs[0] == outs[1] and all(len(t) == 9 for t in outs[0])
+    clock = serving.SimClock()
+    eng = _engine(gpt_tiny, clock, n_blocks=16)
+    greedy = eng.submit(prompts[0], max_new_tokens=9)
+    _drain(eng, clock)
+    assert greedy.tokens_so_far() != outs[0][0]
+    eng.stop()
 
 
 def test_engine_counts_sampling_steps_and_never_recompiles(gpt_tiny):

@@ -494,3 +494,107 @@ def test_router_failover_mid_draft_window_resumes_bit_identical(gpt_tiny):
     assert 0.0 <= rates["replica1"] <= 1.0
     replicas[1].engine.pool.check_balance()
     replicas[1].engine.draft_pool.check_balance()
+
+
+# ---- the step's tail on a draft window's columns (PR 52) ----
+
+_TOKENS = {1: "{", 2: "}", 3: '"a"', 4: ":", 5: "1", 6: "23", 7: ",",
+           8: '"b"', 9: "true", 10: "false"}
+_SCHEMA = {"type": "object",
+           "properties": {"a": {"type": "integer"}, "b": {"type": "boolean"}},
+           "required": ["a", "b"]}
+
+
+def _grammar():
+    from paddle_tpu.serving.llm.sampling import SamplingParams
+    return SamplingParams(temperature=1.0, seed=7,
+                          grammar={"schema": _SCHEMA, "tokens": _TOKENS})
+
+
+@pytest.mark.parametrize("slots,matched", [(3, True), (3, False),
+                                           (40, True), (40, False)])
+def test_a_draft_armed_tail_reads_its_verify_columns(gpt_tiny, gpt_tiny_alt,
+                                                    slots, matched):
+    """A draft-armed engine's tail runs on `slots x (1 + k)` rows (the
+    step's `window`), unpacked (3 slots) and packed (40): verify windows
+    of a draft the target accepts whole (itself) or rejects (other
+    weights: the corrective token is column 0's), a grammar row that never
+    speculates (one column) and prompts that still prefill (the last
+    `window` columns of a chunk) ride the same steps. Streams, acceptance
+    counts and the steps taken are what the block's tail gives."""
+    from paddle_tpu import serving
+    from test_packed_step import block_tail_step
+
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(1, 500, size=(s,)).astype(np.int32)
+               for s in (4, 7, 21, 40)]
+    runs = []
+    for block_tail in (False, True):
+        clock = serving.SimClock()
+        eng = _engine(gpt_tiny, clock,
+                      draft=gpt_tiny if matched else gpt_tiny_alt,
+                      num_slots=slots, n_blocks=8, enable_prefix_cache=False)
+        assert eng._window == 5 and eng._head_positions == slots * 5
+        assert eng._head_positions < eng.step_tokens
+        if block_tail:
+            eng._step_jit = block_tail_step(eng)
+        hs = [eng.submit(p, max_new_tokens=12) for p in prompts[:2]]
+        hs.append(eng.submit(np.arange(1, 9, dtype=np.int32),
+                             max_new_tokens=10, sampling=_grammar()))
+        eng.pump()
+        eng.pump()                      # windows ride from here on
+        hs += [eng.submit(p, max_new_tokens=6) for p in prompts[2:]]
+        _drain(eng, clock)
+        snap = eng.metrics.snapshot()
+        runs.append(([h.tokens_so_far() for h in hs],
+                     {k: snap[k] for k in (
+                         "spec_windows", "spec_drafted", "spec_accepted",
+                         "unified_steps", "constrained_tokens")}))
+        assert snap["head_positions"] == slots * 5 * snap["unified_steps"]
+        eng.stop()
+    assert runs[0] == runs[1]
+    streams, counts = runs[0]
+    assert counts["spec_windows"] > 0 and counts["constrained_tokens"] > 0
+    assert (counts["spec_accepted"] > counts["spec_drafted"] // 2) == matched
+    for p, toks, n in zip(prompts, [streams[0], streams[1], *streams[3:]],
+                          (12, 12, 6, 6)):
+        assert np.array_equal(toks, _ref(gpt_tiny, p, n))
+
+
+@pytest.mark.parametrize("slots", [4, 40])
+def test_one_step_of_a_window_a_decode_row_and_a_chunk(gpt_tiny, slots):
+    """One step that holds a full verify window (`adv` 5), a one-column
+    decode row (a grammar row), a chunk of 16 and one of 5 columns: `sel`
+    holds the block's tail's selections at a row's last `min(adv, 5)`
+    columns and zeros in every other, so the chunk of 16 shows columns
+    11-15; the DFA states are the block's tail's."""
+    from paddle_tpu import serving
+    from test_packed_step import both_tails, step_args
+
+    clock = serving.SimClock()
+    eng = _engine(gpt_tiny, clock, draft=gpt_tiny, num_slots=slots,
+                  n_blocks=8, enable_prefix_cache=False)
+    rng = np.random.RandomState(3)
+    eng.submit(rng.randint(1, 500, size=(6,)).astype(np.int32),
+               max_new_tokens=12)
+    eng.submit(np.arange(1, 9, dtype=np.int32), max_new_tokens=10,
+               sampling=_grammar())
+    eng.pump()
+    eng.pump()
+    eng.submit(rng.randint(1, 500, size=(30,)).astype(np.int32),
+               max_new_tokens=4)
+    eng.submit(rng.randint(1, 500, size=(5,)).astype(np.int32),
+               max_new_tokens=4)
+    eng._admit()
+    adv, args = step_args(eng, eng._draft_phase())
+    assert sorted(int(a) for a in adv if a) == [1, 5, 5, 16]
+    (want_sel, want_lp, want_state), (sel, lp, state) = both_tails(eng, args)
+    cols = np.arange(eng.config.prefill_chunk)[None, :]
+    read = (cols < adv[:, None]) & (cols >= (adv - 5)[:, None])
+    assert read.sum() == 1 + 5 + 5 + 5
+    np.testing.assert_array_equal(sel[read], want_sel[read])
+    np.testing.assert_allclose(lp[read], want_lp[read], rtol=1e-5)
+    assert not sel[~read].any() and not lp[~read].any()
+    np.testing.assert_array_equal(state, want_state)
+    assert int(state.max()) > 0         # the grammar row stepped
+    eng.stop()

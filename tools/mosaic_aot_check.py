@@ -195,6 +195,65 @@ def serve_step_case(name, model, device, want) -> bool:
     return ok
 
 
+def serve_tail_case(name, rows, hidden, vocab, spec) -> bool:
+    """Compile the unified step's tail at a serve cell's widths: the
+    emission rows of a 512-position block (`take_positions`), the head's
+    product, `select_tokens` with the bank of 8 grammars x 128 states the
+    engine holds, the log-softmax at the selections. The product must be
+    `[rows, hidden] x [hidden, vocab]`. No instruction of the entry
+    computation may make an array of the bank's shape or of a piece of it:
+    XLA's TPU gather splits the vocabulary and copies the whole bank to
+    pick its rows, in every step before PR 52 and since then inside the
+    conditional's branch that only a step with a constrained row runs.
+    Beyond that copy the temporaries must stay within two float32 `[rows,
+    vocab]` arrays."""
+    from paddle_tpu.ops.attention import take_positions
+    from paddle_tpu.serving.llm.sampling import select_tokens
+
+    def tail(block, emit, w, adv, temp, topk, topp, samp, seed, ctr, dstate,
+             gid, bank):
+        logits = (take_positions(block, emit) @ w).reshape(rows, 1, vocab)
+        sel, state = select_tokens(logits, adv, temp, topk, topp, samp,
+                                   seed, ctr, dstate, gid, bank)
+        lp = jnp.take_along_axis(
+            jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
+            sel[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        return sel, lp, state
+
+    i32, f32 = spec((rows,), jnp.int32), spec((rows,), jnp.float32)
+    bank = (9, 128, vocab)
+    t0 = time.perf_counter()
+    try:
+        compiled = jax.jit(tail).lower(
+            spec((512, 1, hidden), jnp.bfloat16), i32,
+            spec((hidden, vocab), jnp.bfloat16), i32, f32, i32, f32,
+            spec((rows,), jnp.bool_), i32, i32, i32, i32,
+            spec(bank, jnp.int32)).compile()
+    except Exception as e:  # the tool's job is to report every case
+        print(f"[FAIL] {name}: {type(e).__name__}: {str(e)[:1500]}",
+              flush=True)
+        return False
+    text = compiled.as_text()
+    products = re.findall(r"= bf16\[(\d+),(\d+)\]\S* convolution\(", text)
+    # instructions that make (not merely hand on) the bank or a piece
+    makes = (r"= \(?s32\[9,128,\d+\][^=]*? (?:fusion|copy|copy-start|gather|"
+             r"slice|dynamic-slice)\(")
+    entry = len(re.findall(makes, text[text.index("\nENTRY "):]))
+    inside = len(re.findall(makes, text)) - entry
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    bank_bytes, logits_bytes = 9 * 128 * vocab * 4, rows * vocab * 4
+    ok = products == [(str(rows), str(vocab))] and not entry \
+        and temps < bank_bytes + 2 * logits_bytes
+    print(f"[{'OK' if ok else 'FAIL'}] {name}: head product "
+          f"{[list(map(int, p)) for p in products]} over [{rows}, {hidden}]; "
+          f"{entry} instructions of the entry computation make an array of "
+          f"the bank's shape {list(bank)} or a piece of it, {inside} inside "
+          f"the conditional; {temps} bytes of temporaries (the bank "
+          f"{bank_bytes}, a float32 [rows, vocab] {logits_bytes}) in "
+          f"{time.perf_counter() - t0:.1f}s", flush=True)
+    return ok
+
+
 def slab_loops_and_copies(hlo: str, slabs) -> tuple:
     """(`while` instructions that carry an array of a K/V slab's shape,
     `copy` instructions that make one) in compiled HLO text. A vmapped
@@ -691,6 +750,13 @@ def main() -> int:
     results.append(serve_step_case(
         "serve step, 2 latent layers in 4 hyper-connected streams", model,
         dev1[0], 7))
+    # the step's tail at the two reasoning cells' widths (PR 52): 256 and
+    # 128 emission rows of 512 positions
+    for cell, rows, hidden, vocab in (("xing", 256, 3584, 131072),
+                                      ("laguna", 128, 2048, 100352)):
+        results.append(serve_tail_case(
+            f"step tail bf16 {cell} cell: {rows} rows of 512, "
+            f"{hidden} -> {vocab}", rows, hidden, vocab, spec))
     from paddle_tpu.ops import pallas_mode
     for (kernel, tiling), n in sorted(pallas_mode.KERNEL_TILINGS.items()):
         print(f"tiling {kernel} x{n}: {dict(tiling)}", flush=True)
