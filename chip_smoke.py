@@ -16,7 +16,8 @@ weights are random, from a seed):
 Each leg first checks the Pallas kernel it depends on against the repo's
 own reference ON THE DEVICE (flash fwd + dq/dk/dv vs `_attention_reference`;
 `ragged_paged_attention(impl="pallas")`, full walk and windowed walk through
-a ring, `ssm_update(impl="pallas")` vs `impl="scan"`, and the K/V write's
+a ring, `ssm_update(impl="pallas")` and `kda_update(impl="pallas")` vs
+`impl="scan"`, and the K/V write's
 `kv_write` bit for bit vs the vmapped `dynamic_update_slice`) and then
 requires that kernel's Mosaic custom calls in the compiled step it just ran. Any
 failed check raises; nothing is caught to let a leg fail while the run
@@ -94,6 +95,9 @@ FULL = dict(
     # the hyper-connected cell's residual path: 4 streams of 3,584 over a
     # packed step's 512 positions
     hyper=dict(streams=4, hidden=3584, rows=512),
+    # the linear-attention cell's KDA layers: 64 heads of 128 x 128, 256
+    # slots in a packed step of 512 tokens
+    kda=dict(heads=64, head_dim=128, rows=256, tokens=512),
 )
 TINY = dict(
     preset="gpt2-tiny", batch_per_chip=2, seq=128, scan_steps=2,
@@ -113,6 +117,7 @@ TINY = dict(
                 index_dim=128, topk=32, pages=20),
     ssm=dict(heads=4, head_dim=64, state=16),
     hyper=dict(streams=4, hidden=128, rows=40),
+    kda=dict(heads=2, head_dim=128, rows=6, tokens=40),
 )
 
 
@@ -943,6 +948,83 @@ def _ssm_parity(size: dict):
             f"{ms:.3f} ms a call {name}" for name, ms in took.items()))
 
 
+def _kda_parity(size: dict):
+    """`kda_update(impl="pallas")` against `impl="scan"` on this device at
+    `size["kda"]` (the linear-attention cell's: 256 rows of 64 heads over a
+    packed block of 512 token rows, a float32 state): every row one live
+    column (a decode step), and rows of sixteen beside rows of one, a dead
+    row and fresh rows. Both run the same float32 arithmetic and differ in
+    the order of their sums: 1e-4 of the largest value. The two shapes are
+    also timed, a call alone, the state carried from call to call: what a
+    one-column row and a sixteen-column row cost (`ops/kda.py` has no
+    chunked body). Prints the kernel's grid and state tile."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import kda, pallas_mode
+    g = size["kda"]
+    H, d, rows, tokens, tol = g["heads"], g["head_dim"], g["rows"], \
+        g["tokens"], 1e-4
+    rng = np.random.RandomState(3)
+
+    def unit(x):
+        x = x.reshape(tokens, H, d)
+        return (x / np.linalg.norm(x, axis=-1, keepdims=True)).reshape(
+            tokens, H * d)
+
+    q = jnp.asarray(unit(rng.randn(tokens, H * d)) * d ** -0.5, jnp.float32)
+    k = jnp.asarray(unit(rng.randn(tokens, H * d)), jnp.float32)
+    v = jnp.asarray(rng.randn(tokens, H * d), jnp.bfloat16)
+    decay = jnp.asarray(-rng.uniform(0.001, 1.5, (tokens, H * d)),
+                        jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 2.0, (tokens, H)), jnp.float32)
+    state = jnp.asarray(rng.randn(rows, d, H * d), jnp.float32)
+    wide = np.ones(rows, int)
+    wide[::max(rows // 16, 2)] = 16      # sixteen-column rows among decodes
+    wide[1] = 0
+    wide = np.minimum(wide, np.maximum(tokens - np.cumsum(wide) + wide, 0))
+    ten = jax.jit(lambda s, start, adv, fresh: jax.lax.fori_loop(
+        0, 10, lambda _, c: kda.kda_update(
+            q, k, v, decay, beta, c, start, adv, fresh, columns=16,
+            impl="pallas")[1], s))
+    for name, adv in (("one column a row", np.ones(rows, int)),
+                      ("sixteen beside one", wide)):
+        start = jnp.asarray(np.cumsum(adv) - adv, jnp.int32)
+        fresh = jnp.asarray(np.arange(rows) % 7 == 3, jnp.int32)
+        adv = jnp.asarray(adv, jnp.int32)
+        pallas_mode.KERNEL_TILINGS.clear()
+        outs = {impl: kda.kda_update(q, k, v, decay, beta, state, start, adv,
+                                     fresh, columns=16, impl=impl)
+                for impl in ("pallas", "scan")}
+        (_, tiling), = pallas_mode.KERNEL_TILINGS
+        tiling = dict(tiling)
+        o_most = max(float(jnp.abs(outs["scan"][0]).max()), 1e-3)
+        s_most = max(float(jnp.abs(outs["scan"][1]).max()), 1.0)
+        err_o = _max_err(outs["pallas"][0], outs["scan"][0])
+        err_s = _max_err(outs["pallas"][1], outs["scan"][1])
+        by_adv = dict(sorted(collections.Counter(adv.tolist()).items()))
+        _say(f"kda_update pallas vs scan H={H} d={d} rows by live columns "
+             f"{by_adv} of {tokens} token rows, float32 state: grid "
+             f"{tiling['grid']}, state tile {tiling['state_tile']}; max abs "
+             f"err o {err_o:.3e} of {o_most:.3f}, state {err_s:.3e} of "
+             f"{s_most:.1f} (tolerance {tol:g} of the largest)")
+        _require(np.isfinite(err_o + err_s)
+                 and max(err_o / o_most, err_s / s_most) <= tol,
+                 f"kda_update {name} x {rows} rows within {tol:g}")
+        if pallas_mode.platform() == "cpu":
+            continue        # a time is the chip's to give
+        jax.block_until_ready(ten(state, start, adv, fresh))
+        t0 = time.perf_counter()
+        for _ in range(3):
+            out = ten(state, start, adv, fresh)
+        jax.block_until_ready(out)
+        _say(f"kda_update {rows} rows, {name}: "
+             f"{(time.perf_counter() - t0) / 30 * 1e3:.3f} ms a call "
+             f"(the state's {2 * rows * d * H * d * 4 / 819e9 * 1e3:.3f} ms "
+             "at 819 GB/s)")
+
+
 def _hyper_connection_parity(size: dict):
     """The two hyper-connection kernels (`hc_pre`, `hc_post`) against their
     plain `jax.numpy` forms on this device at `size["hyper"]`: bf16 streams
@@ -1045,6 +1127,7 @@ def leg_serve(size: dict, rehearsal: bool) -> dict:
     _sparse_parity(size)
     _kv_write_parity(size)
     _ssm_parity(size)
+    _kda_parity(size)
     _hyper_connection_parity(size)
 
     import paddle_tpu as paddle

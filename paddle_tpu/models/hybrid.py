@@ -1,24 +1,27 @@
 """A decoder whose mixer differs by layer: the stack that the hybrid
-state-space families share (`models/granitemoehybrid.py`, `models/jamba.py`).
+families share (`models/granitemoehybrid.py`, `models/jamba.py`,
+`models/solar_open2.py`).
 
     x = E[ids] [* embedding_multiplier]
     for l: x = x + [residual_multiplier *] Mixer_l(RMSNorm(x))
            x = x + [residual_multiplier *] FFN_l(RMSNorm(x))
     logits = RMSNorm(x) @ E^T [/ logits_scaling]           (tied head)
+           | RMSNorm(x) @ W_head             (`tie_word_embeddings` false)
 
-`kinds[l]` is "mamba" (a state-space mixer of `nn/layer/mamba.py`) or
-"attention" (`models/llama.py`'s, without rotary embedding: the state-space
-layers carry the order). A family is a layer class: a subclass of
-`HybridDecoderLayer` that builds `self.mamba` or `self.self_attn` and its
-FFN's layers and says what the FFN computes (`ffn`); the multipliers are the
-family's configuration's, and one that is None is not traced at all.
-Serving only: `forward(labels=...)` raises (`ops/ssm.py` has no backward
-kernel).
+`kinds[l]` is "mamba" (a state-space mixer of `nn/layer/mamba.py`), "kda"
+(the delta-rule linear attention of `nn/layer/kda.py`) or "attention"
+(`models/llama.py`'s, without rotary embedding: the recurrent layers carry
+the order). A family is a layer class: a subclass of `HybridDecoderLayer`
+that builds its mixer under the attribute its kind names (`self.mamba`,
+`self.kda` or `self.self_attn`) and its FFN's layers and says what the FFN
+computes (`ffn`); the multipliers are the family's configuration's, and one
+that is None is not traced at all. Serving only: `forward(labels=...)`
+raises (`ops/ssm.py` and `ops/kda.py` have no backward kernel).
 
-The cached-decode contract (`init_cache` / `forward_with_cache`): a mamba
-layer's cache is a `models.generation.RecurrentState`, fixed in size, each
-of its two arrays in the type the mixer's `init_state` gives it; an
-attention layer's is its `(k, v)` slabs.
+The cached-decode contract (`init_cache` / `forward_with_cache`): a
+recurrent layer's cache (mamba, kda) is a `models.generation.RecurrentState`,
+fixed in size, each of its two arrays in the type the mixer's `init_state`
+gives it; an attention layer's is its `(k, v)` slabs.
 """
 from __future__ import annotations
 
@@ -27,29 +30,33 @@ from typing import Sequence
 import jax.numpy as jnp
 
 from ..core.tensor import apply
-from ..distributed.meta_parallel.mp_layers import VocabParallelEmbedding
+from ..distributed.meta_parallel.mp_layers import (ColumnParallelLinear,
+                                                   VocabParallelEmbedding)
 from ..nn.layer.layers import Layer, LayerList, parameter_dtype
 from ..ops.attention import take_positions
 from .generation import RecurrentState
 from .llama import RMSNorm
 
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, KDA, ATTENTION = "mamba", "kda", "attention"
+# the kinds whose mixer carries a fixed-size state (`init_state`, the
+# `Mamba2Mixer.forward` contract); a layer holds it under its kind's name
+RECURRENT = (MAMBA, KDA)
 
 
 def check_kinds(kinds: Sequence[str], num_layers: int, what: str) -> list:
     kinds = list(kinds)
-    bad = set(kinds) - {MAMBA, ATTENTION}
+    bad = set(kinds) - {*RECURRENT, ATTENTION}
     if bad or len(kinds) != num_layers:
         raise ValueError(
-            f"{what} must name {num_layers} layers, each {MAMBA!r} or "
-            f"{ATTENTION!r}; got {len(kinds)} with {sorted(bad)}")
+            f"{what} must name {num_layers} layers, each {MAMBA!r}, "
+            f"{KDA!r} or {ATTENTION!r}; got {len(kinds)} with {sorted(bad)}")
     return kinds
 
 
 class HybridDecoderLayer(Layer):
-    """One block. A subclass's `__init__` builds `self.mamba` (kind MAMBA)
-    or `self.self_attn` (ATTENTION) and its FFN's layers, then calls
-    `_norms`; its `ffn(h, live)` is the FFN of a normed `h`."""
+    """One block. A subclass's `__init__` builds `self.mamba` (kind MAMBA),
+    `self.kda` (KDA) or `self.self_attn` (ATTENTION) and its FFN's layers,
+    then calls `_norms`; its `ffn(h, live)` is the FFN of a normed `h`."""
 
     def __init__(self, kind: str, residual_multiplier=None):
         super().__init__()
@@ -63,12 +70,17 @@ class HybridDecoderLayer(Layer):
     def ffn(self, h, live=None):
         raise NotImplementedError
 
+    @property
+    def recurrent(self):
+        """The layer's recurrent mixer, None for an attention layer."""
+        return getattr(self, self.kind) if self.kind in RECURRENT else None
+
     def forward(self, hidden, cache=None, pos=None, paged=None, adv=None,
                 live=None, pack=None):
         h = self.input_layernorm(hidden)
         new_cache = None
-        if self.kind == MAMBA:
-            h = self.mamba(h, cache=cache, pos=pos, adv=adv, pack=pack)
+        if self.kind in RECURRENT:
+            h = self.recurrent(h, cache=cache, pos=pos, adv=adv, pack=pack)
         else:
             h = self.self_attn(h, cache=cache, pos=pos, paged=paged,
                                pack=pack)
@@ -132,17 +144,25 @@ class HybridModel(Layer):
 
 class HybridForCausalLM(Layer):
     """`config` as `HybridModel`'s, with `dtype`, `num_key_value_heads`,
-    `head_dim`, and `logits_scaling` where the family has one; `layers`
-    is called under the configuration's parameter type."""
+    `head_dim`, and `logits_scaling` or `tie_word_embeddings` false where
+    the family has them; `layers` is called under the configuration's
+    parameter type."""
 
     def __init__(self, config, layers):
         super().__init__()
         self.config = config
         with parameter_dtype(config.dtype):
             self.model = HybridModel(config, layers())
+            if not getattr(config, "tie_word_embeddings", True):
+                self.lm_head = ColumnParallelLinear(
+                    config.hidden_size, config.vocab_size, has_bias=False,
+                    gather_output=False)
 
     def _logits(self, hidden):
-        """The tied head: the embedding's rows are the output's columns."""
+        """The tied head (the embedding's rows are the output's columns),
+        or the model's own `lm_head`."""
+        if not getattr(self.config, "tie_word_embeddings", True):
+            return self.lm_head(hidden)
         scaling = getattr(self.config, "logits_scaling", None)
         if scaling is None:
             return apply(lambda h, e: h @ e.T, hidden,
@@ -164,15 +184,15 @@ class HybridForCausalLM(Layer):
         cfg = self.config
         dt = dtype or self.model.embed_tokens.weight.dtype
         kv = (batch_size, cfg.num_key_value_heads, max_len, cfg.head_dim)
-        return [RecurrentState(*layer.mamba.init_state(batch_size, dt))
-                if layer.kind == MAMBA
+        return [RecurrentState(*layer.recurrent.init_state(batch_size, dt))
+                if layer.kind in RECURRENT
                 else (jnp.zeros(kv, dt), jnp.zeros(kv, dt))
                 for layer in self.model.layers]
 
     def query_heads_by_layer(self):
         """Each layer's query heads, one entry an `init_cache` entry: a
         recurrent layer has none."""
-        return [0 if layer.kind == MAMBA else layer.self_attn.num_heads
+        return [0 if layer.kind in RECURRENT else layer.self_attn.num_heads
                 for layer in self.model.layers]
 
     def forward_with_cache(self, input_ids, caches, pos, paged=None,

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -90,10 +90,11 @@ class LlamaConfig:
     # layer (q, o and the GQA group are then the layer's own; the KV heads
     # and the head width are the model's). None: `num_attention_heads`
     num_attention_heads_per_layer: Optional[Sequence[int]] = None
-    # a sigmoid gate a query head on the attention output, computed from
-    # the layer's normed input by `g_proj [hidden, heads]` ("Gated
-    # Attention for Large Language Models", the head-wise form)
-    attn_output_gate: bool = False
+    # a sigmoid gate on the attention output, computed from the layer's
+    # normed input ("Gated Attention for Large Language Models"): True, a
+    # gate a query head by `g_proj [hidden, heads]` (the head-wise form);
+    # "elementwise", a gate a channel by `g_proj [hidden, heads * head_dim]`
+    attn_output_gate: Union[bool, str] = False
     # one entry a layer, DENSE or SPARSE; None: every layer sparse where
     # `num_experts` > 0. A DENSE layer's FFN is the SwiGLU MLP of width
     # `intermediate_size`
@@ -318,15 +319,16 @@ class LlamaAttention(Layer):
             self.k_norm = RMSNorm(self.num_kv_heads * self.head_dim,
                                   config.rms_norm_eps)
         if config.attn_output_gate:
-            self.g_proj = ColumnParallelLinear(h, self.num_heads,
-                                               has_bias=False,
-                                               gather_output=False)
+            wide = config.attn_output_gate == "elementwise"
+            self.g_proj = ColumnParallelLinear(
+                h, self.num_heads * (self.head_dim if wide else 1),
+                has_bias=False, gather_output=False)
 
     def _gated(self, ctx, hidden):
         """`ctx [..., heads * head_dim]` with each head's part times that
-        head's gate, `sigmoid(hidden g_proj)` in float32 (`hidden` the
-        layer's normed input, in ctx's layout); ctx itself where the model
-        has no gate."""
+        head's gate (each channel times its own in the element-wise form),
+        `sigmoid(hidden g_proj)` in float32 (`hidden` the layer's normed
+        input, in ctx's layout); ctx itself where the model has no gate."""
         if not self.config.attn_output_gate:
             return ctx
         hd = self.head_dim
@@ -334,6 +336,8 @@ class LlamaAttention(Layer):
         def gate(c, g):
             with jax.named_scope("attn_gate"):
                 g = jax.nn.sigmoid(g.astype(jnp.float32))
+                if g.shape[-1] == c.shape[-1]:
+                    return c * g.astype(c.dtype)
                 c = c.reshape(*c.shape[:-1], -1, hd)
                 return (c * g[..., None].astype(c.dtype)).reshape(
                     *c.shape[:-2], -1)

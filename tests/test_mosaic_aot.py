@@ -34,7 +34,7 @@ def tool():
 def test_pallas_kernels_compile_for_v5e_without_a_chip(tool):
     cases = [ln for ln in tool.splitlines()
              if ln.startswith(("[OK]", "[FAIL]"))]
-    assert len(cases) == 83 and all(c.startswith("[OK]") for c in cases)
+    assert len(cases) == 88 and all(c.startswith("[OK]") for c in cases)
     paged = [c for c in cases if c.startswith("[OK] paged bf16")]
     assert len(paged) == 19         # tools/mosaic_aot_check.py's two lists
     window = [c for c in cases if "'paged_window': 1" in c]
@@ -186,13 +186,38 @@ def test_the_mamba1_recurrence_compiles_at_the_reasoning_cells_shapes(tool):
                in t for t in tilings), tilings
     conv = [ln for ln in tool.splitlines()
             if ln.startswith("[OK] conv_tokens bf16")]
-    assert len(conv) == 2 and all("{'conv_tokens': 1}" in c for c in conv)
+    assert len(conv) == 3 and all("{'conv_tokens': 1}" in c for c in conv)
     assert any(ln.startswith("tiling conv_tokens") and "'grid': (4, 8), "
                "'tile': (32, 3, 1280)" in ln for ln in tool.splitlines())
     step, = [ln for ln in tool.splitlines()
              if ln.startswith("[OK] serve step, 3 Mamba-1 layers")]
     assert ": 3 Mosaic bodies " in step
     assert "'selective_scan': 3" in step and "'paged_attention': 1" in step
+
+
+def test_the_delta_rule_recurrence_compiles_at_the_linear_cells_shapes(tool):
+    """`kda_update` for the v5e at Solar-Open2's widths: a float32 state of
+    128 x 8,192 a row, the columns the packed step's 512 token rows, 4 rows
+    and 8 heads a grid step at the cell's 256 slots (the q, k and decay
+    tiles of a token through one 128 x 128 transpose); one-shot
+    `generate()`'s decode step; a prompt of 3,000 columns in chunks; the
+    conv in front of it over 24,576 channels; and in an engine's step the
+    three KDA layers share one body and the state is aliased."""
+    cases = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] kda_update rows=")]
+    assert len(cases) == 3 and all("{'kda_update': 1}" in c for c in cases)
+    tilings = [ln for ln in tool.splitlines()
+               if ln.startswith("tiling kda_update")]
+    assert any("'columns': 16, 'grid': (8, 64), 'state_tile': (4, 128, 1024)"
+               in t for t in tilings), tilings
+    assert any("'columns': 256, 'grid': (8, 1), 'state_tile': (2, 128, 1024)"
+               in t for t in tilings), tilings
+    assert any(ln.startswith("[OK] conv_tokens bf16 rows=256 tokens=512 "
+                             "carried=[3,24576]") for ln in tool.splitlines())
+    step, = [ln for ln in tool.splitlines()
+             if ln.startswith("[OK] serve step, 3 KDA layers")]
+    assert ": 15 Mosaic bodies " in step       # 12 of them the experts'
+    assert "'kda_update': 3" in step and "'paged_attention': 1" in step
 
 
 def test_a_recomputed_layer_holds_one_flash_forward_for_the_v5e(tool):
@@ -253,8 +278,8 @@ def test_lowered_step_holds_one_kernel_body_a_shape_not_one_a_layer(tool):
     Mosaic body, that of an engine with window and full layers two, and
     the compiled step still a custom call a layer."""
     steps = [ln for ln in tool.splitlines() if ln.startswith("[OK] serve")]
-    assert len(steps) == 7
-    full, mixed, hybrid, _, latent, indexed, streams = steps
+    assert len(steps) == 8
+    full, mixed, hybrid, _, _, latent, indexed, streams = steps
     # four residual streams (PR 47): two layers' four connections share one
     # `hc_pre` and one `hc_post` body; the walk, the write and the sparse
     # layer's three grouped matmuls as in the latent engine

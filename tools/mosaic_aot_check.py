@@ -650,6 +650,39 @@ def main() -> int:
         spec((2, 3000, 5120), jnp.float32), spec((16, 5120), jnp.float32),
         spec((2, 3000, 16), jnp.float32), spec((2, 3000, 16), jnp.float32),
         spec((2, 16, 5120), jnp.float32), want={"selective_scan": 1}))
+    # the delta-rule recurrence at Solar-Open2's widths (64 heads of 128 x
+    # 128, a float32 state, the columns token rows): the reasoning cell's
+    # packed step (256 slots, 512 tokens, 4 rows x 8 heads a grid step),
+    # one-shot generate()'s decode step, a whole prompt walked in chunks of
+    # columns; and the conv in front of it over q, k and v's 24,576 channels
+    from paddle_tpu.ops.kda import kda_update, kda_update_rows
+    for rows, tokens, columns in ((256, 512, 16), (2, 2, 1)):
+        results.append(compile_case(
+            f"kda_update rows={rows} tokens={tokens} state=f32[128,8192]",
+            functools.partial(kda_update, columns=columns),
+            spec((tokens, 8192), jnp.float32),
+            spec((tokens, 8192), jnp.float32),
+            spec((tokens, 8192), jnp.bfloat16),
+            spec((tokens, 8192), jnp.float32),
+            spec((tokens, 64), jnp.float32),
+            spec((rows, 128, 8192), jnp.float32), spec((rows,), jnp.int32),
+            spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+            want={"kda_update": 1}))
+    results.append(compile_case(
+        "kda_update rows=2 T=3000 (chunks of 256 columns) "
+        "state=f32[128,8192]", kda_update_rows,
+        spec((2, 3000, 8192), jnp.float32), spec((2, 3000, 8192), jnp.float32),
+        spec((2, 3000, 8192), jnp.bfloat16),
+        spec((2, 3000, 8192), jnp.float32), spec((2, 3000, 64), jnp.float32),
+        spec((2, 128, 8192), jnp.float32), want={"kda_update": 1}))
+    results.append(compile_case(
+        "conv_tokens bf16 rows=256 tokens=512 carried=[3,24576]",
+        causal_conv_tokens, spec((512, 24576), jnp.bfloat16),
+        spec((256, 3, 24576), jnp.bfloat16), spec((24576, 4), jnp.bfloat16),
+        spec((24576,), jnp.float32), spec((512,), jnp.int32),
+        spec((512,), jnp.int32), spec((256,), jnp.int32),
+        spec((256,), jnp.int32), spec((256,), jnp.int32),
+        want={"conv_tokens": 1}))
     # the unified step of an engine, its layers unrolled: a kernel's
     # jitted entry gives the lowered module one Mosaic body a distinct
     # (shapes, window) pair (the paged kernels) or (shapes, ring) pair
@@ -699,6 +732,21 @@ def main() -> int:
     model.eval()
     results.append(serve_step_case(
         "serve step, 3 Mamba-1 layers + 1 multi-query", model, dev1[0], 3))
+    # KDA layers behind a gated NoPE GQA layer: the three recurrent layers
+    # share one `kda_update` body, and the float32 state is aliased beside
+    # the bfloat16 conv columns and pages (the unpacked form)
+    from paddle_tpu.models.solar_open2 import (SolarOpen2Config,
+                                               SolarOpen2ForCausalLM)
+    model = SolarOpen2ForCausalLM(SolarOpen2Config(
+        vocab_size=512, hidden_size=256, moe_intermediate_size=128,
+        num_hidden_layers=4, num_attention_heads=2, num_key_value_heads=1,
+        head_dim=128, linear_num_heads=8, linear_head_dim=128,
+        n_routed_experts=8, num_experts_per_tok=2,
+        max_position_embeddings=1024, dtype="bfloat16",
+        experts_held=(0, 4)))
+    model.eval()
+    results.append(serve_step_case(
+        "serve step, 3 KDA layers + 1 gated GQA", model, dev1[0], 15))
     # latent pages in the donated pool: three MLA layers share one
     # `paged_latent` body and one `kv_write` body; two sparse layers'
     # grouped matmuls, 3 each
